@@ -18,10 +18,6 @@ The user contract mirrors the reference's two-trait API (``WorkerLogic`` /
 ``ParameterServerLogic``) in functional form — see :mod:`fps_tpu.core.api`.
 """
 
-from fps_tpu.utils import compat as _compat
-
-_compat.install()
-
 from fps_tpu.core.api import ServerLogic, WorkerLogic, StepOutput
 from fps_tpu.core.device_ingest import DeviceDataset, DeviceEpochPlan
 from fps_tpu.core.driver import Trainer, TrainerConfig, num_workers_of

@@ -11,7 +11,8 @@ list (with payload bytes, replica groups, custom-call targets) plus the
 ``@main`` argument/result metadata (donation markers, ``jax.result_info``
 names) that the analysis passes (:mod:`fps_tpu.analysis.passes`) audit.
 
-Parsing is line-based, matching the textual form jax 0.4.x emits — the
+Parsing is line-based, matching the textual form jax emits (0.4.x through
+the installed 0.9.0; tests/test_analysis.py holds one of each) — the
 same approach (and the exact same payload/threshold semantics) as the
 ``count_collectives`` helper this module absorbs from ``bench.py``. It
 is deliberately tolerant: unknown ops are still modeled (kind + types),
@@ -63,7 +64,11 @@ _ATTRS = r'\{(?:[^{}"]|"[^"]*"|\{[^{}]*\})*\}'
 _ARG_RE = re.compile(
     r"%arg(\d+):\s*(tensor<[^>]*>|![^,\s){]+)\s*(" + _ATTRS + r")?"
 )
-_RESULT_INFO_RE = re.compile(r'jax\.result_info\s*=\s*"([^"]*)"')
+# jax 0.9 spells the path ``result[0]['tab']`` where 0.4.x wrote
+# ``[0]['tab']``; the leading word is dropped so ``HloResult.info`` is the
+# bare path on either (saved dumps in the older form still parse).
+_RESULT_INFO_RE = re.compile(
+    r'jax\.result_info\s*=\s*"(?:result(?=\[|"))?([^"]*)"')
 # Float element types inside tensor<...> forms: the dims and dtype are
 # one word-char run ("64x8xf32"), so anchor on the preceding 'x' or '<'
 # instead of a word boundary.

@@ -440,8 +440,13 @@ def make_chunks(args, mesh, data, *, route_key=None):
 
 
 def make_mesh(args):
+    """The run's mesh. Every example CLI builds it before its first
+    compile, so this is also where the persistent compilation cache is
+    switched on."""
     from fps_tpu.parallel.mesh import make_ps_mesh
+    from fps_tpu.utils.hostenv import enable_compilation_cache
 
+    enable_compilation_cache()
     return make_ps_mesh(num_shards=args.num_shards, num_data=args.num_data)
 
 

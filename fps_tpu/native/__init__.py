@@ -5,8 +5,8 @@ parsing, skip-gram pair generation) is plain CPU work on the TPU VM, and the
 reference's equivalent layer runs as compiled JVM operators inside Flink.
 This package gives the rebuild a comparable native layer without adding
 dependencies: ``src/fps_native.cc`` is compiled with ``g++ -O3`` the first
-time it's needed (result cached next to the source, rebuilt when the source
-changes) and bound via ctypes. Everything degrades gracefully: if no
+time it's needed (result cached next to the source, rebuilt when the source's
+CONTENT changes) and bound via ctypes. Everything degrades gracefully: if no
 compiler is available, callers use the numpy implementations.
 
 API:
@@ -25,6 +25,7 @@ API:
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -40,12 +41,34 @@ _lib = None
 _tried = False
 
 
+def _source_digest() -> str:
+    with open(_SRC, "rb") as f:
+        return hashlib.sha256(f.read()).hexdigest()
+
+
+def _stale() -> bool:
+    """Is the built library missing or built from other source CONTENT?
+
+    The library is untracked, so a copied or restored tree can carry one
+    whose mtime is newer than a source it was not built from; the digest
+    of the source it WAS built from is recorded beside it instead."""
+    try:
+        with open(_LIB + ".sha256") as f:
+            built_from = f.read().strip()
+    except OSError:
+        return True
+    return not os.path.exists(_LIB) or built_from != _source_digest()
+
+
 def _build() -> bool:
     # Compile to a unique temp path then rename: concurrent processes must
     # never dlopen a half-written .so (the failure would be cached for the
-    # process lifetime).
+    # process lifetime). The digest is read BEFORE compiling and recorded
+    # AFTER the library lands, so an edit or a crash in between leaves a
+    # mismatch (rebuild), never a stale library marked fresh.
     tmp = f"{_LIB}.{os.getpid()}.tmp"
     try:
+        digest = _source_digest()
         subprocess.run(
             ["g++", "-O3", "-shared", "-fPIC", "-o", tmp, _SRC],
             check=True,
@@ -53,6 +76,9 @@ def _build() -> bool:
             timeout=120,
         )
         os.replace(tmp, _LIB)
+        with open(tmp, "w") as f:
+            f.write(digest + "\n")
+        os.replace(tmp, _LIB + ".sha256")
         return True
     except (OSError, subprocess.SubprocessError):
         return False
@@ -70,11 +96,7 @@ def _load():
         if _lib is not None or _tried:
             return _lib
         _tried = True
-        stale = (
-            not os.path.exists(_LIB)
-            or os.path.getmtime(_LIB) < os.path.getmtime(_SRC)
-        )
-        if stale and not _build():
+        if _stale() and not _build():
             return None
         try:
             lib = ctypes.CDLL(_LIB)

@@ -87,21 +87,21 @@ def get_backend() -> str:
     return _BACKEND
 
 
-def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except RuntimeError:
-        return False
-
-
 def _use_pallas() -> tuple[bool, bool]:
-    """(use_pallas, interpret) for the current backend setting."""
+    """(use_pallas, interpret) for programs traced now — the ONE place
+    both are decided; every kernel call site below takes its
+    ``interpret`` from here.
+
+    On TPU a kernel is never interpreted. Off TPU only the forced
+    ``"pallas"`` backend runs kernels, interpreted (the CPU suite's path);
+    ``"auto"`` stays on XLA there. A backend that fails to initialise
+    raises out of ``jax.default_backend()`` — it is never read as "not a
+    TPU", which would quietly swap a compiled kernel for an interpreted
+    one or an XLA route."""
     if _BACKEND == "xla":
         return False, False
-    if _on_tpu():
+    if jax.default_backend() == "tpu":
         return True, False
-    # Off-TPU: only the explicit "pallas" setting runs (interpreted);
-    # "auto" falls back to XLA for speed.
     return _BACKEND == "pallas", True
 
 
@@ -167,15 +167,15 @@ DIM1_MIN_BATCH = 8_192
 DENSE_TABLE_BYTES = 4 << 20
 
 
-def _route_dim1(R: int, D: int, B: int, dtype=jnp.float32) -> bool:
-    if D != 1 or _BACKEND == "xla":
-        return False
-    # The kernels carry values as bf16 hi+lo: f64 would silently lose 8
-    # mantissa bits and integer tables their exact-add semantics.
+def _bf16_pair_ok(dtype) -> bool:
+    """The dim-1 kernels carry values as bf16 hi+lo: f64 would silently
+    lose 8 mantissa bits and integer tables their exact-add semantics."""
     dt = jnp.dtype(dtype)
-    if dt.itemsize > 4 or not jnp.issubdtype(dt, jnp.floating):
-        return False
-    if not (_on_tpu() or _BACKEND == "pallas"):
+    return dt.itemsize <= 4 and jnp.issubdtype(dt, jnp.floating)
+
+
+def _route_dim1(R: int, D: int, B: int, dtype=jnp.float32) -> bool:
+    if D != 1 or not _bf16_pair_ok(dtype) or not _use_pallas()[0]:
         return False
     return R <= DIM1_MAX_ROWS and B >= DIM1_MIN_BATCH
 
@@ -194,10 +194,7 @@ def _route_head_prefix(R: int, D: int, head_prefix: int, hot_rows: int,
     ``fps_tpu.utils.datasets.head_sort_slots``)."""
     if head_prefix < 2048 or hot_rows <= 0 or D != 1:
         return False
-    if _BACKEND == "xla" or not (_on_tpu() or _BACKEND == "pallas"):
-        return False
-    dt = jnp.dtype(dtype)
-    if dt.itemsize > 4 or not jnp.issubdtype(dt, jnp.floating):
+    if not _bf16_pair_ok(dtype) or not _use_pallas()[0]:
         return False
     # Head kernel must be meaningfully cheaper than running the prefix
     # through the full-table route it would otherwise take.
@@ -229,19 +226,20 @@ def gather_rows(table: Array, ids: Array, *, hot_rows: int = 0,
     actually certified.
     """
     R, D = table.shape
+    interpret = _use_pallas()[1]
     if not exact and _route_head_prefix(R, D, head_prefix, hot_rows,
                                         table.dtype):
         from fps_tpu.ops.pallas_kernels import gather_rows_dim1_pallas
 
         head = gather_rows_dim1_pallas(
-            table[:hot_rows], ids[:head_prefix], interpret=not _on_tpu()
+            table[:hot_rows], ids[:head_prefix], interpret=interpret
         )
         tail = gather_rows(table, ids[head_prefix:])
         return jnp.concatenate([head, tail], axis=0)
     if not exact and _route_dim1(R, D, ids.shape[0], table.dtype):
         from fps_tpu.ops.pallas_kernels import gather_rows_dim1_pallas
 
-        return gather_rows_dim1_pallas(table, ids, interpret=not _on_tpu())
+        return gather_rows_dim1_pallas(table, ids, interpret=interpret)
     # Forced-pallas only: XLA's gather is not collision-serialized, and
     # dedup-safe on-chip measurement shows it matching or beating the
     # one-hot kernel at the shipped workloads' shapes, so "auto" never
@@ -251,7 +249,7 @@ def gather_rows(table: Array, ids: Array, *, hot_rows: int = 0,
     ):
         from fps_tpu.ops.pallas_kernels import gather_rows_pallas
 
-        return gather_rows_pallas(table, ids, interpret=not _on_tpu())
+        return gather_rows_pallas(table, ids, interpret=interpret)
     in_range = (ids >= 0) & (ids < R)
     vals = jnp.take(table, jnp.where(in_range, ids, 0), axis=0)
     return jnp.where(in_range[:, None], vals, jnp.zeros_like(vals))
@@ -306,7 +304,7 @@ def scatter_add(
 
         head_new = scatter_add_dim1_pallas(
             table[:hot_rows], ids[:head_prefix], deltas[:head_prefix],
-            interpret=not _on_tpu(),
+            interpret=interpret,
         )
         table = jax.lax.dynamic_update_slice_in_dim(table, head_new, 0,
                                                     axis=0)
@@ -317,7 +315,7 @@ def scatter_add(
 
         return scatter_add_dim1_pallas(table, ids, deltas,
                                        row_tile=512, batch_tile=8192,
-                                       interpret=not _on_tpu())
+                                       interpret=interpret)
 
     if use and hot_rows >= R > 0:
         # Whole-shard packed routing (hot_ids="auto" below the measured

@@ -95,12 +95,8 @@ def init_distributed(coordinator_address: str | None = None,
     """
     # CPU fleets (and the multi-process test harness): cross-process
     # collectives need the gloo transport; without it the CPU backend
-    # refuses multiprocess computations outright. Best-effort — the knob
-    # moved/disappeared across jax versions, and TPU ignores it.
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except (AttributeError, ValueError):
-        pass
+    # refuses multiprocess computations outright. TPU ignores it.
+    jax.config.update("jax_cpu_collectives_implementation", "gloo")
     jax.distributed.initialize(
         coordinator_address=coordinator_address,
         num_processes=num_processes,

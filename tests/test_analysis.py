@@ -155,6 +155,26 @@ def test_arg_attrs_survive_quoted_braces():
     assert results[0].info == "[0]['tab']"
 
 
+def test_main_signature_in_jax_0_9_form():
+    """The installed jax (0.9.0) writes ``result[0]['tab']`` where 0.4.x
+    wrote ``[0]['tab']`` and shards with sdy attributes whose value nests
+    braces; both forms must yield the same bare path, or DonationAudit
+    sees no table result and certifies an empty model."""
+    sig = (
+        'func.func public @main('
+        '%arg0: tensor<48x4xf32> {jax.buffer_donor = true, sdy.sharding = '
+        '#sdy.sharding<@mesh, [{"shard"}, {}]>}, '
+        '%arg1: tensor<4x256xi32> {sdy.sharding = '
+        '#sdy.sharding<@mesh, [{}, {"data", "shard"}]>}) -> '
+        '(tensor<48x4xf32> {jax.result_info = "result[0][\'tab\']"}, '
+        'tensor<64x4xf32> {jax.result_info = "result[1]"}, '
+        'tensor<4xf32> {jax.result_info = "result"}) {'
+    )
+    args, results = HloProgram._parse_main(sig)
+    assert [a.donated for a in args] == [True, False]
+    assert [r.info for r in results] == ["[0]['tab']", "[1]", ""]
+
+
 def test_collective_profile_thresholds():
     # 2 data-plane collectives: the scalar psum is sub-threshold, the
     # singleton-group psum is excluded regardless of payload.

@@ -112,9 +112,6 @@ def main():
         raise RuntimeError("re-exec failed to provide 8 devices")
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = cpu_mesh_env(8)
-    env["PYTHONPATH"] = os.pathsep.join(
-        [root] + [p for p in env["PYTHONPATH"].split(os.pathsep) if p]
-    )
     subprocess.run(
         [sys.executable, os.path.abspath(__file__)] + sys.argv[1:],
         env=env, cwd=root, check=True,

@@ -91,9 +91,6 @@ def _reexec_if_needed() -> None:
     if hostenv.in_reexec():
         return
     env = hostenv.cpu_mesh_env(8)
-    env["PYTHONPATH"] = os.pathsep.join(
-        [_ROOT] + [p for p in env.get("PYTHONPATH", "").split(os.pathsep)
-                   if p])
     os.execve(sys.executable, [sys.executable] + sys.argv, env)
 
 

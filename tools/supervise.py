@@ -151,7 +151,13 @@ def main(argv=None) -> int:
                          "from disk instead of retracing, so "
                          "restart-to-first-dispatch (the digest's "
                          "restart_to_first_signal_s) stops paying the "
-                         "compile on every recovery")
+                         "compile on every recovery. Without the flag "
+                         "children inherit JAX_COMPILATION_CACHE_DIR "
+                         "from this environment; with neither, the "
+                         "example CLIs fall back to the fixed "
+                         "<checkout>/.jax_cache "
+                         "(fps_tpu.utils.hostenv), so a restarted "
+                         "child still hits")
     ap.add_argument("--pretty", action="store_true",
                     help="indent the digest JSON")
     # Split at the first literal "--" BEFORE parsing: parse_known_args
