@@ -16,8 +16,9 @@ calls:
   sharded over every device.
 * **pa** — binary PA-I at RCV1 width (47,236 features, 64 nnz, 800,000
   docs), the one headline that stands on Pallas under the default
-  ``auto`` backend: one epoch with the kernels counted at trace time (they
-  must have been traced COMPILED on a TPU), then the same epoch under
+  ``auto`` backend: one epoch whose Pallas routes are read from the
+  program's own route log (``fps_tpu.ops.routes_traced``; they must have
+  been traced COMPILED on a TPU), then the same epoch under
   ``ops.set_backend("xla")``, and the two results compared.
 * **kernels** — all five Pallas kernels compiled and run once at their
   production shapes against exact float64 host references.
@@ -63,7 +64,6 @@ The body is importable: :func:`run_smoke` takes a mesh and a
 
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 import json
 import sys
@@ -74,9 +74,9 @@ import numpy as np
 PAIR_EPS = 2.0 ** -15
 F32_EPS = 2.0 ** -18
 
-_DIM1 = ("gather_rows_dim1_pallas", "scatter_add_dim1_pallas")
-_KERNELS = _DIM1 + ("scatter_add_packed_pallas", "scatter_add_pallas",
-                    "gather_rows_pallas")
+_KERNELS = ("gather_rows_dim1_pallas", "scatter_add_dim1_pallas",
+            "scatter_add_packed_pallas", "scatter_add_pallas",
+            "gather_rows_pallas")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -143,34 +143,6 @@ def device_identity() -> dict:
 
 def _on_tpu(mesh) -> bool:
     return mesh.devices.flat[0].platform == "tpu"
-
-
-@contextlib.contextmanager
-def record_kernel_traces():
-    """Log ``(kernel, interpret, table_rows, num_ids)`` for every Pallas
-    kernel entry point TRACED inside the block. ``fps_tpu.ops`` resolves
-    the kernels from their module at each call, so wrapping the module
-    attributes sees exactly what the routed program contains — route
-    predicates alone would pass vacuously."""
-    from fps_tpu.ops import pallas_kernels as pk
-
-    traced = []
-    saved = {name: getattr(pk, name) for name in _KERNELS}
-
-    def wrap(name, fn):
-        def counting(table, ids, *args, **kw):
-            traced.append((name, bool(kw.get("interpret", False)),
-                           int(table.shape[0]), int(ids.shape[0])))
-            return fn(table, ids, *args, **kw)
-        return counting
-
-    for name, fn in saved.items():
-        setattr(pk, name, wrap(name, fn))
-    try:
-        yield traced
-    finally:
-        for name, fn in saved.items():
-            setattr(pk, name, fn)
 
 
 # ---------------------------------------------------------------------------
@@ -355,10 +327,13 @@ def stage_mf(mesh, sizes: Sizes) -> dict:
 
 def _pa_epoch(mesh, sizes: Sizes, data, q):
     """One PA-I epoch from zero weights under the ops backend in force;
-    returns (weights (F,), mistake rate, steps, kernels traced, seconds)."""
+    returns (weights (F,), mistake rate, steps, Pallas routes traced,
+    seconds). The routes are the program's own log (``fps_tpu.ops.
+    routes_traced``): ``(route, interpret, table rows, ids)`` for every
+    Pallas call the routed epoch program holds."""
     import jax
 
-    from fps_tpu import DeviceDataset, DeviceEpochPlan, num_workers_of
+    from fps_tpu import DeviceDataset, DeviceEpochPlan, num_workers_of, ops
     from fps_tpu.models.passive_aggressive import (
         PAConfig, passive_aggressive,
     )
@@ -372,10 +347,12 @@ def _pa_epoch(mesh, sizes: Sizes, data, q):
                            num_workers=num_workers_of(mesh),
                            local_batch=sizes.pa_local_batch, seed=1)
     t0 = time.perf_counter()
-    with record_kernel_traces() as traced:
-        tables, local_state, metrics = trainer.run_indexed(
-            tables, local_state, plan, jax.random.key(1))
+    ops.clear_routes()
+    tables, local_state, metrics = trainer.run_indexed(
+        tables, local_state, plan, jax.random.key(1))
     jax.block_until_ready(tables)
+    traced = [(r.route, r.interpret, r.rows, r.ids)
+              for r in ops.routes_traced() if r.route in ops.PALLAS_ROUTES]
     secs = time.perf_counter() - t0
     m = metrics[0]
     require(all(np.isfinite(v).all() for v in jax.tree.leaves(m)),
@@ -431,12 +408,14 @@ def stage_pa(mesh, sizes: Sizes) -> dict:
             f"pa: kernels traced with interpret != {want_interpret}: "
             f"{kernels}")
     names = {k[0] for k in kernels}
-    require(set(_DIM1) <= names, f"pa: dim-1 kernels missing from {names}")
+    require({"gather.dim1", "scatter_add.dim1"} <= names,
+            f"pa: dim-1 routes missing from {names}")
     if q:
         head = {k[0] for k in kernels if k[2] == sizes.pa_head}
         full = {k[0] for k in kernels if k[2] != sizes.pa_head}
-        require(set(_DIM1) <= head and set(_DIM1) <= full,
-                f"pa: want head-prefix AND full-table dim-1 kernels, got "
+        require(head == {"gather.dim1_head", "scatter_add.dim1_head"}
+                and {"gather.dim1", "scatter_add.dim1"} <= full,
+                f"pa: want head-prefix AND full-table dim-1 routes, got "
                 f"head {head} full {full}")
 
     ops.set_backend("xla")

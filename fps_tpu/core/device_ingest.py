@@ -36,6 +36,12 @@ Two consumption styles, one geometry (:class:`DeviceEpochPlan`):
   *inside* its compiled scan, fusing ingest into the training program —
   one dispatch per epoch, zero per-epoch host↔device traffic.
 
+Names in a trace: the per-step batch gather runs under the driver's
+``fps.ingest`` scope; the device programs here that run once a call or
+once a chunk carry names WITHOUT the ``fps.`` prefix (``ingest.pack``,
+``ingest.tbuf``, ``ingest.perm``, ``ingest.chunk``), because a reader may
+count steps by the ops under ``fps.*`` (docs/observability.md).
+
 All grid geometry is baked into the trace as constants: integer div/mod by
 *traced* divisors makes XLA:TPU compiles pathologically slow (40s+ observed
 for this very function), and the grid row count is a power of two so the
@@ -51,6 +57,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from fps_tpu.obs.timing import host_span, settle
 from fps_tpu.parallel.mesh import DATA_AXIS, SHARD_AXIS, host_to_replicated
 
 Array = jax.Array
@@ -77,9 +84,11 @@ class DeviceDataset:
             raise ValueError(f"column lengths differ: {lengths}")
         self.n = next(iter(lengths.values()))
         self._host_data = {k: np.asarray(v) for k, v in data.items()}
-        self.columns = {
-            k: host_to_replicated(v, mesh) for k, v in self._host_data.items()
-        }
+        with host_span("dataset.place"):
+            self.columns = settle({
+                k: host_to_replicated(v, mesh)
+                for k, v in self._host_data.items()
+            })
         self._queues: dict[tuple[str | None, int], tuple[Array, np.ndarray]] = {}
 
     def queues(self, route_key: str | None, num_workers: int):
@@ -91,28 +100,31 @@ class DeviceDataset:
         """
         ck = (route_key, num_workers)
         if ck not in self._queues:
-            if route_key is None:
-                counts = np.full(num_workers, self.n // num_workers, np.int64)
-                counts[: self.n % num_workers] += 1
-                maxq = max(int(counts.max()), 1)
-                q = np.zeros((num_workers, maxq), np.int32)
-                for w in range(num_workers):
-                    q[w, : counts[w]] = np.arange(w, self.n, num_workers)
-            else:
-                keys = self._host_data[route_key].astype(np.int64) % num_workers
-                order = np.argsort(keys, kind="stable").astype(np.int32)
-                counts = np.bincount(keys, minlength=num_workers)
-                maxq = max(int(counts.max()), 1)
-                q = np.zeros((num_workers, maxq), np.int32)
-                start = 0
-                for w in range(num_workers):
-                    q[w, : counts[w]] = order[start : start + counts[w]]
-                    start += counts[w]
-            self._queues[ck] = (
-                host_to_replicated(q, self.mesh),
-                counts.astype(np.int64),
-            )
+            self._queues[ck] = self._build_queues(route_key, num_workers)
         return self._queues[ck]
+
+    @host_span("dataset.queues")
+    def _build_queues(self, route_key: str | None, num_workers: int):
+        """The host sort into per-worker queues, and its upload."""
+        if route_key is None:
+            counts = np.full(num_workers, self.n // num_workers, np.int64)
+            counts[: self.n % num_workers] += 1
+            maxq = max(int(counts.max()), 1)
+            q = np.zeros((num_workers, maxq), np.int32)
+            for w in range(num_workers):
+                q[w, : counts[w]] = np.arange(w, self.n, num_workers)
+        else:
+            keys = self._host_data[route_key].astype(np.int64) % num_workers
+            order = np.argsort(keys, kind="stable").astype(np.int32)
+            counts = np.bincount(keys, minlength=num_workers)
+            maxq = max(int(counts.max()), 1)
+            q = np.zeros((num_workers, maxq), np.int32)
+            start = 0
+            for w in range(num_workers):
+                q[w, : counts[w]] = order[start : start + counts[w]]
+                start += counts[w]
+        return settle(host_to_replicated(q, self.mesh)), counts.astype(
+            np.int64)
 
     def packed(self, route_key: str | None, num_workers: int):
         """Queue-ordered packed row matrix, or ``None`` when not packable.
@@ -141,6 +153,7 @@ class DeviceDataset:
                 names = [k for k, _ in items]
                 dtypes = [v.dtype for _, v in items]
 
+                @jax.named_scope("ingest.pack")
                 def build(queues, columns):
                     flat = queues.reshape(-1)
                     chans = [
@@ -151,10 +164,11 @@ class DeviceDataset:
                     ]
                     return jnp.stack(chans, axis=-1)
 
-                arr = jax.jit(
-                    build,
-                    out_shardings=NamedSharding(self.mesh, P()),
-                )(queues, self.columns)
+                with host_span("dataset.pack"):
+                    arr = settle(jax.jit(
+                        build,
+                        out_shardings=NamedSharding(self.mesh, P()),
+                    )(queues, self.columns))
                 cache[ck] = (arr, names, dtypes)
             else:
                 cache[ck] = None
@@ -178,6 +192,7 @@ class DeviceEpochPlan:
     epoch; positions past a worker's queue produce weight-0 padding rows.
     """
 
+    @host_span("plan.build")
     def __init__(self, dataset: DeviceDataset, *, num_workers: int,
                  local_batch: int, route_key: str | None = None,
                  shuffle: str | None = "interleave", seed: int = 0,
@@ -238,6 +253,7 @@ class DeviceEpochPlan:
                 lambda: jax.random.key_data(jax.random.key(0))
             ).shape
 
+            @jax.named_scope("ingest.perm")
             def mk_perm(key_data):
                 key = jax.random.wrap_key_data(key_data)
                 keys = jax.random.split(key, W)
@@ -268,6 +284,7 @@ class DeviceEpochPlan:
         out_rows = self.steps_per_epoch * self.local_batch
         C = num_channels
 
+        @jax.named_scope("ingest.tbuf")
         def build(packed_mat, off_w):
             outs = []
             for w in range(W):
@@ -319,6 +336,7 @@ class DeviceEpochPlan:
             (tag, self.seed & ((1 << 64) - 1), epoch)
         )
 
+    @host_span("epoch_args")
     def epoch_args(self, epoch: int):
         """Device operands for one epoch (replicated pytree)."""
         mesh = self.dataset.mesh
@@ -434,6 +452,7 @@ class DeviceEpochPlan:
             )
             W, B, s = self.num_workers, self.local_batch, self.sync_every
 
+            @jax.named_scope("ingest.chunk")
             def build(args, start_step):
                 ts = start_step + jnp.arange(steps_per_chunk, dtype=jnp.int32)
                 ws = jnp.arange(W, dtype=jnp.int32)

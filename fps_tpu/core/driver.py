@@ -80,7 +80,7 @@ from fps_tpu.obs.health import (
     HealthMonitor,
     StepWatchdog,
 )
-from fps_tpu.obs.timing import PhaseTimer
+from fps_tpu.obs.timing import PhaseTimer, host_span, settle
 from fps_tpu.parallel.mesh import (
     DATA_AXIS,
     SHARD_AXIS,
@@ -111,8 +111,9 @@ def calls_per_epoch_of(plan, steps_per_call: int) -> int:
 
 
 def _phase(timer: PhaseTimer | None, name: str):
-    """Timer phase scope, or a free no-op when telemetry is off."""
-    return timer.phase(name) if timer is not None else contextlib.nullcontext()
+    """One host phase of a call: :func:`fps_tpu.obs.timing.host_span`,
+    feeding ``timer`` when telemetry is on (two flag tests when off)."""
+    return host_span(name, timer)
 
 
 def _watch(watchdog: StepWatchdog | None, what: str, index: int):
@@ -357,17 +358,19 @@ class Trainer:
     # -- state ------------------------------------------------------------
 
     def init_state(self, key: Array) -> tuple[dict[str, Array], Pytree]:
-        tables = self.store.init(jax.random.fold_in(key, 0))
-        ls_key = jax.random.fold_in(key, 1)
+        with host_span("init_state"):
+            tables = self.store.init(jax.random.fold_in(key, 0))
+            ls_key = jax.random.fold_in(key, 1)
 
-        def make_local_state():
-            return self.logic.init_local_state(ls_key, self.num_workers)
+            def make_local_state():
+                return self.logic.init_local_state(ls_key, self.num_workers)
 
-        local_state = jax.jit(
-            make_local_state,
-            out_shardings=jax.tree.map(lambda _: self._worker_sharding,
-                                       jax.eval_shape(make_local_state)),
-        )()
+            local_state = jax.jit(
+                make_local_state,
+                out_shardings=jax.tree.map(lambda _: self._worker_sharding,
+                                           jax.eval_shape(make_local_state)),
+            )()
+            settle((tables, local_state))
         return tables, local_state
 
     # -- checkpoint plumbing ----------------------------------------------
@@ -1528,10 +1531,12 @@ class Trainer:
                         tables, bufs, t, pushes, hp)
                 out = self._mount_hot_channel(out, hcounts, delta, tier,
                                               dropped)
-                out = jax.tree.map(
-                    lambda x: lax.psum(lax.psum(x, SHARD_AXIS), DATA_AXIS), out
-                )
-                out = self._run_tap(out, tables, batch_t, local_state, t)
+                with jax.named_scope("fps.metrics"):
+                    out = jax.tree.map(
+                        lambda x: lax.psum(lax.psum(x, SHARD_AXIS),
+                                           DATA_AXIS), out
+                    )
+                    out = self._run_tap(out, tables, batch_t, local_state, t)
                 return (tables, hot, delta, fstates, sk, bufs,
                         local_state, key, t + 1), out
 
@@ -1642,7 +1647,8 @@ class Trainer:
             for name, sl in sorted(self.server_logic.items())
         )
 
-    def _get_compiled(self, mode: str, compact_ok: bool = True):
+    def _get_compiled(self, mode: str, compact_ok: bool = True,
+                      timer=None):
         # Keyed on the ops backend, push_delay, and server logic too:
         # set_backend() or a config/logic change after a compile must take
         # effect on the next chunk, not be shadowed by the jit cache.
@@ -1662,11 +1668,13 @@ class Trainer:
                tuple(sorted(self._mapped_tables().items())),
                tuple(sorted(self._track_specs().items())),
                tuple(sorted(compact.items())))
-        if key not in self._compiled:
-            label = "chunk/" + mode + ("+compact" if compact else "")
-            self._compiled[key] = self._wrap_audit(
-                self._build_chunk_fn(mode, compact), label)
-        return self._compiled[key]
+        with host_span("program_lookup", timer) as span:
+            span["built"] = key not in self._compiled
+            if span["built"]:
+                label = "chunk/" + mode + ("+compact" if compact else "")
+                self._compiled[key] = self._wrap_audit(
+                    self._build_chunk_fn(mode, compact), label)
+            return self._compiled[key]
 
     # -- compile-time program certification (fps_tpu.analysis) ------------
 
@@ -1797,7 +1805,8 @@ class Trainer:
             bufs = None
             if self.config.push_delay:
                 # Probe batch for push shapes (unused value, DCE'd by XLA).
-                batch0 = plan.local_batch_at(iargs, widx, start)
+                with jax.named_scope("fps.ingest"):
+                    batch0 = plan.local_batch_at(iargs, widx, start)
                 bufs = self._init_push_bufs(tables, local_state, batch0, key)
 
             hp_seen = {}
@@ -1806,7 +1815,10 @@ class Trainer:
                 (tables, hot, delta, fstates, sk, bufs, local_state,
                  key) = carry
                 key, sub = jax.random.split(key)
-                batch = plan.local_batch_at(iargs, widx, t)
+                # Device ingest: the step's batch, gathered from the
+                # resident dataset inside the compiled loop.
+                with jax.named_scope("fps.ingest"):
+                    batch = plan.local_batch_at(iargs, widx, t)
                 (pushes, local_state, out, hp, hcounts,
                  sk) = self._compute_step(
                     tables, snapshot, local_state, batch, sub,
@@ -1822,10 +1834,12 @@ class Trainer:
                         tables, bufs, t, pushes, hp)
                 out = self._mount_hot_channel(out, hcounts, delta, tier,
                                               dropped)
-                out = jax.tree.map(
-                    lambda x: lax.psum(lax.psum(x, SHARD_AXIS), DATA_AXIS), out
-                )
-                out = self._run_tap(out, tables, batch, local_state, t)
+                with jax.named_scope("fps.metrics"):
+                    out = jax.tree.map(
+                        lambda x: lax.psum(lax.psum(x, SHARD_AXIS),
+                                           DATA_AXIS), out
+                    )
+                    out = self._run_tap(out, tables, batch, local_state, t)
                 return (tables, hot, delta, fstates, sk, bufs,
                         local_state, key), out
 
@@ -2087,7 +2101,7 @@ class Trainer:
         rollback.record(index)
         return metrics, (tables, local_state)
 
-    def _get_indexed_fn(self, plan, mode: str):
+    def _get_indexed_fn(self, plan, mode: str, timer=None):
         """Compiled epoch program for the CURRENT config (looked up per
         epoch, not per run: a HealthMonitor escalation swaps the guard
         mid-run and the next epoch must recompile, keyed on the plan
@@ -2100,12 +2114,15 @@ class Trainer:
               tuple(sorted(self._hot_tier_map().items())),
               tuple(sorted(self._mapped_tables().items())),
               tuple(sorted(self._track_specs().items())))
-        if ck not in self._compiled:
-            self._compiled[ck] = self._wrap_audit(
-                self._build_indexed_fn(plan, mode), f"indexed/{mode}")
-        return self._compiled[ck]
+        with host_span("program_lookup", timer) as span:
+            span["built"] = ck not in self._compiled
+            if span["built"]:
+                self._compiled[ck] = self._wrap_audit(
+                    self._build_indexed_fn(plan, mode), f"indexed/{mode}")
+            return self._compiled[ck]
 
-    def _get_megastep_fn(self, plan, mode: str, K: int, tick=None):
+    def _get_megastep_fn(self, plan, mode: str, K: int, tick=None,
+                         timer=None):
         """Compiled K-chunk megastep program (fps_tpu.core.megastep) for
         the CURRENT config — cache-keyed like the indexed program, plus
         the chunk count and the tick contract (its decayed-sketch spec,
@@ -2125,13 +2142,15 @@ class Trainer:
               tuple(sorted(self._track_specs().items())),
               tuple(sorted(self._cold_compact_map().items())),
               tick_key)
-        if ck not in self._compiled:
-            from fps_tpu.core import megastep as _megastep
+        with host_span("program_lookup", timer) as span:
+            span["built"] = ck not in self._compiled
+            if span["built"]:
+                from fps_tpu.core import megastep as _megastep
 
-            self._compiled[ck] = self._wrap_audit(
-                _megastep.build_megastep_fn(self, plan, mode, K, tick),
-                f"megastep/{mode}")
-        return self._compiled[ck]
+                self._compiled[ck] = self._wrap_audit(
+                    _megastep.build_megastep_fn(self, plan, mode, K, tick),
+                    f"megastep/{mode}")
+            return self._compiled[ck]
 
     def run_megastep(self, tables, local_state, plan, key, *,
                      epochs: int = 1, chunks_per_dispatch: int = 4,
@@ -2188,6 +2207,7 @@ class Trainer:
             self.store.tables = saved
             self.retierer = saved_rt
 
+    @host_span("run_indexed", call=True)
     def run_indexed(self, tables, local_state, plan, key, *, epochs: int = 1,
                     on_epoch=None, checkpointer=None,
                     checkpoint_every: int = 0, start_epoch: int = 0,
@@ -2262,7 +2282,8 @@ class Trainer:
         self._enter_tiering()
         # Two-tier re-split at run entry (restore/warm-start/config
         # changes); per-epoch calls keep the attached structure.
-        tables = self._attach_hot(tables, timer)
+        with _phase(timer, "attach_hot"):
+            tables = self._attach_hot(tables, timer)
         try:
             for e in range(start_epoch, end_epoch):
                 if rollback is not None and e in rollback.preset:
@@ -2274,7 +2295,7 @@ class Trainer:
                         rec.inc("rollback.preset_skipped")
                         rec.flush()
                     continue
-                fn = self._get_indexed_fn(plan, mode)
+                fn = self._get_indexed_fn(plan, mode, timer)
                 if quarantine is not None:
                     last_good = (resilience.tree_copy(tables),
                                  resilience.tree_copy(local_state))
@@ -2284,15 +2305,20 @@ class Trainer:
                 _beat(hb, e, "dispatch")
                 with _watch(watchdog, "epoch", e):
                     for ci in range(n_calls):
-                        ckey = key_to_replicated(
-                            jax.random.fold_in(jax.random.fold_in(key, e), ci),
-                            self.mesh,
-                        )
-                        start = np.int32(ci * T_call)
+                        # dispatch: all the host does to queue one call
+                        # (key derivation and placement, then the call);
+                        # enqueue: the jitted call alone.
                         with _phase(timer, "dispatch"):
-                            tables, local_state, metrics = fn(
-                                tables, local_state, iargs, start, ckey
+                            ckey = key_to_replicated(
+                                jax.random.fold_in(
+                                    jax.random.fold_in(key, e), ci),
+                                self.mesh,
                             )
+                            start = np.int32(ci * T_call)
+                            with _phase(timer, "enqueue"):
+                                tables, local_state, metrics = fn(
+                                    tables, local_state, iargs, start, ckey
+                                )
                         parts.append(metrics)
                     metrics = parts[0] if len(parts) == 1 else jax.tree.map(
                         lambda *xs: jnp.concatenate(xs), *parts
@@ -2455,9 +2481,9 @@ class Trainer:
             else:
                 batches = self._place_chunk(batches, mode)
             key = key_to_replicated(key, self.mesh)
-        with _phase(timer, "dispatch"):
-            tables, local_state, metrics = self._get_compiled(
-                mode, compact_ok)(
+        fn = self._get_compiled(mode, compact_ok, timer)
+        with _phase(timer, "dispatch"), _phase(timer, "enqueue"):
+            tables, local_state, metrics = fn(
                 tables, local_state, batches, key
             )
         # The donated input buffers are dead now; keep the store's host-side
@@ -2489,6 +2515,7 @@ class Trainer:
 
         return jax.tree.map(place, batches)
 
+    @host_span("fit_stream", call=True)
     def fit_stream(
         self,
         tables,
@@ -2680,7 +2707,8 @@ class Trainer:
         self._enter_tiering()
         # Two-tier re-split at stream entry; run_chunk keeps the attached
         # structure live across the loop.
-        tables = self._attach_hot(tables, timer)
+        with _phase(timer, "attach_hot"):
+            tables = self._attach_hot(tables, timer)
 
         def retier_boundary(j):
             """Adaptive-tiering boundary for an adjudicated-clean chunk:

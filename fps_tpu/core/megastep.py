@@ -68,7 +68,7 @@ from fps_tpu.core.store import (
     sketch_key,
     split_tiering,
 )
-from fps_tpu.obs.timing import PhaseTimer
+from fps_tpu.obs.timing import PhaseTimer, host_span
 from fps_tpu.parallel.mesh import (
     DATA_AXIS,
     SHARD_AXIS,
@@ -184,7 +184,8 @@ def build_megastep_fn(trainer, plan, mode: str, K: int, tick=None):
             def step_t(c, t, snapshot=None):
                 (tables, hot, delta, fstates, sk, local_state, kk) = c
                 kk, sub = jax.random.split(kk)
-                batch = plan.local_batch_at(iargs, widx, t)
+                with jax.named_scope("fps.ingest"):
+                    batch = plan.local_batch_at(iargs, widx, t)
                 (pushes, local_state, out, hp, hcounts,
                  sk) = trainer._compute_step(
                     tables, snapshot, local_state, batch, sub,
@@ -200,8 +201,10 @@ def build_megastep_fn(trainer, plan, mode: str, K: int, tick=None):
                     tables = trainer._apply_pushes(tables, pushes, hp)
                 out = trainer._mount_hot_channel(out, hcounts, delta,
                                                  tier, dropped)
-                out = jax.tree.map(_psum_workers, out)
-                out = trainer._run_tap(out, tables, batch, local_state, t)
+                with jax.named_scope("fps.metrics"):
+                    out = jax.tree.map(_psum_workers, out)
+                    out = trainer._run_tap(out, tables, batch, local_state,
+                                           t)
                 return (tables, hot, delta, fstates, sk, local_state,
                         kk), out
 
@@ -437,6 +440,7 @@ def build_megastep_fn(trainer, plan, mode: str, K: int, tick=None):
     return jax.jit(run, donate_argnums=donate)
 
 
+@host_span("run_megastep", call=True)
 def run_megastep(trainer, tables, local_state, plan, key, *,
                  epochs: int = 1, chunks_per_dispatch: int = 4,
                  on_megastep=None, checkpointer=None,
@@ -564,7 +568,8 @@ def run_megastep(trainer, tables, local_state, plan, key, *,
     T_call = trainer._indexed_call_steps(plan)
     n_calls = calls_per_epoch_of(plan, T_call)
     T = plan.steps_per_epoch
-    tables = trainer._attach_hot(tables, timer)
+    with _phase(timer, "attach_hot"):
+        tables = trainer._attach_hot(tables, timer)
     if auto_k:
         from fps_tpu.core.autok import calibrate_chunks_per_dispatch
 
@@ -580,7 +585,7 @@ def run_megastep(trainer, tables, local_state, plan, key, *,
     compact_cfg = trainer._cold_compact_map()
     vote_on = bool(compact_cfg) and bool(
         vote_certifiable_tables(trainer, plan))
-    fn = trainer._get_megastep_fn(plan, mode, K, tick)
+    fn = trainer._get_megastep_fn(plan, mode, K, tick, timer)
     if rec is not None:
         rec.set("megastep.chunks_per_dispatch", K)
     all_metrics = []
@@ -667,7 +672,7 @@ def run_megastep(trainer, tables, local_state, plan, key, *,
             _beat(hb, g, "dispatch")
             restored = None
             with _watch(watchdog, "megastep", g):
-                with _phase(timer, "megastep"):
+                with _phase(timer, "megastep"), _phase(timer, "enqueue"):
                     tables, local_state, metrics, aux = fn(
                         tables, local_state, iargs, np.int32(m * K),
                         ekey, tick_ops)

@@ -51,8 +51,9 @@ import collections
 import logging
 import os
 import threading
-import time
 from typing import Callable, Iterable
+
+from fps_tpu.obs.timing import host_span
 
 _log = logging.getLogger("fps_tpu.prefetch")
 
@@ -207,23 +208,22 @@ class ChunkPrefetcher:
                         self._cv.wait()
                     if self._stop:
                         return
-                t0 = time.perf_counter()
-                item = next(self._it, _END)
-                if (item is not _END and self._place is not None
-                        and self._index not in self._skip_place):
-                    placed = self._place(item)
-                    # A place_fn may return a ready PlacedChunk itself
-                    # (the driver's certifying wrapper does, to attach
-                    # host_ids); only wrap bare batch pytrees.
-                    item = (placed if isinstance(placed, PlacedChunk)
-                            else PlacedChunk(placed))
-                self._index += 1
-                dt = time.perf_counter() - t0
-                if item is not _END:
-                    if self._timer is not None:
-                        self._timer.add("prefetch", dt)
-                    if self._rec is not None:
-                        self._rec.inc("prefetch.chunks")
+                # Worker-thread time (assemble + place), overlapped with
+                # the driver's phases; the pull that finds the stream
+                # exhausted is a (near-empty) segment too.
+                with host_span("prefetch", self._timer):
+                    item = next(self._it, _END)
+                    if (item is not _END and self._place is not None
+                            and self._index not in self._skip_place):
+                        placed = self._place(item)
+                        # A place_fn may return a ready PlacedChunk itself
+                        # (the driver's certifying wrapper does, to attach
+                        # host_ids); only wrap bare batch pytrees.
+                        item = (placed if isinstance(placed, PlacedChunk)
+                                else PlacedChunk(placed))
+                    self._index += 1
+                if item is not _END and self._rec is not None:
+                    self._rec.inc("prefetch.chunks")
                 with self._cv:
                     if self._stop:
                         return

@@ -6,9 +6,14 @@ One subsystem, four altitudes (see ``docs/observability.md``):
   type every metrics leaf; :class:`Recorder` validates emissions and fans
   them out to pluggable sinks (:class:`JsonlSink`,
   :class:`PrometheusSink`, :class:`MemorySink`).
-* **timing** — :class:`PhaseTimer` splits each chunk into host phases
-  (ingest/place/dispatch/host_sync/checkpoint/callback);
-  :class:`Throughput` and :func:`trace` complete the clock set.
+* **timing** — :func:`host_span` is the one host span primitive: a
+  ``fps.host.<name>`` annotation on the profiler's clock, a
+  ``driver.phase_seconds`` sample, and a span record with its parent and
+  the enclosing driver call; :class:`PhaseTimer` splits each chunk into
+  host phases through it (ingest/place/dispatch/host_sync/checkpoint/
+  callback/...); :func:`watch_compiles` folds JAX's compile timings and
+  cache hits in; :class:`Throughput` and :func:`trace` complete the
+  clock set.
 * **alerting** — :class:`HealthMonitor` thresholds the guard's health
   channel (observe→mask escalation, poison abort);
   :class:`StepWatchdog` deadline-flags stalled chunks/stragglers.
@@ -27,6 +32,34 @@ One subsystem, four altitudes (see ``docs/observability.md``):
 
 Everything is host-side: attaching a recorder never changes the compiled
 program (tested), and ``recorder=None`` costs nothing.
+
+Names a trace or a journal is read by (``docs/observability.md`` has the
+table with what opens each):
+
+=========================  ==============================================
+device scope               ``fps.ingest`` ``fps.pull`` ``fps.compute``
+(``jax.named_scope``,      ``fps.push`` ``fps.metrics`` and, inside pull
+step bodies ONLY: a        and push, ``fps.ops/<op>.<route>`` with route
+reader counts steps by     one of ``gather.dim1_head|dim1|onehot|xla``,
+the ops under ``fps.*``)   ``scatter_add.dim1_head|dim1|packed|
+                           packed_head|onehot|xla``
+once a call / a chunk      ``ingest.pack`` ``ingest.tbuf`` ``ingest.perm``
+(no ``fps.`` prefix)       ``ingest.chunk``
+host span                  ``run_indexed`` ``fit_stream`` ``run_megastep``
+(``fps.host.<name>``,      (the call) > ``attach_hot`` > ``reconcile``,
+:data:`DRIVER_PHASES`      ``program_lookup``, ``epoch_args``, ``ingest``,
+and companions in          ``place``, ``dispatch`` > ``enqueue``,
+``obs/timing.py``)         ``megastep``, ``host_sync``, ``checkpoint``,
+                           ``callback``, ``retier``, ``prefetch``;
+                           set-up: ``dataset.place`` ``dataset.queues``
+                           ``dataset.pack`` ``plan.build`` ``init_state``
+compile phase              ``compile.trace`` ``compile.lower``
+                           ``compile.backend``; counters
+                           ``compile.cache_hits`` / ``_misses``; event
+                           ``program_compiled``
+route log                  ``fps_tpu.ops.routes_traced()``: ``(op, route,
+                           rows, dim, ids, interpret, reason)``
+=========================  ==============================================
 """
 
 from __future__ import annotations
@@ -61,7 +94,14 @@ from fps_tpu.obs.registry import (
     default_registry,
 )
 from fps_tpu.obs.sinks import JsonlSink, MemorySink, PrometheusSink, Sink
-from fps_tpu.obs.timing import DRIVER_PHASES, PhaseTimer, Throughput, trace
+from fps_tpu.obs.timing import (
+    DRIVER_PHASES,
+    PhaseTimer,
+    Throughput,
+    host_span,
+    trace,
+    watch_compiles,
+)
 from fps_tpu.obs.trace import (
     PARENT_SPAN_ENV,
     TRACE_ID_ENV,
@@ -75,6 +115,7 @@ __all__ = [
     "MetricSpec", "MetricsRegistry", "Recorder", "default_registry",
     "Sink", "JsonlSink", "MemorySink", "PrometheusSink",
     "PhaseTimer", "Throughput", "trace", "DRIVER_PHASES",
+    "host_span", "watch_compiles",
     "HealthMonitor", "StepWatchdog",
     "HEALTH_OK", "HEALTH_ESCALATE", "HEALTH_ABORT",
     "RunJournal", "new_run_id", "config_digest", "process_index",

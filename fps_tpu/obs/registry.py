@@ -109,9 +109,28 @@ def default_registry() -> MetricsRegistry:
         # Phase timers (fps_tpu.obs.timing.PhaseTimer).
         MetricSpec("driver.phase_seconds", "histogram", unit="s",
                    labels=("phase",),
-                   help="host wall-clock per phase segment: ingest / place "
-                        "/ dispatch / host_sync / checkpoint / callback / "
-                        "reconcile / retier / megastep"),
+                   help="host wall-clock per host span (fps_tpu.obs."
+                        "timing.host_span): the serial driver phases "
+                        "prefetch / ingest / place / dispatch / host_sync "
+                        "/ checkpoint / callback / reconcile / retier / "
+                        "megastep / epoch_args / program_lookup; enqueue "
+                        "and attach_hot, nested in another phase; the "
+                        "call spans run_indexed / fit_stream / "
+                        "run_megastep; the set-up spans dataset.place / "
+                        "dataset.queues / dataset.pack / plan.build / "
+                        "init_state; and JAX's own compile.trace / "
+                        "compile.lower / compile.backend (work done under "
+                        "dispatch) — a sum over phases must leave the "
+                        "nested, call and compile ones out"),
+        # Compiles, from inside (fps_tpu.obs.timing.watch_compiles).
+        MetricSpec("compile.cache_hits", "counter", unit="programs",
+                   help="programs loaded from JAX's persistent compilation "
+                        "cache while a process-default recorder was "
+                        "installed"),
+        MetricSpec("compile.cache_misses", "counter", unit="programs",
+                   help="programs the persistent compilation cache did "
+                        "not hold and the backend compiled (each also a "
+                        "program_compiled event naming the function)"),
         # Device-resident megastep (fps_tpu.core.megastep;
         # docs/performance.md "Megastep").
         MetricSpec("megastep.windows", "counter", unit="windows",

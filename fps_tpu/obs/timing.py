@@ -5,14 +5,25 @@ metrics (SURVEY.md §5 tracing row). On TPU we get device-level tracing
 from ``jax.profiler`` for free; this module packages it plus the two
 host-side clocks the chunked driver makes natural:
 
+* :func:`host_span` — THE host span primitive: every host phase of a
+  call opens one. It is a ``jax.profiler.TraceAnnotation`` named
+  ``fps.host.<name>`` (so a profiler trace shows what the host was doing
+  on the device's own clock), a ``driver.phase_seconds{phase=<name>}``
+  sample, and — with a recorder — a canonical ``span`` event carrying
+  its parent span and the index of the enclosing driver call, from
+  which a reader takes self time.
 * :class:`PhaseTimer` — splits each chunk's host wall-clock into named
   segments (``ingest`` / ``place`` / ``dispatch`` / ``host_sync`` /
   ``checkpoint`` / ``callback``), so a BENCH regression is attributable
-  to a phase instead of a single opaque number. The compiled program
-  fuses pull/compute/push into one dispatch, so those sub-phases are
-  visible on the DEVICE timeline instead: the driver wraps them in
-  ``jax.named_scope`` (``fps.pull`` / ``fps.compute`` / ``fps.push``),
-  which costs nothing outside a profiler trace.
+  to a phase instead of a single opaque number; its phases ARE host
+  spans. The compiled program fuses ingest/pull/compute/push into one
+  dispatch, so those sub-phases are visible on the DEVICE timeline
+  instead: the driver wraps them in ``jax.named_scope`` (``fps.ingest`` /
+  ``fps.pull`` / ``fps.compute`` / ``fps.push`` / ``fps.metrics``),
+  which costs nothing outside a profiler trace
+  (``docs/observability.md`` has the table of names).
+* :func:`watch_compiles` — folds JAX's own compile timings and
+  persistent-cache hits and misses into the process-default recorder.
 * :class:`Throughput` — per-chunk wall-clock + examples/sec accounting
   for ``Trainer.fit_stream(on_chunk=...)``.
 * :func:`trace` — context manager writing a Perfetto/XProf-compatible
@@ -25,13 +36,20 @@ shim.)
 from __future__ import annotations
 
 import contextlib
+import itertools
 import threading
 import time
 
 import numpy as np
 
+from fps_tpu.obs import events
+from fps_tpu.obs.trace import new_span_id
+
 # Phase names the driver emits, in pipeline order. PhaseTimer accepts any
 # name (custom loops may add their own); these are the declared ones.
+# The serial ones tile a chunk's host time; NESTED_PHASES lie inside
+# another phase and COMPILE_PHASES are JAX's own timings of work done
+# under "dispatch"/"enqueue", so a sum over phases must leave both out.
 DRIVER_PHASES = (
     "prefetch",    # background pipeline: chunk assembly + placement on
                    # the worker thread (fps_tpu.core.prefetch) — OVERLAPS
@@ -39,16 +57,182 @@ DRIVER_PHASES = (
     "ingest",      # pulling the next chunk from the host iterator (with
                    # the pipeline on: waiting on the prefetch buffer)
     "place",       # host->device transfer (host_to_sharded)
-    "dispatch",    # the jitted call: enqueue + (first call) compile
+    "dispatch",    # all the host does to queue one call: key derivation
+                   # and placement, then "enqueue" (+ first-call compile)
     "host_sync",   # blocked fetching metrics back to host
     "checkpoint",  # snapshot save on the training thread
     "callback",    # user on_chunk / on_epoch hooks
     "reconcile",   # two-tier re-split at run entry (hot replica derive)
+    "retier",      # adaptive-tiering boundary (Retierer.on_boundary)
     "megastep",    # K-chunk device-resident dispatch (enqueue + first-
                    # call compile): the megastep driver's analog of
                    # "dispatch", kept distinct so the A/B's host-serial
                    # attribution can tell the two loop shapes apart
+    "epoch_args",      # DeviceEpochPlan.epoch_args: host RNG, operand
+                       # upload, the per-epoch ingest.tbuf / ingest.perm
+                       # programs (under "ingest" in the megastep loop)
+    "program_lookup",  # compiled-program cache lookup; built=True when
+                       # the lookup had to build (trace set-up, no compile)
 )
+NESTED_PHASES = (
+    "enqueue",     # the jitted call alone, inside dispatch / megastep
+    "attach_hot",  # Trainer._attach_hot, holding "reconcile"
+)
+COMPILE_PHASES = ("compile.trace", "compile.lower", "compile.backend")
+# Set-up spans (no timer: they report through the process-default
+# recorder). Those that queue device work close on its completion when a
+# recorder is installed, and only then (settle()).
+SETUP_PHASES = ("dataset.place", "dataset.queues", "dataset.pack",
+                "plan.build", "init_state")
+# The driver entry points: each opens a root span and numbers the call.
+CALL_SPANS = ("run_indexed", "fit_stream", "run_megastep")
+
+HOST_SPAN_PREFIX = "fps.host."
+
+_calls = itertools.count()
+_open = threading.local()  # .stack: [(span_id, call index)] of this thread
+
+
+def settle(tree):
+    """Wait for ``tree``'s device work — WHEN a process-default recorder is
+    installed, and not otherwise. The set-up spans that queue device work
+    (uploads, the packed pre-gather, ``init_state``) end with this:
+    set-up is serial, so waiting changes no overlap, and without it the
+    span would measure an enqueue. Returns ``tree``."""
+    if events.get_default_recorder() is not None:
+        import jax
+
+        jax.block_until_ready(tree)
+    return tree
+
+
+@contextlib.contextmanager
+def host_span(name: str, timer: "PhaseTimer | None" = None, *,
+              call: bool = False, **attrs):
+    """One host phase, on every clock the repo has.
+
+    Always a ``TraceAnnotation("fps.host.<name>")`` (a flag test when no
+    profiler runs). With ``timer`` the segment folds into it (and through
+    it into the timer's recorder); without, it is a
+    ``driver.phase_seconds{phase=name}`` sample on the process-default
+    recorder (a no-op when none is installed). Whichever recorder that is
+    also gets the canonical span record of :mod:`fps_tpu.obs.trace`
+    (``event: "span"``, ``span``, ``span_id``, ``parent_id``, ``t0``,
+    ``t1``) with the parent taken from this thread's stack of open spans
+    and ``call``, the index of the enclosing driver call — so
+    ``tools/trace_export.py`` renders it and a reader can take self time
+    (a span's length less its children's). ``call=True`` opens such a
+    driver call: ``@host_span("run_indexed", call=True)`` on the entry
+    point (a context manager made by ``contextlib`` is a decorator too,
+    opening a fresh span per call). Yields a dict: keys set on it before
+    the span closes ride the record (``built=True``).
+
+    No recorder, no profiler: two flag tests. Spans are per call or per
+    chunk, never per step.
+    """
+    import jax
+
+    rec = timer.recorder if timer is not None else None
+    guarded = rec is None
+    if guarded:
+        rec = events.get_default_recorder()
+    if rec is not None:
+        watch_compiles()
+    stack = getattr(_open, "stack", None)
+    if stack is None:
+        stack = _open.stack = []
+    parent, index = stack[-1] if stack else (None, None)
+    if call:
+        index = next(_calls)
+    sid = new_span_id() if rec is not None else None
+    timed = rec is not None or timer is not None
+    stack.append((sid, index))
+    t0, p0 = (time.time(), time.perf_counter()) if timed else (0.0, 0.0)
+    try:
+        with jax.profiler.TraceAnnotation(HOST_SPAN_PREFIX + name):
+            yield attrs
+    finally:
+        stack.pop()
+        if timed:
+            dt = time.perf_counter() - p0
+            if timer is not None:
+                timer.add(name, dt)
+            else:
+                events.record_metric("observe", "driver.phase_seconds", dt,
+                                     phase=name)
+            if rec is not None:
+                if index is not None:
+                    attrs.setdefault("call", index)
+                _emit_span(rec, guarded, name, sid, parent, t0, t0 + dt,
+                           attrs)
+
+
+def _emit_span(rec, guarded, name, sid, parent, t0, t1, attrs):
+    tracer = getattr(rec, "trace", None)
+    fields = dict(span=name, span_id=sid, t0=float(t0), t1=float(t1),
+                  trace_id=getattr(tracer, "trace_id", None),
+                  parent_id=parent or getattr(tracer, "parent_id", None),
+                  **attrs)
+    if guarded:
+        events.emit("span", **fields)
+    else:
+        rec.event("span", **fields)
+
+
+# -- compiles, from inside ----------------------------------------------
+
+_COMPILE_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "compile.trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "compile.lower",
+    "/jax/core/compile/backend_compile_duration": "compile.backend",
+}
+_CACHE_EVENTS = {
+    "/jax/compilation_cache/cache_hits": "compile.cache_hits",
+    "/jax/compilation_cache/cache_misses": "compile.cache_misses",
+}
+_watching = False
+_watch_lock = threading.Lock()
+
+
+def _on_compile_duration(event, duration, **kw):
+    phase = _COMPILE_EVENTS.get(event)
+    if phase is None:
+        return
+    events.record_metric("observe", "driver.phase_seconds", duration,
+                         phase=phase)
+    if phase == "compile.backend":
+        events.emit("program_compiled", seconds=float(duration),
+                    **({"fun_name": str(kw["fun_name"])}
+                       if "fun_name" in kw else {}))
+
+
+def _on_cache_event(event, **kw):
+    name = _CACHE_EVENTS.get(event)
+    if name is not None:
+        events.record_metric("inc", name, 1.0)
+
+
+def watch_compiles() -> None:
+    """Register (once a process) ``jax.monitoring`` listeners that fold
+    JAX's compile timings into ``driver.phase_seconds{phase="compile.
+    trace" | "compile.lower" | "compile.backend"}``, the persistent
+    cache's hits and misses into ``compile.cache_hits`` /
+    ``compile.cache_misses``, and a ``program_compiled`` event naming the
+    function where JAX passes its name — all on the process-default
+    recorder, so the listeners are inert when none is installed.
+    :func:`host_span` calls this the first time it sees a recorder."""
+    global _watching
+    if _watching:
+        return
+    with _watch_lock:
+        if _watching:
+            return
+        import jax
+
+        jax.monitoring.register_event_duration_secs_listener(
+            _on_compile_duration)
+        jax.monitoring.register_event_listener(_on_cache_event)
+        _watching = True
 
 
 class PhaseTimer:
@@ -70,13 +254,9 @@ class PhaseTimer:
         # while the driver thread closes phases and takes summaries.
         self._lock = threading.Lock()
 
-    @contextlib.contextmanager
     def phase(self, name: str):
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            self.add(name, time.perf_counter() - t0)
+        """One timed phase: a :func:`host_span` feeding this timer."""
+        return host_span(name, self)
 
     def add(self, name: str, seconds: float) -> None:
         """Fold an externally-measured segment into the current chunk —

@@ -26,11 +26,20 @@ Backend selection:
 * ``"pallas"`` — force the Pallas kernels (one-hot gather/scatter under
   :data:`SCATTER_FLOP_BUDGET`, plus the hot/cold split); off TPU they run
   in interpreter mode so the CPU-mesh test suite exercises them.
+
+Names: every branch runs the kernel or XLA call it ends in under
+``jax.named_scope("fps.ops")`` and, inside it, a scope naming the route
+(``gather.dim1_head``, ``scatter_add.xla``, ...: :data:`ROUTES`), so a
+device trace lays each op's time to the route that chose it; and the
+choice itself is appended to a route log at trace time
+(:func:`routes_traced`), with the reason a Pallas route was passed over.
 """
 
 from __future__ import annotations
 
+import contextlib
 import os
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -67,6 +76,65 @@ def packed_crossover_rows(dim: int) -> int:
     regime — on one shard the shipped tables (26k-1M rows) stay on XLA.
     """
     return 4096 if 17 <= dim <= 48 else 2048
+
+
+# Every route the two ops can take; the scope under ``fps.ops`` and the
+# ``route`` of a log entry are ``<op>.<route>``.
+ROUTES = {
+    "gather": ("dim1_head", "dim1", "onehot", "xla"),
+    "scatter_add": ("dim1_head", "dim1", "packed", "packed_head", "onehot",
+                    "xla"),
+}
+PALLAS_ROUTES = frozenset(
+    f"{op}.{r}" for op, rs in ROUTES.items() for r in rs if r != "xla")
+
+
+class Route(NamedTuple):
+    """One routing decision, logged where the chosen call is made — at
+    TRACE time, never in the compiled program."""
+    op: str          # "gather" | "scatter_add"
+    route: str       # "gather.dim1_head", "scatter_add.xla", ...
+    rows: int        # rows of the table (slice) the call sees
+    dim: int
+    ids: int         # ids the call moves
+    interpret: bool  # the Pallas kernel runs interpreted (off TPU)
+    reason: str      # why a Pallas route was passed over: "" (taken, or
+                     # exact read asked for), "f64", "flop_budget",
+                     # "backend", "shape"
+
+
+_ROUTES_TRACED: list[Route] = []
+
+
+def routes_traced() -> list[Route]:
+    """The route log: one entry per kernel or XLA call this module made
+    while programs were traced since :func:`clear_routes` (a head-prefix
+    composite logs its head and its tail). What a program holds is what
+    was logged while IT was traced: clear, trace, read."""
+    return list(_ROUTES_TRACED)
+
+
+def clear_routes() -> None:
+    _ROUTES_TRACED.clear()
+
+
+@contextlib.contextmanager
+def _routed(op: str, route: str, rows: int, dim: int, ids: int,
+            reason: str = ""):
+    """Log the decision and open the route's scope round its own call."""
+    name = f"{op}.{route}"
+    _ROUTES_TRACED.append(Route(
+        op, name, int(rows), int(dim), int(ids),
+        name in PALLAS_ROUTES and _use_pallas()[1], reason))
+    with _scope(name):
+        yield
+
+
+@contextlib.contextmanager
+def _scope(name: str):
+    """``fps.ops/<name>``: the scope of one route's own call."""
+    with jax.named_scope("fps.ops"), jax.named_scope(name):
+        yield
 
 
 def set_backend(name: str) -> None:
@@ -180,6 +248,18 @@ def _route_dim1(R: int, D: int, B: int, dtype=jnp.float32) -> bool:
     return R <= DIM1_MAX_ROWS and B >= DIM1_MIN_BATCH
 
 
+def _xla_reason(dtype) -> str:
+    """Why a call that reached the XLA route took no Pallas one, when no
+    budget decided it: the dtype cannot ride the kernels' f32 / bf16-pair
+    arithmetic, the backend keeps kernels out, or no kernel serves the
+    shape under this backend."""
+    if jnp.dtype(dtype).itemsize > 4:
+        return "f64"
+    if not _use_pallas()[0]:
+        return "backend"
+    return "shape"
+
+
 def _route_head_prefix(R: int, D: int, head_prefix: int, hot_rows: int,
                        dtype) -> bool:
     """Route the guaranteed-head prefix through a head-only dim-1 kernel?
@@ -231,37 +311,46 @@ def gather_rows(table: Array, ids: Array, *, hot_rows: int = 0,
                                         table.dtype):
         from fps_tpu.ops.pallas_kernels import gather_rows_dim1_pallas
 
-        head = gather_rows_dim1_pallas(
-            table[:hot_rows], ids[:head_prefix], interpret=interpret
-        )
+        with _routed("gather", "dim1_head", hot_rows, D, head_prefix):
+            head = gather_rows_dim1_pallas(
+                table[:hot_rows], ids[:head_prefix], interpret=interpret
+            )
+        # The tail opens its own scope BESIDE the head's, not under it.
         tail = gather_rows(table, ids[head_prefix:])
         return jnp.concatenate([head, tail], axis=0)
     if not exact and _route_dim1(R, D, ids.shape[0], table.dtype):
         from fps_tpu.ops.pallas_kernels import gather_rows_dim1_pallas
 
-        return gather_rows_dim1_pallas(table, ids, interpret=interpret)
+        with _routed("gather", "dim1", R, D, ids.shape[0]):
+            return gather_rows_dim1_pallas(table, ids, interpret=interpret)
     # Forced-pallas only: XLA's gather is not collision-serialized, and
     # dedup-safe on-chip measurement shows it matching or beating the
     # one-hot kernel at the shipped workloads' shapes, so "auto" never
     # routes WIDE gathers to Pallas (the dim-1 route above is measured).
-    if not exact and _BACKEND == "pallas" and D >= 64 and (
-        R * ids.shape[0] * D <= SCATTER_FLOP_BUDGET
-    ):
+    onehot = not exact and _BACKEND == "pallas" and D >= 64
+    if onehot and R * ids.shape[0] * D <= SCATTER_FLOP_BUDGET:
         from fps_tpu.ops.pallas_kernels import gather_rows_pallas
 
-        return gather_rows_pallas(table, ids, interpret=interpret)
-    in_range = (ids >= 0) & (ids < R)
-    vals = jnp.take(table, jnp.where(in_range, ids, 0), axis=0)
-    return jnp.where(in_range[:, None], vals, jnp.zeros_like(vals))
+        with _routed("gather", "onehot", R, D, ids.shape[0]):
+            return gather_rows_pallas(table, ids, interpret=interpret)
+    reason = ("" if exact else "flop_budget" if onehot
+              else _xla_reason(table.dtype))
+    with _routed("gather", "xla", R, D, ids.shape[0], reason):
+        in_range = (ids >= 0) & (ids < R)
+        vals = jnp.take(table, jnp.where(in_range, ids, 0), axis=0)
+        return jnp.where(in_range[:, None], vals, jnp.zeros_like(vals))
 
 
-def _xla_scatter_add(table: Array, ids: Array, deltas: Array) -> Array:
-    """``table.at[ids].add(deltas)`` with drop semantics for ids ∉ [0, R)."""
-    R = table.shape[0]
-    keep = (ids >= 0) & (ids < R)
-    safe = jnp.where(keep, ids, R)
-    masked = jnp.where(keep[:, None], deltas, 0)
-    return table.at[safe].add(masked.astype(table.dtype), mode="drop")
+def _xla_scatter_add(table: Array, ids: Array, deltas: Array,
+                     reason: str) -> Array:
+    """``table.at[ids].add(deltas)`` with drop semantics for ids ∉ [0, R):
+    the ``scatter_add.xla`` route, taken for ``reason``."""
+    R, D = table.shape
+    with _routed("scatter_add", "xla", R, D, ids.shape[0], reason):
+        keep = (ids >= 0) & (ids < R)
+        safe = jnp.where(keep, ids, R)
+        masked = jnp.where(keep[:, None], deltas, 0)
+        return table.at[safe].add(masked.astype(table.dtype), mode="drop")
 
 
 def scatter_add(
@@ -293,7 +382,7 @@ def scatter_add(
     # in bf16 hi+lo); a table wider than f32 (f64) must take the XLA
     # scatter, which adds in the table's native dtype.
     if jnp.dtype(table.dtype).itemsize > 4:
-        return _xla_scatter_add(table, ids, deltas)
+        return _xla_scatter_add(table, ids, deltas, "f64")
 
     if _route_head_prefix(R, D, head_prefix, hot_rows, table.dtype):
         # Guaranteed-head prefix (see gather_rows): accumulate the prefix
@@ -302,20 +391,23 @@ def scatter_add(
         # split — the prefix split supersedes it for this call).
         from fps_tpu.ops.pallas_kernels import scatter_add_dim1_pallas
 
-        head_new = scatter_add_dim1_pallas(
-            table[:hot_rows], ids[:head_prefix], deltas[:head_prefix],
-            interpret=interpret,
-        )
-        table = jax.lax.dynamic_update_slice_in_dim(table, head_new, 0,
-                                                    axis=0)
+        with _routed("scatter_add", "dim1_head", hot_rows, D, head_prefix):
+            head_new = scatter_add_dim1_pallas(
+                table[:hot_rows], ids[:head_prefix], deltas[:head_prefix],
+                interpret=interpret,
+            )
+            table = jax.lax.dynamic_update_slice_in_dim(table, head_new, 0,
+                                                        axis=0)
+        # The tail opens its own scope BESIDE the head's, not under it.
         return scatter_add(table, ids[head_prefix:], deltas[head_prefix:])
 
     if _route_dim1(R, D, ids.shape[0], table.dtype):
         from fps_tpu.ops.pallas_kernels import scatter_add_dim1_pallas
 
-        return scatter_add_dim1_pallas(table, ids, deltas,
-                                       row_tile=512, batch_tile=8192,
-                                       interpret=interpret)
+        with _routed("scatter_add", "dim1", R, D, ids.shape[0]):
+            return scatter_add_dim1_pallas(table, ids, deltas,
+                                           row_tile=512, batch_tile=8192,
+                                           interpret=interpret)
 
     if use and hot_rows >= R > 0:
         # Whole-shard packed routing (hot_ids="auto" below the measured
@@ -324,35 +416,43 @@ def scatter_add(
         pack = max(1, 128 // D)
         head_flops = -(-R // pack) * (2 * ids.shape[0]) * 128
         if head_flops > SCATTER_FLOP_BUDGET:
-            return _xla_scatter_add(table, ids, deltas)
+            return _xla_scatter_add(table, ids, deltas, "flop_budget")
         from fps_tpu.ops.pallas_kernels import scatter_add_packed_pallas
 
-        return scatter_add_packed_pallas(table, ids, deltas,
-                                         interpret=interpret)
+        with _routed("scatter_add", "packed", R, D, ids.shape[0]):
+            return scatter_add_packed_pallas(table, ids, deltas,
+                                             interpret=interpret)
 
     if use and 0 < hot_rows < R:
         pack = max(1, 128 // D)
         head_flops = -(-hot_rows // pack) * (2 * ids.shape[0]) * 128
         if head_flops > SCATTER_FLOP_BUDGET:
-            return _xla_scatter_add(table, ids, deltas)
+            return _xla_scatter_add(table, ids, deltas, "flop_budget")
         from fps_tpu.ops.pallas_kernels import scatter_add_packed_pallas
 
         in_head = (ids >= 0) & (ids < hot_rows)
         head_ids = jnp.where(in_head, ids, -1)
         tail_ids = jnp.where(in_head, R, ids)
-        head_upd = scatter_add_packed_pallas(
-            jnp.zeros((hot_rows, D), table.dtype),
-            head_ids,
-            deltas,
-            interpret=interpret,
-        )
-        table = _xla_scatter_add(table, tail_ids, deltas)
-        return table.at[:hot_rows].add(head_upd)
+        with _routed("scatter_add", "packed_head", hot_rows, D,
+                     ids.shape[0]):
+            head_upd = scatter_add_packed_pallas(
+                jnp.zeros((hot_rows, D), table.dtype),
+                head_ids,
+                deltas,
+                interpret=interpret,
+            )
+        # The masked tail is the split's other half, not a fallback.
+        table = _xla_scatter_add(table, tail_ids, deltas, "")
+        with _scope("scatter_add.packed_head"):
+            return table.at[:hot_rows].add(head_upd)
 
-    if _BACKEND == "pallas" and use and (
-        R * ids.shape[0] * max(D, 1) <= SCATTER_FLOP_BUDGET
-    ):
+    onehot = _BACKEND == "pallas" and use
+    if onehot and R * ids.shape[0] * max(D, 1) <= SCATTER_FLOP_BUDGET:
         from fps_tpu.ops.pallas_kernels import scatter_add_pallas
 
-        return scatter_add_pallas(table, ids, deltas, interpret=interpret)
-    return _xla_scatter_add(table, ids, deltas)
+        with _routed("scatter_add", "onehot", R, D, ids.shape[0]):
+            return scatter_add_pallas(table, ids, deltas,
+                                      interpret=interpret)
+    return _xla_scatter_add(
+        table, ids, deltas,
+        "flop_budget" if onehot else _xla_reason(table.dtype))

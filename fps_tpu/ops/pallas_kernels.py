@@ -140,6 +140,7 @@ def scatter_add_pallas(
         ],
         out_specs=pl.BlockSpec((row_tile, D), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((R, D), table.dtype),
+        name="scatter_add_onehot",
         interpret=interpret,
     )(ids2, table, deltas2)
 
@@ -244,6 +245,7 @@ def scatter_add_packed_pallas(
         ],
         out_specs=pl.BlockSpec((row_tile, pack * D), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rp, pack * D), jnp.float32),
+        name="scatter_add_packed",
         interpret=interpret,
     )(ids2, d2)
     upd = acc.reshape(rp * pack, D)[:R]
@@ -378,6 +380,7 @@ def scatter_add_dim1_pallas(
         ],
         out_specs=pl.BlockSpec((row_tile, 128), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rp, 128), jnp.float32),
+        name="scatter_add_dim1",
         interpret=interpret,
     )(ids2, d2)
     upd = acc.reshape(rp * 128, 1)[:R]
@@ -465,6 +468,7 @@ def gather_rows_dim1_pallas(
         ],
         out_specs=pl.BlockSpec((1, batch_tile), lambda i, j: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, ids2.shape[1]), jnp.float32),
+        name="gather_dim1",
         interpret=interpret,
     )(ids2, hi, lo)
     return out.reshape(-1)[:B, None].astype(table.dtype)
@@ -538,6 +542,7 @@ def gather_rows_pallas(
         ],
         out_specs=pl.BlockSpec((batch_tile, D), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((ids2.shape[1], D), table.dtype),
+        name="gather_onehot",
         interpret=interpret,
     )(ids2, table)
     return out[:B]

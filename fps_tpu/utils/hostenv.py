@@ -71,7 +71,7 @@ def enable_compilation_cache() -> str | None:
     Every entry point (``chip_smoke.py``, ``bench.py``,
     ``__graft_entry__``, the example CLIs) calls this before its first
     compile. When the environment sets the directory JAX reads it itself
-    and nothing is set in code. Otherwise the default is one fixed
+    and no directory is set in code. Otherwise the default is one fixed
     directory inside the checkout — the path is part of JAX's cache key,
     so it is the same from every cwd and every process of a run, never a
     temp name — and every program is cached, whatever its compile time:
@@ -81,15 +81,31 @@ def enable_compilation_cache() -> str | None:
     entries. CPU runs are dry runs whose compiles are cheap, and XLA:CPU's
     loader logs a machine-feature error on every cache hit, so they are
     left uncached. Failures propagate: a cache that cannot be enabled is a
-    set-up error, not a slower run."""
-    path = os.environ.get(_CACHE_ENV)
-    if path:
-        return path
+    set-up error, not a slower run.
+
+    Wherever the cache is on, its key holds the programs' METADATA too
+    (``jax_compilation_cache_include_metadata_in_key``). By default JAX
+    keys an entry on the module with its debug info stripped, and the
+    ``jax.named_scope`` paths a device trace is read by (``fps.ingest``,
+    ``fps.ops/<route>``: docs/observability.md) live in that debug info:
+    a tree whose scopes changed would load, from a cache an older tree
+    filled, executables that carry the OLD scopes, and every reader of a
+    new scope would find nothing (measured on the v5e, PR 24). The
+    metadata holds source locations as well, so the checkout's root is
+    cut from them (``jax_hlo_source_file_canonicalization_regex``): one
+    tree keys the same wherever it is checked out. The price is that an
+    edit compiles once more the programs traced through the lines it
+    moved (3 of MF's 43 after an edit to driver.py: PERF.md, PR 24)."""
     import jax
 
-    if jax.default_backend() == "cpu":
-        return None
-    path = os.path.join(_REPO_ROOT, ".jax_cache")
-    jax.config.update("jax_compilation_cache_dir", path)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    path = os.environ.get(_CACHE_ENV)
+    if not path:
+        if jax.default_backend() == "cpu":
+            return None
+        path = os.path.join(_REPO_ROOT, ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+    jax.config.update("jax_hlo_source_file_canonicalization_regex",
+                      "^" + re.escape(_REPO_ROOT + os.sep))
     return path

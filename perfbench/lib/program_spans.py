@@ -1,0 +1,153 @@
+"""What the PROGRAM says about its own host time and routes, for the readers.
+
+fps_tpu names every host phase of a call (``fps_tpu.obs.timing.host_span``:
+set-up spans, the driver's phases, JAX's compile timings) and logs every
+ops route it takes (``fps_tpu.ops.routes_traced``). With a process-default
+``Recorder`` installed those land in its sink; this module turns the sink
+into the ``program_spans`` of a run's context — name -> the spans' ``(t0,
+t1)`` in epoch seconds, set-up and window apart — and holds the two kinds
+of reader over it. A program without the spans (a parent commit) leaves
+the sink empty: every reader then returns ``None`` and the metric is left
+out of the line; nothing here raises for want of something to read.
+
+NOT WIRED YET: ``runner.py`` installs no recorder and ``readers.READERS``
+does not hold :data:`READERS`, because a PR that is not a ``benchmark`` PR
+may edit no file the benchmark has (PERF.md, Open questions, lists the
+four additions to ``runner.py`` and the one to ``readers.py``). Until
+then ``tests/test_program_spans.py`` drives this module the way the
+runner will, and the six metric files that name these readers
+(``setup.*``, ``driver.epoch_args_ms``, ``driver.enqueue_ms``,
+``ops.pallas_routes_in_program``) are in no ``per_layer`` entry.
+"""
+
+from __future__ import annotations
+
+import statistics
+import time
+
+COMPILE_PHASES = ("compile.trace", "compile.lower", "compile.backend")
+
+
+def install_recorder():
+    """A memory-only recorder as the process default, before the system is
+    built (traced runs only). Returns ``(recorder, sink)``, or ``(None,
+    None)`` where the program has no recorder to install."""
+    try:
+        from fps_tpu import obs
+        from fps_tpu.obs import events
+    except ImportError:
+        return None, None
+    sink = obs.MemorySink(capacity=1 << 16)
+    recorder = obs.Recorder(sinks=[sink])
+    events.set_default_recorder(recorder)
+    return recorder, sink
+
+
+def epoch_of(perf_counter_s: float) -> float:
+    """A ``time.perf_counter`` reading on the spans' clock (epoch seconds)."""
+    return time.time() - (time.perf_counter() - perf_counter_s)
+
+
+def collect(sink, opened_at: float) -> dict:
+    """``{name: {"setup": [(t0, t1), ...], "window": [...]}}`` from a
+    recorder's sink: every ``span`` event, and JAX's compile timings (which
+    the program records as ``driver.phase_seconds{phase="compile.*"}``
+    samples: a duration ending at the sample's time). A span that ended
+    before ``opened_at`` (epoch seconds: the window's opening) is set-up."""
+    out: dict = {}
+
+    def put(name, t0, t1):
+        part = "setup" if t1 <= opened_at else "window"
+        out.setdefault(name, {"setup": [], "window": []})[part].append(
+            (float(t0), float(t1)))
+
+    if sink is None:
+        return out
+    for e in sink.events("span"):
+        put(e["span"], e["t0"], e["t1"])
+    for m in sink.metrics("driver.phase_seconds"):
+        phase = (m.get("labels") or {}).get("phase")
+        if phase in COMPILE_PHASES:
+            put(phase, m["t"] - m["value"], m["t"])
+    for spans in out.values():
+        for part in spans.values():
+            part.sort()
+    return out
+
+
+def pallas_routes_in_program():
+    """Pallas routes, compiled (``interpret=False``), that the program
+    logged while it was traced; ``None`` where it keeps no route log."""
+    try:
+        from fps_tpu import ops
+        log = ops.routes_traced()
+    except (ImportError, AttributeError):
+        return None
+    return float(sum(1 for r in log
+                     if r.route in ops.PALLAS_ROUTES and not r.interpret))
+
+
+def clear_routes() -> None:
+    try:
+        from fps_tpu import ops
+        ops.clear_routes()
+    except (ImportError, AttributeError):
+        pass
+
+
+# -- readers ---------------------------------------------------------------
+
+def _intervals(ctx, name, part):
+    return (ctx.get("program_spans") or {}).get(name, {}).get(part, [])
+
+
+def _self_seconds(span, others) -> float:
+    """A span's length less the part of it other spans cover (set-up runs
+    on one thread, so what lies inside a span is its child)."""
+    t0, t1 = span
+    covered, edge = 0.0, t0
+    for a, b in sorted(o for o in others
+                       if o != span and o[0] >= t0 and o[1] <= t1):
+        a = max(a, edge)
+        if b > a:
+            covered += b - a
+            edge = b
+    return (t1 - t0) - covered
+
+
+def program_span_total(ctx, p):
+    """Seconds under the named spans in one part of the run: ``spans``
+    summed whole, ``self_spans`` less their children, ``first_of`` only
+    their first occurrence (of the whole run, wherever it fell)."""
+    part = p.get("part", "setup")
+    everything = [iv for spans in (ctx.get("program_spans") or {}).values()
+                  for iv in spans[part]]
+    total, found = 0.0, False
+    for name in p.get("spans", ()):
+        for t0, t1 in _intervals(ctx, name, part):
+            total += t1 - t0
+            found = True
+    for name in p.get("self_spans", ()):
+        for iv in _intervals(ctx, name, part):
+            total += _self_seconds(iv, everything)
+            found = True
+    for name in p.get("first_of", ()):
+        first = sorted(_intervals(ctx, name, "setup")
+                       + _intervals(ctx, name, "window"))[:1]
+        for t0, t1 in first:
+            total += t1 - t0
+            found = True
+    return total * p.get("scale", 1.0) if found else None
+
+
+def program_span_median(ctx, p):
+    """Median length of the named span over one part of the run."""
+    vals = [t1 - t0 for t0, t1 in _intervals(ctx, p["span"],
+                                             p.get("part", "window"))]
+    return statistics.median(vals) * p.get("scale", 1.0) if vals else None
+
+
+READERS = {
+    "program_span_total": program_span_total,
+    "program_span_median": program_span_median,
+}

@@ -79,7 +79,7 @@ def test_pa_stage_one_device_takes_head_prefix_kernels(devices8,
     out = chip_smoke.stage_pa(mesh, sizes)
     assert out["head_prefix_cols"] * 512 >= 2048
     heads = {k[0] for k in out["kernels_traced"] if k[2] == sizes.pa_head}
-    assert heads == {"gather_rows_dim1_pallas", "scatter_add_dim1_pallas"}
+    assert heads == {"gather.dim1_head", "scatter_add.dim1_head"}
 
 
 def test_pa_stage_fails_when_no_kernel_is_traced(devices8):
@@ -101,7 +101,22 @@ def test_script_refuses_cpu_and_names_it():
     assert r.stdout.strip() == ""  # no result line
 
 
-def test_cache_helper_leaves_env_placed_cache_alone(monkeypatch, tmp_path):
+@pytest.fixture
+def cache_key_config():
+    """The two settings that key the cache on the programs' metadata,
+    restored after a test that lets the helper set them."""
+    import jax
+
+    names = ("jax_compilation_cache_include_metadata_in_key",
+             "jax_hlo_source_file_canonicalization_regex")
+    before = {n: getattr(jax.config, n) for n in names}
+    yield names
+    for n, v in before.items():
+        jax.config.update(n, v)
+
+
+def test_cache_helper_leaves_env_placed_cache_alone(monkeypatch, tmp_path,
+                                                    cache_key_config):
     import jax
 
     before = jax.config.jax_compilation_cache_dir
@@ -110,15 +125,38 @@ def test_cache_helper_leaves_env_placed_cache_alone(monkeypatch, tmp_path):
     assert jax.config.jax_compilation_cache_dir == before
 
 
-def test_cache_helper_default_is_fixed_in_checkout(monkeypatch, tmp_path):
+def test_cache_key_holds_scopes_and_not_the_checkouts_path(
+        monkeypatch, tmp_path, cache_key_config):
+    """Wherever the cache is on, its key takes the programs' metadata in
+    (the named scopes a trace is read by live there: a tree with new
+    scopes must not load an older tree's executables), with the
+    checkout's root cut from the source locations."""
+    import re
+
+    import jax
+
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    hostenv.enable_compilation_cache()
+    assert jax.config.jax_compilation_cache_include_metadata_in_key is True
+    cut = jax.config.jax_hlo_source_file_canonicalization_regex
+    here = os.path.join(ROOT, "fps_tpu", "core", "driver.py")
+    assert re.sub(cut, "", here) == os.path.join("fps_tpu", "core",
+                                                 "driver.py")
+    assert re.sub(cut, "", "/elsewhere" + here) == "/elsewhere" + here
+
+
+def test_cache_helper_default_is_fixed_in_checkout(monkeypatch, tmp_path,
+                                                   cache_key_config):
     import jax
 
     monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
     before = jax.config.jax_compilation_cache_dir
     floor = jax.config.jax_persistent_cache_min_compile_time_secs
-    # On the CPU backend (this suite's) the cache stays off.
+    # On the CPU backend (this suite's) the cache stays off, and nothing
+    # about its key is set.
     assert hostenv.enable_compilation_cache() is None
     assert jax.config.jax_compilation_cache_dir == before
+    assert not jax.config.jax_compilation_cache_include_metadata_in_key
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     try:
         first = hostenv.enable_compilation_cache()

@@ -517,6 +517,206 @@ def test_recorder_off_and_on_compile_identically(devices8):
         obs.Recorder(sinks=[obs.MemorySink()]))
 
 
+@pytest.mark.parametrize("program", ["chunk", "indexed", "megastep"])
+def test_default_recorder_off_and_on_compile_identically(devices8, program):
+    """ISSUE 24: the set-up spans and every span with no timer report
+    through the PROCESS-DEFAULT recorder. Installing one must leave each
+    driver's program (lowered text) and what it computes bit-identical —
+    spans and their waits are host-side only."""
+    from fps_tpu import DeviceDataset, DeviceEpochPlan
+    from fps_tpu.models.matrix_factorization import MFConfig, online_mf
+    from fps_tpu.utils.datasets import synthetic_ratings
+
+    mesh = make_ps_mesh(num_shards=2, num_data=1, devices=devices8[:2])
+    data = synthetic_ratings(57, 31, 600, seed=0)
+
+    def run():
+        trainer, _ = online_mf(mesh, MFConfig(num_users=57, num_items=31,
+                                              rank=4),
+                               max_steps_per_call=4)
+        plan = DeviceEpochPlan(DeviceDataset(mesh, data), num_workers=2,
+                               local_batch=16, route_key="user", seed=3)
+        tables, ls = trainer.init_state(jax.random.key(0))
+        key = jax.random.key(1)
+        if program == "chunk":
+            from fps_tpu.core.device_ingest import device_epoch_chunks
+
+            chunk = next(device_epoch_chunks(
+                plan.dataset, num_workers=2, local_batch=16,
+                steps_per_chunk=4, plan=plan))
+            text = trainer.lowered_chunk_text(
+                jax.tree.map(np.asarray, chunk))
+            tables, ls, m = trainer.fit_stream(
+                tables, ls, device_epoch_chunks(
+                    plan.dataset, num_workers=2, local_batch=16,
+                    steps_per_chunk=4, plan=plan), key)
+        elif program == "indexed":
+            from fps_tpu.parallel.mesh import key_to_replicated
+
+            text = trainer._get_indexed_fn(plan, "sync").lower(
+                tables, ls, plan.epoch_args(0), np.int32(0),
+                key_to_replicated(key, mesh)).as_text()
+            tables, ls, m = trainer.run_indexed(tables, ls, plan, key)
+        else:
+            text = trainer.lowered_megastep_text(plan,
+                                                 chunks_per_dispatch=2)
+            tables, ls, m = trainer.run_megastep(
+                tables, ls, plan, key, chunks_per_dispatch=2)
+        return text, jax.tree.map(np.asarray, (tables, ls, m))
+
+    text_off, out_off = run()
+    sink = obs.MemorySink()
+    with obs_events.default_recorder(obs.Recorder(sinks=[sink])):
+        text_on, out_on = run()
+    assert text_off == text_on
+    jax.tree.map(np.testing.assert_array_equal, out_off, out_on)
+    # ... and the recorder did see the run: set-up spans and the call.
+    spans = {e["span"] for e in sink.events("span")}
+    assert {"dataset.place", "dataset.queues", "plan.build", "init_state",
+            "epoch_args", "program_lookup", "enqueue"} <= spans
+    assert spans & {"fit_stream", "run_indexed", "run_megastep"}
+
+
+# ---------------------------------------------------------------------------
+# host_span: the one host span primitive (ISSUE 24).
+# ---------------------------------------------------------------------------
+
+def _spans(sink):
+    return {e["span"]: e for e in sink.events("span")}
+
+
+def test_host_span_parent_call_index_and_self_time():
+    sink = obs.MemorySink()
+    with obs_events.default_recorder(obs.Recorder(sinks=[sink])):
+        for _ in range(2):
+            with obs.host_span("run_indexed", call=True):
+                with obs.host_span("dispatch"):
+                    time.sleep(0.002)
+                    with obs.host_span("enqueue") as span:
+                        span["built"] = True
+                        time.sleep(0.002)
+                with obs.host_span("host_sync"):
+                    time.sleep(0.002)
+    calls = [e for e in sink.events("span") if e["span"] == "run_indexed"]
+    assert calls[1]["call"] == calls[0]["call"] + 1  # the calls are numbered
+    tree = [e for e in sink.events("span") if e["call"] == calls[1]["call"]]
+    by = {e["span"]: e for e in tree}
+    assert set(by) == {"run_indexed", "dispatch", "enqueue", "host_sync"}
+    root = by["run_indexed"]
+    assert root["parent_id"] is None
+    assert by["dispatch"]["parent_id"] == root["span_id"]
+    assert by["host_sync"]["parent_id"] == root["span_id"]
+    assert by["enqueue"]["parent_id"] == by["dispatch"]["span_id"]
+    assert by["enqueue"]["built"] is True
+    # Self time (a span's length less its children's) sums to the root.
+    length = {e["span_id"]: e["t1"] - e["t0"] for e in tree}
+    self_time = dict(length)
+    for e in tree:
+        if e["parent_id"] is not None:
+            self_time[e["parent_id"]] -= length[e["span_id"]]
+    assert all(v >= -1e-9 for v in self_time.values())
+    assert sum(self_time.values()) == pytest.approx(length[root["span_id"]])
+    # Children lie inside their parents on the record's clock.
+    for e in tree:
+        if e["parent_id"] is not None:
+            parent = next(p for p in tree if p["span_id"] == e["parent_id"])
+            assert parent["t0"] <= e["t0"] and e["t1"] <= parent["t1"] + 1e-6
+
+
+def test_host_span_is_inert_with_no_recorder(monkeypatch):
+    """No recorder, no profiler: nothing recorded, nothing registered."""
+    from fps_tpu.obs import timing
+
+    assert obs_events.get_default_recorder() is None
+    registered = []
+    monkeypatch.setattr(timing, "_watching", False)
+    monkeypatch.setattr(jax.monitoring,
+                        "register_event_duration_secs_listener",
+                        registered.append)
+    monkeypatch.setattr(jax.monitoring, "register_event_listener",
+                        registered.append)
+    with obs.host_span("run_indexed", call=True) as span:
+        with obs.host_span("dispatch"):
+            pass
+    assert registered == [] and span == {}
+    assert timing._watching is False
+    # The first span under a recorder registers the compile listeners.
+    with obs_events.default_recorder(obs.Recorder(sinks=[])):
+        with obs.host_span("dispatch"):
+            pass
+    assert len(registered) == 2 and timing._watching is True
+
+
+def test_explicit_phase_timer_is_not_double_counted_by_the_default():
+    mine, default = obs.MemorySink(), obs.MemorySink()
+    timer = obs.PhaseTimer(obs.Recorder(sinks=[mine]))
+    with obs_events.default_recorder(obs.Recorder(sinks=[default])):
+        with timer.phase("dispatch"):          # PhaseTimer.phase IS host_span
+            with obs.host_span("enqueue", timer):
+                pass
+        with obs.host_span("epoch_args"):      # no timer: the default's
+            pass
+    assert set(timer.chunk_summary()) == {"dispatch", "enqueue"}
+    assert {m["labels"]["phase"] for m in mine.metrics(
+        "driver.phase_seconds")} == {"dispatch", "enqueue"}
+    assert set(_spans(mine)) == {"dispatch", "enqueue"}
+    assert {m["labels"]["phase"] for m in default.metrics(
+        "driver.phase_seconds")} == {"epoch_args"}
+    assert set(_spans(default)) == {"epoch_args"}
+
+
+def test_compiles_fold_into_the_default_recorder():
+    """watch_compiles: JAX's own compile timings as compile.* phases, a
+    program_compiled event naming the function."""
+    import jax.numpy as jnp
+
+    sink = obs.MemorySink()
+    rec = obs.Recorder(sinks=[sink])
+    with obs_events.default_recorder(rec):
+        with obs.host_span("dispatch"):
+            jax.jit(lambda x: x * 3 + 1)(jnp.arange(7)).block_until_ready()
+    phases = rec.phase_totals()
+    assert {"compile.trace", "compile.lower", "compile.backend"} <= set(
+        phases)
+    compiled = sink.events("program_compiled")
+    assert compiled and all(e["seconds"] > 0 for e in compiled)
+    assert any("lambda" in e.get("fun_name", "") for e in compiled)
+    # With the recorder gone the listeners are inert.
+    n = len(sink.records)
+    jax.jit(lambda x: x * 5)(jnp.arange(3)).block_until_ready()
+    assert len(sink.records) == n
+
+
+def test_driver_phases_cover_what_the_drivers_emit():
+    """DRIVER_PHASES and its companions name every span in the tree (the
+    list went stale once: ``retier`` was emitted and not declared)."""
+    import ast
+
+    from fps_tpu.obs import timing
+
+    declared = (set(timing.DRIVER_PHASES) | set(timing.NESTED_PHASES)
+                | set(timing.SETUP_PHASES) | set(timing.CALL_SPANS))
+    root = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "fps_tpu")
+    emitted = set()
+    for d, _, files in os.walk(root):
+        for f in files:
+            if not f.endswith(".py"):
+                continue
+            tree = ast.parse(open(os.path.join(d, f)).read())
+            for node in ast.walk(tree):
+                if (isinstance(node, ast.Call)
+                        and getattr(node.func, "id", getattr(
+                            node.func, "attr", "")) in ("_phase", "host_span",
+                                                        "phase")):
+                    for a in node.args:
+                        if isinstance(a, ast.Constant) and isinstance(
+                                a.value, str):
+                            emitted.add(a.value)
+    assert emitted and emitted <= declared, emitted - declared
+    assert declared <= emitted, declared - emitted
+
+
 # ---------------------------------------------------------------------------
 # Registry completeness (ISSUE 12 satellite): every metric name the
 # package emits has a spec — the silently-unregistered-metric class.

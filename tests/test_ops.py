@@ -539,3 +539,109 @@ def test_head_prefix_routing_conditions(pallas_backend):
     assert not ops._route_head_prefix(47_236, 2, 8192, 2048, f32)  # D!=1
     assert not ops._route_head_prefix(47_236, 1, 8192, 0, f32)     # no head
     assert not ops._route_head_prefix(4_096, 1, 8192, 2048, f32)   # H~R
+
+
+# ---------------------------------------------------------------------------
+# The route log: what a program holds, said by the layer that chose it.
+# ---------------------------------------------------------------------------
+
+# PA's step on one chip (perfbench/configs/pa-rcv1.json): 47,236 features,
+# 16,384 documents x 64 slots a step, the first 16 columns of every document
+# guaranteed inside the 2,048-row head.
+_PA = dict(R=47_236, H=2_048, ids=16_384 * 64, prefix=16_384 * 16)
+
+
+def _pa_routes():
+    """Route log of PA's pull and push at the cell's shape, traced only
+    (``eval_shape``: nothing of this size runs on the CPU)."""
+    table = jax.ShapeDtypeStruct((_PA["R"], 1), jnp.float32)
+    ids = jax.ShapeDtypeStruct((_PA["ids"],), jnp.int32)
+    deltas = jax.ShapeDtypeStruct((_PA["ids"], 1), jnp.float32)
+
+    def step(table, ids, deltas):
+        vals = ops.gather_rows(table, ids, hot_rows=_PA["H"],
+                               head_prefix=_PA["prefix"])
+        return ops.scatter_add(table, ids, deltas + vals,
+                               hot_rows=_PA["H"], head_prefix=_PA["prefix"])
+
+    ops.clear_routes()
+    jax.eval_shape(step, table, ids, deltas)
+    return ops.routes_traced()
+
+
+@pytest.mark.parametrize("route,rows,ids", [
+    ("gather.dim1_head", _PA["H"], _PA["prefix"]),
+    ("gather.dim1", _PA["R"], _PA["ids"] - _PA["prefix"]),
+    ("scatter_add.dim1_head", _PA["H"], _PA["prefix"]),
+    ("scatter_add.dim1", _PA["R"], _PA["ids"] - _PA["prefix"]),
+])
+def test_route_log_holds_pa_routes_once_each(pallas_backend, route, rows,
+                                             ids):
+    log = _pa_routes()
+    assert len(log) == 4  # head and tail of each composite, nothing else
+    (entry,) = [r for r in log if r.route == route]
+    assert entry == ops.Route(route.split(".")[0], route, rows, 1, ids,
+                              interpret=True, reason="")
+
+
+@pytest.mark.parametrize("case,route,reason", [
+    ("f64", "scatter_add.xla", "f64"),
+    ("over_budget_hot_rows", "scatter_add.xla", "flop_budget"),
+    ("over_budget_onehot_gather", "gather.xla", "flop_budget"),
+    ("auto_on_cpu", "scatter_add.xla", "backend"),
+    ("exact_read", "gather.xla", ""),
+    ("packed", "scatter_add.packed", ""),
+    ("packed_head", "scatter_add.packed_head", ""),
+    ("onehot", "scatter_add.onehot", ""),
+])
+def test_route_log_says_why_a_pallas_route_was_passed_over(case, route,
+                                                           reason):
+    f32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32)  # noqa: E731
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)  # noqa: E731
+    backend = "auto" if case == "auto_on_cpu" else "pallas"
+    # Fresh lambdas: ``eval_shape`` caches a trace by function and shapes,
+    # and the backend, which decides the route, is not in that key.
+    scatter = lambda t, i, d, **kw: ops.scatter_add(t, i, d, **kw)  # noqa: E731
+    gather = lambda t, i, **kw: ops.gather_rows(t, i, **kw)  # noqa: E731
+    small = (f32(64, 4), i32(32), f32(32, 4))
+    calls = {
+        "f64": lambda: jax.eval_shape(
+            scatter, jax.ShapeDtypeStruct((64, 4), jnp.float64), i32(32),
+            jax.ShapeDtypeStruct((32, 4), jnp.float64)),
+        # 2^20 ids into a 2^20-row head: 2 * 2^20 * 2^20 / pack flops.
+        "over_budget_hot_rows": lambda: jax.eval_shape(
+            lambda t, i, d: scatter(t, i, d, hot_rows=1 << 20),
+            f32(1 << 21, 8), i32(1 << 20), f32(1 << 20, 8)),
+        "over_budget_onehot_gather": lambda: jax.eval_shape(
+            gather, f32(1 << 20, 64), i32(1 << 16)),
+        "auto_on_cpu": lambda: jax.eval_shape(scatter, *small),
+        "exact_read": lambda: jax.eval_shape(
+            lambda t, i: gather(t, i, exact=True),
+            f32(_PA["R"], 1), i32(_PA["ids"])),
+        "packed": lambda: jax.eval_shape(
+            lambda t, i, d: scatter(t, i, d, hot_rows=64), *small),
+        "packed_head": lambda: jax.eval_shape(
+            lambda t, i, d: scatter(t, i, d, hot_rows=16), *small),
+        "onehot": lambda: jax.eval_shape(scatter, *small),
+    }
+    prev, x64 = ops.get_backend(), jax.config.jax_enable_x64
+    ops.set_backend(backend)
+    jax.config.update("jax_enable_x64", case == "f64")
+    try:
+        ops.clear_routes()
+        calls[case]()
+        log = ops.routes_traced()
+    finally:
+        ops.set_backend(prev)
+        jax.config.update("jax_enable_x64", x64)
+    hit = [r for r in log if r.route == route]
+    assert len(hit) == 1 and hit[0].reason == reason, log
+    # A Pallas route off the TPU runs interpreted; an XLA route never does.
+    assert hit[0].interpret == (route in ops.PALLAS_ROUTES)
+    if case == "packed_head":
+        # The split's other half is the masked XLA tail: a route of its
+        # own, passed over for nothing.
+        assert [(r.route, r.reason) for r in log] == [
+            ("scatter_add.packed_head", ""), ("scatter_add.xla", "")]
+    else:
+        assert len(log) == 1

@@ -1,0 +1,136 @@
+"""The names a device trace reads the program by (docs/observability.md).
+
+A compiled step's ops carry the ``jax.named_scope`` path they were traced
+under, and the benchmark's trace reducer selects by it — so the names are
+an interface, pinned here on the lowered text WITH locations
+(``as_text(debug_info=True)``; the default text carries none, which is
+why scopes cannot change a program). Two rules besides the names: a
+route's scope covers only its own call (the head-prefix composite's tail
+opens its scope beside the head's), and ``fps.*`` scopes live in step
+bodies only — device programs that run once a call or once a chunk are
+named without that prefix, because a reader counts steps by the ops
+under ``fps.*``.
+"""
+
+import re
+
+import jax
+import numpy as np
+import pytest
+
+import fps_tpu.ops as ops
+from fps_tpu import DeviceDataset, DeviceEpochPlan
+from fps_tpu.models.matrix_factorization import MFConfig, online_mf
+from fps_tpu.models.passive_aggressive import PAConfig, passive_aggressive
+from fps_tpu.parallel.mesh import key_to_replicated, make_ps_mesh
+
+
+def _scope_paths(lowered) -> set:
+    """Every op's scope path in a lowered program, the primitive cut off
+    (a named location reads ``loc("jit(run)/fps.pull/gather"(#loc7))``; a
+    file location has a colon after its closing quote, not a bracket)."""
+    text = lowered.as_text(debug_info=True)
+    return {name.rsplit("/", 1)[0]
+            for name in re.findall(r'loc\("([^"]+/[^"]*)"\(', text)}
+
+
+@pytest.fixture(scope="module")
+def pa_step_scopes(devices8):
+    """PA's epoch program on one device at the benchmark cell's shape
+    (47,236 features, 16,384 x 64 slots a step, 16 head columns), traced
+    and lowered under the forced ``pallas`` backend; nothing runs."""
+    F, S, B = 47_236, 64, 16_384
+    rng = np.random.default_rng(0)
+    ids = rng.integers(0, F, (B, S)).astype(np.int32)
+    ids[:, :16] = rng.integers(0, 2048, (B, 16))
+    data = {"feat_ids": ids,
+            "feat_vals": rng.random((B, S)).astype(np.float32),
+            "label": rng.choice([-1.0, 1.0], B).astype(np.float32)}
+    mesh = make_ps_mesh(devices=devices8[:1])
+    prev = ops.get_backend()
+    ops.set_backend("pallas")
+    try:
+        trainer, _ = passive_aggressive(
+            mesh, PAConfig(num_features=F, variant="PA-I", C=1.0,
+                           hot_features=2048, head_prefix_cols=16))
+        tables, ls = trainer.init_state(jax.random.key(0))
+        plan = DeviceEpochPlan(DeviceDataset(mesh, data), num_workers=1,
+                               local_batch=B, seed=1)
+        lowered = trainer._get_indexed_fn(plan, "sync").lower(
+            tables, ls, plan.epoch_args(0), np.int32(0),
+            key_to_replicated(jax.random.key(1), mesh))
+    finally:
+        ops.set_backend(prev)
+    return _scope_paths(lowered)
+
+
+@pytest.mark.parametrize("scope", [
+    "fps.ingest", "fps.pull", "fps.compute", "fps.push", "fps.metrics",
+    "fps.pull/fps.ops/gather.dim1_head", "fps.pull/fps.ops/gather.dim1",
+    "fps.push/fps.ops/scatter_add.dim1_head",
+    "fps.push/fps.ops/scatter_add.dim1",
+])
+def test_pa_step_holds_scope(pa_step_scopes, scope):
+    assert any(p == scope or p.startswith(scope + "/")
+               or f"/{scope}/" in p + "/" for p in pa_step_scopes), (
+        sorted(pa_step_scopes))
+
+
+def test_head_prefix_tail_scope_is_beside_the_head_not_under_it(
+        pa_step_scopes):
+    under_head = [p for p in pa_step_scopes
+                  if re.search(r"dim1_head/.*(gather|scatter_add)\.dim1(/|$)",
+                               p)]
+    assert not under_head
+    # ... and fps.ops is entered once per route, not once per composite.
+    assert not [p for p in pa_step_scopes if p.count("fps.ops") > 1]
+
+
+@pytest.fixture(scope="module")
+def mf_plans(devices8):
+    """A packable (all 1-D, 4-byte) MF data set on two workers: the
+    transposed-buffer plan and a ``shuffle="sort"`` one over it."""
+    mesh = make_ps_mesh(num_shards=2, num_data=1, devices=devices8[:2])
+    rng = np.random.default_rng(1)
+    n = 512
+    ds = DeviceDataset(mesh, {
+        "user": rng.integers(0, 64, n).astype(np.int32),
+        "item": rng.integers(0, 32, n).astype(np.int32),
+        "rating": rng.random(n).astype(np.float32)})
+    mk = lambda **kw: DeviceEpochPlan(  # noqa: E731
+        ds, num_workers=2, local_batch=32, route_key="user", seed=3, **kw)
+    return mesh, mk(), mk(shuffle="sort")
+
+
+@pytest.mark.parametrize("program,name", [
+    ("tbuf", "ingest.tbuf"), ("perm", "ingest.perm"),
+    ("chunk", "ingest.chunk"),
+])
+def test_once_a_call_programs_carry_no_fps_scope(mf_plans, program, name):
+    _, plan, sort_plan = mf_plans
+    if program == "tbuf":
+        packed = plan.dataset.packed(plan.route_key, plan.num_workers)[0]
+        lowered = plan._tbuf_jit.lower(
+            packed, np.zeros(plan.num_workers, np.int32))
+    elif program == "perm":
+        lowered = sort_plan._perm_jit.lower(
+            np.zeros(sort_plan._key_data_shape, np.uint32))
+    else:
+        lowered = plan._chunk_builder(4).lower(plan.epoch_args(0),
+                                               np.int32(0))
+    paths = _scope_paths(lowered)
+    assert any(name in p.split("/") for p in paths), sorted(paths)
+    assert not [p for p in paths if "fps." in p]
+
+
+def test_mf_step_ingest_is_scoped_and_tbuf_is_not_in_the_step(mf_plans):
+    mesh, plan, _ = mf_plans
+    trainer, _ = online_mf(mesh, MFConfig(num_users=64, num_items=32,
+                                          rank=4))
+    tables, ls = trainer.init_state(jax.random.key(0))
+    paths = _scope_paths(trainer._get_indexed_fn(plan, "sync").lower(
+        tables, ls, plan.epoch_args(0), np.int32(0),
+        key_to_replicated(jax.random.key(1), mesh)))
+    assert any("fps.ingest" in p.split("/") for p in paths)
+    assert any("fps.ops" in p.split("/") for p in paths)
+    assert not [p for p in paths if "ingest.tbuf" in p]

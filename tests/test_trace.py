@@ -267,8 +267,10 @@ def _synthetic_pod_dir(tmp_path):
          "parent_id": "a0y", "host": "h0", "process": 0},
         {"kind": "event", "t": 112.0, "event": "chunk", "index": 3,
          "run_id": "r1",
-         "phases": {"ingest": 0.1, "dispatch": 0.3, "host_sync": 0.1,
-                    "prefetch": 0.2}},
+         # enqueue lies INSIDE dispatch (obs.timing.NESTED_PHASES): the
+         # serial sum, and with it the chunk span's start, leave it out.
+         "phases": {"ingest": 0.1, "dispatch": 0.3, "enqueue": 0.25,
+                    "host_sync": 0.1, "prefetch": 0.2}},
         {"kind": "event", "t": 112.5, "event": "checkpoint_saved",
          "run_id": "r1", "step": 4, "seconds": 0.2, "bytes": 1024},
         {"kind": "event", "t": 128.0, "event": "run_end", "run_id": "r1"},
@@ -320,6 +322,40 @@ def test_trace_export_builds_one_restart_tree(tmp_path):
 
     # Every span carries the one trace id it inherited.
     assert {s["trace_id"] for s in spans if s["trace_id"]} == {trace}
+
+
+def test_trace_export_renders_the_drivers_host_spans_unchanged(
+        devices8, tmp_path):
+    """ISSUE 24: the drivers' host spans are the canonical span record,
+    so the exporter needs no change to hang a call's phases under the
+    call and the call under the run."""
+    te = _load_trace_export()
+    build, chunks, _ = _mf_harness(devices8)
+    d = str(tmp_path / "obs")
+    rec = obs.open_run(d, config={"w": "mf"})
+    try:
+        trainer, _ = build()
+        trainer.recorder = rec
+        tables, ls = trainer.init_state(jax.random.key(0))
+        trainer.fit_stream(tables, ls, iter(chunks[:2]), jax.random.key(1))
+    finally:
+        rec.close()
+    assert te._NESTED_PHASES == obs.timing.NESTED_PHASES
+    spans = [s for s in te.collect_spans([d]) if s["cat"] == "span"]
+    by_id = {s["span_id"]: s for s in spans}
+    run = next(s for s in te.collect_spans([d]) if s["cat"] == "run")
+    call = next(s for s in spans if s["name"] == "fit_stream")
+    assert call["parent_id"] == run["span_id"]
+    enq = [s for s in spans if s["name"] == "enqueue"]
+    assert len(enq) == 2  # one per chunk
+    for s in enq:
+        parent = by_id[s["parent_id"]]
+        assert parent["name"] == "dispatch"
+        assert by_id[parent["parent_id"]] is call
+        assert s["attrs"]["call"] == call["attrs"]["call"]
+        assert parent["t0"] <= s["t0"] and s["t1"] <= parent["t1"] + 1e-6
+    # Set-up spans (no timer) went to the installed default: same journal.
+    assert any(s["name"] == "init_state" for s in spans)
 
 
 def test_trace_export_chrome_and_cli(tmp_path, capsys):

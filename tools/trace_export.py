@@ -46,6 +46,10 @@ import sys
 _SERIAL_PHASES = ("ingest", "place", "dispatch", "host_sync",
                   "checkpoint", "callback", "reconcile", "retier")
 _OVERLAPPED_PHASES = ("prefetch",)
+# Spans that lie INSIDE another phase (fps_tpu.obs.timing.NESTED_PHASES:
+# enqueue in dispatch/megastep, attach_hot round reconcile): their time is
+# in the enclosing phase already, so the serial sum leaves them out.
+_NESTED_PHASES = ("enqueue", "attach_hot")
 
 # Journal events rendered as zero-duration instants, by source.
 _POD_INSTANTS = (
@@ -272,7 +276,8 @@ def _run_spans(records, host_hint, mint) -> list[dict]:
                          for p in _SERIAL_PHASES)
             serial += sum(float(v) for k, v in phases.items()
                           if k not in _SERIAL_PHASES
-                          and k not in _OVERLAPPED_PHASES)
+                          and k not in _OVERLAPPED_PHASES
+                          and k not in _NESTED_PHASES)
             t0 = t - serial
             parent = run_span and run_span["span_id"]
             sid = mint()
