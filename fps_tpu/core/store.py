@@ -715,6 +715,20 @@ def pull_local(
     return ops.gather_rows(local_shard, ids // num_shards)
 
 
+def push_local(
+    local_shard: Array,
+    ids: Array,
+    deltas: Array,
+    *,
+    num_shards: int,
+) -> Array:
+    """Scatter-add into rows the calling device already owns (no
+    communication): the twin of :func:`pull_local`, for the worker-local
+    table's own updates. Duplicate ids accumulate; ids outside the shard
+    (negative ones too: floor division keeps them negative) are dropped."""
+    return ops.scatter_add(local_shard, ids // num_shards, deltas)
+
+
 def push(
     local_shard: Array,
     ids: Array,

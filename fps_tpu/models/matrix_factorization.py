@@ -37,6 +37,7 @@ from fps_tpu.core.store import (
     TableSpec,
     make_table_values,
     pull_local,
+    push_local,
     ranged_uniform_init,
     rows_per_shard,
 )
@@ -200,7 +201,7 @@ class MatrixFactorizationWorker(WorkerLogic):
             targets = r[:, None]
             wts = w[:, None]
 
-        uidx = u // self.num_workers  # local row (ingest routes u % W == me)
+        # Local row u // W (ingest routes u % W == me), both ways.
         p = pull_local(user_factors, u, num_shards=self.num_workers)
 
         pred = jnp.einsum("bd,bkd->bk", p, q)  # (B, 1+n)
@@ -212,7 +213,8 @@ class MatrixFactorizationWorker(WorkerLogic):
         dq = lr * (err[:, :, None] * p[:, None, :]
                    - cfg.reg * wts[:, :, None] * q)
 
-        user_factors = user_factors.at[uidx].add(dp.astype(cfg.dtype))
+        user_factors = push_local(user_factors, u, dp.astype(cfg.dtype),
+                                  num_shards=self.num_workers)
 
         out = {
             # Quality metrics track the REAL ratings only (column 0), so

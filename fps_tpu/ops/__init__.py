@@ -20,9 +20,13 @@ Backend selection:
 * ``set_backend("auto" | "xla" | "pallas")`` or env ``FPS_TPU_OPS`` at
   import time. Default ``"auto"``.
 * ``"auto"`` — on TPU, XLA everywhere except the hot/cold split (the only
-  Pallas route that beats XLA at realistic duplication on real hardware);
-  off TPU, pure XLA.
-* ``"xla"`` — pure XLA everywhere (debugging / bit-exact baseline).
+  Pallas route that beats XLA at realistic duplication on real hardware)
+  and the scalar-table kernels; a narrow-row table too large for XLA's
+  VMEM regime takes the lane-packed XLA route (``gather.xla_packed`` /
+  ``scatter_add.xla_packed``: :data:`XLA_VMEM_TABLE_BYTES`); off TPU, pure
+  XLA.
+* ``"xla"`` — the PLAIN XLA ops everywhere (debugging / bit-exact baseline;
+  the lane-packed XLA route is off too, so this is its A/B).
 * ``"pallas"`` — force the Pallas kernels (one-hot gather/scatter under
   :data:`SCATTER_FLOP_BUDGET`, plus the hot/cold split); off TPU they run
   in interpreter mode so the CPU-mesh test suite exercises them.
@@ -32,7 +36,9 @@ Names: every branch runs the kernel or XLA call it ends in under
 (``gather.dim1_head``, ``scatter_add.xla``, ...: :data:`ROUTES`), so a
 device trace lays each op's time to the route that chose it; and the
 choice itself is appended to a route log at trace time
-(:func:`routes_traced`), with the reason a Pallas route was passed over.
+(:func:`routes_traced`), with the reason a Pallas route, or the lane-packed
+XLA route, was passed over (``"vmem_fit"``: the plain op already runs with
+its table in VMEM).
 """
 
 from __future__ import annotations
@@ -81,12 +87,13 @@ def packed_crossover_rows(dim: int) -> int:
 # Every route the two ops can take; the scope under ``fps.ops`` and the
 # ``route`` of a log entry are ``<op>.<route>``.
 ROUTES = {
-    "gather": ("dim1_head", "dim1", "onehot", "xla"),
+    "gather": ("dim1_head", "dim1", "onehot", "xla_packed", "xla"),
     "scatter_add": ("dim1_head", "dim1", "packed", "packed_head", "onehot",
-                    "xla"),
+                    "xla_packed", "xla"),
 }
 PALLAS_ROUTES = frozenset(
-    f"{op}.{r}" for op, rs in ROUTES.items() for r in rs if r != "xla")
+    f"{op}.{r}" for op, rs in ROUTES.items() for r in rs
+    if not r.startswith("xla"))
 
 
 class Route(NamedTuple):
@@ -98,9 +105,10 @@ class Route(NamedTuple):
     dim: int
     ids: int         # ids the call moves
     interpret: bool  # the Pallas kernel runs interpreted (off TPU)
-    reason: str      # why a Pallas route was passed over: "" (taken, or
-                     # exact read asked for), "f64", "flop_budget",
-                     # "backend", "shape"
+    reason: str      # why a Pallas route, or the lane-packed XLA one, was
+                     # passed over: "" (taken, or exact read asked for),
+                     # "f64", "flop_budget", "backend", "shape",
+                     # "vmem_fit" (the plain XLA op already runs in VMEM)
 
 
 _ROUTES_TRACED: list[Route] = []
@@ -234,6 +242,64 @@ DIM1_MIN_BATCH = 8_192
 # wrong for embedding-scale ones (w2v 20 MB+), hence the cap.
 DENSE_TABLE_BYTES = 4 << 20
 
+# XLA's own fast regime for row ops on a narrow-row table, and the
+# lane-packed XLA route that puts a table back inside it. XLA's TPU gather
+# and scatter run on a copy of the table in VMEM (memory space 1: ``S(1)``
+# in the compiled layout, indices sorted first) while the table's
+# ROW-MAJOR TILED form fits there. That form pads a row to 128 lanes
+# (512 B in f32) whatever its width, so a rank-10 table of 480,189 rows
+# (19 MB of numbers) counts as 246 MB, falls out, and is left transposed
+# in HBM where every update is a strided read-modify-write. Compile-only,
+# v5e (``tests/test_v5e_compile.py`` guards it), ``t.at[i].add(d)``,
+# f32[R,10], 32,768 ids; the edge lies at 117.2-117.5 MB of tiled bytes
+# for f32 and bf16, D = 10 and 32, 32,768 and 262,144 ids alike:
+#
+#   R                          scatter operand                 ids sorted
+#   120,048; 160,063; 200,000  {1,0:T(8,128)S(1)}: VMEM        yes
+#   240,095                    {1,0:T(8,128)}: row-major, HBM  yes
+#   320,126; 480,189           {0,1:T(8,128)}: transposed, HBM no
+#
+# In time (``tools/bench_scatter.py rows``, one v5 lite chip, B = 32768
+# uniform ids, f32, us a call with the table a loop carry of its plain
+# shape, so the packed route's relayout both ways is in its number;
+# plain / packed; MB = tiled bytes of the plain and of the packed form):
+#
+#   R (plain MB)       D=10 (packed MB) scatter    gather     gather+scatter
+#   17,770 (9.1)       (0.8)            323 / 335  139 / 152  394 / 416
+#   120,048 (61.5)     (5.2)            399 / 345  143 / 162  462 / 440
+#   200,000 (102.4)    (8.6)            675 / 361  256 / 170  672 / 450
+#   240,095 (122.9)    (10.3)           744 / 366  366 / 168  1018 / 458
+#   320,126 (163.9)    (13.7)           1999 / 384 228 / 179  2150 / 468
+#   480,189 (245.9)    (20.5)           2020 / 458 221 / 195  2148 / 556
+#   1,048,576 (536.9)  (44.8)           1997 / 735 226 / 492  2153 / 818
+#
+#   R = 480,189    D=8 (15.4) D=11 (22.4) D=16 (30.7) D=20 (41.0) D=32 (61.5)
+#   scatter        1437 / 371 2000 / 472  2003 / 470  2503 / 572  2313 / 1271
+#   gather         184 / 176  229 / 201   236 / 212   310 / 355   392 / 545
+#   R = 1,048,576  D=8 (33.6) D=11 (48.8) D=16 (67.1) D=20 (89.5) D=32 (134.2)
+#   scatter        1436 / 526 2005 / 743  2004 / 1533 2509 / 1918 4138 / 5324
+#   gather         182 / 217  223 / 502   236 / 1113  314 / 1004  1279 / 1858
+#
+# The plain op pays for the VMEM copy of its tiled table both ways (the
+# rise from 61.5 to 102.4 MB) before it falls out altogether; at 61.5 MB
+# the two routes are level, at 102.4 MB the packed one wins every width
+# (scatter by 31-49 %, gather by 3-37 %). XLA_VMEM_TABLE_BYTES sits at
+# that clear-win edge; (61.5, 102.4) MB is unmeasured. The packed form
+# pays its relayout (a pad, a reshape, one 2-D transpose each way: source
+# and result in VMEM together, so it falls out past ~58 MB, see 61.5 and
+# up) and wins both ops only while small: XLA_PACKED_TABLE_BYTES is the
+# largest packed form whose gather ALONE stays within a fifth of the
+# plain one's (33.6 MB; at 41-49 MB the scatter still wins by 63-77 %
+# but a lone gather runs from 9 % faster to 125 % slower). Fewer ids
+# than XLA_PACKED_MIN_IDS do not pay the relayout (gather+scatter at
+# Netflix's block, plain / packed: 1,024 ids 114 / 169, 4,096 312 / 324,
+# 8,192 572 / 244, 16,384 1102 / 337). Widths outside XLA_PACKED_DIMS
+# were not swept.
+XLA_VMEM_TABLE_BYTES = 96 << 20
+XLA_PACKED_TABLE_BYTES = 32 << 20
+XLA_PACKED_DIMS = (8, 32)
+XLA_PACKED_MIN_IDS = 8_192
+
 
 def _bf16_pair_ok(dtype) -> bool:
     """The dim-1 kernels carry values as bf16 hi+lo: f64 would silently
@@ -248,16 +314,118 @@ def _route_dim1(R: int, D: int, B: int, dtype=jnp.float32) -> bool:
     return R <= DIM1_MAX_ROWS and B >= DIM1_MIN_BATCH
 
 
-def _xla_reason(dtype) -> str:
-    """Why a call that reached the XLA route took no Pallas one, when no
+def _tiled_table_bytes(rows: int, dtype) -> int:
+    """Bytes of ``rows`` rows in XLA's row-major tiled form: a row of any
+    width up to 128 is one 128-lane row."""
+    return rows * 128 * jnp.dtype(dtype).itemsize
+
+
+def _xla_packed_rows(R: int, D: int) -> int:
+    """Rows of the lane-packed form of an ``[R, D]`` table: ``128 // D``
+    table rows a packed row, rounded up to whole 128-row tiles (the packed
+    form is taken from the transposed one, where rows run along lanes)."""
+    return -(-R // (128 // D * 128)) * 128
+
+
+def _xla_packable(D: int, dtype) -> bool:
+    """A swept width, and a float of at most 4 bytes (a row is 128 lanes)."""
+    return (XLA_PACKED_DIMS[0] <= D <= XLA_PACKED_DIMS[1]
+            and _bf16_pair_ok(dtype))
+
+
+def _route_xla_packed(R: int, D: int, B: int, dtype) -> bool:
+    """Take the lane-packed XLA route? From shapes alone: on the TPU
+    (backend not ``"xla"``), a float table of a swept width whose plain
+    tiled form is past XLA's VMEM regime (:data:`XLA_VMEM_TABLE_BYTES`)
+    while its packed form is well inside
+    (:data:`XLA_PACKED_TABLE_BYTES`), moving enough ids to pay for the
+    relayout."""
+    use, interpret = _use_pallas()
+    if not use or interpret or not _xla_packable(D, dtype):
+        return False
+    return (B >= XLA_PACKED_MIN_IDS
+            and _tiled_table_bytes(R, dtype) > XLA_VMEM_TABLE_BYTES
+            and _tiled_table_bytes(_xla_packed_rows(R, D), dtype)
+            <= XLA_PACKED_TABLE_BYTES)
+
+
+def _xla_reason(R: int, D: int, dtype) -> str:
+    """Why a call that reached the plain XLA route took no other, when no
     budget decided it: the dtype cannot ride the kernels' f32 / bf16-pair
-    arithmetic, the backend keeps kernels out, or no kernel serves the
-    shape under this backend."""
+    arithmetic, the backend keeps every other route out, the table is
+    already inside XLA's VMEM regime (so the lane-packed route has nothing
+    to add), or no route serves the shape under this backend."""
     if jnp.dtype(dtype).itemsize > 4:
         return "f64"
-    if not _use_pallas()[0]:
+    use, interpret = _use_pallas()
+    if not use:
         return "backend"
+    if (not interpret and _xla_packable(D, dtype)
+            and _tiled_table_bytes(R, dtype) <= XLA_VMEM_TABLE_BYTES):
+        return "vmem_fit"
     return "shape"
+
+
+def _xla_pack(table: Array) -> Array:
+    """``[R, D]`` -> lane-packed ``[Rp, D * pack]``: table row ``i`` lies in
+    packed row ``i % Rp``, lanes ``d * pack + i // Rp``. Taken from the
+    TRANSPOSED table, which is how XLA keeps a narrow table of this size
+    in HBM (``{0,1:T(8,128)}``, entry parameter and loop carry alike): a
+    lane pad, a reshape that splits lanes at a multiple of 128 and one 2-D
+    transpose of the packed bytes, all of which XLA runs in VMEM. Packing
+    consecutive rows instead (``table.reshape(R // pack, pack * D)``) makes
+    XLA carry the table row-major, 128 lanes a row, and relayout that each
+    way each step (246 MB at Netflix's user block, compile-only)."""
+    R, D = table.shape
+    pack, Rp = 128 // D, _xla_packed_rows(R, D)
+    tT = jnp.pad(table.T, ((0, 0), (0, pack * Rp - R)))
+    return tT.reshape(D * pack, Rp).T
+
+
+def _xla_unpack(packed: Array, R: int, D: int) -> Array:
+    return packed.T.reshape(D, -1)[:, :R].T
+
+
+def _xla_packed_slots(R: int, D: int, ids: Array):
+    """Where each id lies in the packed form: ``in_range [B]``, its packed
+    ``row [B]`` (0 where out of range) and the one-hot ``sel [B, pack]`` of
+    its lane group (all False where out of range)."""
+    pack, Rp = 128 // D, _xla_packed_rows(R, D)
+    in_range = (ids >= 0) & (ids < R)
+    safe = jnp.where(in_range, ids, 0)
+    sel = ((jnp.arange(pack, dtype=safe.dtype)[None, :]
+            == (safe // Rp)[:, None]) & in_range[:, None])
+    return in_range, safe % Rp, sel
+
+
+def _xla_packed_gather(table: Array, ids: Array) -> Array:
+    """``gather.xla_packed``: one XLA gather of whole packed rows, then the
+    id's own lanes picked by its one-hot. Exact: a row's ``D`` numbers and
+    zeros are summed (a ``-0.0`` reads ``+0.0``)."""
+    R, D = table.shape
+    with _routed("gather", "xla_packed", R, D, ids.shape[0]):
+        _, row, sel = _xla_packed_slots(R, D, ids)
+        rows = jnp.take(_xla_pack(table), row, axis=0)
+        rows = rows.reshape(ids.shape[0], D, 128 // D)
+        return jnp.sum(jnp.where(sel[:, None, :], rows, 0), axis=2)
+
+
+def _xla_packed_scatter_add(table: Array, ids: Array,
+                            deltas: Array) -> Array:
+    """``scatter_add.xla_packed``: each update widened to its packed row
+    (zero outside the id's own lanes: adding ``+0.0`` to the neighbours is
+    exact), one XLA scatter-add into the packed table, unpacked. XLA sorts
+    ``(packed row, position)``, so one id's duplicates add in the batch's
+    order, as on the plain route."""
+    R, D = table.shape
+    with _routed("scatter_add", "xla_packed", R, D, ids.shape[0]):
+        in_range, row, sel = _xla_packed_slots(R, D, ids)
+        Rp = _xla_packed_rows(R, D)
+        upd = jnp.where(sel[:, None, :],
+                        deltas.astype(table.dtype)[:, :, None], 0)
+        packed = _xla_pack(table).at[jnp.where(in_range, row, Rp)].add(
+            upd.reshape(ids.shape[0], -1), mode="drop")
+        return _xla_unpack(packed, R, D)
 
 
 def _route_head_prefix(R: int, D: int, head_prefix: int, hot_rows: int,
@@ -323,6 +491,8 @@ def gather_rows(table: Array, ids: Array, *, hot_rows: int = 0,
 
         with _routed("gather", "dim1", R, D, ids.shape[0]):
             return gather_rows_dim1_pallas(table, ids, interpret=interpret)
+    if _route_xla_packed(R, D, ids.shape[0], table.dtype):
+        return _xla_packed_gather(table, ids)
     # Forced-pallas only: XLA's gather is not collision-serialized, and
     # dedup-safe on-chip measurement shows it matching or beating the
     # one-hot kernel at the shipped workloads' shapes, so "auto" never
@@ -334,7 +504,7 @@ def gather_rows(table: Array, ids: Array, *, hot_rows: int = 0,
         with _routed("gather", "onehot", R, D, ids.shape[0]):
             return gather_rows_pallas(table, ids, interpret=interpret)
     reason = ("" if exact else "flop_budget" if onehot
-              else _xla_reason(table.dtype))
+              else _xla_reason(R, D, table.dtype))
     with _routed("gather", "xla", R, D, ids.shape[0], reason):
         in_range = (ids >= 0) & (ids < R)
         vals = jnp.take(table, jnp.where(in_range, ids, 0), axis=0)
@@ -347,10 +517,15 @@ def _xla_scatter_add(table: Array, ids: Array, deltas: Array,
     the ``scatter_add.xla`` route, taken for ``reason``."""
     R, D = table.shape
     with _routed("scatter_add", "xla", R, D, ids.shape[0], reason):
+        # Dropped by the sentinel row ALONE. Masking the deltas as well is
+        # redundant, and a select on a worker's local deltas made XLA lay
+        # their producer out row-major, which put the push's all-gather of
+        # the SIBLING deltas into 128-lane rows in HBM (mf-netflix.x4:
+        # 77.5 M -> 50.4 M examples/s, chip run, PR 25;
+        # tests/test_v5e_compile.py guards the layout).
         keep = (ids >= 0) & (ids < R)
         safe = jnp.where(keep, ids, R)
-        masked = jnp.where(keep[:, None], deltas, 0)
-        return table.at[safe].add(masked.astype(table.dtype), mode="drop")
+        return table.at[safe].add(deltas.astype(table.dtype), mode="drop")
 
 
 def scatter_add(
@@ -446,6 +621,9 @@ def scatter_add(
         with _scope("scatter_add.packed_head"):
             return table.at[:hot_rows].add(head_upd)
 
+    if _route_xla_packed(R, D, ids.shape[0], table.dtype):
+        return _xla_packed_scatter_add(table, ids, deltas)
+
     onehot = _BACKEND == "pallas" and use
     if onehot and R * ids.shape[0] * max(D, 1) <= SCATTER_FLOP_BUDGET:
         from fps_tpu.ops.pallas_kernels import scatter_add_pallas
@@ -455,4 +633,4 @@ def scatter_add(
                                       interpret=interpret)
     return _xla_scatter_add(
         table, ids, deltas,
-        "flop_budget" if onehot else _xla_reason(table.dtype))
+        "flop_budget" if onehot else _xla_reason(R, D, table.dtype))

@@ -81,3 +81,51 @@ def test_mf_sync_deterministic(devices8):
     r2 = run_mf(mesh, epochs=1)
     assert r1[0] == r2[0]
     assert r1[1] == r2[1]
+
+
+@pytest.mark.parametrize("num_workers,negatives", [(1, 0), (4, 0), (4, 2)])
+def test_worker_step_through_push_local_equals_at_add(monkeypatch,
+                                                      num_workers,
+                                                      negatives):
+    """The worker's local scatter-add runs through the routing layer
+    (``store.push_local`` -> ``ops.scatter_add``): bit for bit the
+    ``.at[u // W].add`` it replaced, and one ``scatter_add.*`` route in the
+    log beside the pull's ``gather.*``."""
+    import jax.numpy as jnp
+
+    import fps_tpu.models.matrix_factorization as mfm
+    import fps_tpu.ops as ops
+
+    cfg = MFConfig(num_users=NU, num_items=NI, rank=RANK,
+                   negative_samples=negatives)
+    worker = mfm.MatrixFactorizationWorker(cfg, num_workers)
+    rng = np.random.default_rng(5)
+    B, rows = 256, -(-NU // num_workers)
+    batch = {
+        # This worker's users (u % W == 0), many of them more than once.
+        "user": jnp.asarray(rng.integers(0, rows, B) * num_workers,
+                            jnp.int32),
+        "item": jnp.asarray(rng.integers(0, NI, B), jnp.int32),
+        "rating": jnp.asarray(rng.normal(0, 1, B), jnp.float32),
+        "weight": jnp.asarray(rng.random(B) < 0.9, jnp.float32),
+    }
+    batch = worker.prepare(batch, jax.random.key(2))
+    pulled = {mfm.ITEM_TABLE: jnp.asarray(
+        rng.normal(0, 0.1, (B * (1 + negatives), RANK)), jnp.float32)}
+    local = jnp.asarray(rng.normal(0, 0.1, (rows, RANK)), jnp.float32)
+
+    ops.clear_routes()
+    got = worker.step(batch, pulled, local, jax.random.key(3))
+    routes = [r.route for r in ops.routes_traced()]
+    assert routes == ["gather.xla", "scatter_add.xla"]
+
+    monkeypatch.setattr(
+        mfm, "push_local",
+        lambda t, ids, d, *, num_shards: t.at[ids // num_shards].add(d))
+    want = worker.step(batch, pulled, local, jax.random.key(3))
+    np.testing.assert_array_equal(np.asarray(got.local_state),
+                                  np.asarray(want.local_state))
+    assert not np.array_equal(np.asarray(got.local_state), np.asarray(local))
+    for a, b in zip(jax.tree.leaves((got.pushes, got.out)),
+                    jax.tree.leaves((want.pushes, want.out))):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

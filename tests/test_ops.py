@@ -645,3 +645,133 @@ def test_route_log_says_why_a_pallas_route_was_passed_over(case, route,
             ("scatter_add.packed_head", ""), ("scatter_add.xla", "")]
     else:
         assert len(log) == 1
+
+
+# --- The lane-packed XLA route (gather.xla_packed / scatter_add.xla_packed).
+
+def _packed_case(R, D, dtype, B=2048, seed=0):
+    """Table, ids, deltas: duplicates of one id, two ids sharing a packed
+    row (``i`` and ``i + Rp``), ``-1`` and ids at and past ``R``."""
+    rng = np.random.default_rng(seed + R + D)
+    ids = rng.integers(0, R, B)
+    ids[:64] = ids[64:128]            # one id more than once
+    Rp = ops._xla_packed_rows(R, D)
+    assert Rp < R                     # so packed rows are shared
+    ids[200:264] = (ids[264:328] + Rp) % R   # mostly: same packed row
+    ids[300], ids[301], ids[302], ids[303] = -1, R, R + Rp, -R
+    table = jnp.asarray(rng.normal(0, 1, (R, D)), dtype)
+    deltas = jnp.asarray(rng.normal(0, 1, (B, D)), dtype)
+    return table, jnp.asarray(ids, jnp.int32), deltas
+
+
+_PACKED_SHAPES = [(D, R) for D in (8, 10, 11, 16, 20, 32)
+                  for R in (128 // D * 128 * 3,      # whole packed rows
+                            128 // D * 128 * 2 + 77)]  # a ragged last group
+
+
+def _bits(x):
+    return np.asarray(x.astype(jnp.float32))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("D,R", _PACKED_SHAPES)
+def test_xla_packed_scatter_add_equals_plain_bit_for_bit(D, R, dtype):
+    table, ids, deltas = _packed_case(R, D, dtype)
+    keep = (ids >= 0) & (ids < R)
+    want = table.at[jnp.where(keep, ids, R)].add(deltas, mode="drop")
+    got = jax.jit(ops._xla_packed_scatter_add)(table, ids, deltas)
+    assert got.dtype == want.dtype and got.shape == want.shape
+    np.testing.assert_array_equal(_bits(got), _bits(want))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("D,R", _PACKED_SHAPES)
+def test_xla_packed_gather_equals_take_bit_for_bit(D, R, dtype):
+    table, ids, _ = _packed_case(R, D, dtype)
+    keep = np.asarray((ids >= 0) & (ids < R))
+    want = np.where(keep[:, None],
+                    _bits(table)[np.where(keep, np.asarray(ids), 0)], 0)
+    got = jax.jit(ops._xla_packed_gather)(table, ids)
+    assert got.dtype == table.dtype
+    np.testing.assert_array_equal(_bits(got), want)
+
+
+@pytest.fixture
+def as_on_tpu(monkeypatch):
+    """Routing as a TPU process decides it (kernels compiled, never
+    interpreted; ``"xla"`` keeps every other route out): what the
+    predicates read, with no chip."""
+    monkeypatch.setattr(ops, "_use_pallas",
+                        lambda: (ops.get_backend() != "xla", False))
+
+
+_NETFLIX = (480_189, 10, 32_768)
+
+
+@pytest.mark.parametrize("R,D,B,dtype,backend,taken,reason", [
+    (*_NETFLIX, "float32", "auto", True, ""),
+    (*_NETFLIX, "bfloat16", "auto", True, ""),       # 123 MB tiled: out too
+    (200_000, 10, 32_768, "float32", "auto", True, ""),   # 102.4 MB tiled
+    (320_126, 32, 32_768, "float32", "auto", False, "shape"),  # packed 41 MB
+    (1_000_000, 20, 32_768, "float32", "auto", False, "shape"),  # 89.5 MB
+    (120_048, 10, 32_768, "float32", "auto", False, "vmem_fit"),   # x4
+    (17_770, 11, 32_768, "float32", "auto", False, "vmem_fit"),
+    (47_236, 1, 4_096, "float32", "auto", False, "shape"),
+    (1_000_000, 1, 32_768, "float32", "auto", False, "shape"),
+    (200_000, 100, 32_768, "float32", "auto", False, "shape"),  # pack 1
+    (480_189, 48, 32_768, "float32", "auto", False, "shape"),   # not swept
+    (8_000_000, 10, 32_768, "float32", "auto", False, "shape"),  # packed out
+    (*_NETFLIX[:2], 1_024, "float32", "auto", False, "shape"),  # too few ids
+    (*_NETFLIX, "float64", "auto", False, "f64"),
+    (*_NETFLIX, "float32", "xla", False, "backend"),
+])
+def test_xla_packed_predicate_over_shapes(as_on_tpu, R, D, B, dtype, backend,
+                                          taken, reason):
+    prev = ops.get_backend()
+    ops.set_backend(backend)
+    try:
+        assert ops._route_xla_packed(R, D, B, dtype) == taken
+        if not taken:
+            assert ops._xla_reason(R, D, dtype) == reason
+    finally:
+        ops.set_backend(prev)
+
+
+def test_xla_packed_is_never_taken_off_the_tpu():
+    assert not ops._route_xla_packed(*_NETFLIX, jnp.float32)
+    assert ops._xla_reason(*_NETFLIX[:2], jnp.float32) == "backend"
+
+
+@pytest.mark.parametrize("op", ["gather", "scatter_add"])
+def test_route_log_holds_the_xla_packed_route(as_on_tpu, op):
+    R, D, B = _NETFLIX
+    f32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32)  # noqa: E731
+    ids = jax.ShapeDtypeStruct((B,), jnp.int32)
+    ops.clear_routes()
+    if op == "gather":
+        out = jax.eval_shape(lambda t, i: ops.gather_rows(t, i),
+                             f32(R, D), ids)
+        assert out.shape == (B, D)
+    else:
+        out = jax.eval_shape(lambda t, i, d: ops.scatter_add(t, i, d),
+                             f32(R, D), ids, f32(B, D))
+        assert out.shape == (R, D)
+    assert ops.routes_traced() == [
+        ops.Route(op, f"{op}.xla_packed", R, D, B, interpret=False,
+                  reason="")]
+    # XLA routes both: no kernel, and a name of their own beside ``xla``.
+    assert f"{op}.xla_packed" in {f"{op}.{r}" for r in ops.ROUTES[op]}
+    assert f"{op}.xla_packed" not in ops.PALLAS_ROUTES
+    assert f"{op}.xla" not in ops.PALLAS_ROUTES
+
+
+def test_route_log_says_vmem_fit_where_the_plain_op_already_fits(as_on_tpu):
+    f32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32)  # noqa: E731
+    ops.clear_routes()
+    jax.eval_shape(lambda t, i, d: ops.scatter_add(t, ops.gather_rows(
+        t, i)[:, 0].astype(jnp.int32), d), f32(120_048, 10),
+        jax.ShapeDtypeStruct((32_768,), jnp.int32), f32(32_768, 10))
+    assert [(r.route, r.reason) for r in ops.routes_traced()] == [
+        ("gather.xla", "vmem_fit"), ("scatter_add.xla", "vmem_fit")]

@@ -1,0 +1,177 @@
+"""Compile-only guards of the layouts the ops routing stands on (v5e).
+
+The TPU's compiler is installed wherever the suite runs and compiles for a
+chip that is DESCRIBED, not attached: nothing runs, no time is taken. What
+is read is the compiled program's text — which memory space XLA puts a
+table in (``S(1)`` is VMEM) and whether it sorts a scatter's indices —
+the facts ``fps_tpu.ops.XLA_VMEM_TABLE_BYTES`` and the lane-packed XLA
+route were set from. A later compiler that moves the edge fails here, at
+no chip time. The topology is described inside a fixture of THIS file
+(one process may load the TPU's library; see the on-chip-measurement
+guide), and every test of it lives here.
+"""
+
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax import lax
+
+import fps_tpu.ops as ops
+
+NETFLIX = (480_189, 10, 32_768)  # user block rows, rank, ratings a step
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — whatever keeps libtpu out
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # A compile for a described chip is written to the persistent cache
+    # and cannot be read back without one: keep the cache out of it.
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", prev)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compiled(fn, one_chip, *shapes):
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+    return jax.jit(fn).lower(*args).compile()
+
+
+def _row_op_outputs(text, prim):
+    """Result types of the compiled row ops traced from ``prim``
+    (``gather`` / ``scatter-add``): XLA's custom fusions."""
+    return [m.group(1) for line in text.splitlines()
+            if f'/{prim}"' in line and "kind=kCustom" in line
+            and (m := re.search(r"= (\S+) fusion\(", line))]
+
+
+def _plain_scatter(R, D, B, one_chip):
+    c = _compiled(lambda t, i, d: t.at[i].add(d, mode="drop"), one_chip,
+                  ((R, D), jnp.float32), ((B,), jnp.int32),
+                  ((B, D), jnp.float32))
+    return c.as_text()
+
+
+@pytest.mark.parametrize("R", [120_048, 200_000])
+def test_plain_scatter_runs_in_vmem_up_to_the_edge(one_chip, R):
+    """x4's user block (61.5 MB row-major tiled: passed over, ``vmem_fit``)
+    and 200,000 rows x 512 B (102.4 MB: the largest measured table XLA
+    still copies into VMEM, where ``XLA_VMEM_TABLE_BYTES`` already hands
+    over to the packed form) ride VMEM with sorted indices."""
+    D, B = NETFLIX[1:]
+    fits = ops._tiled_table_bytes(R, jnp.float32) <= ops.XLA_VMEM_TABLE_BYTES
+    assert fits == (R == 120_048)
+    text = _plain_scatter(R, D, B, one_chip)
+    assert f"f32[{R},{D}]{{1,0:T(8,128)S(1)}}" in _row_op_outputs(
+        text, "scatter-add")
+    assert "indices_are_sorted=true" in text
+
+
+@pytest.mark.parametrize("R", [240_095, NETFLIX[0]])
+def test_plain_scatter_is_out_of_vmem_past_the_edge(one_chip, R):
+    """The upper side: 240,095 rows (122.9 MB) and Netflix's user block
+    are scattered into in HBM — the regime the packed route leaves."""
+    D, B = NETFLIX[1:]
+    assert ops._tiled_table_bytes(R, jnp.float32) > ops.XLA_VMEM_TABLE_BYTES
+    outs = _row_op_outputs(_plain_scatter(R, D, B, one_chip), "scatter-add")
+    assert outs and not any("S(1)" in o for o in outs), outs
+
+
+def test_packed_route_scatters_netflix_block_in_vmem_sorted(one_chip):
+    """The route's own scatter at Netflix's shape: operand
+    ``f32[40064,120]`` row-major in memory space 1, indices sorted; so is
+    its gather's table."""
+    R, D, B = NETFLIX
+    Rp, lanes = ops._xla_packed_rows(R, D), 128 // D * D
+    assert (Rp, lanes) == (40_064, 120)
+    assert (ops._tiled_table_bytes(Rp, jnp.float32)
+            <= ops.XLA_PACKED_TABLE_BYTES)
+    text = _compiled(ops._xla_packed_scatter_add, one_chip,
+                     ((R, D), jnp.float32), ((B,), jnp.int32),
+                     ((B, D), jnp.float32)).as_text()
+    assert f"f32[{Rp},{lanes}]{{1,0:T(8,128)S(1)}}" in _row_op_outputs(
+        text, "scatter-add")
+    assert "indices_are_sorted=true" in text
+    text = _compiled(ops._xla_packed_gather, one_chip,
+                     ((R, D), jnp.float32), ((B,), jnp.int32)).as_text()
+    assert re.search(
+        rf"f32\[{Rp},{lanes}\]\{{1,0:T\(8,128\)S\(1\)\}}", text)
+
+
+def test_packed_route_keeps_the_loop_carry_compact(one_chip):
+    """A worker step's gather then scatter-add in a loop whose carry is
+    the PLAIN ``[R, D]`` table: XLA keeps the carry transposed (30.7 MB,
+    not the 246 MB row-major form), relayouts in VMEM, and needs no
+    table-sized temporary. Packing consecutive rows fails exactly this."""
+    R, D, B = NETFLIX
+
+    def steps(t, ids, deltas):
+        def body(t, x):
+            i, d = x
+            return ops._xla_packed_scatter_add(
+                t, i, d + ops._xla_packed_gather(t, i)), None
+        return lax.scan(body, t, (ids, deltas))[0]
+
+    c = _compiled(steps, one_chip, ((R, D), jnp.float32),
+                  ((4, B), jnp.int32), ((4, B, D), jnp.float32))
+    (loop,) = [ln for ln in c.as_text().splitlines() if " while(" in ln]
+    assert re.search(rf"f32\[{R},{D}\]\{{0,1:T\(8,128\)", loop), loop
+    assert c.memory_analysis().temp_size_in_bytes < 8 << 20
+
+
+def test_x4_push_all_gathers_its_deltas_compact(topo):
+    """``mf-netflix.x4``'s step program (the trainer's own chunk builder
+    on the described 1x4 mesh): the push's all-gather of a step's
+    ``[131072, 10]`` deltas stays in the compact transposed layout in
+    VMEM. A select on the worker's LOCAL deltas (its scatter-add's
+    neighbour in one fusion) once turned it row-major in HBM, 128 lanes a
+    row, and cost the cell a third of its rate; no CPU test can see that."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from fps_tpu.models.matrix_factorization import MFConfig, online_mf
+    from fps_tpu.parallel.mesh import make_ps_mesh
+
+    W, B, T, rank = 4, NETFLIX[2], 2, NETFLIX[1]
+    mesh = make_ps_mesh(num_shards=W, devices=list(topo.devices)[:W])
+    trainer, _ = online_mf(
+        mesh, MFConfig(num_users=NETFLIX[0], num_items=17_770, rank=rank),
+        combine="mean")
+
+    def shape(s, dtype, spec):
+        return jax.ShapeDtypeStruct(s, dtype,
+                                    sharding=NamedSharding(mesh, spec))
+
+    workers = P(None, ("data", "shard"))
+    tables = {"item_factors": shape((-(-17_770 // W) * W, rank),
+                                    jnp.float32, P("shard", None))}
+    local = shape((-(-NETFLIX[0] // W) * W, rank), jnp.float32,
+                  P(("data", "shard")))
+    batches = {k: shape((T, W * B), d, workers) for k, d in (
+        ("user", jnp.int32), ("item", jnp.int32), ("rating", jnp.float32),
+        ("weight", jnp.float32))}
+    key = shape((), jax.random.key(0).dtype, P())
+    text = trainer._build_chunk_fn("sync").lower(
+        tables, local, batches, key).compile().as_text()
+    gathered = re.findall(rf"= (f32\[{W * B},{rank}\]\S+) all-gather\(", text)
+    compact = f"f32[{W * B},{rank}]{{0,1:T(8,128)S(1)}}"
+    assert gathered and all(g.startswith(compact) for g in gathered), gathered

@@ -2,11 +2,21 @@
 
 Each timed call runs a scan of T iterations whose table carry chains, so no
 dispatch dedup; timing is fenced by a host read. Reports us per scatter.
+
+``rows`` arm: the plain XLA gather / scatter-add against the lane-packed XLA
+route (``fps_tpu.ops``: ``gather.xla_packed`` / ``scatter_add.xla_packed``)
+over table rows x row width at uniform ids — the sweep that set
+``ops.XLA_VMEM_TABLE_BYTES`` / ``XLA_PACKED_DIMS`` / ``XLA_PACKED_MIN_IDS``.
 """
 
+import os
+import sys
 import time
 
-import jax
+# `python tools/bench_scatter.py` puts tools/ (not the repo root) on sys.path.
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+import jax  # noqa: E402
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
@@ -158,6 +168,93 @@ def small_r_sweep():
             run(f"sweep D={D}", R, D, B)
 
 
+ROWS_R = (17_770, 120_048, 200_000, 240_095, 320_126, 480_189, 1_048_576)
+ROWS_D = (8, 10, 11, 16, 20, 32)
+ROWS_T = 64
+
+
+def rows_point(R, D, B, ops_wanted=("scatter", "gather")):
+    """us a call of the plain XLA ops and of the lane-packed XLA route on
+    an f32 ``[R, D]`` table, ``B`` uniform ids, the table a loop carry of
+    its plain shape (so the packed route's relayout is counted, both
+    ways, every iteration). ``scatter`` chains on the table; ``gather``
+    chains on an accumulator with one table element rewritten each
+    iteration (nothing hoisted out of the loop); ``pair`` is a worker
+    step's gather then scatter-add of the same ids (the route packs the
+    table once for both)."""
+    import fps_tpu.ops as ops
+
+    rng = np.random.default_rng(R * 131 + D)
+    tab = jnp.asarray(rng.normal(0, 0.1, (R, D)), jnp.float32)
+    ids = jnp.asarray(rng.integers(0, R, (ROWS_T, B)), jnp.int32)
+    deltas = jnp.asarray(rng.normal(0, 1e-4, (ROWS_T, B, D)), jnp.float32)
+
+    def plain_gather(t, i):
+        return jnp.take(t, i, axis=0)
+
+    routes = {"plain": (plain_gather, xla_scatter),
+              "packed": (ops._xla_packed_gather, ops._xla_packed_scatter_add)}
+
+    def program(op, gather, scatter):
+        def body(carry, x):
+            t, acc = carry
+            i, d = x
+            if op == "scatter":
+                return (scatter(t, i, d), acc), None
+            if op == "gather":
+                t = lax.dynamic_update_slice(t, acc[:1, :1], (0, 0))
+                return (t, acc + gather(t, i)), None
+            return (scatter(t, i, d + 1e-6 * gather(t, i)), acc), None
+
+        @jax.jit
+        def f(t, ids, deltas):
+            return lax.scan(body, (t, jnp.zeros((B, D), jnp.float32)),
+                            (ids, deltas))[0]
+        return f
+
+    out = {"rows": R, "dim": D, "ids": B}
+    for op in ops_wanted:
+        for name, (g, sc) in routes.items():
+            f = program(op, g, sc)
+            r = f(tab, ids, deltas)
+            np.asarray(r[1]).ravel()[0]
+            best = 1e9
+            for _ in range(2):
+                t0 = time.perf_counter()
+                r = f(tab, ids, deltas)
+                np.asarray(r[1]).ravel()[0], np.asarray(r[0][0, 0])
+                best = min(best, time.perf_counter() - t0)
+            out[f"{op}_{name}_us"] = round(best / ROWS_T * 1e6, 1)
+    return out
+
+
+def rows_sweep(args):
+    """``rows``: the whole grid at B = 32768 (pair at D = 10 only), then
+    the fewest ids at which the route pays at Netflix's user block.
+    ``rows quick``: Netflix's user block alone. One JSON line a point on
+    stdout, all of them in ``chiprun_out/bench_scatter_rows.jsonl``."""
+    import json
+
+    B = 32768
+    if args == ["quick"]:
+        points = [(480_189, 10, B, ("scatter", "gather", "pair"))]
+    else:
+        points = [(R, D, B, ("scatter", "gather", "pair") if D == 10
+                   else ("scatter", "gather"))
+                  for D in ROWS_D for R in ROWS_R]
+        points += [(480_189, 10, b, ("pair",))
+                   for b in (1024, 4096, 8192, 16384)]
+    os.makedirs("chiprun_out", exist_ok=True)
+    with open("chiprun_out/bench_scatter_rows.jsonl", "a") as fh:
+        fh.write(json.dumps({"device": jax.devices()[0].device_kind,
+                             "platform": jax.default_backend()}) + "\n")
+        for R, D, b, wanted in points:
+            line = json.dumps(rows_point(R, D, b, wanted))
+            print(line, flush=True)
+            fh.write(line + "\n")
+            fh.flush()
+
+
 if __name__ == "__main__":
     import sys
 
@@ -167,9 +264,13 @@ if __name__ == "__main__":
         small_r_sweep()
     elif sys.argv[1:] == ["dim1"]:
         dim1_shapes()
+    elif sys.argv[1] == "rows":
+        rows_sweep(sys.argv[2:])
     else:
         raise SystemExit(
             f"unknown args {sys.argv[1:]!r} — usage: bench_scatter.py "
-            "[sweep|dim1]  (no args = full workload-shape bench; 'sweep' "
-            "= small-R crossover sweep; 'dim1' = scalar-table PA shape)"
+            "[sweep|dim1|rows [quick]]  (no args = full workload-shape "
+            "bench; 'sweep' = small-R crossover sweep; 'dim1' = "
+            "scalar-table PA shape; 'rows' = plain XLA against the "
+            "lane-packed XLA route over table rows x row width)"
         )
