@@ -33,13 +33,13 @@ def readings(loaded: dict, seed: int, control: bool):
     import jax.numpy as jnp
     import numpy as np
 
-    from perfbench.lib import check, datagen, runner, systems, window
+    from perfbench.lib import check, resolve, runner, window
 
     cfg, traffic = loaded["config"], loaded["traffic"]
-    data, data_sum = datagen.KINDS[cfg["data"]["kind"]](seed, cfg["data"])
-    system = systems.KINDS[cfg["model"]["kind"]](cfg, traffic, data, seed)
+    data, data_sum = resolve.generator(cfg)(seed, cfg["data"])
+    system = resolve.system_class(cfg, traffic)(cfg, traffic, data, seed)
     del data
-    init = check.load_reference(cfg).init_tables(seed, cfg)
+    init = resolve.reference(cfg).init_tables(seed, cfg)
     state, warm = window.queue_call(system, system.place(init))
     warm.wait()
     program = system.export(*state)

@@ -27,16 +27,11 @@ float32. It must come out as not correct (tests/, PERF.md).
 
 from __future__ import annotations
 
-import importlib
-
 import numpy as np
 
+from perfbench.lib import resolve
+
 STEPS_PER_CHUNK = 64
-
-
-def load_reference(cfg: dict):
-    return importlib.import_module(
-        f"perfbench.lib.reference.{cfg['reference']}")
 
 
 def row_checksum(batch: dict, names):
@@ -70,7 +65,7 @@ def run_reference(system, cfg: dict, init: dict, call_index: int = 0,
     import jax
     import jax.numpy as jnp
 
-    ref = load_reference(cfg)
+    ref = resolve.reference(cfg)
     dtype = dtype or jnp.float32
     step = ref.make_step(cfg, dtype=dtype, workers=system.W)
     names = sorted(system.plan.dataset.column_names())

@@ -5,23 +5,21 @@ set-up spans, the driver's phases, JAX's compile timings) and logs every
 ops route it takes (``fps_tpu.ops.routes_traced``). With a process-default
 ``Recorder`` installed those land in its sink; this module turns the sink
 into the ``program_spans`` of a run's context — name -> the spans' ``(t0,
-t1)`` in epoch seconds, set-up and window apart — and holds the two kinds
-of reader over it. A program without the spans (a parent commit) leaves
-the sink empty: every reader then returns ``None`` and the metric is left
-out of the line; nothing here raises for want of something to read.
+t1)`` in epoch seconds, set-up, window and what came after it apart — and
+holds the readers over it. A program without the spans (a parent commit)
+leaves the sink empty: every reader then returns ``None`` and the metric is
+left out of the line; nothing here raises for want of something to read.
 
-NOT WIRED YET: ``runner.py`` installs no recorder and ``readers.READERS``
-does not hold :data:`READERS`, because a PR that is not a ``benchmark`` PR
-may edit no file the benchmark has (PERF.md, Open questions, lists the
-four additions to ``runner.py`` and the one to ``readers.py``). Until
-then ``tests/test_program_spans.py`` drives this module the way the
-runner will, and the six metric files that name these readers
-(``setup.*``, ``driver.epoch_args_ms``, ``driver.enqueue_ms``,
-``ops.pallas_routes_in_program``) are in no ``per_layer`` entry.
+The runner does this on a traced run only (``runner.run_cell``): the
+recorder goes in before the data is made, the route log is cleared before
+and read after the warm-up call (what a program holds is what was logged
+while IT was traced), and the sink is collected once the trace is read.
+``readers.READERS`` takes in :data:`READERS`.
 """
 
 from __future__ import annotations
 
+import re
 import statistics
 import time
 
@@ -30,17 +28,16 @@ COMPILE_PHASES = ("compile.trace", "compile.lower", "compile.backend")
 
 def install_recorder():
     """A memory-only recorder as the process default, before the system is
-    built (traced runs only). Returns ``(recorder, sink)``, or ``(None,
-    None)`` where the program has no recorder to install."""
+    built (traced runs only). Returns its sink, or ``None`` where the
+    program has no recorder to install."""
     try:
         from fps_tpu import obs
         from fps_tpu.obs import events
     except ImportError:
-        return None, None
+        return None
     sink = obs.MemorySink(capacity=1 << 16)
-    recorder = obs.Recorder(sinks=[sink])
-    events.set_default_recorder(recorder)
-    return recorder, sink
+    events.set_default_recorder(obs.Recorder(sinks=[sink]))
+    return sink
 
 
 def epoch_of(perf_counter_s: float) -> float:
@@ -48,18 +45,22 @@ def epoch_of(perf_counter_s: float) -> float:
     return time.time() - (time.perf_counter() - perf_counter_s)
 
 
-def collect(sink, opened_at: float) -> dict:
-    """``{name: {"setup": [(t0, t1), ...], "window": [...]}}`` from a
-    recorder's sink: every ``span`` event, and JAX's compile timings (which
-    the program records as ``driver.phase_seconds{phase="compile.*"}``
-    samples: a duration ending at the sample's time). A span that ended
-    before ``opened_at`` (epoch seconds: the window's opening) is set-up."""
+def collect(sink, opened_at: float, closed_at: float) -> dict:
+    """``{name: {"setup": [(t0, t1), ...], "window": [...], "after":
+    [...]}}`` from a recorder's sink: every ``span`` event, and JAX's
+    compile timings (which the program records as
+    ``driver.phase_seconds{phase="compile.*"}`` samples: a duration ending
+    at the sample's time). A span that ended before ``opened_at`` (epoch
+    seconds: the window's opening) is set-up; one that began after
+    ``closed_at`` (the profiled calls, the reference's replay) is in no
+    reading of the window."""
     out: dict = {}
 
     def put(name, t0, t1):
-        part = "setup" if t1 <= opened_at else "window"
-        out.setdefault(name, {"setup": [], "window": []})[part].append(
-            (float(t0), float(t1)))
+        part = ("setup" if t1 <= opened_at
+                else "after" if t0 >= closed_at else "window")
+        out.setdefault(name, {"setup": [], "window": [], "after": []})[
+            part].append((float(t0), float(t1)))
 
     if sink is None:
         return out
@@ -75,16 +76,32 @@ def collect(sink, opened_at: float) -> dict:
     return out
 
 
-def pallas_routes_in_program():
-    """Pallas routes, compiled (``interpret=False``), that the program
-    logged while it was traced; ``None`` where it keeps no route log."""
+def totals(spans: dict, part: str) -> dict:
+    """``{name: [count, seconds]}`` of one part of :func:`collect`'s
+    output, for the runner's event line."""
+    return {name: [len(parts[part]), sum(b - a for a, b in parts[part])]
+            for name, parts in sorted(spans.items()) if parts[part]}
+
+
+def routes_traced():
+    """The program's route log since :func:`clear_routes`, one dict an
+    entry (the fields of ``fps_tpu.ops.Route`` and ``pallas``: whether the
+    route is a Pallas kernel's); ``None`` where it keeps no route log."""
     try:
         from fps_tpu import ops
         log = ops.routes_traced()
     except (ImportError, AttributeError):
         return None
-    return float(sum(1 for r in log
-                     if r.route in ops.PALLAS_ROUTES and not r.interpret))
+    return [dict(r._asdict(), pallas=r.route in ops.PALLAS_ROUTES)
+            for r in log]
+
+
+def pallas_routes_in_program(routes):
+    """Pallas routes, compiled (``interpret=False``), among ``routes``."""
+    if routes is None:
+        return None
+    return float(sum(1 for r in routes
+                     if r["pallas"] and not r["interpret"]))
 
 
 def clear_routes() -> None:
@@ -147,7 +164,17 @@ def program_span_median(ctx, p):
     return statistics.median(vals) * p.get("scale", 1.0) if vals else None
 
 
+def routes_logged(ctx, p):
+    """Entries of the route log whose route matches ``route_regex``."""
+    routes = ctx.get("routes")
+    if routes is None:
+        return None
+    pat = re.compile(p["route_regex"])
+    return float(sum(1 for r in routes if pat.search(r["route"])))
+
+
 READERS = {
     "program_span_total": program_span_total,
     "program_span_median": program_span_median,
+    "routes_logged": routes_logged,
 }

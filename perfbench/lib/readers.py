@@ -10,8 +10,10 @@ source needs a function here (README.md).
 
 Context (``ctx``): ``ops`` (the reduced trace, or None), ``spans`` (name ->
 list of host-clock seconds, taken by the runner), ``counters`` (name ->
-number, counted by the runner), ``config`` (the configuration file),
-``workers`` and ``peaks`` (this device's row of peaks.json).
+number, counted by the runner), ``program_spans`` and ``routes`` (the
+program's own host spans and route log: ``program_spans.py``, which holds
+their readers), ``config`` (the configuration file), ``workers`` and
+``peaks`` (this device's row of peaks.json).
 """
 
 from __future__ import annotations
@@ -19,6 +21,7 @@ from __future__ import annotations
 import re
 import statistics
 
+from perfbench.lib import program_spans
 from perfbench.lib import trace_reduce as tr
 
 
@@ -99,6 +102,7 @@ READERS = {
     "rowop_ns_per_row": rowop_ns_per_row,
     "rowop_roofline_percent": rowop_roofline_percent,
     "device_idle_percent": device_idle_percent,
+    **program_spans.READERS,
 }
 
 

@@ -26,11 +26,19 @@ import jax.numpy as jnp
 
 
 def init_tables(seed: int, cfg: dict) -> dict:
-    """Seeded initial factors in LOGICAL id order, uniform in the
-    configuration's range; float32. The benchmark hands the same arrays to
-    the program and to this reference."""
+    """Initial factors in LOGICAL id order, uniform in the configuration's
+    range; float32. The benchmark hands the same arrays to the program and
+    to this reference. They are drawn from the CONFIGURATION's
+    ``init_salt``, not from ``seed``: how long SGD takes to leave the small
+    start depends on how the random start happens to lie to the planted
+    factors, a handful of numbers that do not average out, so a start
+    drawn from the seed moves the examples to the quality target by several
+    percent from seed to seed (configuration file, ``assumed``). Like the
+    planted structure, the start is one fixed member of the population; the
+    seed draws the ratings, the noise and the shuffles."""
+    del seed
     m = cfg["model"]
-    ku, kv = jax.random.split(jax.random.key(seed & 0xFFFFFFFF))
+    ku, kv = jax.random.split(jax.random.key(m["init_salt"] & 0xFFFFFFFF))
     lo, hi = m["init_min"], m["init_max"]
     return {
         "user_factors": jax.random.uniform(

@@ -17,7 +17,7 @@ import time
 
 import numpy as np
 
-from perfbench.lib import check, datagen, readers, systems, window
+from perfbench.lib import check, program_spans, readers, resolve, window
 from perfbench.lib import trace_reduce as tr
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
@@ -117,18 +117,22 @@ def run_cell(loaded: dict, *, seed: int, seconds: float, trace: bool,
     lowerings = CompileCounter()
     t_devices = time.perf_counter() - t_start
 
+    # A traced run takes the program's own host spans (a memory-only
+    # recorder, installed before anything of the program is built) and its
+    # route log; an untraced run installs and reads nothing.
+    spans_sink = program_spans.install_recorder() if trace else None
+
     # -- set-up: data, system, seeded state, warm-up ---------------------
     t0 = time.perf_counter()
-    data, data_sum = datagen.KINDS[cfg["data"]["kind"]](seed, cfg["data"])
+    data, data_sum = resolve.generator(cfg)(seed, cfg["data"])
     jax.block_until_ready(data)
     t_data = time.perf_counter() - t0
     t0 = time.perf_counter()
-    system = systems.KINDS[cfg["model"]["kind"]](cfg, traffic, data, seed)
+    system = resolve.system_class(cfg, traffic)(cfg, traffic, data, seed)
     del data
     t_build = time.perf_counter() - t0
     t0 = time.perf_counter()
-    ref = check.load_reference(cfg)
-    init = ref.init_tables(seed, cfg)
+    init = resolve.reference(cfg).init_tables(seed, cfg)
     jax.block_until_ready(init)
     t_init = time.perf_counter() - t0
     t0 = time.perf_counter()
@@ -138,8 +142,11 @@ def run_cell(loaded: dict, *, seed: int, seconds: float, trace: bool,
 
     kernels = []
     t0 = time.perf_counter()
+    if trace:
+        program_spans.clear_routes()
     with count_pallas_kernels(kernels):
         state, warm = window.queue_call(system, state)
+    routes = program_spans.routes_traced() if trace else None
     # The state the warm-up call leaves is what the reference is compared
     # with; the next call donates it, so keep a copy on the device.
     after_warm = jax.tree.map(lambda x: x.copy(), state)
@@ -212,6 +219,9 @@ def run_cell(loaded: dict, *, seed: int, seconds: float, trace: bool,
     within, rows = check.judge(numbers, limits)
     for row in rows:
         emit("compared", **row)
+    # Last in the result's line: every number compared beside its limit.
+    compared = {r["number"]: {"value": r["value"], "limit": r["limit"]}
+                for r in rows}
     emit("reference", seconds=time.perf_counter() - t0,
          steps=len(ref_loss), name=cfg["reference"])
 
@@ -231,6 +241,7 @@ def run_cell(loaded: dict, *, seed: int, seconds: float, trace: bool,
             m["name"]: {"value": values[m["name"]], "unit": m["unit"]}
             for m in loaded["end_to_end"] if m["name"] in values}
         result["device"] = device
+        result["compared"] = compared
         return result
 
     # -- traced run: per-layer metrics from the trace, spans and counters --
@@ -238,16 +249,24 @@ def run_cell(loaded: dict, *, seed: int, seconds: float, trace: bool,
         trace_dir, "**", "*.trace.json.gz"), recursive=True))
     if not paths:
         raise RuntimeError(f"the profiler left no trace under {trace_dir}")
-    ops = tr.load_trace(paths[-1])
+    # An idle gap is named by the shortest host span covering its middle:
+    # the program's own (fps.host.*) where one does, else the runner's.
+    ops = tr.load_trace(paths[-1], host_prefix=("bench.", "fps.host."))
     busy_s, window_s, _ = tr.busy_and_window(ops)
     ctx = {
         "ops": ops,
         "spans": {"bench.dispatch": [c.dispatch_s for c in done]},
+        "program_spans": program_spans.collect(
+            spans_sink, program_spans.epoch_of(t_open),
+            program_spans.epoch_of(done[-1].done_at)),
+        "routes": routes,
         "counters": {
             "median_call_examples_per_s": statistics.median(rates),
             "examples_to_target": to_target,
             "pallas_kernels_in_program": float(
                 sum(1 for _, interp in kernels if not interp)),
+            "pallas_routes_in_program":
+                program_spans.pallas_routes_in_program(routes),
             "peak_hbm_gb": peak_bytes / 1e9,
         },
         "config": cfg, "workers": system.W,
@@ -255,9 +274,13 @@ def run_cell(loaded: dict, *, seed: int, seconds: float, trace: bool,
     }
     emit("kernels_traced", kernels=sorted(set(kernels)),
          steps_traced=tr.steps_traced(ops), trace_file=paths[-1])
+    emit("program", routes=routes, spans={
+        part: program_spans.totals(ctx["program_spans"], part)
+        for part in ("setup", "window")})
     result["metrics"] = readers.read_all(loaded["readers"], ctx)
     result["device"] = dict(device, busy_s=busy_s, window_s=window_s)
     result["breakdown"] = tr.breakdown(ops)
+    result["compared"] = compared
     if not (busy_s > 0 and math.isfinite(busy_s)):
         raise RuntimeError("no operation ran on the device in the trace")
     return result

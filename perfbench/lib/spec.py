@@ -4,12 +4,16 @@ The runner finds everything by name:
 
 * ``configs[].file``            one configuration (perfbench/configs/),
 * ``perfbench/traffic/<traffic>.json``   one traffic mix,
-* ``perfbench/metrics/<metric>.json``    one per-layer metric's reader.
+* ``perfbench/metrics/<metric>.json``    one per-layer metric's reader,
+* what a configuration or a traffic mix names in turn (its kind of model,
+  of data, its reference, its entry): ``resolve.py``.
 
 :func:`validate` is the check the runner makes as it starts: names and
 units hold only the characters the contract allows, every per-layer
-metric moves an end-to-end metric that each of its cells reports, every
-configuration has a cell and a reference, every file named exists.
+metric moves an end-to-end metric that each of its cells reports and
+names a reader there is, every configuration has a cell, every file named
+exists, and every configuration's kinds and reference and every cell's
+entry resolve.
 """
 
 from __future__ import annotations
@@ -18,16 +22,14 @@ import json
 import os
 import re
 
-HERE = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+from perfbench.lib import readers, resolve
+from perfbench.lib.resolve import HERE, SpecError  # noqa: F401 (callers')
+
 ROOT = os.path.dirname(HERE)
 
 _NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 _UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 _SOURCES = ("device_trace", "program_span", "program_counter", "host_clock")
-
-
-class SpecError(ValueError):
-    pass
 
 
 def _load(path: str) -> dict:
@@ -115,10 +117,11 @@ def validate(bench: dict, root: str = ROOT) -> None:
             raise SpecError(f"config {c['name']}: no file {c['file']}")
         body = _load(path)
         name_ok(body.get("name"), f"{c['file']} name")
-        ref = os.path.join(HERE, "lib", "reference",
-                           str(body.get("reference")) + ".py")
-        if not os.path.exists(ref):
-            raise SpecError(f"config {c['name']}: no plain reference {ref}")
+        resolve.generator(body)
+        resolve.reference(body)
+        for w in cells.values():
+            if w["config"] == c["name"]:
+                resolve.system_class(body, load_traffic(w["traffic"]))
         if not body.get("limits"):
             raise SpecError(f"config {c['name']}: no limits for the "
                             "comparison with its reference")
@@ -158,6 +161,9 @@ def validate(bench: dict, root: str = ROOT) -> None:
         name_ok(reader.get("name"), f"{path} name")
         if reader["name"] != m["name"] or reader.get("unit") != m["unit"]:
             raise SpecError(f"{path}: name/unit differ from BENCHMARK.json")
+        if reader.get("reader") not in readers.READERS:
+            raise SpecError(f"{path}: no reader {reader.get('reader')!r} in "
+                            f"lib/readers.py; it has {sorted(readers.READERS)}")
     for w in cells:
         if not any(w in e2e[m] for m in e2e if m != "setup_s"):
             raise SpecError(f"{w} reports no end-to-end metric but setup_s")

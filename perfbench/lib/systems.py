@@ -1,21 +1,22 @@
 """The system under test, as the benchmark drives it.
 
-One small adapter per ``model.kind`` of a configuration file. It builds
-the program's own objects through the API a user calls (``make_ps_mesh``
--> model factory -> ``DeviceDataset`` -> ``DeviceEpochPlan`` ->
-``Trainer.run_indexed``), places seeded initial tables that the
-BENCHMARK made (so the reference can start from the same ones without
-taking anything from the program), and reads tables back in logical id
-order. Nothing here computes a metric.
-
-A configuration of a kind listed in :data:`KINDS` is data only; a new
-kind of model needs an adapter here (README.md).
+:class:`System` is the driving surface every model kind's adapter
+(``perfbench/models/<kind>.py``, found by ``resolve.py``) stands on. An
+adapter builds the program's own objects through the API a user calls
+(``make_ps_mesh`` -> model factory -> ``DeviceDataset`` ->
+``DeviceEpochPlan``), places seeded initial tables that the BENCHMARK
+made (so the reference can start from the same ones without taking
+anything from the program), and reads tables back in logical id order.
+The base drives ``Trainer.run_indexed``; an adapter of another entry
+(``perfbench/entries/<kind>/<entry>.py``) overrides ``call`` and, where
+the batches it feeds are other ones, ``fed_chunks``. Nothing here
+computes a metric.
 """
 
 from __future__ import annotations
 
 
-def _to_physical(logical, num_shards: int, like):
+def to_physical(logical, num_shards: int, like):
     """Logical ``(ids, dim)`` rows -> the program's owner-major table,
     placed like ``like`` (an array of the program's own making, for its
     shape and sharding). Layout helpers are the program's."""
@@ -38,7 +39,8 @@ class System:
     """Common driving surface; subclasses build ``trainer``, ``store``,
     ``plan`` and say how tables map to the reference's names."""
 
-    loss_key = "loss"
+    entry = "run_indexed"   # the entry of the program that ``call`` drives
+    loss_key = "loss"       # the per-step metric the reference's loss mirrors
 
     def __init__(self, cfg: dict, traffic: dict, data: dict, seed: int):
         import jax
@@ -115,70 +117,3 @@ class System:
                     start_epoch=e):
                 yield chunk, min(steps_per_chunk, T - done)
                 done += steps_per_chunk
-
-
-class OnlineMF(System):
-    loss_key = "se"
-
-    def build(self, data, dataset):
-        from fps_tpu.models.matrix_factorization import MFConfig, online_mf
-
-        m = self.cfg["model"]
-        self.trainer, self.store = online_mf(
-            self.mesh,
-            MFConfig(num_users=m["num_users"], num_items=m["num_items"],
-                     rank=m["rank"], learning_rate=m["learning_rate"],
-                     reg=m["reg"], init_min=m["init_min"],
-                     init_max=m["init_max"]),
-            combine=m["combine"])
-        self.plan = self._plan(dataset, m["local_batch"], m["route_key"])
-
-    def place(self, init):
-        tables, local_state = self._shells()
-        tables = dict(tables, item_factors=_to_physical(
-            init["item_factors"], self.store.num_shards,
-            tables["item_factors"]))
-        local_state = _to_physical(init["user_factors"], self.W, local_state)
-        return tables, local_state
-
-    def export(self, tables, local_state):
-        self.store.tables = dict(tables)
-        return {
-            "item_factors": self.store.dump_model("item_factors")[1],
-            "user_factors": self.trainer.logic.export_local_state(
-                local_state),
-        }
-
-
-class PassiveAggressive(System):
-    loss_key = "loss"
-
-    def build(self, data, dataset):
-        from fps_tpu.models.passive_aggressive import (
-            PAConfig, passive_aggressive,
-        )
-
-        m = self.cfg["model"]
-        # Head-prefix routing is specified on one device only (bench.py
-        # run_pa); wider meshes take the dense collective route.
-        q = m["head_prefix_cols"] if self.mesh.devices.size == 1 else 0
-        self.trainer, self.store = passive_aggressive(
-            self.mesh,
-            PAConfig(num_features=m["num_features"], variant=m["variant"],
-                     C=m["C"], hot_features=m["head_features"] if q else 0,
-                     head_prefix_cols=q),
-            max_steps_per_call=m.get("max_steps_per_call"))
-        self.plan = self._plan(dataset, m["local_batch"], None)
-
-    def place(self, init):
-        tables, local_state = self._shells()
-        tables = dict(tables, weights=_to_physical(
-            init["weights"], self.store.num_shards, tables["weights"]))
-        return tables, local_state
-
-    def export(self, tables, local_state):
-        self.store.tables = dict(tables)
-        return {"weights": self.store.dump_model("weights")[1][:, 0]}
-
-
-KINDS = {"online_mf": OnlineMF, "passive_aggressive": PassiveAggressive}

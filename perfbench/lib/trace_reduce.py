@@ -41,9 +41,10 @@ class Op:
         return self.start + self.dur
 
 
-def load_trace(path: str, host_prefix: str = "bench.") -> list:
+def load_trace(path: str, host_prefix: str | tuple = "bench.") -> list:
     """Device ops of every TPU plane and the host events whose name starts
-    with ``host_prefix``, from a ``*.trace.json.gz``."""
+    with ``host_prefix`` (one prefix or a tuple of them), from a
+    ``*.trace.json.gz``."""
     with gzip.open(path, "rt") as f:
         events = json.load(f)["traceEvents"]
     planes, lines = {}, {}
@@ -171,7 +172,7 @@ def stable_name(op: Op) -> str:
 def breakdown(ops, top: int = 10) -> dict:
     """The device ops that took most time, under stable names, and the
     longest idle gaps of device 0 by what the host was doing (the
-    ``bench.*`` annotation covering the gap's middle)."""
+    shortest host span loaded that covers the gap's middle)."""
     n = device_count(ops)
     by_name = {}
     for d in range(n):

@@ -8,7 +8,9 @@ cell's data from ``--seed`` on the device, builds the system through the
 API a user calls, warms up, measures for ``--seconds``, compares the first
 call with the configuration's plain reference, and prints one JSON object
 as its LAST line: ``correct``, ``attempted``, ``failed``, ``metrics``,
-``device`` (and ``breakdown`` when traced). Everything else worth keeping
+``device`` (and ``breakdown`` when traced), then ``compared``: every
+number compared beside its limit, which are also the last lines on
+standard error. Everything else worth keeping
 — every reading, every number compared beside its limit, the counters —
 goes on earlier lines, each one JSON object with an ``"event"`` key.
 
@@ -59,9 +61,11 @@ def main(argv=None) -> int:
               f"{e}", file=sys.stderr)
         return 1
 
+    t_imported = time.perf_counter() - _T_START
     import jax
 
     devs = jax.devices()
+    t_devices = time.perf_counter() - _T_START
     chips = loaded["cell"]["chips"]
     if devs[0].platform != "tpu" or len(devs) != chips:
         print(f"perfbench: cell {args.workload} needs {chips} TPU chip(s); "
@@ -77,7 +81,8 @@ def main(argv=None) -> int:
     # code; every program of a cell must be cached all the same.
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     emit("start", workload=args.workload, seed=args.seed,
-         seconds=args.seconds, trace=args.trace, compile_cache=cache_dir)
+         seconds=args.seconds, trace=args.trace, compile_cache=cache_dir,
+         imported_s=t_imported, devices_s=t_devices)
 
     from perfbench.lib.runner import run_cell
 
@@ -85,6 +90,10 @@ def main(argv=None) -> int:
                       trace=bool(args.trace), t_start=_T_START, emit=emit,
                       out_dir=os.path.join(ROOT, "perfbench_out"))
     print(json.dumps(result), flush=True)
+    for name, c in result["compared"].items():
+        print(f"compared {name}: {c['value']!r} limit {c['limit']!r}",
+              file=sys.stderr)
+    sys.stderr.flush()
     return 0
 
 

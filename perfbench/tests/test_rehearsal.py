@@ -16,7 +16,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from perfbench.lib import check, datagen, runner, spec, systems, window
+from perfbench.lib import check, resolve, runner, spec, window
 
 TINY = {
     "mf-netflix": {
@@ -85,10 +85,14 @@ def test_validate_accepts_the_committed_files():
 @pytest.mark.parametrize("workload,n", CELLS)
 def test_cell_runs_and_agrees_with_its_reference(workload, n):
     loaded, result, events = run(workload, n)
-    assert set(result) == {"correct", "attempted", "failed", "metrics",
-                           "device"}
+    assert list(result) == ["correct", "attempted", "failed", "metrics",
+                            "device", "compared"]
     compared = [e for e in events if e["event"] == "compared"]
     assert result["correct"], compared
+    # Last in the line: every number compared beside its limit.
+    assert result["compared"] == {e["number"]: {"value": e["value"],
+                                                "limit": e["limit"]}
+                                  for e in compared}
     assert result["failed"] == 0 and result["attempted"] >= 2
     want = {m["name"] for m in loaded["end_to_end"]}
     assert set(result["metrics"]) == want
@@ -113,10 +117,10 @@ def test_bf16_control_fails_the_comparison(workload, n):
     loaded = tiny_cell(workload)
     cfg = loaded["config"]
     with mesh_devices(n):
-        data, _ = datagen.KINDS[cfg["data"]["kind"]](5, cfg["data"])
-        system = systems.KINDS[cfg["model"]["kind"]](
+        data, _ = resolve.generator(cfg)(5, cfg["data"])
+        system = resolve.system_class(cfg, loaded["traffic"])(
             cfg, loaded["traffic"], data, 5)
-        init = check.load_reference(cfg).init_tables(5, cfg)
+        init = resolve.reference(cfg).init_tables(5, cfg)
         ref, loss, n_ref, feed = check.run_reference(system, cfg, init)
         low, low_loss, low_n, _ = check.run_reference(
             system, cfg, init, dtype=jnp.bfloat16)
@@ -162,16 +166,18 @@ def _drop_part_of_the_batch(system):
 def test_broken_timed_path_is_not_correct(workload, n, break_system,
                                           monkeypatch):
     """The rest of a run with the timed path broken underneath: the
-    system's adapter is swapped for one that breaks what it built."""
-    kind = tiny_cell(workload)["config"]["model"]["kind"]
-    real = systems.KINDS[kind]
+    adapter the resolver finds is swapped for one that breaks what it
+    built."""
+    real = resolve.system_class
 
-    def broken_kind(*a, **kw):
-        system = real(*a, **kw)
-        break_system(system)
-        return system
+    def broken_class(cfg, traffic):
+        def build(*a, **kw):
+            system = real(cfg, traffic)(*a, **kw)
+            break_system(system)
+            return system
+        return build
 
-    monkeypatch.setitem(systems.KINDS, kind, broken_kind)
+    monkeypatch.setattr(resolve, "system_class", broken_class)
     _, result, events = run(workload, n)
     assert result["correct"] is False, [
         e for e in events if e["event"] == "compared"]
@@ -274,7 +280,7 @@ def test_route_groups_hold_their_stated_shares_for_every_seed():
     d = tiny_cell("mf-netflix.x4")["config"]["data"]
     counts = []
     for seed in (3, 2147483659):
-        data, _ = datagen.mf_ratings(seed, d)
+        data, _ = resolve.load("data", "mf_ratings").generate(seed, d)
         assert data["user"].min() >= 0
         assert data["user"].max() < d["num_users"]
         counts.append(np.bincount(data["user"] % 4, minlength=4))
