@@ -177,6 +177,10 @@ class DeviceDataset:
     def column_names(self):
         return list(self.columns)
 
+    def host_column(self, name: str) -> np.ndarray:
+        """The host copy of a column (what the constructor was given)."""
+        return self._host_data[name]
+
 
 class DeviceEpochPlan:
     """Epoch traversal geometry over a :class:`DeviceDataset`.

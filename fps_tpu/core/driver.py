@@ -1031,7 +1031,10 @@ class Trainer:
         track = track or {}
         compact = compact or {}
         key, prep_key = jax.random.split(key)
-        batch = self.logic.prepare(batch, prep_key)
+        # fps.prepare: the worker's own sampling before the pull (SGNS
+        # draws its negatives here), beside fps.pull, not under it.
+        with jax.named_scope("fps.prepare"):
+            batch = self.logic.prepare(batch, prep_key)
         ids = self.logic.pull_ids(batch)
         hp = self._head_prefix(batch)
         if track:
@@ -1184,7 +1187,8 @@ class Trainer:
 
         def probe(batch, local_state, key):
             key, prep_key = jax.random.split(key)
-            b = self.logic.prepare(batch, prep_key)
+            with jax.named_scope("fps.prepare"):
+                b = self.logic.prepare(batch, prep_key)
             ids = self.logic.pull_ids(b)
             pulled = {
                 name: jnp.zeros(

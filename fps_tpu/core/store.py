@@ -699,6 +699,14 @@ def pull(
     return lax.psum_scatter(vals, shard_axis, scatter_dimension=0, tiled=True)
 
 
+# Device scope of a non-"sum" combine's TABLE-SIZED work in :func:`push`:
+# the making of the (rows, dim + 1) accumulator, the normalisation and the
+# add to the shard (max / min: their raw scatter too). The routed
+# scatter-add between them keeps its own ``fps.ops/...`` scope BESIDE this
+# one, so no op counts under both (docs/observability.md).
+COMBINE_SCOPE = "fps.combine"
+
+
 def pull_local(
     local_shard: Array,
     ids: Array,
@@ -864,23 +872,24 @@ def push(
         # Sentinel beyond any representable delta IN THE ACCUMULATOR dtype —
         # a hard-coded f32-range constant would silently clamp f64 deltas of
         # magnitude > 3e38 to the sentinel.
-        lim = jnp.finfo(acc_dt).max
-        fill = jnp.asarray(-lim if combine == "max" else lim, acc_dt)
-        ind = jnp.where(owned, 1.0, fill)[:, None]
-        filled = jnp.where(
-            owned[:, None],
-            jnp.concatenate(
-                [gathered_deltas.astype(acc_dt), ind], axis=1
-            ),
-            fill,
-        )
-        target = jnp.full((rps, dim + 1), fill, acc_dt)
-        if combine == "max":
-            ext = target.at[local_idx].max(filled, mode="drop")
-        else:
-            ext = target.at[local_idx].min(filled, mode="drop")
-        counts = (jnp.abs(ext[:, dim]) <= 1.0).astype(acc_dt)
-        combined = jnp.where((counts > 0)[:, None], ext[:, :dim], 0.0)
+        with jax.named_scope(COMBINE_SCOPE):  # its raw scatter too
+            lim = jnp.finfo(acc_dt).max
+            fill = jnp.asarray(-lim if combine == "max" else lim, acc_dt)
+            ind = jnp.where(owned, 1.0, fill)[:, None]
+            filled = jnp.where(
+                owned[:, None],
+                jnp.concatenate(
+                    [gathered_deltas.astype(acc_dt), ind], axis=1
+                ),
+                fill,
+            )
+            target = jnp.full((rps, dim + 1), fill, acc_dt)
+            if combine == "max":
+                ext = target.at[local_idx].max(filled, mode="drop")
+            else:
+                ext = target.at[local_idx].min(filled, mode="drop")
+            counts = (jnp.abs(ext[:, dim]) <= 1.0).astype(acc_dt)
+            combined = jnp.where((counts > 0)[:, None], ext[:, :dim], 0.0)
     else:
         # Combine duplicate ids first, then apply once per touched row. The
         # per-id sums and counts ride ONE scatter (counts as an appended
@@ -890,23 +899,33 @@ def push(
             [masked.astype(acc_dt), owned.astype(acc_dt)[:, None]],
             axis=1,
         )
-        acc = ops.scatter_add(
-            jnp.zeros((rps, dim + 1), acc_dt), local_idx, withcnt,
-            hot_rows=hot_rows,
-        )
-        combined, counts = acc[:, :dim], acc[:, dim]
-        if combine == "mean":
-            combined = combined * (1.0 / jnp.maximum(counts, 1.0))[:, None]
-        elif callable(combine):
-            combined = jnp.where(
-                (counts > 0)[:, None], combine(combined, counts), 0.0
-            )
-    if apply_fn is None:
-        # Additive fold: untouched rows receive exactly zero, so no mask is
-        # needed (a full-table where() is a measurable per-step cost).
-        return local_shard + combined.astype(local_shard.dtype)
-    new_rows = apply_fn(local_shard, combined.astype(local_shard.dtype))
-    return jnp.where((counts > 0)[:, None], new_rows, local_shard)
+        with jax.named_scope(COMBINE_SCOPE):
+            # A zero the compiler cannot see through. As a broadcast of a
+            # literal, XLA's TPU pipeline re-made the fill under the loop
+            # body's name and it lost this scope: 1.7 GB a table a step
+            # written under no name at 1.1 M x 301 (chip run, PR 27;
+            # tests/test_v5e_compile.py reads the compiled text).
+            zeros = jnp.broadcast_to(
+                lax.optimization_barrier(jnp.zeros((), acc_dt)),
+                (rps, dim + 1))
+        acc = ops.scatter_add(zeros, local_idx, withcnt, hot_rows=hot_rows)
+        with jax.named_scope(COMBINE_SCOPE):
+            combined, counts = acc[:, :dim], acc[:, dim]
+            if combine == "mean":
+                combined = combined * (
+                    1.0 / jnp.maximum(counts, 1.0))[:, None]
+            elif callable(combine):
+                combined = jnp.where(
+                    (counts > 0)[:, None], combine(combined, counts), 0.0
+                )
+    with jax.named_scope(COMBINE_SCOPE):
+        if apply_fn is None:
+            # Additive fold: untouched rows receive exactly zero, so no
+            # mask is needed (a full-table where() is a measurable
+            # per-step cost).
+            return local_shard + combined.astype(local_shard.dtype)
+        new_rows = apply_fn(local_shard, combined.astype(local_shard.dtype))
+        return jnp.where((counts > 0)[:, None], new_rows, local_shard)
 
 
 # ---------------------------------------------------------------------------

@@ -125,8 +125,11 @@ def main(argv=None) -> int:
         if args.ingest == "device":
             # Fused path: tokens resident on device, subsampling/compaction
             # and pair generation inside the compiled epoch.
+            from fps_tpu import DeviceDataset
+
             plan = Word2VecDevicePlan(
-                tokens, uni, cfg, mesh, num_workers=W, block_len=block_len,
+                DeviceDataset(mesh, {"token": np.asarray(tokens, np.int32)}),
+                uni, cfg, mesh, num_workers=W, block_len=block_len,
                 seed=args.seed, sync_every=args.sync_every, mode="block",
             )
             tables, local_state, _ = trainer.run_indexed(

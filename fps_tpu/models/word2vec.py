@@ -36,6 +36,7 @@ import numpy as np
 
 from fps_tpu.core.api import StepOutput, WorkerLogic
 from fps_tpu.core.store import ParamStore, TableSpec, ranged_uniform_init
+from fps_tpu.obs.timing import host_span
 from fps_tpu.parallel.mesh import host_to_replicated, key_to_replicated
 
 Array = jax.Array
@@ -60,22 +61,42 @@ def _build_alias(p: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
 
     Returns (prob, alias): draw ``j ~ U{0..V-1}``, ``u ~ U[0,1)``; the
     sample is ``j`` if ``u < prob[j]`` else ``alias[j]``.
+
+    Rounds instead of a walk over Python lists (1.1 M words took seconds
+    of set-up): every still-open small column is paired with its own
+    large one, all pairs of a round at once. A large column takes, in
+    index order, as many of the open smalls as it can fill and stay large
+    (a prefix sum and a ``searchsorted``), then one more that turns it
+    small for the next round. Each column closes once with an alias that
+    was large when it closed, so the tables encode ``p`` exactly up to
+    float rounding, as the walk's do; the pairing differs from the
+    walk's, the sampled distribution does not.
     """
     V = len(p)
-    prob = np.zeros(V)
-    alias = np.zeros(V, np.int64)
     scaled = np.asarray(p, np.float64) * V
-    small = [i for i in range(V) if scaled[i] < 1.0]
-    large = [i for i in range(V) if scaled[i] >= 1.0]
-    while small and large:
-        s = small.pop()
-        l = large.pop()
-        prob[s] = scaled[s]
-        alias[s] = l
-        scaled[l] = scaled[l] - (1.0 - scaled[s])
-        (small if scaled[l] < 1.0 else large).append(l)
-    for i in large + small:
-        prob[i] = 1.0
+    prob = np.ones(V)
+    alias = np.arange(V, dtype=np.int64)
+    small = np.flatnonzero(scaled < 1.0)
+    large = np.flatnonzero(scaled >= 1.0)
+    while len(small) and len(large):
+        # Room a large column has above 1, and the smalls' needs, as
+        # running totals: small k goes to the first large whose room,
+        # cumulated, still covers the smalls before k (so the last one a
+        # large takes may leave it under 1: it joins the next round).
+        need = np.cumsum(1.0 - scaled[small])
+        room = np.cumsum(scaled[large] - 1.0)
+        before = np.concatenate([[0.0], need[:-1]])
+        owner = np.searchsorted(room, before, side="right")
+        placed = owner < len(large)
+        if not placed.any():  # rounding left no large with room: all 1
+            break
+        s_ids, l_ids = small[placed], large[owner[placed]]
+        prob[s_ids] = scaled[s_ids]
+        alias[s_ids] = l_ids
+        np.subtract.at(scaled, l_ids, 1.0 - scaled[s_ids])
+        open_ = np.concatenate([small[~placed], large])
+        small = open_[scaled[open_] < 1.0]
+        large = open_[scaled[open_] >= 1.0]
     return prob, alias
 
 
@@ -303,10 +324,15 @@ class Word2VecBlockWorker(WorkerLogic, _AliasNegativeSampler):
         pad = self.num_groups * G - LW
         vp = jnp.pad(v, ((0, pad), (0, 0))).reshape(self.num_groups, G, -1)
         instp = jnp.pad(inst, (0, pad)).reshape(self.num_groups, G)
-        ln = jnp.einsum("gid,gkd->gik", vp, negs_u)  # (NG, G, K)
+        # precision=HIGHEST: the TPU's default runs an f32 einsum in bf16
+        # passes, and the tables are float32 (the positives above are
+        # element-wise products, exact in f32 already).
+        hi = jax.lax.Precision.HIGHEST
+        ln = jnp.einsum("gid,gkd->gik", vp, negs_u,
+                        precision=hi)  # (NG, G, K)
         sn = jax.nn.sigmoid(ln) * instp[:, :, None]
-        dv_neg = -lr * jnp.einsum("gik,gkd->gid", sn, negs_u)
-        du_neg = -lr * jnp.einsum("gik,gid->gkd", sn, vp)
+        dv_neg = -lr * jnp.einsum("gik,gkd->gid", sn, negs_u, precision=hi)
+        du_neg = -lr * jnp.einsum("gik,gid->gkd", sn, vp, precision=hi)
         dv = dv + dv_neg.reshape(-1, v.shape[-1])[:LW]
         loss += jnp.sum(-jax.nn.log_sigmoid(-ln) * instp[:, :, None])
 
@@ -713,8 +739,10 @@ class Word2VecDevicePlan:
 
     The host streaming path (:func:`skipgram_chunks`) materializes and
     uploads every (center, context) chunk — dominated by the host→device
-    link on a TPU VM. Here the raw token stream is uploaded once; each
-    epoch then runs as ONE compiled program that:
+    link on a TPU VM. Here the raw token stream is uploaded once (a
+    one-column :class:`~fps_tpu.core.device_ingest.DeviceDataset`, kept as
+    ``.dataset`` as :class:`DeviceEpochPlan` keeps its own); each epoch
+    then runs as ONE compiled program that:
 
     1. **subsamples + compacts** the stream on device (uniform-vs-keep_p
        mask → cumsum → scatter), exactly word2vec's semantics where
@@ -732,14 +760,31 @@ class Word2VecDevicePlan:
     overflow tokens are dropped (one-pass streaming semantics).
     """
 
-    def __init__(self, dataset_tokens: np.ndarray, unigram_counts: np.ndarray,
+    TOKEN = "token"  # the data set's one column
+
+    @host_span("plan.build")
+    def __init__(self, dataset, unigram_counts: np.ndarray,
                  cfg: W2VConfig, mesh, *, num_workers: int,
                  block_len: int = 8192, seed: int = 0,
                  sync_every: int | None = None, mode: str = "pairs"):
+        """``dataset``: a :class:`~fps_tpu.core.device_ingest.DeviceDataset`
+        of one int32 column ``token`` (the stream, uploaded once under
+        ``dataset.place`` like every other plan's data), kept as
+        ``.dataset``; a host token array is wrapped into one."""
         from jax.sharding import NamedSharding, PartitionSpec as P
+
+        from fps_tpu.core.device_ingest import DeviceDataset
 
         if mode not in ("pairs", "block"):
             raise ValueError(f"unknown mode {mode!r}")
+        if not isinstance(dataset, DeviceDataset):
+            dataset = DeviceDataset(
+                mesh, {self.TOKEN: np.asarray(dataset, np.int32)})
+        if dataset.column_names() != [self.TOKEN]:
+            raise ValueError(
+                f"the token stream is a DeviceDataset of one column "
+                f"{self.TOKEN!r}; got {dataset.column_names()}")
+        self.dataset = dataset
         self.cfg = cfg
         self.mode = mode
         self.num_workers = num_workers
@@ -747,16 +792,14 @@ class Word2VecDevicePlan:
         self.local_batch = 2 * cfg.window * block_len  # pairs per step
         self.seed = seed
         self.sync_every = sync_every
-        self.num_tokens = int(len(dataset_tokens))
+        self.num_tokens = int(dataset.n)
 
         replicated = NamedSharding(mesh, P())
-        self._tokens = host_to_replicated(
-            np.asarray(dataset_tokens, np.int32), mesh
-        )
         keep_p = _keep_probs(cfg, unigram_counts)
         self._keep_p = host_to_replicated(keep_p.astype(np.float32), mesh)
 
-        expected_kept = float(keep_p[np.asarray(dataset_tokens)].sum())
+        expected_kept = float(
+            keep_p[dataset.host_column(self.TOKEN)].sum())
         bound = int(expected_kept + 8.0 * np.sqrt(expected_kept + 1.0) + 1024)
         bound = min(bound, self.num_tokens)
         per_worker = -(-bound // (block_len * num_workers))
@@ -766,15 +809,14 @@ class Word2VecDevicePlan:
         self.steps_per_epoch = steps
         # Compacted buffer: every block slice (+ window lookahead) in range.
         self._buf_len = steps * block_len * num_workers + cfg.window
-
-        W = cfg.window
         buf_len = self._buf_len
 
-        def compact(key_data):
-            key = jax.random.wrap_key_data(key_data)
-            toks = self._tokens
+        # Once a call, so named WITHOUT the fps. prefix (a reader counts
+        # steps by the ops under fps.*; docs/observability.md).
+        @jax.named_scope("ingest.compact")
+        def compact(key, toks, keep_p):
             keep = (jax.random.uniform(key, toks.shape)
-                    < jnp.take(self._keep_p, toks))
+                    < jnp.take(keep_p, toks))
             dest = jnp.cumsum(keep.astype(jnp.int32)) - 1
             kept = dest[-1] + 1
             dest = jnp.where(keep, jnp.minimum(dest, buf_len - 1), buf_len)
@@ -782,21 +824,28 @@ class Word2VecDevicePlan:
             compacted = compacted.at[dest].set(toks, mode="drop")
             return compacted[:buf_len], jnp.minimum(kept, buf_len)
 
-        # Takes raw key data (plain numpy, implicitly replicated) and pins
-        # replicated outputs so the path works under multi-controller JAX.
+        # Takes a replicated key (key_to_replicated) and pins replicated
+        # outputs, so the path works under multi-controller JAX.
         self._compact_jit = jax.jit(
             compact, out_shardings=(replicated, replicated)
         )
         self._mesh = mesh
 
+    @host_span("epoch_args")
     def epoch_args(self, epoch: int):
         ekey = jax.random.fold_in(jax.random.key(self.seed), epoch)
         ck, wk = jax.random.split(ekey)
         # _compact_jit pins replicated outputs, so the (tokens,)-sized
         # buffer is placed once and never re-broadcast by the dispatches.
-        compacted, kept = self._compact_jit(
-            np.asarray(jax.random.key_data(ck))
-        )
+        # The key goes in as a device array: reading its data back to the
+        # host would wait for the epoch the device is still running
+        # (5.4 s a call at 1.1 M words x 300, chip run, PR 27), and the
+        # call after it could not be queued ahead.
+        with host_span("compact"):
+            compacted, kept = self._compact_jit(
+                key_to_replicated(ck, self._mesh),
+                self.dataset.columns[self.TOKEN], self._keep_p,
+            )
         return {
             "compacted": compacted,
             "kept": kept,

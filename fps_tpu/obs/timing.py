@@ -18,8 +18,7 @@ host-side clocks the chunked driver makes natural:
   to a phase instead of a single opaque number; its phases ARE host
   spans. The compiled program fuses ingest/pull/compute/push into one
   dispatch, so those sub-phases are visible on the DEVICE timeline
-  instead: the driver wraps them in ``jax.named_scope`` (``fps.ingest`` /
-  ``fps.pull`` / ``fps.compute`` / ``fps.push`` / ``fps.metrics``),
+  instead: the driver wraps them in ``jax.named_scope`` (:data:`STEP_SCOPES`),
   which costs nothing outside a profiler trace
   (``docs/observability.md`` has the table of names).
 * :func:`watch_compiles` — folds JAX's own compile timings and
@@ -70,15 +69,33 @@ DRIVER_PHASES = (
                    # attribution can tell the two loop shapes apart
     "epoch_args",      # DeviceEpochPlan.epoch_args: host RNG, operand
                        # upload, the per-epoch ingest.tbuf / ingest.perm
-                       # programs (under "ingest" in the megastep loop)
+                       # programs (under "ingest" in the megastep loop);
+                       # Word2VecDevicePlan.epoch_args likewise ("compact")
     "program_lookup",  # compiled-program cache lookup; built=True when
                        # the lookup had to build (trace set-up, no compile)
 )
 NESTED_PHASES = (
     "enqueue",     # the jitted call alone, inside dispatch / megastep
     "attach_hot",  # Trainer._attach_hot, holding "reconcile"
+    "compact",     # Word2VecDevicePlan's per-epoch subsample-and-compact
+                   # program (ingest.compact) queued, inside "epoch_args"
 )
 COMPILE_PHASES = ("compile.trace", "compile.lower", "compile.backend")
+# Device scopes (``jax.named_scope``: op metadata, free outside a profiler
+# trace). STEP_SCOPES lie in step bodies, in step order: ``fps.prepare``
+# is the worker's own sampling before the pull; ``fps.combine`` the
+# table-sized work of a non-"sum" push (``core/store.COMBINE_SCOPE``),
+# inside ``fps.push`` beside the routed scatter's ``fps.ops`` (under which
+# ``fps_tpu.ops`` names the route); the rest belong to the tiered and
+# megastep paths. Programs that run once a call or once a chunk are named
+# WITHOUT the prefix (ONCE_SCOPES): a reader counts steps by the ops
+# under ``fps.*``. A test walks the tree against both lists.
+STEP_SCOPES = ("fps.ingest", "fps.prepare", "fps.sketch", "fps.pull",
+               "fps.compute", "fps.push", "fps.combine", "fps.ops",
+               "fps.hot_accumulate", "fps.reconcile", "fps.sketch_merge",
+               "fps.megastep_vote", "fps.megastep_tick", "fps.metrics")
+ONCE_SCOPES = ("ingest.pack", "ingest.tbuf", "ingest.perm", "ingest.chunk",
+               "ingest.compact")
 # Set-up spans (no timer: they report through the process-default
 # recorder). Those that queue device work close on its completion when a
 # recorder is installed, and only then (settle()).

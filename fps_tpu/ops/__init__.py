@@ -314,10 +314,11 @@ def _route_dim1(R: int, D: int, B: int, dtype=jnp.float32) -> bool:
     return R <= DIM1_MAX_ROWS and B >= DIM1_MIN_BATCH
 
 
-def _tiled_table_bytes(rows: int, dtype) -> int:
-    """Bytes of ``rows`` rows in XLA's row-major tiled form: a row of any
-    width up to 128 is one 128-lane row."""
-    return rows * 128 * jnp.dtype(dtype).itemsize
+def _tiled_table_bytes(rows: int, dim: int, dtype) -> int:
+    """Bytes of ``rows`` rows of ``dim`` numbers in XLA's row-major tiled
+    form: a row takes whole 128-lane tiles, one for any width up to 128
+    (512 B in f32 at rank 10), three at 300."""
+    return rows * -(-dim // 128) * 128 * jnp.dtype(dtype).itemsize
 
 
 def _xla_packed_rows(R: int, D: int) -> int:
@@ -344,8 +345,8 @@ def _route_xla_packed(R: int, D: int, B: int, dtype) -> bool:
     if not use or interpret or not _xla_packable(D, dtype):
         return False
     return (B >= XLA_PACKED_MIN_IDS
-            and _tiled_table_bytes(R, dtype) > XLA_VMEM_TABLE_BYTES
-            and _tiled_table_bytes(_xla_packed_rows(R, D), dtype)
+            and _tiled_table_bytes(R, D, dtype) > XLA_VMEM_TABLE_BYTES
+            and _tiled_table_bytes(_xla_packed_rows(R, D), 128 // D * D, dtype)
             <= XLA_PACKED_TABLE_BYTES)
 
 
@@ -361,7 +362,7 @@ def _xla_reason(R: int, D: int, dtype) -> str:
     if not use:
         return "backend"
     if (not interpret and _xla_packable(D, dtype)
-            and _tiled_table_bytes(R, dtype) <= XLA_VMEM_TABLE_BYTES):
+            and _tiled_table_bytes(R, D, dtype) <= XLA_VMEM_TABLE_BYTES):
         return "vmem_fit"
     return "shape"
 

@@ -775,3 +775,53 @@ def test_route_log_says_vmem_fit_where_the_plain_op_already_fits(as_on_tpu):
         jax.ShapeDtypeStruct((32_768,), jnp.int32), f32(32_768, 10))
     assert [(r.route, r.reason) for r in ops.routes_traced()] == [
         ("gather.xla", "vmem_fit"), ("scatter_add.xla", "vmem_fit")]
+
+
+@pytest.mark.parametrize("R,D,want", [
+    (17_770, 10, 17_770 * 512),          # one 128-lane tile: as before
+    (480_189, 10, 480_189 * 512),
+    (40_064, 120, 40_064 * 512),         # Netflix's packed form
+    (47_236, 128, 47_236 * 512),
+    (47_236, 129, 47_236 * 1024),        # a second tile for one float more
+    (1_115_011, 300, 1_115_011 * 1536),  # w2v-1bw: three tiles a row
+    (1_115_011, 301, 1_115_011 * 1536),
+])
+def test_tiled_table_bytes_counts_a_rows_real_lane_tiles(R, D, want):
+    assert ops._tiled_table_bytes(R, D, jnp.float32) == want
+    assert ops._tiled_table_bytes(R, D, jnp.bfloat16) == want // 2
+
+
+# Every row op of every cell of the benchmark: (rows, dim, ids) -> the
+# route "auto" takes on the TPU and why it passed the others over. The
+# first nine are PERF.md's route logs as PR 25 and PR 26 read them on the
+# chip: counting a row's real lane tiles changes none of them.
+_CELL_ROW_OPS = [
+    ("gather", 17_770, 10, 32_768, "gather.xla", "vmem_fit"),
+    ("gather", 480_189, 10, 32_768, "gather.xla_packed", ""),
+    ("scatter_add", 480_189, 10, 32_768, "scatter_add.xla_packed", ""),
+    ("scatter_add", 17_770, 11, 32_768, "scatter_add.xla", "vmem_fit"),
+    ("gather", 17_772, 10, 32_768, "gather.xla", "vmem_fit"),
+    ("gather", 120_048, 10, 32_768, "gather.xla", "vmem_fit"),
+    ("scatter_add", 120_048, 10, 32_768, "scatter_add.xla", "vmem_fit"),
+    ("scatter_add", 4_443, 11, 131_072, "scatter_add.xla", "vmem_fit"),
+    ("gather", 47_236, 1, 786_432, "gather.dim1", ""),
+    ("gather", 1_115_011, 300, 8_197, "gather.xla", "shape"),
+    ("gather", 1_115_011, 300, 49_182, "gather.xla", "shape"),
+    ("scatter_add", 1_115_011, 301, 8_197, "scatter_add.xla", "shape"),
+    ("scatter_add", 1_115_011, 301, 49_182, "scatter_add.xla", "shape"),
+]
+
+
+@pytest.mark.parametrize("op,R,D,B,route,reason", _CELL_ROW_OPS)
+def test_route_of_every_row_op_of_the_benchmarks_cells(as_on_tpu, op, R, D,
+                                                       B, route, reason):
+    f32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32)  # noqa: E731
+    ids = jax.ShapeDtypeStruct((B,), jnp.int32)
+    ops.clear_routes()
+    if op == "gather":
+        jax.eval_shape(lambda t, i: ops.gather_rows(t, i), f32(R, D), ids)
+    else:
+        jax.eval_shape(lambda t, i, d: ops.scatter_add(t, i, d), f32(R, D),
+                       ids, f32(B, D))
+    assert ops.routes_traced() == [
+        ops.Route(op, route, R, D, B, interpret=False, reason=reason)]

@@ -34,6 +34,12 @@ def _scope_paths(lowered) -> set:
             for name in re.findall(r'loc\("([^"]+/[^"]*)"\(', text)}
 
 
+def _holds(paths, scope) -> bool:
+    """Some op's path is ``scope`` or lies under it."""
+    return any(p == scope or p.startswith(scope + "/")
+               or f"/{scope}/" in p + "/" for p in paths)
+
+
 @pytest.fixture(scope="module")
 def pa_step_scopes(devices8):
     """PA's epoch program on one device at the benchmark cell's shape
@@ -71,9 +77,7 @@ def pa_step_scopes(devices8):
     "fps.push/fps.ops/scatter_add.dim1",
 ])
 def test_pa_step_holds_scope(pa_step_scopes, scope):
-    assert any(p == scope or p.startswith(scope + "/")
-               or f"/{scope}/" in p + "/" for p in pa_step_scopes), (
-        sorted(pa_step_scopes))
+    assert _holds(pa_step_scopes, scope), sorted(pa_step_scopes)
 
 
 def test_head_prefix_tail_scope_is_beside_the_head_not_under_it(
@@ -134,3 +138,122 @@ def test_mf_step_ingest_is_scoped_and_tbuf_is_not_in_the_step(mf_plans):
     assert any("fps.ingest" in p.split("/") for p in paths)
     assert any("fps.ops" in p.split("/") for p in paths)
     assert not [p for p in paths if "ingest.tbuf" in p]
+
+
+# -- word2vec SGNS: fps.prepare, fps.combine, ingest.compact ---------------
+
+@pytest.fixture(scope="module")
+def w2v_programs(devices8):
+    """The block worker's step in the step builders that take its plan
+    (the indexed epoch and the megastep; all three builders go through
+    ``Trainer._compute_step``, where the scope is) on one device, traced
+    and lowered; nothing runs. Scope paths by builder, the
+    compaction's, and the route log of the indexed program."""
+    from fps_tpu.models.word2vec import (
+        W2VConfig,
+        Word2VecDevicePlan,
+        word2vec_block,
+    )
+
+    V, D, L = 300, 300, 64      # the cell's row width, a toy vocabulary
+    mesh = make_ps_mesh(devices=devices8[:1])
+    rng = np.random.default_rng(2)
+    counts = 1.0 / (np.arange(V) + 1.5)
+    tokens = rng.integers(0, V, 4096).astype(np.int32)
+    cfg = W2VConfig(vocab_size=V, dim=D)
+    trainer, _ = word2vec_block(mesh, cfg, counts, L)
+    plan = Word2VecDevicePlan(DeviceDataset(mesh, {"token": tokens}),
+                              counts, cfg, mesh, num_workers=1, block_len=L,
+                              seed=1, mode="block")
+    tables, ls = trainer.init_state(jax.random.key(0))
+    key = key_to_replicated(jax.random.key(1), mesh)
+    iargs = plan.epoch_args(0)
+    ops.clear_routes()
+    indexed = trainer._get_indexed_fn(plan, "sync").lower(
+        tables, ls, iargs, np.int32(0), key)
+    routes = ops.routes_traced()
+    mega = trainer._get_megastep_fn(plan, "sync", 2).lower(
+        tables, ls, iargs, np.int32(0), key, {})
+    compact = plan._compact_jit.lower(
+        key, plan.dataset.columns["token"], plan._keep_p)
+    return {"indexed": _scope_paths(indexed), "megastep": _scope_paths(mega),
+            "compact": _scope_paths(compact), "routes": routes}
+
+
+@pytest.mark.parametrize("builder", ["indexed", "megastep"])
+@pytest.mark.parametrize("scope", [
+    "fps.ingest", "fps.prepare", "fps.pull", "fps.compute", "fps.push",
+    "fps.push/fps.combine", "fps.pull/fps.ops/gather.xla",
+    "fps.push/fps.ops/scatter_add.xla",
+])
+def test_w2v_step_holds_scope_in_every_step_builder(w2v_programs, builder,
+                                                    scope):
+    assert _holds(w2v_programs[builder], scope), sorted(
+        w2v_programs[builder])
+
+
+def test_scope_lists_name_every_scope_in_the_tree(w2v_programs):
+    """``obs.timing.STEP_SCOPES`` / ``ONCE_SCOPES`` against every literal
+    ``jax.named_scope("...")`` and ``COMBINE_SCOPE`` under ``fps_tpu/``,
+    and against what the lowered w2v step really carries."""
+    import ast
+    import os
+
+    from fps_tpu.core.store import COMBINE_SCOPE
+    from fps_tpu.obs import timing
+
+    declared = set(timing.STEP_SCOPES) | set(timing.ONCE_SCOPES)
+    root = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "fps_tpu")
+    named = {COMBINE_SCOPE}
+    for d, _, files in os.walk(root):
+        for f in files:
+            if not f.endswith(".py"):
+                continue
+            for node in ast.walk(ast.parse(open(os.path.join(d, f)).read())):
+                if (isinstance(node, ast.Call)
+                        and getattr(node.func, "attr", "") == "named_scope"
+                        and node.args
+                        and isinstance(node.args[0], ast.Constant)):
+                    named.add(node.args[0].value)
+    assert named == declared, named ^ declared
+    assert not [n for n in timing.ONCE_SCOPES if n.startswith("fps.")]
+    used = {part for p in w2v_programs["indexed"] for part in p.split("/")
+            if part.startswith("fps.")}
+    assert {"fps.prepare", "fps.combine"} <= used <= set(timing.STEP_SCOPES)
+
+
+def test_combine_scope_lies_beside_the_routed_scatter_not_round_it(
+        w2v_programs):
+    """An op counts under ``fps.combine`` or under ``fps.ops/<route>``,
+    never both; and prepare's gathers on the alias tables are not the
+    pull's."""
+    for paths in (w2v_programs["indexed"], w2v_programs["megastep"]):
+        both = [p for p in paths if "fps.combine" in p and "fps.ops" in p]
+        assert not both, both
+        assert not [p for p in paths
+                    if "fps.prepare" in p and ("fps.pull" in p
+                                               or "fps.compute" in p)]
+
+
+def test_compaction_is_named_without_the_fps_prefix(w2v_programs):
+    paths = w2v_programs["compact"]
+    assert any("ingest.compact" in p.split("/") for p in paths), sorted(paths)
+    assert not [p for p in paths if "fps." in p]
+    assert not [p for p in w2v_programs["indexed"] if "ingest.compact" in p]
+
+
+def test_route_log_of_a_300_wide_table(w2v_programs):
+    """Both tables take the plain XLA routes, a gather and a scatter-add
+    each, the accumulator one column wider; on the CPU the reason is the
+    backend's (on the TPU: ``shape``, tests/test_ops.py)."""
+    L, W, K = 64, 5, 5
+    got = [(r.route, r.rows, r.dim, r.ids) for r in w2v_programs["routes"]]
+    assert got == [
+        ("gather.xla", 300, 300, L + W),
+        ("gather.xla", 300, 300, (L + W) * (1 + K)),
+        ("scatter_add.xla", 300, 301, L + W),
+        ("scatter_add.xla", 300, 301, (L + W) * (1 + K)),
+    ], got
+    assert not any(r.route in ops.PALLAS_ROUTES
+                   for r in w2v_programs["routes"])

@@ -79,7 +79,8 @@ def test_plain_scatter_runs_in_vmem_up_to_the_edge(one_chip, R):
     still copies into VMEM, where ``XLA_VMEM_TABLE_BYTES`` already hands
     over to the packed form) ride VMEM with sorted indices."""
     D, B = NETFLIX[1:]
-    fits = ops._tiled_table_bytes(R, jnp.float32) <= ops.XLA_VMEM_TABLE_BYTES
+    fits = (ops._tiled_table_bytes(R, D, jnp.float32)
+            <= ops.XLA_VMEM_TABLE_BYTES)
     assert fits == (R == 120_048)
     text = _plain_scatter(R, D, B, one_chip)
     assert f"f32[{R},{D}]{{1,0:T(8,128)S(1)}}" in _row_op_outputs(
@@ -92,7 +93,8 @@ def test_plain_scatter_is_out_of_vmem_past_the_edge(one_chip, R):
     """The upper side: 240,095 rows (122.9 MB) and Netflix's user block
     are scattered into in HBM — the regime the packed route leaves."""
     D, B = NETFLIX[1:]
-    assert ops._tiled_table_bytes(R, jnp.float32) > ops.XLA_VMEM_TABLE_BYTES
+    assert (ops._tiled_table_bytes(R, D, jnp.float32)
+            > ops.XLA_VMEM_TABLE_BYTES)
     outs = _row_op_outputs(_plain_scatter(R, D, B, one_chip), "scatter-add")
     assert outs and not any("S(1)" in o for o in outs), outs
 
@@ -104,7 +106,7 @@ def test_packed_route_scatters_netflix_block_in_vmem_sorted(one_chip):
     R, D, B = NETFLIX
     Rp, lanes = ops._xla_packed_rows(R, D), 128 // D * D
     assert (Rp, lanes) == (40_064, 120)
-    assert (ops._tiled_table_bytes(Rp, jnp.float32)
+    assert (ops._tiled_table_bytes(Rp, lanes, jnp.float32)
             <= ops.XLA_PACKED_TABLE_BYTES)
     text = _compiled(ops._xla_packed_scatter_add, one_chip,
                      ((R, D), jnp.float32), ((B,), jnp.int32),
@@ -175,3 +177,54 @@ def test_x4_push_all_gathers_its_deltas_compact(topo):
     gathered = re.findall(rf"= (f32\[{W * B},{rank}\]\S+) all-gather\(", text)
     compact = f"f32[{W * B},{rank}]{{0,1:T(8,128)S(1)}}"
     assert gathered and all(g.startswith(compact) for g in gathered), gathered
+
+
+def test_w2v_epoch_program_fits_and_names_its_table_sized_work(
+        topo, monkeypatch):
+    """``w2v-1bw.epochs``'s epoch program at the cell's own size (two
+    ``[1115011, 300]`` tables, blocks of 8,192 tokens) for one described
+    chip: it fits beside the runner's copy of the state (temporaries under
+    8 GB of 16), both tables take the plain XLA routes for reason
+    ``shape``, and the mean-combine's zero fill of the ``[rows, 301]``
+    accumulator (1.7 GB written a table a step) keeps the ``fps.combine``
+    scope in the COMPILED text. A broadcast of a literal zero lost it: the
+    TPU pipeline re-made it under the loop body's name, and a sixth of the
+    step ran under no name (chip run, PR 27); no CPU test can see that."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from fps_tpu.models.word2vec import (
+        W2VConfig,
+        Word2VecDevicePlan,
+        word2vec_block,
+    )
+    from fps_tpu.parallel.mesh import make_ps_mesh
+
+    monkeypatch.setattr(ops, "_use_pallas", lambda: (True, False))
+    V, D, L, T = 1_115_011, 300, 8_192, 172
+    mesh = make_ps_mesh(num_shards=1, devices=list(topo.devices)[:1])
+    cfg = W2VConfig(vocab_size=V, dim=D)
+    trainer, _ = word2vec_block(mesh, cfg, 1.0 / (jnp.arange(V) + 1.5), L)
+    # The plan's geometry without its uploads (no device holds an array).
+    plan = object.__new__(Word2VecDevicePlan)
+    plan.cfg, plan.mode, plan.num_workers, plan.block_len = cfg, "block", 1, L
+    plan.steps_per_epoch, plan.sync_every = T, None
+
+    def shape(s, dtype, spec=P()):
+        return jax.ShapeDtypeStruct(s, dtype,
+                                    sharding=NamedSharding(mesh, spec))
+
+    key = shape((), jax.random.key(0).dtype)
+    tables = {n: shape((V, D), jnp.float32, P("shard", None))
+              for n in ("in_embeddings", "out_embeddings")}
+    iargs = {"compacted": shape((T * L + cfg.window,), jnp.int32),
+             "kept": shape((), jnp.int32), "wkey": key}
+    ops.clear_routes()
+    compiled = trainer._build_indexed_fn(plan, "sync").lower(
+        tables, (), iargs, jnp.int32(0), key).compile()
+    assert [(r.route, r.dim, r.reason) for r in ops.routes_traced()] == [
+        ("gather.xla", 300, "shape"), ("gather.xla", 300, "shape"),
+        ("scatter_add.xla", 301, "shape"), ("scatter_add.xla", 301, "shape")]
+    assert compiled.memory_analysis().temp_size_in_bytes < 8 << 30
+    fills = [line for line in compiled.as_text().splitlines()
+             if re.search(rf"= f32\[{V},{D + 1}\]\S* broadcast\(", line)]
+    assert fills and all("fps.push/fps.combine/" in f for f in fills), fills

@@ -47,9 +47,10 @@ _SERIAL_PHASES = ("ingest", "place", "dispatch", "host_sync",
                   "checkpoint", "callback", "reconcile", "retier")
 _OVERLAPPED_PHASES = ("prefetch",)
 # Spans that lie INSIDE another phase (fps_tpu.obs.timing.NESTED_PHASES:
-# enqueue in dispatch/megastep, attach_hot round reconcile): their time is
-# in the enclosing phase already, so the serial sum leaves them out.
-_NESTED_PHASES = ("enqueue", "attach_hot")
+# enqueue in dispatch/megastep, attach_hot round reconcile, compact in
+# epoch_args): their time is in the enclosing phase already, so the serial
+# sum leaves them out.
+_NESTED_PHASES = ("enqueue", "attach_hot", "compact")
 
 # Journal events rendered as zero-duration instants, by source.
 _POD_INSTANTS = (
