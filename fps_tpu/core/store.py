@@ -699,12 +699,77 @@ def pull(
     return lax.psum_scatter(vals, shard_axis, scatter_dimension=0, tiled=True)
 
 
-# Device scope of a non-"sum" combine's TABLE-SIZED work in :func:`push`:
-# the making of the (rows, dim + 1) accumulator, the normalisation and the
-# add to the shard (max / min: their raw scatter too). The routed
-# scatter-add between them keeps its own ``fps.ops/...`` scope BESIDE this
-# one, so no op counts under both (docs/observability.md).
+# Device scope of a non-"sum" combine's own work in :func:`push`, whatever
+# the routed scatter-add does not do. On the accumulator branch
+# (``push.mean_dense``, callable, max / min) that is TABLE-SIZED: the making
+# of the (rows, dim + 1) accumulator, the normalisation and the add to the
+# shard (max / min: their raw scatter too). On the row branch
+# (``push.mean_rows``) it is PAYLOAD-SIZED: the per-id counts (two sorts of
+# the B ids), the multiply of the B pushed rows and their sum by id in a
+# (B, dim) buffer. The routed scatter-add keeps its own ``fps.ops/...``
+# scope BESIDE this one, so no op counts under both
+# (docs/observability.md).
 COMBINE_SCOPE = "fps.combine"
+
+
+def _id_runs(idx: Array, drop: int) -> tuple[Array, Array, Array]:
+    """The duplicates of a batch of row indices ``idx [B]``, in
+    ``[B]``-sized arrays alone: ``n[i]``, how many entries equal
+    ``idx[i]`` (at least 1); ``slot[i]`` in ``[0, B)``, one slot an index,
+    the same for all its entries; and ``slot_idx[j]``, the index whose
+    slot ``j`` is, ``drop`` where it is no index's. By sorting the
+    indices with their positions, taking each run's first and last
+    position (a running max, a reversed running min) and sorting back. No
+    ``[rows]`` count vector: a scalar scatter-add and gather of the same
+    ids cost 3.6x as much beside the push's row scatter at 49,182 ids
+    into 1,115,011 rows (0.70 against 0.19 ms; 0.12 against 0.03 at
+    8,197: ``tools/bench_scatter.py mean counts``, chip run, PR 28)."""
+    B = idx.shape[0]
+    pos = jnp.arange(B, dtype=jnp.int32)
+    s, order = lax.sort_key_val(idx, pos)
+    edge = s[1:] != s[:-1]
+    one = jnp.ones((1,), bool)
+    first = jnp.concatenate([one, edge])
+    lo = lax.cummax(jnp.where(first, pos, 0))
+    hi = lax.cummin(jnp.where(jnp.concatenate([edge, one]), pos, B - 1),
+                    reverse=True)
+    _, n, slot = lax.sort((order, hi - lo + 1, lo), num_keys=1)
+    return n, slot, jnp.where(first, s, drop)
+
+
+def _mean_push_ratio(rps: int, dim: int, num_ids: int, dtype) -> float:
+    """Bytes of a mean push's ``(rps, dim + 1)`` accumulator over bytes of
+    its payload (``num_ids`` rows of ``dim``), both as XLA tiles them: the
+    payload row-major, the accumulator in the SMALLER of its two forms (a
+    temporary of narrow rows past XLA's VMEM regime is kept transposed, 64
+    B a row at ``dim`` 10 where row-major takes 512). What
+    :data:`fps_tpu.ops.MEAN_ROWS_TABLE_RATIO` is compared with."""
+    acc = min(ops._tiled_table_bytes(rps, dim + 1, dtype),
+              ops._tiled_table_bytes(-(-(dim + 1) // 8) * 8, rps, dtype))
+    return acc / ops._tiled_table_bytes(num_ids, dim, dtype)
+
+
+def _mean_push_route(local_shard: Array, num_ids: int,
+                     apply_fn) -> tuple[str, str]:
+    """Which branch a ``"mean"`` push takes, from :func:`push`'s own
+    arguments: ``("mean_rows", "")`` (normalise the pushed rows, scatter
+    them into the table once) or ``("mean_dense", reason)`` (the
+    ``(rows, dim + 1)`` accumulator). ``reason``: ``"fold"`` (a
+    non-additive ``apply_fn`` must see the combined delta once per id),
+    ``"dtype"`` (the table is narrower than the accumulate dtype: a bf16
+    table sums its duplicates in f32 and rounds once, a scatter into it
+    would sum in bf16) or ``"small_table"`` (the accumulator's passes cost
+    less than counting and summing the pushes of every id apart from the
+    scatter: :data:`fps_tpu.ops.MEAN_ROWS_TABLE_RATIO`)."""
+    rps, dim = local_shard.shape
+    dt = local_shard.dtype
+    if apply_fn is not None:
+        return "mean_dense", "fold"
+    if jnp.promote_types(dt, jnp.float32) != dt:
+        return "mean_dense", "dtype"
+    if _mean_push_ratio(rps, dim, num_ids, dt) < ops.MEAN_ROWS_TABLE_RATIO:
+        return "mean_dense", "small_table"
+    return "mean_rows", ""
 
 
 def pull_local(
@@ -771,7 +836,19 @@ def push(
         ``CombinationLogic``, expected upstream ``.../ps/client/sender/``):
         * ``"sum"`` — every message folds in (reference semantics);
         * ``"mean"`` — per-id average: one averaged step per touched row
-          per push, stable for Zipfian-hot ids under large batches;
+          per push, stable for Zipfian-hot ids under large batches. Two
+          branches, chosen from the arguments' shapes and logged in the
+          route log (:func:`_mean_push_route`): with the additive fold,
+          on a table of the accumulate dtype that is large against the
+          payload (:data:`fps_tpu.ops.MEAN_ROWS_TABLE_RATIO`), the pushed
+          rows are scaled by ``1 / n`` (``n`` the id's count over the
+          gathered, owned, non-negative pushes), summed by id from zero,
+          ``sum_i(d_i * (1/n))``, and scatter-added into the table once,
+          one add a touched row: the cost follows the payload
+          (``push.mean_rows``). Otherwise sums and counts ride one
+          scatter into a ``(rps, dim + 1)`` accumulator that is
+          normalised and added to the shard, ``sum_i(d_i) * (1/n)``:
+          table-sized passes whatever the batch (``push.mean_dense``);
         * ``"max"`` / ``"min"`` — elementwise extremum of the id's deltas
           (a native scatter-max/min, no serial fold);
         * a callable ``(summed, counts) -> combined`` mapping each
@@ -863,6 +940,26 @@ def push(
     # a float64 table must fold its duplicates in float64 (hard-coding f32
     # here would silently shave 29 mantissa bits off every non-"sum" push).
     acc_dt = jnp.promote_types(local_shard.dtype, jnp.float32)
+    if combine == "mean":
+        B = local_idx.shape[0]
+        route, reason = _mean_push_route(local_shard, B, apply_fn)
+        ops.log_route("push", route, rps, dim, B, reason)
+        if route == "mean_rows":
+            # The cost follows the payload: every pushed row is scaled by
+            # 1 / (pushes of its id) and the rows of one id are summed
+            # FROM ZERO in a (B, dim) buffer, sum_i(d_i * (1/n)); then ONE
+            # scatter-add of the buffer's rows into the table itself, one
+            # add a touched row, as "sum" does. Scattering the scaled rows
+            # straight into the table rounds each of a hot id's hundreds
+            # of small addends at the TABLE value's magnitude: 12-20x the
+            # accumulator's gap to a float64 mean (chip run, PR 28).
+            with jax.named_scope(COMBINE_SCOPE):
+                n, slot, slot_idx = _id_runs(local_idx, rps)
+                scaled = masked.astype(acc_dt) * (
+                    1.0 / n.astype(acc_dt))[:, None]
+                combined = jnp.zeros((B, dim), acc_dt).at[slot].add(scaled)
+            return ops.scatter_add(local_shard, slot_idx, combined,
+                                   hot_rows=hot_rows)
     if combine in ("max", "min"):
         # Extremum fold: ONE scatter-max/min of the raw deltas (duplicates
         # combine natively, no serialized pairwise fold) with the touched

@@ -99,7 +99,8 @@ PALLAS_ROUTES = frozenset(
 class Route(NamedTuple):
     """One routing decision, logged where the chosen call is made — at
     TRACE time, never in the compiled program."""
-    op: str          # "gather" | "scatter_add"
+    op: str          # "gather" | "scatter_add" | "push" (the store's choice
+                     # of a mean-combine's branch: fps_tpu.core.store.push)
     route: str       # "gather.dim1_head", "scatter_add.xla", ...
     rows: int        # rows of the table (slice) the call sees
     dim: int
@@ -108,7 +109,8 @@ class Route(NamedTuple):
     reason: str      # why a Pallas route, or the lane-packed XLA one, was
                      # passed over: "" (taken, or exact read asked for),
                      # "f64", "flop_budget", "backend", "shape",
-                     # "vmem_fit" (the plain XLA op already runs in VMEM)
+                     # "vmem_fit" (the plain XLA op already runs in VMEM);
+                     # of "push.mean_dense": "fold", "dtype", "small_table"
 
 
 _ROUTES_TRACED: list[Route] = []
@@ -126,15 +128,21 @@ def clear_routes() -> None:
     _ROUTES_TRACED.clear()
 
 
-@contextlib.contextmanager
-def _routed(op: str, route: str, rows: int, dim: int, ids: int,
-            reason: str = ""):
-    """Log the decision and open the route's scope round its own call."""
+def log_route(op: str, route: str, rows: int, dim: int, ids: int,
+              reason: str = "") -> str:
+    """Append one decision to the route log; returns its ``<op>.<route>``."""
     name = f"{op}.{route}"
     _ROUTES_TRACED.append(Route(
         op, name, int(rows), int(dim), int(ids),
         name in PALLAS_ROUTES and _use_pallas()[1], reason))
-    with _scope(name):
+    return name
+
+
+@contextlib.contextmanager
+def _routed(op: str, route: str, rows: int, dim: int, ids: int,
+            reason: str = ""):
+    """Log the decision and open the route's scope round its own call."""
+    with _scope(log_route(op, route, rows, dim, ids, reason)):
         yield
 
 
@@ -241,6 +249,46 @@ DIM1_MIN_BATCH = 8_192
 # every shipped small table (PA 190 KB, MF items 1.2 MB, logreg 4 MB) and
 # wrong for embedding-scale ones (w2v 20 MB+), hence the cap.
 DENSE_TABLE_BYTES = 4 << 20
+
+# When the store's per-id MEAN push (fps_tpu.core.store.push,
+# combine="mean", additive fold) leaves its (rows, dim + 1) accumulator for
+# the row branch ("push.mean_rows": count the pushes of each id, scale the
+# B pushed rows, sum them by id in a (B, dim) buffer, scatter-add that into
+# the table once). The accumulator branch makes about five passes over the
+# accumulator whatever the batch; the row branch pays two sorts of B ids
+# and a second, payload-sized, row scatter instead. The ratio
+# (``store._mean_push_ratio``) is the accumulator's tiled bytes, in the
+# smaller of its row-major and transposed forms, over the payload's. In
+# time (``tools/bench_scatter.py mean``, one v5 lite chip, f32, Zipf(1.0)
+# ids, the table a loop carry; us a push, accumulator / rows; under each
+# pair the ratio):
+#
+#   R          D=10, B=8192  B=32768      D=64, B=8192  B=32768      D=300, B=8192  B=32768
+#   17,770     111 / 185     303 / 771    123 / 182     343 / 762    366 / 443      1054 / 1612
+#              0.27          0.07         1.2           0.31         1.7            0.43
+#   32,768     161 / 210     439 / 851    171 / 209     474 / 839    397 / 461      1148 / 1694
+#              0.50          0.13         2.3           0.56         3.2            0.79
+#   65,536     469 / 468     482 / 847    476 / 462     514 / 840    933 / 710      1364 / 1873
+#              1.0           0.25         4.5           1.1          6.3            1.6
+#   131,072    689 / 469     694 / 858    697 / 465     730 / 854    2259 / 1076    2301 / 2239
+#              2.0           0.50         9.0           2.3          12.7           3.2
+#   262,144    145 / 226     347 / 800    1609 / 673    3419 / 2662  3561 / 1094    6077 / 4109
+#              4.0           1.0          18            4.5          25             6.3
+#   1,115,011  727 / 511     2060 / 2011  4807 / 741    6644 / 2867  11985 / 1219   14869 / 4503
+#              17            4.3          77            19           108            27
+#
+# and w2v-1bw's two pushes, [1115011, 300] under 8,197 and 49,182 ids
+# (108 and 18): 12019 / 1275 and 17205 / 7277. From 6.3 up the rows win
+# every measured point (13, by 31 % to 9.4x); up to 4.5 the accumulator
+# wins or is level within 3 % at 23 of 25, and the rows take two (D=10 at
+# 2.0 by 47 %, D=64 at 4.5 under 32,768 ids by 28 %). Keeping the
+# accumulator is what every push did before, so the constant sits at the
+# rows' clear-win edge; (4.5, 6.3) is unmeasured. By the accumulator's
+# ROW-MAJOR bytes alone no constant serves: [262144, 10] under 8,192 ids
+# (row-major ratio 32, accumulator 145 / rows 226: the transposed
+# accumulator is 17 MB and rides VMEM) lies above w2v-1bw's out table (23,
+# rows 2.4x faster).
+MEAN_ROWS_TABLE_RATIO = 6.0
 
 # XLA's own fast regime for row ops on a narrow-row table, and the
 # lane-packed XLA route that puts a table back inside it. XLA's TPU gather

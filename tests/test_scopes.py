@@ -245,15 +245,22 @@ def test_compaction_is_named_without_the_fps_prefix(w2v_programs):
 
 def test_route_log_of_a_300_wide_table(w2v_programs):
     """Both tables take the plain XLA routes, a gather and a scatter-add
-    each, the accumulator one column wider; on the CPU the reason is the
-    backend's (on the TPU: ``shape``, tests/test_ops.py)."""
-    L, W, K = 64, 5, 5
+    each; each mean push logs its branch BEFORE its scatter-add: the
+    accumulator (one column wider) or, where the toy table is still large
+    enough against the push (``ops.MEAN_ROWS_TABLE_RATIO``; the cell's
+    own tables are, 23 and 136 times: tests/test_v5e_compile.py), the
+    rows themselves. On the CPU the scatter's reason is the backend's (on
+    the TPU: ``shape``, tests/test_ops.py)."""
+    L, W, K, V = 64, 5, 5, 300
     got = [(r.route, r.rows, r.dim, r.ids) for r in w2v_programs["routes"]]
-    assert got == [
-        ("gather.xla", 300, 300, L + W),
-        ("gather.xla", 300, 300, (L + W) * (1 + K)),
-        ("scatter_add.xla", 300, 301, L + W),
-        ("scatter_add.xla", 300, 301, (L + W) * (1 + K)),
-    ], got
+    want = [("gather.xla", V, 300, L + W),
+            ("gather.xla", V, 300, (L + W) * (1 + K))]
+    for ids in (L + W, (L + W) * (1 + K)):
+        rows = V >= ops.MEAN_ROWS_TABLE_RATIO * ids  # 301 tiles as 300 does
+        want += [("push.mean_rows" if rows else "push.mean_dense",
+                  V, 300, ids),
+                 ("scatter_add.xla", V, 300 if rows else 301, ids)]
+    assert got == want, got
+    assert want[-2][0] == "push.mean_dense"  # 414 ids into 300 rows
     assert not any(r.route in ops.PALLAS_ROUTES
                    for r in w2v_programs["routes"])

@@ -11,6 +11,7 @@ import numpy as np
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+import fps_tpu.ops as ops
 from fps_tpu.core.store import (
     ParamStore,
     TableSpec,
@@ -394,9 +395,13 @@ def test_push_combine_min_and_validation(devices8):
           jnp.asarray(deltas.reshape(-1, 2)))
 
 
-def test_push_combine_mean_float64_precision(devices8):
+@pytest.mark.parametrize("R,route", [(4, "push.mean_dense"),
+                                     (8192, "push.mean_rows")])
+def test_push_combine_mean_float64_precision(devices8, R, route):
     """A float64 table must fold duplicate pushes in float64: deltas that
-    differ only below f32 precision (2^-40) must survive a mean-combine.
+    differ only below f32 precision (2^-40) must survive a mean-combine,
+    on the accumulator branch and on the row branch (a table large against
+    its payload) alike.
     Regression for the hard-coded f32 accumulator (round-2 advice)."""
     import contextlib
 
@@ -417,7 +422,6 @@ def test_push_combine_mean_float64_precision(devices8):
 
     with x64():
         mesh = make_ps_mesh(num_shards=2, num_data=1, devices=devices8[:2])
-        R = 4
         eps = 2.0 ** -40  # representable in f64, vanishes in f32 (1+eps==1)
         ids = np.array([1, 1, 1, 1], np.int32)
         deltas = np.array(
@@ -436,8 +440,11 @@ def test_push_combine_mean_float64_precision(devices8):
                       P((DATA_AXIS, SHARD_AXIS))),
             out_specs=P(SHARD_AXIS, None), check_vma=False,
         ))
+        ops.clear_routes()
         got = np.asarray(f(tables["t"], jnp.asarray(ids),
                            jnp.asarray(deltas)))
+        assert [r.route for r in ops.routes_traced()] == [
+            route, "scatter_add.xla"]
         assert got.dtype == np.float64
         rps = rows_per_shard(R, 2)
         phys = int(np.asarray(id_to_phys(np.array([1]), 2, rps))[0])
@@ -461,6 +468,166 @@ def test_push_combine_mean_float64_precision(devices8):
         got2 = np.asarray(g(tables["t"], jnp.asarray(ids2),
                             jnp.asarray(big)))
         assert got2[phys, 0] == pytest.approx(-1.0e39, rel=1e-12)
+
+
+@pytest.mark.parametrize("idx", [
+    [7], [3, 3, 3, 3], [5, 1, 4, 2, 3], [9, 0, 9, 9, 0, 4, 9, 100, 100],
+    list(np.random.default_rng(3).integers(0, 40, 1000)),
+])
+def test_id_runs_count_and_slot_every_occurrence(idx):
+    """The row branch's bookkeeping, from sorts of the batch alone: for
+    every position how often its index occurs (the sentinel row of
+    dropped pushes is one more index), one slot an index, and the slots'
+    own indices (``drop`` elsewhere)."""
+    from fps_tpu.core.store import _id_runs
+
+    idx = np.asarray(idx, np.int32)
+    n, slot, slot_idx = map(np.asarray,
+                            jax.jit(lambda i: _id_runs(i, 10_000))(
+                                jnp.asarray(idx)))
+    np.testing.assert_array_equal(n, [(idx == i).sum() for i in idx])
+    np.testing.assert_array_equal(slot_idx[slot], idx)
+    live = slot_idx[slot_idx != 10_000]
+    assert sorted(live) == sorted(set(idx)) and len(set(slot)) == len(live)
+
+
+def _mean_push_case(D, S, num_ids, dim, seed=11):
+    """A dup-heavy mean push on a ``D x S`` mesh: 96 ids a worker drawn
+    from 12 hot ids and the whole id space (so every shard sees ids of the
+    others), an eighth of them negative (dropped), onto a non-zero
+    table."""
+    rng = np.random.default_rng(seed)
+    W, B = D * S, 96
+    hot = rng.integers(0, num_ids, 12)
+    ids = np.where(rng.random(W * B) < 0.7, hot[rng.integers(0, 12, W * B)],
+                   rng.integers(0, num_ids, W * B)).astype(np.int32)
+    ids[rng.random(W * B) < 0.125] = -1
+    deltas = rng.normal(0, 1, (W * B, dim)).astype(np.float32)
+    rps = rows_per_shard(num_ids, S)
+    table = rng.normal(0, 1, (rps * S, dim)).astype(np.float32)
+    want = table.astype(np.float64)
+    for i in np.unique(ids[ids >= 0]):
+        want[int(id_to_phys(np.int32(i), S, rps))] += (
+            deltas[ids == i].astype(np.float64).mean(axis=0))
+    return table, ids, deltas, want
+
+
+def _push_on_mesh(devices, D, S, table, ids, deltas, **kw):
+    """``push`` under ``shard_map`` on a ``D x S`` mesh; the table and the
+    route log of its one trace."""
+    mesh = make_ps_mesh(num_shards=S, num_data=D, devices=devices[:D * S])
+    f = jax.jit(jax.shard_map(
+        lambda t, i, d: push(t, i, d, num_shards=S,
+                             data_axis=DATA_AXIS if D > 1 else None, **kw),
+        mesh=mesh,
+        in_specs=(P(SHARD_AXIS, None), P((DATA_AXIS, SHARD_AXIS)),
+                  P((DATA_AXIS, SHARD_AXIS), None)),
+        out_specs=P(SHARD_AXIS, None), check_vma=False))
+    ops.clear_routes()
+    out = f(jax.device_put(jnp.asarray(table),
+                           NamedSharding(mesh, P(SHARD_AXIS, None))),
+            jnp.asarray(ids), jnp.asarray(deltas))
+    return np.asarray(out), ops.routes_traced()
+
+
+MEAN_MESHES = [(1, 1), (1, 2), (2, 2)]  # one shard, two, two x data axis
+
+
+@pytest.mark.parametrize("D,S", MEAN_MESHES)
+def test_push_mean_large_table_takes_the_row_branch(devices8, monkeypatch,
+                                                    D, S):
+    """A table large against its payload (``[65536, 64]``, 96 ids a
+    worker): the per-id mean by the row branch, chosen by shape alone, is
+    the accumulator branch's table to 1e-6 and the float64 oracle's."""
+    R, dim = 65_536, 64
+    table, ids, deltas, want = _mean_push_case(D, S, R, dim)
+    got, log = _push_on_mesh(devices8, D, S, table, ids, deltas,
+                             combine="mean")
+    rps, B = rows_per_shard(R, S), ids.shape[0]
+    assert log[0] == ops.Route("push", "push.mean_rows", rps, dim, B,
+                               False, "")
+    assert [r.route for r in log[1:]] == ["scatter_add.xla"]
+    assert log[1].dim == dim  # the table itself, no count column
+    monkeypatch.setattr(ops, "MEAN_ROWS_TABLE_RATIO", float("inf"))
+    dense, log = _push_on_mesh(devices8, D, S, table, ids, deltas,
+                               combine="mean")
+    assert [(r.route, r.dim, r.reason) for r in log] == [
+        ("push.mean_dense", dim, "small_table"),
+        ("scatter_add.xla", dim + 1, log[1].reason)]
+    scale = np.abs(want).max()
+    assert np.abs(got - dense).max() <= 1e-6 * scale
+    assert np.abs(got - want).max() <= 1e-6 * scale
+    assert np.abs(got - table).max() > 0.1  # something was pushed
+
+
+def test_push_mean_rows_sum_a_hot_id_from_zero(devices8):
+    """The row branch adds ONE combined row a touched id: 976 pushes of
+    1e-3 onto a table value near 1 land within an ulp or two of the
+    float64 mean, as on the accumulator branch. Scattering the scaled rows
+    straight into the table rounds each addend at the table's magnitude
+    (ten times this gap here; 12-20x the gap on the chip, PR 28)."""
+    R, dim, B = 131_072, 8, 1_024
+    rng = np.random.default_rng(4)
+    table = (1.0 + rng.random((R, dim))).astype(np.float32)
+    ids = np.full(B, 77, np.int32)
+    ids[:48] = rng.integers(0, R, 48)
+    deltas = rng.normal(1e-3, 1e-3, (B, dim)).astype(np.float32)
+    got, log = _push_on_mesh(devices8, 1, 1, table, ids, deltas,
+                             combine="mean")
+    assert log[0].route == "push.mean_rows"
+    want = table[77] + deltas[ids == 77].astype(np.float64).mean(axis=0)
+    assert np.abs(got[77] - want).max() <= 2.0 ** -22  # two ulps at 1-2
+
+
+@pytest.mark.parametrize("D,S", MEAN_MESHES)
+def test_push_mean_small_table_keeps_the_accumulator(devices8, D, S):
+    """The same push into a table the payload dwarfs (MF's movie table
+    under a step's ratings): ``push.mean_dense`` / ``small_table``, the
+    count riding the one scatter as a column."""
+    R, dim = 64, 64
+    table, ids, deltas, want = _mean_push_case(D, S, R, dim)
+    got, log = _push_on_mesh(devices8, D, S, table, ids, deltas,
+                             combine="mean")
+    assert log[0] == ops.Route("push", "push.mean_dense",
+                               rows_per_shard(R, S), dim, ids.shape[0],
+                               False, "small_table")
+    assert [(r.route, r.dim) for r in log[1:]] == [
+        ("scatter_add.xla", dim + 1)]
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("case,logged", [
+    ("apply_fn", [("push.mean_dense", "fold")]),
+    ("bf16", [("push.mean_dense", "dtype")]),
+    ("callable", []),
+    ("max", []),
+])
+def test_push_mean_row_branch_is_for_the_additive_mean_alone(devices8, case,
+                                                             logged):
+    """On a table past the constant: a non-additive fold, a table narrower
+    than the accumulate dtype, a callable combine and an extremum keep the
+    accumulator (no ``push.mean_rows`` in the log), with their answers."""
+    R, dim, S = 65_536, 8, 2
+    table, ids, deltas, want = _mean_push_case(1, S, R, dim)
+    kw = {"apply_fn": {"combine": "mean",
+                       "apply_fn": lambda rows, delta: rows + delta},
+          "bf16": {"combine": "mean"},
+          "callable": {"combine": lambda s, c: s / jnp.maximum(c, 1)[:, None]},
+          "max": {"combine": "max"}}[case]
+    if case == "bf16":
+        table = np.asarray(jnp.asarray(table, jnp.bfloat16))
+    got, log = _push_on_mesh(devices8, 1, S, table, ids, deltas, **kw)
+    assert [(r.route, r.reason) for r in log if r.op == "push"] == logged
+    assert "push.mean_rows" not in [r.route for r in log]
+    if case == "max":
+        return
+    if case == "bf16":
+        # Duplicates summed in f32, ONE rounding into the bf16 row.
+        assert got.dtype == jnp.bfloat16
+        np.testing.assert_allclose(got.astype(np.float64), want,
+                                   rtol=0, atol=2.0 ** -7 * np.abs(want).max())
+    else:
+        np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
 
 
 def test_server_logic_swap_recompiles(devices8):

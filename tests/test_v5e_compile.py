@@ -183,13 +183,15 @@ def test_w2v_epoch_program_fits_and_names_its_table_sized_work(
         topo, monkeypatch):
     """``w2v-1bw.epochs``'s epoch program at the cell's own size (two
     ``[1115011, 300]`` tables, blocks of 8,192 tokens) for one described
-    chip: it fits beside the runner's copy of the state (temporaries under
-    8 GB of 16), both tables take the plain XLA routes for reason
-    ``shape``, and the mean-combine's zero fill of the ``[rows, 301]``
-    accumulator (1.7 GB written a table a step) keeps the ``fps.combine``
-    scope in the COMPILED text. A broadcast of a literal zero lost it: the
-    TPU pipeline re-made it under the loop body's name, and a sixth of the
-    step ran under no name (chip run, PR 27); no CPU test can see that."""
+    chip. Both mean pushes take the row branch (``push.mean_rows``): the
+    only table-sized work left in the loop body is the two scatter-adds
+    into the tables themselves, in place on the carry; no ``[rows, 301]``
+    accumulator anywhere, no copy, add or fill of a table a step. The
+    counts (two sorts of the pushed ids) and the multiply keep
+    ``fps.combine`` in the COMPILED text and make nothing of the table's
+    length; temporaries under 4 GB of 16 (8 with the accumulators,
+    PR 27). No CPU test can see a copy XLA puts round a scatter into a
+    live table."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from fps_tpu.models.word2vec import (
@@ -223,8 +225,58 @@ def test_w2v_epoch_program_fits_and_names_its_table_sized_work(
         tables, (), iargs, jnp.int32(0), key).compile()
     assert [(r.route, r.dim, r.reason) for r in ops.routes_traced()] == [
         ("gather.xla", 300, "shape"), ("gather.xla", 300, "shape"),
-        ("scatter_add.xla", 301, "shape"), ("scatter_add.xla", 301, "shape")]
-    assert compiled.memory_analysis().temp_size_in_bytes < 8 << 30
-    fills = [line for line in compiled.as_text().splitlines()
-             if re.search(rf"= f32\[{V},{D + 1}\]\S* broadcast\(", line)]
-    assert fills and all("fps.push/fps.combine/" in f for f in fills), fills
+        ("push.mean_rows", 300, ""), ("scatter_add.xla", 300, "shape"),
+        ("push.mean_rows", 300, ""), ("scatter_add.xla", 300, "shape")]
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 << 30
+    text = compiled.as_text()
+    assert f"f32[{V},{D + 1}]" not in text
+    # The loop body: the computation the epoch's `while` names as its body.
+    (body,) = set(re.findall(r" while\(.*body=(%[\w.\-]+)", text))
+    start = text.index(f"\n{body} (")
+    loop = text[start:text.index("\n}\n", start)].splitlines()
+    sized = [ln for ln in loop
+             if (m := re.search(rf"= f32\[{V},{D}\]\S* ([\w\-]+)\(", ln))
+             and m.group(1) != "get-tuple-element"]
+    assert len(sized) == 2 and all(
+        "/fps.ops/scatter_add.xla/scatter-add" in ln and " fusion(" in ln
+        for ln in sized), sized
+    combine = [ln for ln in text.splitlines()
+               if "fps.push/fps.combine/" in ln]
+    assert any("/sort" in ln for ln in combine), combine
+    assert any("/mul" in ln for ln in combine), combine
+    assert not [ln for ln in combine if f"[{V}" in ln.split(" fusion(")[0]]
+
+
+def test_mf_epoch_step_keeps_the_accumulator_for_its_small_table(topo):
+    """The MF twin: ``mf-netflix.epochs``'s step pushes 32,768 ratings'
+    deltas into ``[17770, 10]`` (9.1 MB of accumulator under 16.8 MB of
+    payload): ``push.mean_dense`` / ``small_table``, the count riding the
+    one scatter as an eleventh column, as before PR 28."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from fps_tpu.models.matrix_factorization import MFConfig, online_mf
+    from fps_tpu.parallel.mesh import make_ps_mesh
+
+    B, rank = NETFLIX[2], NETFLIX[1]
+    mesh = make_ps_mesh(num_shards=1, devices=list(topo.devices)[:1])
+    trainer, _ = online_mf(
+        mesh, MFConfig(num_users=NETFLIX[0], num_items=17_770, rank=rank),
+        combine="mean")
+
+    def shape(s, dtype, spec=P()):
+        return jax.ShapeDtypeStruct(s, dtype,
+                                    sharding=NamedSharding(mesh, spec))
+
+    workers = P(None, ("data", "shard"))
+    tables = {"item_factors": shape((17_770, rank), jnp.float32,
+                                    P("shard", None))}
+    local = shape((NETFLIX[0], rank), jnp.float32, P(("data", "shard")))
+    batches = {k: shape((2, B), d, workers) for k, d in (
+        ("user", jnp.int32), ("item", jnp.int32), ("rating", jnp.float32),
+        ("weight", jnp.float32))}
+    ops.clear_routes()
+    trainer._build_chunk_fn("sync").lower(
+        tables, local, batches, shape((), jax.random.key(0).dtype))
+    pushes = [r for r in ops.routes_traced() if r.op == "push"]
+    assert pushes == [ops.Route("push", "push.mean_dense", 17_770, rank, B,
+                                False, "small_table")], pushes

@@ -7,6 +7,11 @@ dispatch dedup; timing is fenced by a host read. Reports us per scatter.
 route (``fps_tpu.ops``: ``gather.xla_packed`` / ``scatter_add.xla_packed``)
 over table rows x row width at uniform ids — the sweep that set
 ``ops.XLA_VMEM_TABLE_BYTES`` / ``XLA_PACKED_DIMS`` / ``XLA_PACKED_MIN_IDS``.
+
+``mean`` arm: the store's per-id mean push (``fps_tpu.core.store.push``,
+``combine="mean"``) by its accumulator branch against its row branch over
+rows x width x ids at Zipf(1.0) ids — the sweep that set
+``ops.MEAN_ROWS_TABLE_RATIO`` — and the ways to count an id's pushes.
 """
 
 import os
@@ -255,6 +260,169 @@ def rows_sweep(args):
             fh.flush()
 
 
+MEAN_R = (17_770, 32_768, 65_536, 131_072, 262_144, 1_115_011)
+MEAN_D = (10, 64, 300)
+MEAN_B = (8_192, 32_768)
+W2V_1BW = ((1_115_011, 300, 8_197), (1_115_011, 300, 49_182))
+
+
+def _zipf_ids(rng, R, shape, alpha=1.0):
+    pop = 1.0 / np.arange(1, R + 1) ** alpha
+    cdf = np.cumsum(pop / pop.sum())
+    return np.minimum(np.searchsorted(cdf, rng.random(shape)),
+                      R - 1).astype(np.int32)
+
+
+def _mean_case(R, D, B):
+    """A table, ``T`` steps of Zipf(1.0) ids and deltas (at most ~1 GB of
+    tiled deltas), and the runner of one program over them: us a step of
+    ``op(table, ids, deltas) -> table`` with the table a loop carry."""
+    rng = np.random.default_rng(R * 131 + D + B)
+    T = int(max(4, min(64, (1 << 30) // (B * -(-D // 128) * 512))))
+    tab = jnp.asarray(rng.normal(0, 0.1, (R, D)), jnp.float32)
+    ids = jnp.asarray(_zipf_ids(rng, R, (T, B)))
+    deltas = jnp.asarray(rng.normal(0, 1e-2, (T, B, D)), jnp.float32)
+
+    def us_a_step(op):
+        f = jax.jit(lambda t, i, d: lax.scan(
+            lambda t, x: (op(t, *x), None), t, (i, d))[0])
+        r = f(tab, ids, deltas)
+        np.asarray(r[0, 0])
+        best = 1e9
+        for _ in range(2):
+            t0 = time.perf_counter()
+            r = f(tab, ids, deltas)
+            np.asarray(r[0, 0])
+            best = min(best, time.perf_counter() - t0)
+        return round(best / T * 1e6, 1), r
+
+    return us_a_step
+
+
+def _store_mean_push(ratio):
+    """``store.push(combine="mean")`` on a one-shard mesh with
+    ``ops.MEAN_ROWS_TABLE_RATIO`` set to ``ratio`` while it is traced:
+    0 takes the row branch whatever the shape, inf the accumulator."""
+    from jax.sharding import PartitionSpec as P
+
+    import fps_tpu.ops as ops
+    from fps_tpu.core import store
+    from fps_tpu.parallel.mesh import SHARD_AXIS, make_ps_mesh
+
+    mesh = make_ps_mesh(num_shards=1, devices=jax.devices()[:1])
+
+    def op(t, i, d):
+        ops.MEAN_ROWS_TABLE_RATIO = ratio
+        return jax.shard_map(
+            lambda t, i, d: store.push(t, i, d, num_shards=1, data_axis=None,
+                                       combine="mean"),
+            mesh=mesh, in_specs=(P(SHARD_AXIS, None), P(), P()),
+            out_specs=P(SHARD_AXIS, None), check_vma=False)(t, i, d)
+    return op
+
+
+def _both_branches(us_a_step):
+    """``(accumulator us, its table, rows us, its table)`` of one case."""
+    import fps_tpu.ops as ops
+
+    keep = ops.MEAN_ROWS_TABLE_RATIO
+    try:
+        return (*us_a_step(_store_mean_push(float("inf"))),
+                *us_a_step(_store_mean_push(0.0)))
+    finally:
+        ops.MEAN_ROWS_TABLE_RATIO = keep
+
+
+def _gap(got, want):
+    return float(jnp.max(jnp.abs(got - want)) / jnp.max(jnp.abs(want)))
+
+
+def mean_point(R, D, B):
+    """us a step of the mean push's two branches (``push.mean_dense``: the
+    ``(R, D + 1)`` accumulator; ``push.mean_rows``: the pushed rows
+    normalised, summed by id and scattered into the table once) and the
+    largest gap of their tables over the largest value."""
+    from fps_tpu.core.store import _mean_push_ratio
+
+    dense_us, want, rows_us, got = _both_branches(_mean_case(R, D, B))
+    return {"rows": R, "dim": D, "ids": B,
+            "ratio": round(_mean_push_ratio(R, D, B, jnp.float32), 2),
+            "mean_dense_us": dense_us, "mean_rows_us": rows_us,
+            "gap": _gap(got, want)}
+
+
+def _counts_vector(idx, R):
+    """(a) a count VECTOR of the table's rows, scattered into and read
+    back at the ids: O(B) + O(R) scalars."""
+    cnt = jnp.zeros((R,), jnp.float32).at[idx].add(1.0, mode="drop")
+    return jnp.take(cnt, idx, mode="fill", fill_value=0)
+
+
+def _counts_runs(idx, R):
+    """(b) sort the ids, count by run length, sort back: [B]-sized arrays
+    only. ``store.push``'s (``core/store._id_runs``)."""
+    from fps_tpu.core.store import _id_runs
+
+    return _id_runs(idx, R)[0].astype(jnp.float32)
+
+
+def counts_point(R, D, B):
+    """us a step at one shape of: the bare scatter-add ("sum": what no
+    mean can beat); the accumulator branch; the scaled rows scattered
+    STRAIGHT into the table under each way to count an id's pushes
+    (``*_alone_us``: the counts alone, chained through one table element);
+    and the row branch as ``store.push`` has it (the rows of an id summed
+    in a (B, D) buffer first). ``*_gap``: largest gap to the accumulator
+    branch's table over its largest value."""
+    import fps_tpu.ops as ops
+
+    us_a_step = _mean_case(R, D, B)
+    out = {"rows": R, "dim": D, "ids": B}
+    out["sum_us"], _ = us_a_step(ops.scatter_add)
+    (out["mean_dense_us"], want,
+     out["mean_rows_us"], got) = _both_branches(us_a_step)
+    out["mean_rows_gap"] = _gap(got, want)
+    for name, counts in (("vector", _counts_vector), ("runs", _counts_runs)):
+        def push(t, i, d, counts=counts):
+            inv = 1.0 / jnp.maximum(counts(i, R), 1.0)
+            return ops.scatter_add(t, i, d * inv[:, None])
+
+        def alone(t, i, d, counts=counts):
+            i = i.at[0].set(t[0, 0].astype(jnp.int32) % 2)
+            return lax.dynamic_update_slice(
+                t, jnp.sum(counts(i, R))[None, None] * 1e-9, (0, 0))
+
+        out[f"{name}_straight_us"], r = us_a_step(push)
+        out[f"{name}_alone_us"], _ = us_a_step(alone)
+        out[f"{name}_straight_gap"] = _gap(r, want)
+    return out
+
+
+def mean_sweep(args):
+    """``mean``: both branches of the mean push over rows x width x ids at
+    Zipf(1.0) ids (the sweep that set ``ops.MEAN_ROWS_TABLE_RATIO``), and
+    ``w2v-1bw``'s two shapes. ``mean counts``: the ways to count, and
+    both branches, at those two shapes alone. One JSON line a point, all
+    in ``chiprun_out/bench_scatter_mean.jsonl``."""
+    import json
+
+    if args == ["counts"]:
+        points = [(counts_point, p) for p in W2V_1BW]
+    else:
+        points = [(mean_point, (R, D, B))
+                  for D in MEAN_D for B in MEAN_B for R in MEAN_R]
+        points += [(mean_point, p) for p in W2V_1BW]
+    os.makedirs("chiprun_out", exist_ok=True)
+    with open("chiprun_out/bench_scatter_mean.jsonl", "a") as fh:
+        fh.write(json.dumps({"device": jax.devices()[0].device_kind,
+                             "platform": jax.default_backend()}) + "\n")
+        for fn, p in points:
+            line = json.dumps(fn(*p))
+            print(line, flush=True)
+            fh.write(line + "\n")
+            fh.flush()
+
+
 if __name__ == "__main__":
     import sys
 
@@ -266,11 +434,14 @@ if __name__ == "__main__":
         dim1_shapes()
     elif sys.argv[1] == "rows":
         rows_sweep(sys.argv[2:])
+    elif sys.argv[1] == "mean":
+        mean_sweep(sys.argv[2:])
     else:
         raise SystemExit(
             f"unknown args {sys.argv[1:]!r} — usage: bench_scatter.py "
-            "[sweep|dim1|rows [quick]]  (no args = full workload-shape "
-            "bench; 'sweep' = small-R crossover sweep; 'dim1' = "
-            "scalar-table PA shape; 'rows' = plain XLA against the "
-            "lane-packed XLA route over table rows x row width)"
+            "[sweep|dim1|rows [quick]|mean [counts]]  (no args = full "
+            "workload-shape bench; 'sweep' = small-R crossover sweep; "
+            "'dim1' = scalar-table PA shape; 'rows' = plain XLA against the "
+            "lane-packed XLA route over table rows x row width; 'mean' = "
+            "the mean push's accumulator against its row branch)"
         )
