@@ -549,29 +549,17 @@ class Trainer:
     # -- device-side bodies ----------------------------------------------
 
     def _resolve_hot_rows(self, spec) -> int:
-        """LOCAL hot-row count for a table's push scatter.
-
-        ``hot_ids="auto"`` routes the WHOLE shard slice through the packed
-        MXU scatter when it is thinner than the measured crossover
-        (:func:`fps_tpu.ops.packed_crossover_rows`) — the many-shard
-        regime; on fat shards it resolves to 0 (plain XLA scatter, exact).
-        An int is the NuPS-style global head count: global hot ids [0, H)
-        sit in local rows [0, ceil(H/S)) under the owner-major cyclic
-        layout.
-        """
-        from fps_tpu.core.store import rows_per_shard
-
-        if spec.hot_ids == "auto":
-            rps = rows_per_shard(spec.num_ids, self.num_shards)
-            return rps if rps <= ops.packed_crossover_rows(spec.dim) else 0
-        if isinstance(spec.hot_ids, str):
+        """LOCAL rows of a table's certified head: global head ids
+        ``[0, H)`` (``spec.hot_ids``) sit in local rows ``[0, ceil(H/S))``
+        under the owner-major cyclic layout."""
+        if not isinstance(spec.hot_ids, int):
             # Fail at the right altitude — inside the jitted push this
             # would surface as a cryptic TypeError on a unary minus.
             raise ValueError(
                 f"table {spec.name!r}: hot_ids={spec.hot_ids!r} — "
-                "expected an int or the literal 'auto'"
+                "expected an int"
             )
-        return -(-spec.hot_ids // self.num_shards) if spec.hot_ids else 0
+        return -(-spec.hot_ids // self.num_shards)
 
     def _resolve_dense(self, spec) -> bool:
         """Dense-collective route for this table on this mesh (see

@@ -387,8 +387,8 @@ def compact_cold(
     pos = jnp.cumsum(live.astype(jnp.int32)) - 1
     pos = jnp.where(live & (pos < budget), pos, -1)
     # Negative .at[] indices WRAP (numpy semantics) — map masked slots to
-    # ``budget`` so mode="drop" actually drops them (the
-    # ops._xla_scatter_add pattern).
+    # ``budget`` so mode="drop" actually drops them (ops.scatter_add's
+    # xla step).
     safe = jnp.where(pos >= 0, pos, budget)
     lane_ids = jnp.full((budget,), -1, ids.dtype).at[safe].set(
         ids, mode="drop")
@@ -451,7 +451,7 @@ def accumulate_hot(
         ones = jnp.ones(hot_ids_arr.shape, delta_buf.dtype)[:, None]
         filled = jnp.concatenate([vals, ones], axis=1)
         # Negative .at[] indices wrap — map the masked -1 slots out of
-        # range so mode="drop" drops them (ops._xla_scatter_add pattern).
+        # range so mode="drop" drops them (ops.scatter_add's xla step).
         safe = jnp.where(hot_ids_arr >= 0, hot_ids_arr,
                          delta_buf.shape[0])
         if combine == "max":
@@ -858,10 +858,12 @@ def push(
           learning-rate-by-frequency, ...). Untouched rows (count 0) are
           masked out after the callable, so it need not special-case
           them.
-      hot_rows: number of LOCAL leading rows of this shard treated as
-        write-hot (see :func:`fps_tpu.ops.scatter_add`); under the
-        owner-major cyclic layout, global hot ids ``[0, H)`` land exactly
-        in local rows ``[0, ceil(H / num_shards))`` on every shard.
+      hot_rows, head_prefix: the ingest layer's guarantee that
+        ``ids[:head_prefix]`` lie in the LOCAL leading ``hot_rows`` rows
+        (see :func:`fps_tpu.ops.scatter_add`); under the owner-major
+        cyclic layout, global head ids ``[0, H)`` land exactly in local
+        rows ``[0, ceil(H / num_shards))`` on every shard. ``hot_rows``
+        without ``head_prefix`` changes nothing.
       dense: dense-reduce route for SMALL tables with the ADDITIVE fold:
         each worker scatters its OWN ``B`` deltas into a table-shaped
         zeros buffer (physical layout); an ``all_to_all`` of per-shard
@@ -958,8 +960,7 @@ def push(
                 scaled = masked.astype(acc_dt) * (
                     1.0 / n.astype(acc_dt))[:, None]
                 combined = jnp.zeros((B, dim), acc_dt).at[slot].add(scaled)
-            return ops.scatter_add(local_shard, slot_idx, combined,
-                                   hot_rows=hot_rows)
+            return ops.scatter_add(local_shard, slot_idx, combined)
     if combine in ("max", "min"):
         # Extremum fold: ONE scatter-max/min of the raw deltas (duplicates
         # combine natively, no serialized pairwise fold) with the touched
@@ -1005,7 +1006,7 @@ def push(
             zeros = jnp.broadcast_to(
                 lax.optimization_barrier(jnp.zeros((), acc_dt)),
                 (rps, dim + 1))
-        acc = ops.scatter_add(zeros, local_idx, withcnt, hot_rows=hot_rows)
+        acc = ops.scatter_add(zeros, local_idx, withcnt)
         with jax.named_scope(COMBINE_SCOPE):
             combined, counts = acc[:, :dim], acc[:, dim]
             if combine == "mean":
@@ -1045,25 +1046,14 @@ class TableSpec:
     dim: int
     init_fn: Callable[[Array, Array], Array] = None  # (key, ids) -> values
     dtype: Any = jnp.float32
-    # Write-hot routing for push scatters (:func:`fps_tpu.ops.scatter_add`):
-    #   * int H > 0 — NuPS-style split: the leading H GLOBAL ids ride the
-    #     lane-packed MXU contraction, the tail keeps the XLA scatter.
-    #     Meaningful when ids are frequency-ranked (hottest first) — the
-    #     shipped loaders and synthetic generators lay ids out that way —
-    #     but drop/duplicate semantics hold for any distribution; a wrong
-    #     guess costs only MXU work, capped by SCATTER_FLOP_BUDGET.
-    #   * "auto" — whole-shard packed routing whenever the per-shard row
-    #     slice is below the MEASURED single-chip crossover
-    #     (:func:`fps_tpu.ops.packed_crossover_rows`, from
-    #     ``tools/bench_scatter.py sweep``) — i.e. enabled exactly in the
-    #     many-shard regime it wins in, off on fat single-chip shards.
-    # Default 0 (pure XLA): the packed path carries f32 deltas as bf16
-    # hi+lo (~16 mantissa bits) and would break bit-reproducibility across
-    # shard counts, so it is opt-in. (f32 SCALAR tables are the exception:
-    # they auto-route to the dim-1 kernels on TPU — see
-    # ``fps_tpu.ops._route_dim1`` for the precise invariant scope and the
-    # xla-backend escape hatch.)
-    hot_ids: int | str = 0
+    # The table's certified frequency head: an int H > 0 declares that
+    # the leading H GLOBAL ids are the hottest (the shipped loaders and
+    # synthetic generators lay ids out that way), which is what a worker's
+    # ``head_prefix`` guarantee refers to (``WorkerLogic.head_prefix``:
+    # the batch's leading ids lie in ``[0, H) ∪ {-1}``, and ride a
+    # head-only kernel — :func:`fps_tpu.ops.scatter_add`). Without such a
+    # guarantee H selects nothing. Default 0: no head declared.
+    hot_ids: int = 0
     # Dense collective route (replicate-on-read / dense-reduce-on-write,
     # :func:`pull`/:func:`push` ``dense=``): per-worker row transactions
     # drop from the gathered route's O(W * B) per shard to O(B), at the

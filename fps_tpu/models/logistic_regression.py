@@ -43,10 +43,6 @@ class LogRegConfig:
     # the ServerLogic fold is general enough to host optimizer state.
     optimizer: str = "sgd"
     adagrad_eps: float = 1e-6
-    # Feature ids [0, hot_features) are write-hot (NuPS-style hot/cold push
-    # split, fps_tpu.ops.scatter_add); effective with frequency-ranked ids
-    # and a small per-shard table slice. Default 0 — see MFConfig.hot_items.
-    hot_features: int = 0
     # FIXED-SLOT dense head: the first ``dense_features`` batch slots carry
     # feature id j at slot j in EVERY example (value 0 = inactive), the
     # Criteo loader's layout for the 13 numeric columns. The worker then
@@ -179,7 +175,7 @@ class LogisticRegressionWorker(WorkerLogic):
 def make_store(mesh, cfg: LogRegConfig) -> ParamStore:
     spec = TableSpec(
         name=WEIGHT_TABLE, num_ids=cfg.num_features, dim=cfg.table_width,
-        dtype=cfg.dtype, hot_ids=min(cfg.hot_features, cfg.num_features),
+        dtype=cfg.dtype,
     ).zeros_init()
     return ParamStore(mesh, [spec])
 

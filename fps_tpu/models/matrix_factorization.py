@@ -56,14 +56,6 @@ class MFConfig:
     reg: float = 0.01
     init_min: float = -0.1
     init_max: float = 0.1
-    # Item ids [0, hot_items) are treated as write-hot (NuPS-style hot/cold
-    # push splitting, see fps_tpu.ops.scatter_add), moving the Zipf head's
-    # pushes onto the MXU when item ids are popularity-ranked. Exact for
-    # any id order. Default 0: dedup-safe on-chip measurement shows XLA's
-    # scatter cost is ~flat in duplication on a single chip, so the split
-    # only pays off when the per-shard table slice is small (large shard
-    # axis) — enable it there.
-    hot_items: int = 0
     # Negative sampling of unrated items (the reference MF's optional knob,
     # SURVEY.md §2 #8): each rating additionally samples this many random
     # items, treated as pseudo-ratings of ``negative_target`` with weight
@@ -236,7 +228,6 @@ def make_store(mesh, cfg: MFConfig) -> ParamStore:
         dim=cfg.rank,
         init_fn=ranged_uniform_init(cfg.init_min, cfg.init_max, cfg.rank, cfg.dtype),
         dtype=cfg.dtype,
-        hot_ids=min(cfg.hot_items, cfg.num_items),
     )
     return ParamStore(mesh, [spec])
 

@@ -60,9 +60,9 @@ class PAConfig:
     variant: str = "PA-I"  # "PA" | "PA-I" | "PA-II"
     C: float = 1.0
     batch_average: bool = True
-    # Feature ids [0, hot_features) are write-hot (NuPS-style hot/cold push
-    # split, fps_tpu.ops.scatter_add); effective with frequency-ranked ids
-    # and a small per-shard table slice. Default 0 — see MFConfig.hot_items.
+    # Feature ids [0, hot_features) are the table's frequency head
+    # (``TableSpec.hot_ids``): what ``head_prefix_cols`` below refers to,
+    # and nothing without it. Default 0: no head declared.
     hot_features: int = 0
     # Head-prefix routing (single-device meshes): set together with
     # ``hot_features = H`` after laying the dataset out with

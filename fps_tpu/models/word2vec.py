@@ -109,17 +109,6 @@ class W2VConfig:
     learning_rate: float = 0.025
     subsample_t: float | None = 1e-4  # None disables frequent-word subsampling
     neg_power: float = 0.75
-    # Word ids [0, hot_words) are write-hot (NuPS-style hot/cold push split,
-    # fps_tpu.ops.scatter_add); vocabulary ids are frequency-ranked by every
-    # loader (most_common order), so the Zipf head sits exactly there.
-    # "auto" routes the WHOLE shard slice through the packed MXU scatter
-    # when the mesh leaves it thinner than the measured crossover
-    # (fps_tpu.ops.packed_crossover_rows) — the many-shard regime; w2v's
-    # mean-combine push always takes the gathered route, so this is the
-    # shipped family where "auto" actually fires (vocab 50k on 32+ shards,
-    # or proportionally smaller vocabs — see dryrun_multichip).
-    # Default 0 — see MFConfig.hot_items for when enabling it pays.
-    hot_words: int | str = 0
     # Block-mode only (Word2VecBlockWorker): positions share one set of K
     # negatives per group of this many tokens. Default 1 = per-POSITION
     # negatives (shared only across a position's ~2*window instances) —
@@ -372,28 +361,16 @@ class Word2VecBlockWorker(WorkerLogic, _AliasNegativeSampler):
 
 def make_store(mesh, cfg: W2VConfig) -> ParamStore:
     half = 0.5 / cfg.dim
-    hot = cfg.hot_words
-    if isinstance(hot, str):
-        if hot != "auto":
-            # Same altitude contract as driver._resolve_hot_rows: a typo'd
-            # literal must not surface as a TypeError inside min().
-            raise ValueError(
-                f"hot_words={hot!r} — expected an int or the literal 'auto'"
-            )
-    else:
-        hot = min(hot, cfg.vocab_size)
     in_spec = TableSpec(
         name=IN_TABLE,
         num_ids=cfg.vocab_size,
         dim=cfg.dim,
         init_fn=ranged_uniform_init(-half, half, cfg.dim, cfg.dtype),
         dtype=cfg.dtype,
-        hot_ids=hot,
     )
     # word2vec initializes the output matrix to zeros.
     out_spec = TableSpec(
         name=OUT_TABLE, num_ids=cfg.vocab_size, dim=cfg.dim, dtype=cfg.dtype,
-        hot_ids=hot,
     ).zeros_init()
     return ParamStore(mesh, [in_spec, out_spec])
 

@@ -40,9 +40,8 @@ table with what opens each):
 device scope               ``fps.ingest`` ``fps.pull`` ``fps.compute``
 (``jax.named_scope``,      ``fps.push`` ``fps.metrics`` and, inside pull
 step bodies ONLY: a        and push, ``fps.ops/<op>.<route>`` with route
-reader counts steps by     one of ``gather.dim1_head|dim1|onehot|xla``,
-the ops under ``fps.*``)   ``scatter_add.dim1_head|dim1|packed|
-                           packed_head|onehot|xla``
+reader counts steps by     one of ``dim1_head|dim1|xla_packed|xla`` under
+the ops under ``fps.*``)   ``gather.`` and ``scatter_add.`` alike
 once a call / a chunk      ``ingest.pack`` ``ingest.tbuf`` ``ingest.perm``
 (no ``fps.`` prefix)       ``ingest.chunk``
 host span                  ``run_indexed`` ``fit_stream`` ``run_megastep``

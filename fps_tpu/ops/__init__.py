@@ -1,35 +1,34 @@
-"""Sparse hot-path ops with pluggable backends (XLA + Pallas TPU kernels).
+"""Sparse hot-path ops: the store's two row ops and the routes they take.
 
 The store's pull/push collectives bottom out in two local ops per shard:
 row **gather** (pull answers) and duplicate-combining **scatter-add** (push
-folds). XLA's TPU scatter serializes colliding updates — per-row-transaction
-cost that explodes on Zipfian-hot batches (measured on-chip, dedup-safe
-fencing: 828us for a 32k-id push with 62% duplicates into a (26744, 11)
-table vs ~280us at 0% duplicates). The framework's answer is NuPS-style
-**hot/cold splitting** (:func:`scatter_add` with ``hot_rows``): pushes to
-the frequency-ranked head rows ride a dense lane-packed one-hot MXU
-contraction (:func:`fps_tpu.ops.pallas_kernels.scatter_add_packed_pallas`)
-with zero serialization, while the low-duplication tail keeps the XLA
-scatter. Correctness never depends on the hotness guess — a mis-ranked
-table only wastes MXU work, and that waste is capped: a ``hot_rows`` whose
-head contraction would exceed :data:`SCATTER_FLOP_BUDGET` falls back to
-the plain XLA scatter.
+folds). :func:`gather_rows` and :func:`scatter_add` are one chain each, the
+same four steps in the same order, every step a predicate over what the
+call can observe (platform, backend, rows, width, ids, dtype, and the
+ingest layer's ``head_prefix`` guarantee):
+
+1. ``dim1_head`` — a scalar table whose leading ids the ingest layer
+   certified inside the head ``[0, hot_rows)``: the prefix rides a dim-1
+   Pallas kernel over the head slice alone, the tail goes down the chain
+   again (:func:`_route_head_prefix`);
+2. ``dim1`` — a scalar table of at most :data:`DIM1_MAX_ROWS` rows moving
+   at least :data:`DIM1_MIN_BATCH` ids: the dim-1 Pallas kernels over the
+   whole table (:func:`_route_dim1`);
+3. ``xla_packed`` — a narrow-row table too large for XLA's VMEM regime:
+   the same XLA op on the table's lane-packed form
+   (:func:`_route_xla_packed`, :data:`XLA_VMEM_TABLE_BYTES`);
+4. ``xla`` — the plain XLA op, with the reason the others were passed over.
 
 Backend selection:
 
 * ``set_backend("auto" | "xla" | "pallas")`` or env ``FPS_TPU_OPS`` at
   import time. Default ``"auto"``.
-* ``"auto"`` — on TPU, XLA everywhere except the hot/cold split (the only
-  Pallas route that beats XLA at realistic duplication on real hardware)
-  and the scalar-table kernels; a narrow-row table too large for XLA's
-  VMEM regime takes the lane-packed XLA route (``gather.xla_packed`` /
-  ``scatter_add.xla_packed``: :data:`XLA_VMEM_TABLE_BYTES`); off TPU, pure
-  XLA.
+* ``"auto"`` — on TPU, the chain above; off TPU, pure XLA.
 * ``"xla"`` — the PLAIN XLA ops everywhere (debugging / bit-exact baseline;
   the lane-packed XLA route is off too, so this is its A/B).
-* ``"pallas"`` — force the Pallas kernels (one-hot gather/scatter under
-  :data:`SCATTER_FLOP_BUDGET`, plus the hot/cold split); off TPU they run
-  in interpreter mode so the CPU-mesh test suite exercises them.
+* ``"pallas"`` — the chain above on any platform: off TPU the dim-1 kernels
+  run in interpreter mode, which is how the CPU-mesh test suite exercises
+  them.
 
 Names: every branch runs the kernel or XLA call it ends in under
 ``jax.named_scope("fps.ops")`` and, inside it, a scope naming the route
@@ -54,42 +53,12 @@ Array = jax.Array
 
 _BACKEND = os.environ.get("FPS_TPU_OPS", "auto").lower()
 
-# One-hot scatter cost ceiling (MXU flops per call) for the FORCED pallas
-# backend's full-table kernels. ~2e10 fl32 flops is ~0.2 ms on a v5e chip —
-# beyond that the serialization cost XLA's scatter pays is cheaper than the
-# dense indicator matmul.
-SCATTER_FLOP_BUDGET = 2e10
-
-
-def packed_crossover_rows(dim: int) -> int:
-    """Measured single-chip crossover: the lane-packed MXU scatter beats
-    XLA's scatter when the per-shard row count is at or below this (below
-    it the whole-shard one-hot contraction is cheaper than the per-row
-    -transaction scatter). From ``tools/bench_scatter.py sweep`` on a
-    v5 lite chip, B=32768, Zipf(0.8) ids:
-
-    ==== ======================= =======================
-    dim  packed wins through R=  packed loses from R=
-    ==== ======================= =======================
-    10   2048 (667 vs 702 us)    16384 (1222 vs 1092)
-    32   4096 (577 vs 663 us)     8192 ( 911 vs  684)
-    100  2048 (828 vs 829 us)     4096 (1394 vs 1091)
-    ==== ======================= =======================
-
-    Returned thresholds sit at the conservative (clear-win) edge. This is
-    the ``TableSpec.hot_ids="auto"`` policy: a large shard axis leaves
-    each shard a thin row slice, which is exactly the packed kernel's
-    regime — on one shard the shipped tables (26k-1M rows) stay on XLA.
-    """
-    return 4096 if 17 <= dim <= 48 else 2048
-
-
-# Every route the two ops can take; the scope under ``fps.ops`` and the
-# ``route`` of a log entry are ``<op>.<route>``.
+# Every route the two ops can take, in the order the chain tries them; the
+# scope under ``fps.ops`` and the ``route`` of a log entry are
+# ``<op>.<route>``.
 ROUTES = {
-    "gather": ("dim1_head", "dim1", "onehot", "xla_packed", "xla"),
-    "scatter_add": ("dim1_head", "dim1", "packed", "packed_head", "onehot",
-                    "xla_packed", "xla"),
+    "gather": ("dim1_head", "dim1", "xla_packed", "xla"),
+    "scatter_add": ("dim1_head", "dim1", "xla_packed", "xla"),
 }
 PALLAS_ROUTES = frozenset(
     f"{op}.{r}" for op, rs in ROUTES.items() for r in rs
@@ -108,7 +77,7 @@ class Route(NamedTuple):
     interpret: bool  # the Pallas kernel runs interpreted (off TPU)
     reason: str      # why a Pallas route, or the lane-packed XLA one, was
                      # passed over: "" (taken, or exact read asked for),
-                     # "f64", "flop_budget", "backend", "shape",
+                     # "f64", "backend", "shape",
                      # "vmem_fit" (the plain XLA op already runs in VMEM);
                      # of "push.mean_dense": "fold", "dtype", "small_table"
 
@@ -141,14 +110,9 @@ def log_route(op: str, route: str, rows: int, dim: int, ids: int,
 @contextlib.contextmanager
 def _routed(op: str, route: str, rows: int, dim: int, ids: int,
             reason: str = ""):
-    """Log the decision and open the route's scope round its own call."""
-    with _scope(log_route(op, route, rows, dim, ids, reason)):
-        yield
-
-
-@contextlib.contextmanager
-def _scope(name: str):
-    """``fps.ops/<name>``: the scope of one route's own call."""
+    """Log the decision and open ``fps.ops/<op>.<route>``, the route's
+    scope, round its own call."""
+    name = log_route(op, route, rows, dim, ids, reason)
     with jax.named_scope("fps.ops"), jax.named_scope(name):
         yield
 
@@ -217,7 +181,7 @@ def _use_pallas() -> tuple[bool, bool]:
 # logreg table stays correctly excluded — its full-table contraction is
 # MAC-bound at ~2x XLA's transaction cost. Reads
 # and duplicate sums carry the hi+lo bf16 contract (~16 mantissa bits) —
-# see scatter_add_packed_pallas — hence bit-exactness is not promised for
+# see fps_tpu.ops.pallas_kernels — hence bit-exactness is not promised for
 # routed shapes, neither across backends (CPU "auto" stays on XLA) nor
 # across SHARD COUNTS on TPU: the route predicate sees per-shard R and
 # the gathered W*B batch, both of which change with the mesh, so the
@@ -399,8 +363,8 @@ def _route_xla_packed(R: int, D: int, B: int, dtype) -> bool:
 
 
 def _xla_reason(R: int, D: int, dtype) -> str:
-    """Why a call that reached the plain XLA route took no other, when no
-    budget decided it: the dtype cannot ride the kernels' f32 / bf16-pair
+    """Why a call that reached the plain XLA route took no other: the
+    dtype cannot ride the kernels' f32 / bf16-pair
     arithmetic, the backend keeps every other route out, the table is
     already inside XLA's VMEM regime (so the lane-packed route has nothing
     to add), or no route serves the shape under this backend."""
@@ -520,9 +484,11 @@ def gather_rows(table: Array, ids: Array, *, hot_rows: int = 0,
     ``ceil(H/128)`` row tiles instead of ``ceil(R/128)``. Violating the
     guarantee silently reads zeros for the out-of-head ids (the drop
     contract), so callers must only pass prefixes the ingest layer
-    actually certified.
+    actually certified. ``hot_rows`` is the ``H`` of that guarantee and
+    nothing else: without ``head_prefix`` it changes nothing.
     """
     R, D = table.shape
+    B = ids.shape[0]
     interpret = _use_pallas()[1]
     if not exact and _route_head_prefix(R, D, head_prefix, hot_rows,
                                         table.dtype):
@@ -535,46 +501,18 @@ def gather_rows(table: Array, ids: Array, *, hot_rows: int = 0,
         # The tail opens its own scope BESIDE the head's, not under it.
         tail = gather_rows(table, ids[head_prefix:])
         return jnp.concatenate([head, tail], axis=0)
-    if not exact and _route_dim1(R, D, ids.shape[0], table.dtype):
+    if not exact and _route_dim1(R, D, B, table.dtype):
         from fps_tpu.ops.pallas_kernels import gather_rows_dim1_pallas
 
-        with _routed("gather", "dim1", R, D, ids.shape[0]):
+        with _routed("gather", "dim1", R, D, B):
             return gather_rows_dim1_pallas(table, ids, interpret=interpret)
-    if _route_xla_packed(R, D, ids.shape[0], table.dtype):
+    if _route_xla_packed(R, D, B, table.dtype):
         return _xla_packed_gather(table, ids)
-    # Forced-pallas only: XLA's gather is not collision-serialized, and
-    # dedup-safe on-chip measurement shows it matching or beating the
-    # one-hot kernel at the shipped workloads' shapes, so "auto" never
-    # routes WIDE gathers to Pallas (the dim-1 route above is measured).
-    onehot = not exact and _BACKEND == "pallas" and D >= 64
-    if onehot and R * ids.shape[0] * D <= SCATTER_FLOP_BUDGET:
-        from fps_tpu.ops.pallas_kernels import gather_rows_pallas
-
-        with _routed("gather", "onehot", R, D, ids.shape[0]):
-            return gather_rows_pallas(table, ids, interpret=interpret)
-    reason = ("" if exact else "flop_budget" if onehot
-              else _xla_reason(R, D, table.dtype))
-    with _routed("gather", "xla", R, D, ids.shape[0], reason):
+    reason = "" if exact else _xla_reason(R, D, table.dtype)
+    with _routed("gather", "xla", R, D, B, reason):
         in_range = (ids >= 0) & (ids < R)
         vals = jnp.take(table, jnp.where(in_range, ids, 0), axis=0)
         return jnp.where(in_range[:, None], vals, jnp.zeros_like(vals))
-
-
-def _xla_scatter_add(table: Array, ids: Array, deltas: Array,
-                     reason: str) -> Array:
-    """``table.at[ids].add(deltas)`` with drop semantics for ids ∉ [0, R):
-    the ``scatter_add.xla`` route, taken for ``reason``."""
-    R, D = table.shape
-    with _routed("scatter_add", "xla", R, D, ids.shape[0], reason):
-        # Dropped by the sentinel row ALONE. Masking the deltas as well is
-        # redundant, and a select on a worker's local deltas made XLA lay
-        # their producer out row-major, which put the push's all-gather of
-        # the SIBLING deltas into 128-lane rows in HBM (mf-netflix.x4:
-        # 77.5 M -> 50.4 M examples/s, chip run, PR 25;
-        # tests/test_v5e_compile.py guards the layout).
-        keep = (ids >= 0) & (ids < R)
-        safe = jnp.where(keep, ids, R)
-        return table.at[safe].add(deltas.astype(table.dtype), mode="drop")
 
 
 def scatter_add(
@@ -584,35 +522,19 @@ def scatter_add(
     """``table.at[ids].add(deltas)``; ids outside ``[0, rows)`` are dropped,
     duplicate ids accumulate (the server's additive ``paramUpdate`` fold).
 
-    ``hot_rows > 0`` marks rows ``[0, hot_rows)`` as write-hot (tables laid
-    out with frequency-ranked ids put the Zipfian head there): pushes to
-    them are accumulated by a dense lane-packed MXU contraction with zero
-    update serialization, and only the (low-duplication) tail pays the XLA
-    scatter. The split preserves drop/duplicate semantics for any id
-    distribution, but the head contraction carries f32 deltas as a hi+lo
-    bf16 pair (~16 of 24 mantissa bits — see
-    :func:`fps_tpu.ops.pallas_kernels.scatter_add_packed_pallas`), so
-    head-row sums can differ from the XLA scatter in the low mantissa
-    bits; SGD-style updates are insensitive to this, bit-exact
-    reproducibility across ``hot_rows`` settings is not promised. The
-    head contraction is cost-capped by :data:`SCATTER_FLOP_BUDGET`: an
-    oversized ``hot_rows`` silently falls back to the plain XLA scatter
-    instead of burning unbounded MXU time per push.
+    ``head_prefix`` and ``hot_rows`` are :func:`gather_rows`'s: the
+    certified prefix is accumulated into the head slice by a head-only
+    kernel, the tail goes down the chain again. The dim-1 routes carry f32
+    deltas as a hi+lo bf16 pair (~16 of 24 mantissa bits — see
+    :mod:`fps_tpu.ops.pallas_kernels`), so their sums can differ from the
+    XLA scatter's in the low mantissa bits; a table wider than f32 takes
+    the XLA scatter, which adds in the table's own dtype (every other
+    predicate rejects it: :func:`_bf16_pair_ok`).
     """
-    use, interpret = _use_pallas()
     R, D = table.shape
-
-    # Every Pallas scatter variant accumulates in f32 (the packed head path
-    # in bf16 hi+lo); a table wider than f32 (f64) must take the XLA
-    # scatter, which adds in the table's native dtype.
-    if jnp.dtype(table.dtype).itemsize > 4:
-        return _xla_scatter_add(table, ids, deltas, "f64")
-
+    B = ids.shape[0]
+    interpret = _use_pallas()[1]
     if _route_head_prefix(R, D, head_prefix, hot_rows, table.dtype):
-        # Guaranteed-head prefix (see gather_rows): accumulate the prefix
-        # into the head slice via the head-only kernel, then run the tail
-        # through the normal routing (WITHOUT the legacy hot_rows masked
-        # split — the prefix split supersedes it for this call).
         from fps_tpu.ops.pallas_kernels import scatter_add_dim1_pallas
 
         with _routed("scatter_add", "dim1_head", hot_rows, D, head_prefix):
@@ -624,62 +546,23 @@ def scatter_add(
                                                         axis=0)
         # The tail opens its own scope BESIDE the head's, not under it.
         return scatter_add(table, ids[head_prefix:], deltas[head_prefix:])
-
-    if _route_dim1(R, D, ids.shape[0], table.dtype):
+    if _route_dim1(R, D, B, table.dtype):
         from fps_tpu.ops.pallas_kernels import scatter_add_dim1_pallas
 
-        with _routed("scatter_add", "dim1", R, D, ids.shape[0]):
+        with _routed("scatter_add", "dim1", R, D, B):
             return scatter_add_dim1_pallas(table, ids, deltas,
                                            row_tile=512, batch_tile=8192,
                                            interpret=interpret)
-
-    if use and hot_rows >= R > 0:
-        # Whole-shard packed routing (hot_ids="auto" below the measured
-        # crossover): every row is "hot", so there is no tail scatter at
-        # all — out-of-range/-1 ids match no one-hot row and drop.
-        pack = max(1, 128 // D)
-        head_flops = -(-R // pack) * (2 * ids.shape[0]) * 128
-        if head_flops > SCATTER_FLOP_BUDGET:
-            return _xla_scatter_add(table, ids, deltas, "flop_budget")
-        from fps_tpu.ops.pallas_kernels import scatter_add_packed_pallas
-
-        with _routed("scatter_add", "packed", R, D, ids.shape[0]):
-            return scatter_add_packed_pallas(table, ids, deltas,
-                                             interpret=interpret)
-
-    if use and 0 < hot_rows < R:
-        pack = max(1, 128 // D)
-        head_flops = -(-hot_rows // pack) * (2 * ids.shape[0]) * 128
-        if head_flops > SCATTER_FLOP_BUDGET:
-            return _xla_scatter_add(table, ids, deltas, "flop_budget")
-        from fps_tpu.ops.pallas_kernels import scatter_add_packed_pallas
-
-        in_head = (ids >= 0) & (ids < hot_rows)
-        head_ids = jnp.where(in_head, ids, -1)
-        tail_ids = jnp.where(in_head, R, ids)
-        with _routed("scatter_add", "packed_head", hot_rows, D,
-                     ids.shape[0]):
-            head_upd = scatter_add_packed_pallas(
-                jnp.zeros((hot_rows, D), table.dtype),
-                head_ids,
-                deltas,
-                interpret=interpret,
-            )
-        # The masked tail is the split's other half, not a fallback.
-        table = _xla_scatter_add(table, tail_ids, deltas, "")
-        with _scope("scatter_add.packed_head"):
-            return table.at[:hot_rows].add(head_upd)
-
-    if _route_xla_packed(R, D, ids.shape[0], table.dtype):
+    if _route_xla_packed(R, D, B, table.dtype):
         return _xla_packed_scatter_add(table, ids, deltas)
-
-    onehot = _BACKEND == "pallas" and use
-    if onehot and R * ids.shape[0] * max(D, 1) <= SCATTER_FLOP_BUDGET:
-        from fps_tpu.ops.pallas_kernels import scatter_add_pallas
-
-        with _routed("scatter_add", "onehot", R, D, ids.shape[0]):
-            return scatter_add_pallas(table, ids, deltas,
-                                      interpret=interpret)
-    return _xla_scatter_add(
-        table, ids, deltas,
-        "flop_budget" if onehot else _xla_reason(R, D, table.dtype))
+    with _routed("scatter_add", "xla", R, D, B,
+                 _xla_reason(R, D, table.dtype)):
+        # Dropped by the sentinel row ALONE. Masking the deltas as well is
+        # redundant, and a select on a worker's local deltas made XLA lay
+        # their producer out row-major, which put the push's all-gather of
+        # the SIBLING deltas into 128-lane rows in HBM (mf-netflix.x4:
+        # 77.5 M -> 50.4 M examples/s, chip run, PR 25;
+        # tests/test_v5e_compile.py guards the layout).
+        keep = (ids >= 0) & (ids < R)
+        safe = jnp.where(keep, ids, R)
+        return table.at[safe].add(deltas.astype(table.dtype), mode="drop")

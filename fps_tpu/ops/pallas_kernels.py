@@ -4,54 +4,27 @@ SURVEY.md §7 flags the sparse gather / scatter-add paths as the rebuild's
 throughput hard part (the reference's per-message ``onPullRecv`` /
 ``onPushRecv`` handling, expected upstream
 ``src/main/scala/hu/sztaki/ilab/ps/server/SimplePSLogic.scala``, becomes a
-bulk row gather + duplicate-combining scatter-add here). Both kernels use
-the same TPU-first idea — turn data-dependent indexing into dense
-**indicator (one-hot) matmuls on the MXU**, the systolic array's native
-operation, instead of the serialized dynamic-memory ops XLA's gather/scatter
-lower to:
+bulk row gather + duplicate-combining scatter-add here). XLA's own gather
+and scatter serve every table but one kind: a SCALAR table (PA's and
+logreg's weight vectors), where XLA pays a row transaction per scalar
+moved. The two kernels here, :func:`gather_rows_dim1_pallas` and
+:func:`scatter_add_dim1_pallas`, pack 128 rows per lane row and turn the
+data-dependent indexing into dense **indicator (one-hot) matmuls on the
+MXU**: duplicates accumulate in the f32 accumulator, drop sentinels (ids
+outside ``[0, R)``) never match a row and vanish, and there is zero update
+serialization.
 
-* :func:`scatter_add_pallas` — for each (row-tile, batch-tile) grid cell,
-  build the ``(row_tile, batch_tile)`` indicator ``ids == row`` and contract
-  with the delta block: duplicates accumulate exactly (the reference's
-  additive ``paramUpdate`` fold per message), drop sentinels (ids outside
-  ``[0, R)``) never match a row and vanish, and there is zero update
-  serialization.
-* :func:`gather_rows_pallas` — the transpose: ``(batch_tile, row_tile)``
-  indicator contracted with the table block accumulates each requested row
-  into the output (pull = one-hot matmul route, SURVEY.md §7 step 1).
+Precision contract of both: f32 values ride as hi+lo bf16 halves
+(:func:`_split_hi_lo`, ~16 of 24 mantissa bits) with exact f32 MXU
+accumulation — ~8x cheaper than a ``Precision.HIGHEST`` f32 contraction
+and far more update-mass accuracy than single-pass bf16 on hot rows;
+gathered rows and duplicate sums can differ from XLA in the low mantissa
+bits.
 
-A third kernel, :func:`scatter_add_packed_pallas`, packs ``128 // D``
-logical small-rank rows per physical lane row so the MXU pass is not mostly
-padding, and splits f32 deltas into hi+lo bf16 halves (exact f32
-accumulation) instead of paying ``Precision.HIGHEST``.
-
-Measured in rounds 4-5 on one v5 lite chip under an earlier runtime, not
-re-measured on the current installation: each sample is a 256-step scan
-with a chained table carry, fenced by a host read. Per-scatter times at
-B=32768 ids with realistic popularity skew (p ~ 1/rank^0.8, 62%
-duplication), ~370us/step dispatch floor subtracted:
-
-==================================  ===========  =================
-shape (R rows × D dim)              XLA scatter  packed one-hot
-==================================  ===========  =================
-MF item   26744 × 11                ~460 µs      ~470 µs
-MF user  138496 × 10                ~420 µs      worse (R large)
-==================================  ===========  =================
-
-Two further on-chip findings: XLA's scatter cost is ~flat in duplication
-(all-unique ids measured *slower*: 517 vs 365 µs at the item shape), and
-rows masked to the drop sentinel still pay full cost — so neither
-dedup-before-scatter nor hot/cold splitting wins on a single chip, where
-XLA's scatter is simply a good primitive at ~12-15 ns/row. The packed
-kernel's MXU cost is ``(R/pack) × 2B × 128`` MACs: it wins only when the
-per-shard row slice is small — the many-shard regime — hence the
-``hot_rows`` routing in :func:`fps_tpu.ops.scatter_add` defaults off and is
-worth enabling on large shard axes.
-
-All kernels run in interpreter mode off-TPU so the CPU-mesh test suite
-exercises them bit-for-bit. Tile sizes respect Mosaic's block constraints:
-the id row is laid out ``(1, batch_tile)`` with ``batch_tile`` a multiple of
-128 (lane dim), and row/batch tiles are multiples of 8 (sublane dim).
+Both run in interpreter mode off-TPU so the CPU-mesh test suite exercises
+them bit-for-bit. Tile sizes respect Mosaic's block constraints: the id
+row is laid out ``(1, batch_tile)`` with ``batch_tile`` a multiple of 128
+(lane dim), and row/batch tiles are multiples of 8 (sublane dim).
 """
 
 from __future__ import annotations
@@ -78,200 +51,19 @@ def _tiles(R: int, B: int, row_tile: int, batch_tile: int) -> tuple[int, int]:
 
 
 # ---------------------------------------------------------------------------
-# Scatter-add: table[ids] += deltas (duplicates combine, out-of-range drop)
-# ---------------------------------------------------------------------------
-
-def _scatter_kernel(ids_ref, table_ref, deltas_ref, out_ref, *, row_tile):
-    i = pl.program_id(0)  # row-tile index (slow)
-    j = pl.program_id(1)  # batch-tile index (fast: out block stays resident)
-
-    @pl.when(j == 0)
-    def _():
-        out_ref[:] = table_ref[:]
-
-    bt = ids_ref.shape[1]
-    rows = i * row_tile + jax.lax.broadcasted_iota(
-        jnp.int32, (row_tile, bt), dimension=0
-    )
-    onehot = (ids_ref[:] == rows).astype(jnp.float32)  # (row_tile, bt)
-    acc = jnp.dot(
-        onehot,
-        deltas_ref[:].astype(jnp.float32),
-        preferred_element_type=jnp.float32,
-        precision=jax.lax.Precision.HIGHEST,
-    )
-    out_ref[:] += acc.astype(out_ref.dtype)
-
-
-@functools.partial(
-    jax.jit, static_argnames=("row_tile", "batch_tile", "interpret")
-)
-def scatter_add_pallas(
-    table: Array,
-    ids: Array,
-    deltas: Array,
-    *,
-    row_tile: int = 256,
-    batch_tile: int = 2048,
-    interpret: bool = False,
-):
-    """``table.at[ids].add(deltas)`` with drop semantics for ids ∉ [0, R).
-
-    ``ids (B,)`` int32, ``deltas (B, D)``. Returns the updated ``(R, D)``
-    table. Duplicate ids within the batch accumulate additively.
-    """
-    R, D = table.shape
-    B = ids.shape[0]
-    row_tile, batch_tile = _tiles(R, B, row_tile, batch_tile)
-
-    pad_b = _round_up(B, batch_tile) - B
-    ids2 = jnp.pad(ids.astype(jnp.int32), (0, pad_b), constant_values=-1)
-    deltas2 = jnp.pad(deltas, ((0, pad_b), (0, 0)))
-    ids2 = ids2.reshape(1, -1)  # 2-D for TPU layout
-
-    grid = (pl.cdiv(R, row_tile), ids2.shape[1] // batch_tile)
-    return pl.pallas_call(
-        functools.partial(_scatter_kernel, row_tile=row_tile),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, batch_tile), lambda i, j: (0, j)),
-            pl.BlockSpec((row_tile, D), lambda i, j: (i, 0)),
-            pl.BlockSpec((batch_tile, D), lambda i, j: (j, 0)),
-        ],
-        out_specs=pl.BlockSpec((row_tile, D), lambda i, j: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((R, D), table.dtype),
-        name="scatter_add_onehot",
-        interpret=interpret,
-    )(ids2, table, deltas2)
-
-
-# ---------------------------------------------------------------------------
-# Lane-packed scatter-add: the small-rank fast path.
-# ---------------------------------------------------------------------------
-
-def _onehot_accum_kernel(ids_ref, deltas_ref, out_ref, *, row_tile):
-    """out[r, :] += sum_b [ids[b] == r] * deltas[b, :] — bf16 MXU contract,
-    f32 accumulate. The caller is responsible for any lane packing and for
-    precision splitting (deltas arrive bf16)."""
-    i = pl.program_id(0)  # row tile (slow)
-    j = pl.program_id(1)  # batch tile (fast: out block stays resident)
-
-    @pl.when(j == 0)
-    def _():
-        out_ref[:] = jnp.zeros_like(out_ref)
-
-    bt = ids_ref.shape[1]
-    rows = i * row_tile + jax.lax.broadcasted_iota(
-        jnp.int32, (row_tile, bt), dimension=0
-    )
-    onehot = (ids_ref[:] == rows).astype(jnp.bfloat16)  # exact 0/1 in bf16
-    out_ref[:] += jnp.dot(
-        onehot, deltas_ref[:], preferred_element_type=jnp.float32
-    )
-
-
-@functools.partial(
-    jax.jit, static_argnames=("row_tile", "batch_tile", "interpret")
-)
-def scatter_add_packed_pallas(
-    table: Array,
-    ids: Array,
-    deltas: Array,
-    *,
-    row_tile: int = 256,
-    batch_tile: int = 4096,
-    interpret: bool = False,
-):
-    """``table.at[ids].add(deltas)`` via a LANE-PACKED one-hot contraction.
-
-    XLA's scatter-add serializes colliding updates — per-row-transaction
-    cost that explodes on Zipfian-hot batches. This path instead pays dense
-    MXU work with zero serialization:
-
-    * **lane packing** — a plain one-hot scatter wastes the 128-wide lane
-      dim on small-rank rows (D=10 uses 8% of every MXU pass). Here
-      ``pack = 128 // D`` logical rows share one physical lane row: the
-      accumulator is ``(ceil(R/pack), pack*D)``, the one-hot indexes
-      ``id // pack``, and each delta is pre-placed (by XLA, outside the
-      kernel — cheap vectorized VPU work) into lane block ``id % pack``.
-      MXU work drops by the pack factor to ``(R/pack) x B x 128`` MACs.
-    * **split-precision deltas** — f32 deltas ride as hi+lo bf16 halves
-      (concatenated along the contraction dim with duplicated ids), giving
-      ~16 mantissa bits per element with exact f32 MXU accumulation:
-      ~8x cheaper than a ``Precision.HIGHEST`` f32 contraction and far
-      more update-mass accuracy than single-pass bf16 on hot rows.
-
-    Duplicates accumulate in the f32 accumulator; ids outside ``[0, R)``
-    are dropped (negative packed rows never match a tile; overflow rows
-    land in padding the final slice discards).
-    """
-    R, D = table.shape
-    B = ids.shape[0]
-    pack = max(1, 128 // D)
-    rp = -(-R // pack)  # packed rows
-
-    ids = ids.astype(jnp.int32)
-    prow = ids // pack  # negative ids floor to -1: never matches
-    lane = jnp.where(ids >= 0, ids % pack, 0)
-    # Place each delta into its lane block: (B, pack*D).
-    if pack > 1:
-        onehot_lane = (
-            lane[:, None] == jnp.arange(pack, dtype=jnp.int32)[None, :]
-        )
-        dt = (
-            deltas.astype(jnp.float32)[:, None, :]
-            * onehot_lane[:, :, None].astype(jnp.float32)
-        ).reshape(B, pack * D)
-    else:
-        dt = deltas.astype(jnp.float32)
-    hi, lo = _split_hi_lo(dt)
-    # One kernel pass over 2B rows: [hi; lo] with duplicated ids.
-    ids_cat = jnp.concatenate([prow, prow])
-    d_cat = jnp.concatenate([hi, lo])
-
-    B2 = 2 * B
-    row_tile, batch_tile = _tiles(rp, B2, row_tile, batch_tile)
-    pad_b = _round_up(B2, batch_tile) - B2
-    ids2 = jnp.pad(ids_cat, (0, pad_b), constant_values=-1).reshape(1, -1)
-    d2 = jnp.pad(d_cat, ((0, pad_b), (0, 0)))
-
-    grid = (pl.cdiv(rp, row_tile), ids2.shape[1] // batch_tile)
-    acc = pl.pallas_call(
-        functools.partial(_onehot_accum_kernel, row_tile=row_tile),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, batch_tile), lambda i, j: (0, j)),
-            pl.BlockSpec((batch_tile, pack * D), lambda i, j: (j, 0)),
-        ],
-        out_specs=pl.BlockSpec((row_tile, pack * D), lambda i, j: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((rp, pack * D), jnp.float32),
-        name="scatter_add_packed",
-        interpret=interpret,
-    )(ids2, d2)
-    upd = acc.reshape(rp * pack, D)[:R]
-    return table + upd.astype(table.dtype)
-
-
-# ---------------------------------------------------------------------------
 # Dim-1 lane-packed kernels: scalar tables (PA / logreg weight vectors).
 #
-# For D == 1 the generic packed path's XLA-side lane placement materializes
-# a (B, 128) delta matrix in HBM — at the PA workload shape (B = 2^20 ids
-# into a 47k-row scalar table) that is ~0.5 GB per step and measured to
-# cost as much as the XLA scatter it replaces (~12 vs ~13.5 ms/step).
-# These kernels move BOTH the packed-row one-hot and the lane placement
-# inside the kernel: HBM traffic is just ids + deltas (8 MB), and the MXU
-# pays (R/128) x B x 128 MACs per precision pass. The round-4 v2
-# formulation is TRANSPOSE-FREE (see the kernel docstrings): measured
-# on-chip at the PA shape, dedup-safe T=256 scan timing
-# (tools/bench_scatter.py dim1): scatter 7.6 -> 1.5 ms, gather
-# 8.1 -> 1.6 ms per 2^20-id call (the v1 kernels with in-kernel lane
-# placement via minor-dim reshapes measured 2.8 ms each).
-#
-# Precision contract matches scatter_add_packed_pallas: f32 values ride as
-# hi+lo bf16 halves (~16 of 24 mantissa bits) with exact f32 MXU
-# accumulation; gathered rows and duplicate sums can differ from XLA in
-# the low mantissa bits.
+# Placing the lanes OUTSIDE the kernel (a (B, 128) delta matrix built by
+# XLA) materializes ~0.5 GB per step in HBM at the PA workload shape
+# (B = 2^20 ids into a 47k-row scalar table) and measured as much as the
+# XLA scatter it replaced (~12 vs ~13.5 ms/step). These kernels build BOTH
+# the packed-row one-hot and the lane placement inside the kernel: HBM
+# traffic is just ids + deltas (8 MB), and the MXU pays (R/128) x B x 128
+# MACs per precision pass. The round-4 v2 formulation is TRANSPOSE-FREE
+# (see the kernel docstrings): measured on-chip at the PA shape, dedup-safe
+# T=256 scan timing (tools/bench_scatter.py dim1): scatter 7.6 -> 1.5 ms,
+# gather 8.1 -> 1.6 ms per 2^20-id call (the v1 kernels with in-kernel
+# lane placement via minor-dim reshapes measured 2.8 ms each).
 # ---------------------------------------------------------------------------
 
 def _split_hi_lo(x: Array) -> tuple[Array, Array]:
@@ -346,8 +138,7 @@ def scatter_add_dim1_pallas(
     """``table.at[ids].add(deltas)`` for a scalar table ``(R, 1)``.
 
     ``ids (B,)`` int32 (negative/out-of-range dropped), ``deltas (B, 1)``
-    f32. hi+lo bf16 precision contract as in
-    :func:`scatter_add_packed_pallas`.
+    f32. hi+lo bf16 precision contract (module docstring).
     """
     R, D = table.shape
     assert D == 1, "scatter_add_dim1_pallas requires a (R, 1) table"
@@ -472,77 +263,3 @@ def gather_rows_dim1_pallas(
         interpret=interpret,
     )(ids2, hi, lo)
     return out.reshape(-1)[:B, None].astype(table.dtype)
-
-
-# ---------------------------------------------------------------------------
-# Gather: rows = table[ids] (one-hot matmul route)
-# ---------------------------------------------------------------------------
-
-def _gather_kernel(ids_ref, table_ref, out_ref, *, row_tile, num_rows):
-    i = pl.program_id(0)  # batch-tile index (slow)
-    j = pl.program_id(1)  # row-tile index (fast: out block stays resident)
-
-    @pl.when(j == 0)
-    def _():
-        out_ref[:] = jnp.zeros_like(out_ref)
-
-    bt = ids_ref.shape[1]
-    rows = j * row_tile + jax.lax.broadcasted_iota(
-        jnp.int32, (bt, row_tile), dimension=1
-    )
-    ids_col = ids_ref[:].reshape(bt, 1)
-    onehot = (ids_col == rows).astype(jnp.float32)  # (bt, row_tile)
-    # Boundary row tiles read past the table; those rows carry garbage
-    # (NaN in interpret mode) and 0 x NaN would poison the contraction,
-    # so zero them explicitly.
-    row_ids = j * row_tile + jax.lax.broadcasted_iota(
-        jnp.int32, (row_tile, 1), dimension=0
-    )
-    tb = jnp.where(row_ids < num_rows, table_ref[:].astype(jnp.float32), 0.0)
-    acc = jnp.dot(
-        onehot,
-        tb,
-        preferred_element_type=jnp.float32,
-        precision=jax.lax.Precision.HIGHEST,
-    )
-    out_ref[:] += acc.astype(out_ref.dtype)
-
-
-@functools.partial(
-    jax.jit, static_argnames=("row_tile", "batch_tile", "interpret")
-)
-def gather_rows_pallas(
-    table: Array,
-    ids: Array,
-    *,
-    row_tile: int = 512,
-    batch_tile: int = 1024,
-    interpret: bool = False,
-):
-    """``table[ids]`` — ``(B,)`` int32 ids into a ``(R, D)`` table.
-
-    Ids outside ``[0, R)`` produce zero rows (the pull path only sends
-    in-range ids; padding uses ``-1``).
-    """
-    R, D = table.shape
-    B = ids.shape[0]
-    row_tile, batch_tile = _tiles(R, B, row_tile, batch_tile)
-
-    pad_b = _round_up(B, batch_tile) - B
-    ids2 = jnp.pad(ids.astype(jnp.int32), (0, pad_b), constant_values=-1)
-    ids2 = ids2.reshape(1, -1)
-
-    grid = (ids2.shape[1] // batch_tile, pl.cdiv(R, row_tile))
-    out = pl.pallas_call(
-        functools.partial(_gather_kernel, row_tile=row_tile, num_rows=R),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, batch_tile), lambda i, j: (0, i)),
-            pl.BlockSpec((row_tile, D), lambda i, j: (j, 0)),
-        ],
-        out_specs=pl.BlockSpec((batch_tile, D), lambda i, j: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((ids2.shape[1], D), table.dtype),
-        name="gather_onehot",
-        interpret=interpret,
-    )(ids2, table)
-    return out[:B]

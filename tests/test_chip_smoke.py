@@ -30,9 +30,6 @@ TINY = chip_smoke.Sizes(
     kernel_cases=(
         ("scatter_add_dim1_pallas", 1000, 1, 8192),
         ("gather_rows_dim1_pallas", 1000, 1, 8192),
-        ("scatter_add_packed_pallas", 200, 10, 1024),
-        ("scatter_add_pallas", 200, 64, 512),
-        ("gather_rows_pallas", 200, 64, 512),
     ),
 )
 

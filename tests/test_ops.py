@@ -1,9 +1,10 @@
-"""Pallas kernel parity tests (interpreter mode on the CPU mesh).
+"""Row-op parity and routing tests (Pallas kernels interpreted on the CPU).
 
 Oracle: numpy gather / np.add.at. Covers duplicates (Zipfian ids), drop
-sentinels, ragged (non-tile-multiple) shapes, and the dispatcher's backend
-switching — including a full MF training chunk run end-to-end with the
-Pallas backend to prove the kernels compose inside shard_map + scan.
+sentinels, ragged (non-tile-multiple) shapes, the dispatcher's backend
+switching and its route log — including a full training chunk run
+end-to-end with the Pallas backend to prove the kernels compose inside
+shard_map + scan.
 """
 
 import jax
@@ -12,7 +13,6 @@ import numpy as np
 import pytest
 
 import fps_tpu.ops as ops
-from fps_tpu.ops.pallas_kernels import gather_rows_pallas, scatter_add_pallas
 
 
 @pytest.fixture
@@ -23,49 +23,19 @@ def pallas_backend():
     ops.set_backend(prev)
 
 
-@pytest.mark.parametrize("R,D,B", [(64, 8, 32), (57, 5, 40), (8, 128, 256)])
-def test_gather_parity(R, D, B):
-    rng = np.random.default_rng(0)
-    table = rng.normal(0, 1, (R, D)).astype(np.float32)
-    ids = rng.integers(0, R, B).astype(np.int32)
-    got = gather_rows_pallas(jnp.asarray(table), jnp.asarray(ids), interpret=True)
-    np.testing.assert_array_equal(np.asarray(got), table[ids])
-
-
-@pytest.mark.parametrize(
-    "R,D,B,row_tile,batch_tile",
-    [
-        (64, 8, 100, 16, 32),   # ragged batch vs tile
-        (57, 5, 40, 256, 2048),  # tiles larger than data
-        (130, 3, 513, 64, 128),  # ragged rows vs tile
-    ],
-)
-def test_scatter_add_parity(R, D, B, row_tile, batch_tile):
-    rng = np.random.default_rng(1)
-    table = rng.normal(0, 1, (R, D)).astype(np.float32)
-    # Zipfian ids -> heavy duplication, plus drop sentinels -1 and R.
-    ids = (rng.zipf(1.5, B) % R).astype(np.int32)
-    ids[::7] = -1
-    ids[3::11] = R
-    deltas = rng.normal(0, 1, (B, D)).astype(np.float32)
-
-    got = scatter_add_pallas(
-        jnp.asarray(table), jnp.asarray(ids), jnp.asarray(deltas),
-        row_tile=row_tile, batch_tile=batch_tile, interpret=True,
-    )
-
-    want = table.copy()
-    keep = (ids >= 0) & (ids < R)
-    np.add.at(want, ids[keep], deltas[keep])
-    np.testing.assert_allclose(np.asarray(got), want, rtol=1e-5, atol=1e-5)
+def _numpy_scatter_add(table, ids, deltas):
+    want = table.astype(np.float64).copy()
+    keep = (ids >= 0) & (ids < table.shape[0])
+    np.add.at(want, ids[keep], deltas[keep].astype(np.float64))
+    return want
 
 
 @pytest.mark.parametrize("R,D,B,hot", [(64, 8, 100, 16), (130, 3, 513, 7),
                                        (57, 200, 64, 8)])
 def test_scatter_add_hot_cold_split_parity(pallas_backend, R, D, B, hot):
-    """scatter_add with hot_rows>0 (head via the lane-packed one-hot kernel,
-    tail via XLA) must match the plain scatter semantics exactly: drops,
-    duplicates, and head/tail boundary ids."""
+    """``hot_rows`` is the H of the ``head_prefix`` guarantee and nothing
+    else: without ``head_prefix`` the call is the plain scatter bit for
+    bit — drops, duplicates, and ids on the head's boundary."""
     rng = np.random.default_rng(7)
     table = rng.normal(0, 1, (R, D)).astype(np.float32)
     ids = (rng.zipf(1.5, B) % R).astype(np.int32)  # heavy head duplication
@@ -74,66 +44,63 @@ def test_scatter_add_hot_cold_split_parity(pallas_backend, R, D, B, hot):
     ids[1::17] = hot - 1  # boundary: last head row
     ids[2::17] = hot      # boundary: first tail row
     deltas = rng.normal(0, 1, (B, D)).astype(np.float32)
+    args = (jnp.asarray(table), jnp.asarray(ids), jnp.asarray(deltas))
 
-    got = np.asarray(ops.scatter_add(
-        jnp.asarray(table), jnp.asarray(ids), jnp.asarray(deltas),
-        hot_rows=hot,
-    ))
-    want = table.astype(np.float64).copy()
-    keep = (ids >= 0) & (ids < R)
-    np.add.at(want, ids[keep], deltas[keep].astype(np.float64))
-    np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
-
-
-def test_packed_scatter_parity():
-    """The lane-packed kernel alone (pack = 128 // D logical rows per lane
-    row, hi/lo bf16 split) vs the numpy oracle."""
-    from fps_tpu.ops.pallas_kernels import scatter_add_packed_pallas
-
-    rng = np.random.default_rng(8)
-    for R, D, B in [(64, 8, 100), (53, 11, 513), (16, 130, 64), (512, 1, 700)]:
-        table = rng.normal(0, 1, (R, D)).astype(np.float32)
-        ids = (rng.zipf(1.5, B) % (R + 8) - 2).astype(np.int32)  # some oob
-        deltas = rng.normal(0, 1, (B, D)).astype(np.float32)
-        got = np.asarray(scatter_add_packed_pallas(
-            jnp.asarray(table), jnp.asarray(ids), jnp.asarray(deltas),
-            interpret=True,
-        ))
-        want = table.astype(np.float64).copy()
-        keep = (ids >= 0) & (ids < R)
-        np.add.at(want, ids[keep], deltas[keep].astype(np.float64))
-        np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4,
-                                   err_msg=f"R={R} D={D} B={B}")
+    ops.clear_routes()
+    got = np.asarray(ops.scatter_add(*args, hot_rows=hot))
+    plain = np.asarray(ops.scatter_add(*args))
+    assert [r.route for r in ops.routes_traced()] == ["scatter_add.xla"] * 2
+    np.testing.assert_array_equal(got, plain)
+    np.testing.assert_allclose(got, _numpy_scatter_add(table, ids, deltas),
+                               rtol=1e-4, atol=1e-4)
+    np.testing.assert_array_equal(
+        np.asarray(ops.gather_rows(args[0], args[1], hot_rows=hot)),
+        np.asarray(ops.gather_rows(args[0], args[1])))
 
 
-def test_dispatcher_backends():
+# Odd shapes through the dispatcher: sizes that are no multiple of a tile,
+# a table narrower and wider than a lane row, more ids than rows.
+_GATHER_SHAPES = [(64, 8, 32), (57, 5, 40), (8, 128, 256)]
+_SCATTER_SHAPES = [(64, 8, 100), (57, 5, 40), (130, 3, 513)]
+
+
+@pytest.mark.parametrize("op,R,D,B", [
+    ("both", 30, 4, 50),
+    *[("gather", *s) for s in _GATHER_SHAPES],
+    *[("scatter_add", *s) for s in _SCATTER_SHAPES],
+])
+def test_dispatcher_backends(op, R, D, B):
+    """Under each backend value: out-of-range ids drop (a gather reads
+    zeros), duplicates accumulate, against numpy."""
     with pytest.raises(ValueError):
         ops.set_backend("cuda")
     assert ops.get_backend() in ("xla", "pallas", "auto")
 
     rng = np.random.default_rng(2)
-    table = rng.normal(0, 1, (30, 4)).astype(np.float32)
-    ids = rng.integers(-1, 31, 50).astype(np.int32)  # includes drop values
-    deltas = rng.normal(0, 1, (50, 4)).astype(np.float32)
-    keep = (ids >= 0) & (ids < 30)
-    want = table.copy()
-    np.add.at(want, ids[keep], deltas[keep])
+    table = rng.normal(0, 1, (R, D)).astype(np.float32)
+    # Zipfian ids -> heavy duplication, plus drop sentinels -1 and R.
+    ids = (rng.zipf(1.5, B) % R).astype(np.int32)
+    ids[::7] = -1
+    ids[3::11] = R
+    deltas = rng.normal(0, 1, (B, D)).astype(np.float32)
+    keep = (ids >= 0) & (ids < R)
 
     prev = ops.get_backend()
     try:
-        results = {}
-        for backend in ("xla", "pallas"):
+        for backend in ("xla", "pallas", "auto"):
             ops.set_backend(backend)
-            results[backend] = np.asarray(
-                ops.scatter_add(jnp.asarray(table), jnp.asarray(ids),
-                                jnp.asarray(deltas))
-            )
-            gids = np.clip(ids, 0, 29)
-            g = np.asarray(ops.gather_rows(jnp.asarray(table), jnp.asarray(gids)))
-            np.testing.assert_array_equal(g, table[gids])
-        for backend, got in results.items():
-            np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5,
-                                       err_msg=f"backend={backend}")
+            if op != "gather":
+                got = np.asarray(ops.scatter_add(
+                    jnp.asarray(table), jnp.asarray(ids), jnp.asarray(deltas)))
+                np.testing.assert_allclose(
+                    got, _numpy_scatter_add(table, ids, deltas), rtol=1e-5,
+                    atol=1e-5, err_msg=f"backend={backend}")
+            if op != "scatter_add":
+                g = np.asarray(ops.gather_rows(jnp.asarray(table),
+                                               jnp.asarray(ids)))
+                np.testing.assert_array_equal(
+                    g, np.where(keep[:, None], table[np.where(keep, ids, 0)],
+                                0), err_msg=f"backend={backend}")
     finally:
         ops.set_backend(prev)
 
@@ -161,93 +128,89 @@ def test_gather_oob_zero_rows_on_every_backend():
         ops.set_backend(prev)
 
 
+def _pa_chunk_on(mesh, steps):
+    """A PA trainer on a 2,048-feature SCALAR table and one chunk whose
+    worker step moves 128 x 64 = DIM1_MIN_BATCH ids: the shape where the
+    backend decides between the dim-1 kernels and XLA."""
+    from fps_tpu.core.driver import num_workers_of
+    from fps_tpu.core.ingest import epoch_chunks
+    from fps_tpu.models.passive_aggressive import PAConfig, passive_aggressive
+    from fps_tpu.utils.datasets import synthetic_sparse_classification
+
+    W = num_workers_of(mesh)
+    trainer, store = passive_aggressive(
+        mesh, PAConfig(num_features=2048, variant="PA-I", C=1.0),
+        donate=False)
+    data = synthetic_sparse_classification(128 * W * steps, 2048, 64, seed=5)
+    chunk = next(epoch_chunks(data, num_workers=W, local_batch=128,
+                              steps_per_chunk=steps))
+    return trainer, store, chunk
+
+
 def test_set_backend_takes_effect_on_compiled_trainer(devices8):
     """set_backend() after a chunk has compiled must retrace, not silently
     reuse the old backend's executable (Trainer keys its cache on it)."""
-    import fps_tpu.ops as ops_mod
-    from fps_tpu.core.driver import num_workers_of
-    from fps_tpu.core.ingest import epoch_chunks
-    from fps_tpu.models.matrix_factorization import MFConfig, online_mf
     from fps_tpu.parallel.mesh import make_ps_mesh
-    from fps_tpu.utils.datasets import synthetic_ratings
 
     mesh = make_ps_mesh(num_shards=2, num_data=1, devices=devices8[:2])
-    trainer, store = online_mf(mesh, MFConfig(16, 12, rank=4), donate=False)
-    data = synthetic_ratings(16, 12, 128, seed=5)
-    chunk = next(epoch_chunks(data, num_workers=num_workers_of(mesh),
-                              local_batch=8, steps_per_chunk=2,
-                              route_key="user"))
+    trainer, _, chunk = _pa_chunk_on(mesh, steps=2)
     tables, ls = trainer.init_state(jax.random.key(0))
-    prev = ops_mod.get_backend()
+    prev = ops.get_backend()
     try:
-        ops_mod.set_backend("xla")
+        ops.set_backend("xla")
+        ops.clear_routes()
         trainer.run_chunk(tables, ls, chunk, jax.random.key(1))
         assert any(k[:2] == ("sync", "xla") for k in trainer._compiled)
-        ops_mod.set_backend("pallas")
+        assert not {r.route for r in ops.routes_traced()} & ops.PALLAS_ROUTES
+        ops.set_backend("pallas")
+        ops.clear_routes()
         trainer.run_chunk(tables, ls, chunk, jax.random.key(1))
         assert any(k[:2] == ("sync", "pallas") for k in trainer._compiled)
+        # The retrace is another program: the dim-1 kernels are in it.
+        assert {r.route for r in ops.routes_traced()} >= {
+            "gather.dim1", "scatter_add.dim1"}
     finally:
-        ops_mod.set_backend(prev)
+        ops.set_backend(prev)
 
 
 def test_mf_chunk_runs_with_pallas_backend(devices8, pallas_backend):
     """Full compiled training chunk (shard_map + scan + collectives) with the
-    Pallas kernels in the pull/push hot path, vs the XLA backend result."""
-    import fps_tpu.ops as ops_mod
-    from fps_tpu.core.driver import num_workers_of
-    from fps_tpu.core.ingest import epoch_chunks
-    from fps_tpu.models.matrix_factorization import MFConfig, online_mf
+    Pallas kernels in the pull/push hot path, vs the XLA backend result.
+    On PA's scalar table: it is the dim-1 kernels the backend still
+    switches (an MF table takes XLA under every backend)."""
     from fps_tpu.parallel.mesh import make_ps_mesh
-    from fps_tpu.utils.datasets import synthetic_ratings
 
     mesh = make_ps_mesh(num_shards=4, num_data=1, devices=devices8[:4])
-    cfg = MFConfig(num_users=32, num_items=24, rank=4)
-    data = synthetic_ratings(32, 24, 512, seed=3)
+    steps = 4
 
     def run_one():
-        trainer, store = online_mf(mesh, cfg, donate=False)
-        W = num_workers_of(mesh)
-        chunk = next(epoch_chunks(data, num_workers=W, local_batch=16,
-                                  steps_per_chunk=4, route_key="user"))
+        trainer, store, chunk = _pa_chunk_on(mesh, steps)
         tables, ls = trainer.init_state(jax.random.key(0))
+        ops.clear_routes()
         tables, ls, m = trainer.run_chunk(tables, ls, chunk, jax.random.key(1))
-        return np.asarray(tables["item_factors"])
+        return (np.asarray(store.dump_model("weights")[1]),
+                {r.route for r in ops.routes_traced()})
 
-    got = run_one()
-    ops_mod.set_backend("xla")
-    want = run_one()
-    np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-5)
-
-
-def test_whole_shard_packed_scatter_matches_xla(devices8):
-    """hot_rows >= R routes the ENTIRE scatter through the packed MXU
-    kernel (no tail scatter); result must match the XLA scatter within
-    the bf16 hi+lo limb tolerance, including drops and duplicates."""
-    from fps_tpu import ops
-
-    rng = np.random.default_rng(3)
-    R, D, B = 96, 8, 512
-    tab = jnp.asarray(rng.normal(0, 0.1, (R, D)), jnp.float32)
-    ids = jnp.asarray(rng.integers(-1, R + 2, B), jnp.int32)  # drops both ends
-    deltas = jnp.asarray(rng.normal(0, 1e-2, (B, D)), jnp.float32)
-
-    want = np.asarray(ops.scatter_add(tab, ids, deltas))  # hot_rows=0: XLA
-    old = ops.get_backend()
-    ops.set_backend("pallas")
-    try:
-        got = np.asarray(ops.scatter_add(tab, ids, deltas, hot_rows=R))
-    finally:
-        ops.set_backend(old)
-    np.testing.assert_allclose(got, want, rtol=2e-3, atol=2e-5)
+    got, routes = run_one()
+    assert routes >= {"gather.dim1", "scatter_add.dim1"}
+    ops.set_backend("xla")
+    want, routes = run_one()
+    assert not routes & ops.PALLAS_ROUTES
+    assert np.abs(want).max() > 0
+    # One kernel read and one kernel push of every touched weight a step,
+    # each off by at most 2**-16 relative (the hi+lo bf16 contract), and
+    # PA-I's step is continuous in the weights (chip_smoke.py's bound).
+    np.testing.assert_allclose(
+        got, want, rtol=0, atol=2 * steps * 2.0 ** -16 * np.abs(want).max())
 
 
 def test_hot_ids_auto_resolution(devices8):
-    """hot_ids="auto" enables whole-shard packed routing exactly when the
-    per-shard slice is at or below the measured crossover."""
+    """``TableSpec.hot_ids`` is an int, the certified head H, and resolves
+    to the head's local rows ``ceil(H / S)``; ``"auto"`` went with the
+    route it selected and raises like any other string."""
     from fps_tpu.core.api import ServerLogic, StepOutput, WorkerLogic
     from fps_tpu.core.driver import Trainer
-    from fps_tpu.core.store import ParamStore, TableSpec, rows_per_shard
-    from fps_tpu.ops import packed_crossover_rows
+    from fps_tpu.core.store import ParamStore, TableSpec
     from fps_tpu.parallel.mesh import make_ps_mesh
 
     class Noop(WorkerLogic):
@@ -258,105 +221,22 @@ def test_hot_ids_auto_resolution(devices8):
             return StepOutput(pushes={}, local_state=local_state, out={})
 
     mesh = make_ps_mesh(num_shards=8, num_data=1)
-    thin = TableSpec("thin", 8 * 1024, 10, hot_ids="auto").zeros_init()
-    fat = TableSpec("fat", 8 * 65536, 10, hot_ids="auto").zeros_init()
-    head = TableSpec("head", 8 * 65536, 10, hot_ids=4096).zeros_init()
-    store = ParamStore(mesh, [thin, fat, head])
+    specs = [TableSpec("none", 8 * 1024, 10).zeros_init(),
+             TableSpec("head", 8 * 65536, 10, hot_ids=4096).zeros_init(),
+             TableSpec("ragged", 8 * 65536, 10, hot_ids=4097).zeros_init(),
+             TableSpec("auto", 8 * 1024, 10, hot_ids="auto").zeros_init(),
+             TableSpec("Auto", 100, 4, hot_ids="Auto").zeros_init()]
+    store = ParamStore(mesh, specs)
     tr = Trainer(mesh, store, Noop(), server_logic=ServerLogic())
 
-    assert rows_per_shard(8 * 1024, 8) <= packed_crossover_rows(10)
-    assert tr._resolve_hot_rows(store.specs["thin"]) == 1024  # whole shard
-    assert tr._resolve_hot_rows(store.specs["fat"]) == 0      # above cutover
-    assert tr._resolve_hot_rows(store.specs["head"]) == 512   # ceil(4096/8)
-
-    # Any other string must fail loudly at the right altitude, not as a
-    # cryptic TypeError inside the jitted push.
-    bad = TableSpec("bad", 100, 4, hot_ids="Auto").zeros_init()
-    store2 = ParamStore(mesh, [bad])
-    tr2 = Trainer(mesh, store2, Noop(), server_logic=ServerLogic())
-    with pytest.raises(ValueError, match="hot_ids"):
-        tr2._resolve_hot_rows(store2.specs["bad"])
-
-
-def test_hot_ids_auto_trains_equivalently(devices8, monkeypatch):
-    """End-to-end: a Trainer with hot_ids="auto" on a thin 8-shard table
-    (auto -> whole-shard packed routing) trains to the same result as the
-    exact XLA path within the packed kernel's bf16 hi+lo tolerance — AND
-    the packed kernel is asserted to actually be on the traced path (a
-    route that never fires would vacuously pass the equality check)."""
-    from fps_tpu.core.api import ServerLogic, StepOutput, WorkerLogic
-    from fps_tpu.core.driver import Trainer, TrainerConfig
-    from fps_tpu.core.ingest import epoch_chunks
-    from fps_tpu.core.store import ParamStore, TableSpec
-    from fps_tpu.parallel.mesh import make_ps_mesh
-
-    class Pusher(WorkerLogic):
-        def pull_ids(self, batch):
-            return {"t": batch["id"].astype(jnp.int32)}
-
-        def step(self, batch, pulled, local_state, key):
-            ids = jnp.where(batch["weight"] > 0,
-                            batch["id"].astype(jnp.int32), -1)
-            # pulled-dependent delta: exercises gather AND scatter
-            deltas = (0.5 * batch["val"][:, None]
-                      - 0.1 * pulled["t"]).astype(jnp.float32)
-            return StepOutput(pushes={"t": (ids, deltas)},
-                              local_state=local_state, out={})
-
-    mesh = make_ps_mesh(num_shards=8, num_data=1)
-    R, D = 512, 4  # 64 rows/shard, far below the crossover -> auto packs
-    rng = np.random.default_rng(9)
-    n = 1024
-    data = {"id": rng.integers(0, R, n).astype(np.int32),
-            "val": rng.normal(0, 1, n).astype(np.float32)}
-    chunks = list(epoch_chunks(data, num_workers=8, local_batch=32,
-                               steps_per_chunk=2, seed=1))
-
-    # Mean combine = word2vec's SHIPPED server logic; non-"sum" combines
-    # always take the gathered route (the dense-collective route would
-    # otherwise claim every small additive table and bypass hot_rows —
-    # which is exactly where hot_ids="auto" spent two rounds dark).
-    def run(hot):
-        store = ParamStore(
-            mesh, [TableSpec("t", R, D, hot_ids=hot).zeros_init()])
-        tr = Trainer(mesh, store, Pusher(),
-                     server_logic=ServerLogic(combine="mean"),
-                     config=TrainerConfig(donate=False))
-        t, ls = tr.init_state(jax.random.key(0))
-        for c in chunks:
-            t, ls, _ = tr.run_chunk(t, ls, c, jax.random.key(1))
-        return store.dump_model("t")[1]
-
-    from fps_tpu import ops
-    from fps_tpu.ops import pallas_kernels
-
-    # Count packed-kernel invocations at TRACE time (scatter_add imports it
-    # per call, so patching the module attribute intercepts the route).
-    calls = {"packed": 0}
-    real_packed = pallas_kernels.scatter_add_packed_pallas
-
-    def counting_packed(*args, **kwargs):
-        calls["packed"] += 1
-        return real_packed(*args, **kwargs)
-
-    monkeypatch.setattr(pallas_kernels, "scatter_add_packed_pallas",
-                        counting_packed)
-    old = ops.get_backend()
-    ops.set_backend("pallas")  # interpret-mode kernels on the CPU mesh
-    try:
-        got_auto = run("auto")
-        assert calls["packed"] > 0, (
-            "auto never routed through the packed kernel")
-        # The negative claim must run INSIDE the pallas window too: with
-        # the backend restored to CPU "auto", every packed route is off
-        # regardless of hot_ids and the assert would be vacuous.
-        calls["packed"] = 0
-        want = run(0)
-        assert calls["packed"] == 0  # hot_ids=0 must NOT take packed route
-    finally:
-        ops.set_backend(old)
-    np.testing.assert_allclose(got_auto, want, rtol=3e-3, atol=3e-5)
-    assert np.abs(want).sum() > 0  # the workload actually moved the table
+    assert tr._resolve_hot_rows(store.specs["none"]) == 0
+    assert tr._resolve_hot_rows(store.specs["head"]) == 512   # 4096 / 8
+    assert tr._resolve_hot_rows(store.specs["ragged"]) == 513  # ceil
+    # A string must fail loudly at the right altitude, not as a cryptic
+    # TypeError inside the jitted push.
+    for name in ("auto", "Auto"):
+        with pytest.raises(ValueError, match="hot_ids"):
+            tr._resolve_hot_rows(store.specs[name])
 
 
 # ---------------------------------------------------------------------------
@@ -400,13 +280,20 @@ def test_dim1_gather_parity(R, B):
     np.testing.assert_allclose(got, ref, rtol=2e-4, atol=2e-4)
 
 
+# (R, D, B, taken) under the forced backend.
+_DIM1_CASES = [
+    (47_236, 1, 1 << 20, True),
+    (47_236, 2, 1 << 20, False),      # not scalar
+    (1_000_000, 1, 1 << 20, False),   # row cap
+    (47_236, 1, 1024, False),         # batch floor
+]
+
+
 def test_dim1_routing_conditions(pallas_backend):
     """_route_dim1: only scalar tables below the measured row cap at large
     batch route to the dim-1 kernels; everything else keeps its path."""
-    assert ops._route_dim1(47_236, 1, 1 << 20)
-    assert not ops._route_dim1(47_236, 2, 1 << 20)      # not scalar
-    assert not ops._route_dim1(1_000_000, 1, 1 << 20)   # row cap
-    assert not ops._route_dim1(47_236, 1, 1024)         # batch floor
+    for R, D, B, taken in _DIM1_CASES:
+        assert ops._route_dim1(R, D, B) == taken, (R, D, B)
     prev = ops.get_backend()
     ops.set_backend("xla")
     try:
@@ -532,13 +419,20 @@ def test_head_prefix_scatter_and_gather_parity(pallas_backend, R, H, B, q):
     np.testing.assert_allclose(got_g, ref_g, rtol=2e-4, atol=2e-4)
 
 
+# (R, D, head_prefix, hot_rows, taken) under the forced backend.
+_HEAD_PREFIX_CASES = [
+    (47_236, 1, 8192, 2048, True),
+    (47_236, 1, 1024, 2048, False),   # short
+    (47_236, 2, 8192, 2048, False),   # D != 1
+    (47_236, 1, 8192, 0, False),      # no head
+    (4_096, 1, 8192, 2048, False),    # H ~ R
+]
+
+
 def test_head_prefix_routing_conditions(pallas_backend):
-    f32 = np.float32
-    assert ops._route_head_prefix(47_236, 1, 8192, 2048, f32)
-    assert not ops._route_head_prefix(47_236, 1, 1024, 2048, f32)  # short
-    assert not ops._route_head_prefix(47_236, 2, 8192, 2048, f32)  # D!=1
-    assert not ops._route_head_prefix(47_236, 1, 8192, 0, f32)     # no head
-    assert not ops._route_head_prefix(4_096, 1, 8192, 2048, f32)   # H~R
+    for R, D, prefix, H, taken in _HEAD_PREFIX_CASES:
+        assert ops._route_head_prefix(R, D, prefix, H, np.float32) == taken, (
+            R, D, prefix, H)
 
 
 # ---------------------------------------------------------------------------
@@ -586,13 +480,14 @@ def test_route_log_holds_pa_routes_once_each(pallas_backend, route, rows,
 
 @pytest.mark.parametrize("case,route,reason", [
     ("f64", "scatter_add.xla", "f64"),
-    ("over_budget_hot_rows", "scatter_add.xla", "flop_budget"),
-    ("over_budget_onehot_gather", "gather.xla", "flop_budget"),
     ("auto_on_cpu", "scatter_add.xla", "backend"),
     ("exact_read", "gather.xla", ""),
-    ("packed", "scatter_add.packed", ""),
-    ("packed_head", "scatter_add.packed_head", ""),
-    ("onehot", "scatter_add.onehot", ""),
+    # The three calls that took the Pallas scatters PR 29 removed (a whole
+    # shard marked hot, a hot head, the forced backend on a wide table):
+    # one plain XLA scatter each, for want of a route that serves the shape.
+    ("packed", "scatter_add.xla", "shape"),
+    ("packed_head", "scatter_add.xla", "shape"),
+    ("onehot", "scatter_add.xla", "shape"),
 ])
 def test_route_log_says_why_a_pallas_route_was_passed_over(case, route,
                                                            reason):
@@ -608,12 +503,6 @@ def test_route_log_says_why_a_pallas_route_was_passed_over(case, route,
         "f64": lambda: jax.eval_shape(
             scatter, jax.ShapeDtypeStruct((64, 4), jnp.float64), i32(32),
             jax.ShapeDtypeStruct((32, 4), jnp.float64)),
-        # 2^20 ids into a 2^20-row head: 2 * 2^20 * 2^20 / pack flops.
-        "over_budget_hot_rows": lambda: jax.eval_shape(
-            lambda t, i, d: scatter(t, i, d, hot_rows=1 << 20),
-            f32(1 << 21, 8), i32(1 << 20), f32(1 << 20, 8)),
-        "over_budget_onehot_gather": lambda: jax.eval_shape(
-            gather, f32(1 << 20, 64), i32(1 << 16)),
         "auto_on_cpu": lambda: jax.eval_shape(scatter, *small),
         "exact_read": lambda: jax.eval_shape(
             lambda t, i: gather(t, i, exact=True),
@@ -634,17 +523,9 @@ def test_route_log_says_why_a_pallas_route_was_passed_over(case, route,
     finally:
         ops.set_backend(prev)
         jax.config.update("jax_enable_x64", x64)
-    hit = [r for r in log if r.route == route]
-    assert len(hit) == 1 and hit[0].reason == reason, log
-    # A Pallas route off the TPU runs interpreted; an XLA route never does.
-    assert hit[0].interpret == (route in ops.PALLAS_ROUTES)
-    if case == "packed_head":
-        # The split's other half is the masked XLA tail: a route of its
-        # own, passed over for nothing.
-        assert [(r.route, r.reason) for r in log] == [
-            ("scatter_add.packed_head", ""), ("scatter_add.xla", "")]
-    else:
-        assert len(log) == 1
+    # Exactly one entry; an XLA route never runs interpreted.
+    assert [(r.route, r.reason, r.interpret) for r in log] == [
+        (route, reason, False)], log
 
 
 # --- The lane-packed XLA route (gather.xla_packed / scatter_add.xla_packed).
@@ -710,7 +591,7 @@ def as_on_tpu(monkeypatch):
 _NETFLIX = (480_189, 10, 32_768)
 
 
-@pytest.mark.parametrize("R,D,B,dtype,backend,taken,reason", [
+_XLA_PACKED_CASES = [
     (*_NETFLIX, "float32", "auto", True, ""),
     (*_NETFLIX, "bfloat16", "auto", True, ""),       # 123 MB tiled: out too
     (200_000, 10, 32_768, "float32", "auto", True, ""),   # 102.4 MB tiled
@@ -726,7 +607,11 @@ _NETFLIX = (480_189, 10, 32_768)
     (*_NETFLIX[:2], 1_024, "float32", "auto", False, "shape"),  # too few ids
     (*_NETFLIX, "float64", "auto", False, "f64"),
     (*_NETFLIX, "float32", "xla", False, "backend"),
-])
+]
+
+
+@pytest.mark.parametrize("R,D,B,dtype,backend,taken,reason",
+                         _XLA_PACKED_CASES)
 def test_xla_packed_predicate_over_shapes(as_on_tpu, R, D, B, dtype, backend,
                                           taken, reason):
     prev = ops.get_backend()
@@ -825,3 +710,19 @@ def test_route_of_every_row_op_of_the_benchmarks_cells(as_on_tpu, op, R, D,
                        ids, f32(B, D))
     assert ops.routes_traced() == [
         ops.Route(op, route, R, D, B, interpret=False, reason=reason)]
+
+
+def test_every_declared_route_is_a_cells_or_a_swept_predicates():
+    """``ROUTES`` holds nothing but what a cell of the benchmark runs or a
+    predicate table above sweeps on both sides: a route that only a
+    user-set option reaches has neither."""
+    swept = {"dim1": {taken for *_, taken in _DIM1_CASES},
+             "dim1_head": {taken for *_, taken in _HEAD_PREFIX_CASES},
+             "xla_packed": {taken for *_, taken, _ in _XLA_PACKED_CASES}}
+    assert all(sides == {True, False} for sides in swept.values()), swept
+    for op, declared in ops.ROUTES.items():
+        of_cells = {route.split(".", 1)[1]
+                    for o, *_, route, _ in _CELL_ROW_OPS if o == op}
+        assert of_cells, op
+        assert set(declared) <= of_cells | set(swept), (
+            op, sorted(set(declared) - of_cells - set(swept)))

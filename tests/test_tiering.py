@@ -4,8 +4,9 @@ auto-tiering planner.
 The contracts under test, per docs/performance.md "Adaptive tiering":
 
 * **mapped == static on the identity ranking** — the adaptive tier with
-  hot set ``[0, H)`` trains bit-identically to PR 5's static head (the
-  slot-map machinery changes routing representation, not semantics);
+  hot set ``[0, H)`` trains to PR 5's static head's values, to the few
+  ulps the compiler's own rounding differs by (the slot-map machinery
+  changes routing representation, not semantics);
 * **re-ranks NEVER recompile** — the hot membership rides as replicated
   slot-map/gid DATA; the compile cache is keyed on H only (asserted on
   the cache itself AND on the program-build count);
@@ -87,15 +88,25 @@ def _fit(trainer, chunks, **kw):
 def test_mapped_identity_ranking_matches_static_head(devices8):
     """The adaptive (slot-mapped) tier with hot set [0, H) must train to
     the same values as the static id<H tier — the mapped routing is a
-    representation change, not a semantics change. (Not asserted at the
-    HLO level: the mapped reconcile scatters where the static one
-    slice-adds; value equality is the contract.)"""
+    representation change, not a semantics change.
+
+    To a few ulps of the largest weight, not bit for bit, and in cold rows
+    as in hot ones: every store op adds in the same order on both sides
+    (running the mapped trainer with ``reconcile_hot`` in place of
+    ``reconcile_hot_mapped``, or ``split_hot_push`` in place of
+    ``split_hot_push_slots``, changes no bit), but the mapped PULL decides
+    membership by a gather from the slot map where the static one
+    compares ``id < H``, and XLA's CPU pipeline compiles the worker's
+    float arithmetic downstream of a gather differently from downstream
+    of a compare (``lookup_hot_slots`` answered by the compare: bit
+    equality, PR 29). Which float op it re-rounds is the compiler's
+    choice; what is held exactly is every count, and the replica."""
     mesh = make_ps_mesh(num_shards=4, num_data=1, devices=devices8[:4])
     train, _ = logreg_data()
     chunks = logreg_chunks(train, num_workers_of(mesh), epochs=2)
 
     trainer, store = _make_trainer(mesh, hot_tier=64, hot_sync_every=3)
-    _fit(trainer, chunks)
+    _, _, m_static = _fit(trainer, chunks)
     w_static = weights(store)
 
     # check_every > len(chunks): the Retierer engages the mapped routes
@@ -103,9 +114,15 @@ def test_mapped_identity_ranking_matches_static_head(devices8):
     rt = Retierer(check_every=100)
     trainer, store = _make_trainer(mesh, hot_tier=64, hot_sync_every=3,
                                    retierer=rt)
-    tables, _, _ = _fit(trainer, chunks)
+    tables, _, m_mapped = _fit(trainer, chunks)
     w_mapped = weights(store)
-    assert np.array_equal(w_static, w_mapped)
+    # Measured: half an ulp of the largest weight after the 64 steps.
+    np.testing.assert_allclose(
+        w_mapped, w_static, rtol=0,
+        atol=8 * np.spacing(np.abs(w_static).max()))
+    for a, b in zip(m_static, m_mapped, strict=True):
+        for k in ("n", "mistakes"):
+            assert np.array_equal(np.asarray(a[k]), np.asarray(b[k])), k
     # Boundary invariant, mapped flavor: replica == canonical rows of
     # the CURRENT hot ids.
     gids = rt.hot_ids_for("weights", 64)

@@ -160,16 +160,3 @@ def test_w2v_push_delay_guardrail_warns(devices8):
         warnings.simplefilter("error")
         word2vec(mesh, W2VConfig(vocab_size=50, dim=8, subsample_t=None),
                  uni, push_delay=4)
-
-
-def test_w2v_hot_words_literal_validated(devices8):
-    """A typo'd hot_words literal must fail with the altitude-correct
-    ValueError at store construction, not a TypeError inside min()."""
-    import pytest
-
-    from fps_tpu.models.word2vec import W2VConfig, make_store
-    from fps_tpu.parallel.mesh import make_ps_mesh
-
-    mesh = make_ps_mesh(num_shards=4, num_data=2, devices=devices8[:8])
-    with pytest.raises(ValueError, match="hot_words"):
-        make_store(mesh, W2VConfig(vocab_size=64, dim=8, hot_words="Auto"))

@@ -3,6 +3,9 @@
 Each timed call runs a scan of T iterations whose table carry chains, so no
 dispatch dedup; timing is fenced by a host read. Reports us per scatter.
 
+``dim1`` arm: XLA's gather and scatter against the dim-1 Pallas kernels at
+the PA step's shape (``fps_tpu/ops/pallas_kernels.py``'s dim-1 header).
+
 ``rows`` arm: the plain XLA gather / scatter-add against the lane-packed XLA
 route (``fps_tpu.ops``: ``gather.xla_packed`` / ``scatter_add.xla_packed``)
 over table rows x row width at uniform ids — the sweep that set
@@ -26,12 +29,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from fps_tpu.ops.pallas_kernels import (
-    scatter_add_packed_pallas,
-    scatter_add_pallas,
-    gather_rows_pallas,
-)
-
 T = 256
 
 
@@ -52,51 +49,6 @@ def timeit(fn, *args):
 def xla_scatter(tab, ids, deltas):
     safe = jnp.where((ids >= 0) & (ids < tab.shape[0]), ids, tab.shape[0])
     return tab.at[safe].add(deltas, mode="drop")
-
-
-def run(name, R, D, B, alpha=0.8):
-    rng = np.random.default_rng(0)
-    tab = jnp.asarray(rng.normal(0, 0.1, (R, D)), jnp.float32)
-    # Realistic popularity skew: p ~ 1/rank^alpha (matches the synthetic
-    # workload generators), not rng.zipf (far too head-heavy).
-    pop = 1.0 / np.arange(1, R + 1) ** alpha
-    pop /= pop.sum()
-    cdf = np.cumsum(pop)
-    ids = jnp.asarray(
-        np.searchsorted(cdf, rng.random((T, B))), jnp.int32
-    )
-    dup = 1 - len(np.unique(np.asarray(ids[0]))) / B
-    deltas = jnp.asarray(rng.normal(0, 1e-4, (T, B, D)), jnp.float32)
-    print(f"{name}: dup frac {dup:.2f}", flush=True)
-
-    def scan_of(op):
-        @jax.jit
-        def f(tab, ids, deltas):
-            def body(t, x):
-                i, d = x
-                return op(t, i, d), None
-            return lax.scan(body, tab, (ids, deltas))[0]
-        return f
-
-    us_x = timeit(scan_of(xla_scatter), tab, ids, deltas)
-    us_p = timeit(scan_of(lambda t, i, d: scatter_add_packed_pallas(t, i, d)),
-                  tab, ids, deltas)
-    print(f"{name:28s} R={R:7d} D={D:3d} B={B:6d}  "
-          f"xla {us_x:7.1f}  packed {us_p:7.1f} us", flush=True)
-
-    # correctness spot check vs xla
-    a = np.asarray(xla_scatter(tab, ids[0], deltas[0]))
-    b = np.asarray(scatter_add_packed_pallas(tab, ids[0], deltas[0]))
-    err = np.max(np.abs(a - b) / (np.abs(a) + 1e-6))
-    print(f"{'':28s} packed vs xla max relerr {err:.2e}")
-
-
-def main():
-    run("MF item (mean push D+1)", 26744, 11, 32768)
-    run("MF item (raw)", 26744, 10, 32768)
-    run("MF user", 138496, 10, 32768)
-    run("logreg shard (1/8 of 1M)", 131072, 2, 16384 * 39 // 8)
-    run("w2v 1chip", 50000, 100, 49152, alpha=0.75)
 
 
 def dim1_shapes():
@@ -148,29 +100,6 @@ def dim1_shapes():
     a = np.asarray(xla_scatter(tab, ids[0], deltas[0]))
     b = np.asarray(scatter_add_dim1_pallas(tab, ids[0], deltas[0]))
     print(f"dim1 scatter vs xla max abs err {np.max(np.abs(a - b)):.2e}")
-
-
-
-def small_r_sweep():
-    """The hot/cold split's claimed win regime (round-2 verdict #5): SMALL
-    per-shard row counts — a large shard axis leaves each shard a thin row
-    slice, where the packed one-hot MXU contraction can beat the per-row
-    -transaction-bound XLA scatter. Sweep R x D at fixed batch, print the
-    measured crossover. Configs whose packed-contraction FLOPs exceed ~4x
-    the runtime budget are skipped — scatter_add's flop cap auto-rejects
-    them in production anyway, so timing them is pure wall-clock burn."""
-    from fps_tpu.ops import SCATTER_FLOP_BUDGET
-
-    B = 32768
-    for D in (10, 32, 100):
-        for R in (256, 1024, 2048, 4096, 8192, 16384):
-            pack = max(1, 128 // D)
-            flops = -(-R // pack) * (2 * B) * 128
-            if flops > 4 * SCATTER_FLOP_BUDGET:
-                print(f"sweep D={D:3d} R={R:6d}: skipped "
-                      f"(packed flops {flops:.1e} > 4x budget)", flush=True)
-                continue
-            run(f"sweep D={D}", R, D, B)
 
 
 ROWS_R = (17_770, 120_048, 200_000, 240_095, 320_126, 480_189, 1_048_576)
@@ -426,22 +355,17 @@ def mean_sweep(args):
 if __name__ == "__main__":
     import sys
 
-    if len(sys.argv) == 1:
-        main()
-    elif sys.argv[1:] == ["sweep"]:
-        small_r_sweep()
-    elif sys.argv[1:] == ["dim1"]:
+    if sys.argv[1:] == ["dim1"]:
         dim1_shapes()
-    elif sys.argv[1] == "rows":
+    elif sys.argv[1:2] == ["rows"]:
         rows_sweep(sys.argv[2:])
-    elif sys.argv[1] == "mean":
+    elif sys.argv[1:2] == ["mean"]:
         mean_sweep(sys.argv[2:])
     else:
         raise SystemExit(
             f"unknown args {sys.argv[1:]!r} — usage: bench_scatter.py "
-            "[sweep|dim1|rows [quick]|mean [counts]]  (no args = full "
-            "workload-shape bench; 'sweep' = small-R crossover sweep; "
-            "'dim1' = scalar-table PA shape; 'rows' = plain XLA against the "
-            "lane-packed XLA route over table rows x row width; 'mean' = "
-            "the mean push's accumulator against its row branch)"
+            "dim1|rows [quick]|mean [counts]  ('dim1' = scalar-table PA "
+            "shape; 'rows' = plain XLA against the lane-packed XLA route "
+            "over table rows x row width; 'mean' = the mean push's "
+            "accumulator against its row branch)"
         )
