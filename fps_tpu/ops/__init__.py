@@ -3,9 +3,10 @@
 The store's pull/push collectives bottom out in two local ops per shard:
 row **gather** (pull answers) and duplicate-combining **scatter-add** (push
 folds). :func:`gather_rows` and :func:`scatter_add` are one chain each, the
-same four steps in the same order, every step a predicate over what the
-call can observe (platform, backend, rows, width, ids, dtype, and the
-ingest layer's ``head_prefix`` guarantee):
+same steps in the same order (the scatter-add has one more), every step a
+predicate over what the call can observe (platform, backend, rows, width,
+ids, dtype, and the caller's STATIC guarantees: the ingest layer's
+``head_prefix``, the mean push's ``ids_sorted``):
 
 1. ``dim1_head`` — a scalar table whose leading ids the ingest layer
    certified inside the head ``[0, hot_rows)``: the prefix rides a dim-1
@@ -17,7 +18,12 @@ ingest layer's ``head_prefix`` guarantee):
 3. ``xla_packed`` — a narrow-row table too large for XLA's VMEM regime:
    the same XLA op on the table's lane-packed form
    (:func:`_route_xla_packed`, :data:`XLA_VMEM_TABLE_BYTES`);
-4. ``xla`` — the plain XLA op, with the reason the others were passed over.
+4. ``xla_sorted`` (scatter-add alone) — a wide-row table too large for
+   XLA's VMEM regime whose caller guarantees non-decreasing ids, the
+   dropped ones last: the plain XLA scatter-add over blocks of ids in a
+   loop that stops where the dropped begin, so its time follows the live
+   ids (:func:`_route_xla_sorted`, :data:`XLA_SORTED_BLOCK_IDS`);
+5. ``xla`` — the plain XLA op, with the reason the others were passed over.
 
 Backend selection:
 
@@ -58,7 +64,7 @@ _BACKEND = os.environ.get("FPS_TPU_OPS", "auto").lower()
 # ``<op>.<route>``.
 ROUTES = {
     "gather": ("dim1_head", "dim1", "xla_packed", "xla"),
-    "scatter_add": ("dim1_head", "dim1", "xla_packed", "xla"),
+    "scatter_add": ("dim1_head", "dim1", "xla_packed", "xla_sorted", "xla"),
 }
 PALLAS_ROUTES = frozenset(
     f"{op}.{r}" for op, rs in ROUTES.items() for r in rs
@@ -220,7 +226,9 @@ DENSE_TABLE_BYTES = 4 << 20
 # B pushed rows, sum them by id in a (B, dim) buffer, scatter-add that into
 # the table once). The accumulator branch makes about five passes over the
 # accumulator whatever the batch; the row branch pays two sorts of B ids
-# and a second, payload-sized, row scatter instead. The ratio
+# (three since PR 30; the table below is PR 28's, before the third and
+# before ``scatter_add.xla_sorted``, which only widens the rows' lead at
+# its lower right) and a second, payload-sized, row scatter instead. The ratio
 # (``store._mean_push_ratio``) is the accumulator's tiled bytes, in the
 # smaller of its row-major and transposed forms, over the payload's. In
 # time (``tools/bench_scatter.py mean``, one v5 lite chip, f32, Zipf(1.0)
@@ -312,6 +320,54 @@ XLA_PACKED_TABLE_BYTES = 32 << 20
 XLA_PACKED_DIMS = (8, 32)
 XLA_PACKED_MIN_IDS = 8_192
 
+# The sorted route of the scatter-add (``scatter_add.xla_sorted``): what a
+# caller's ``ids_sorted`` guarantee is worth past XLA's VMEM regime. There
+# the plain scatter-add pays about 100 ns for every id it is handed,
+# dropped by the sentinel or not (99-104 ns a row at [1115011, 300], chip
+# runs, PR 28 and PR 30; ``.at[].set``, ``unique_indices`` and a gather,
+# add and set cost the same). With the dropped ids LAST the op can stop at
+# the last live one: the same XLA scatter-add, a block of
+# XLA_SORTED_BLOCK_IDS ids at a time, in a loop of ``ceil(live / block)``
+# trips. In time (``tools/bench_scatter.py wide``, one v5 lite chip, f32,
+# the table a loop carry, Zipf(1.0) ids handed over as ``push.mean_rows``
+# does: the distinct ones sorted at the front, the sentinel in the place of
+# every duplicate; us a call, plain / sorted; MB = tiled bytes; under B the
+# live ids of the R = 1,115,011 row, fewer above it):
+#
+#   R (MB)             D=64, B=8,192  B=32,768     B=49,182     D=128, B=8,192  B=32,768     B=49,182
+#   live ids           4,526          14,974       21,100       4,524           14,947       21,119
+#   65,536 (33.6)      376 / 206      394 / 491    625 / 695    376 / 203       396 / 496    625 / 691
+#   131,072 (67.1)     377 / 207      409 / 578    645 / 783    379 / 206       411 / 584    639 / 780
+#   262,144 (134.2)    573 / 337      2178 / 998   1027 / 1394  600 / 333       2298 / 1005  1021 / 1393
+#   1,115,011 (570.9)  646 / 435      2390 / 1187  3630 / 1679  637 / 422       2395 / 1179  3615 / 1664
+#
+#   R (MB)               D=300, B=8,192  B=32,768     B=49,182
+#   65,536 (100.7)       565 / 362       1108 / 1150  1697 / 1732
+#   131,072 (201.3)      926 / 552       1475 / 1366  2080 / 1977
+#   262,144 (402.7)      946 / 581       3305 / 1530  2834 / 2264
+#   1,115,011 (1,712.7)  1063 / 783      3671 / 2096  5793 / 3084
+#
+# and w2v-1bw's two pushes, [1115011, 300] under 8,197 ids (4,515 live) and
+# 49,182 (21,093 live): 1096 / 791 and 5800 / 3102; the larger under blocks
+# of 512 / 1,024 / 2,048 / 4,096 / 8,192 ids: 3107 / 3107 / 3185 / 3373 /
+# 3390 (a block less is about 100 us; a trip more costs under 3). Inside the
+# VMEM regime (the first two rows; [65536, 300] is 96 MiB to the byte) the
+# plain op pays about 13 ns an id and the loop loses from 32,768 ids up: the
+# route stays out. Past it the plain op is NOT linear in the ids at every
+# size: at 262,144 rows it is cheaper under 49,182 ids than under 32,768
+# (XLA turns to an emitter that walks the table once the ids are many
+# against the rows; told ``indices_are_sorted`` it always does, 7.2 ms at
+# [1115011, 300] whatever the ids, chip run, PR 30), and there the loop
+# loses by 36 % at D = 64 and 128 and wins by 20 % at 300. With at least
+# XLA_SORTED_ROWS_PER_ID rows an id the sorted route wins every measured
+# point (16, by 26-56 %); with fewer it wins three by 5-20 % and loses two;
+# the constant sits at the clear-win edge, (5.3, 8) rows an id is
+# unmeasured, and so are widths under 64 (a table the lane-packed route
+# could take is left to it). The gain is the dropped share of the ids: a
+# batch with none pays the loop's trips and wins nothing.
+XLA_SORTED_BLOCK_IDS = 1_024
+XLA_SORTED_ROWS_PER_ID = 8
+
 
 def _bf16_pair_ok(dtype) -> bool:
     """The dim-1 kernels carry values as bf16 hi+lo: f64 would silently
@@ -362,12 +418,31 @@ def _route_xla_packed(R: int, D: int, B: int, dtype) -> bool:
             <= XLA_PACKED_TABLE_BYTES)
 
 
+def _route_xla_sorted(R: int, D: int, B: int, dtype,
+                      ids_sorted: bool) -> bool:
+    """Scatter by blocks and stop where the dropped ids begin? Only on the
+    caller's guarantee, on the TPU (backend not ``"xla"``), for more ids
+    than one block, few enough against the rows
+    (:data:`XLA_SORTED_ROWS_PER_ID`), and a table of rows wider than the
+    lane-packed route's (:data:`XLA_PACKED_DIMS`) whose tiled form is past
+    XLA's VMEM regime (:data:`XLA_VMEM_TABLE_BYTES`): there the plain op
+    pays for every id it is handed, dropped or not."""
+    use, interpret = _use_pallas()
+    return (ids_sorted and use and not interpret
+            and jnp.dtype(dtype).itemsize <= 4
+            and XLA_SORTED_BLOCK_IDS < B <= R // XLA_SORTED_ROWS_PER_ID
+            and D > XLA_PACKED_DIMS[1]
+            and _tiled_table_bytes(R, D, dtype) > XLA_VMEM_TABLE_BYTES)
+
+
 def _xla_reason(R: int, D: int, dtype) -> str:
     """Why a call that reached the plain XLA route took no other: the
     dtype cannot ride the kernels' f32 / bf16-pair
     arithmetic, the backend keeps every other route out, the table is
     already inside XLA's VMEM regime (so the lane-packed route has nothing
-    to add), or no route serves the shape under this backend."""
+    to add), or no route serves the shape under this backend (a
+    scatter-add of wide rows past the VMEM regime whose caller gave no
+    ``ids_sorted`` guarantee among them)."""
     if jnp.dtype(dtype).itemsize > 4:
         return "f64"
     use, interpret = _use_pallas()
@@ -439,6 +514,32 @@ def _xla_packed_scatter_add(table: Array, ids: Array,
         packed = _xla_pack(table).at[jnp.where(in_range, row, Rp)].add(
             upd.reshape(ids.shape[0], -1), mode="drop")
         return _xla_unpack(packed, R, D)
+
+
+def _xla_sorted_scatter_add(table: Array, ids: Array,
+                            deltas: Array) -> Array:
+    """``scatter_add.xla_sorted``: the plain XLA scatter-add, a block of
+    :data:`XLA_SORTED_BLOCK_IDS` ids at a time, in place on the table, for
+    as many blocks as hold a live id. The guarantee puts the ids to drop
+    last, so the blocks past ``ceil(live / block)`` hold nothing but them.
+    The last block starts early enough to end with the batch; the ids it
+    shares with the block before are dropped from it. Duplicates add in
+    the batch's order, as on the plain route."""
+    R, D = table.shape
+    B, C = ids.shape[0], XLA_SORTED_BLOCK_IDS
+    with _routed("scatter_add", "xla_sorted", R, D, B):
+        safe = jnp.where((ids >= 0) & (ids < R), ids, R)
+        deltas = deltas.astype(table.dtype)
+        live = jnp.sum((safe < R).astype(jnp.int32))
+
+        def block(c, t):
+            start = jnp.minimum(c * C, B - C)
+            i = jax.lax.dynamic_slice(safe, (start,), (C,))
+            i = jnp.where(start + jnp.arange(C) >= c * C, i, R)
+            d = jax.lax.dynamic_slice(deltas, (start, 0), (C, D))
+            return t.at[i].add(d, mode="drop")
+
+        return jax.lax.fori_loop(0, (live + C - 1) // C, block, table)
 
 
 def _route_head_prefix(R: int, D: int, head_prefix: int, hot_rows: int,
@@ -517,7 +618,7 @@ def gather_rows(table: Array, ids: Array, *, hot_rows: int = 0,
 
 def scatter_add(
     table: Array, ids: Array, deltas: Array, *, hot_rows: int = 0,
-    head_prefix: int = 0
+    head_prefix: int = 0, ids_sorted: bool = False
 ) -> Array:
     """``table.at[ids].add(deltas)``; ids outside ``[0, rows)`` are dropped,
     duplicate ids accumulate (the server's additive ``paramUpdate`` fold).
@@ -530,6 +631,17 @@ def scatter_add(
     XLA scatter's in the low mantissa bits; a table wider than f32 takes
     the XLA scatter, which adds in the table's own dtype (every other
     predicate rejects it: :func:`_bf16_pair_ok`).
+
+    ``ids_sorted=True`` asserts the STATIC guarantee that ``ids`` are
+    non-decreasing and none is negative, so the ids to drop (``>= rows``)
+    come last; duplicates are then adjacent and still accumulate. A wide
+    table past XLA's VMEM regime then takes ``scatter_add.xla_sorted``
+    (:func:`_route_xla_sorted`), which never looks at the ids past the
+    last live one; everywhere else the guarantee is accepted
+    and changes nothing. The answer is the plain route's on every input
+    that keeps the promise; one that breaks it is silently wrong on the
+    TPU, so only a caller that made the order itself gives it
+    (:func:`fps_tpu.core.store.push`'s ``push.mean_rows``).
     """
     R, D = table.shape
     B = ids.shape[0]
@@ -555,6 +667,8 @@ def scatter_add(
                                            interpret=interpret)
     if _route_xla_packed(R, D, B, table.dtype):
         return _xla_packed_scatter_add(table, ids, deltas)
+    if _route_xla_sorted(R, D, B, table.dtype, ids_sorted):
+        return _xla_sorted_scatter_add(table, ids, deltas)
     with _routed("scatter_add", "xla", R, D, B,
                  _xla_reason(R, D, table.dtype)):
         # Dropped by the sentinel row ALONE. Masking the deltas as well is

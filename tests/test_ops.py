@@ -662,6 +662,156 @@ def test_route_log_says_vmem_fit_where_the_plain_op_already_fits(as_on_tpu):
         ("gather.xla", "vmem_fit"), ("scatter_add.xla", "vmem_fit")]
 
 
+# --- The sorted XLA route (scatter_add.xla_sorted).
+
+_W2V = (1_115_011, 300, 49_182)
+
+# (rows, dim, ids, dtype, backend, ids_sorted) -> the route taken, its reason
+_XLA_SORTED_CASES = [
+    (*_W2V, "float32", "auto", True, "xla_sorted", ""),
+    (1_115_011, 300, 8_197, "float32", "auto", True, "xla_sorted", ""),
+    (*_W2V, "float32", "pallas", True, "xla_sorted", ""),
+    (262_144, 128, 32_768, "float32", "auto", True, "xla_sorted", ""),  # 134 MB
+    (262_144, 300, 8_192, "float32", "auto", True, "xla_sorted", ""),   # 403 MB
+    (262_144, 128, 49_182, "float32", "auto", True, "xla", "shape"),  # 5 rows/id
+    (131_072, 300, 32_768, "float32", "auto", True, "xla", "shape"),  # 4 rows/id
+    (*_W2V, "bfloat16", "auto", True, "xla_sorted", ""),                # 856 MB
+    (*_W2V, "float32", "auto", False, "xla", "shape"),   # never unasked
+    (*_W2V[:2], 1_024, "float32", "auto", True, "xla", "shape"),  # one block
+    (*_W2V, "float32", "xla", True, "xla", "backend"),   # the exact baseline
+    (*_W2V, "float64", "auto", True, "xla", "f64"),
+    (65_536, 300, 32_768, "float32", "auto", True, "xla", "shape"),  # 100.7 MB
+    (131_072, 128, 32_768, "float32", "auto", True, "xla", "shape"),  # 67 MB
+    (*_NETFLIX, "float32", "auto", True, "xla_packed", ""),  # lane-packable
+    (1_000_000, 32, 32_768, "float32", "auto", True, "xla", "shape"),
+    (8_000_000, 1, 32_768, "float32", "auto", True, "xla", "shape"),
+]
+
+
+@pytest.mark.parametrize("R,D,B,dtype,backend,ids_sorted,route,reason",
+                         _XLA_SORTED_CASES)
+def test_xla_sorted_predicate_over_shapes(as_on_tpu, R, D, B, dtype, backend,
+                                          ids_sorted, route, reason):
+    """The chain takes ``scatter_add.xla_sorted`` exactly when the
+    guarantee, the tiled bytes and the width say so: the route log of the
+    one call, entry by entry."""
+    x64 = jax.config.jax_enable_x64
+    jax.config.update("jax_enable_x64", dtype == "float64")
+    prev = ops.get_backend()
+    ops.set_backend(backend)
+    try:
+        assert ops._route_xla_sorted(R, D, B, dtype, ids_sorted) == (
+            route == "xla_sorted")
+        ops.clear_routes()
+        out = jax.eval_shape(
+            lambda t, i, d: ops.scatter_add(t, i, d, ids_sorted=ids_sorted),
+            jax.ShapeDtypeStruct((R, D), dtype),
+            jax.ShapeDtypeStruct((B,), jnp.int32),
+            jax.ShapeDtypeStruct((B, D), dtype))
+        log = ops.routes_traced()
+    finally:
+        ops.set_backend(prev)
+        jax.config.update("jax_enable_x64", x64)
+    assert (out.shape, out.dtype) == ((R, D), jnp.dtype(dtype))
+    assert log == [ops.Route("scatter_add", f"scatter_add.{route}", R, D, B,
+                             interpret=False, reason=reason)]
+
+
+@pytest.mark.parametrize("backend", ["auto", "pallas", "xla"])
+def test_xla_sorted_is_never_taken_off_the_tpu(backend):
+    """Off the TPU the guarantee is accepted and the plain op runs."""
+    prev = ops.get_backend()
+    ops.set_backend(backend)
+    try:
+        assert not ops._route_xla_sorted(*_W2V, jnp.float32, True)
+        ops.clear_routes()
+        jax.eval_shape(
+            lambda t, i, d: ops.scatter_add(t, i, d, ids_sorted=True),
+            jax.ShapeDtypeStruct(_W2V[:2], jnp.float32),
+            jax.ShapeDtypeStruct(_W2V[2:], jnp.int32),
+            jax.ShapeDtypeStruct((_W2V[2], _W2V[1]), jnp.float32))
+        assert [r.route for r in ops.routes_traced()] == ["scatter_add.xla"]
+    finally:
+        ops.set_backend(prev)
+
+
+def test_xla_sorted_is_an_xla_route_with_a_name_of_its_own():
+    assert ops.ROUTES["scatter_add"][-2:] == ("xla_sorted", "xla")
+    assert "xla_sorted" not in ops.ROUTES["gather"]
+    assert "scatter_add.xla_sorted" not in ops.PALLAS_ROUTES
+
+
+def _sorted_case(case, R=700, D=40, B=512):
+    """Sorted ids as ``push.mean_rows`` makes them, and their rows."""
+    rng = np.random.default_rng(len(case))
+    deltas = rng.normal(0, 1, (B, D)).astype(np.float32)
+    if case == "runs":            # runs of duplicates, hot ids
+        ids = np.sort(rng.integers(0, R, 40)[rng.integers(0, 40, B)])
+    elif case == "zero_rows":     # a run's later entries carry exact zeros
+        ids = np.sort(rng.integers(0, R, 40)[rng.integers(0, 40, B)])
+        deltas[np.concatenate([[False], ids[1:] == ids[:-1]])] = 0.0
+    elif case == "sentinel_last":  # the dropped, ``R`` and past it, last
+        ids = np.sort(np.concatenate([rng.integers(0, R, B - 100),
+                                      np.full(60, R), np.full(40, R + 9)]))
+    elif case == "all_dropped":
+        ids = np.full(B, R)
+    else:                         # distinct ids, first and last row
+        ids = np.sort(rng.choice(R, B, replace=False))
+        ids[0], ids[-1] = 0, R - 1
+    table = rng.normal(0, 1, (R, D)).astype(np.float32)
+    return jnp.asarray(table), jnp.asarray(ids, jnp.int32), jnp.asarray(deltas)
+
+
+@pytest.mark.parametrize("block", [64, 100, 511])
+@pytest.mark.parametrize("case", ["runs", "zero_rows", "sentinel_last",
+                                  "all_dropped", "distinct"])
+def test_xla_sorted_scatter_add_equals_plain_bit_for_bit(monkeypatch, case,
+                                                         block):
+    """The sorted route's answer (its predicate answering yes at a test's
+    size, blocks that divide the 512 ids, that leave a ragged last block
+    and that leave one id for it) is the plain route's on ids that keep
+    the promise, f32 bit for bit, and the oracle's."""
+    table, ids, deltas = _sorted_case(case)
+    ops.clear_routes()
+    want = jax.jit(lambda t, i, d: ops.scatter_add(t, i, d))(
+        table, ids, deltas)
+    monkeypatch.setattr(ops, "XLA_SORTED_BLOCK_IDS", block)
+    monkeypatch.setattr(ops, "_route_xla_sorted",
+                        lambda R, D, B, dtype, ids_sorted: ids_sorted)
+    got = jax.jit(lambda t, i, d: ops.scatter_add(t, i, d, ids_sorted=True))(
+        table, ids, deltas)
+    assert [r.route for r in ops.routes_traced()] == [
+        "scatter_add.xla", "scatter_add.xla_sorted"]
+    np.testing.assert_array_equal(_bits(got), _bits(want))
+    np.testing.assert_allclose(
+        got, _numpy_scatter_add(np.asarray(table), np.asarray(ids),
+                                np.asarray(deltas)), rtol=1e-5, atol=1e-5)
+    if case == "all_dropped":
+        np.testing.assert_array_equal(_bits(got), _bits(table))
+
+
+def test_xla_sorted_scatter_add_never_reads_past_the_live_ids(monkeypatch):
+    """What the route is for: one scatter, inside a loop of
+    ``ceil(live / block)`` trips; the blocks past the last live id are
+    not run."""
+    table, ids, deltas = _sorted_case("sentinel_last")   # 412 live of 512
+    monkeypatch.setattr(ops, "XLA_SORTED_BLOCK_IDS", 64)
+    text = jax.jit(ops._xla_sorted_scatter_add).lower(
+        table, ids, deltas).as_text()
+    assert "stablehlo.while" in text
+    assert text.count('"stablehlo.scatter"(') == 1  # one scatter, in the loop
+    trips = []
+    plain = jax.lax.fori_loop
+
+    def counting(lo, hi, body, init):
+        trips.append(hi)
+        return plain(lo, hi, body, init)
+
+    monkeypatch.setattr(jax.lax, "fori_loop", counting)
+    ops._xla_sorted_scatter_add(table, ids, deltas)
+    assert [int(t) for t in trips] == [-(-412 // 64)]
+
+
 @pytest.mark.parametrize("R,D,want", [
     (17_770, 10, 17_770 * 512),          # one 128-lane tile: as before
     (480_189, 10, 480_189 * 512),
@@ -694,6 +844,12 @@ _CELL_ROW_OPS = [
     ("gather", 1_115_011, 300, 49_182, "gather.xla", "shape"),
     ("scatter_add", 1_115_011, 301, 8_197, "scatter_add.xla", "shape"),
     ("scatter_add", 1_115_011, 301, 49_182, "scatter_add.xla", "shape"),
+    # w2v-1bw's two pushes since PR 30: ``push.mean_rows`` hands its ids
+    # over sorted (the op is "scatter_add" under ``ids_sorted=True``).
+    ("scatter_add_sorted", 1_115_011, 300, 8_197,
+     "scatter_add.xla_sorted", ""),
+    ("scatter_add_sorted", 1_115_011, 300, 49_182,
+     "scatter_add.xla_sorted", ""),
 ]
 
 
@@ -706,10 +862,12 @@ def test_route_of_every_row_op_of_the_benchmarks_cells(as_on_tpu, op, R, D,
     if op == "gather":
         jax.eval_shape(lambda t, i: ops.gather_rows(t, i), f32(R, D), ids)
     else:
-        jax.eval_shape(lambda t, i, d: ops.scatter_add(t, i, d), f32(R, D),
-                       ids, f32(B, D))
+        jax.eval_shape(lambda t, i, d: ops.scatter_add(
+            t, i, d, ids_sorted=op.endswith("_sorted")), f32(R, D), ids,
+            f32(B, D))
     assert ops.routes_traced() == [
-        ops.Route(op, route, R, D, B, interpret=False, reason=reason)]
+        ops.Route(op.removesuffix("_sorted"), route, R, D, B,
+                  interpret=False, reason=reason)]
 
 
 def test_every_declared_route_is_a_cells_or_a_swept_predicates():
@@ -718,11 +876,13 @@ def test_every_declared_route_is_a_cells_or_a_swept_predicates():
     user-set option reaches has neither."""
     swept = {"dim1": {taken for *_, taken in _DIM1_CASES},
              "dim1_head": {taken for *_, taken in _HEAD_PREFIX_CASES},
-             "xla_packed": {taken for *_, taken, _ in _XLA_PACKED_CASES}}
+             "xla_packed": {taken for *_, taken, _ in _XLA_PACKED_CASES},
+             "xla_sorted": {route == "xla_sorted"
+                            for *_, route, _ in _XLA_SORTED_CASES}}
     assert all(sides == {True, False} for sides in swept.values()), swept
     for op, declared in ops.ROUTES.items():
-        of_cells = {route.split(".", 1)[1]
-                    for o, *_, route, _ in _CELL_ROW_OPS if o == op}
+        of_cells = {route.split(".", 1)[1] for o, *_, route, _
+                    in _CELL_ROW_OPS if o.removesuffix("_sorted") == op}
         assert of_cells, op
         assert set(declared) <= of_cells | set(swept), (
             op, sorted(set(declared) - of_cells - set(swept)))

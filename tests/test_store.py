@@ -478,7 +478,8 @@ def test_id_runs_count_and_slot_every_occurrence(idx):
     """The row branch's bookkeeping, from sorts of the batch alone: for
     every position how often its index occurs (the sentinel row of
     dropped pushes is one more index), one slot an index, and the slots'
-    own indices (``drop`` elsewhere)."""
+    own indices: the distinct indices sorted, at the front, ``drop``
+    after them."""
     from fps_tpu.core.store import _id_runs
 
     idx = np.asarray(idx, np.int32)
@@ -487,8 +488,9 @@ def test_id_runs_count_and_slot_every_occurrence(idx):
                                 jnp.asarray(idx)))
     np.testing.assert_array_equal(n, [(idx == i).sum() for i in idx])
     np.testing.assert_array_equal(slot_idx[slot], idx)
-    live = slot_idx[slot_idx != 10_000]
-    assert sorted(live) == sorted(set(idx)) and len(set(slot)) == len(live)
+    distinct = np.unique(idx)
+    np.testing.assert_array_equal(slot_idx[:len(distinct)], distinct)
+    assert (slot_idx[len(distinct):] == 10_000).all()
 
 
 def _mean_push_case(D, S, num_ids, dim, seed=11):
@@ -558,6 +560,43 @@ def test_push_mean_large_table_takes_the_row_branch(devices8, monkeypatch,
     assert np.abs(got - dense).max() <= 1e-6 * scale
     assert np.abs(got - want).max() <= 1e-6 * scale
     assert np.abs(got - table).max() > 0.1  # something was pushed
+
+
+@pytest.mark.parametrize("seed", [11, 12])
+@pytest.mark.parametrize("D,S", MEAN_MESHES)
+def test_push_mean_rows_hands_its_ids_over_sorted(devices8, monkeypatch, D,
+                                                  S, seed):
+    """What ``push.mean_rows`` promises ``ops.scatter_add`` with
+    ``ids_sorted=True`` it keeps, on every shard, over hot, unowned and
+    negative ids: the ids it hands over are non-decreasing, none negative,
+    the dropped (``rps``) last; the live ones are the shard's distinct
+    ids, once each; the rows beside the dropped are exact zeros; and the
+    pushed table is the float64 per-id mean."""
+    R, dim = 65_536, 64
+    table, ids, deltas, want = _mean_push_case(D, S, R, dim, seed=seed)
+    rps, seen, plain = rows_per_shard(R, S), [], ops.scatter_add
+
+    def spy(t, i, d, **kw):
+        assert kw == {"ids_sorted": True}
+        jax.debug.callback(lambda i, d: seen.append(
+            (np.asarray(i), np.asarray(d))), i, d)
+        return plain(t, i, d, **kw)
+
+    monkeypatch.setattr(ops, "scatter_add", spy)
+    got, log = _push_on_mesh(devices8, D, S, table, ids, deltas,
+                             combine="mean")
+    jax.effects_barrier()
+    assert [r.route for r in log] == ["push.mean_rows", "scatter_add.xla"]
+    assert len(seen) == D * S  # every device of the mesh runs the push
+    for i, d in seen:
+        assert i.shape == (ids.shape[0],) and i.min() >= 0
+        assert (np.diff(i) >= 0).all() and i.max() == rps  # some dropped
+        assert (np.diff(i[i < rps]) > 0).all() and not d[i == rps].any()
+    # Every pushed id is live on the one shard that owns it (and on each
+    # of that shard's replicas along the data axis).
+    assert sum((i < rps).sum() for i, _ in seen) == D * len(
+        np.unique(ids[ids >= 0]))
+    assert np.abs(got - want).max() <= 1e-6 * np.abs(want).max()
 
 
 def test_push_mean_rows_sum_a_hot_id_from_zero(devices8):

@@ -72,6 +72,49 @@ def _plain_scatter(R, D, B, one_chip):
     return c.as_text()
 
 
+def _top_level(text):
+    """The instructions of a compiled module outside its entry computation
+    and its fused computations' bodies."""
+    for comp in text.split("\n}\n"):
+        head, _, body = comp.strip().partition("\n")
+        if not head.startswith(("ENTRY", "%fused_computation", "HloModule")):
+            yield from body.splitlines()
+
+
+def test_sorted_route_scatters_by_blocks_in_place(one_chip, monkeypatch):
+    """``w2v-1bw``'s out-table push, ``[1115011, 300]`` x 49,182 ids in a
+    loop whose carry is the table, by the sorted route: an inner loop of
+    dynamic trip count round ONE plain scatter fusion of a block's ids
+    (417,792 B of scoped VMEM, as the plain route's over all the ids), in
+    place on the carry. XLA's own emitter for a scatter it is TOLD is
+    sorted is not what the route uses: at this shape it takes 14.8 MB of
+    scoped VMEM and walks the whole table (7.2 ms whatever the ids; chip
+    run, PR 30)."""
+    monkeypatch.setattr(ops, "_use_pallas", lambda: (True, False))
+    R, D, B = 1_115_011, 300, 49_182
+
+    def steps(t, ids, deltas):
+        return lax.scan(lambda t, x: (ops.scatter_add(
+            t, *x, ids_sorted=True), None), t, (ids, deltas))[0]
+
+    ops.clear_routes()
+    c = _compiled(steps, one_chip, ((R, D), jnp.float32),
+                  ((2, B), jnp.int32), ((2, B, D), jnp.float32))
+    assert [r.route for r in ops.routes_traced()] == [
+        "scatter_add.xla_sorted"]
+    text = c.as_text()
+    assert "indices_are_sorted=true" not in text
+    sized = [ln for ln in _top_level(text)
+             if (m := re.search(rf"= f32\[{R},{D}\]\S* ([\w\-]+)\(", ln))
+             and m.group(1) not in ("get-tuple-element", "parameter")
+             and "/while/body/" in ln]
+    (fusion,) = sized
+    assert "/fps.ops/scatter_add.xla_sorted/while/body/" in fusion
+    assert "kind=kCustom" in fusion and '"size":"417792"' in fusion
+    assert len(re.findall(r" while\(", text)) == 2  # the scan, the blocks
+    assert c.memory_analysis().temp_size_in_bytes < 2 << 30  # one table
+
+
 @pytest.mark.parametrize("R", [120_048, 200_000])
 def test_plain_scatter_runs_in_vmem_up_to_the_edge(one_chip, R):
     """x4's user block (61.5 MB row-major tiled: passed over, ``vmem_fit``)
@@ -185,9 +228,10 @@ def test_w2v_epoch_program_fits_and_names_its_table_sized_work(
     ``[1115011, 300]`` tables, blocks of 8,192 tokens) for one described
     chip. Both mean pushes take the row branch (``push.mean_rows``): the
     only table-sized work left in the loop body is the two scatter-adds
-    into the tables themselves, in place on the carry; no ``[rows, 301]``
+    into the tables themselves, in place on the carry, a block of ids at
+    a time in the sorted route's own loops (PR 30); no ``[rows, 301]``
     accumulator anywhere, no copy, add or fill of a table a step. The
-    counts (two sorts of the pushed ids) and the multiply keep
+    counts (three sorts of the pushed ids) and the multiply keep
     ``fps.combine`` in the COMPILED text and make nothing of the table's
     length; temporaries under 4 GB of 16 (8 with the accumulators,
     PR 27). No CPU test can see a copy XLA puts round a scatter into a
@@ -225,21 +269,19 @@ def test_w2v_epoch_program_fits_and_names_its_table_sized_work(
         tables, (), iargs, jnp.int32(0), key).compile()
     assert [(r.route, r.dim, r.reason) for r in ops.routes_traced()] == [
         ("gather.xla", 300, "shape"), ("gather.xla", 300, "shape"),
-        ("push.mean_rows", 300, ""), ("scatter_add.xla", 300, "shape"),
-        ("push.mean_rows", 300, ""), ("scatter_add.xla", 300, "shape")]
+        ("push.mean_rows", 300, ""), ("scatter_add.xla_sorted", 300, ""),
+        ("push.mean_rows", 300, ""), ("scatter_add.xla_sorted", 300, "")]
     assert compiled.memory_analysis().temp_size_in_bytes < 4 << 30
     text = compiled.as_text()
     assert f"f32[{V},{D + 1}]" not in text
-    # The loop body: the computation the epoch's `while` names as its body.
-    (body,) = set(re.findall(r" while\(.*body=(%[\w.\-]+)", text))
-    start = text.index(f"\n{body} (")
-    loop = text[start:text.index("\n}\n", start)].splitlines()
-    sized = [ln for ln in loop
+    # Every computation but the entry (the epoch's loop body, and the
+    # bodies of the sorted route's own loops inside it).
+    sized = [ln for ln in _top_level(text)
              if (m := re.search(rf"= f32\[{V},{D}\]\S* ([\w\-]+)\(", ln))
-             and m.group(1) != "get-tuple-element"]
+             and m.group(1) not in ("get-tuple-element", "parameter")]
     assert len(sized) == 2 and all(
-        "/fps.ops/scatter_add.xla/scatter-add" in ln and " fusion(" in ln
-        for ln in sized), sized
+        "/fps.ops/scatter_add.xla_sorted/while/body/" in ln
+        and "/scatter-add" in ln and " fusion(" in ln for ln in sized), sized
     combine = [ln for ln in text.splitlines()
                if "fps.push/fps.combine/" in ln]
     assert any("/sort" in ln for ln in combine), combine

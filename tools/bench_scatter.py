@@ -15,6 +15,12 @@ over table rows x row width at uniform ids — the sweep that set
 ``combine="mean"``) by its accumulator branch against its row branch over
 rows x width x ids at Zipf(1.0) ids — the sweep that set
 ``ops.MEAN_ROWS_TABLE_RATIO`` — and the ways to count an id's pushes.
+
+``wide`` arm: the plain XLA scatter-add against the sorted route
+(``scatter_add.xla_sorted``: the same op by blocks of ids, stopping where
+the dropped ids begin) on Zipf(1.0) ids handed over as ``push.mean_rows``
+does, over rows x width x ids, wide rows on both sides of XLA's VMEM edge
+— the sweep ``ops._route_xla_sorted`` and its two constants stand on.
 """
 
 import os
@@ -202,14 +208,22 @@ def _zipf_ids(rng, R, shape, alpha=1.0):
                       R - 1).astype(np.int32)
 
 
-def _mean_case(R, D, B):
+def _mean_case(R, D, B, sorted_ids=False):
     """A table, ``T`` steps of Zipf(1.0) ids and deltas (at most ~1 GB of
     tiled deltas), and the runner of one program over them: us a step of
-    ``op(table, ids, deltas) -> table`` with the table a loop carry."""
+    ``op(table, ids, deltas) -> table`` with the table a loop carry.
+    ``sorted_ids``: each step's ids as ``push.mean_rows`` hands them to
+    ``ops.scatter_add``: the distinct ids sorted, at the front, the drop
+    sentinel ``R`` in the place of every duplicate, last."""
     rng = np.random.default_rng(R * 131 + D + B)
     T = int(max(4, min(64, (1 << 30) // (B * -(-D // 128) * 512))))
     tab = jnp.asarray(rng.normal(0, 0.1, (R, D)), jnp.float32)
-    ids = jnp.asarray(_zipf_ids(rng, R, (T, B)))
+    ids = _zipf_ids(rng, R, (T, B))
+    if sorted_ids:
+        ids = np.sort(ids, axis=1)
+        ids[:, 1:][ids[:, 1:] == ids[:, :-1]] = R
+        ids = np.sort(ids, axis=1)
+    ids = jnp.asarray(ids)
     deltas = jnp.asarray(rng.normal(0, 1e-2, (T, B, D)), jnp.float32)
 
     def us_a_step(op):
@@ -225,6 +239,7 @@ def _mean_case(R, D, B):
             best = min(best, time.perf_counter() - t0)
         return round(best / T * 1e6, 1), r
 
+    us_a_step.live = round(float(jnp.mean(jnp.sum(ids < R, axis=1))), 1)
     return us_a_step
 
 
@@ -327,22 +342,13 @@ def counts_point(R, D, B):
     return out
 
 
-def mean_sweep(args):
-    """``mean``: both branches of the mean push over rows x width x ids at
-    Zipf(1.0) ids (the sweep that set ``ops.MEAN_ROWS_TABLE_RATIO``), and
-    ``w2v-1bw``'s two shapes. ``mean counts``: the ways to count, and
-    both branches, at those two shapes alone. One JSON line a point, all
-    in ``chiprun_out/bench_scatter_mean.jsonl``."""
+def _write_points(name, points):
+    """Run ``fn(*args)`` for each of ``points``: one JSON line a point on
+    stdout, all of them in ``chiprun_out/bench_scatter_<name>.jsonl``."""
     import json
 
-    if args == ["counts"]:
-        points = [(counts_point, p) for p in W2V_1BW]
-    else:
-        points = [(mean_point, (R, D, B))
-                  for D in MEAN_D for B in MEAN_B for R in MEAN_R]
-        points += [(mean_point, p) for p in W2V_1BW]
     os.makedirs("chiprun_out", exist_ok=True)
-    with open("chiprun_out/bench_scatter_mean.jsonl", "a") as fh:
+    with open(f"chiprun_out/bench_scatter_{name}.jsonl", "a") as fh:
         fh.write(json.dumps({"device": jax.devices()[0].device_kind,
                              "platform": jax.default_backend()}) + "\n")
         for fn, p in points:
@@ -350,6 +356,67 @@ def mean_sweep(args):
             print(line, flush=True)
             fh.write(line + "\n")
             fh.flush()
+
+
+def mean_sweep(args):
+    """``mean``: both branches of the mean push over rows x width x ids at
+    Zipf(1.0) ids (the sweep that set ``ops.MEAN_ROWS_TABLE_RATIO``), and
+    ``w2v-1bw``'s two shapes. ``mean counts``: the ways to count, and
+    both branches, at those two shapes alone. One JSON line a point, all
+    in ``chiprun_out/bench_scatter_mean.jsonl``."""
+    if args == ["counts"]:
+        points = [(counts_point, p) for p in W2V_1BW]
+    else:
+        points = [(mean_point, (R, D, B))
+                  for D in MEAN_D for B in MEAN_B for R in MEAN_R]
+        points += [(mean_point, p) for p in W2V_1BW]
+    _write_points("mean", points)
+
+
+WIDE_R = (65_536, 131_072, 262_144, 1_115_011)
+WIDE_D = (64, 128, 300)
+WIDE_B = (8_192, 32_768, 49_182)
+WIDE_BLOCKS = (512, 1_024, 2_048, 4_096, 8_192)
+
+
+def wide_point(R, D, B, block=None):
+    """us a step of ``ops.scatter_add`` on sorted ids by the plain route
+    and by the sorted one (its predicate answering yes whatever the
+    shape while it is traced; ``block`` ids a block where given, else
+    ``ops.XLA_SORTED_BLOCK_IDS``), the route each logged, and the largest
+    gap of their tables over the largest value."""
+    import fps_tpu.ops as ops
+
+    us_a_step = _mean_case(R, D, B, sorted_ids=True)
+    out = {"rows": R, "dim": D, "ids": B, "tiled_mb": round(
+        ops._tiled_table_bytes(R, D, jnp.float32) / 1e6, 1),
+        "live": us_a_step.live,
+        "block": block or ops.XLA_SORTED_BLOCK_IDS}
+    keep = ops._route_xla_sorted, ops.XLA_SORTED_BLOCK_IDS
+    ops._route_xla_sorted = lambda R, D, B, dtype, ids_sorted: ids_sorted
+    ops.XLA_SORTED_BLOCK_IDS = out["block"]
+    try:
+        ops.clear_routes()
+        out["plain_us"], want = us_a_step(ops.scatter_add)
+        out["sorted_us"], got = us_a_step(
+            lambda t, i, d: ops.scatter_add(t, i, d, ids_sorted=True))
+        out["routes"] = [r.route for r in ops.routes_traced()]
+    finally:
+        ops._route_xla_sorted, ops.XLA_SORTED_BLOCK_IDS = keep
+    out["gap"] = _gap(got, want)
+    return out
+
+
+def wide_sweep(args):
+    """``wide``: the whole grid, then ``w2v-1bw``'s two shapes, then its
+    larger one under every block size. ``wide quick``: the last two
+    alone. One JSON line a point, all in
+    ``chiprun_out/bench_scatter_wide.jsonl``."""
+    points = list(W2V_1BW) + [(*W2V_1BW[1], b) for b in WIDE_BLOCKS]
+    if args != ["quick"]:
+        points = [(R, D, B) for D in WIDE_D for B in WIDE_B
+                  for R in WIDE_R] + points
+    _write_points("wide", [(wide_point, p) for p in points])
 
 
 if __name__ == "__main__":
@@ -361,11 +428,14 @@ if __name__ == "__main__":
         rows_sweep(sys.argv[2:])
     elif sys.argv[1:2] == ["mean"]:
         mean_sweep(sys.argv[2:])
+    elif sys.argv[1:2] == ["wide"]:
+        wide_sweep(sys.argv[2:])
     else:
         raise SystemExit(
             f"unknown args {sys.argv[1:]!r} — usage: bench_scatter.py "
-            "dim1|rows [quick]|mean [counts]  ('dim1' = scalar-table PA "
-            "shape; 'rows' = plain XLA against the lane-packed XLA route "
-            "over table rows x row width; 'mean' = the mean push's "
-            "accumulator against its row branch)"
+            "dim1|rows [quick]|mean [counts]|wide [quick]  ('dim1' = "
+            "scalar-table PA shape; 'rows' = plain XLA against the "
+            "lane-packed XLA route over table rows x row width; 'mean' = "
+            "the mean push's accumulator against its row branch; 'wide' = "
+            "the plain scatter-add against the sorted route on wide rows)"
         )
