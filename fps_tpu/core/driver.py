@@ -1117,6 +1117,9 @@ class Trainer:
                     # ride the same lane-packed kernel as live pulls on TPU.
                     # phys == ids on the single-device meshes where hp is
                     # nonempty, so the head guarantee survives the mapping.
+                    # Logged before the gather.* entry of the call it ends in.
+                    ops.log_route("pull", "snapshot", *snapshot[name].shape,
+                                  phys.shape[0])
                     pulled[name] = ops.gather_rows(
                         snapshot[name], phys,
                         hot_rows=self._resolve_hot_rows(
@@ -1381,6 +1384,26 @@ class Trainer:
                     folds[name] = fstate
         return (tables, hot, delta, folds) + tuple(carry[4:])
 
+    def _ssp_round(self, step, carry, xs, tier, gids=None):
+        """One SSP round, shared by the three step builders: the snapshot
+        of every table (an ``all_gather`` over the shard axis) at its
+        head, ``step(carry, x, snapshot)`` scanned over the round's ``xs``
+        (pulls read the snapshot, pushes land in the live tables), the hot
+        reconcile at its foot (identity when untiered), so the next
+        round's gather sees reconciled head rows. Gather and reconcile run
+        once a round under ``ssp.snapshot``: no ``fps.`` prefix, since a
+        reader counts steps by the ops under ``fps.*``
+        (``obs.timing.ROUND_SCOPES``). On one shard XLA drops the gather
+        and copies the live table itself, under no name: the scope then
+        holds no device op, and the round's cost is that copy's."""
+        with jax.named_scope("ssp.snapshot"):
+            snapshot = {name: lax.all_gather(tb, SHARD_AXIS, tiled=True)
+                        for name, tb in sorted(carry[0].items())}
+        carry, outs = lax.scan(lambda c, x: step(c, x, snapshot), carry, xs)
+        with jax.named_scope("ssp.snapshot"):
+            carry = self._reconcile_carry(carry, tier, gids)
+        return carry, outs
+
     def _windowed_scan(self, step, carry0, tier, *, head, tail,
                        gids=None):
         """Scan in reconcile windows: ``head`` is the stacked xs of the
@@ -1556,24 +1579,11 @@ class Trainer:
                  t) = carry
             else:
                 # SSP: batches leaves are (R, s, B_local, ...).
-                def round_body(carry, batches_r):
-                    tables = carry[0]
-                    snapshot = {
-                        name: lax.all_gather(tb, SHARD_AXIS, tiled=True)
-                        for name, tb in sorted(tables.items())
-                    }
-                    carry, outs = lax.scan(
-                        lambda c, b: step_fn(c, b, snapshot), carry,
-                        batches_r
-                    )
-                    # Hot reconcile rides the round boundary: the next
-                    # round's snapshot gather must see reconciled head
-                    # rows (identity when untiered).
-                    return self._reconcile_carry(carry, tier, gids), outs
-
                 (tables, hot, delta, fstates, sk, bufs, local_state, _,
-                 t), outs = (
-                    lax.scan(round_body, carry0, batches))
+                 t), outs = lax.scan(
+                    lambda c, batches_r: self._ssp_round(
+                        step_fn, c, batches_r, tier, gids),
+                    carry0, batches)
                 outs = jax.tree.map(
                     lambda x: x.reshape((-1,) + x.shape[2:]), outs
                 )
@@ -1874,23 +1884,12 @@ class Trainer:
                 )
                 return finish(carry, outs)
 
-            def round_body(carry, r):
-                tables = carry[0]
-                snapshot = {
-                    name: lax.all_gather(tb, SHARD_AXIS, tiled=True)
-                    for name, tb in sorted(tables.items())
-                }
-                carry, outs = lax.scan(
-                    lambda c, t: step_t(c, t, snapshot), carry,
-                    start + r * s + jnp.arange(s, dtype=jnp.int32),
-                )
-                # Hot reconcile rides the round boundary (identity when
-                # untiered): the next snapshot gather sees reconciled
-                # head rows.
-                return self._reconcile_carry(carry, tier, gids), outs
-
             carry, outs = lax.scan(
-                round_body, carry0, jnp.arange(T // s, dtype=jnp.int32),
+                lambda c, r: self._ssp_round(
+                    step_t, c,
+                    start + r * s + jnp.arange(s, dtype=jnp.int32),
+                    tier, gids),
+                carry0, jnp.arange(T // s, dtype=jnp.int32),
             )
             outs = jax.tree.map(lambda x: x.reshape((-1,) + x.shape[2:]), outs)
             return finish(carry, outs)

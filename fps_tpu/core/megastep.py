@@ -226,19 +226,12 @@ def build_megastep_fn(trainer, plan, mode: str, K: int, tick=None):
                         gids=gids,
                     )
             else:
-                def round_body(c, r):
-                    snapshot = {
-                        name: lax.all_gather(tb, SHARD_AXIS, tiled=True)
-                        for name, tb in sorted(c[0].items())
-                    }
-                    c, outs = lax.scan(
-                        lambda cc, t: step_t(cc, t, snapshot), c,
-                        start + r * s + jnp.arange(s, dtype=jnp.int32),
-                    )
-                    return trainer._reconcile_carry(c, tier, gids), outs
-
                 c1, outs = lax.scan(
-                    round_body, c0, jnp.arange(T // s, dtype=jnp.int32))
+                    lambda c, r: trainer._ssp_round(
+                        step_t, c,
+                        start + r * s + jnp.arange(s, dtype=jnp.int32),
+                        tier, gids),
+                    c0, jnp.arange(T // s, dtype=jnp.int32))
                 outs = jax.tree.map(
                     lambda x: x.reshape((-1,) + x.shape[2:]), outs)
             (tables, hot, delta, fstates, sk, local_state, _) = c1

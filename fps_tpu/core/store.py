@@ -1005,6 +1005,12 @@ def push(
         # per-id sums and counts ride ONE scatter (counts as an appended
         # ones column) — the scatter is per-row-transaction bound on TPU,
         # so a second scatter for counts would double its cost.
+        if apply_fn is not None and combine != "mean":
+            # A stateful fold under "sum" (or a callable combine): the
+            # (rows, dim + 1) accumulator, apply_fn over the whole shard
+            # and a table-sized where (a mean push logged its own branch).
+            ops.log_route("push", "fold", rps, dim, local_idx.shape[0],
+                          "apply_fn")
         withcnt = jnp.concatenate(
             [masked.astype(acc_dt), owned.astype(acc_dt)[:, None]],
             axis=1,

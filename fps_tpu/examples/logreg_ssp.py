@@ -28,12 +28,14 @@ from fps_tpu.examples.common import (
     maybe_serve,
     maybe_warm_start,
 )
+from fps_tpu.utils.datasets import CRITEO_NUM_FEATURES
 
 
 def main(argv=None) -> int:
     ap = base_parser("SSP logistic regression on the TPU PS")
-    ap.add_argument("--num-features", type=int, default=1 << 18,
-                    help="hashed feature space size")
+    ap.add_argument("--num-features", type=int, default=CRITEO_NUM_FEATURES,
+                    help="hashed feature space size (default: the Criteo "
+                         "loader's own, 1,000,000)")
     ap.add_argument("--num-examples", type=int, default=100_000)
     ap.add_argument("--nnz", type=int, default=32)
     ap.add_argument("--learning-rate", type=float, default=0.1)

@@ -98,8 +98,6 @@ class LogisticRegressionWorker(WorkerLogic):
         host-uncertifiable (None), like negative-sampling MF."""
         if self.cfg.dense_features:
             return None
-        import numpy as np
-
         ids = np.asarray(chunk["feat_ids"])
         if ids.ndim >= 2:
             # (..., B, nnz) -> (..., B*nnz): worker-major blocks survive.

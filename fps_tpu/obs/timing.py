@@ -88,14 +88,19 @@ COMPILE_PHASES = ("compile.trace", "compile.lower", "compile.backend")
 # inside ``fps.push`` beside the routed scatter's ``fps.ops`` (under which
 # ``fps_tpu.ops`` names the route); the rest belong to the tiered and
 # megastep paths. Programs that run once a call or once a chunk are named
-# WITHOUT the prefix (ONCE_SCOPES): a reader counts steps by the ops
-# under ``fps.*``. A test walks the tree against both lists.
+# WITHOUT the prefix (ONCE_SCOPES), and so is what a compiled loop runs
+# once a ROUND of steps (ROUND_SCOPES: ``ssp.snapshot``, the SSP round's
+# snapshot gather and its hot reconcile, ``Trainer._ssp_round``): a reader
+# counts steps by the ops under ``fps.*``, and an op that runs once in
+# ``sync_every`` steps would sit in that count at a fraction of a step. A
+# test walks the tree against the three lists.
 STEP_SCOPES = ("fps.ingest", "fps.prepare", "fps.sketch", "fps.pull",
                "fps.compute", "fps.push", "fps.combine", "fps.ops",
                "fps.hot_accumulate", "fps.reconcile", "fps.sketch_merge",
                "fps.megastep_vote", "fps.megastep_tick", "fps.metrics")
 ONCE_SCOPES = ("ingest.pack", "ingest.tbuf", "ingest.perm", "ingest.chunk",
                "ingest.compact")
+ROUND_SCOPES = ("ssp.snapshot",)
 # Set-up spans (no timer: they report through the process-default
 # recorder). Those that queue device work close on its completion when a
 # recorder is installed, and only then (settle()).

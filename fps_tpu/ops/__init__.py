@@ -75,8 +75,11 @@ class Route(NamedTuple):
     """One routing decision, logged where the chosen call is made — at
     TRACE time, never in the compiled program."""
     op: str          # "gather" | "scatter_add" | "push" (the store's choice
-                     # of a mean-combine's branch: fps_tpu.core.store.push)
-    route: str       # "gather.dim1_head", "scatter_add.xla", ...
+                     # of a combine's branch: fps_tpu.core.store.push) |
+                     # "pull" (the driver's read of the SSP snapshot)
+    route: str       # "gather.dim1_head", "scatter_add.xla", ...;
+                     # "push.mean_rows" / "push.mean_dense" / "push.fold";
+                     # "pull.snapshot"
     rows: int        # rows of the table (slice) the call sees
     dim: int
     ids: int         # ids the call moves
@@ -85,7 +88,8 @@ class Route(NamedTuple):
                      # passed over: "" (taken, or exact read asked for),
                      # "f64", "backend", "shape",
                      # "vmem_fit" (the plain XLA op already runs in VMEM);
-                     # of "push.mean_dense": "fold", "dtype", "small_table"
+                     # of "push.mean_dense": "fold", "dtype", "small_table";
+                     # of "push.fold": "apply_fn"
 
 
 _ROUTES_TRACED: list[Route] = []
@@ -168,7 +172,8 @@ def _use_pallas() -> tuple[bool, bool]:
 # measured 1.5 (scatter) / 1.6 (gather) ms vs XLA's 7.6 / 8.1 ms per
 # call. Kernel cost scales with ceil(R/128) once MAC-bound, so the win
 # inverts above the cap below — MEASURED with the v2 kernels at the
-# logreg stream shape (B = 426k Zipf(0.9) ids, round 5,
+# logreg stream shape (B = 426k Zipf(0.9) ids, round 5's runtime, an
+# EARLIER one than the current v5e installation's,
 # tools/bench_logreg_routes.py stage b on a v5 lite chip):
 #
 #   R        dim1 scatter/gather   XLA scatter/gather
@@ -176,6 +181,13 @@ def _use_pallas() -> tuple[bool, bool]:
 #   262k     2.93 / 3.11 ms        3.67 / 3.89 ms   (dim1 still wins)
 #   524k     5.53 / 5.78 ms        3.89 / 4.35 ms   (XLA wins)
 #   1M      12.09 / 11.44 ms       6.15 / 5.29 ms   (XLA wins 2x+)
+#
+# The XLA column does not carry to the current runtime at every width: the
+# same stream on [1000000, 2] (cell lr-criteo.epochs, 425,997 ids a step
+# through gather.xla / scatter_add.xla, chip run, PR 32) reads 2.25 ms for
+# the gather (5.3 ns an id) and 19.8 ms for the scatter-add into the
+# AdaGrad fold's [1000000, 3] accumulator (46.5 ns an id: PR 25's sweep
+# read 44 at [1048576, 8]), the accumulator kept transposed and in VMEM.
 #
 # The cap sits at the last measured win (262144) — a THIN (~20%) margin
 # verified only at the single-chip logreg stream shape above (B = 426k

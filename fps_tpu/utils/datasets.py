@@ -394,7 +394,14 @@ def _parse_criteo_py(path: str, num_features: int):
     )
 
 
-def load_criteo(path: str, *, num_features: int = 1 << 20,
+# The hashed feature space of a Criteo click log, wherever one is defaulted
+# (here, ``load_sparse``, ``examples/logreg_ssp.py --num-features``): the
+# 1,000,000 features LIBSVM's ``criteo`` set publishes and the benchmark's
+# ``lr-criteo`` configuration runs.
+CRITEO_NUM_FEATURES = 1_000_000
+
+
+def load_criteo(path: str, *, num_features: int = CRITEO_NUM_FEATURES,
                 use_native: bool | None = None):
     """Load a Criteo click-log TSV (label + 13 numeric + 26 categorical).
 
@@ -456,8 +463,9 @@ def load_sparse(path: str, *, fmt: str = "auto",
         return load_svmlight(path, num_features=num_features,
                              nnz_cap=nnz_cap, use_native=use_native)
     if fmt == "criteo":
-        return load_criteo(path, num_features=num_features or (1 << 20),
-                           use_native=use_native)
+        return load_criteo(
+            path, num_features=num_features or CRITEO_NUM_FEATURES,
+            use_native=use_native)
     raise ValueError(f"unknown sparse dataset format {fmt!r}")
 
 def synthetic_sparse_classification(

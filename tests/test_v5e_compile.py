@@ -16,6 +16,7 @@ import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax import lax
 
@@ -322,3 +323,95 @@ def test_mf_epoch_step_keeps_the_accumulator_for_its_small_table(topo):
     pushes = [r for r in ops.routes_traced() if r.op == "push"]
     assert pushes == [ops.Route("push", "push.mean_dense", 17_770, rank, B,
                                 False, "small_table")], pushes
+
+
+@pytest.mark.parametrize("shards", [1, 4])
+def test_lr_criteo_epoch_program_names_its_round_and_fits(topo, monkeypatch,
+                                                          shards):
+    """``lr-criteo.epochs``'s epoch program at the cell's own size (a
+    ``[1000000, 2]`` table under rounds of 8 steps, 16,384 rows x 39 slots
+    a worker a step, 2^23 resident rows) for one described chip, and the
+    same job over four. Read from the COMPILED text, since a scope round
+    an op XLA drops names nothing. On FOUR shards the round's snapshot is
+    an all-gather of the ``[250000, 2]`` shards under ``ssp.snapshot``
+    (and the relayout of what it gathered). On ONE the gather is gone and
+    the round's copy of the live table is XLA's own, without a name: no
+    device op is under ``ssp.snapshot``. On one chip also: the stateful
+    fold's ``(rows, dim + 1)`` accumulator is kept transposed and in VMEM
+    (16 MB, not the 512 MB of its row-major tiles), and so is the select
+    that writes the table back; the step holds one gather and one scatter
+    of 425,997 rows and every table-sized op of the body is the fold's,
+    under ``fps.combine``."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from fps_tpu import DeviceEpochPlan
+    from fps_tpu.models.logistic_regression import (
+        LogRegConfig, logistic_regression,
+    )
+    from fps_tpu.parallel.mesh import make_ps_mesh
+
+    monkeypatch.setattr(ops, "_use_pallas", lambda: (True, False))
+    F, B, N, s, slots = 1_000_000, 16_384, 1 << 23, 8, 39
+    q = N // shards
+    mesh = make_ps_mesh(num_shards=shards,
+                        devices=list(topo.devices)[:shards])
+    trainer, _ = logistic_regression(
+        mesh, LogRegConfig(num_features=F, learning_rate=0.001,
+                           optimizer="adagrad", dense_features=13),
+        sync_every=s)
+    # The plan's geometry without its uploads (no device holds an array).
+    plan = object.__new__(DeviceEpochPlan)
+    plan.local_batch, plan.shuffle, plan.num_workers = B, "interleave", shards
+    plan.sync_every, plan.maxq, plan.grid_r = s, q, 4096
+    plan.counts = np.full(shards, q, np.int32)
+    plan.grid_c = np.full(shards, q // 4096, np.int32)
+    plan.grid_m = np.full(shards, q, np.int32)
+    plan.steps_per_epoch = 520 // shards // s * s
+
+    def shape(sh, dtype, spec=P()):
+        return jax.ShapeDtypeStruct(sh, dtype,
+                                    sharding=NamedSharding(mesh, spec))
+
+    key = shape((), jax.random.key(0).dtype)
+    tables = {"weights": shape((F, 2), jnp.float32, P("shard", None))}
+    iargs = {"columns": {"feat_ids": shape((N, slots), jnp.int32),
+                         "feat_vals": shape((N, slots), jnp.float32),
+                         "label": shape((N,), jnp.float32)},
+             "queues": shape((shards, q), jnp.int32),
+             "off_w": shape((shards,), jnp.int32),
+             "perm": shape((1, 1), jnp.int32)}
+    rows = B * (slots - 13) + 13
+    ops.clear_routes()
+    compiled = trainer._build_indexed_fn(plan, "ssp").lower(
+        tables, (), iargs, jnp.int32(0), key).compile()
+    assert [(r.route, r.rows, r.dim, r.ids, r.reason)
+            for r in ops.routes_traced()] == [
+        ("pull.snapshot", F, 2, rows, ""),
+        ("gather.xla", F, 2, rows, "shape"),
+        ("push.fold", F // shards, 2, rows * shards, "apply_fn"),
+        ("scatter_add.xla", F // shards, 3, rows * shards, "shape")]
+    text = compiled.as_text()
+    snap = [ln for ln in text.splitlines() if "/ssp.snapshot/" in ln]
+    assert not [ln for ln in snap if "/fps." in ln]
+    if shards > 1:
+        gathers = [ln for ln in snap if " all-gather(" in ln]
+        assert len(gathers) == 1, snap
+        assert f"f32[{shards},{F // shards},2]" in gathers[0]
+        return
+    assert snap == []
+    # The round's copy of the live table: XLA's own, and nameless.
+    copies = [ln for ln in text.splitlines()
+              if re.search(rf"= f32\[{F},2\]\S* copy\(", ln)]
+    assert copies and not [ln for ln in copies if "op_name" in ln], copies
+    mem = compiled.memory_analysis()
+    assert 2.6e9 < mem.argument_size_in_bytes < 2.9e9     # the columns
+    assert mem.temp_size_in_bytes < 256 << 20
+    acc = [ln for ln in text.splitlines()
+           if re.search(rf"= f32\[{F},3\]\S* fusion\(", ln)]
+    assert len(acc) == 1 and "{0,1:T(4,128)S(1)}" in acc[0], acc
+    assert "/fps.push/fps.ops/scatter_add.xla/" in acc[0]
+    sized = [ln for ln in _top_level(text)
+             if re.search(rf"= \w+\[{F},\d\]\S* fusion\(", ln)]
+    assert sized and all("/fps.push/fps.combine/" in ln
+                         or "/fps.push/fps.ops/scatter_add.xla/" in ln
+                         for ln in sized), sized

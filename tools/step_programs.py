@@ -1,0 +1,205 @@
+"""Are the accepted cells' step programs the same, instruction for
+instruction, in two trees? Compile-only: no chip is touched.
+
+    python tools/step_programs.py write <tree> <out_dir>
+    python tools/step_programs.py diff <out_dir_a> <out_dir_b>
+
+``write`` imports ``fps_tpu`` FROM ``tree`` (a checkout: this one, or a
+``git archive`` of the parent), builds the step program of each cell the
+benchmark had before ``lr-criteo`` at the cell's own shapes
+(``mf-netflix.epochs``, ``pa-rcv1.epochs``, ``mf-netflix.x4``,
+``w2v-1bw.epochs``), compiles it for a described ``v5e:2x2`` with the ops
+layer routing as on the chip, and writes the compiled text with metadata,
+stack frames and location tables dropped, and the route log, under
+``out_dir``. One process per tree (a process imports one ``fps_tpu``).
+``diff`` counts the instructions of each program and the lines that
+differ; what is left are Pallas kernels' debug strings, which hold the
+checkout's path: it says so when the two differ in nothing else. Exit 1
+if a program or a route log differs otherwise.
+
+The method is PR 29's (PERF.md section 6); a PR that must leave the other
+cells' programs alone shows it this way before it spends chip time.
+"""
+
+from __future__ import annotations
+
+import json
+import os
+import re
+import sys
+
+CELLS = ("mf-netflix.epochs", "pa-rcv1.epochs", "mf-netflix.x4",
+         "w2v-1bw.epochs")
+
+
+def _normalised(text: str) -> str:
+    text = re.sub(r", metadata=\{[^}]*\}", "", text)
+    text = re.sub(r"stack_frame_id=\d+", "", text)
+    tables = ("FileNames", "FunctionNames", "FileLocations", "StackFrames")
+    return "\n".join(ln for ln in text.splitlines()
+                     if not ln.startswith(tables)
+                     and not re.match(r"^\d+ ", ln))
+
+
+def write(tree: str, out: str) -> None:
+    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    tree = os.path.abspath(tree)
+    sys.path.insert(0, tree)
+    os.makedirs(out, exist_ok=True)
+
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import topologies
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    import fps_tpu
+    import fps_tpu.ops as ops
+    from fps_tpu.parallel.mesh import make_ps_mesh
+
+    if not fps_tpu.__file__.startswith(tree + os.sep):
+        raise SystemExit(f"imported {fps_tpu.__file__}, not {tree}'s")
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+    ops._use_pallas = lambda: (True, False)
+
+    def mesh_of(n):
+        mesh = make_ps_mesh(num_shards=n, devices=list(topo.devices)[:n])
+        return mesh, lambda s, d, spec=P(): jax.ShapeDtypeStruct(
+            s, d, sharding=NamedSharding(mesh, spec))
+
+    def emit(name, lower):
+        ops.clear_routes()
+        text = _normalised(lower().compile().as_text())
+        routes = [list(r) for r in ops.routes_traced()]
+        with open(os.path.join(out, name + ".txt"), "w") as f:
+            f.write(text)
+        with open(os.path.join(out, name + ".routes.json"), "w") as f:
+            json.dump(routes, f)
+        print(name, sum(" = " in ln for ln in text.splitlines()),
+              "instructions", len(routes), "routes", flush=True)
+
+    workers = P(None, ("data", "shard"))
+
+    def model(config, part="model"):
+        """A group of ``tree``'s own configuration file."""
+        with open(os.path.join(tree, "perfbench", "configs",
+                               config + ".json")) as f:
+            return json.load(f)[part]
+
+    def mf(n):
+        from fps_tpu.models.matrix_factorization import MFConfig, online_mf
+
+        m = model("mf-netflix")
+        U, I, rank = m["num_users"], m["num_items"], m["rank"]
+        mesh, shape = mesh_of(n)
+        trainer, _ = online_mf(
+            mesh, MFConfig(num_users=U, num_items=I, rank=rank,
+                           learning_rate=m["learning_rate"], reg=m["reg"]),
+            combine=m["combine"])
+        users, items = -(-U // n) * n, -(-I // n) * n
+        tables = {"item_factors": shape((items, rank), jnp.float32,
+                                        P("shard", None))}
+        local = shape((users, rank), jnp.float32, P(("data", "shard")))
+        batches = {k: shape((2, m["local_batch"] * n), d, workers)
+                   for k, d in (("user", jnp.int32), ("item", jnp.int32),
+                                ("rating", jnp.float32),
+                                ("weight", jnp.float32))}
+        key = shape((), jax.random.key(0).dtype)
+        emit("mf-netflix." + ("epochs" if n == 1 else "x4"),
+             lambda: trainer._build_chunk_fn("sync").lower(
+                 tables, local, batches, key))
+
+    def pa():
+        from fps_tpu.models.passive_aggressive import (
+            PAConfig, passive_aggressive,
+        )
+
+        m = model("pa-rcv1")
+        mesh, shape = mesh_of(1)
+        trainer, _ = passive_aggressive(
+            mesh, PAConfig(num_features=m["num_features"],
+                           variant=m["variant"], C=m["C"],
+                           hot_features=m["head_features"],
+                           head_prefix_cols=m["head_prefix_cols"]))
+        tables = {"weights": shape((m["num_features"], 1), jnp.float32,
+                                   P("shard", None))}
+        B, nnz = m["local_batch"], model("pa-rcv1", "data")["nnz"]
+        batches = {"feat_ids": shape((2, B, nnz), jnp.int32, workers),
+                   "feat_vals": shape((2, B, nnz), jnp.float32, workers),
+                   "label": shape((2, B), jnp.float32, workers),
+                   "weight": shape((2, B), jnp.float32, workers)}
+        key = shape((), jax.random.key(0).dtype)
+        emit("pa-rcv1.epochs",
+             lambda: trainer._build_chunk_fn("sync").lower(
+                 tables, (), batches, key))
+
+    def w2v():
+        from fps_tpu.models.word2vec import (
+            W2VConfig, Word2VecDevicePlan, word2vec_block,
+        )
+
+        m = model("w2v-1bw")
+        # T: the steps of an epoch over the cell's 2^21 resident tokens.
+        V, D, L, T = m["vocab_size"], m["dim"], m["block_len"], 172
+        mesh, shape = mesh_of(1)
+        cfg = W2VConfig(vocab_size=V, dim=D)
+        trainer, _ = word2vec_block(mesh, cfg, 1.0 / (jnp.arange(V) + 1.5), L)
+        # The plan's geometry without its uploads.
+        plan = object.__new__(Word2VecDevicePlan)
+        plan.cfg, plan.mode, plan.num_workers, plan.block_len = (
+            cfg, "block", 1, L)
+        plan.steps_per_epoch, plan.sync_every = T, None
+        key = shape((), jax.random.key(0).dtype)
+        tables = {n: shape((V, D), jnp.float32, P("shard", None))
+                  for n in ("in_embeddings", "out_embeddings")}
+        iargs = {"compacted": shape((T * L + cfg.window,), jnp.int32),
+                 "kept": shape((), jnp.int32), "wkey": key}
+        emit("w2v-1bw.epochs",
+             lambda: trainer._build_indexed_fn(plan, "sync").lower(
+                 tables, (), iargs, jnp.int32(0), key))
+
+    mf(1)
+    pa()
+    mf(4)
+    w2v()
+
+
+def diff(a: str, b: str) -> int:
+    worse = 0
+    for name in CELLS:
+        with open(os.path.join(a, name + ".txt")) as f:
+            ta = f.read().splitlines()
+        with open(os.path.join(b, name + ".txt")) as f:
+            tb = f.read().splitlines()
+        with open(os.path.join(a, name + ".routes.json")) as f:
+            ra = json.load(f)
+        with open(os.path.join(b, name + ".routes.json")) as f:
+            rb = json.load(f)
+        differ = [(x, y) for x, y in zip(ta, tb) if x != y]
+        # A Mosaic body carries the checkout's path in its debug strings.
+        other = [p for p in differ if "custom_call_target=\"tpu_custom_call\""
+                 not in p[0] or "custom_call_target=\"tpu_custom_call\""
+                 not in p[1]]
+        same = len(ta) == len(tb) and not other and ra == rb
+        print(name, sum(" = " in ln for ln in ta), "instructions;",
+              f"{len(differ)} lines differ, all of them Mosaic bodies;"
+              if differ and not other else f"{len(other)} lines differ;",
+              "route logs", "equal" if ra == rb else "DIFFER")
+        worse += not same
+    return 1 if worse else 0
+
+
+def main(argv) -> int:
+    if len(argv) == 3 and argv[0] == "write":
+        write(argv[1], argv[2])
+        return 0
+    if len(argv) == 3 and argv[0] == "diff":
+        return diff(argv[1], argv[2])
+    print(__doc__, file=sys.stderr)
+    return 2
+
+
+if __name__ == "__main__":
+    sys.exit(main(sys.argv[1:]))
