@@ -707,6 +707,9 @@ def pull(
 # (``push.mean_rows``) it is PAYLOAD-SIZED: the per-id counts and the
 # distinct ids compacted to the front (three sorts of the B ids), the
 # multiply of the B pushed rows and their sum by id in a (B, dim) buffer.
+# And where the accumulator branch sums the pushed rows by id run before
+# its scatter (``push.acc_runs``): two sorts of the B ids carrying the
+# rows' columns and the doubling passes between them, payload-sized too.
 # The routed scatter-add keeps its own ``fps.ops/...``
 # scope BESIDE this one, so no op counts under both
 # (docs/observability.md).
@@ -740,6 +743,74 @@ def _id_runs(idx: Array, drop: int) -> tuple[Array, Array, Array]:
     run = jnp.cumsum(first.astype(jnp.int32)) - 1
     _, n, slot = lax.sort((order, hi - lo + 1, run), num_keys=1)
     return n, slot, jnp.sort(jnp.where(first, s, drop))
+
+
+def _run_sums(first: Array, cols: tuple[Array, ...]) -> tuple[Array, ...]:
+    """Inclusive sums of each of ``cols`` (``[B]`` arrays) WITHIN the runs
+    whose first elements ``first [B]`` flags: a run's last element holds
+    its total. A segmented scan of log depth by doubling: at distance
+    ``s`` an element adds the one ``s`` before it unless a run began in
+    between, ``ceil(log2(B))`` elementwise passes over ``[B]`` arrays. A
+    run's addends meet in a balanced tree, so its total carries the
+    rounding of ``log2(run length)`` additions of its OWN addends; the
+    difference of two running sums over the batch would carry that of a
+    prefix of the whole batch."""
+    B = first.shape[0]
+    began, s = first, 1
+    while s < B:
+        cols = tuple(
+            jnp.where(began, c, c + jnp.pad(c[:-s], (s, 0))) for c in cols)
+        began = began | jnp.pad(began[:-s], (s, 0), constant_values=True)
+        s *= 2
+    return cols
+
+
+def _sum_id_runs(idx: Array, rows: Array, drop: int) -> tuple[Array, Array]:
+    """The rows of a batch summed by index, and counted: ``(ids [B], sums
+    [B, W + 1])`` from ``idx [B]`` (``drop`` for a row to leave out) and
+    ``rows [B, W]``: the distinct indices SORTED at the front of ``ids``,
+    beside each the sum of its rows and, last column, their number, and
+    ``drop`` past the last of them (the rows beside those are to be
+    dropped, not zeros). One sort of the indices that carries the rows'
+    columns, the sums within each run of equal indices (:func:`_run_sums`:
+    a tree within the run, no scatter and no running sum over the batch;
+    a ones column rides along and sums to the count, exactly), the total
+    kept on the run's last element and every other element given
+    ``drop``, and one sort more that brings the totals to the front.
+    Neither sort is stable: the order of one index's addends is the
+    tree's whatever the sort, its second keys are distinct but for
+    ``drop``, and a stable TPU sort takes twice as long to compile (69 s
+    against 36 with three operands, compile-only, PR 33's builder). On
+    ``lr-criteo.epochs``' 425,997 ids of which 75,553 are distinct, 1.56
+    ms (``tools/bench_scatter.py fold probes``, that builder's chip run:
+    the first sort 0.91, the second 0.73, the 19 doubling passes under
+    0.1; ``lax.associative_scan`` in their place 4.25 in all, positions
+    and two gathers of the rows 5.75)."""
+    W = rows.shape[1]
+    s, *cols = lax.sort((idx, *(rows[:, j] for j in range(W))), num_keys=1,
+                        is_stable=False)
+    edge = s[1:] != s[:-1]
+    one = jnp.ones((1,), bool)
+    cols = _run_sums(jnp.concatenate([one, edge]),
+                     (*cols, (s != drop).astype(rows.dtype)))
+    last = jnp.concatenate([edge, one])
+    ids, *cols = lax.sort((jnp.where(last, s, drop), *cols), num_keys=1,
+                          is_stable=False)
+    return ids, jnp.stack(cols, axis=1)
+
+
+def _acc_runs_route(rps: int, dim: int, num_ids: int, acc_dt) -> bool:
+    """Sum the pushed rows by id run before the ``(rps, dim + 1)``
+    accumulator's scatter (``push.acc_runs``)? From :func:`push`'s own
+    shapes, whatever the platform: the accumulator is one XLA keeps
+    transposed (:func:`fps_tpu.ops._xla_transposed`: there the scatter
+    pays some 45 ns for every id it is handed, a repeat or not, and the
+    sorted route stops at the last distinct one; inside XLA's VMEM regime
+    an id costs a few ns and the sorts would not pay), and the ids are
+    many enough against the rows that a batch without skew does not lose
+    (:data:`fps_tpu.ops.ACC_RUNS_MIN_IDS_PER_ROW`)."""
+    return (ops._xla_transposed(rps, dim + 1, acc_dt)
+            and num_ids >= ops.ACC_RUNS_MIN_IDS_PER_ROW * rps)
 
 
 def _mean_push_ratio(rps: int, dim: int, num_ids: int, dtype) -> float:
@@ -834,8 +905,18 @@ def push(
       apply_fn: fold function ``(current_rows, summed_delta) -> new_rows``;
         defaults to addition (the reference's ``paramUpdate = _ + _``,
         ``SimplePSLogic``). Non-additive folds see the batch-combined delta
-        once per id (duplicates are pre-combined with ``segment_sum``) and
-        are applied only to rows with at least one non-dropped push.
+        once per id and are applied only to rows with at least one
+        non-dropped push: an id's pushes and their count are summed in a
+        zeroed ``(rps, dim + 1)`` accumulator by ONE scatter-add (a ones
+        column rides it), ``apply_fn`` runs over the whole shard and a
+        ``where`` by ``count > 0`` keeps every other row bit for bit. Where
+        that accumulator is one XLA keeps transposed, under enough ids a
+        row (:func:`_acc_runs_route`: ``push.acc_runs`` in the route log), the
+        pushed rows are first summed by id run from sorts of the batch
+        (:func:`_sum_id_runs`), and the scatter is handed each distinct id
+        once, sorted, and nothing after the last: its cost follows the
+        distinct ids, not the pushes. The same for a callable ``combine``
+        and a mean push that keeps its accumulator.
       combine: how duplicate ids within one push combine — the analog of
         the reference's pluggable combining senders (user-supplied
         ``CombinationLogic``, expected upstream ``.../ps/client/sender/``):
@@ -947,8 +1028,8 @@ def push(
     # a float64 table must fold its duplicates in float64 (hard-coding f32
     # here would silently shave 29 mantissa bits off every non-"sum" push).
     acc_dt = jnp.promote_types(local_shard.dtype, jnp.float32)
+    B = local_idx.shape[0]
     if combine == "mean":
-        B = local_idx.shape[0]
         route, reason = _mean_push_route(local_shard, B, apply_fn)
         ops.log_route("push", route, rps, dim, B, reason)
         if route == "mean_rows":
@@ -1009,12 +1090,26 @@ def push(
             # A stateful fold under "sum" (or a callable combine): the
             # (rows, dim + 1) accumulator, apply_fn over the whole shard
             # and a table-sized where (a mean push logged its own branch).
-            ops.log_route("push", "fold", rps, dim, local_idx.shape[0],
-                          "apply_fn")
-        withcnt = jnp.concatenate(
-            [masked.astype(acc_dt), owned.astype(acc_dt)[:, None]],
-            axis=1,
-        )
+            ops.log_route("push", "fold", rps, dim, B, "apply_fn")
+        runs = _acc_runs_route(rps, dim, B, acc_dt)
+        if runs:
+            # Into an accumulator XLA keeps transposed the scatter pays
+            # for every id it is handed, and most of a large batch's ids
+            # are repeats: the rows of one id are summed first (a ones
+            # column to its count), and the scatter sees each distinct id
+            # once, sorted, and the drop sentinel after the last of them.
+            ops.log_route("push", "acc_runs", rps, dim, B,
+                          "mean_dense" if combine == "mean"
+                          else "fold" if apply_fn is not None
+                          else "callable")
+            with jax.named_scope(COMBINE_SCOPE):
+                local_idx, withcnt = _sum_id_runs(
+                    local_idx, masked.astype(acc_dt), rps)
+        else:
+            withcnt = jnp.concatenate(
+                [masked.astype(acc_dt), owned.astype(acc_dt)[:, None]],
+                axis=1,
+            )
         with jax.named_scope(COMBINE_SCOPE):
             # A zero the compiler cannot see through. As a broadcast of a
             # literal, XLA's TPU pipeline re-made the fill under the loop
@@ -1024,7 +1119,7 @@ def push(
             zeros = jnp.broadcast_to(
                 lax.optimization_barrier(jnp.zeros((), acc_dt)),
                 (rps, dim + 1))
-        acc = ops.scatter_add(zeros, local_idx, withcnt)
+        acc = ops.scatter_add(zeros, local_idx, withcnt, ids_sorted=runs)
         with jax.named_scope(COMBINE_SCOPE):
             combined, counts = acc[:, :dim], acc[:, dim]
             if combine == "mean":

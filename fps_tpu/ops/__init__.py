@@ -78,8 +78,8 @@ class Route(NamedTuple):
                      # of a combine's branch: fps_tpu.core.store.push) |
                      # "pull" (the driver's read of the SSP snapshot)
     route: str       # "gather.dim1_head", "scatter_add.xla", ...;
-                     # "push.mean_rows" / "push.mean_dense" / "push.fold";
-                     # "pull.snapshot"
+                     # "push.mean_rows" / "push.mean_dense" / "push.fold"
+                     # / "push.acc_runs"; "pull.snapshot"
     rows: int        # rows of the table (slice) the call sees
     dim: int
     ids: int         # ids the call moves
@@ -89,7 +89,9 @@ class Route(NamedTuple):
                      # "f64", "backend", "shape",
                      # "vmem_fit" (the plain XLA op already runs in VMEM);
                      # of "push.mean_dense": "fold", "dtype", "small_table";
-                     # of "push.fold": "apply_fn"
+                     # of "push.fold": "apply_fn"; of "push.acc_runs"
+                     # what brought the push to the accumulator: "fold",
+                     # "mean_dense", "callable"
 
 
 _ROUTES_TRACED: list[Route] = []
@@ -380,6 +382,104 @@ XLA_PACKED_MIN_IDS = 8_192
 XLA_SORTED_BLOCK_IDS = 1_024
 XLA_SORTED_ROWS_PER_ID = 8
 
+# A NARROW table past XLA's VMEM regime, and when the store's accumulator
+# body (fps_tpu.core.store.push: a stateful fold, a callable combine, a
+# mean push that kept its accumulator) sums the pushed rows by id run
+# before the accumulator's scatter ("push.acc_runs": two sorts of the B
+# ids carrying the rows' columns, a segmented scan between them, and the
+# scatter handed each distinct id once, sorted, the sentinel after: the
+# sorted route above then stops at the last of them). A table of rows
+# narrower than the lane-packed route's whose row-major tiles are a little
+# past XLA_VMEM_TABLE_BYTES is left ROW-MAJOR IN HBM; further out XLA keeps
+# it TRANSPOSED, and in VMEM while that form fits. Compile-only, v5e
+# (``tests/test_v5e_compile.py`` guards the cell's), a zero f32[R,3]
+# accumulator scattered into inside a loop:
+#
+#   R (row-major MB)                      the accumulator, plain and by blocks
+#   196,000 (100.4)                       {1,0:T(8,128)S(1)}: row-major, VMEM
+#   250,000 (128.0); 262,144 (134.2)      {1,0:T(8,128)}: row-major, HBM
+#   300,000 (153.6) ... 1,000,000 (512)   {0,1:T(4,128)S(1)}: transposed, VMEM
+#
+# In the transposed regime the plain scatter-add pays 41-46 ns for EVERY id
+# it is handed, a repeat or dropped by the sentinel alike, linear in the ids
+# (425,997 ids into [1000000,3]: 19.7 ms sorted or not, told
+# ``indices_are_sorted`` / ``unique_indices`` or not, the last 82 % of them
+# the sentinel or not); the block loop keeps its carry in VMEM across trips
+# and pays the same for every LIVE id (75,553 distinct: 3.32 ms in blocks of
+# 1,024, 3.38 / 3.55 in blocks of 4,096 / 16,384; a static slice of as
+# many 3.27). A ``lax.switch`` over 4 / 16 static prefixes loses VMEM
+# (9.5 / 7.3 ms). In the row-major-HBM band a block's id costs 71-78 ns and
+# the plain op turns to a faster emitter once the ids are many against the
+# rows (13-16 ns an id from 0.5 ids a row). In time (chip runs of PR 33's
+# builder, the same code, quoted by PR 34 and not run again;
+# ``tools/bench_scatter.py fold``, one v5 lite chip, f32, width 2 + the
+# count column, the table a loop carry; us a push through the AdaGrad fold,
+# plain accumulator / summed runs; under each pair the live (distinct) ids;
+# ids UNIFORM over the rows, then Zipf(1.05)):
+#
+#   R (MB)             B=32,768       B=131,072       B=425,997        B=1,703,988
+#   250,000 (128.0)                                                    28130 / 35864 (a)
+#   uniform                                                            249,720
+#   262,144 (134.2)    4014 / 2915    2919 / 8379     7690 / 17960
+#   uniform            30,796         103,176         210,554
+#   320,126 (163.9)                   5452 / 4767     20173 / 11179
+#   uniform                           107,561         235,528
+#   500,000 (256.0)                                   20204 / 13281
+#   uniform                                           286,712
+#   1,000,000 (512.0)  1528 / 1563    5549 / 5469     20276 / 15810    80581 / 40779
+#   uniform            32,240         122,827         346,889          818,063
+#   4,194,304 (2,147)  1780 / 1785    5772 / 5917     20697 / 18211
+#   uniform            32,641         129,051         405,064
+#
+#   262,144 (134.2)    3969 / 1490    3121 / 3169     8332 / 7258
+#   Zipf(1.05)         10,620         30,736          69,863
+#   320,126 (163.9)                   5420 / 1698     19972 / 4614
+#   Zipf(1.05)                        31,852          73,675
+#   500,000 (256.0)                                   20001 / 4977
+#   Zipf(1.05)                                        82,000
+#   1,000,000 (512.0)  1530 / 739     5519 / 2020     20087 / 5550     79836 / 17803
+#   Zipf(1.05)         12,206         37,792          94,158           251,502
+#   4,194,304 (2,147)  1773 / 1037    5744 / 2470     20491 / 6644
+#   Zipf(1.05)         13,609         43,924          115,859
+#
+# and lr-criteo.epochs' own push ([1000000, 2], 425,997 ids of its 26
+# hashed columns, 75,551 live): 20276 / 4799, of it the sums by run 1.56 ms
+# (``fold probes``). The scatter-add ALONE on such ids (the distinct sorted,
+# the sentinel after) into an [R, 3] loop carry, plain / sorted route: in
+# the transposed regime the sorted route is level within 1.3 % where nothing
+# is dropped (1366 / 1372, 5275 / 5285) and wins everywhere else, by the
+# dropped share and a little more (a sorted distinct id costs 38-41 ns);
+# in the row-major band it loses wherever the plain op has its fast emitter
+# (262,144 rows: 1670 / 7514 under 131,072 uniform ids, 6744 / 15923 under
+# 425,997), so ``_route_xla_sorted`` takes a narrow table from
+# XLA_TRANSPOSED_TABLE_BYTES up, a byte count between the last row-major
+# and the first transposed layout compiled; in TIME (134.2, 163.9) MB is
+# unmeasured, and so are tables whose transposed form is past 64 MiB
+# (R over 4,194,304). The width swept is 3 ({0,1:T(4,128)}: four sublanes
+# a row); 4 compiles to the same tiles and is taken with it
+# (XLA_TRANSPOSED_DIMS); 1, 2 (T(1,128), T(2,128)) and 5 to 7 (T(8,128))
+# compile transposed and in VMEM too but were not swept and stay out. The
+# summed runs: in the transposed regime under UNIFORM ids level within
+# 2.5 % up to 0.131 ids a row (5 points) and a win at every point from
+# 0.409 (6: +14 % at 0.409, +22 % at 0.426, x1.5-2.0 from 0.85; also +12 %
+# at 0.102 under 425,997 ids), and under Zipf(1.05) a win at every point
+# (x1.7-4.3). ACC_RUNS_MIN_IDS_PER_ROW sits at that clear-win edge without
+# skew; (0.131, 0.409) is unmeasured.
+# In the row-major band (262,144 rows: read with the sorted route taking
+# the scatter) they lose without skew by 2.3-2.9x from 0.5 ids a row (and
+# win 27 % at 0.125): the band stays out. (a) One shard of four
+# of the cell's job, [250000, 2] under the four workers' 1,703,988 ids
+# (49,081 live under the cell's columns), with this predicate, so the
+# scatter stays plain and pays for all the ids: a loss; its scatter alone
+# by the sorted route reads 27670 / 19377 uniform and 28064 / 3916 under the
+# cell's columns, so with both predicates open there the push would read
+# about 27.6 (level) and 12.1 ms (x2.4): one point, not enough for a rule.
+# In XLA's VMEM regime (mf-netflix.x4's [4443, 11] under 131,072 ids, route
+# forced) 1041 / 2190: eleven sort operands cost more than the scatter.
+XLA_TRANSPOSED_TABLE_BYTES = 144 << 20
+XLA_TRANSPOSED_DIMS = (3, 4)
+ACC_RUNS_MIN_IDS_PER_ROW = 0.4
+
 
 def _bf16_pair_ok(dtype) -> bool:
     """The dim-1 kernels carry values as bf16 hi+lo: f64 would silently
@@ -430,21 +530,37 @@ def _route_xla_packed(R: int, D: int, B: int, dtype) -> bool:
             <= XLA_PACKED_TABLE_BYTES)
 
 
+def _xla_transposed(R: int, D: int, dtype) -> bool:
+    """A narrow table so far past XLA's VMEM regime that XLA keeps it
+    TRANSPOSED (in VMEM while that form fits), where its scatter-add pays
+    the same for every id it is handed: a swept width
+    (:data:`XLA_TRANSPOSED_DIMS`) of at most 4 bytes a number whose
+    row-major tiles are past :data:`XLA_TRANSPOSED_TABLE_BYTES`."""
+    return (D in XLA_TRANSPOSED_DIMS
+            and jnp.dtype(dtype).itemsize <= 4
+            and _tiled_table_bytes(R, D, dtype) > XLA_TRANSPOSED_TABLE_BYTES)
+
+
 def _route_xla_sorted(R: int, D: int, B: int, dtype,
                       ids_sorted: bool) -> bool:
     """Scatter by blocks and stop where the dropped ids begin? Only on the
     caller's guarantee, on the TPU (backend not ``"xla"``), for more ids
-    than one block, few enough against the rows
-    (:data:`XLA_SORTED_ROWS_PER_ID`), and a table of rows wider than the
-    lane-packed route's (:data:`XLA_PACKED_DIMS`) whose tiled form is past
-    XLA's VMEM regime (:data:`XLA_VMEM_TABLE_BYTES`): there the plain op
-    pays for every id it is handed, dropped or not."""
+    than one block, where the plain op pays for every id it is handed,
+    dropped or not. Two such regimes are measured: rows WIDER than the
+    lane-packed route's (:data:`XLA_PACKED_DIMS`) in a table whose tiled
+    form is past XLA's VMEM regime (:data:`XLA_VMEM_TABLE_BYTES`), under
+    few enough ids against the rows (:data:`XLA_SORTED_ROWS_PER_ID`); and
+    rows NARROWER than them in a table XLA keeps transposed
+    (:func:`_xla_transposed`), whatever the ids."""
     use, interpret = _use_pallas()
-    return (ids_sorted and use and not interpret
+    if not (ids_sorted and use and not interpret
             and jnp.dtype(dtype).itemsize <= 4
-            and XLA_SORTED_BLOCK_IDS < B <= R // XLA_SORTED_ROWS_PER_ID
-            and D > XLA_PACKED_DIMS[1]
-            and _tiled_table_bytes(R, D, dtype) > XLA_VMEM_TABLE_BYTES)
+            and B > XLA_SORTED_BLOCK_IDS):
+        return False
+    return _xla_transposed(R, D, dtype) or (
+        D > XLA_PACKED_DIMS[1]
+        and _tiled_table_bytes(R, D, dtype) > XLA_VMEM_TABLE_BYTES
+        and B <= R // XLA_SORTED_ROWS_PER_ID)
 
 
 def _xla_reason(R: int, D: int, dtype) -> str:
@@ -647,13 +763,14 @@ def scatter_add(
     ``ids_sorted=True`` asserts the STATIC guarantee that ``ids`` are
     non-decreasing and none is negative, so the ids to drop (``>= rows``)
     come last; duplicates are then adjacent and still accumulate. A wide
-    table past XLA's VMEM regime then takes ``scatter_add.xla_sorted``
-    (:func:`_route_xla_sorted`), which never looks at the ids past the
-    last live one; everywhere else the guarantee is accepted
-    and changes nothing. The answer is the plain route's on every input
-    that keeps the promise; one that breaks it is silently wrong on the
-    TPU, so only a caller that made the order itself gives it
-    (:func:`fps_tpu.core.store.push`'s ``push.mean_rows``).
+    table past XLA's VMEM regime, or a narrow one, then takes
+    ``scatter_add.xla_sorted`` (:func:`_route_xla_sorted`), which never
+    looks at the ids past the last live one; everywhere else the
+    guarantee is accepted and changes nothing. The answer is the plain
+    route's on every input that keeps the promise; one that breaks it is
+    silently wrong on the TPU, so only a caller that made the order itself
+    gives it (:func:`fps_tpu.core.store.push`'s ``push.mean_rows`` and
+    ``push.acc_runs``).
     """
     R, D = table.shape
     B = ids.shape[0]

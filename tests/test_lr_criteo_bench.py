@@ -440,6 +440,20 @@ def _under(paths, scope):
     return [p for p in paths if scope in p.split("/")]
 
 
+def _lr_step(mesh, sync_every):
+    """Logistic regression with the AdaGrad fold at the tiny cell's shape:
+    the trainer, its seeded state and an epoch plan over 32 batches."""
+    data, _ = criteo_rows.generate(4, dict(
+        tiny_cell()["config"]["data"], examples_resident=32 * B))
+    trainer, _ = logistic_regression(
+        mesh, LogRegConfig(num_features=F, optimizer="adagrad",
+                           dense_features=D), sync_every=sync_every)
+    tables, ls = trainer.init_state(jax.random.key(0))
+    plan = DeviceEpochPlan(DeviceDataset(mesh, data), num_workers=1,
+                           local_batch=B, seed=1, sync_every=sync_every)
+    return trainer, tables, ls, plan
+
+
 @pytest.fixture(scope="module")
 def lr_programs(devices8):
     """Logistic regression's step in all three step builders (the chunked
@@ -448,17 +462,10 @@ def lr_programs(devices8):
     paths by (builder, mode) and the route log of the two indexed
     programs."""
     mesh = make_ps_mesh(devices=devices8[:1])
-    data, _ = criteo_rows.generate(4, dict(
-        tiny_cell()["config"]["data"], examples_resident=32 * B))
     key = key_to_replicated(jax.random.key(1), mesh)
     out = {}
     for mode, s in (("ssp", 8), ("sync", None)):
-        cfg = LogRegConfig(num_features=F, optimizer="adagrad",
-                           dense_features=D)
-        trainer, _ = logistic_regression(mesh, cfg, sync_every=s)
-        tables, ls = trainer.init_state(jax.random.key(0))
-        plan = DeviceEpochPlan(DeviceDataset(mesh, data), num_workers=1,
-                               local_batch=B, seed=1, sync_every=s)
+        trainer, tables, ls, plan = _lr_step(mesh, s)
         iargs = plan.epoch_args(0)
         ops.clear_routes()
         out["indexed", mode] = _scope_paths(
@@ -505,6 +512,33 @@ def test_route_log_names_the_snapshot_read_and_the_stateful_fold(
     assert not {"pull.snapshot", "push.fold"} & ops.PALLAS_ROUTES
 
 
+def test_route_log_names_the_summed_runs_past_the_vmem_regime(
+        devices8, monkeypatch):
+    """With the fold's accumulator past the edge where XLA keeps it
+    transposed (the cell's own is, at 1,000,000 rows; here the edge is
+    moved under this table's) the log gains ``push.acc_runs`` between
+    ``push.fold`` and the scatter's entry, and the sorts that sum the id
+    runs are under ``fps.combine`` (off the TPU the sorted route stays
+    out: ``scatter_add.xla``)."""
+    monkeypatch.setattr(ops, "XLA_TRANSPOSED_TABLE_BYTES", 1 << 20)
+    mesh = make_ps_mesh(devices=devices8[:1])
+    trainer, tables, ls, plan = _lr_step(mesh, 8)
+    ops.clear_routes()
+    lowered = trainer._get_indexed_fn(plan, "ssp").lower(
+        tables, ls, plan.epoch_args(0), np.int32(0),
+        key_to_replicated(jax.random.key(1), mesh))
+    rows = B * (NNZ - D) + D
+    got = [(r.route, r.rows, r.dim, r.ids, r.reason)
+           for r in ops.routes_traced()]
+    assert got[2:] == [("push.fold", F, 2, rows, "apply_fn"),
+                       ("push.acc_runs", F, 2, rows, "fold"),
+                       ("scatter_add.xla", F, 3, rows, got[1][4])], got
+    assert "push.acc_runs" not in ops.PALLAS_ROUTES
+    sorts = re.findall(r'loc\("([^"]+)/sort"\(',
+                       lowered.as_text(debug_info=True))
+    assert sorts and all("fps.combine" in p.split("/") for p in sorts), sorts
+
+
 OTHERS = {
     "mf-netflix.epochs": {
         "model": {"num_users": 1201, "num_items": 97, "local_batch": 256},
@@ -524,7 +558,8 @@ OTHERS = {
 @pytest.mark.parametrize("workload", sorted(OTHERS))
 def test_the_other_configurations_hold_none_of_the_new_names(workload):
     """Their step programs take neither branch: no op under
-    ``ssp.snapshot``, no ``pull.snapshot`` or ``push.fold`` in the log."""
+    ``ssp.snapshot``, no ``pull.snapshot``, ``push.fold`` or
+    ``push.acc_runs`` in the log."""
     loaded = spec.load_cell(spec.load_benchmark(), workload)
     cfg = copy.deepcopy(loaded["config"])
     for part, over in OTHERS[workload].items():
@@ -539,19 +574,29 @@ def test_the_other_configurations_hold_none_of_the_new_names(workload):
         tables, ls, system.plan.epoch_args(0), np.int32(0),
         key_to_replicated(jax.random.key(1), system.mesh))
     routes = [r.route for r in ops.routes_traced()]
-    assert routes and not [r for r in routes
-                           if r in ("pull.snapshot", "push.fold")], routes
+    assert routes and not [r for r in routes if r in (
+        "pull.snapshot", "push.fold", "push.acc_runs")], routes
     assert not _under(_scope_paths(lowered), "ssp.snapshot")
 
 
 # -- the runner's whole path -----------------------------------------------
 
-@pytest.mark.parametrize("n,seed", [(1, 11), (4, 2_147_484_123)])
-def test_cell_rehearsal_runs_the_runners_whole_path(n, seed):
+@pytest.mark.parametrize("n,seed,acc_runs", [
+    (1, 11, False), (4, 2_147_484_123, False),
+    (1, 12, True), (4, 2_147_484_124, True)])
+def test_cell_rehearsal_runs_the_runners_whole_path(monkeypatch, n, seed,
+                                                    acc_runs):
     """The benchmark's own path for the cell (data, system, seeded state,
     warm-up, queue-ahead window, comparison) at a tiny size; the limits
-    are the committed file's."""
+    are the committed file's. ``acc_runs``: with the predicate's edge moved
+    under the tiny table's accumulator, so the fold sums the id runs
+    before its scatter as the cell's own size does (``push.acc_runs``),
+    held to the same reference by the same limits."""
+    if acc_runs:
+        monkeypatch.setattr(ops, "XLA_TRANSPOSED_TABLE_BYTES", 1 << 18)
+        monkeypatch.setattr(ops, "ACC_RUNS_MIN_IDS_PER_ROW", 0.0)
     events = []
+    ops.clear_routes()
     with mesh_devices(n):
         result = runner.run_cell(
             tiny_cell(), seed=seed, seconds=0.3, trace=False,
@@ -560,6 +605,8 @@ def test_cell_rehearsal_runs_the_runners_whole_path(n, seed):
             out_dir="unused")
     compared = [e for e in events if e["event"] == "compared"]
     assert result["correct"], compared
+    assert ("push.acc_runs" in [r.route for r in ops.routes_traced()]
+            ) is acc_runs
     assert result["failed"] == 0 and result["attempted"] >= 2
     assert set(result["metrics"]) == {"setup_s", "examples_per_s"}
     assert {e["number"] for e in compared} == set(

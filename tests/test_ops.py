@@ -685,6 +685,16 @@ _XLA_SORTED_CASES = [
     (*_NETFLIX, "float32", "auto", True, "xla_packed", ""),  # lane-packable
     (1_000_000, 32, 32_768, "float32", "auto", True, "xla", "shape"),
     (8_000_000, 1, 32_768, "float32", "auto", True, "xla", "shape"),
+    # Narrow rows (PR 34): a table XLA keeps transposed, whatever the ids.
+    (1_000_000, 3, 425_997, "float32", "auto", True, "xla_sorted", ""),
+    (300_000, 3, 131_072, "float32", "auto", True, "xla_sorted", ""),  # 154 MB
+    (1_000_000, 4, 1_703_988, "float32", "auto", True, "xla_sorted", ""),
+    (262_144, 3, 131_072, "float32", "auto", True, "xla", "shape"),  # 134 MB:
+    (250_000, 3, 1_703_988, "float32", "auto", True, "xla", "shape"),  # row-major
+    (1_000_000, 2, 425_997, "float32", "auto", True, "xla", "shape"),  # unswept
+    (1_000_000, 5, 425_997, "float32", "auto", True, "xla", "shape"),  # widths
+    (1_000_000, 3, 425_997, "float32", "auto", False, "xla", "shape"),
+    (1_000_000, 3, 425_997, "float64", "auto", True, "xla", "f64"),
 ]
 
 

@@ -22,6 +22,7 @@ from fps_tpu.core.store import (
     push,
     rows_per_shard,
 )
+from fps_tpu.models.logistic_regression import adagrad_fold
 from fps_tpu.parallel.mesh import DATA_AXIS, SHARD_AXIS, make_ps_mesh
 
 
@@ -667,6 +668,148 @@ def test_push_mean_row_branch_is_for_the_additive_mean_alone(devices8, case,
                                    rtol=0, atol=2.0 ** -7 * np.abs(want).max())
     else:
         np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+
+
+def _acc_runs_case(case, S, num_ids, dim, seed=21):
+    """A push of 96 ids a worker on ``S`` shards for ``push.acc_runs``'
+    cases, onto a non-zero table whose second column is positive (an
+    AdaGrad accumulator)."""
+    rng = np.random.default_rng(seed)
+    n = S * 96
+    if case == "heavy_repeats":
+        hot = rng.integers(0, num_ids, 12)
+        ids = np.where(rng.random(n) < 0.7, hot[rng.integers(0, 12, n)],
+                       rng.integers(0, num_ids, n))
+        ids[rng.random(n) < 0.125] = -1
+    elif case == "no_repeats":
+        ids = rng.choice(num_ids, n, replace=False)
+    elif case == "all_dropped":
+        ids = np.full(n, -1)
+    elif case == "one_id":
+        ids = np.full(n, 77)
+    else:  # "another_shard": every id is shard 1 % S's, none of the rest
+        ids = rng.integers(0, num_ids // S, n) * S + 1 % S
+    deltas = rng.normal(0, 1, (n, dim)).astype(np.float32)
+    table = np.abs(rng.normal(0, 1, (num_ids, dim))).astype(np.float32) + 0.5
+    return table, ids.astype(np.int32), deltas
+
+
+_ACC_RUNS_KINDS = {
+    # kw of push; the reason "push.acc_runs" logs; the push entry before it
+    "fold": (lambda: {"apply_fn": adagrad_fold(0.1, 1e-6)}, "fold",
+             ("push.fold", "apply_fn")),
+    "mean_fold": (lambda: {"combine": "mean",
+                           "apply_fn": lambda rows, delta: rows - delta},
+                  "mean_dense", ("push.mean_dense", "fold")),
+    # Column 0 of the combined delta is the id's COUNT, the rest its sums.
+    "callable": (lambda: {"combine": lambda s, c: s.at[:, 0].set(c)},
+                 "callable", None),
+}
+
+
+@pytest.mark.parametrize("case", ["heavy_repeats", "no_repeats",
+                                  "all_dropped", "one_id", "another_shard"])
+@pytest.mark.parametrize("kind", list(_ACC_RUNS_KINDS))
+@pytest.mark.parametrize("S", [1, 4])
+def test_push_acc_runs_equals_the_plain_accumulator(devices8, monkeypatch,
+                                                    S, kind, case):
+    """``push`` through ``push.acc_runs`` (the pushed rows summed by id
+    run, the accumulator's scatter handed each distinct id once, sorted,
+    the dropped last) is ``push`` through the plain accumulator, the
+    predicate's constant patched both ways on a shard whose accumulator
+    XLA keeps transposed (300,000 rows: 153.6 MB of row-major tiles): the
+    touched mask and the counts bit for bit, the sums within float32
+    reassociation."""
+    R, dim = 300_000 * S, 2
+    make_kw, reason, before = _ACC_RUNS_KINDS[kind]
+    table, ids, deltas = _acc_runs_case(case, S, R, dim)
+    rps, seen, plain_scatter = rows_per_shard(R, S), [], ops.scatter_add
+
+    def spy(t, i, d, **kw):
+        jax.debug.callback(lambda i: seen.append(np.asarray(i)), i)
+        assert kw == {"ids_sorted": True}
+        return plain_scatter(t, i, d, **kw)
+
+    monkeypatch.setattr(ops, "ACC_RUNS_MIN_IDS_PER_ROW", float("inf"))
+    want, log = _push_on_mesh(devices8, 1, S, table, ids, deltas,
+                              **make_kw())
+    assert "push.acc_runs" not in [r.route for r in log]
+    monkeypatch.setattr(ops, "ACC_RUNS_MIN_IDS_PER_ROW", 0.0)
+    monkeypatch.setattr(ops, "scatter_add", spy)
+    got, log = _push_on_mesh(devices8, 1, S, table, ids, deltas, **make_kw())
+    jax.effects_barrier()
+    assert [(r.route, r.rows, r.dim, r.ids, r.reason) for r in log] == (
+        [(before[0], rps, dim, ids.shape[0], before[1])] if before else []
+    ) + [("push.acc_runs", rps, dim, ids.shape[0], reason),
+         ("scatter_add.xla", rps, dim + 1, ids.shape[0], log[-1].reason)]
+    # What the scatter was handed on each shard: non-decreasing, none
+    # negative, the shard's distinct ids once each, then the sentinel.
+    assert len(seen) == S
+    for i in seen:
+        assert i.shape == ids.shape and i.min() >= 0
+        assert (np.diff(i) >= 0).all() and (np.diff(i[i < rps]) > 0).all()
+    assert sum((i < rps).sum() for i in seen) == len(
+        np.unique(ids[ids >= 0]))
+    touched = np.zeros(R, bool)
+    live = np.unique(ids[ids >= 0])
+    touched[np.asarray(id_to_phys(live, S, rps))] = True
+    np.testing.assert_array_equal(got[~touched], table[~touched])
+    np.testing.assert_array_equal((got != table).any(axis=1), touched)
+    np.testing.assert_array_equal((want != table).any(axis=1), touched)
+    if kind == "callable":  # column 0: the table's plus the id's count
+        np.testing.assert_array_equal(got[:, 0], want[:, 0])
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-6)
+
+
+@pytest.mark.parametrize("rps,dim,num_ids,engages", [
+    (1_000_000, 2, 425_997, True),      # lr-criteo.epochs
+    (300_000, 2, 1_703_988, True),
+    # The same job on four shards: 128 MB of row-major tiles, which XLA
+    # keeps row-major in HBM; the plain scatter of many ids is fast there
+    # and the summed runs lose without skew (tools/bench_scatter.py fold).
+    (250_000, 2, 1_703_988, False),
+    (17_770, 10, 32_768, False),        # mf-netflix.epochs
+    (17_770, 10, 131_072, False),
+    (4_443, 10, 32_768, False),
+    (4_443, 10, 131_072, False),        # mf-netflix.x4
+    (1_000_000, 2, 32_768, False),      # too few ids for certain repeats
+    (1_000_000, 1, 425_997, False),     # accumulator widths not swept:
+    (1_000_000, 4, 425_997, False),     # the scatter would not stop early
+])
+def test_acc_runs_route_from_shapes_alone(rps, dim, num_ids, engages):
+    """``push.acc_runs`` engages by ``push``'s own shapes: an accumulator
+    whose row-major tiles are so far past XLA's VMEM regime that XLA keeps
+    it transposed, under enough ids a row; both MF cells' accumulators are
+    inside the regime and keep the plain scatter whatever their ids."""
+    from fps_tpu.core.store import _acc_runs_route
+
+    assert _acc_runs_route(rps, dim, num_ids, jnp.float32) is engages
+
+
+@pytest.mark.parametrize("idx", [
+    [7], [3, 3, 3, 3], [5, 1, 4, 2, 3], [9, 0, 9, 9, 0, 4, 9, 100, 100],
+    list(np.random.default_rng(5).integers(0, 40, 1000)),
+])
+def test_sum_id_runs_sums_each_index_once(idx):
+    """The accumulator's pre-combine from sorts of the batch alone: the
+    distinct indices sorted at the front, each with the sum of its rows
+    and, last column, their number EXACTLY; ``drop`` after them."""
+    from fps_tpu.core.store import _sum_id_runs
+
+    idx = np.asarray(idx, np.int32)
+    rows = np.random.default_rng(6).normal(0, 1, (len(idx), 2))
+    ids, sums = map(np.asarray, jax.jit(
+        lambda i, r: _sum_id_runs(i, r, 10_000))(
+            jnp.asarray(idx), jnp.asarray(rows, jnp.float32)))
+    distinct = np.unique(idx)
+    np.testing.assert_array_equal(ids[:len(distinct)], distinct)
+    assert (ids[len(distinct):] == 10_000).all()
+    np.testing.assert_array_equal(sums[:len(distinct), 2],
+                                  [(idx == i).sum() for i in distinct])
+    np.testing.assert_allclose(
+        sums[:len(distinct), :2],
+        [rows[idx == i].sum(axis=0) for i in distinct],
+        rtol=1e-5, atol=1e-5)
 
 
 def test_server_logic_swap_recompiles(devices8):

@@ -322,7 +322,7 @@ def test_mf_epoch_step_keeps_the_accumulator_for_its_small_table(topo):
         tables, local, batches, shape((), jax.random.key(0).dtype))
     pushes = [r for r in ops.routes_traced() if r.op == "push"]
     assert pushes == [ops.Route("push", "push.mean_dense", 17_770, rank, B,
-                                False, "small_table")], pushes
+                                False, "small_table")], pushes  # no acc_runs
 
 
 @pytest.mark.parametrize("shards", [1, 4])
@@ -339,9 +339,13 @@ def test_lr_criteo_epoch_program_names_its_round_and_fits(topo, monkeypatch,
     device op is under ``ssp.snapshot``. On one chip also: the stateful
     fold's ``(rows, dim + 1)`` accumulator is kept transposed and in VMEM
     (16 MB, not the 512 MB of its row-major tiles), and so is the select
-    that writes the table back; the step holds one gather and one scatter
-    of 425,997 rows and every table-sized op of the body is the fold's,
-    under ``fps.combine``."""
+    that writes the table back; the step holds one gather of 425,997 rows
+    and NO scatter of as many: the pushed rows are summed by id run under
+    ``fps.combine`` (``push.acc_runs``: two sorts and the passes between
+    them) and the accumulator's scatter runs a block of the distinct ids
+    at a time (``scatter_add.xla_sorted``), its carry in VMEM across
+    trips; every table-sized op of the body is the fold's, under
+    ``fps.combine``."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from fps_tpu import DeviceEpochPlan
@@ -389,7 +393,11 @@ def test_lr_criteo_epoch_program_names_its_round_and_fits(topo, monkeypatch,
         ("pull.snapshot", F, 2, rows, ""),
         ("gather.xla", F, 2, rows, "shape"),
         ("push.fold", F // shards, 2, rows * shards, "apply_fn"),
-        ("scatter_add.xla", F // shards, 3, rows * shards, "shape")]
+        *([("push.acc_runs", F, 2, rows, "fold"),
+           ("scatter_add.xla_sorted", F, 3, rows, "")] if shards == 1 else
+          # 250,000 rows a shard: 128 MB of row-major tiles, which XLA
+          # keeps row-major in HBM; the plain accumulator stays.
+          [("scatter_add.xla", F // shards, 3, rows * shards, "shape")])]
     text = compiled.as_text()
     snap = [ln for ln in text.splitlines() if "/ssp.snapshot/" in ln]
     assert not [ln for ln in snap if "/fps." in ln]
@@ -406,12 +414,37 @@ def test_lr_criteo_epoch_program_names_its_round_and_fits(topo, monkeypatch,
     mem = compiled.memory_analysis()
     assert 2.6e9 < mem.argument_size_in_bytes < 2.9e9     # the columns
     assert mem.temp_size_in_bytes < 256 << 20
+    # The fold's accumulator: ONE scatter fusion, of a block of the summed
+    # runs' ids inside the sorted route's own loop, transposed and in VMEM
+    # (and so is the loop's carry); no scatter is handed all the pushed
+    # rows any more.
     acc = [ln for ln in text.splitlines()
            if re.search(rf"= f32\[{F},3\]\S* fusion\(", ln)]
     assert len(acc) == 1 and "{0,1:T(4,128)S(1)}" in acc[0], acc
-    assert "/fps.push/fps.ops/scatter_add.xla/" in acc[0]
+    assert ("/fps.push/fps.ops/scatter_add.xla_sorted/while/body/"
+            "scatter-add" in acc[0])
+    carry = [ln for ln in text.splitlines()
+             if re.search(rf"= f32\[{F},3\]\S* get-tuple-element\(", ln)
+             and "/scatter_add.xla_sorted/while" in ln]
+    assert carry and all("{0,1:T(4,128)S(1)}" in ln for ln in carry), carry
+    scatters = [ln for ln in text.splitlines() if " scatter(" in ln]
+    assert scatters and not [ln for ln in scatters
+                             if f"f32[{rows},3]" in ln], scatters
+    # The sums by run: two sorts of the pushed ids carrying the rows'
+    # columns and the doubling passes between them, all under fps.combine.
+    sorts = [ln for ln in text.splitlines()
+             if re.search(r"= \(s32\[%d\]\S*, f32\[%d\]" % (rows, rows), ln)
+             and " sort(" in ln]
+    assert len(sorts) == 2, sorts
+    assert all("/fps.push/fps.combine/" in ln for ln in sorts), sorts
+    passes = [ln for ln in _top_level(text)
+              if re.search(rf"= \(?f32\[{rows}\]\S* fusion\(", ln)
+              and "/fps.push/" in ln]
+    assert passes and all("/fps.push/fps.combine/" in ln
+                          for ln in passes), passes
     sized = [ln for ln in _top_level(text)
              if re.search(rf"= \w+\[{F},\d\]\S* fusion\(", ln)]
-    assert sized and all("/fps.push/fps.combine/" in ln
-                         or "/fps.push/fps.ops/scatter_add.xla/" in ln
-                         for ln in sized), sized
+    assert sized and all(
+        "/fps.push/fps.combine/" in ln
+        or "/fps.push/fps.ops/scatter_add.xla_sorted/" in ln
+        for ln in sized), sized

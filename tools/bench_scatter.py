@@ -21,6 +21,14 @@ rows x width x ids at Zipf(1.0) ids — the sweep that set
 the dropped ids begin) on Zipf(1.0) ids handed over as ``push.mean_rows``
 does, over rows x width x ids, wide rows on both sides of XLA's VMEM edge
 — the sweep ``ops._route_xla_sorted`` and its two constants stand on.
+
+``fold`` arm: the store's accumulator body (a stateful fold's push) by the
+plain accumulator against the pushed rows summed by id run first
+(``push.acc_runs``), over rows x ids at width 2 under uniform ids,
+Zipf(1.05) ids and ``lr-criteo.epochs``' own columns, with the scatter-add
+alone plain against sorted — the sweep ``ops.XLA_TRANSPOSED_TABLE_BYTES``
+and ``ops.ACC_RUNS_MIN_IDS_PER_ROW`` stand on; ``fold probes``: the ways to
+scatter the summed runs and to sum them, at the cell's shape.
 """
 
 import os
@@ -208,6 +216,38 @@ def _zipf_ids(rng, R, shape, alpha=1.0):
                       R - 1).astype(np.int32)
 
 
+def _scan_timer(tab, *xs):
+    """``us_a_step(op)``: us a step (the best of two timed calls after one
+    that compiles) of ``op(table, *x) -> table`` scanned over the steps of
+    ``xs`` (each ``(T, ...)``) with the table a loop carry; and the last
+    table."""
+    T = xs[0].shape[0]
+
+    def us_a_step(op):
+        f = jax.jit(lambda t, *xs: lax.scan(
+            lambda t, x: (op(t, *x), None), t, xs)[0])
+        r = f(tab, *xs)
+        np.asarray(r[0, 0])
+        best = 1e9
+        for _ in range(2):
+            t0 = time.perf_counter()
+            r = f(tab, *xs)
+            np.asarray(r[0, 0])
+            best = min(best, time.perf_counter() - t0)
+        return round(best / T * 1e6, 1), r
+    return us_a_step
+
+
+def _compacted(ids, R, front=True):
+    """Each step's ids as the summed runs leave them: the distinct ids
+    sorted, at the front, the drop sentinel ``R`` after the last
+    (``front=False``: before the sort that brings them there, the sentinel
+    in the place of every repeat)."""
+    ids = np.sort(np.where(ids < 0, R, ids), axis=1)
+    ids[:, 1:][ids[:, 1:] == ids[:, :-1]] = R
+    return np.sort(ids, axis=1) if front else ids
+
+
 def _mean_case(R, D, B, sorted_ids=False):
     """A table, ``T`` steps of Zipf(1.0) ids and deltas (at most ~1 GB of
     tiled deltas), and the runner of one program over them: us a step of
@@ -220,25 +260,10 @@ def _mean_case(R, D, B, sorted_ids=False):
     tab = jnp.asarray(rng.normal(0, 0.1, (R, D)), jnp.float32)
     ids = _zipf_ids(rng, R, (T, B))
     if sorted_ids:
-        ids = np.sort(ids, axis=1)
-        ids[:, 1:][ids[:, 1:] == ids[:, :-1]] = R
-        ids = np.sort(ids, axis=1)
+        ids = _compacted(ids, R)
     ids = jnp.asarray(ids)
     deltas = jnp.asarray(rng.normal(0, 1e-2, (T, B, D)), jnp.float32)
-
-    def us_a_step(op):
-        f = jax.jit(lambda t, i, d: lax.scan(
-            lambda t, x: (op(t, *x), None), t, (i, d))[0])
-        r = f(tab, ids, deltas)
-        np.asarray(r[0, 0])
-        best = 1e9
-        for _ in range(2):
-            t0 = time.perf_counter()
-            r = f(tab, ids, deltas)
-            np.asarray(r[0, 0])
-            best = min(best, time.perf_counter() - t0)
-        return round(best / T * 1e6, 1), r
-
+    us_a_step = _scan_timer(tab, ids, deltas)
     us_a_step.live = round(float(jnp.mean(jnp.sum(ids < R, axis=1))), 1)
     return us_a_step
 
@@ -419,6 +444,337 @@ def wide_sweep(args):
     _write_points("wide", [(wide_point, p) for p in points])
 
 
+FOLD_R = (262_144, 1_000_000, 4_194_304)
+FOLD_B = (32_768, 131_072, 425_997)
+FOLD_D = 2          # the pushed row [g, g^2]; the accumulator is D + 1 wide
+LR_CRITEO = (1_000_000, 16_384)   # rows; examples a step (x 26 + 13 ids)
+MF_X4 = (4_443, 131_072, "zipf", 10)  # mf-netflix.x4's shard push, in VMEM
+# Round the grid: the first rows XLA keeps transposed (320,126: PR 25's
+# sweep), many ids a row there, and one shard of four of the cell's job.
+FOLD_EDGE = ((320_126, 131_072), (320_126, 425_997), (500_000, 425_997),
+             (1_000_000, 1_703_988), (250_000, 1_703_988))
+
+
+def _criteo_ids(T, seed=33):
+    """``T`` steps of ``lr-criteo.epochs``'s pushed ids: 16,384 rows x 26
+    categorical columns drawn by the configuration's own generator
+    (``perfbench/datasets/criteo_rows.py``: Zipf(1.05) within a column,
+    hashed into 1,000,000 features) and the dense head's 13."""
+    import json
+
+    from perfbench.datasets.criteo_rows import hash_tokens, zipf_tokens
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "perfbench/configs/lr-criteo.json")) as fh:
+        d = json.load(fh)["data"]
+    cards = d["categorical_cardinalities"]
+    u = jax.random.uniform(jax.random.key(seed),
+                           (T, LR_CRITEO[1], len(cards)))
+    ids = hash_tokens(zipf_tokens(u, cards, d["token_zipf"]),
+                      d["num_features"], d["numeric_columns"])
+    head = jnp.broadcast_to(jnp.arange(d["numeric_columns"], dtype=jnp.int32),
+                            (T, d["numeric_columns"]))
+    return np.asarray(jnp.concatenate([head, ids.reshape(T, -1)], axis=1))
+
+
+def _fold_ids(R, B, dist, T):
+    """``(T, B)`` ids over ``R`` rows: ``uniform``, ``zipf`` (Zipf(1.05)
+    over the rows) or ``criteo`` (the cell's own columns; its own R, B)."""
+    rng = np.random.default_rng(R * 131 + B)
+    if dist == "criteo":
+        return _criteo_ids(T)
+    if dist == "criteo_x4":  # shard 0's view of four workers' pushes
+        ids = np.concatenate([_criteo_ids(T, seed=33 + w) for w in range(4)],
+                             axis=1)
+        return np.where(ids % 4 == 0, ids // 4, -1).astype(np.int32)
+    if dist == "uniform":
+        return rng.integers(0, R, (T, B)).astype(np.int32)
+    return _zipf_ids(rng, R, (T, B), alpha=1.05)
+
+
+def _fold_rows(ids, D=FOLD_D):
+    """The pushed rows of a step, made on the device from its ids (no
+    ``(T, B, D)`` operand: its row-major tiles would be 218 MB a step):
+    ``[g, g^2]`` as the AdaGrad fold is pushed them, and multiples of
+    ``g`` for the columns past two."""
+    g = ((ids % 97).astype(jnp.float32) - 48.0) * 1e-3
+    return jnp.stack([g, g * g] + [g * (k + 2) for k in range(D - 2)],
+                     axis=1)
+
+
+def _fold_rows_counted(ids, R, D=FOLD_D):
+    """:func:`_fold_rows` with the accumulator's count column: 1 beside
+    an id under ``R``, 0 beside the drop sentinel."""
+    return jnp.concatenate(
+        [_fold_rows(ids, D), (ids < R).astype(jnp.float32)[:, None]], axis=1)
+
+
+def _fold_runner(R, ids, width=FOLD_D):
+    """us a step of ``op(table [R, width], ids [B]) -> table`` over the
+    steps of ``ids (T, B)``, the table a loop carry; and the last table."""
+    return _scan_timer(jnp.zeros((R, width), jnp.float32), jnp.asarray(ids))
+
+
+def _store_fold_push(runs, D=FOLD_D):
+    """``store.push`` through its accumulator body on a one-shard mesh
+    with ``store._acc_runs_route`` answering ``runs`` whatever the shape
+    while it is traced: the id runs summed before the accumulator's
+    scatter (``push.acc_runs``), or the plain accumulator. At width 2 a
+    stateful fold (``adagrad_fold``), at any other the per-id mean kept on
+    its accumulator (MF's push)."""
+    from jax.sharding import PartitionSpec as P
+
+    import fps_tpu.ops as ops
+    from fps_tpu.core import store
+    from fps_tpu.models.logistic_regression import adagrad_fold
+    from fps_tpu.parallel.mesh import SHARD_AXIS, make_ps_mesh
+
+    mesh = make_ps_mesh(num_shards=1, devices=jax.devices()[:1])
+    kw = ({"apply_fn": adagrad_fold(0.001, 1e-6)} if D == 2
+          else {"combine": "mean"})
+
+    def op(t, i):
+        keep = store._acc_runs_route, ops.MEAN_ROWS_TABLE_RATIO
+        store._acc_runs_route = lambda *shape: runs
+        ops.MEAN_ROWS_TABLE_RATIO = float("inf")
+        try:
+            return jax.shard_map(
+                lambda t, i: store.push(t, i, _fold_rows(i, D), num_shards=1,
+                                        data_axis=None, **kw),
+                mesh=mesh, in_specs=(P(SHARD_AXIS, None), P()),
+                out_specs=P(SHARD_AXIS, None), check_vma=False)(t, i)
+        finally:
+            store._acc_runs_route, ops.MEAN_ROWS_TABLE_RATIO = keep
+    return op
+
+
+def fold_point(R, B, dist, D=FOLD_D):
+    """us a step of a push through ``store.push``'s accumulator body by
+    the plain accumulator and with the id runs summed first
+    (``push.acc_runs``), the live ids a step (the distinct ones) and the
+    largest gap of the two tables over the largest value; and of the
+    scatter-add ALONE into an ``[R, D + 1]`` loop carry under the summed
+    runs' ids (the distinct ones sorted, the sentinel after), by the
+    plain route and by ``scatter_add.xla_sorted`` (its predicate answering
+    yes whatever the shape)."""
+    import fps_tpu.ops as ops
+
+    T = int(max(4, min(32, (1 << 24) // B)))
+    ids = _fold_ids(R, B, dist, T)
+    us_a_step = _fold_runner(R, ids, D)
+    ops.clear_routes()
+    plain_us, want = us_a_step(_store_fold_push(False, D))
+    runs_us, got = us_a_step(_store_fold_push(True, D))
+    out = {"rows": R, "dim": D, "ids": ids.shape[1], "dist": dist,
+           "ids_per_row": round(ids.shape[1] / R, 3),
+           "live": round(float(np.mean(
+               [len(np.unique(i[i >= 0])) for i in ids])), 1),
+           "tiled_mb": round(ops._tiled_table_bytes(
+               R, D + 1, jnp.float32) / 1e6, 1),
+           "acc_us": plain_us, "acc_runs_us": runs_us,
+           "gap": _gap(got, want),
+           "routes": [r.route for r in ops.routes_traced()]}
+
+    def scatter(ids_sorted):
+        return lambda t, i: ops.scatter_add(
+            t, i, _fold_rows_counted(i, R, D), ids_sorted=ids_sorted)
+
+    us_a_step = _fold_runner(R, _compacted(ids, R), D + 1)
+    keep = ops._route_xla_sorted
+    ops._route_xla_sorted = lambda R, D, B, dtype, ids_sorted: ids_sorted
+    try:
+        out["scatter_plain_us"], want = us_a_step(scatter(False))
+        out["scatter_sorted_us"], got = us_a_step(scatter(True))
+    finally:
+        ops._route_xla_sorted = keep
+    out["scatter_gap"] = _gap(got, want)
+    return out
+
+
+def _ladder_scatter(zeros, ids, rows, rungs):
+    """Probe (c): ``lax.switch`` on the live count over static prefixes of
+    the sorted ids, each branch one plain scatter-add of its slice."""
+    import fps_tpu.ops as ops
+
+    B, R = ids.shape[0], zeros.shape[0]
+    live = jnp.sum((ids < R).astype(jnp.int32))
+    sizes = [-(-B * k // rungs) for k in range(1, rungs + 1)]
+    which = jnp.searchsorted(jnp.asarray(sizes, jnp.int32), live)
+    return lax.switch(
+        jnp.minimum(which, rungs - 1),
+        [lambda z, i, d, n=n: ops.scatter_add(z, i[:n], d[:n])
+         for n in sizes], zeros, ids, rows)
+
+
+def _runs_variants():
+    """Ways to sum a batch's rows by id run, ``(idx, rows, drop) -> (ids,
+    sums and count)`` as ``store._sum_id_runs`` (``shipped``), and the
+    parts of it alone (what they return is not the sums)."""
+    from fps_tpu.core import store
+
+    def ends(s):
+        edge = s[1:] != s[:-1]
+        one = jnp.ones((1,), bool)
+        return jnp.concatenate([one, edge]), jnp.concatenate([edge, one])
+
+    def unstable(*operands):
+        return lax.sort(operands, num_keys=1, is_stable=False)
+
+    def counted(s, cols, drop):
+        return (*cols, (s != drop).astype(cols[0].dtype))
+
+    def assoc_scan(idx, rows, drop):
+        s, *cols = unstable(idx, *rows.T)
+        first, last = ends(s)
+
+        def seg(a, b):
+            return a[0] | b[0], jnp.where(b[0][:, None], b[1], a[1] + b[1])
+
+        _, tot = lax.associative_scan(
+            seg, (first, jnp.stack(counted(s, cols, drop), axis=1)))
+        ids, *cols = unstable(jnp.where(last, s, drop), *tot.T)
+        return ids, jnp.stack(cols, axis=1)
+
+    def positions(idx, rows, drop):
+        pos = jnp.arange(idx.shape[0], dtype=jnp.int32)
+        s, order = unstable(idx, pos)
+        first, last = ends(s)
+        cols = store._run_sums(first, counted(
+            s, tuple(jnp.take(rows, order, axis=0).T), drop))
+        ids, order = unstable(jnp.where(last, s, drop), pos)
+        return ids, jnp.take(jnp.stack(cols, axis=1), order, axis=0)
+
+    def ones_through_both_sorts(idx, rows, drop):
+        s, *cols = unstable(idx, *counted(idx, tuple(rows.T), drop))
+        first, last = ends(s)
+        ids, *cols = unstable(jnp.where(last, s, drop),
+                              *store._run_sums(first, tuple(cols)))
+        return ids, jnp.stack(cols, axis=1)
+
+    def sort_once(idx, rows, drop):
+        s, *cols = unstable(idx, *rows.T)
+        return s, jnp.stack(cols, axis=1)
+
+    def sorts_no_scan(idx, rows, drop):
+        s, *cols = unstable(idx, *rows.T)
+        ids, *cols = unstable(jnp.where(ends(s)[1], s, drop),
+                              *counted(s, cols, drop))
+        return ids, jnp.stack(cols, axis=1)
+
+    return {"shipped": store._sum_id_runs, "assoc_scan": assoc_scan,
+            "positions": positions,
+            "ones_through_both_sorts": ones_through_both_sorts,
+            "sort_once": sort_once, "sorts_no_scan": sorts_no_scan}
+
+
+def _fold_probe_ops(R, B, live):
+    """``name -> (op(table, ids) -> table, ids wanted)`` of every probe at
+    one shape; ids wanted: ``"front"`` / ``"between"`` as the summed runs
+    leave them (:func:`_compacted`), ``"raw"`` as they are pushed."""
+    import fps_tpu.ops as ops
+
+    def fold_with(scatter):
+        def op(t, i):
+            acc = scatter(jnp.broadcast_to(lax.optimization_barrier(
+                jnp.zeros((), jnp.float32)), (R, FOLD_D + 1)), i,
+                _fold_rows_counted(i, R))
+            return jnp.where(acc[:, FOLD_D:] > 0, t + acc[:, :FOLD_D], t)
+        return op
+
+    def blocks(C):
+        def scatter(z, i, d):
+            keep = ops.XLA_SORTED_BLOCK_IDS
+            ops.XLA_SORTED_BLOCK_IDS = C
+            try:
+                return ops._xla_sorted_scatter_add(z, i, d)
+            finally:
+                ops.XLA_SORTED_BLOCK_IDS = keep
+        return scatter
+
+    def flagged(**kw):
+        return lambda z, i, d: z.at[i].add(d, mode="drop", **kw)
+
+    def prefix(n):
+        return lambda z, i, d: ops.scatter_add(z, i[:n], d[:n])
+
+    def runs_alone(sum_runs):
+        def op(t, i):
+            idx, cols = sum_runs(i, _fold_rows(i), R)
+            return lax.dynamic_update_slice(
+                t, (jnp.sum(cols) + jnp.sum(idx) * 1e-9)[None, None] * 1e-9,
+                (0, 0))
+        return op
+
+    out = {"plain_unsorted": (fold_with(ops.scatter_add), "raw"),
+           "a_plain_sorted_sentinel_between": (fold_with(ops.scatter_add),
+                                               "between")}
+    for name, sc in (
+            ("a_plain_sorted_sentinel_tail", ops.scatter_add),
+            ("a_told_sorted", flagged(indices_are_sorted=True)),
+            ("a_told_sorted_unique", flagged(indices_are_sorted=True,
+                                             unique_indices=True)),
+            ("b_blocks_1024", blocks(1_024)),
+            ("b_blocks_4096", blocks(4_096)),
+            ("b_blocks_16384", blocks(16_384)),
+            ("c_ladder_4", lambda z, i, d: _ladder_scatter(z, i, d, 4)),
+            ("c_ladder_16", lambda z, i, d: _ladder_scatter(z, i, d, 16)),
+            ("static_prefix_live", prefix(-(-live // 1024) * 1024)),
+            ("static_prefix_quarter", prefix(B // 4))):
+        out[name] = (fold_with(sc), "front")
+    for name, fn in _runs_variants().items():
+        out[f"runs_{name}"] = (runs_alone(fn), "raw")
+    return out
+
+
+def fold_probes():
+    """The probes behind ``push.acc_runs``' last step, at the cell's own
+    shape (``[1000000, 3]`` zeros, 425,997 ids of ``lr-criteo.epochs``'s
+    own columns): us a step of each way to scatter the summed runs, and of
+    each way to sum them (``runs_*``: no scatter). ``(a)`` the plain
+    scatter-add handed the distinct ids sorted and the sentinel after;
+    ``(b)`` the block loop of ``scatter_add.xla_sorted`` on the zeros as
+    its carry; ``(c)`` the ladder of static prefixes."""
+    R, T = LR_CRITEO[0], 16
+    raw = _criteo_ids(T)
+    front = _compacted(raw, R)
+    live = int(np.mean(np.sum(front < R, axis=1)))
+    out = {"rows": R, "ids": raw.shape[1], "live": live}
+    runners = {"raw": _fold_runner(R, raw), "front": _fold_runner(R, front),
+               "between": _fold_runner(R, _compacted(raw, R, front=False))}
+    for name, (op, wanted) in _fold_probe_ops(
+            R, raw.shape[1], live).items():
+        out[f"{name}_us"], _ = runners[wanted](op)
+        print(f"  {name}: {out[f'{name}_us']}", flush=True)
+    return out
+
+
+def fold_sweep(args):
+    """``fold``: the stateful fold's push by the plain accumulator against
+    ``push.acc_runs`` over rows x ids at width 2, uniform and Zipf(1.05)
+    ids (the sweep that set ``ops.ACC_RUNS_MIN_IDS_PER_ROW`` and widened
+    ``ops._route_xla_sorted`` to narrow rows), then the cell's own shape
+    and columns and ``mf-netflix.x4``'s shard push (inside XLA's VMEM
+    regime, the route forced). ``fold edge``: the points round the grid
+    alone. ``fold probes``: the ways to scatter and to sum the runs at the
+    cell's shape. ``fold quick``: the cell's shape alone. One JSON line a
+    point, all in ``chiprun_out/bench_scatter_fold.jsonl``."""
+    cell = (fold_point, (LR_CRITEO[0], LR_CRITEO[1] * 26 + 13, "criteo"))
+    edge = [(fold_point, (*p, dist)) for p in FOLD_EDGE
+            for dist in ("uniform", "zipf")] + [
+                (fold_point, (*FOLD_EDGE[-1], "criteo_x4"))]
+    if args == ["probes"]:
+        points = [(fold_probes, ())]
+    elif args == ["quick"]:
+        points = [cell]
+    elif args == ["edge"]:
+        points = edge
+    else:
+        points = [(fold_point, (R, B, dist)) for dist in ("uniform", "zipf")
+                  for B in FOLD_B for R in FOLD_R] + edge + [
+                      cell, (fold_point, MF_X4)]
+    _write_points("fold", points)
+
+
 if __name__ == "__main__":
     import sys
 
@@ -430,12 +786,17 @@ if __name__ == "__main__":
         mean_sweep(sys.argv[2:])
     elif sys.argv[1:2] == ["wide"]:
         wide_sweep(sys.argv[2:])
+    elif sys.argv[1:2] == ["fold"]:
+        fold_sweep(sys.argv[2:])
     else:
         raise SystemExit(
             f"unknown args {sys.argv[1:]!r} — usage: bench_scatter.py "
-            "dim1|rows [quick]|mean [counts]|wide [quick]  ('dim1' = "
+            "dim1|rows [quick]|mean [counts]|wide [quick]|"
+            "fold [quick|edge|probes]  ('dim1' = "
             "scalar-table PA shape; 'rows' = plain XLA against the "
             "lane-packed XLA route over table rows x row width; 'mean' = "
             "the mean push's accumulator against its row branch; 'wide' = "
-            "the plain scatter-add against the sorted route on wide rows)"
+            "the plain scatter-add against the sorted route on wide rows; "
+            "'fold' = the accumulator body's plain scatter against the "
+            "pushed rows summed by id run first)"
         )
