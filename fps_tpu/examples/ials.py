@@ -99,9 +99,14 @@ def main(argv=None) -> int:
             wcm = (wd.watch("epoch", epoch) if wd is not None
                    else contextlib.nullcontext())
             with cm, wcm:
-                solver.epoch(lambda _e=epoch: source(_e, 1))
-            loss = solver.weighted_loss(train["user"], train["item"],
-                                        train["rating"])
+                _, item_sweep = solver.epoch(lambda _e=epoch: source(_e, 1))
+            # The sweep's own loss: the observed term sum c (1 - x.y)^2
+            # under the tables the item sweep READ (this epoch's users,
+            # the movies as it found them), summed on the device step by
+            # step; no dump of both tables to the host. This read is the
+            # epoch's one wait for the device.
+            loss = float(np.sum(np.asarray(item_sweep["loss"]),
+                                dtype=np.float64))
             emit({"event": "epoch", "epoch": epoch, "weighted_loss": loss})
             if rec is not None:
                 rec.inc("driver.epochs")

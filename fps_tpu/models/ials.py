@@ -26,8 +26,29 @@ TPU-native decomposition (everything static-shape, jit-compiled once):
    the solved side's id. iALS thus *reuses the PS fabric*: the normal
    equations are just another sharded table being pushed to.
 3. **Solve** — each shard solves its own ``(rps, k, k)`` batched SPD systems
-   locally (``jnp.linalg.solve``; k is small so the batched LU is cheap
-   next to the accumulate pass), no communication.
+   locally by a Cholesky factorisation and two triangular solves (the
+   left-hand side is the Gramian plus a non-negative combination of outer
+   products plus ``reg*I``: SPD by construction), a block of ids at a
+   time (:data:`SOLVE_BLOCK_IDS`), no communication.
+
+Float32 means float32: the Gramian's contraction carries
+``precision=HIGHEST`` (the TPU's default would run it in bfloat16 passes:
+7e-5 on the solved tables where this reads 1e-6; chip runs, PR 35), and
+XLA's Cholesky and triangular-solve kernels are float32 throughout (5e-7
+against a float64 solve). A half-epoch only QUEUES device work and returns
+what it did: per
+accumulate step ``n`` (the live interactions) and ``loss``, the observed
+term ``sum c (1 - x_u . y_i)^2`` under the tables the sweep READ (both
+sides as the sweep found them), as device arrays nothing on the host waits
+for.
+
+Names (``docs/observability.md``): the accumulate body opens the step
+scopes a ``Trainer`` opens (``fps.pull`` / ``fps.compute`` / ``fps.push`` /
+``fps.metrics``); what runs once a sweep is ``als.gram``, ``als.zeros`` and
+``als.solve`` (no ``fps.`` prefix: a reader counts steps by the ops under
+it); host spans ``als.half_epoch`` and inside it ``als.gram``,
+``als.accumulate`` (one a chunk queued), ``als.solve``; the route log gets
+one ``als.accumulate`` and one ``als.solve`` a traced program.
 
 The user and item factor tables share the owner-major-cyclic layout of
 :mod:`fps_tpu.core.store`, so accumulators align row-for-row with the factor
@@ -45,6 +66,7 @@ import numpy as np
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from fps_tpu import ops
 from fps_tpu.core.store import (
     ParamStore,
     TableSpec,
@@ -54,12 +76,23 @@ from fps_tpu.core.store import (
     ranged_uniform_init,
     rows_per_shard,
 )
+from fps_tpu.obs.timing import host_span
 from fps_tpu.parallel.mesh import DATA_AXIS, SHARD_AXIS
 
 Array = jax.Array
 
 USER_TABLE = "user_factors"
 ITEM_TABLE = "item_factors"
+
+# Ids a shard factorises at a time in the solve. XLA lays a batch of
+# 64 x 64 float32 systems out with its 64 lanes padded to 128, 32 KB a
+# system and three such arrays live (the left-hand sides, the factors, a
+# transposed copy): at MovieLens-20M's 138,493 users and rank 64 the whole
+# batch at once needs 13.6 GB beside the 2.27 GB accumulator and does not
+# fit a 16 GB v5e (the LU of ``jnp.linalg.solve`` needs 9.2 GB and takes
+# 2.78 s); in blocks of 8,192 it needs 0.8 GB and takes 1.15 s (16,384:
+# 1.22 s; 32,768: 1.36 s; chip runs, PR 35).
+SOLVE_BLOCK_IDS = 8192
 
 
 @dataclasses.dataclass
@@ -143,10 +176,13 @@ class IALSSolver:
     def _gram_fn(self, num_ids: int, rps: int):
         """jit: sharded table -> replicated (k, k) Gramian (padding excluded)."""
 
+        @jax.named_scope("als.gram")
         def device_fn(table):
             valid = self._valid_mask(num_ids, rps)(None)
             rows = jnp.where(valid[:, None], table, 0.0)
-            g = rows.T @ rows
+            # HIGHEST: the TPU's default runs an f32 contraction in bf16
+            # passes.
+            g = jnp.matmul(rows.T, rows, precision=lax.Precision.HIGHEST)
             return lax.psum(g, SHARD_AXIS)
 
         def run(table):
@@ -160,18 +196,24 @@ class IALSSolver:
 
         return jax.jit(run)
 
-    def _accumulate_fn(self):
+    def _accumulate_fn(self, solve: str):
         """jit: stream one chunk of interactions into (A, b) accumulators.
 
         Chunk leaves are (T, B) with B split over ALL devices (the data AND
         shard axes): ``solve_ids``, ``fixed_ids``, ``rating``, ``weight``.
         With a data axis, pushes gather across it so the replicated
         accumulators fold every worker's contributions exactly once.
+        Returns ``(A, b, metrics)``: per step ``n`` and ``loss`` summed
+        over all workers, ``(T,)`` each, under the two tables as they came
+        in (``solve_table`` is read, never written, by this program).
         """
         cfg = self.cfg
         k = cfg.rank
 
-        def device_fn(fixed_table, A, b, chunk):
+        def device_fn(fixed_table, solve_table, A, b, chunk):
+            ops.log_route("als", "accumulate", A.shape[0], k * k,
+                          chunk["weight"].shape[1] * self.num_workers, solve)
+
             def body(carry, xs):
                 A, b = carry
                 solve_ids = xs["solve_ids"].astype(jnp.int32)
@@ -179,50 +221,85 @@ class IALSSolver:
                 r = xs["rating"].astype(cfg.dtype)
                 w = xs["weight"].astype(cfg.dtype)
 
-                y = pull(fixed_table, fixed_ids, num_shards=self.num_shards)
-                cr = cfg.alpha * r * w  # confidence minus 1, masked
-                outer = (cr[:, None, None] * y[:, :, None] * y[:, None, :])
-                vec = ((1.0 + cfg.alpha * r) * w)[:, None] * y
-
-                ids = jnp.where(w > 0, solve_ids, -1)
+                with jax.named_scope("fps.pull"):
+                    y = pull(fixed_table, fixed_ids,
+                             num_shards=self.num_shards)
+                    x = pull(solve_table, solve_ids,
+                             num_shards=self.num_shards)
+                with jax.named_scope("fps.compute"):
+                    c = 1.0 + cfg.alpha * r  # confidence
+                    cr = cfg.alpha * r * w   # confidence minus 1, masked
+                    outer = (cr[:, None, None] * y[:, :, None]
+                             * y[:, None, :])
+                    vec = (c * w)[:, None] * y
+                    miss = 1.0 - jnp.sum(x * y, axis=-1)
+                    out = {"n": jnp.sum(w.astype(jnp.float32)),
+                           "loss": jnp.sum((w * c * miss * miss)
+                                           .astype(jnp.float32))}
+                    ids = jnp.where(w > 0, solve_ids, -1)
                 data_axis = DATA_AXIS if self.num_data > 1 else None
-                A = push(A, ids, outer.reshape(-1, k * k),
-                         num_shards=self.num_shards, data_axis=data_axis)
-                b = push(b, ids, vec,
-                         num_shards=self.num_shards, data_axis=data_axis)
-                return (A, b), None
+                with jax.named_scope("fps.push"):
+                    A = push(A, ids, outer.reshape(-1, k * k),
+                             num_shards=self.num_shards, data_axis=data_axis)
+                    b = push(b, ids, vec,
+                             num_shards=self.num_shards, data_axis=data_axis)
+                with jax.named_scope("fps.metrics"):
+                    out = jax.tree.map(
+                        lambda v: lax.psum(lax.psum(v, SHARD_AXIS),
+                                           DATA_AXIS), out)
+                return (A, b), out
 
-            (A, b), _ = lax.scan(body, (A, b), chunk)
-            return A, b
+            (A, b), metrics = lax.scan(body, (A, b), chunk)
+            return A, b, metrics
 
-        def run(fixed_table, A, b, chunk):
+        def run(fixed_table, solve_table, A, b, chunk):
+            table = P(SHARD_AXIS, None)
             return jax.shard_map(
                 device_fn,
                 mesh=self.mesh,
                 in_specs=(
-                    P(SHARD_AXIS, None),
-                    P(SHARD_AXIS, None),
-                    P(SHARD_AXIS, None),
+                    table, table, table, table,
                     jax.tree.map(
                         lambda _: P(None, (DATA_AXIS, SHARD_AXIS)), chunk
                     ),
                 ),
-                out_specs=(P(SHARD_AXIS, None), P(SHARD_AXIS, None)),
+                out_specs=(table, table, P()),
                 check_vma=False,
-            )(fixed_table, A, b, chunk)
+            )(fixed_table, solve_table, A, b, chunk)
 
-        return jax.jit(run, donate_argnums=(1, 2))
+        return jax.jit(run, donate_argnums=(2, 3))
 
     def _solve_fn(self, num_ids: int, rps: int):
-        """jit: (gram, A, b) -> solved factor table (local batched Cholesky)."""
+        """jit: (gram, A, b) -> solved factor table: each shard's own
+        ``(rps, k, k)`` SPD systems by a batched Cholesky factorisation
+        and two triangular solves, float32 throughout,
+        :data:`SOLVE_BLOCK_IDS` ids at a time. Padding rows come out
+        zero."""
         cfg = self.cfg
         k = cfg.rank
+        blk = min(SOLVE_BLOCK_IDS, rps)
 
+        @jax.named_scope("als.solve")
         def device_fn(gram, A, b):
-            lhs = gram[None] + A.reshape(-1, k, k)
-            lhs = lhs + cfg.reg * jnp.eye(k, dtype=cfg.dtype)[None]
-            # Batched SPD solve; jnp.linalg handles the (rps, k, k) batch.
-            x = jnp.linalg.solve(lhs, b[:, :, None])[:, :, 0]
+            ops.log_route("als", "solve", rps, k, blk, "cholesky")
+            base = gram + cfg.reg * jnp.eye(k, dtype=cfg.dtype)
+
+            def block(j, x):
+                # The last block starts early: it solves some ids again,
+                # to the same rows.
+                lo = jnp.minimum(j * blk, rps - blk)
+                lhs = base[None] + lax.dynamic_slice(
+                    A, (lo, 0), (blk, k * k)).reshape(blk, k, k)
+                rhs = lax.dynamic_slice(b, (lo, 0), (blk, k))[:, :, None]
+                chol = lax.linalg.cholesky(lhs)
+                z = lax.linalg.triangular_solve(
+                    chol, rhs, left_side=True, lower=True)
+                sol = lax.linalg.triangular_solve(
+                    chol, z, left_side=True, lower=True, transpose_a=True)
+                return lax.dynamic_update_slice(x, sol[:, :, 0], (lo, 0))
+
+            x = lax.fori_loop(0, -(-rps // blk), block,
+                              jnp.zeros((rps, k), cfg.dtype))
             valid = self._valid_mask(num_ids, rps)(None)
             return jnp.where(valid[:, None], x, 0.0).astype(cfg.dtype)
 
@@ -243,18 +320,25 @@ class IALSSolver:
         fn = self._compiled_zeros.get((rows, dim))
         if fn is None:
             fn = self._compiled_zeros[(rows, dim)] = jax.jit(
-                lambda: jnp.zeros((rows, dim), self.cfg.dtype),
+                jax.named_scope("als.zeros")(
+                    lambda: jnp.zeros((rows, dim), self.cfg.dtype)),
                 out_shardings=self._sharding,
             )
         return fn()
 
-    def half_epoch(self, solve: str, chunks: Iterable[dict]) -> None:
+    def half_epoch(self, solve: str, chunks: Iterable[dict]) -> dict:
         """One ALS half-step: solve ``"user"`` or ``"item"`` factors.
 
         ``chunks`` yield dicts with (T, B) arrays ``user``, ``item``,
         ``rating``, ``weight`` (as produced by
         :func:`fps_tpu.core.ingest.epoch_chunks`; B must be divisible by
         ``num_workers`` = data * shard, the full device count).
+
+        Only queues device work (nothing here reads a device value, so
+        the next sweep can be queued behind this one) and returns the
+        sweep's per-step device metrics: ``{"n", "loss"}``, each
+        ``(steps,)`` over every chunk's steps in order, padding steps
+        (weight 0) reading 0.
         """
         cfg = self.cfg
         if solve == "user":
@@ -271,17 +355,6 @@ class IALSSolver:
         solve_rps = rows_per_shard(solve_n, self.num_shards)
         fixed_rps = rows_per_shard(fixed_n, self.num_shards)
         k = cfg.rank
-
-        if fixed_name not in self._compiled_gram:
-            self._compiled_gram[fixed_name] = self._gram_fn(fixed_n, fixed_rps)
-        gram = self._compiled_gram[fixed_name](self.store.tables[fixed_name])
-
-        A = self._zeros_acc(solve_rps * self.num_shards, k * k)
-        b = self._zeros_acc(solve_rps * self.num_shards, k)
-
-        acc = self._compiled_acc.get(solve)
-        if acc is None:
-            acc = self._compiled_acc[solve] = self._accumulate_fn()
         sharding = NamedSharding(self.mesh, P(None, (DATA_AXIS, SHARD_AXIS)))
 
         def to_dev(x):
@@ -300,31 +373,60 @@ class IALSSolver:
                 "weight": to_dev(chunk["weight"]),
             }
 
-        it, pf = chunks, None
-        if self.prefetch:
-            from fps_tpu.core.prefetch import ChunkPrefetcher
+        # The spans time the host's QUEUEING, recorder or none, as the
+        # Trainer's ``enqueue`` does: nothing here waits for the device. (A
+        # span that closed on the device's completion under a recorder made
+        # a traced call return finished; the benchmark's traced loop stops
+        # its profiler from inside its wait for a call and never did.)
+        with host_span("als.half_epoch", call=True, solve=solve):
+            fixed, solved = (self.store.tables[fixed_name],
+                             self.store.tables[solve_name])
+            with host_span("als.gram"):
+                if fixed_name not in self._compiled_gram:
+                    self._compiled_gram[fixed_name] = self._gram_fn(
+                        fixed_n, fixed_rps)
+                gram = self._compiled_gram[fixed_name](fixed)
 
-            it = pf = ChunkPrefetcher(chunks, place, depth=self.prefetch)
-        try:
-            for item in it:
-                # Prefetched items arrive pre-placed (PlacedChunk).
-                dev_chunk = item.batches if pf is not None else place(item)
-                A, b = acc(self.store.tables[fixed_name], A, b, dev_chunk)
-        finally:
-            if pf is not None:
-                pf.close()
+            A = self._zeros_acc(solve_rps * self.num_shards, k * k)
+            b = self._zeros_acc(solve_rps * self.num_shards, k)
+            acc = self._compiled_acc.get(solve)
+            if acc is None:
+                acc = self._compiled_acc[solve] = self._accumulate_fn(solve)
 
-        if solve_name not in self._compiled_solve:
-            self._compiled_solve[solve_name] = self._solve_fn(solve_n, solve_rps)
-        self.store.tables[solve_name] = self._compiled_solve[solve_name](
-            gram, A, b
-        )
+            it, pf = chunks, None
+            if self.prefetch:
+                from fps_tpu.core.prefetch import ChunkPrefetcher
 
-    def epoch(self, make_chunks) -> None:
+                it = pf = ChunkPrefetcher(chunks, place, depth=self.prefetch)
+            metrics = []
+            try:
+                for item in it:
+                    with host_span("als.accumulate"):
+                        # Prefetched items arrive pre-placed (PlacedChunk).
+                        dev_chunk = (item.batches if pf is not None
+                                     else place(item))
+                        A, b, m = acc(fixed, solved, A, b, dev_chunk)
+                    metrics.append(m)
+            finally:
+                if pf is not None:
+                    pf.close()
+
+            with host_span("als.solve"):
+                if solve_name not in self._compiled_solve:
+                    self._compiled_solve[solve_name] = self._solve_fn(
+                        solve_n, solve_rps)
+                self.store.tables[solve_name] = self._compiled_solve[
+                    solve_name](gram, A, b)
+            if len(metrics) < 2:
+                return metrics[0] if metrics else {}
+            return jax.tree.map(lambda *xs: jnp.concatenate(xs), *metrics)
+
+    def epoch(self, make_chunks) -> tuple[dict, dict]:
         """One full ALS epoch. ``make_chunks()`` returns a fresh chunk
-        iterator (it is consumed twice: once per half-epoch)."""
-        self.half_epoch("user", make_chunks())
-        self.half_epoch("item", make_chunks())
+        iterator (it is consumed twice: once per half-epoch). Returns the
+        two sweeps' metrics, the user sweep's first."""
+        return (self.half_epoch("user", make_chunks()),
+                self.half_epoch("item", make_chunks()))
 
     # -- evaluation ----------------------------------------------------------
 

@@ -99,7 +99,10 @@ STEP_SCOPES = ("fps.ingest", "fps.prepare", "fps.sketch", "fps.pull",
                "fps.hot_accumulate", "fps.reconcile", "fps.sketch_merge",
                "fps.megastep_vote", "fps.megastep_tick", "fps.metrics")
 ONCE_SCOPES = ("ingest.pack", "ingest.tbuf", "ingest.perm", "ingest.chunk",
-               "ingest.compact")
+               "ingest.compact",
+               # models/ials.py, once a sweep: the Gramian of the fixed
+               # table, the accumulators' zero fill, the batched solve
+               "als.gram", "als.zeros", "als.solve")
 ROUND_SCOPES = ("ssp.snapshot",)
 # Set-up spans (no timer: they report through the process-default
 # recorder). Those that queue device work close on its completion when a
@@ -107,7 +110,12 @@ ROUND_SCOPES = ("ssp.snapshot",)
 SETUP_PHASES = ("dataset.place", "dataset.queues", "dataset.pack",
                 "plan.build", "init_state")
 # The driver entry points: each opens a root span and numbers the call.
-CALL_SPANS = ("run_indexed", "fit_stream", "run_megastep")
+# ``als.half_epoch`` is models/ials.py's (one ALS sweep; no Trainer).
+CALL_SPANS = ("run_indexed", "fit_stream", "run_megastep", "als.half_epoch")
+# Inside ``als.half_epoch``, in order: the Gramian queued, one
+# ``als.accumulate`` a chunk queued, the solve queued: the host's cost of
+# queueing, like ``enqueue`` (a sweep reads nothing back).
+SWEEP_PHASES = ("als.gram", "als.accumulate", "als.solve")
 
 HOST_SPAN_PREFIX = "fps.host."
 

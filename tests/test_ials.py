@@ -177,3 +177,62 @@ def test_full_mesh_matches_shard_only_mesh(mods, devices8):
     # float32 reassociation.
     np.testing.assert_allclose(U_a, U_b, rtol=5e-4, atol=5e-5)
     np.testing.assert_allclose(V_a, V_b, rtol=5e-4, atol=5e-5)
+
+
+def test_half_epoch_returns_the_sweeps_own_count_and_loss(mods, devices8):
+    """A sweep returns per-step ``n`` and ``loss`` (device arrays): their
+    sums are the ratings fed and the host ``weighted_loss``'s observed
+    term on the tables the sweep READ, not on the one it leaves."""
+    ials = mods["ials"]
+    nu, ni = 48, 32
+    solver = _solver(mods, 4, nu, ni, rank=8, alpha=10.0, reg=0.5)
+    data = mods["synthetic_implicit"](nu, ni, 12, rank=3, seed=3)
+    n_rows = len(data["user"])
+
+    def chunks():
+        return ials.interaction_chunks(data, num_workers=4, local_batch=8,
+                                       steps_per_chunk=4, seed=0)
+
+    def observed():
+        U, V = solver.factors()
+        reg = solver.cfg.reg * float(np.sum(U * U) + np.sum(V * V))
+        return solver.weighted_loss(data["user"], data["item"],
+                                    data["rating"]) - reg
+
+    for side in ("user", "item", "user"):
+        before = observed()
+        m = solver.half_epoch(side, chunks())
+        assert set(m) == {"n", "loss"} and m["n"].shape == m["loss"].shape
+        assert m["n"].shape[0] % 4 == 0  # whole chunks, padding included
+        assert float(np.sum(m["n"])) == n_rows
+        np.testing.assert_allclose(float(np.sum(np.asarray(m["loss"]), dtype=np.float64)),
+                                   before, rtol=1e-5)
+        assert observed() < before
+    both = solver.epoch(chunks)
+    assert [float(np.sum(m["n"])) for m in both] == [n_rows, n_rows]
+
+
+@pytest.mark.parametrize("block", [5, 7, 64])
+def test_cholesky_solve_matches_numpy_in_every_block(mods, devices8,
+                                                     monkeypatch, block):
+    """``_solve_fn`` (Cholesky and two triangular solves, a block of ids
+    at a time) against ``np.linalg.solve`` in float64: a block count that
+    divides the shard's rows, one that does not (the last block starts
+    early and solves some ids twice), and one block for all."""
+    jax, ials = mods["jax"], mods["ials"]
+    monkeypatch.setattr(ials, "SOLVE_BLOCK_IDS", block)
+    nu, k = 35, 6
+    solver = _solver(mods, 1, nu, 9, k, reg=0.3)
+    rng = np.random.default_rng(block)
+    Y = rng.normal(size=(nu, 4, k))
+    A = np.einsum("nri,nrj->nij", Y, Y)
+    gram = rng.normal(size=(12, k))
+    gram = gram.T @ gram
+    b = rng.normal(size=(nu, k))
+    got = solver._solve_fn(nu, nu)(
+        jax.numpy.asarray(gram, np.float32),
+        jax.numpy.asarray(A.reshape(nu, k * k), np.float32),
+        jax.numpy.asarray(b, np.float32))
+    want = np.linalg.solve(gram[None] + A + 0.3 * np.eye(k)[None],
+                           b[:, :, None])[:, :, 0]
+    np.testing.assert_allclose(np.asarray(got), want, rtol=2e-4, atol=2e-5)

@@ -695,7 +695,8 @@ def test_driver_phases_cover_what_the_drivers_emit():
     from fps_tpu.obs import timing
 
     declared = (set(timing.DRIVER_PHASES) | set(timing.NESTED_PHASES)
-                | set(timing.SETUP_PHASES) | set(timing.CALL_SPANS))
+                | set(timing.SETUP_PHASES) | set(timing.CALL_SPANS)
+                | set(timing.SWEEP_PHASES))
     root = os.path.join(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))), "fps_tpu")
     emitted = set()

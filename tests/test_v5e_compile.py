@@ -448,3 +448,100 @@ def test_lr_criteo_epoch_program_names_its_round_and_fits(topo, monkeypatch,
         "/fps.push/fps.combine/" in ln
         or "/fps.push/fps.ops/scatter_add.xla_sorted/" in ln
         for ln in sized), sized
+
+
+@pytest.fixture(scope="module")
+def ials_user_sweep(topo):
+    """``ials-ml20m.sweeps``'s user sweep at the cell's own size (138,493
+    users x 26,744 movies, rank 64, 16,384 ratings a step, 64 steps a
+    chunk) for one described chip: its Gramian, accumulate and solve
+    programs compiled, and the accumulate program's route log."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from fps_tpu.models.ials import IALSConfig, IALSSolver
+    from fps_tpu.parallel.mesh import make_ps_mesh
+
+    NU, NI, K, B, T = 138_493, 26_744, 64, 16_384, 64
+    mesh = make_ps_mesh(num_shards=1, devices=list(topo.devices)[:1])
+    solver = IALSSolver(mesh, IALSConfig(num_users=NU, num_items=NI, rank=K))
+
+    def shape(s, dtype, spec=P()):
+        return jax.ShapeDtypeStruct(s, dtype,
+                                    sharding=NamedSharding(mesh, spec))
+
+    def table(rows, dim):
+        return shape((rows, dim), jnp.float32, P("shard", None))
+
+    chunk = {k: shape((T, B), jnp.int32 if k.endswith("ids")
+                      else jnp.float32, P(None, ("data", "shard")))
+             for k in ("solve_ids", "fixed_ids", "rating", "weight")}
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(ops, "_use_pallas", lambda: (True, False))
+        gram = solver._gram_fn(NI, NI).lower(table(NI, K)).compile()
+        ops.clear_routes()
+        acc = solver._accumulate_fn("user").lower(
+            table(NI, K), table(NU, K), table(NU, K * K), table(NU, K),
+            chunk).compile()
+        routes = [(r.route, r.rows, r.dim, r.ids, r.reason)
+                  for r in ops.routes_traced()]
+        solve = solver._solve_fn(NU, NU).lower(
+            shape((K, K), jnp.float32), table(NU, K * K),
+            table(NU, K)).compile()
+    return gram, acc, solve, routes
+
+
+def test_ials_gramian_and_solve_are_float32_and_the_solve_fits(
+        ials_user_sweep):
+    """The Gramian's contraction carries ``HIGHEST`` in the COMPILED text
+    and nothing in either program is bfloat16; the solve is XLA's Cholesky
+    kernel a block of 8,192 ids at a time under ``als.solve``: under
+    1.5 GB of temporaries, where the whole batch at once needs 13.6 GB
+    beside the 2.27 GB accumulator (my compile-only reading, PR 35)."""
+    gram, _, solve, _ = ials_user_sweep
+    text = gram.as_text()
+    dots = [ln for ln in text.splitlines() if " convolution(" in ln]
+    assert dots and all("operand_precision={highest,highest}" in ln
+                        and "/als.gram/dot_general" in ln
+                        for ln in dots), dots
+    assert "bf16" not in text
+    text = solve.as_text()
+    assert "bf16" not in text
+    assert 'custom_call_target="Cholesky"' in text
+    chol = [ln for ln in text.splitlines() if '"Cholesky"' in ln]
+    assert all("/als.solve/while/body/" in ln and "f32[8192,64,64]" in ln
+               for ln in chol), chol
+    assert "f32[138493,64,64]" not in text
+    assert solve.memory_analysis().temp_size_in_bytes < 1.5 * (1 << 30)
+
+
+def test_ials_accumulate_scatters_in_place_under_the_step_scopes(
+        ials_user_sweep):
+    """The accumulate program: the one op that makes an
+    ``f32[138493,4096]`` inside the loop is the wide push's scatter-add,
+    in place on the carry (the accumulator is aliased from argument to
+    result and nothing copies it); both pulls and both pushes take the
+    plain XLA routes; every step scope a reader selects by is in the
+    compiled text (``fps.metrics`` holds the cross-worker sums alone,
+    which one chip has none of)."""
+    _, acc, _, routes = ials_user_sweep
+    assert routes == [
+        ("als.accumulate", 138_493, 4096, 16_384, "user"),
+        ("gather.xla", 26_744, 64, 16_384, "shape"),
+        ("gather.xla", 138_493, 64, 16_384, "shape"),
+        ("scatter_add.xla", 138_493, 4096, 16_384, "shape"),
+        ("scatter_add.xla", 138_493, 64, 16_384, "shape")]
+    mem = acc.memory_analysis()
+    assert mem.alias_size_in_bytes >= 138_493 * 4096 * 4
+    assert mem.temp_size_in_bytes < 1 << 30
+    text = acc.as_text()
+    assert "bf16" not in text
+    sized = [ln for ln in _top_level(text)
+             if (m := re.search(r"= f32\[138493,4096\]\S* ([\w\-]+)\(", ln))
+             and m.group(1) not in ("get-tuple-element", "parameter")]
+    assert len(sized) == 1 and " fusion(" in sized[0] and (
+        "/fps.push/fps.ops/scatter_add.xla/scatter-add" in sized[0]), sized
+    assert not [ln for ln in text.splitlines()
+                if re.search(r"= f32\[138493,4096\]\S* copy\(", ln)]
+    for scope in ("/fps.pull/fps.ops/gather.xla/", "/fps.compute/",
+                  "/fps.push/fps.ops/scatter_add.xla/"):
+        assert scope in text, scope
