@@ -709,8 +709,10 @@ def pull(
 # multiply of the B pushed rows and their sum by id in a (B, dim) buffer.
 # And where the accumulator branch sums the pushed rows by id run before
 # its scatter (``push.acc_runs``): two sorts of the B ids carrying the
-# rows' columns and the doubling passes between them, payload-sized too.
-# The routed scatter-add keeps its own ``fps.ops/...``
+# rows' columns and the doubling passes between them, payload-sized too;
+# and where it is filled by the dense exchange (``push.dense_acc``): the
+# all_to_all of the buffer's windows and the sum over the source shards,
+# table-sized. The routed scatter-add keeps its own ``fps.ops/...``
 # scope BESIDE this one, so no op counts under both
 # (docs/observability.md).
 COMBINE_SCOPE = "fps.combine"
@@ -825,11 +827,12 @@ def _mean_push_ratio(rps: int, dim: int, num_ids: int, dtype) -> float:
     return acc / ops._tiled_table_bytes(num_ids, dim, dtype)
 
 
-def _mean_push_route(local_shard: Array, num_ids: int,
+def _mean_push_route(rps: int, dim: int, dt, num_ids: int,
                      apply_fn) -> tuple[str, str]:
-    """Which branch a ``"mean"`` push takes, from :func:`push`'s own
-    arguments: ``("mean_rows", "")`` (normalise the pushed rows, scatter
-    them into the table once) or ``("mean_dense", reason)`` (the
+    """Which branch a ``"mean"`` push takes, from the shapes its exchange
+    scatters (``num_ids`` rows of ``dim`` into ``rps`` rows of the table's
+    dtype ``dt``): ``("mean_rows", "")`` (normalise the pushed rows,
+    scatter them into the table once) or ``("mean_dense", reason)`` (the
     ``(rows, dim + 1)`` accumulator). ``reason``: ``"fold"`` (a
     non-additive ``apply_fn`` must see the combined delta once per id),
     ``"dtype"`` (the table is narrower than the accumulate dtype: a bf16
@@ -837,8 +840,6 @@ def _mean_push_route(local_shard: Array, num_ids: int,
     would sum in bf16) or ``"small_table"`` (the accumulator's passes cost
     less than counting and summing the pushes of every id apart from the
     scatter: :data:`fps_tpu.ops.MEAN_ROWS_TABLE_RATIO`)."""
-    rps, dim = local_shard.shape
-    dt = local_shard.dtype
     if apply_fn is not None:
         return "mean_dense", "fold"
     if jnp.promote_types(dt, jnp.float32) != dt:
@@ -846,6 +847,52 @@ def _mean_push_route(local_shard: Array, num_ids: int,
     if _mean_push_ratio(rps, dim, num_ids, dt) < ops.MEAN_ROWS_TABLE_RATIO:
         return "mean_dense", "small_table"
     return "mean_rows", ""
+
+
+def _gathered_exchange(ids: Array, deltas: Array, *, rps: int,
+                       num_shards: int, shard_axis: str,
+                       data_axis: str | None) -> tuple[Array, Array, Array]:
+    """The gathered exchange of a push: every shard sees EVERY worker's
+    ids and deltas (all-gathered over the data axis, then the shard axis)
+    and keeps the rows it owns. Returns ``(local_idx, gathered_deltas,
+    owned)``: the shard-local row of each gathered push, ``rps`` (out of
+    range: dropped by the scatter) for one this shard does not own or the
+    worker dropped; the deltas as gathered; the mask of the owned."""
+    if data_axis is not None:
+        ids = lax.all_gather(ids, data_axis, tiled=True)
+        deltas = lax.all_gather(deltas, data_axis, tiled=True)
+    ids = lax.all_gather(ids, shard_axis, tiled=True)
+    deltas = lax.all_gather(deltas, shard_axis, tiled=True)
+    me = lax.axis_index(shard_axis)
+    owned = ((ids % num_shards) == me) & (ids >= 0)
+    return jnp.where(owned, ids // num_shards, rps), deltas, owned
+
+
+def _dense_exchange(buf: Array, *, num_shards: int, shard_axis: str,
+                    data_axis: str | None) -> Array:
+    """The dense exchange of a push: from ``buf [num_shards * rps, W]``,
+    this worker's OWN pushes scatter-added into a zeroed buffer of all the
+    table's rows in physical (owner-major) layout, to ``[rps, W]``, this
+    shard's rows summed over every worker. ``O(B)`` row transactions a
+    worker where the gathered exchange pays ``O(W * B)`` a shard, at the
+    price of table-sized collectives: for small tables.
+
+    NOTE deliberate collective choice: all_to_all / all_gather move
+    position-indexed data (order-insensitive), and the cross-worker sums
+    run as FIXED-ORDER in-program reductions — a psum / psum_scatter here
+    would delegate the float reduction order to the backend topology and
+    break the tested bit-identity of 1-process vs multi-process runs on
+    the same mesh (tests/test_multiprocess.py)."""
+    if num_shards > 1:
+        # Route each shard's window of my contributions to its owner:
+        # every shard receives (S, rps, W) — all workers' sums for ITS
+        # rows — and folds them in shard-index order.
+        buf = jnp.sum(lax.all_to_all(
+            buf.reshape(num_shards, -1, buf.shape[1]), shard_axis,
+            split_axis=0, concat_axis=0, tiled=False), axis=0)
+    if data_axis is not None:
+        buf = jnp.sum(lax.all_gather(buf, data_axis), axis=0)
+    return buf
 
 
 def pull_local(
@@ -950,88 +997,92 @@ def push(
         cyclic layout, global head ids ``[0, H)`` land exactly in local
         rows ``[0, ceil(H / num_shards))`` on every shard. ``hot_rows``
         without ``head_prefix`` changes nothing.
-      dense: dense-reduce route for SMALL tables with the ADDITIVE fold:
-        each worker scatters its OWN ``B`` deltas into a table-shaped
-        zeros buffer (physical layout); an ``all_to_all`` of per-shard
-        windows plus fixed-order in-program sums (see the NOTE in the
-        body — deliberately NOT psum/psum_scatter) deliver every shard
-        its summed slice — ``O(B)`` row transactions per worker instead
-        of ``O(W * B)`` per shard, at the price of table-sized
-        collectives. Non-additive folds (``apply_fn``/non-"sum"
-        ``combine`` need per-id combine-then-apply semantics over the
-        gathered union) silently keep the gathered route.
+      dense: the DENSE EXCHANGE for SMALL tables (the driver's decision
+        from the table's bytes, ``TableSpec.dense_collectives`` against
+        :data:`fps_tpu.ops.DENSE_TABLE_BYTES`): each worker scatters its
+        OWN ``B`` rows into a zeroed buffer of all the table's rows
+        (physical layout); an ``all_to_all`` of per-shard windows plus
+        fixed-order in-program sums (:func:`_dense_exchange` — deliberately
+        NOT psum/psum_scatter) deliver every shard its summed slice —
+        ``O(B)`` row transactions per worker instead of the gathered
+        exchange's ``O(W * B)`` per shard (every shard scatters every
+        worker's rows and drops the unowned by index), at the price of
+        table-sized collectives. The additive fold exchanges the deltas'
+        sums (``(rps, dim)``); every push that keeps the ``(rps, dim + 1)``
+        accumulator (a ``"mean"`` on ``push.mean_dense``, a callable
+        ``combine``, any ``apply_fn``) exchanges the accumulator itself,
+        the ones column riding the rows (``push.dense_acc`` in the route
+        log): the same sums and the same exact counts, the float additions
+        per worker first and then over the shards in index order. Two
+        pushes keep the gathered exchange under ``dense=True``:
+        ``"max"`` / ``"min"`` (no sum to exchange) and a mean push whose
+        table is large against the worker's own payload
+        (``push.mean_rows``, asked about the ``B`` ids and
+        ``num_shards * rps`` rows the dense exchange would scatter). On
+        one device nothing is exchanged and ``dense`` changes nothing.
 
     Returns:
       Updated ``(rps, dim)`` local block.
     """
-    if dense and apply_fn is None and combine == "sum":
-        # NOTE deliberate collective choice: all_to_all/all_gather move
-        # position-indexed data (order-insensitive), and the cross-worker
-        # sums below run as FIXED-ORDER in-program reductions — a psum /
-        # psum_scatter here would delegate the f32 reduction order to the
-        # backend topology and break the tested bit-identity of 1-process
-        # vs multi-process runs on the same mesh
-        # (tests/test_multiprocess.py). Payloads are table-sized either
-        # way; only small tables take this route.
-        rps = local_shard.shape[0]
-        phys = jnp.where(ids >= 0, id_to_phys(ids, num_shards, rps), -1)
-        buf = ops.scatter_add(
-            jnp.zeros((rps * num_shards, deltas.shape[1]),
-                      local_shard.dtype),
-            phys,
-            deltas,
-        )
-        if num_shards > 1:
-            # Route each shard's window of my contributions to its owner:
-            # every shard receives (S, rps, dim) — all workers' deltas for
-            # ITS rows — and folds them in shard-index order.
-            parts = lax.all_to_all(
-                buf.reshape(num_shards, rps, -1), shard_axis,
-                split_axis=0, concat_axis=0, tiled=False,
-            )
-            mine = jnp.sum(parts, axis=0)
-        else:
-            mine = buf
-        if data_axis is not None:
-            mine = jnp.sum(lax.all_gather(mine, data_axis), axis=0)
-        return local_shard + mine
-
-    gathered_ids = ids
-    gathered_deltas = deltas
-    if data_axis is not None:
-        gathered_ids = lax.all_gather(gathered_ids, data_axis, tiled=True)
-        gathered_deltas = lax.all_gather(gathered_deltas, data_axis, tiled=True)
-    gathered_ids = lax.all_gather(gathered_ids, shard_axis, tiled=True)
-    gathered_deltas = lax.all_gather(gathered_deltas, shard_axis, tiled=True)
-
-    me = lax.axis_index(shard_axis)
-    rps = local_shard.shape[0]
-    owned = ((gathered_ids % num_shards) == me) & (gathered_ids >= 0)
-    # Unowned/dropped rows get an out-of-range index, dropped by the scatter.
-    local_idx = jnp.where(owned, gathered_ids // num_shards, rps)
-    masked = jnp.where(owned[:, None], gathered_deltas, jnp.zeros_like(gathered_deltas))
-
     if not callable(combine) and combine not in ("sum", "mean", "max", "min"):
         raise ValueError(f"unknown combine mode {combine!r}")
-
-    if apply_fn is None and combine == "sum":
-        # Head-prefix guarantee survives only when the gathered stream is
-        # the caller's own (single shard, no data axis — the driver also
-        # gates it to single-device meshes).
-        keep_prefix = (num_shards == 1 and data_axis is None)
-        return ops.scatter_add(local_shard, local_idx, masked,
-                               hot_rows=hot_rows,
-                               head_prefix=head_prefix if keep_prefix else 0)
-
-    dim = masked.shape[1]
+    rps, dim = local_shard.shape
+    B = ids.shape[0]
+    additive = apply_fn is None and combine == "sum"
     # Accumulate in at least f32, but never BELOW the table's own precision:
     # a float64 table must fold its duplicates in float64 (hard-coding f32
     # here would silently shave 29 mantissa bits off every non-"sum" push).
     acc_dt = jnp.promote_types(local_shard.dtype, jnp.float32)
-    B = local_idx.shape[0]
+    # Which exchange fills the buffer the scatter writes: the dense one
+    # (the worker's OWN pushes into all the table's rows, then the shards'
+    # windows to their owners) where the driver says the table is small
+    # and there is somebody to exchange with; max / min (no sum to
+    # exchange) and the mean's row branch (asked about what the dense
+    # exchange would scatter: B ids into all the rows) keep the gathered
+    # one, which on one device is no collective at all.
+    workers = num_shards * (
+        1 if data_axis is None else lax.axis_size(data_axis))
+    dense = (dense and workers > 1 and combine not in ("max", "min")
+             and (combine != "mean" or _mean_push_route(
+                 rps * num_shards, dim, local_shard.dtype, B, apply_fn)[0]
+                 == "mean_dense"))
+    if dense:
+        exchange = partial(_dense_exchange, num_shards=num_shards,
+                           shard_axis=shard_axis, data_axis=data_axis)
+        # Physical (owner-major) rows; a dropped id past the last of them
+        # (the end a sort puts it at: ``push.acc_runs``).
+        acc_rows = rps * num_shards
+        local_idx = jnp.where(
+            ids >= 0, id_to_phys(ids, num_shards, rps), acc_rows)
+        if additive:
+            return local_shard + exchange(ops.scatter_add(
+                jnp.zeros((acc_rows, dim), local_shard.dtype), local_idx,
+                deltas))
+        rows = deltas.astype(acc_dt)
+        live = jnp.ones((B,), acc_dt)
+    else:
+        acc_rows = rps
+        local_idx, gathered_deltas, owned = _gathered_exchange(
+            ids, deltas, rps=rps, num_shards=num_shards,
+            shard_axis=shard_axis, data_axis=data_axis)
+        B = local_idx.shape[0]
+        masked = jnp.where(owned[:, None], gathered_deltas,
+                           jnp.zeros_like(gathered_deltas))
+        if additive:
+            # Head-prefix guarantee survives only when the gathered stream
+            # is the caller's own (single shard, no data axis — the driver
+            # also gates it to single-device meshes).
+            keep_prefix = (num_shards == 1 and data_axis is None)
+            return ops.scatter_add(
+                local_shard, local_idx, masked, hot_rows=hot_rows,
+                head_prefix=head_prefix if keep_prefix else 0)
+        rows = masked.astype(acc_dt)
+        live = owned.astype(acc_dt)
+
     if combine == "mean":
-        route, reason = _mean_push_route(local_shard, B, apply_fn)
-        ops.log_route("push", route, rps, dim, B, reason)
+        route, reason = _mean_push_route(acc_rows, dim, local_shard.dtype, B,
+                                         apply_fn)
+        ops.log_route("push", route, acc_rows, dim, B, reason)
         if route == "mean_rows":
             # The cost follows the payload: every pushed row is scaled by
             # 1 / (pushes of its id) and the rows of one id are summed
@@ -1043,8 +1094,7 @@ def push(
             # accumulator's gap to a float64 mean (chip run, PR 28).
             with jax.named_scope(COMBINE_SCOPE):
                 n, slot, slot_idx = _id_runs(local_idx, rps)
-                scaled = masked.astype(acc_dt) * (
-                    1.0 / n.astype(acc_dt))[:, None]
+                scaled = rows * (1.0 / n.astype(acc_dt))[:, None]
                 combined = jnp.zeros((B, dim), acc_dt).at[slot].add(scaled)
             # ``slot_idx`` is the distinct ids in their sorted order, the
             # rows of ``combined`` beside them, and past the last of them
@@ -1090,26 +1140,26 @@ def push(
             # A stateful fold under "sum" (or a callable combine): the
             # (rows, dim + 1) accumulator, apply_fn over the whole shard
             # and a table-sized where (a mean push logged its own branch).
-            ops.log_route("push", "fold", rps, dim, B, "apply_fn")
-        runs = _acc_runs_route(rps, dim, B, acc_dt)
+            ops.log_route("push", "fold", acc_rows, dim, B, "apply_fn")
+        why = ("mean_dense" if combine == "mean"
+               else "fold" if apply_fn is not None else "callable")
+        if dense:
+            # The accumulator is filled by the dense exchange: this
+            # worker's own B rows into all the table's rows, then the
+            # shards' windows to their owners (``rps`` rows of it stay).
+            ops.log_route("push", "dense_acc", rps, dim, B, why)
+        runs = _acc_runs_route(acc_rows, dim, B, acc_dt)
         if runs:
             # Into an accumulator XLA keeps transposed the scatter pays
             # for every id it is handed, and most of a large batch's ids
             # are repeats: the rows of one id are summed first (a ones
             # column to its count), and the scatter sees each distinct id
             # once, sorted, and the drop sentinel after the last of them.
-            ops.log_route("push", "acc_runs", rps, dim, B,
-                          "mean_dense" if combine == "mean"
-                          else "fold" if apply_fn is not None
-                          else "callable")
+            ops.log_route("push", "acc_runs", acc_rows, dim, B, why)
             with jax.named_scope(COMBINE_SCOPE):
-                local_idx, withcnt = _sum_id_runs(
-                    local_idx, masked.astype(acc_dt), rps)
+                local_idx, withcnt = _sum_id_runs(local_idx, rows, acc_rows)
         else:
-            withcnt = jnp.concatenate(
-                [masked.astype(acc_dt), owned.astype(acc_dt)[:, None]],
-                axis=1,
-            )
+            withcnt = jnp.concatenate([rows, live[:, None]], axis=1)
         with jax.named_scope(COMBINE_SCOPE):
             # A zero the compiler cannot see through. As a broadcast of a
             # literal, XLA's TPU pipeline re-made the fill under the loop
@@ -1118,9 +1168,11 @@ def push(
             # tests/test_v5e_compile.py reads the compiled text).
             zeros = jnp.broadcast_to(
                 lax.optimization_barrier(jnp.zeros((), acc_dt)),
-                (rps, dim + 1))
+                (acc_rows, dim + 1))
         acc = ops.scatter_add(zeros, local_idx, withcnt, ids_sorted=runs)
         with jax.named_scope(COMBINE_SCOPE):
+            if dense:
+                acc = exchange(acc)
             combined, counts = acc[:, :dim], acc[:, dim]
             if combine == "mean":
                 combined = combined * (
@@ -1177,8 +1229,10 @@ class TableSpec:
     #     meshes always take the (collective-free) gathered route.
     #   * True / False — force. Forcing True on an embedding-scale table
     #     turns every step into a full-table broadcast; measure first.
-    # Only the additive fold takes the dense write path; non-additive
-    # folds keep gathered writes (reads may still go dense).
+    # The additive fold and every push that keeps the (rows, dim + 1)
+    # accumulator (a small table's mean, a callable combine, an apply_fn)
+    # take the dense write path; "max" / "min" and a mean push on the row
+    # branch keep gathered writes (reads may still go dense).
     dense_collectives: bool | str = "auto"
     # Two-tier hot storage (module docstring; docs/performance.md): an
     # int H > 0 replicates the leading H GLOBAL ids across the shard axis

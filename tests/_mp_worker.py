@@ -12,6 +12,12 @@ global mesh. Scenarios:
 * ``host_sync`` — HOST ingest (`fit_stream` over numpy chunks placed via
   ``make_array_from_process_local_data``), synchronous.
 * ``host_ssp``  — host ingest, SSP bounded staleness (sync_every=2).
+* ``indexed_mean`` / ``indexed_shard8_mean`` — the two indexed scenarios
+  under ``combine="mean"``: the item table is small, so its push fills the
+  ``(rps, dim + 1)`` accumulator by the DENSE exchange (``push.dense_acc``),
+  whose ``all_gather`` over the data axis (the former) and ``all_to_all``
+  over the shard axis (the latter) cross the process boundary; their
+  fixed-order in-program sums must give one process's bits.
 * ``indexed_shard8`` — indexed ingest on a ``(data=1, shard=8)`` mesh, so
   the SHARD axis spans the process boundary: every pull's all_gather /
   psum_scatter, every push's shard-axis all_gather, ``dump_model``'s
@@ -54,6 +60,8 @@ def main() -> int:
     from fps_tpu.parallel.mesh import make_ps_mesh
     from fps_tpu.utils.datasets import synthetic_ratings
 
+    combine = "mean" if scenario.endswith("_mean") else "sum"
+    scenario = scenario.removesuffix("_mean")
     if scenario == "indexed_shard8":
         mesh = make_ps_mesh(num_shards=8, num_data=1)
     else:
@@ -62,7 +70,8 @@ def main() -> int:
     data = synthetic_ratings(57, 31, 2000, seed=0)
     cfg = MFConfig(num_users=57, num_items=31, rank=4, learning_rate=0.1)
     sync_every = 2 if scenario == "host_ssp" else None
-    trainer, store = online_mf(mesh, cfg, sync_every=sync_every)
+    trainer, store = online_mf(mesh, cfg, sync_every=sync_every,
+                               combine=combine)
     tables, ls = trainer.init_state(jax.random.key(0))
 
     if scenario in ("indexed", "indexed_shard8"):

@@ -66,6 +66,7 @@ def _run_two_processes(tmp_path, scenario: str) -> np.ndarray:
 def _single_process_reference(devices8, scenario: str) -> np.ndarray:
     import jax
 
+    import fps_tpu.ops as ops
     from fps_tpu.core.device_ingest import DeviceDataset, DeviceEpochPlan
     from fps_tpu.core.driver import num_workers_of
     from fps_tpu.core.ingest import multi_epoch_chunks
@@ -73,6 +74,8 @@ def _single_process_reference(devices8, scenario: str) -> np.ndarray:
     from fps_tpu.parallel.mesh import make_ps_mesh
     from fps_tpu.utils.datasets import synthetic_ratings
 
+    combine = "mean" if scenario.endswith("_mean") else "sum"
+    scenario = scenario.removesuffix("_mean")
     if scenario == "indexed_shard8":
         mesh = make_ps_mesh(num_shards=8, num_data=1, devices=devices8[:8])
     else:
@@ -81,7 +84,9 @@ def _single_process_reference(devices8, scenario: str) -> np.ndarray:
     data = synthetic_ratings(57, 31, 2000, seed=0)
     cfg = MFConfig(num_users=57, num_items=31, rank=4, learning_rate=0.1)
     sync_every = 2 if scenario == "host_ssp" else None
-    trainer, store = online_mf(mesh, cfg, sync_every=sync_every)
+    trainer, store = online_mf(mesh, cfg, sync_every=sync_every,
+                               combine=combine)
+    ops.clear_routes()
     tables, ls = trainer.init_state(jax.random.key(0))
     if scenario in ("indexed", "indexed_shard8"):
         ds = DeviceDataset(mesh, data)
@@ -98,11 +103,14 @@ def _single_process_reference(devices8, scenario: str) -> np.ndarray:
         )
         tables, ls, _ = trainer.fit_stream(tables, ls, chunks,
                                            jax.random.key(1))
+    if combine == "mean":  # the route under test is the one that ran
+        assert "push.dense_acc" in [r.route for r in ops.routes_traced()]
     return store.dump_model("item_factors")[1]
 
 
 @pytest.mark.parametrize(
-    "scenario", ["indexed", "host_sync", "host_ssp", "indexed_shard8"]
+    "scenario", ["indexed", "host_sync", "host_ssp", "indexed_shard8",
+                 "indexed_mean", "indexed_shard8_mean"]
 )
 def test_two_process_training_matches_single_process(devices8, tmp_path,
                                                      scenario):
@@ -110,7 +118,11 @@ def test_two_process_training_matches_single_process(devices8, tmp_path,
     shard=8) mesh over 2 processes puts the SHARD axis across the process
     boundary, so pull/push collectives, ``dump_model`` replication, and the
     checkpoint save all move shard rows between OS processes (the worker
-    also cross-checks checkpoint-vs-dump agreement in-process)."""
+    also cross-checks checkpoint-vs-dump agreement in-process). The two
+    ``_mean`` scenarios put the DENSE exchange of the mean push's
+    accumulator (``push.dense_acc``: an ``all_gather`` over the data axis,
+    an ``all_to_all`` over the shard axis, fixed-order sums in the program)
+    across the process boundary."""
     mp_values = _run_two_processes(tmp_path, scenario)
     sp_values = _single_process_reference(devices8, scenario)
     np.testing.assert_array_equal(sp_values, mp_values)

@@ -771,7 +771,8 @@ def test_push_acc_runs_equals_the_plain_accumulator(devices8, monkeypatch,
     (17_770, 10, 32_768, False),        # mf-netflix.epochs
     (17_770, 10, 131_072, False),
     (4_443, 10, 32_768, False),
-    (4_443, 10, 131_072, False),        # mf-netflix.x4
+    (4_443, 10, 131_072, False),        # mf-netflix.x4 until PR 36
+    (17_772, 10, 32_768, False),        # since: the dense exchange's buffer
     (1_000_000, 2, 32_768, False),      # too few ids for certain repeats
     (1_000_000, 1, 425_997, False),     # accumulator widths not swept:
     (1_000_000, 4, 425_997, False),     # the scatter would not stop early
@@ -916,6 +917,23 @@ def test_pull_dense_matches_gathered(devices8, mesh_shape):
     np.testing.assert_allclose(np.asarray(run(False)), expected, rtol=1e-6)
 
 
+def _push_on(mesh, table, ids, deltas, **kw):
+    """``push`` of ``ids`` / ``deltas`` (the workers' batches end to end)
+    into ``table`` (physical layout) on ``mesh``, jitted, not yet called."""
+    D, S = mesh.devices.shape
+    fn = jax.jit(jax.shard_map(
+        lambda t, i, d: push(t, i, d, num_shards=S,
+                             data_axis=DATA_AXIS if D > 1 else None, **kw),
+        mesh=mesh,
+        in_specs=(P(SHARD_AXIS, None), P((DATA_AXIS, SHARD_AXIS)),
+                  P((DATA_AXIS, SHARD_AXIS), None)),
+        out_specs=P(SHARD_AXIS, None), check_vma=False))
+    args = (jax.device_put(jnp.asarray(table),
+                           NamedSharding(mesh, P(SHARD_AXIS, None))),
+            jnp.asarray(ids), jnp.asarray(deltas))
+    return fn, args
+
+
 @pytest.mark.parametrize("mesh_shape", [(1, 8), (2, 4), (8, 1)])
 def test_push_dense_matches_gathered(devices8, mesh_shape):
     mesh = make_ps_mesh(num_shards=mesh_shape[1], num_data=mesh_shape[0])
@@ -924,32 +942,14 @@ def test_push_dense_matches_gathered(devices8, mesh_shape):
     num_ids, dim, B = 50, 4, 12
     rps = rows_per_shard(num_ids, S)
     table = np.zeros((rps * S, dim), np.float32)
-    table_dev = jax.device_put(
-        jnp.asarray(table), NamedSharding(mesh, P(SHARD_AXIS, None))
-    )
     rng = np.random.default_rng(4)
     ids = rng.integers(0, num_ids, (W * B,)).astype(np.int32)
     ids[::5] = -1  # dropped pushes
     deltas = rng.normal(0, 1, (W * B, dim)).astype(np.float32)
 
     def run(dense):
-        return jax.jit(
-            jax.shard_map(
-                lambda t, i, d: push(
-                    t, i, d, num_shards=S,
-                    data_axis=DATA_AXIS if D > 1 else None,
-                    dense=dense,
-                ),
-                mesh=mesh,
-                in_specs=(
-                    P(SHARD_AXIS, None),
-                    P((DATA_AXIS, SHARD_AXIS)),
-                    P((DATA_AXIS, SHARD_AXIS), None),
-                ),
-                out_specs=P(SHARD_AXIS, None),
-                check_vma=False,
-            )
-        )(table_dev, jnp.asarray(ids), jnp.asarray(deltas))
+        fn, args = _push_on(mesh, table, ids, deltas, dense=dense)
+        return fn(*args)
 
     expected = np.zeros((rps * S, dim), np.float32)
     keep = ids >= 0
@@ -959,6 +959,124 @@ def test_push_dense_matches_gathered(devices8, mesh_shape):
                                rtol=1e-5, atol=1e-5)
     np.testing.assert_allclose(np.asarray(run(False)), expected,
                                rtol=1e-5, atol=1e-5)
+
+
+def _pushes_logged():
+    return [(r.route, r.reason) for r in ops.routes_traced()
+            if r.op == "push"]
+
+
+_ACC_FOLDS = {
+    # name -> (push kwargs, reason logged, float64 oracle of a touched row
+    # from its current value, the sum of its pushes and their number)
+    "mean": (dict(combine="mean"), "mean_dense",
+             lambda cur, s, n: cur + s / n),
+    "callable": (dict(combine=lambda s, c: s / jnp.sqrt(
+        jnp.maximum(c, 1.0))[:, None]), "callable",
+                 lambda cur, s, n: cur + s / np.sqrt(n)),
+    "apply_fn": (dict(apply_fn=lambda cur, d: 0.5 * cur + d), "fold",
+                 lambda cur, s, n: 0.5 * cur + s),
+}
+
+
+@pytest.mark.parametrize("fold", list(_ACC_FOLDS))
+@pytest.mark.parametrize("mesh_shape", [(1, 8), (2, 4), (8, 1)])
+def test_push_dense_accumulator_matches_gathered(devices8, mesh_shape, fold):
+    """The ``(rps, dim + 1)`` accumulator filled by the dense exchange
+    (each worker scatters its OWN rows, the shards' windows exchanged and
+    summed in a fixed order) holds the gathered exchange's sums and
+    counts: under the per-id mean, a callable combine and a stateful fold
+    the table equals the gathered route's and a float64 oracle's, rows
+    nobody pushed (dropped ids, ids no worker of the shard holds) keep
+    their bits, and the route log says which exchange ran, once."""
+    kw, reason, oracle = _ACC_FOLDS[fold]
+    D, S = mesh_shape
+    mesh = make_ps_mesh(num_shards=S, num_data=D)
+    W = D * S
+    num_ids, dim, B = 50, 4, 12
+    rps = rows_per_shard(num_ids, S)
+    rng = np.random.default_rng(8)
+    table = rng.normal(0, 1, (rps * S, dim)).astype(np.float32)
+    # ids 30.. are never pushed: whole rows of every shard stay untouched
+    ids = rng.integers(0, 30, (W * B,)).astype(np.int32)
+    ids[::5] = -1  # dropped pushes
+    deltas = rng.normal(0, 1, (W * B, dim)).astype(np.float32)
+
+    ops.clear_routes()
+    fn, args = _push_on(mesh, table, ids, deltas, dense=True, **kw)
+    dense = np.asarray(fn(*args))
+    logged = _pushes_logged()
+    assert logged.count(("push.dense_acc", reason)) == 1, logged
+    assert [r for r in ops.routes_traced()
+            if r.route == "scatter_add.xla"][-1][2:5] == (
+                rps * S, dim + 1, B)  # the worker's own ids, all the rows
+    ops.clear_routes()
+    fn, args = _push_on(mesh, table, ids, deltas, dense=False, **kw)
+    gathered = np.asarray(fn(*args))
+    assert "push.dense_acc" not in [r for r, _ in _pushes_logged()]
+
+    want = table.astype(np.float64)
+    keep = ids >= 0
+    phys = np.asarray(id_to_phys(ids[keep], S, rps))
+    touched = np.unique(phys)
+    for row in touched:
+        mine = deltas[keep][phys == row].astype(np.float64)
+        want[row] = oracle(want[row], mine.sum(axis=0), len(mine))
+    scale = np.abs(want).max()
+    assert np.abs(dense - gathered).max() <= 1e-6 * scale
+    assert np.abs(dense - want).max() <= 1e-6 * scale
+    untouched = np.setdiff1d(np.arange(rps * S), touched)
+    assert len(untouched) >= 20
+    np.testing.assert_array_equal(dense[untouched], table[untouched])
+
+
+@pytest.mark.parametrize("combine", ["max", "min", "mean"])
+def test_push_dense_keeps_gathered_for_extrema_and_mean_rows(devices8,
+                                                             combine):
+    """What the dense exchange does not serve keeps the gathered one under
+    ``dense=True``, program for program: the extrema (no sum to exchange)
+    and a mean push whose table is large against its payload
+    (``push.mean_rows``: the pushed rows go straight into the shard)."""
+    mesh = make_ps_mesh(num_shards=4, num_data=2)
+    num_ids, dim, B = 16_000, 4, 2
+    rps = rows_per_shard(num_ids, 4)
+    rng = np.random.default_rng(9)
+    table = rng.normal(0, 1, (rps * 4, dim)).astype(np.float32)
+    ids = rng.integers(0, 40, (8 * B,)).astype(np.int32)
+    ids[3] = -1
+    deltas = rng.normal(0, 1, (8 * B, dim)).astype(np.float32)
+
+    out, logs, texts = {}, {}, {}
+    for dense in (True, False):
+        ops.clear_routes()
+        fn, args = _push_on(mesh, table, ids, deltas, dense=dense,
+                            combine=combine)
+        texts[dense] = fn.lower(*args).as_text()
+        logs[dense] = _pushes_logged()
+        out[dense] = np.asarray(fn(*args))
+    assert logs[True] == logs[False] == (
+        [("push.mean_rows", "")] if combine == "mean" else [])
+    assert texts[True] == texts[False]
+    np.testing.assert_array_equal(out[True], out[False])
+
+
+@pytest.mark.parametrize("fold", ["sum", *_ACC_FOLDS])
+def test_push_on_one_device_lowers_one_program_dense_or_not(devices8, fold):
+    """On one device there is nobody to exchange with: ``dense=True``
+    lowers the program ``dense=False`` lowers, and logs no exchange."""
+    mesh = make_ps_mesh(num_shards=1, num_data=1, devices=devices8[:1])
+    kw = _ACC_FOLDS[fold][0] if fold != "sum" else {}
+    rng = np.random.default_rng(10)
+    table = rng.normal(0, 1, (50, 4)).astype(np.float32)
+    ids = rng.integers(-1, 50, (12,)).astype(np.int32)
+    deltas = rng.normal(0, 1, (12, 4)).astype(np.float32)
+    texts = {}
+    for dense in (True, False):
+        ops.clear_routes()
+        fn, args = _push_on(mesh, table, ids, deltas, dense=dense, **kw)
+        texts[dense] = fn.lower(*args).as_text()
+        assert "push.dense_acc" not in [r for r, _ in _pushes_logged()]
+    assert texts[True] == texts[False]
 
 
 def test_dense_route_trains_pa_equivalently(devices8):

@@ -185,13 +185,10 @@ def test_packed_route_keeps_the_loop_carry_compact(one_chip):
     assert c.memory_analysis().temp_size_in_bytes < 8 << 20
 
 
-def test_x4_push_all_gathers_its_deltas_compact(topo):
+@pytest.fixture(scope="module")
+def x4_step(topo):
     """``mf-netflix.x4``'s step program (the trainer's own chunk builder
-    on the described 1x4 mesh): the push's all-gather of a step's
-    ``[131072, 10]`` deltas stays in the compact transposed layout in
-    VMEM. A select on the worker's LOCAL deltas (its scatter-add's
-    neighbour in one fusion) once turned it row-major in HBM, 128 lanes a
-    row, and cost the cell a third of its rate; no CPU test can see that."""
+    on the described 1x4 mesh) compiled, and its route log."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from fps_tpu.models.matrix_factorization import MFConfig, online_mf
@@ -216,11 +213,63 @@ def test_x4_push_all_gathers_its_deltas_compact(topo):
         ("user", jnp.int32), ("item", jnp.int32), ("rating", jnp.float32),
         ("weight", jnp.float32))}
     key = shape((), jax.random.key(0).dtype, P())
+    ops.clear_routes()
     text = trainer._build_chunk_fn("sync").lower(
         tables, local, batches, key).compile().as_text()
-    gathered = re.findall(rf"= (f32\[{W * B},{rank}\]\S+) all-gather\(", text)
-    compact = f"f32[{W * B},{rank}]{{0,1:T(8,128)S(1)}}"
-    assert gathered and all(g.startswith(compact) for g in gathered), gathered
+    return text, ops.routes_traced()
+
+
+def test_x4_push_exchanges_its_accumulator_compact(x4_step):
+    """The layouts ``mf-netflix.x4``'s rate stands on, which no CPU test
+    can see. Since PR 36 the push's ``(rows, 11)`` accumulator is filled by
+    the dense exchange: the ``all_to_all``'s operand, four windows of
+    ``[4443, 11]``, is the compact transposed form in VMEM (1.15 MB;
+    row-major tiles would pad 11 lanes to 128: 9.1 MB on the wire), and no
+    collective carries a step's 131,072 pushes any more. Its siblings keep
+    theirs: the worker's scatter into its ``[120048, 10]`` block and the
+    push's into the ``[17772, 11]`` buffer row-major in VMEM, the former
+    handed sorted ids; the pull's all-gather of the table compact. (Until
+    PR 36 this pinned the gathered push's all-gather of ``[131072, 10]``
+    deltas: a select on the worker's LOCAL deltas, its scatter-add's
+    neighbour in one fusion, once turned that row-major in HBM and cost
+    the cell a third of its rate, PR 25.)"""
+    text = x4_step[0]
+    W, B, rank = 4, NETFLIX[2], NETFLIX[1]
+    rps = -(-17_770 // W)
+    vmem = "T(8,128)S(1)}"
+    exchanged = re.findall(r"= (f32\[\S+) all-to-all\(", text)
+    assert exchanged == [f"f32[{W},{rps},{rank + 1}]{{1,2,0:{vmem}"], exchanged
+    gathered = re.findall(r"= (f32\[\S+) all-gather\(", text)
+    assert gathered == [f"f32[{W},{rps},{rank}]{{1,2,0:{vmem}"], gathered
+    assert f"[{W * B}," not in text  # nobody holds every worker's pushes
+    scatters = {m.group(1): m.group(2) for m in re.finditer(
+        r"= (f32\[\S+) scatter\((.*)", text)}
+    block = f"f32[{-(-NETFLIX[0] // W)},{rank}]{{1,0:{vmem}"
+    buffer = f"f32[{W * rps},{rank + 1}]{{1,0:{vmem}"
+    assert sorted(scatters) == sorted([block, buffer]), list(scatters)
+    assert "indices_are_sorted=true" in scatters[block]
+
+
+def test_x4_step_fills_the_accumulator_by_the_dense_exchange(x4_step):
+    """``mf-netflix.x4``'s route log, the four-shard twin of the pin below:
+    the movie table (782 KB: dense by ``ops.DENSE_TABLE_BYTES``) keeps the
+    accumulator (``push.mean_dense`` / ``small_table``, asked about the
+    32,768 ids a worker scatters into all 17,772 rows), fills it by the
+    dense exchange (``push.dense_acc`` / ``mean_dense``), and the
+    scatter-add under them is handed the worker's own 32,768 ids, not the
+    131,072 of the step."""
+    W, B, rank = 4, NETFLIX[2], NETFLIX[1]
+    rows = -(-17_770 // W) * W
+    routes = [r for r in x4_step[1] if r.op in ("push", "scatter_add")]
+    assert routes[1:] == [
+        ops.Route("push", "push.mean_dense", rows, rank, B, False,
+                  "small_table"),
+        ops.Route("push", "push.dense_acc", rows // W, rank, B, False,
+                  "mean_dense"),
+        ops.Route("scatter_add", "scatter_add.xla", rows, rank + 1, B,
+                  False, routes[-1].reason),
+    ], routes
+    assert routes[0].route == "scatter_add.xla"  # the worker's own block
 
 
 def test_w2v_epoch_program_fits_and_names_its_table_sized_work(

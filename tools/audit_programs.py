@@ -215,19 +215,25 @@ BUDGETS: dict[str, dict] = {
     # Sparse logreg, gathered route + adagrad server fold.
     "logreg": dict(max_collectives=2, max_collective_bytes=3200,
                    per_kind_max={"all_gather": 1, "all_to_all": 1}),
-    # Word2vec: in/out vectors for center+context+negatives across two
-    # tables lower as six gathered pulls (pushes fold into the same
-    # gather/scatter route — no all_to_all at this scale).
-    "w2v": dict(max_collectives=6, max_collective_bytes=40448,
-                per_kind_max={"all_gather": 6}),
+    # Word2vec, both tables under the per-id mean and dense at this
+    # scale: a dense pull (the table's all_gather) and, since PR 36, a
+    # dense push (the (rows, dim + 1) accumulator's all_to_all,
+    # ``push.dense_acc``) a table. Until then the mean pushes kept the
+    # gathered exchange: an all_gather of ids and one of deltas a table
+    # more (6 all_gathers, 40,448 B).
+    "w2v": dict(max_collectives=4, max_collective_bytes=7616,
+                per_kind_max={"all_gather": 2, "all_to_all": 2}),
     # Passive-aggressive shares logreg's route structure.
     "pa": dict(max_collectives=2, max_collective_bytes=3200,
                per_kind_max={"all_gather": 1, "all_to_all": 1}),
-    # iALS accumulate: the fixed factor table and per-step row gathers
-    # (5 all_gathers) feed the normal-equation fold; accumulators stay
-    # sharded through one reduce_scatter.
-    "ials": dict(max_collectives=6, max_collective_bytes=84992,
-                 per_kind_max={"all_gather": 5, "reduce_scatter": 1}),
+    # iALS accumulate: the pulls of the fixed side's rows and, since
+    # PR 35, of the solved side's (the sweep's own loss), each an
+    # all_gather of ids and a reduce_scatter of rows, and the two
+    # gathered pushes' all_gathers of ids and rows feed the
+    # normal-equation fold (re-pinned in PR 36 with the builder repaired:
+    # 6 / 84,992 B until PR 35).
+    "ials": dict(max_collectives=8, max_collective_bytes=94208,
+                 per_kind_max={"all_gather": 6, "reduce_scatter": 2}),
 }
 
 
@@ -483,10 +489,12 @@ def build_ials(mesh) -> str:
     rps = rows_per_shard(cfg.num_users, solver.num_shards)
     A = solver._zeros_acc(rps * solver.num_shards, RANK * RANK)
     b = solver._zeros_acc(rps * solver.num_shards, RANK)
-    acc = solver._accumulate_fn()
-    from fps_tpu.models.ials import ITEM_TABLE
+    acc = solver._accumulate_fn("user")
+    from fps_tpu.models.ials import ITEM_TABLE, USER_TABLE
 
-    return acc.lower(solver.store.tables[ITEM_TABLE], A, b, dev).as_text()
+    tables = solver.store.tables
+    return acc.lower(tables[ITEM_TABLE], tables[USER_TABLE], A, b,
+                     dev).as_text()
 
 
 BUILDERS = {

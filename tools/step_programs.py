@@ -5,11 +5,13 @@ instruction, in two trees? Compile-only: no chip is touched.
     python tools/step_programs.py diff <out_dir_a> <out_dir_b>
 
 ``write`` imports ``fps_tpu`` FROM ``tree`` (a checkout: this one, or a
-``git archive`` of the parent), builds the step program of each cell the
-benchmark had before ``lr-criteo`` at the cell's own shapes
-(``mf-netflix.epochs``, ``pa-rcv1.epochs``, ``mf-netflix.x4``,
-``w2v-1bw.epochs``), compiles it for a described ``v5e:2x2`` with the ops
-layer routing as on the chip, and writes the compiled text with metadata,
+``git archive`` of the parent), builds the step program of each cell of
+the benchmark at the cell's own shapes (``mf-netflix.epochs``,
+``pa-rcv1.epochs``, ``mf-netflix.x4``, ``w2v-1bw.epochs``,
+``lr-criteo.epochs`` and, since PR 36, both accumulate programs of
+``ials-ml20m.sweeps``, the ones that push), compiles it for a described
+``v5e:2x2`` with the ops layer routing as on the chip, and writes the
+compiled text with metadata,
 stack frames and location tables dropped, and the route log, under
 ``out_dir``. One process per tree (a process imports one ``fps_tpu``).
 ``diff`` counts the instructions of each program and the lines that
@@ -29,7 +31,8 @@ import re
 import sys
 
 CELLS = ("mf-netflix.epochs", "pa-rcv1.epochs", "mf-netflix.x4",
-         "w2v-1bw.epochs")
+         "w2v-1bw.epochs", "lr-criteo.epochs", "ials-ml20m.sweeps.user",
+         "ials-ml20m.sweeps.item")
 
 
 def _normalised(text: str) -> str:
@@ -160,10 +163,74 @@ def write(tree: str, out: str) -> None:
              lambda: trainer._build_indexed_fn(plan, "sync").lower(
                  tables, (), iargs, jnp.int32(0), key))
 
+    def lr():
+        import numpy as np
+
+        from fps_tpu import DeviceEpochPlan
+        from fps_tpu.models.logistic_regression import (
+            LogRegConfig, logistic_regression,
+        )
+
+        m = model("lr-criteo")
+        F, B, s = m["num_features"], m["local_batch"], m["sync_every"]
+        N = model("lr-criteo", "data")["examples_resident"]
+        slots, numeric = 39, m["dense_features"]
+        mesh, shape = mesh_of(1)
+        trainer, _ = logistic_regression(
+            mesh, LogRegConfig(num_features=F,
+                               learning_rate=m["learning_rate"],
+                               optimizer=m["optimizer"],
+                               dense_features=numeric), sync_every=s)
+        # The plan's geometry without its uploads.
+        plan = object.__new__(DeviceEpochPlan)
+        plan.local_batch, plan.shuffle, plan.num_workers = B, "interleave", 1
+        plan.sync_every, plan.maxq, plan.grid_r = s, N, 4096
+        plan.counts = np.full(1, N, np.int32)
+        plan.grid_c = np.full(1, N // 4096, np.int32)
+        plan.grid_m = np.full(1, N, np.int32)
+        plan.steps_per_epoch = -(-N // B // s) * s + s
+        key = shape((), jax.random.key(0).dtype)
+        tables = {"weights": shape((F, 2), jnp.float32, P("shard", None))}
+        iargs = {"columns": {"feat_ids": shape((N, slots), jnp.int32),
+                             "feat_vals": shape((N, slots), jnp.float32),
+                             "label": shape((N,), jnp.float32)},
+                 "queues": shape((1, N), jnp.int32),
+                 "off_w": shape((1,), jnp.int32),
+                 "perm": shape((1, 1), jnp.int32)}
+        emit("lr-criteo.epochs",
+             lambda: trainer._build_indexed_fn(plan, "ssp").lower(
+                 tables, (), iargs, jnp.int32(0), key))
+
+    def ials():
+        from fps_tpu.models.ials import IALSConfig, IALSSolver
+
+        m = model("ials-ml20m")
+        NU, NI, K, B = (m["num_users"], m["num_items"], m["rank"],
+                        m["local_batch"])
+        mesh, shape = mesh_of(1)
+        solver = IALSSolver(mesh, IALSConfig(
+            num_users=NU, num_items=NI, rank=K, alpha=m["alpha"],
+            reg=m["reg"]))
+
+        def table(rows, dim):
+            return shape((rows, dim), jnp.float32, P("shard", None))
+
+        chunk = {k: shape((m["steps_per_chunk"], B),
+                          jnp.int32 if k.endswith("ids") else jnp.float32,
+                          workers)
+                 for k in ("solve_ids", "fixed_ids", "rating", "weight")}
+        for side, n, other in (("user", NU, NI), ("item", NI, NU)):
+            emit("ials-ml20m.sweeps." + side,
+                 lambda: solver._accumulate_fn(side).lower(
+                     table(other, K), table(n, K), table(n, K * K),
+                     table(n, K), chunk))
+
     mf(1)
     pa()
     mf(4)
     w2v()
+    lr()
+    ials()
 
 
 def diff(a: str, b: str) -> int:
