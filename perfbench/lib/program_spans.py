@@ -5,8 +5,9 @@ set-up spans, the driver's phases, JAX's compile timings) and logs every
 ops route it takes (``fps_tpu.ops.routes_traced``). With a process-default
 ``Recorder`` installed those land in its sink; this module turns the sink
 into the ``program_spans`` of a run's context — name -> the spans' ``(t0,
-t1)`` in epoch seconds, set-up, window and what came after it apart — and
-holds the readers over it. A program without the spans (a parent commit)
+t1)`` in epoch seconds, set-up, window and what came after it apart, and
+beside it (``program_span_events``) every span event whole — and holds
+the readers over it. A program without the spans (a parent commit)
 leaves the sink empty: every reader then returns ``None`` and the metric is
 left out of the line; nothing here raises for want of something to read.
 
@@ -45,6 +46,11 @@ def epoch_of(perf_counter_s: float) -> float:
     return time.time() - (time.perf_counter() - perf_counter_s)
 
 
+def _part(t0: float, t1: float, opened_at: float, closed_at: float) -> str:
+    return ("setup" if t1 <= opened_at
+            else "after" if t0 >= closed_at else "window")
+
+
 def collect(sink, opened_at: float, closed_at: float) -> dict:
     """``{name: {"setup": [(t0, t1), ...], "window": [...], "after":
     [...]}}`` from a recorder's sink: every ``span`` event, and JAX's
@@ -57,10 +63,9 @@ def collect(sink, opened_at: float, closed_at: float) -> dict:
     out: dict = {}
 
     def put(name, t0, t1):
-        part = ("setup" if t1 <= opened_at
-                else "after" if t0 >= closed_at else "window")
         out.setdefault(name, {"setup": [], "window": [], "after": []})[
-            part].append((float(t0), float(t1)))
+            _part(t0, t1, opened_at, closed_at)].append(
+                (float(t0), float(t1)))
 
     if sink is None:
         return out
@@ -73,6 +78,21 @@ def collect(sink, opened_at: float, closed_at: float) -> dict:
     for spans in out.values():
         for part in spans.values():
             part.sort()
+    return out
+
+
+def collect_events(sink, opened_at: float, closed_at: float) -> dict:
+    """The same parts as :func:`collect`, holding each ``span`` event WHOLE
+    (its ``t0`` and ``t1`` and whatever else the program set on it: a
+    call's index, a chunk's ``steps``, bytes, a queue's depth), in the
+    order of their ``t0``: the ``program_span_events`` of a run's context,
+    for a reader that wants more of a span than its length."""
+    out: dict = {}
+    if sink is None:
+        return out
+    for e in sorted(sink.events("span"), key=lambda e: (e["t0"], e["t1"])):
+        out.setdefault(e["span"], {"setup": [], "window": [], "after": []})[
+            _part(e["t0"], e["t1"], opened_at, closed_at)].append(dict(e))
     return out
 
 

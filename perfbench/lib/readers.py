@@ -6,13 +6,16 @@ span or the counter it reads). A reader gets the run's context and returns
 a number, or ``None`` when it finds nothing to read — the harness then
 leaves the metric out of the line. Adding a metric that reads a kind of
 source listed in :data:`READERS` is a file and an entry; a new kind of
-source needs a function here (README.md).
+source is a file too: ``perfbench/readers/<name>.py`` holding
+``read(ctx, params)``, found by the name a metric's file gives
+(:func:`reader`, ``resolve.py``), never an edit here (README.md).
 
 Context (``ctx``): ``ops`` (the reduced trace, or None), ``spans`` (name ->
 list of host-clock seconds, taken by the runner), ``counters`` (name ->
-number, counted by the runner), ``program_spans`` and ``routes`` (the
-program's own host spans and route log: ``program_spans.py``, which holds
-their readers), ``config`` (the configuration file), ``workers`` and
+number, counted by the runner), ``program_spans``, ``program_span_events``
+and ``routes`` (the program's own host spans, each span event's other
+fields, and its route log: ``program_spans.py``, which holds their
+readers), ``config`` (the configuration file), ``workers`` and
 ``peaks`` (this device's row of peaks.json).
 """
 
@@ -21,7 +24,7 @@ from __future__ import annotations
 import re
 import statistics
 
-from perfbench.lib import program_spans
+from perfbench.lib import program_spans, resolve
 from perfbench.lib import trace_reduce as tr
 
 
@@ -60,17 +63,25 @@ def op_time_per_step(ctx, p):
 
 
 def _rowop(ctx, p):
-    """(measured seconds per step, rows per worker step, row bytes)."""
+    """``(measured seconds per step, rows per worker step, least bytes per
+    worker step)``. Measured is ALL the leaf-op time under ``p["scopes"]``
+    (the store's pull and push and every routed op, wherever it lies), each
+    leaf once, whatever primitive did the work: a sort, a scan or a matrix
+    product that forms a push's sums counts as the scatter it replaced.
+    The bytes are the configuration's (``rowops``): the least ANY
+    implementation of the step must move, ``bytes_per_worker_step`` where
+    one ``row_bytes`` cannot express the mix."""
     steps = _steps(ctx)
     spec = ctx["config"].get("rowops")
     if not steps or not spec:
         return None
-    pat = re.compile(p["tf_op_regex"])
-    t = tr.time_where(ctx["ops"], lambda o: bool(pat.search(o.tf_op)))
+    t = tr.time_where(ctx["ops"], lambda o: tr.in_scope(o, p["scopes"]))
     if t <= 0:
         return None
-    return t / steps, float(spec["rows_per_worker_step"]), float(
-        spec["row_bytes"])
+    rows = float(spec["rows_per_worker_step"])
+    least_bytes = float(spec.get("bytes_per_worker_step",
+                                 rows * spec["row_bytes"]))
+    return t / steps, rows, least_bytes
 
 
 def rowop_ns_per_row(ctx, p):
@@ -82,8 +93,7 @@ def rowop_roofline_percent(ctx, p):
     r = _rowop(ctx, p)
     if r is None:
         return None
-    least = tr.rowop_least_seconds(r[1], r[2],
-                                   ctx["peaks"]["hbm_bytes_per_s"])
+    least = tr.rowop_least_seconds(r[2], ctx["peaks"]["hbm_bytes_per_s"])
     return 100.0 * least / r[0]
 
 
@@ -106,11 +116,18 @@ READERS = {
 }
 
 
+def reader(name: str):
+    """The reader called ``name``: one of :data:`READERS`, else the
+    ``read`` of ``perfbench/readers/<name>.py`` (:class:`resolve.SpecError`
+    naming the file to add where there is neither)."""
+    return READERS.get(name) or resolve.load("reader", name).read
+
+
 def read_all(readers: dict, ctx: dict) -> dict:
     """name -> value for every metric whose reader found something."""
     out = {}
     for name, spec in readers.items():
-        value = READERS[spec["reader"]](ctx, spec.get("params", {}))
+        value = reader(spec["reader"])(ctx, spec.get("params", {}))
         if value is not None:
             out[name] = {"value": float(value), "unit": spec["unit"]}
     return out

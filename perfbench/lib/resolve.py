@@ -7,13 +7,15 @@ found by that name — one mechanism for all of them:
 | ``data`` | ``config.data.kind`` | ``datasets/<kind>.py`` | ``generate`` |
 | ``reference`` | ``config.reference`` | ``lib/reference/<name>.py`` | ``init_tables``, ``make_step`` |
 | ``entry`` | ``config.model.kind`` and ``traffic.entry`` | ``entries/<kind>/<entry>.py`` | ``System`` |
+| ``reader`` | a metric file's ``reader``, where ``lib/readers.py`` has none of that name | ``readers/<name>.py`` | ``read`` |
 
 A model kind's ``System`` drives the entry it names (``System.entry``);
 a traffic file without an ``entry`` key means that one. Another entry
 over the same model kind is a ``System`` in a file of its own under
 ``entries/<kind>/``. A name with no file raises :class:`SpecError` naming
-the file to add, so a new kind of model, of data or of entry is new files
-and an entry in ``BENCHMARK.json``, never an edit here.
+the file to add, so a new kind of model, of data, of entry or of reader
+(``read(ctx, params)``: ``lib/readers.py``) is new files and an entry in
+``BENCHMARK.json``, never an edit here.
 """
 
 from __future__ import annotations
@@ -29,6 +31,7 @@ THINGS = {
     "data": (("datasets",), ("generate",)),
     "reference": (("lib", "reference"), ("init_tables", "make_step")),
     "entry": (("entries",), ("System",)),
+    "reader": (("readers",), ("read",)),
 }
 
 

@@ -253,12 +253,15 @@ def run_cell(loaded: dict, *, seed: int, seconds: float, trace: bool,
     # the program's own (fps.host.*) where one does, else the runner's.
     ops = tr.load_trace(paths[-1], host_prefix=("bench.", "fps.host."))
     busy_s, window_s, _ = tr.busy_and_window(ops)
+    opened_at = program_spans.epoch_of(t_open)
+    closed_at = program_spans.epoch_of(done[-1].done_at)
     ctx = {
         "ops": ops,
         "spans": {"bench.dispatch": [c.dispatch_s for c in done]},
-        "program_spans": program_spans.collect(
-            spans_sink, program_spans.epoch_of(t_open),
-            program_spans.epoch_of(done[-1].done_at)),
+        "program_spans": program_spans.collect(spans_sink, opened_at,
+                                               closed_at),
+        "program_span_events": program_spans.collect_events(
+            spans_sink, opened_at, closed_at),
         "routes": routes,
         "counters": {
             "median_call_examples_per_s": statistics.median(rates),
@@ -278,6 +281,10 @@ def run_cell(loaded: dict, *, seed: int, seconds: float, trace: bool,
         part: program_spans.totals(ctx["program_spans"], part)
         for part in ("setup", "window")})
     result["metrics"] = readers.read_all(loaded["readers"], ctx)
+    # What the cell lists and no reader found: a route or a scope the
+    # program no longer has shows here, in the run that lost it.
+    emit("silent", metrics=sorted(set(loaded["readers"])
+                                  - set(result["metrics"])))
     result["device"] = dict(device, busy_s=busy_s, window_s=window_s)
     result["breakdown"] = tr.breakdown(ops)
     result["compared"] = compared

@@ -11,9 +11,9 @@ The runner finds everything by name:
 :func:`validate` is the check the runner makes as it starts: names and
 units hold only the characters the contract allows, every per-layer
 metric moves an end-to-end metric that each of its cells reports and
-names a reader there is, every configuration has a cell, every file named
-exists, and every configuration's kinds and reference and every cell's
-entry resolve.
+names a reader there is (in ``lib/readers.py`` or a file of its own),
+every configuration has a cell, every file named exists, and every
+configuration's kinds and reference and every cell's entry resolve.
 """
 
 from __future__ import annotations
@@ -161,9 +161,11 @@ def validate(bench: dict, root: str = ROOT) -> None:
         name_ok(reader.get("name"), f"{path} name")
         if reader["name"] != m["name"] or reader.get("unit") != m["unit"]:
             raise SpecError(f"{path}: name/unit differ from BENCHMARK.json")
-        if reader.get("reader") not in readers.READERS:
-            raise SpecError(f"{path}: no reader {reader.get('reader')!r} in "
-                            f"lib/readers.py; it has {sorted(readers.READERS)}")
+        try:
+            readers.reader(str(reader.get("reader")))
+        except SpecError as e:
+            raise SpecError(f"{path}: {e}; lib/readers.py has "
+                            f"{sorted(readers.READERS)}") from None
     for w in cells:
         if not any(w in e2e[m] for m in e2e if m != "setup_s"):
             raise SpecError(f"{w} reports no end-to-end metric but setup_s")

@@ -197,12 +197,18 @@ def breakdown(ops, top: int = 10) -> dict:
 
 # -- the arithmetic of the row-operation roofline -------------------------
 
-def rowop_least_seconds(rows: float, row_bytes: float,
+def rowop_least_seconds(least_bytes: float,
                         hbm_bytes_per_s: float) -> float:
-    """The least time ``rows`` gathers or scatters of ``row_bytes`` each
-    can take on a chip bound by HBM bandwidth: every row is read once and
-    written once (a gather reads the table row and writes the batch row, a
-    scatter-add reads the delta and writes the table row; the
-    read-modify-write of the destination is NOT counted, nor are indices,
-    so the count is the algorithm's least and a share cannot pass 100%)."""
-    return rows * row_bytes * 2.0 / hbm_bytes_per_s
+    """The least time a step's row operations can take on a chip bound by
+    HBM bandwidth. ``least_bytes`` is what the ALGORITHM must move, rows x
+    row bytes (a configuration's ``rowops``), and each such byte crosses
+    HBM twice: a gather reads the table row and writes the batch row, a
+    scatter-add reads the delta and writes the table row, a per-id sum is
+    written once and read once by what consumes it. The read-modify-write
+    of a destination is NOT counted, nor are indices, repeats of an id
+    within a step where the configuration says so, or anything a
+    particular program moves besides (a row per addend, a relayout, a
+    sort's passes): so the count is the algorithm's least and a share
+    cannot pass 100% while the measured time holds every op that moves
+    those bytes."""
+    return least_bytes * 2.0 / hbm_bytes_per_s

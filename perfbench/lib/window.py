@@ -112,8 +112,11 @@ def run_traced(system, state, seconds: float, call_s: float,
     ``call_s`` is a call's length as the window read it: calls are queued
     to cover the traced span and a quarter of a call more, so the device
     does not run dry before the profiler stops. The profiler is stopped
-    from the wait loop's poll, in the middle of a call; stopping can block
-    the host for seconds, which is why none of this runs in the window."""
+    from the wait loop's poll, in the middle of a call, or before a wait
+    where the call has already ended (a call that returns finished never
+    enters the wait's loop: without that poll nothing would stop the
+    profiler and calls would be queued for ever); stopping can block the
+    host for seconds, which is why none of this runs in the window."""
     import jax
 
     jax.profiler.start_trace(trace_dir)
@@ -132,6 +135,7 @@ def run_traced(system, state, seconds: float, call_s: float,
             state, nxt = queue_call(system, state)
             queued.append(nxt)
             covered += call_s
+        poll()
         queued.pop(0).wait(poll)
     for c in queued:
         c.wait()

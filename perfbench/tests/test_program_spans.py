@@ -144,13 +144,52 @@ def test_plan_self_time_leaves_out_the_dataset_spans_inside_it(traced):
         own + first[1] - first[0])
 
 
+def test_a_traced_run_names_the_metrics_it_lists_and_could_not_read(traced):
+    """One more event line, ``silent``: every metric the cell lists whose
+    reader found nothing, so a route or scope the program lost shows in
+    the run that lost it. The recorded traces are PR 24's tree's: no
+    lane-packed route (PR 25) and no ``fps.combine`` (PR 27) in them."""
+    workload, loaded, result, events, _ = traced
+    (line,) = [e for e in events if e["event"] == "silent"]
+    assert line["metrics"] == sorted(
+        set(loaded["readers"]) - set(result["metrics"]))
+    want = {"mf-netflix.epochs": ["kernel.xla_packed_ms_per_step",
+                                  "store.combine_dense_ms_per_step"],
+            "pa-rcv1.epochs": []}[workload]
+    assert [m for m in line["metrics"] if m in want] == want
+    # what read is not named, and the result object carries no new key
+    assert "kernel.rowop_roofline" in result["metrics"]
+    assert "kernel.rowop_roofline" not in line["metrics"]
+    assert set(result) == {"correct", "attempted", "failed", "metrics",
+                           "device", "breakdown", "compared"}
+
+
+def test_span_events_ride_whole_beside_their_intervals(traced):
+    """``program_span_events``: the same names and parts as
+    ``program_spans``, each span event with every field the program set
+    (here ``call``, the driver call's index), for a reader that wants more
+    of a span than its length."""
+    _, _, result, _, ctx = traced
+    spans, whole = ctx["program_spans"], ctx["program_span_events"]
+    assert set(whole) == {n for n in spans if not n.startswith("compile.")}
+    for name, parts in whole.items():
+        for part, evs in parts.items():
+            assert [(e["t0"], e["t1"]) for e in evs] == spans[name][part]
+            assert all(e["span"] == name and e["event"] == "span"
+                       for e in evs)
+    calls = [e["call"] for part in ("setup", "window")
+             for e in whole["run_indexed"][part]]
+    assert len(calls) == result["attempted"] == len(set(calls))
+    assert program_spans.collect_events(None, 0.0, 1.0) == {}
+
+
 def test_an_untraced_run_installs_no_recorder_and_reads_no_route_log(
         tmp_path):
     _, result, events, ctx = traced_run("mf-netflix.epochs", tmp_path,
                                         trace=False)
     assert ctx == {"installed": None}  # the readers were never called
     assert "setup_s" in result["metrics"]
-    assert not [e for e in events if e["event"] == "program"]
+    assert not [e for e in events if e["event"] in ("program", "silent")]
 
 
 def test_a_program_without_the_spans_reads_nothing_and_raises_nothing():

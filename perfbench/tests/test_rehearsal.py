@@ -265,6 +265,43 @@ def test_traced_calls_come_after_the_window_and_cover_the_span(monkeypatch):
     assert 3 <= kinds.count("queue") <= 5
 
 
+@pytest.mark.parametrize("call_s", [0.03, 0.0])
+def test_traced_calls_that_return_finished_still_stop_the_profiler(
+        monkeypatch, call_s):
+    """An entry whose call returns only when its work is done (a streamed
+    entry; a span that waits for the device under a recorder, PR 35): the
+    wait's loop, and with it its poll, is never entered. ``run_traced``
+    polls before each wait too, so the profiler stops exactly once and the
+    function returns; without that it queued calls for ever."""
+    log = []
+
+    class Ready:
+        def is_ready(self):
+            return True
+
+    class Fake:
+        calls = 0
+
+        def call(self, tables, local_state):
+            self.calls += 1
+            if self.calls > 10_000:
+                raise AssertionError("run_traced never stopped queueing")
+            time.sleep(call_s)  # blocks: finished when it returns
+            return tables, local_state, [{"n": Ready()}]
+
+    monkeypatch.setattr(jax.profiler, "start_trace",
+                        lambda d: log.append(("start", time.perf_counter())))
+    monkeypatch.setattr(jax.profiler, "stop_trace",
+                        lambda: log.append(("stop", time.perf_counter())))
+    monkeypatch.setattr(jax, "device_get", lambda m: m)
+    fake = Fake()
+    t0 = time.perf_counter()
+    window.run_traced(fake, ({}, {}), 0.1, max(call_s, 1e-3), "unused")
+    assert [k for k, _ in log] == ["start", "stop"]
+    assert 0.1 <= dict(log)["stop"] - t0 < 0.1 + 2 * call_s + 0.05
+    assert fake.calls >= 2
+
+
 def test_traffic_like_lays_its_keys_over_the_mix_it_names():
     base, x4 = spec.load_traffic("epochs"), spec.load_traffic("x4")
     assert x4["name"] == "x4" and x4["like"] == "epochs"

@@ -1,10 +1,10 @@
 """Kinds and entries are files found by name (``lib/resolve.py``).
 
 What the tree names resolves; a name with no file says which file to add;
-and the proof that a new kind of model, of data and of entry needs no edit
-of a file the benchmark has: in a temporary copy of ``perfbench/`` they are
-ADDED with their reference, configuration, traffic file and
-``BENCHMARK.json`` entries, and the CPU rehearsal of that cell runs, is
+and the proof that a new kind of model, of data, of entry and of READER
+needs no edit of a file the benchmark has: in a temporary copy of
+``perfbench/`` they are ADDED with their reference, configuration, traffic
+file, metric file and ``BENCHMARK.json`` entries, and the CPU rehearsal of that cell runs, is
 ``correct``, and its bfloat16 control is not.
 """
 
@@ -52,6 +52,7 @@ def test_a_cells_entry_resolves(cell):
     ("reference", "no_such_ref", "perfbench/lib/reference/no_such_ref.py"),
     ("entry", "online_mf/no_such_entry",
      "perfbench/entries/online_mf/no_such_entry.py"),
+    ("reader", "no_such_reader", "perfbench/readers/no_such_reader.py"),
 ])
 def test_a_missing_kind_names_the_file_to_add(thing, name, file):
     with pytest.raises(spec.SpecError, match=re.escape(f"add {file}")):
@@ -86,7 +87,7 @@ def test_validate_refuses_what_does_not_resolve(edit, file, monkeypatch):
 @pytest.mark.parametrize("metric", [m["name"] for m in BENCH["per_layer"]])
 def test_a_per_layer_metrics_file_names_a_reader(metric):
     body = spec._load(os.path.join(spec.HERE, "metrics", metric + ".json"))
-    assert body["reader"] in readers.READERS
+    assert callable(readers.reader(body["reader"]))
     entry = next(m for m in BENCH["per_layer"] if m["name"] == metric)
     assert (body["name"], body["unit"], body["layer"]) == (
         entry["name"], entry["unit"], entry["layer"])
@@ -98,7 +99,9 @@ def test_validate_refuses_a_metric_file_with_no_reader(monkeypatch):
     monkeypatch.setattr(spec, "_load", lambda path: (
         dict(real(path), reader="no_such_reader")
         if path.endswith("device.idle_share.json") else real(path)))
-    with pytest.raises(spec.SpecError, match="no reader 'no_such_reader'"):
+    with pytest.raises(spec.SpecError, match=re.escape(
+            "no reader 'no_such_reader': add perfbench/readers/"
+            "no_such_reader.py holding read")):
         spec.validate(BENCH)
 
 
@@ -200,6 +203,19 @@ def generate(seed, d):
     return data, int(row_checksum(batch, sorted(data)))
 '''
 
+TOY_READER = '''
+"""Reader ``span_field_total``: the sum of one FIELD of a span's events
+over one part of the run (what ``program_span_events`` is for: a span's
+steps, bytes or depth, not its length)."""
+
+
+def read(ctx, p):
+    events = (ctx.get("program_span_events") or {}).get(
+        p["span"], {}).get(p.get("part", "window"), [])
+    vals = [e[p["field"]] for e in events if p["field"] in e]
+    return float(sum(vals)) * p.get("scale", 1.0) if vals else None
+'''
+
 REHEARSE = '''
 import json, os, sys, time
 os.environ["JAX_PLATFORMS"] = "cpu"
@@ -230,7 +246,15 @@ control_ok, _ = check.judge(check.compare(
     {k: np.asarray(v, np.float32) for k, v in low.items()}, ref, init,
     low_loss, low_n, loss, n, feed, feed, system.examples_per_call),
     cfg["limits"])
+from perfbench.lib import readers
+toy = {"toy.chunk_steps": loaded["readers"]["toy.chunk_steps"]}
+events = {"chunk": {"window": [{"span": "chunk", "t0": 1.0, "t1": 2.0,
+                                "steps": 513},
+                               {"span": "chunk", "t0": 2.0, "t1": 3.0,
+                                "steps": 512}], "setup": [], "after": []}}
 print(json.dumps({
+    "toy_reader": [readers.read_all(toy, {"program_span_events": events}),
+                   readers.read_all(toy, {})],
     "result": result, "control_ok": control_ok,
     "system": [type(system).__module__, type(system).entry],
     "entry_calls": len(blocking.CALLS)}))
@@ -277,6 +301,12 @@ def toy_copy(tmp_path_factory):
     put("configs/toy.json", json.dumps(cfg))
     put("traffic/toy-blocking.json", json.dumps(
         {"name": "toy-blocking", "like": "epochs", "entry": "blocking"}))
+    put("readers/span_field_total.py", TOY_READER)
+    put("metrics/toy.chunk_steps.json", json.dumps(
+        {"name": "toy.chunk_steps", "unit": "count", "layer": "step driver",
+         "what": "steps the window's chunks say they held",
+         "reader": "span_field_total",
+         "params": {"span": "chunk", "field": "steps"}}))
     bench = copy.deepcopy(BENCH)
     bench["configs"].append({
         "name": "toy", "source": "none: a test's toy", "reduced": [],
@@ -287,6 +317,10 @@ def toy_copy(tmp_path_factory):
     next(m for m in bench["per_layer"]
          if m["name"] == "driver.dispatch_ms")["workloads"].append(
              "toy.blocking")
+    bench["per_layer"].append({
+        "name": "toy.chunk_steps", "unit": "count", "better": "higher",
+        "source": "program_span", "layer": "step driver",
+        "moves": "examples_per_s", "workloads": ["toy.blocking"]})
     with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
         json.dump(bench, f)
     return root, before
@@ -299,7 +333,9 @@ def test_a_new_kind_of_model_data_and_entry_is_added_files_only(toy_copy):
         "perfbench/models/toy_mf.py", "perfbench/entries/toy_mf/blocking.py",
         "perfbench/datasets/toy_ratings.py", "perfbench/configs/toy.json",
         "perfbench/lib/reference/toy_sgd.py",
-        "perfbench/traffic/toy-blocking.json"}
+        "perfbench/traffic/toy-blocking.json",
+        "perfbench/readers/span_field_total.py",
+        "perfbench/metrics/toy.chunk_steps.json"}
     # No file that was there differs, BENCHMARK.json but by additions.
     for rel in sorted(before - {"BENCHMARK.json"}):
         assert filecmp.cmp(os.path.join(root, rel),
@@ -331,6 +367,10 @@ def test_the_added_cell_rehearses_correct_and_its_control_does_not(toy_copy):
     assert out["result"]["attempted"] >= 2
     assert out["control_ok"] is False
     assert out["system"] == ["perfbench.entries.toy_mf.blocking", "blocking"]
+    # The added reader was found by its name, read a span event's FIELD,
+    # and with nothing to read said nothing.
+    assert out["toy_reader"] == [
+        {"toy.chunk_steps": {"value": 1025.0, "unit": "count"}}, {}]
     # The window drove the entry's own call (the warm-up call and every
     # timed one), not the model kind's.
     assert out["entry_calls"] == out["result"]["attempted"]

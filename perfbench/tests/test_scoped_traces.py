@@ -99,8 +99,7 @@ def test_a_routes_scope_holds_only_its_own_call(pa):
         read_metric("ops.routed_ms_per_step", ops), rel=1e-9)
     assert not [r for r in rows if "dim1_head/" in r[5]
                 and ("gather.dim1/" in r[5] or "scatter_add.dim1/" in r[5])]
-    # The kernels carry their own names and still end in pallas_call,
-    # which is what kernel.rowop_* select by.
+    # The kernels carry their own names and still end in pallas_call.
     calls = sorted({r[5].split("/")[-2] for r in rows
                     if r[5].endswith("/pallas_call:")})
     assert calls == ["gather_dim1", "scatter_add_dim1"]
@@ -115,15 +114,38 @@ def test_the_existing_scope_readers_read_the_same_ops_as_before(mf, pa):
                            "scale": 1000.0})
         assert store == pytest.approx(
             per_step_ms(rows, steps, "fps.pull", "fps.push"))
-    rowops = (r"/fps\.(pull|compute|push)/(.*/)?"
-              r"(gather|scatter-add|pallas_call):$")
+    # kernel.rowop_* read ALL the time under pull, push and the routing
+    # layer (PR 37), each leaf once though fps.ops nests under the others:
+    # on PA the two Pallas kernels and what the parent's rule (ops named
+    # gather, scatter-add or pallas_call) left out, the push's mask, the
+    # pull's concatenate of head and tail, the kernels' own casts.
+    with open(os.path.join(spec.HERE, "metrics",
+                           "kernel.rowop_ns_per_row.json")) as f:
+        params = json.load(f)["params"]
+    assert params == {"scopes": ["fps.pull", "fps.push", "fps.ops"]}
+    rows_pa = 2 * 16384 * 64
     ctx = {"ops": pa[2], "config": {"rowops": {
-        "rows_per_worker_step": 2 * 16384 * 64, "row_bytes": 4}},
+        "rows_per_worker_step": rows_pa, "row_bytes": 4}},
         "peaks": {"hbm_bytes_per_s": 819e9}}
-    ns = readers.rowop_ns_per_row(ctx, {"tf_op_regex": rowops})
+    ns = readers.rowop_ns_per_row(ctx, params)
+    assert ns == pytest.approx(per_step_ms(pa[0], 2, *params["scopes"])
+                               * 1e-3 / rows_pa * 1e9)
+    assert 0.91 < ns < 0.92
     kernels = sum(r[4] for r in pa[0] if r[5].endswith("/pallas_call:"))
-    assert ns == pytest.approx(kernels / 2 / (2 * 16384 * 64) * 1e9)
-    assert 0.81 < ns < 0.83  # ledger, PR 23: 0.82087
+    assert 0.81 < kernels / 2 / rows_pa * 1e9 < 0.83  # ledger, PR 23
+    # On MF (PR 24's tree) the worker's local gather went through the
+    # routing layer and lies under fps.compute/fps.ops: it is in, with the
+    # store's pull and push, and no leaf is counted twice.
+    ctx = {"ops": mf[2], "config": {"rowops": {
+        "rows_per_worker_step": 131072, "row_bytes": 40}},
+        "peaks": {"hbm_bytes_per_s": 819e9}}
+    want = per_step_ms(mf[0], 3, *params["scopes"])
+    assert want == pytest.approx(1.016699, rel=1e-5)
+    assert want > per_step_ms(mf[0], 3, "fps.pull", "fps.push") + 0.6
+    assert readers.rowop_ns_per_row(ctx, params) == pytest.approx(
+        want * 1e-3 / 131072 * 1e9)
+    assert readers.rowop_roofline_percent(ctx, params) == pytest.approx(
+        100 * 131072 * 40 * 2 / 819e9 / (want * 1e-3))
 
 
 def test_once_a_call_ops_leave_the_step_count_alone(mf, pa):

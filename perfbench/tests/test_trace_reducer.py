@@ -16,6 +16,7 @@ import pytest
 
 from perfbench.lib import readers
 from perfbench.lib import trace_reduce as tr
+from test_rowop_yardstick import PARENT_RULE  # the rule until PR 37
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 
@@ -83,40 +84,70 @@ def test_collective_reader_finds_nothing_on_one_chip(ops):
         ctx(ops), {"name_regex": "^(all-gather|all-reduce)"}) is None
 
 
-ROWOPS = r"/fps\.(pull|compute|push)/(.*/)?(gather|scatter-add|pallas_call):$"
+SCOPES = {"scopes": ["fps.pull", "fps.push", "fps.ops"]}
 
 
 def test_rowop_time_rows_and_roofline(ops):
-    picked = [o for o in ops if o.tf_op.endswith(("gather:", "scatter-add:"))
-              and "/fps." in o.tf_op]
+    """Both denominators on the one recorded trace, worked out by plain
+    loops. This trace is PR 23's: its tree had no ``fps.ops`` scope yet, so
+    the worker's local gather and scatter-add lie under ``fps.compute``
+    alone and only the store's pull and push are under the scopes the
+    reader takes now (on a tree that routes them,
+    ``test_scoped_traces.py``). What the two rules disagree on here is
+    exactly that, and the ops of the push the regex left out."""
+    picked = [o for o in ops if PARENT_RULE.search(o.tf_op)]
     # a step's two gathers and two scatter-adds, with the copies XLA makes
     # of a gather's result under the same primitive: seven ops
     assert len(picked) == 7 * 6
-    per_step = sum(o.dur for o in picked) / 6
-    ns = readers.rowop_ns_per_row(ctx(ops), {"tf_op_regex": ROWOPS})
+    parent = sum(o.dur for o in picked)
+    assert parent == pytest.approx(0.017287049, rel=1e-6)
+    under = [o for o in ops if o.category != "while" and any(
+        f"/{s}/" in o.tf_op for s in SCOPES["scopes"])]
+    now = sum(o.dur for o in under)
+    assert now == pytest.approx(0.002074569, rel=1e-6)
+    # in the parent's and not under the scopes: the worker's local row ops
+    local = sum(o.dur for o in picked if o not in under)
+    assert {o.tf_op.split("closed_call/")[1] for o in picked
+            if o not in under} == {"fps.compute/scatter-add:",
+                                   "fps.compute/jit(_take)/gather:"}
+    # under the scopes and not in the parent's: the push's own arithmetic
+    # (its mask, the mean's divide, the add to the shard), 0.27 ms here
+    left_out = [o for o in under if o not in picked]
+    assert {o.tf_op.rstrip(":").split("/")[-1] for o in left_out} == {
+        "select_n", "div", "add", "and"}
+    assert now - sum(o.dur for o in left_out) == pytest.approx(
+        parent - local, rel=1e-9)
+    per_step = now / 6
+    ns = readers.rowop_ns_per_row(ctx(ops), SCOPES)
     assert ns == pytest.approx(per_step / 131072 * 1e9)
-    assert 20 < ns < 24
+    assert 2.5 < ns < 2.8
     # least time: 131,072 rows x 40 B x (one read + one write) at 819 GB/s
-    least = tr.rowop_least_seconds(131072, 40, 819e9)
+    least = tr.rowop_least_seconds(131072 * 40, 819e9)
     assert least == pytest.approx(12.8031e-6, rel=1e-4)
-    share = readers.rowop_roofline_percent(ctx(ops), {"tf_op_regex": ROWOPS})
+    share = readers.rowop_roofline_percent(ctx(ops), SCOPES)
     assert share == pytest.approx(100 * least / per_step)
-    assert 0.4 < share < 0.5
+    assert 3.6 < share < 3.8
 
 
 def test_byte_count_is_the_least_so_a_share_cannot_pass_100():
     """An op that moved each row exactly once in and once out at the peak
     reads 100 %; anything real is slower, so reads less."""
     rows, row_bytes, peak = 32768, 40, 819e9
-    least = tr.rowop_least_seconds(rows, row_bytes, peak)
+    least = tr.rowop_least_seconds(rows * row_bytes, peak)
     at_peak = [tr.Op(0, "XLA Ops", "fusion.1", 0.0, least,
                      "jit(run)/while/body/closed_call/fps.pull/gather:",
                      "f32[32768,10]", "custom fusion")]
     c = ctx(at_peak, rows=rows, row_bytes=row_bytes)
-    assert readers.rowop_roofline_percent(
-        c, {"tf_op_regex": ROWOPS}) == pytest.approx(100.0)
+    assert readers.rowop_roofline_percent(c, SCOPES) == pytest.approx(100.0)
     # the count holds no index bytes, no read-modify-write, no padding
     assert least == rows * row_bytes * 2 / peak
+    # ... and a second op under the scopes, whatever it is, only lowers it
+    more = at_peak + [tr.Op(0, "XLA Ops", "fusion.2", least, least,
+                            "jit(run)/while/body/closed_call/fps.pull/sort:",
+                            "s32[32768]", "loop fusion")]
+    assert readers.rowop_roofline_percent(
+        ctx(more, rows=rows, row_bytes=row_bytes),
+        SCOPES) == pytest.approx(50.0)
 
 
 def test_nested_and_gapped_synthetic_trace():
