@@ -339,7 +339,7 @@ class _Linter(ast.NodeVisitor):
                 if alias.name == "fps_tpu.utils.profiling":
                     self._add("FPS005", node,
                               "import of the utils.profiling shim — use "
-                              "fps_tpu.obs (trace/Throughput live there)")
+                              "fps_tpu.obs (trace lives there)")
         self.generic_visit(node)
 
     def visit_ImportFrom(self, node):
@@ -350,7 +350,7 @@ class _Linter(ast.NodeVisitor):
                     and any(a.name == "profiling" for a in node.names)):
                 self._add("FPS005", node,
                           "import of the utils.profiling shim — use "
-                          "fps_tpu.obs (trace/Throughput live there)")
+                          "fps_tpu.obs (trace lives there)")
         self.generic_visit(node)
 
     # -- FPS006 -----------------------------------------------------------

@@ -80,7 +80,7 @@ from fps_tpu.obs.health import (
     HealthMonitor,
     StepWatchdog,
 )
-from fps_tpu.obs.timing import PhaseTimer, host_span, settle
+from fps_tpu.obs.timing import PhaseTimer, host_span, settle, watch_device
 from fps_tpu.parallel.mesh import (
     DATA_AXIS,
     SHARD_AXIS,
@@ -2318,6 +2318,10 @@ class Trainer:
                     # metrics always have exactly steps_per_epoch rows.
                     if n_calls * T_call > T:
                         metrics = jax.tree.map(lambda x: x[:T], metrics)
+                    # The epoch is queued: its completion is the watcher's
+                    # to stamp (a None test with no recorder).
+                    watch_device("device.run_indexed", metrics, timer,
+                                 epoch=e, steps=T)
                     if quarantine is not None:
                         with _phase(timer, "host_sync"):
                             metrics, restored = self._maybe_quarantine(
@@ -2744,6 +2748,14 @@ class Trainer:
                                       touched=tc)
             saved_at = step
 
+        def dispatch():
+            """Queue chunk ``i`` on the live state; its completion is the
+            watcher's to stamp (a None test with no recorder)."""
+            out = self.run_chunk(tables, local_state, chunk, ckey,
+                                 timer=timer, recorder=rec)
+            watch_device("device.fit_stream", out[2], timer, chunk=i)
+            return out
+
         def sync_entry(entry):
             """Forced host sync for one dispatched chunk; on poison,
             _maybe_quarantine repoints the STORE at the restored state —
@@ -2880,10 +2892,7 @@ class Trainer:
                 if lag:
                     prev, pending = pending, None
                     with _watch(watchdog, "chunk", i):
-                        tables, local_state, metrics = self.run_chunk(
-                            tables, local_state, chunk, ckey, timer=timer,
-                            recorder=rec,
-                        )
+                        tables, local_state, metrics = dispatch()
                         save = boundary_copy(i) if save_due(i) else None
                         # Adjudicate chunk i-1 NOW — its host sync waits
                         # while the device is already busy with chunk i.
@@ -2906,11 +2915,7 @@ class Trainer:
                                            if self.retierer is not None
                                            else None)
                             with _watch(watchdog, "chunk", i):
-                                tables, local_state, metrics = (
-                                    self.run_chunk(tables, local_state,
-                                                   chunk, ckey,
-                                                   timer=timer,
-                                                   recorder=rec))
+                                tables, local_state, metrics = dispatch()
                             save = boundary_copy(i) if save_due(i) else None
                         else:
                             retier_boundary(prev["index"])
@@ -2919,10 +2924,7 @@ class Trainer:
                                "retier_state": rt_snap}
                 else:
                     with _watch(watchdog, "chunk", i):
-                        tables, local_state, metrics = self.run_chunk(
-                            tables, local_state, chunk, ckey, timer=timer,
-                            recorder=rec,
-                        )
+                        tables, local_state, metrics = dispatch()
                         entry = {"index": i, "metrics": metrics,
                                  "last_good": last_good, "save": None,
                                  "retier_state": rt_snap}

@@ -68,7 +68,7 @@ from fps_tpu.core.store import (
     sketch_key,
     split_tiering,
 )
-from fps_tpu.obs.timing import PhaseTimer, host_span
+from fps_tpu.obs.timing import PhaseTimer, host_span, watch_device
 from fps_tpu.parallel.mesh import (
     DATA_AXIS,
     SHARD_AXIS,
@@ -677,6 +677,10 @@ def run_megastep(trainer, tables, local_state, plan, key, *,
                 real_segs = min(K, -(-keep // T_call)) if T_call else K
                 if keep < K * T_call:
                     metrics = jax.tree.map(lambda x: x[:keep], metrics)
+                # The megastep is queued: its completion is the watcher's
+                # to stamp (a None test with no recorder).
+                watch_device("device.run_megastep", metrics, timer,
+                             epoch=e, chunk=m * K, steps=keep)
                 if quarantine is not None:
                     with _phase(timer, "host_sync"):
                         metrics, restored = trainer._maybe_quarantine(

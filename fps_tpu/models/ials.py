@@ -76,7 +76,7 @@ from fps_tpu.core.store import (
     ranged_uniform_init,
     rows_per_shard,
 )
-from fps_tpu.obs.timing import host_span
+from fps_tpu.obs.timing import host_span, watch_device
 from fps_tpu.parallel.mesh import DATA_AXIS, SHARD_AXIS
 
 Array = jax.Array
@@ -417,6 +417,15 @@ class IALSSolver:
                         solve_n, solve_rps)
                 self.store.tables[solve_name] = self._compiled_solve[
                     solve_name](gram, A, b)
+            # The sweep is queued: its completion (the solved table, which
+            # nothing donates, and the last chunk's metrics) is the
+            # watcher's to stamp, off this thread (a None test with no
+            # recorder): ``device.als.half_epoch`` is how long the sweep
+            # kept the device.
+            watch_device("device.als.half_epoch",
+                         (self.store.tables[solve_name], metrics[-1:]),
+                         solve=solve,
+                         steps=sum(m["n"].shape[0] for m in metrics))
             if len(metrics) < 2:
                 return metrics[0] if metrics else {}
             return jax.tree.map(lambda *xs: jnp.concatenate(xs), *metrics)

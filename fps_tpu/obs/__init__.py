@@ -12,8 +12,11 @@ One subsystem, four altitudes (see ``docs/observability.md``):
   the enclosing driver call; :class:`PhaseTimer` splits each chunk into
   host phases through it (ingest/place/dispatch/host_sync/checkpoint/
   callback/...); :func:`watch_compiles` folds JAX's compile timings and
-  cache hits in; :class:`Throughput` and :func:`trace` complete the
-  clock set.
+  cache hits in; :func:`watch_device` hands every unit of work an entry
+  point queues to a watcher thread that stamps its completion (the
+  ``device.<entry>`` spans: how long the device ran the unit, how long
+  it starved before it, with no caller made to wait); :func:`trace`
+  completes the clock set.
 * **alerting** — :class:`HealthMonitor` thresholds the guard's health
   channel (observe→mask escalation, poison abort);
   :class:`StepWatchdog` deadline-flags stalled chunks/stragglers.
@@ -52,6 +55,11 @@ and companions in          ``place``, ``dispatch`` > ``enqueue``,
                            ``callback``, ``retier``, ``prefetch``;
                            set-up: ``dataset.place`` ``dataset.queues``
                            ``dataset.pack`` ``plan.build`` ``init_state``
+device span                ``device.run_indexed`` (an epoch)
+(:func:`watch_device`;     ``device.fit_stream`` (a chunk)
+``fps.device.<entry>``     ``device.run_megastep`` ``device.als.half_epoch``
+in a profiler trace)       (a sweep): ``t0`` ``t1`` ``t_enqueued``
+                           ``wait_s`` ``starved_s`` ``in_flight`` ``steps``
 compile phase              ``compile.trace`` ``compile.lower``
                            ``compile.backend``; counters
                            ``compile.cache_hits`` / ``_misses``; event
@@ -96,10 +104,10 @@ from fps_tpu.obs.sinks import JsonlSink, MemorySink, PrometheusSink, Sink
 from fps_tpu.obs.timing import (
     DRIVER_PHASES,
     PhaseTimer,
-    Throughput,
     host_span,
     trace,
     watch_compiles,
+    watch_device,
 )
 from fps_tpu.obs.trace import (
     PARENT_SPAN_ENV,
@@ -113,8 +121,8 @@ from fps_tpu.obs.trace import (
 __all__ = [
     "MetricSpec", "MetricsRegistry", "Recorder", "default_registry",
     "Sink", "JsonlSink", "MemorySink", "PrometheusSink",
-    "PhaseTimer", "Throughput", "trace", "DRIVER_PHASES",
-    "host_span", "watch_compiles",
+    "PhaseTimer", "trace", "DRIVER_PHASES",
+    "host_span", "watch_compiles", "watch_device",
     "HealthMonitor", "StepWatchdog",
     "HEALTH_OK", "HEALTH_ESCALATE", "HEALTH_ABORT",
     "RunJournal", "new_run_id", "config_digest", "process_index",

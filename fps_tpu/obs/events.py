@@ -24,6 +24,12 @@ _default = None
 def set_default_recorder(recorder) -> None:
     """Install (or clear, with ``None``) the process-default recorder."""
     global _default
+    prev = _default
+    if prev is not None and prev is not recorder:
+        # The device watcher's last spans under ``prev`` (a bounded wait).
+        from fps_tpu.obs import timing  # lazy: timing imports this module
+
+        timing.drain_device_spans(prev)
     with _lock:
         _default = recorder
 

@@ -631,6 +631,16 @@ class Recorder:
                 s.flush()
 
     def close(self) -> None:
+        if self.closed:
+            return
+        # The device watcher's last spans come through this recorder from
+        # a thread of its own: wait for them (bounded, and outside the
+        # lock), with what is buffered flushed first, so that a kill
+        # during the wait loses no line.
+        self.flush()
+        from fps_tpu.obs import timing  # lazy: timing imports this package
+
+        timing.drain_device_spans(self)
         with self._lock:
             if self.closed:
                 return

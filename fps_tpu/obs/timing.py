@@ -1,4 +1,4 @@
-"""Phase timers, throughput accounting, and device tracing.
+"""Phase timers, device spans, and device tracing.
 
 The reference has no tracing subsystem — only Flink's built-in operator
 metrics (SURVEY.md §5 tracing row). On TPU we get device-level tracing
@@ -23,8 +23,12 @@ host-side clocks the chunked driver makes natural:
   (``docs/observability.md`` has the table of names).
 * :func:`watch_compiles` — folds JAX's own compile timings and
   persistent-cache hits and misses into the process-default recorder.
-* :class:`Throughput` — per-chunk wall-clock + examples/sec accounting
-  for ``Trainer.fit_stream(on_chunk=...)``.
+* :func:`watch_device` — the DEVICE's time, from inside: every unit of
+  work a driver entry point queues (an epoch, a chunk, a megastep, an
+  ALS sweep) is handed to one watcher thread that stamps its completion,
+  so a ``device.<entry>`` span says how long the device ran the unit,
+  how long the unit waited for it and how long the device starved before
+  it — no profiler, and no caller made to wait.
 * :func:`trace` — context manager writing a Perfetto/XProf-compatible
   trace of everything (XLA ops, collectives, host callbacks).
 
@@ -34,12 +38,12 @@ shim.)
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import itertools
+import logging
 import threading
 import time
-
-import numpy as np
 
 from fps_tpu.obs import events
 from fps_tpu.obs.trace import new_span_id
@@ -116,11 +120,29 @@ CALL_SPANS = ("run_indexed", "fit_stream", "run_megastep", "als.half_epoch")
 # ``als.accumulate`` a chunk queued, the solve queued: the host's cost of
 # queueing, like ``enqueue`` (a sweep reads nothing back).
 SWEEP_PHASES = ("als.gram", "als.accumulate", "als.solve")
+# Device spans (:func:`watch_device`), one per unit of work an entry point
+# queues: an epoch of ``run_indexed``, a chunk of ``fit_stream`` /
+# ``run_chunk``, a megastep, an ALS sweep. In a profiler trace the
+# watcher's wait for a unit is ``fps.<name>`` (``fps.device.run_indexed``):
+# NOT under ``fps.host.``, where a reader names the device's idle gaps by
+# the shortest host span covering them; one of these is always open.
+DEVICE_SPANS = ("device.run_indexed", "device.fit_stream",
+                "device.run_megastep", "device.als.half_epoch")
 
 HOST_SPAN_PREFIX = "fps.host."
+DEVICE_SPAN_PREFIX = "fps."
 
 _calls = itertools.count()
 _open = threading.local()  # .stack: [(span_id, call index)] of this thread
+
+# The spans' one clock, host and device spans alike: epoch seconds that
+# advance with ``perf_counter`` (monotonic: a step of the wall clock moves
+# no span against another).
+_EPOCH = time.time() - time.perf_counter()
+
+
+def _now() -> float:
+    return _EPOCH + time.perf_counter()
 
 
 def settle(tree):
@@ -177,24 +199,23 @@ def host_span(name: str, timer: "PhaseTimer | None" = None, *,
     sid = new_span_id() if rec is not None else None
     timed = rec is not None or timer is not None
     stack.append((sid, index))
-    t0, p0 = (time.time(), time.perf_counter()) if timed else (0.0, 0.0)
+    t0 = _now() if timed else 0.0
     try:
         with jax.profiler.TraceAnnotation(HOST_SPAN_PREFIX + name):
             yield attrs
     finally:
         stack.pop()
         if timed:
-            dt = time.perf_counter() - p0
+            t1 = _now()
             if timer is not None:
-                timer.add(name, dt)
+                timer.add(name, t1 - t0)
             else:
-                events.record_metric("observe", "driver.phase_seconds", dt,
-                                     phase=name)
+                events.record_metric("observe", "driver.phase_seconds",
+                                     t1 - t0, phase=name)
             if rec is not None:
                 if index is not None:
                     attrs.setdefault("call", index)
-                _emit_span(rec, guarded, name, sid, parent, t0, t0 + dt,
-                           attrs)
+                _emit_span(rec, guarded, name, sid, parent, t0, t1, attrs)
 
 
 def _emit_span(rec, guarded, name, sid, parent, t0, t1, attrs):
@@ -207,6 +228,207 @@ def _emit_span(rec, guarded, name, sid, parent, t0, t1, attrs):
         events.emit("span", **fields)
     else:
         rec.event("span", **fields)
+
+
+# -- the device's time, from inside ---------------------------------------
+
+_log = logging.getLogger("fps_tpu.obs")
+
+# The longest a recorder's closing (or clearing) waits for the device spans
+# of the units still queued under it; past it they are dropped. A device
+# that hangs must not hang ``Recorder.close``.
+DRAIN_SECONDS = 5.0
+
+
+class _DeviceUnit:
+    """One unit of queued device work in the watcher's FIFO."""
+
+    __slots__ = ("name", "leaves", "rec", "parent", "attrs", "t_enqueued",
+                 "in_flight")
+
+    def __init__(self, name, leaves, rec, parent, attrs):
+        self.name, self.leaves, self.rec = name, leaves, rec
+        self.parent, self.attrs = parent, attrs
+        self.t_enqueued = _now()
+        self.in_flight = 0
+
+
+class _DeviceWatcher:
+    """The completion watcher: a FIFO of units and one daemon thread that
+    waits for each in turn and stamps it. The thread lives while the FIFO
+    holds a unit: the first unit put on an empty FIFO starts it, and it
+    ends with the last one stamped, so none is left behind by a recorder
+    nobody closes.
+
+    thread-safety: ``_cond`` guards ``_fifo`` and ``_thread``; ``_last_t1``
+    is the running thread's own (one that ends leaves it to the next under
+    ``_cond``). A unit stays at the head of ``_fifo`` while the thread
+    waits for it (so ``in_flight`` counts it) and is stamped and emitted by
+    the thread alone, straight on the recorder it was queued under; callers
+    only append, and :meth:`drain` may take a unit's recorder away. The
+    thread never touches a donated buffer: a unit holds outputs nothing
+    donates, and one whose wait raises all the same (a failed call, a
+    deleted buffer) is dropped.
+    """
+
+    def __init__(self):
+        self._cond = threading.Condition()
+        self._fifo: collections.deque = collections.deque()
+        self._thread = None
+        self._last_t1 = None
+
+    def put(self, unit: _DeviceUnit) -> None:
+        with self._cond:
+            unit.in_flight = len(self._fifo)
+            self._fifo.append(unit)
+            if self._thread is None:
+                self._thread = threading.Thread(
+                    target=self._run, name="fps-device-watcher", daemon=True)
+                self._thread.start()
+
+    def drain(self, rec, timeout: float) -> None:
+        """Wait, ``timeout`` seconds at most, until every unit queued under
+        ``rec`` is stamped and emitted; what is not by then is dropped (the
+        thread still waits for it, for its successor's ``t0``, and records
+        nothing). The one place a caller waits for the watcher."""
+        deadline = time.monotonic() + timeout
+        with self._cond:
+            while any(u.rec is rec for u in self._fifo):
+                left = deadline - time.monotonic()
+                if left <= 0:
+                    late = [u for u in self._fifo if u.rec is rec]
+                    for u in late:
+                        u.rec = None
+                    _log.warning(
+                        "%d device span(s) dropped: the device had not "
+                        "finished %s %.1f s after its recorder closed",
+                        len(late), late[0].name, timeout)
+                    return
+                self._cond.wait(left)
+
+    def _run(self) -> None:
+        import jax
+
+        try:
+            while True:
+                with self._cond:
+                    unit = self._fifo[0]
+                try:
+                    with jax.profiler.TraceAnnotation(
+                            DEVICE_SPAN_PREFIX + unit.name):
+                        jax.block_until_ready(unit.leaves)
+                    t1 = _now()
+                except Exception:  # noqa: BLE001 - a failed call, a
+                    # deleted buffer: no span, and the watcher carries on
+                    t1 = None
+                    _log.debug("device unit %s dropped", unit.name,
+                               exc_info=True)
+                unit.leaves = None
+                if t1 is not None:
+                    prev, self._last_t1 = self._last_t1, t1
+                    try:
+                        self._emit(unit, prev, t1)
+                    except Exception:  # noqa: BLE001 - telemetry must
+                        # not end the watcher
+                        _log.warning("device span %s not recorded",
+                                     unit.name, exc_info=True)
+                with self._cond:
+                    self._fifo.popleft()
+                    self._cond.notify_all()
+                    if not self._fifo:
+                        self._thread = None
+                        return
+        finally:
+            with self._cond:  # died outside the guards: put() starts anew
+                if self._thread is threading.current_thread():
+                    self._thread = None
+                    self._fifo.clear()
+                    self._cond.notify_all()
+
+    @staticmethod
+    def _emit(unit: _DeviceUnit, prev, t1) -> None:
+        rec = unit.rec
+        if rec is None:  # its recorder closed before the device was done
+            return
+        t0 = unit.t_enqueued if prev is None else max(unit.t_enqueued, prev)
+        sid, call = unit.parent
+        attrs = dict(
+            unit.attrs, t_enqueued=unit.t_enqueued,
+            wait_s=t0 - unit.t_enqueued,
+            starved_s=0.0 if prev is None else max(0.0,
+                                                   unit.t_enqueued - prev),
+            in_flight=unit.in_flight)
+        if call is not None:
+            attrs.setdefault("call", call)
+        _emit_span(rec, False, unit.name, new_span_id(), sid, t0, t1, attrs)
+
+
+_watcher = _DeviceWatcher()
+
+
+def watch_device(name: str, outputs, timer: "PhaseTimer | None" = None,
+                 **attrs) -> None:
+    """Hand one unit of queued device work to the completion watcher.
+
+    Called by a driver entry point right after the LAST program of the
+    unit is queued, with outputs of the unit that nothing donates (its
+    metrics; the solved table of an ALS sweep). The recorder is
+    :func:`host_span`'s: ``timer``'s, else the process default; with
+    neither this is two ``None`` tests, no thread exists and nothing is
+    recorded. Otherwise the watcher's thread waits for the outputs (the
+    caller never does: the call returns as unfinished as without a
+    recorder) and emits the canonical ``span`` record under ``name`` (one of
+    :data:`DEVICE_SPANS`), with the ``call`` index of the enclosing driver
+    call and that call's root span as ``parent_id``:
+
+    * ``t_enqueued``: host time of this call (the unit's last program
+      queued); ``t1``: the completion stamp;
+    * ``t0 = max(t_enqueued, t1 of the unit before)``: the device is one
+      in-order stream, so a unit starts when it is queued or when its
+      predecessor ends, whichever is later. A unit of several programs
+      queued onto an IDLE device began up to its own queueing time
+      earlier than ``t0``; with a unit queued ahead the span is completion
+      to completion, so the runtime's launch latency is inside it;
+    * ``wait_s = t0 - t_enqueued``: how long the work waited for the
+      device; ``starved_s = max(0, t_enqueued - previous t1)``: how long
+      the device had nothing of this program's queued. It is zero whenever
+      work was queued ahead, whatever the runtime then did with it: a
+      launch or transfer latency is NOT in it;
+    * ``in_flight``: units queued and not complete when this one was
+      queued; ``attrs`` (``steps``, ``epoch``, ``chunk``, ``solve``):
+      what the call site knows without reading the device (``steps``
+      defaults to the leading dimension of the first output, the shape of
+      per-step metrics).
+
+    The span is the whole record: ``tools/obs_report.py``'s ``device``
+    section and the benchmark's per-layer metrics read it, and nothing
+    else is kept beside it.
+    """
+    rec = timer.recorder if timer is not None else None
+    if rec is None:
+        rec = events.get_default_recorder()
+        if rec is None:
+            return
+    import jax
+
+    leaves = [x for x in jax.tree.leaves(outputs)
+              if hasattr(x, "block_until_ready")]
+    if not leaves:
+        return  # nothing of the unit is on the device to wait for
+    if "steps" not in attrs and getattr(leaves[0], "ndim", 0):
+        attrs["steps"] = int(leaves[0].shape[0])  # per-step metrics
+    stack = getattr(_open, "stack", None)
+    _watcher.put(_DeviceUnit(name, leaves, rec,
+                             stack[-1] if stack else (None, None), attrs))
+
+
+def drain_device_spans(recorder) -> None:
+    """Wait, :data:`DRAIN_SECONDS` at most, for the device spans of the
+    units still queued under ``recorder``, and drop those the device has
+    not finished by then. ``Recorder.close`` (its sinks flushed first) and
+    a change of the process-default recorder call it, so a journal holds
+    its last sweep and a device that hangs hangs neither."""
+    _watcher.drain(recorder, DRAIN_SECONDS)
 
 
 # -- compiles, from inside ----------------------------------------------
@@ -327,77 +549,3 @@ def trace(log_dir: str):
         yield
     finally:
         jax.profiler.stop_trace()
-
-
-class Throughput:
-    """Callable chunk hook accumulating wall-clock and example counts.
-
-    ``count_key`` names the metrics leaf holding per-step example counts
-    (every shipped model emits ``"n"``). The first chunk is recorded
-    separately (``first_s``) since it includes compilation.
-
-    Timing origin: :meth:`start` marks the stream start explicitly; when
-    it was never called, the first observation measures from CONSTRUCTION
-    time. (It used to fall back to "now", which recorded a zero-width
-    first chunk and understated compile time — the hook is conventionally
-    built immediately before ``fit_stream``, so construction time is the
-    honest origin; any setup between the two is attributed to the first
-    chunk, which already absorbs one-time costs by design. Call
-    ``start()`` right before the run when that setup is expensive, and
-    before any *second* stream reusing this hook, or the inter-run gap
-    lands in ``steady_s``.)
-    """
-
-    def __init__(self, count_key: str = "n"):
-        self.count_key = count_key
-        self.chunks = 0
-        self.first_s: float | None = None
-        self._first_examples = 0.0
-        self.steady_s = 0.0
-        self._steady_examples = 0.0
-        self._last: float | None = None
-        self._created = time.perf_counter()
-
-    def start(self) -> None:
-        """Mark the stream start (see the class docstring for when the
-        implicit construction-time origin is not what you want)."""
-        self._last = time.perf_counter()
-
-    def __call__(self, step: int, metrics) -> None:
-        now = time.perf_counter()
-        if self._last is None:
-            # No explicit start(): the stream began, as far as this hook
-            # can know, when the hook was constructed.
-            self._last = self._created
-        dt = now - self._last
-        self._last = now
-        count = (
-            float(np.sum(metrics[self.count_key]))
-            if self.count_key in metrics
-            else 0.0
-        )
-        if self.first_s is None:
-            self.first_s = dt
-            self._first_examples = count
-        else:
-            self.steady_s += dt
-            self._steady_examples += count
-        self.chunks += 1
-
-    @property
-    def examples(self) -> float:
-        return self._first_examples + self._steady_examples
-
-    @property
-    def examples_per_sec(self) -> float:
-        """Steady-state throughput (excludes the compile-laden first chunk)."""
-        return self._steady_examples / self.steady_s if self.steady_s else 0.0
-
-    def summary(self) -> dict:
-        return {
-            "chunks": self.chunks,
-            "examples": self.examples,
-            "first_chunk_s": round(self.first_s or 0.0, 4),
-            "steady_s": round(self.steady_s, 4),
-            "examples_per_sec": round(self.examples_per_sec, 1),
-        }

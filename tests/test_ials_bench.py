@@ -157,12 +157,18 @@ def test_a_call_reads_nothing_back_from_the_device(monkeypatch, recorder):
     """``System.call`` (two sweeps of ``IALSSolver.half_epoch``) only
     queues, with a process-default recorder installed (a traced run) as
     without: with every way a device value reaches the host, and every
-    wait for the device, made to raise, two calls go through, and the
-    metrics come back afterwards. (The CPU backend honours no
-    device-to-host transfer guard, so the array's own host accessors are
-    what is guarded; the guard is set all the same.) A call that returned
-    FINISHED under a recorder hung a traced run of the cell on the chip:
-    the runner stops its profiler from inside its wait for a call."""
+    wait for the device, made to raise ON THE CALLING THREAD, two calls go
+    through, and the metrics come back afterwards. (The CPU backend
+    honours no device-to-host transfer guard, so the array's own host
+    accessors are what is guarded; the guard is set all the same.) A call
+    that returned FINISHED under a recorder hung a traced run of the cell
+    on the chip: the runner stops its profiler from inside its wait for a
+    call. Under a recorder the sweeps' completion is the watcher thread's
+    to wait for (``obs.timing.watch_device``): its wait is held back here
+    until both calls have returned, so the four ``device.als.half_epoch``
+    spans arrive AFTER them, and no caller waited."""
+    import threading
+
     from jax._src import array
 
     from fps_tpu import obs
@@ -173,34 +179,67 @@ def test_a_call_reads_nothing_back_from_the_device(monkeypatch, recorder):
     state, _ = window.queue_call(system, state)  # compiles
     jax.block_until_ready(state)
 
-    def host_read(*a, **k):
-        raise AssertionError("a device value was read inside a sweep")
+    caller = threading.current_thread()
+    returned = threading.Event()
+
+    def guarded(real):
+        def wait_or_read(*a, **k):
+            if threading.current_thread() is caller:
+                raise AssertionError(
+                    "a device value was read inside a sweep")
+            assert returned.wait(60)  # the watcher's thread: not before
+            return real(*a, **k)      # the calls have returned
+        return wait_or_read
+
+    def device_spans():
+        return [e for e in sink.events("span")
+                if e["span"] == "device.als.half_epoch"]
 
     sink = obs.MemorySink(capacity=1 << 10)
     with monkeypatch.context() as m, \
             jax.transfer_guard_device_to_host("disallow"):
         if recorder:
             events.set_default_recorder(obs.Recorder(sinks=[sink]))
-        m.setattr(array.ArrayImpl, "_value", property(host_read))
-        m.setattr(array.ArrayImpl, "__array__", host_read)
-        m.setattr(array.ArrayImpl, "block_until_ready", host_read)
-        m.setattr(jax, "block_until_ready", host_read)
+        m.setattr(array.ArrayImpl, "_value",
+                  property(guarded(array.ArrayImpl._value.fget)))
+        m.setattr(array.ArrayImpl, "__array__",
+                  guarded(array.ArrayImpl.__array__))
+        m.setattr(array.ArrayImpl, "block_until_ready",
+                  guarded(array.ArrayImpl.block_until_ready))
+        m.setattr(jax, "block_until_ready", guarded(jax.block_until_ready))
         try:
             state, _ = window.queue_call(system, state)
             state, third = window.queue_call(system, state)
+            assert not device_spans()
+            watchers = [t for t in threading.enumerate()
+                        if t.name == "fps-device-watcher"]
+            assert len(watchers) == (1 if recorder else 0)
         finally:
+            returned.set()
             if recorder:
+                # Clearing the recorder drains the watcher: the one place
+                # a caller waits for it (a condition, not the device).
                 events.set_default_recorder(None)
     host = third.wait().host
     assert len(host) == 2 and all(
         float(np.sum(h["n"])) == 9001 for h in host)
     spans = [e["span"] for e in sink.events("span")]
     assert (spans.count("als.half_epoch") == 4) is recorder
+    assert len(device_spans()) == (4 if recorder else 0)
     if recorder:
         # One accumulate program a chunk: ceil(steps / steps_per_chunk).
         T = int(system.plan.steps_per_epoch)
         assert spans.count("als.accumulate") == 4 * -(-T // 8)
         assert spans.count("als.gram") == spans.count("als.solve") == 4
+        # Each sweep's device span hangs under the sweep that queued it,
+        # in queue order; the steps are the sweep's chunks' (padded).
+        roots = [e for e in sink.events("span")
+                 if e["span"] == "als.half_epoch"]
+        assert [e["parent_id"] for e in device_spans()] == [
+            r["span_id"] for r in roots]
+        assert [e["solve"] for e in device_spans()] == [
+            "user", "item"] * 2
+        assert all(e["steps"] == 8 * -(-T // 8) for e in device_spans())
 
 
 # -- the program against the reference ---------------------------------------

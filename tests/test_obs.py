@@ -15,6 +15,7 @@ Acceptance contract (ISSUE 2):
 
 import json
 import os
+import threading
 import time
 
 import numpy as np
@@ -163,28 +164,6 @@ def test_phase_timer_accumulates_and_records():
     assert t.chunk_summary() == {}  # reset
     # Run-level totals live on the recorder, the single source of truth.
     assert rec.phase_totals()["dispatch"]["n"] == 2
-
-
-def test_throughput_first_chunk_covers_construction_gap():
-    """Satellite fix: auto-start on first observation used to record a
-    zero-width first chunk; it must now measure from construction."""
-    tp = obs.Throughput()
-    time.sleep(0.05)
-    tp(0, {"n": np.array([10.0])})
-    assert tp.first_s is not None and tp.first_s >= 0.045
-    tp(1, {"n": np.array([10.0])})
-    s = tp.summary()
-    # Keys stable (the documented contract).
-    assert set(s) == {"chunks", "examples", "first_chunk_s", "steady_s",
-                      "examples_per_sec"}
-    assert s["chunks"] == 2 and s["examples"] == 20.0
-    assert s["first_chunk_s"] >= 0.045
-    # Explicit start() still overrides the construction origin.
-    tp2 = obs.Throughput()
-    time.sleep(0.02)
-    tp2.start()
-    tp2(0, {"n": np.array([1.0])})
-    assert tp2.first_s < 0.02
 
 
 # ---------------------------------------------------------------------------
@@ -523,58 +502,24 @@ def test_default_recorder_off_and_on_compile_identically(devices8, program):
     through the PROCESS-DEFAULT recorder. Installing one must leave each
     driver's program (lowered text) and what it computes bit-identical —
     spans and their waits are host-side only."""
-    from fps_tpu import DeviceDataset, DeviceEpochPlan
-    from fps_tpu.models.matrix_factorization import MFConfig, online_mf
     from fps_tpu.utils.datasets import synthetic_ratings
 
     mesh = make_ps_mesh(num_shards=2, num_data=1, devices=devices8[:2])
     data = synthetic_ratings(57, 31, 600, seed=0)
+    entry = {"chunk": "fit_stream", "indexed": "run_indexed",
+             "megastep": "run_megastep"}[program]
 
-    def run():
-        trainer, _ = online_mf(mesh, MFConfig(num_users=57, num_items=31,
-                                              rank=4),
-                               max_steps_per_call=4)
-        plan = DeviceEpochPlan(DeviceDataset(mesh, data), num_workers=2,
-                               local_batch=16, route_key="user", seed=3)
-        tables, ls = trainer.init_state(jax.random.key(0))
-        key = jax.random.key(1)
-        if program == "chunk":
-            from fps_tpu.core.device_ingest import device_epoch_chunks
-
-            chunk = next(device_epoch_chunks(
-                plan.dataset, num_workers=2, local_batch=16,
-                steps_per_chunk=4, plan=plan))
-            text = trainer.lowered_chunk_text(
-                jax.tree.map(np.asarray, chunk))
-            tables, ls, m = trainer.fit_stream(
-                tables, ls, device_epoch_chunks(
-                    plan.dataset, num_workers=2, local_batch=16,
-                    steps_per_chunk=4, plan=plan), key)
-        elif program == "indexed":
-            from fps_tpu.parallel.mesh import key_to_replicated
-
-            text = trainer._get_indexed_fn(plan, "sync").lower(
-                tables, ls, plan.epoch_args(0), np.int32(0),
-                key_to_replicated(key, mesh)).as_text()
-            tables, ls, m = trainer.run_indexed(tables, ls, plan, key)
-        else:
-            text = trainer.lowered_megastep_text(plan,
-                                                 chunks_per_dispatch=2)
-            tables, ls, m = trainer.run_megastep(
-                tables, ls, plan, key, chunks_per_dispatch=2)
-        return text, jax.tree.map(np.asarray, (tables, ls, m))
-
-    text_off, out_off = run()
+    text_off, _, out_off = _entry_point_run(entry, mesh, data)
     sink = obs.MemorySink()
     with obs_events.default_recorder(obs.Recorder(sinks=[sink])):
-        text_on, out_on = run()
+        text_on, _, out_on = _entry_point_run(entry, mesh, data)
     assert text_off == text_on
     jax.tree.map(np.testing.assert_array_equal, out_off, out_on)
     # ... and the recorder did see the run: set-up spans and the call.
     spans = {e["span"] for e in sink.events("span")}
     assert {"dataset.place", "dataset.queues", "plan.build", "init_state",
             "epoch_args", "program_lookup", "enqueue"} <= spans
-    assert spans & {"fit_stream", "run_indexed", "run_megastep"}
+    assert entry in spans
 
 
 # ---------------------------------------------------------------------------
@@ -716,6 +661,362 @@ def test_driver_phases_cover_what_the_drivers_emit():
                             emitted.add(a.value)
     assert emitted and emitted <= declared, emitted - declared
     assert declared <= emitted, declared - emitted
+
+
+def test_device_spans_cover_what_the_entry_points_watch():
+    """DEVICE_SPANS names every unit an entry point hands to the watcher
+    (``watch_device("device.<entry>", ...)``), and every name is one a
+    driver call opens (``CALL_SPANS``): a device span hangs under its
+    entry's root span."""
+    import ast
+
+    from fps_tpu.obs import timing
+
+    root = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "fps_tpu")
+    watched = set()
+    for d, _, files in os.walk(root):
+        for f in files:
+            if not f.endswith(".py"):
+                continue
+            tree = ast.parse(open(os.path.join(d, f)).read())
+            for node in ast.walk(tree):
+                if (isinstance(node, ast.Call)
+                        and getattr(node.func, "id", getattr(
+                            node.func, "attr", "")) == "watch_device"
+                        and node.args
+                        and isinstance(node.args[0], ast.Constant)):
+                    watched.add(node.args[0].value)
+    assert watched == set(timing.DEVICE_SPANS)
+    assert {n[len("device."):] for n in timing.DEVICE_SPANS} == set(
+        timing.CALL_SPANS)
+
+
+# ---------------------------------------------------------------------------
+# watch_device: the completion watcher (ISSUE 38). Fake handles whose
+# readiness the test controls stand for a unit's device outputs.
+# ---------------------------------------------------------------------------
+
+class _Handle:
+    """A unit's output: ready when the test says so; ``fail`` makes the
+    wait raise, as the wait for a failed call's output does."""
+
+    def __init__(self, fail=False):
+        self.ready = threading.Event()
+        self.fail = fail
+
+    def block_until_ready(self):
+        assert self.ready.wait(10), "the test never released the handle"
+        if self.fail:
+            raise RuntimeError("the call failed")
+        return self
+
+
+def _device_spans(sink, n=0):
+    """The sink's device spans, once ``n`` of them are there (the watcher's
+    thread records them; the wait is for that thread alone)."""
+    deadline = time.monotonic() + 5.0
+    while True:
+        spans = [e for e in sink.events("span")
+                 if e["span"].startswith("device.")]
+        if len(spans) >= n or time.monotonic() > deadline:
+            return spans
+        time.sleep(0.002)
+
+
+def _watcher_threads(gone=False):
+    """The live watcher threads; with ``gone`` a thread that is on its way
+    out (its last unit stamped) is given a moment to end."""
+    deadline = time.monotonic() + 5.0
+    while True:
+        threads = [t for t in threading.enumerate()
+                   if t.name == "fps-device-watcher"]
+        if not (gone and threads) or time.monotonic() > deadline:
+            return threads
+        time.sleep(0.002)
+
+
+def test_device_unit_queued_ahead_starts_at_its_predecessors_end():
+    """Two units in flight: stamps come in queue order whatever order the
+    outputs turn ready in; the second starts at the first's end, waited
+    for the device that long and starved it of nothing."""
+    sink = obs.MemorySink()
+    with obs_events.default_recorder(obs.Recorder(sinks=[sink])):
+        with obs.host_span("run_indexed", call=True):
+            a, b = _Handle(), _Handle()
+            obs.watch_device("device.run_indexed", {"n": a}, epoch=0)
+            obs.watch_device("device.run_indexed", {"n": b}, epoch=1)
+            b.ready.set()            # the later unit's output first
+            time.sleep(0.02)
+            assert not _device_spans(sink)
+            a.ready.set()
+            first, second = _device_spans(sink, 2)
+    assert (first["epoch"], second["epoch"]) == (0, 1)
+    assert first["t1"] <= second["t1"]
+    assert first["t0"] == first["t_enqueued"] and first["wait_s"] == 0
+    assert second["t0"] == first["t1"] > second["t_enqueued"]
+    assert second["wait_s"] == pytest.approx(
+        first["t1"] - second["t_enqueued"])
+    assert second["wait_s"] >= 0.02 and second["starved_s"] == 0
+    assert (first["in_flight"], second["in_flight"]) == (0, 1)
+    # On the host spans' clock: queued inside the root span that was open.
+    (root,) = [e for e in sink.events("span") if e["span"] == "run_indexed"]
+    assert root["t0"] <= first["t_enqueued"] <= second["t_enqueued"]
+    assert second["t_enqueued"] <= root["t1"]
+
+
+def test_device_unit_queued_late_counts_the_time_the_device_starved():
+    sink = obs.MemorySink()
+    with obs_events.default_recorder(obs.Recorder(sinks=[sink])):
+        a, b = _Handle(), _Handle()
+        a.ready.set()
+        obs.watch_device("device.fit_stream", [a], chunk=0)
+        assert len(_device_spans(sink, 1)) == 1
+        time.sleep(0.03)             # the host is late with the next unit
+        b.ready.set()
+        obs.watch_device("device.fit_stream", [b], chunk=1)
+        first, second = _device_spans(sink, 2)
+    assert second["in_flight"] == 0
+    assert second["t0"] == second["t_enqueued"] and second["wait_s"] == 0
+    assert second["starved_s"] == pytest.approx(
+        second["t_enqueued"] - first["t1"])
+    assert second["starved_s"] >= 0.03
+    # The span is the whole record: no metric is kept beside it.
+    assert not sink.metrics()
+
+
+def test_device_unit_whose_call_raised_is_dropped():
+    sink = obs.MemorySink()
+    with obs_events.default_recorder(obs.Recorder(sinks=[sink])):
+        bad, good = _Handle(fail=True), _Handle()
+        obs.watch_device("device.run_megastep", [bad])
+        obs.watch_device("device.run_megastep", [good])
+        bad.ready.set()
+        good.ready.set()
+    (only,) = _device_spans(sink)
+    assert only["in_flight"] == 1 and only["t0"] == only["t_enqueued"]
+
+
+def test_watcher_thread_lives_while_a_unit_is_queued():
+    assert not _watcher_threads()
+    obs.watch_device("device.run_indexed", [_Handle()])
+    assert not _watcher_threads()    # no recorder: nothing of it runs
+    sink = obs.MemorySink()
+    h = _Handle()
+    with obs_events.default_recorder(obs.Recorder(sinks=[sink])):
+        obs.watch_device("device.run_indexed", [np.zeros(3)])
+        assert not _watcher_threads()  # nothing to wait for: no unit
+        obs.watch_device("device.run_indexed", [h], steps=7)
+        (thread,) = _watcher_threads()
+        assert thread.daemon
+        # Clearing the recorder drains: the one place a caller waits.
+        threading.Timer(0.02, h.ready.set).start()
+    (span,) = _device_spans(sink)
+    assert span["steps"] == 7 and span["parent_id"] is None
+    assert "call" not in span
+    assert not _watcher_threads(gone=True)
+    thread.join(5.0)
+    assert not thread.is_alive()
+
+
+def test_watcher_thread_ends_by_itself_under_a_recorder_nobody_closes():
+    """A recorder held by a timer and never closed leaves no thread behind:
+    the thread ends with the last unit stamped, and the next unit starts
+    another."""
+    sink = obs.MemorySink()
+    timer = obs.PhaseTimer(obs.Recorder(sinks=[sink]))
+    for chunk in range(2):
+        h = _Handle()
+        h.ready.set()
+        obs.watch_device("device.fit_stream", [h], timer, chunk=chunk)
+        assert len(_device_spans(sink, chunk + 1)) == chunk + 1
+        assert not _watcher_threads(gone=True)
+    assert [e["chunk"] for e in _device_spans(sink)] == [0, 1]
+
+
+def test_device_spans_go_to_the_timers_recorder_not_the_default():
+    mine, default = obs.MemorySink(), obs.MemorySink()
+    rec = obs.Recorder(sinks=[mine])
+    timer = obs.PhaseTimer(rec)
+    h = _Handle()
+    with obs_events.default_recorder(obs.Recorder(sinks=[default])):
+        obs.watch_device("device.fit_stream", [h], timer, chunk=3)
+    # Clearing the default waited for nothing: the unit is not its own.
+    assert not _device_spans(mine)
+    threading.Timer(0.02, h.ready.set).start()
+    rec.close()                      # closing the unit's recorder drains
+    assert [e["chunk"] for e in _device_spans(mine)] == [3]
+    assert not _device_spans(default)
+    assert not _watcher_threads(gone=True)
+
+
+class _FlushedSink(obs.MemorySink):
+    """Remembers how many records it held when it was first flushed."""
+
+    flushed_at = None
+
+    def flush(self):
+        if self.flushed_at is None:
+            self.flushed_at = len(self.records)
+
+
+def test_closing_a_recorder_waits_for_a_hung_device_no_longer_than_bounded(
+        monkeypatch, caplog):
+    """A device that never finishes must not hang ``Recorder.close``: the
+    wait for the watcher is bounded, the unit's span is dropped with a log
+    line, and the sinks were flushed BEFORE the wait (a kill during it
+    loses no buffered line)."""
+    from fps_tpu.obs import timing
+
+    monkeypatch.setattr(timing, "DRAIN_SECONDS", 0.05)
+    sink = _FlushedSink()
+    rec = obs.Recorder(sinks=[sink])
+    hung = _Handle()
+    with obs.host_span("fit_stream", obs.PhaseTimer(rec), call=True):
+        obs.watch_device("device.fit_stream", [hung], obs.PhaseTimer(rec))
+    t = time.monotonic()
+    with caplog.at_level("WARNING", logger="fps_tpu.obs"):
+        rec.close()
+    assert time.monotonic() - t < 2.0 and rec.closed
+    assert sink.flushed_at is not None and sink.flushed_at >= 1
+    assert "1 device span(s) dropped" in caplog.text
+    hung.ready.set()                 # the device ends after all: no span,
+    assert not _watcher_threads(gone=True)   # and the thread ends
+    assert not _device_spans(sink)
+    # The next unit's start is still its predecessor's (unrecorded) end.
+    sink2 = obs.MemorySink()
+    h = _Handle()
+    h.ready.set()
+    with obs_events.default_recorder(obs.Recorder(sinks=[sink2])):
+        obs.watch_device("device.fit_stream", [h])
+    (span,) = _device_spans(sink2)
+    assert span["starved_s"] > 0
+
+
+def test_watcher_thread_that_dies_is_replaced_by_the_next_unit(monkeypatch):
+    """A fault outside the watcher's guards ends its thread; the next unit
+    starts a new one and a drain does not wait for the dead one's units."""
+    from fps_tpu.obs import timing
+
+    class Fatal(BaseException):
+        pass
+
+    def die(*a, **k):
+        raise Fatal
+
+    sink = obs.MemorySink()
+    with obs_events.default_recorder(obs.Recorder(sinks=[sink])):
+        h = _Handle()
+        h.ready.set()
+        with monkeypatch.context() as m:
+            m.setattr(timing._DeviceWatcher, "_emit", staticmethod(die))
+            m.setattr(threading, "excepthook", lambda args: None)
+            obs.watch_device("device.fit_stream", [h], chunk=0)
+            assert not _watcher_threads(gone=True)
+        obs.watch_device("device.fit_stream", [h], chunk=1)
+        assert [e["chunk"] for e in _device_spans(sink, 1)] == [1]
+    assert not _watcher_threads(gone=True)
+
+
+def _entry_point_run(entry, mesh, data):
+    """One run of a driver entry point: ``(lowered text of its program,
+    units it queued, what it computed as host arrays)``."""
+    from fps_tpu import DeviceDataset, DeviceEpochPlan
+    from fps_tpu.core.device_ingest import device_epoch_chunks
+    from fps_tpu.models.matrix_factorization import MFConfig, online_mf
+
+    key = jax.random.key(1)
+    if entry == "als.half_epoch":
+        from fps_tpu.models.ials import IALSConfig, IALSSolver
+
+        solver = IALSSolver(mesh, IALSConfig(num_users=57, num_items=31,
+                                             rank=4))
+        solver.store.tables = solver.init(jax.random.key(0))
+        plan = DeviceEpochPlan(DeviceDataset(mesh, data), num_workers=2,
+                               local_batch=16, seed=3)
+
+        def chunks():
+            return device_epoch_chunks(
+                plan.dataset, num_workers=2, local_batch=16,
+                steps_per_chunk=8, plan=plan)
+
+        metrics = solver.epoch(chunks)
+        chunk = {"solve_ids": "user", "fixed_ids": "item",
+                 "rating": "rating", "weight": "weight"}
+        first = next(chunks())
+        tables = solver.store.tables
+        text = solver._compiled_acc["user"].lower(
+            tables["item_factors"], tables["user_factors"],
+            solver._zeros_acc(tables["user_factors"].shape[0], 16),
+            solver._zeros_acc(tables["user_factors"].shape[0], 4),
+            {k: first[v] for k, v in chunk.items()}).as_text()
+        return text, 2, jax.tree.map(np.asarray, (tables, metrics))
+    trainer, _ = online_mf(mesh, MFConfig(num_users=57, num_items=31,
+                                          rank=4), max_steps_per_call=4)
+    plan = DeviceEpochPlan(DeviceDataset(mesh, data), num_workers=2,
+                           local_batch=16, route_key="user", seed=3)
+    tables, ls = trainer.init_state(jax.random.key(0))
+    T = int(plan.steps_per_epoch)
+    if entry == "fit_stream":
+        def chunks():
+            return device_epoch_chunks(
+                plan.dataset, num_workers=2, local_batch=16,
+                steps_per_chunk=4, plan=plan)
+
+        text = trainer.lowered_chunk_text(
+            jax.tree.map(np.asarray, next(chunks())))
+        out = trainer.fit_stream(tables, ls, chunks(), key)
+        return text, -(-T // 4), jax.tree.map(np.asarray, out)
+    if entry == "run_indexed":
+        from fps_tpu.parallel.mesh import key_to_replicated
+
+        text = trainer._get_indexed_fn(plan, "sync").lower(
+            tables, ls, plan.epoch_args(0), np.int32(0),
+            key_to_replicated(key, mesh)).as_text()
+        out = trainer.run_indexed(tables, ls, plan, key, epochs=2)
+        return text, 2, jax.tree.map(np.asarray, out)
+    text = trainer.lowered_megastep_text(plan, chunks_per_dispatch=2)
+    out = trainer.run_megastep(tables, ls, plan, key, chunks_per_dispatch=2)
+    return text, -(-(-(-T // 4)) // 2), jax.tree.map(np.asarray, out)
+
+
+@pytest.mark.parametrize("entry", ["run_indexed", "fit_stream",
+                                   "run_megastep", "als.half_epoch"])
+def test_each_entry_point_queues_one_device_span_a_unit(devices8, entry):
+    """ISSUE 38: under a recorder every unit an entry point queues (an
+    epoch, a chunk, a megastep, a sweep) comes back as one ``device.<entry>``
+    span carrying the ``call`` index of the root host span that queued it
+    and that span as its parent; without a recorder nothing of the watcher
+    runs; the program's lowered text is the same either way."""
+    from fps_tpu.utils.datasets import synthetic_ratings
+
+    mesh = make_ps_mesh(num_shards=2, num_data=1, devices=devices8[:2])
+    data = synthetic_ratings(57, 31, 600, seed=0)
+    data["weight"] = np.ones(len(data["rating"]), np.float32)
+
+    text_off, units, out_off = _entry_point_run(entry, mesh, data)
+    assert not _watcher_threads()
+    sink = obs.MemorySink()
+    with obs_events.default_recorder(obs.Recorder(sinks=[sink])):
+        text_on, _, out_on = _entry_point_run(entry, mesh, data)
+    assert text_on == text_off
+    jax.tree.map(np.testing.assert_array_equal, out_off, out_on)
+    assert not _watcher_threads()
+    spans = _device_spans(sink)
+    assert {e["span"] for e in spans} == {"device." + entry}
+    assert len(spans) == units
+    roots = {e["call"]: e for e in sink.events("span")
+             if e["span"] == entry}
+    assert len(roots) == (2 if entry == "als.half_epoch" else 1)
+    for e in spans:
+        assert e["parent_id"] == roots[e["call"]]["span_id"]
+        assert e["t_enqueued"] <= e["t0"] <= e["t1"]
+        assert e["t0"] - e["t_enqueued"] == pytest.approx(e["wait_s"])
+        assert e["starved_s"] >= 0 and e["steps"] > 0
+    # In queue order, each starting no earlier than the one before ended.
+    assert all(a["t1"] <= b["t0"] for a, b in zip(spans, spans[1:]))
+    if entry == "als.half_epoch":
+        assert [e["solve"] for e in spans] == ["user", "item"]
 
 
 # ---------------------------------------------------------------------------

@@ -165,6 +165,51 @@ def test_obs_report_raw_speed_sections(tmp_path):
         "max_s": None}
 
 
+def test_obs_report_device_section(tmp_path):
+    """ISSUE 38: the device's time from inside. The ``device.<entry>``
+    spans (one a unit queued) fold into a ``device`` section per entry
+    point; a span in both the event log and the journal counts once, and
+    the idle time before the first unit is not starved time."""
+    report = _load_report()
+    d = str(tmp_path)
+
+    def span(of, t0, t1, t_enq, starved, in_flight, **kw):
+        return {"kind": "event", "t": t1, "event": "span",
+                "span": "device." + of, "t0": t0, "t1": t1,
+                "t_enqueued": t_enq, "wait_s": t0 - t_enq,
+                "starved_s": starved, "in_flight": in_flight, **kw}
+
+    sweeps = [span("als.half_epoch", 100.0, 107.0, 100.0, 30.0, 0,
+                   solve="user"),
+              span("als.half_epoch", 107.0, 110.5, 100.5, 0.0, 1,
+                   solve="item"),
+              # The host came back late: 0.5 s with nothing queued.
+              span("als.half_epoch", 111.0, 118.0, 111.0, 0.5, 0,
+                   solve="user")]
+    epochs = [span("run_indexed", 10.0, 12.0, 10.0, 0.0, 0, epoch=0)]
+    host = {"kind": "event", "t": 1.0, "event": "span", "span": "enqueue",
+            "t0": 0.0, "t1": 1.0}
+    with open(os.path.join(d, "events-p0.jsonl"), "w") as f:
+        for rec in sweeps + epochs + [host]:
+            f.write(json.dumps(rec) + "\n")
+    with open(os.path.join(d, "journal-p0.jsonl"), "w") as f:
+        for rec in sweeps:  # the same records again: folded once
+            f.write(json.dumps(rec) + "\n")
+    dev = report.render_digest(d)["device"]
+    assert set(dev) == {"als.half_epoch", "run_indexed"}
+    assert dev["als.half_epoch"] == {
+        "units": 3, "device_s": 17.5, "starved_s": 0.5,
+        "starved_share": round(0.5 / 18.0, 6), "wait_median_s": 0.0,
+        "in_flight_max": 1}
+    assert dev["run_indexed"]["units"] == 1
+    assert dev["run_indexed"]["starved_share"] == 0.0
+    # A run that recorded no device span has the section, empty.
+    os.remove(os.path.join(d, "journal-p0.jsonl"))
+    with open(os.path.join(d, "events-p0.jsonl"), "w") as f:
+        f.write(json.dumps(host) + "\n")
+    assert report.render_digest(d)["device"] == {}
+
+
 def test_obs_report_recovery_slo_breach(tmp_path):
     """--recovery-slo-s turns a late paired restart into a
     recovery_slo_breach incident and annotates the recovery section;

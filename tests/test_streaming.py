@@ -155,28 +155,44 @@ def test_combinators(devices8):
     )
 
 
-def test_throughput_hook(devices8):
+def test_every_chunk_of_a_stream_is_a_device_span(devices8):
+    """What an ``on_chunk`` stopwatch measured from outside (and forced a
+    host sync a chunk to measure), the program stamps from inside: every
+    chunk is a ``device.fit_stream`` span under the journal's chunk index,
+    whether the loop reads the chunk back or not."""
     import jax
 
+    from fps_tpu import obs
     from fps_tpu.core.driver import num_workers_of
     from fps_tpu.models.matrix_factorization import MFConfig, online_mf
     from fps_tpu.parallel.mesh import make_ps_mesh
     from fps_tpu.utils.datasets import synthetic_ratings
-    from fps_tpu.utils.profiling import Throughput
 
     mesh = make_ps_mesh(num_shards=2, num_data=1, devices=devices8[:2])
     W = num_workers_of(mesh)
     trainer, _ = online_mf(mesh, MFConfig(16, 12, rank=4), donate=False)
     data = synthetic_ratings(16, 12, 512, seed=4)
-    chunks = epoch_chunks(data, num_workers=W, local_batch=8,
-                          steps_per_chunk=2, route_key="user")
-    tables, ls = trainer.init_state(jax.random.key(0))
-    tp = Throughput()
-    trainer.fit_stream(tables, ls, chunks, jax.random.key(1), on_chunk=tp)
-    s = tp.summary()
-    assert s["chunks"] >= 2
-    assert s["examples"] == 512.0
-    assert s["examples_per_sec"] > 0
+
+    def run(on_chunk):
+        sink = obs.MemorySink()
+        rec = obs.Recorder(sinks=[sink])
+        tables, ls = trainer.init_state(jax.random.key(0))
+        trainer.fit_stream(
+            tables, ls, epoch_chunks(data, num_workers=W, local_batch=8,
+                                     steps_per_chunk=2, route_key="user"),
+            jax.random.key(1), on_chunk=on_chunk, recorder=rec)
+        rec.close()  # drains the watcher: the last chunk's span is in
+        spans = [e for e in sink.events("span")
+                 if e["span"] == "device.fit_stream"]
+        return sink.events("chunk"), spans
+
+    seen = []
+    for on_chunk in (lambda step, metrics: seen.append(step), None):
+        chunks, spans = run(on_chunk)
+        assert len(chunks) == len(spans) == len(seen) >= 2
+        assert [e["chunk"] for e in spans] == [e["index"] for e in chunks]
+        assert all(e["steps"] == 2 for e in spans)
+        assert all(a["t1"] <= b["t0"] for a, b in zip(spans, spans[1:]))
 
 
 def test_trace_writes_profile(tmp_path, devices8):

@@ -10,6 +10,12 @@ join on ``run_id``) and prints a single JSON digest:
   (prefetch / ingest / place / dispatch / host_sync / checkpoint /
   callback — ``prefetch`` is the background pipeline's worker-thread
   time, i.e. host work OVERLAPPED with the phases beside it);
+* **device** — the device's time from inside (the ``device.<entry>``
+  spans of ``fps_tpu.obs.timing.watch_device``), per entry point: units
+  (epochs, chunks, megasteps, ALS sweeps) completed, the seconds the
+  device ran them, the share of their extent it had nothing of the
+  program's queued (``starved_share``: the host was late), the median
+  time a unit waited for the device, and the most units in flight;
 * **host pipeline** — chunks prefetched and the queue-depth gauge's
   last/max (the gauge samples after every put/get, so with any traffic
   the max is >= 1; a max STUCK at 1 means the driver drained each chunk
@@ -132,7 +138,7 @@ REQUIRED_FIELDS = (
     "schema", "obs_dir", "run_ids", "processes", "chunks", "epochs",
     "steps", "examples", "phase_seconds", "health", "incidents",
     "checkpoint", "checkpoint_saves", "quarantined", "wall_span_s",
-    "prefetch",
+    "prefetch", "device",
     "hot_tier", "megastep", "tiering", "source_stalls", "analysis",
     "serve", "pod", "net", "recovery",
 )
@@ -159,6 +165,33 @@ def _quantile(sorted_vals: list, q: float):
     if not n:
         return None
     return sorted_vals[min(n - 1, int(q * (n - 1) + 0.5))]
+
+
+def _device_section(spans: dict) -> dict:
+    """Per entry point (``of``), from its ``device.<of>`` span events:
+    how long the device ran the units the program queued, and how long it
+    had nothing of them queued. A unit's starved time lies before its
+    ``t_enqueued``; only what lies inside the extent (first ``t0`` to last
+    ``t1``) counts, so the idle time before a run's first unit does not."""
+    out = {}
+    for of, evs in sorted(spans.items()):
+        first = min(e["t0"] for e in evs)
+        extent = max(e["t1"] for e in evs) - first
+        starved = sum(
+            max(0.0, e["t_enqueued"]
+                - max(first, e["t_enqueued"] - e.get("starved_s", 0.0)))
+            for e in evs)
+        waits = sorted(e.get("wait_s", 0.0) for e in evs)
+        out[of] = {
+            "units": len(evs),
+            "device_s": round(sum(e["t1"] - e["t0"] for e in evs), 6),
+            "starved_s": round(starved, 6),
+            "starved_share": (round(starved / extent, 6)
+                              if extent > 0 else None),
+            "wait_median_s": round(_quantile(waits, 0.5), 6),
+            "in_flight_max": max(int(e.get("in_flight", 0)) for e in evs),
+        }
+    return out
 
 
 def _read_jsonl(path: str):
@@ -205,6 +238,7 @@ def render_digest(obs_dir: str, *, recovery_slo_s: float | None = None) -> dict:
     }
     swap_directions: dict[str, int] = collections.defaultdict(int)
     phases: dict[str, dict] = {}
+    device_spans: dict[str, list] = collections.defaultdict(list)
     health: dict[str, dict] = {}
     incidents: dict[str, list] = {k: [] for k in _INCIDENT_EVENTS}
     run_ids: set[str] = set()
@@ -242,6 +276,10 @@ def render_digest(obs_dir: str, *, recovery_slo_s: float | None = None) -> dict:
                 {k: v for k, v in rec.items() if k != "kind"})
         if et in ("chunk", "epoch") and rec.get("quarantined"):
             quarantined.append(rec.get("index"))
+        if (et == "span" and str(rec.get("span", "")).startswith("device.")
+                and all(isinstance(rec.get(k), (int, float))
+                        for k in ("t0", "t1", "t_enqueued"))):
+            device_spans[rec["span"][len("device."):]].append(rec)
         if (et in ("attempt_first_signal", "attempt_end")
                 and rec.get("t") is not None
                 and rec.get("attempt") is not None):
@@ -362,6 +400,10 @@ def render_digest(obs_dir: str, *, recovery_slo_s: float | None = None) -> dict:
         "steps": int(counters.get("driver.steps", 0)),
         "examples": counters.get("driver.examples", 0.0),
         "phase_seconds": dict(sorted(phases.items())),
+        # The device's time, from inside (obs.timing.watch_device): the
+        # phases above time the host QUEUEING; these say how long the
+        # device ran what was queued, and whether the host kept it fed.
+        "device": _device_section(device_spans),
         # Host pipeline (fps_tpu.core.prefetch): the 'prefetch' entry in
         # phase_seconds is this worker's time, overlapped with the rest.
         "prefetch": {
