@@ -19,12 +19,30 @@ TPU-native decomposition (everything static-shape, jit-compiled once):
 1. **Gramian** — each shard computes ``local_block^T @ local_block`` on its
    ``(rps, k)`` rows (MXU matmul) and ``psum``s over the shard axis.
    Padding rows are zeroed first via an on-device validity mask.
-2. **Accumulate** — stream interaction chunks through a scan: collective
-   :func:`fps_tpu.core.store.pull` of the fixed side's rows, form per-example
-   ``alpha*r * y y^T`` (k*k) and ``(1+alpha*r) * y`` (k) blocks, collective
-   :func:`~fps_tpu.core.store.push` into sharded accumulator tables keyed by
-   the solved side's id. iALS thus *reuses the PS fabric*: the normal
-   equations are just another sharded table being pushed to.
+2. **Accumulate** — one program a chunk of interactions, two parts. A
+   narrow scan in the plan's step order: collective
+   :func:`fps_tpu.core.store.pull` of both sides' rows for the step's
+   ``n`` and ``loss``, nothing pushed. Then the chunk GROUPED by the
+   solved side's id on the device: the worker's live ratings sorted by
+   that id (a stable ``lax.sort``: an id's ratings stay in the plan's
+   order) and, a block of :data:`RUN_BLOCK` ratings at a time, the fixed
+   side's rows pulled in sorted order, every rating's addend formed
+   (``(alpha r w y) y^T``, k*k, and ``(1 + alpha r) w y``, k) and a
+   kernel (:func:`_run_sums`) adding an id's addends ONE AT A TIME in
+   float32 to a running sum it keeps on the chip, which it writes out as
+   ONE row where the id's run ends. The finished rows go through
+   collective :func:`~fps_tpu.core.store.push` into sharded accumulator
+   tables keyed by the solved side's id, so an id that recurs in the next
+   chunk or is rated on another worker is summed by the store's own
+   rules. iALS thus *reuses the PS fabric*: the normal equations are just
+   another sharded table being pushed to, one 16 KB row an ID a chunk at
+   rank 64 where it was one a rating (PR 41). The sums are chains in the
+   plan's order because that is the sum the benchmark's reference forms
+   and float32 makes the order part of the answer (the chain of a movie's
+   28,000 addends stands 8e-6 of its largest entry from the exact sum;
+   any other grouping, a matrix product a segment among them, stands
+   nearer the exact sum and as far from the chain:
+   ``tools/ials_sum_order.py``).
 3. **Solve** — each shard solves its own ``(rps, k, k)`` batched SPD systems
    locally by a Cholesky factorisation and two triangular solves (the
    left-hand side is the Gramian plus a non-negative combination of outer
@@ -32,9 +50,9 @@ TPU-native decomposition (everything static-shape, jit-compiled once):
    time (:data:`SOLVE_BLOCK_IDS`), no communication.
 
 Float32 means float32: the Gramian's contraction carries
-``precision=HIGHEST`` (the TPU's default would run it in bfloat16 passes:
-7e-5 on the solved tables where this reads 1e-6; chip runs, PR 35), and
-XLA's Cholesky and triangular-solve kernels are float32 throughout (5e-7
+``precision=HIGHEST`` (the TPU's default would run it in bfloat16
+passes: 7e-5 on the solved tables where this reads 1e-6; chip runs, PR 35),
+the sums are float32 additions on the vector unit, and XLA's Cholesky and triangular-solve kernels are float32 throughout (5e-7
 against a float64 solve). A half-epoch only QUEUES device work and returns
 what it did: per
 accumulate step ``n`` (the live interactions) and ``loss``, the observed
@@ -42,13 +60,14 @@ term ``sum c (1 - x_u . y_i)^2`` under the tables the sweep READ (both
 sides as the sweep found them), as device arrays nothing on the host waits
 for.
 
-Names (``docs/observability.md``): the accumulate body opens the step
-scopes a ``Trainer`` opens (``fps.pull`` / ``fps.compute`` / ``fps.push`` /
-``fps.metrics``); what runs once a sweep is ``als.gram``, ``als.zeros`` and
+Names (``docs/observability.md``): the accumulate program's scan body
+opens ``fps.pull`` / ``fps.compute`` / ``fps.metrics`` as a ``Trainer``'s
+step does; everything that forms the pushed sums (the sort, the block
+loop's pull, addends, kernel and pushes) runs under ``fps.push``; what runs once a sweep is ``als.gram``, ``als.zeros`` and
 ``als.solve`` (no ``fps.`` prefix: a reader counts steps by the ops under
 it); host spans ``als.half_epoch`` and inside it ``als.gram``,
 ``als.accumulate`` (one a chunk queued), ``als.solve``; the route log gets
-one ``als.accumulate`` and one ``als.solve`` a traced program.
+one ``als.grouped`` and one ``als.solve`` a traced program.
 
 The user and item factor tables share the owner-major-cyclic layout of
 :mod:`fps_tpu.core.store`, so accumulators align row-for-row with the factor
@@ -58,12 +77,15 @@ table being solved and the solve phase is purely local.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Iterable, Iterator
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from fps_tpu import ops
@@ -93,6 +115,147 @@ ITEM_TABLE = "item_factors"
 # 2.78 s); in blocks of 8,192 it needs 0.8 GB and takes 1.15 s (16,384:
 # 1.22 s; 32,768: 1.36 s; chip runs, PR 35).
 SOLVE_BLOCK_IDS = 8192
+
+# The accumulate program sums an id's ratings ONE AT A TIME, in the plan's
+# order, in float32: the order the benchmark's reference adds them in (a
+# float32 chain of 28,000 such addends stands 8e-6 of its largest entry
+# from the exact sum and as far from any other grouping of the same
+# addends, and the solve passes that on: ``tools/ials_sum_order.py``), in
+# a kernel that keeps the running sum on the chip. It takes the sorted
+# chunk a block of ratings at a time (their addends are formed by XLA and
+# kept for the block: no more than RUN_BLOCK ratings, whose ids and bits
+# sit in SMEM, nor than RUN_BLOCK_BYTES of addends), a tile of
+# RUN_TILE_BYTES a grid step in VMEM (twice, for the pipeline), and
+# pushes the finished sums RUN_PUSH rows at a time. At rank 64 an addend
+# is 20 KB: 2,048 ratings a block, 256 a tile. Read on a v5e at
+# MovieLens-20M's shape, seconds a user sweep + an item sweep of 8.4 M
+# ratings each, the solves' 1.15 + 0.22 inside (probe runs, PR 41):
+# blocks of 2,048 pushing 32 rows at a time 1.763 + 0.906; 64 rows
+# 1.776 + 0.920; 128 rows 1.884 + 0.949; blocks of 4,096 by 32 / 64 /
+# 128 rows 1.737 + 0.971 / 1.754 + 0.982 / 1.768 + 1.017; of 8,192 by
+# 128 1.908 + 0.973; of 1,024 by 32 1.752 + 1.224. A block's addends
+# that fit VMEM are kept there (42 MB at 2,048: 0.67 s an epoch to form
+# and read them against 1.01 through HBM at 8,192); smaller blocks push
+# more often, each push a whole RUN_PUSH rows however few sums the block
+# finished.
+RUN_BLOCK = 2048
+RUN_BLOCK_BYTES = 160 << 20
+RUN_TILE_BYTES = 5 << 20
+RUN_PUSH = 32
+
+
+def _sum_layout(k: int) -> tuple[int, int, int]:
+    """How one id's sums lie in rows of 128 lanes: ``(kp, g, rows)``. The
+    rank is padded to ``kp``, a power of two up to 128 or a multiple of
+    128 beyond; the ``kp x kp`` left side fills the first ``g`` rows
+    row-major; the right side's ``kp`` start the row after; ``rows`` is
+    the whole, in ``(8, 128)`` float32 tiles (64, 32 and 40 at rank 64)."""
+    kp = (1 << (k - 1).bit_length()) if k <= 128 else -(-k // 128) * 128
+    g = -(-kp * kp // 128)
+    return kp, g, -(-(g + -(-kp // 128)) // 8) * 8
+
+
+def _addends(ya: Array, y: Array, by: Array) -> Array:
+    """``(n, rows, 128)``: every rating's addend to its id's sums in
+    :func:`_sum_layout`: ``ya y^T`` and ``by``, from ``(n, k)`` each.
+    Written as broadcasts along lanes and lane rows (nothing reshapes
+    the lanes), so that XLA forms it in one pass in the layout the kernel
+    reads."""
+    n, k = y.shape
+    kp, g, rows = _sum_layout(k)
+    ya, y, by = (jnp.pad(x, ((0, 0), (0, kp - k))) for x in (ya, y, by))
+    if kp <= 128:
+        m = 128 // kp  # rows of the left side a lane row holds
+        ya = jnp.pad(ya, ((0, 0), (0, rows * m - kp)))
+        group = lax.broadcasted_iota(jnp.int32, (1, 1, 128), 2) // kp
+        col = ya[:, 0::m, None]
+        for h in range(1, m):  # a select a group: no reshape of lanes
+            col = jnp.where(group == h, ya[:, h::m, None], col)
+        row = jnp.tile(y, (1, m))[:, None, :]
+        by = jnp.pad(by, ((0, 0), (0, 128 - kp)))
+    else:
+        q = kp // 128  # lane rows a row of the left side fills
+        col = jnp.pad(jnp.repeat(ya, q, axis=1),
+                      ((0, 0), (0, rows - g)))[:, :, None]
+        row = jnp.tile(y.reshape(n, q, 128), (1, rows // q, 1))
+    by = by.reshape(n, -1, 128)
+    # The left side's rows past ``g`` are zero; the right side lies there.
+    return col * row + jnp.pad(
+        by, ((0, 0), (g, rows - g - by.shape[1]), (0, 0)))
+
+
+def _run_sums_kernel(code_ref, key_ref, add_ref, carry_ref, rows_ref, ids_ref,
+                     carry_out_ref, acc, stage, sem, count, *, tile):
+    """One grid step: ``tile`` ratings of the sorted order added to the
+    running sum one after another. ``code`` bit 0: the rating is its id's
+    first here (the sum restarts at zero); bit 1: its last (the sum is
+    copied out as the next row of ``rows_ref``, in HBM, and its id as
+    the next of ``ids_ref``)."""
+    i = pl.program_id(0)
+
+    @pl.when(i == 0)
+    def _():
+        acc[...] = carry_ref[...]
+        count[0] = 0
+
+    def add_one(t, a):
+        c = code_ref[i * tile + t]
+        a = jnp.where((c & 1) > 0, 0.0, a) + add_ref[t]
+
+        @pl.when((c & 2) > 0)
+        def _():
+            stage[...] = a
+            copy = pltpu.make_async_copy(stage, rows_ref.at[count[0]], sem)
+            copy.start()
+            copy.wait()
+            ids_ref[count[0]] = key_ref[i * tile + t]
+            count[0] = count[0] + 1
+
+        return a
+
+    acc[...] = lax.fori_loop(0, tile, add_one, acc[...])
+
+    @pl.when(i == pl.num_programs(0) - 1)
+    def _():
+        carry_out_ref[...] = acc[...]
+
+
+def _run_sums(code: Array, key: Array, add: Array, carry: Array, tile: int):
+    """The sums of the id runs that END inside a block of sorted ratings.
+
+    ``add (n, R, 128)``: a rating's addend; ``key (n,)``: its id;
+    ``code (n,)``: its first / last bits; ``carry (R, 128)``: the sum of
+    the run the block opens inside, from the block before. Returns
+    ``(rows, ids, carry)``: ``rows (n + RUN_PUSH, R, 128)`` and ``ids``
+    as long, whose
+    first entries are the finished runs' sums and ids in order (as many
+    as ``code`` has last bits; the rest is never written), and the open
+    run's sum. Every sum is a chain of float32 additions in the
+    order given, from zero. The kernel is compiled where ``fps_tpu.ops``
+    compiles its own (on the TPU) and interpreted elsewhere."""
+    n, rows, _ = add.shape
+    out = n + RUN_PUSH  # whole pushes can be cut from it
+    tile_spec = pl.BlockSpec((tile, rows, 128), lambda i, *_: (i, 0, 0))
+    sum_spec = pl.BlockSpec((rows, 128), lambda i, *_: (0, 0))
+    return pl.pallas_call(
+        functools.partial(_run_sums_kernel, tile=tile),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(n // tile,),
+            in_specs=[tile_spec, sum_spec],
+            out_specs=[pl.BlockSpec(memory_space=pl.ANY),
+                       pl.BlockSpec(memory_space=pltpu.SMEM), sum_spec],
+            scratch_shapes=[pltpu.VMEM((rows, 128), add.dtype),
+                            pltpu.VMEM((rows, 128), add.dtype),
+                            pltpu.SemaphoreType.DMA(()),
+                            pltpu.SMEM((1,), jnp.int32)]),
+        out_shape=[jax.ShapeDtypeStruct((out, rows, 128), add.dtype),
+                   jax.ShapeDtypeStruct((out,), jnp.int32),
+                   jax.ShapeDtypeStruct(carry.shape, add.dtype)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",), has_side_effects=True),
+        name="als_run_sums",
+        interpret=ops._use_pallas() != (True, False),
+    )(code, key, add, carry)
 
 
 @dataclasses.dataclass
@@ -201,21 +364,36 @@ class IALSSolver:
 
         Chunk leaves are (T, B) with B split over ALL devices (the data AND
         shard axes): ``solve_ids``, ``fixed_ids``, ``rating``, ``weight``.
-        With a data axis, pushes gather across it so the replicated
+        Each worker sorts its own slice of the chunk by the solved side's
+        id and pushes ONE pre-summed row for every id in it; with
+        a data axis the pushes gather across it so the replicated
         accumulators fold every worker's contributions exactly once.
-        Returns ``(A, b, metrics)``: per step ``n`` and ``loss`` summed
-        over all workers, ``(T,)`` each, under the two tables as they came
-        in (``solve_table`` is read, never written, by this program).
+        Returns ``(A, b, metrics)``:
+        per step ``n`` and ``loss`` summed over all workers, ``(T,)``
+        each, under the two tables as they came in (``solve_table`` is
+        read, never written, by this program).
         """
         cfg = self.cfg
         k = cfg.rank
+        num_ids = cfg.num_users if solve == "user" else cfg.num_items
+        data_axis = DATA_AXIS if self.num_data > 1 else None
 
         def device_fn(fixed_table, solve_table, A, b, chunk):
-            ops.log_route("als", "accumulate", A.shape[0], k * k,
-                          chunk["weight"].shape[1] * self.num_workers, solve)
+            T, B = chunk["weight"].shape
+            kp, g, R = _sum_layout(k)
+            # The worker's ratings, padded to whole blocks of whole tiles.
+            tile = max(8, RUN_TILE_BYTES // (R * 512) // 8 * 8)
+            blk = max(1, min(RUN_BLOCK, RUN_BLOCK_BYTES // (R * 512),
+                             -(-T * B // tile) * tile) // tile) * tile
+            blocks = -(-T * B // blk)
+            N = blocks * blk
+            # A run is an id's ratings in the sorted chunk: no more runs
+            # than ids, nor than ratings.
+            runs = min(num_ids, T * B)
+            ops.log_route("als", "grouped", A.shape[0], k * k,
+                          runs * self.num_workers, solve)
 
-            def body(carry, xs):
-                A, b = carry
+            def body(_, xs):
                 solve_ids = xs["solve_ids"].astype(jnp.int32)
                 fixed_ids = xs["fixed_ids"].astype(jnp.int32)
                 r = xs["rating"].astype(cfg.dtype)
@@ -228,28 +406,96 @@ class IALSSolver:
                              num_shards=self.num_shards)
                 with jax.named_scope("fps.compute"):
                     c = 1.0 + cfg.alpha * r  # confidence
-                    cr = cfg.alpha * r * w   # confidence minus 1, masked
-                    outer = (cr[:, None, None] * y[:, :, None]
-                             * y[:, None, :])
-                    vec = (c * w)[:, None] * y
                     miss = 1.0 - jnp.sum(x * y, axis=-1)
                     out = {"n": jnp.sum(w.astype(jnp.float32)),
                            "loss": jnp.sum((w * c * miss * miss)
                                            .astype(jnp.float32))}
-                    ids = jnp.where(w > 0, solve_ids, -1)
-                data_axis = DATA_AXIS if self.num_data > 1 else None
-                with jax.named_scope("fps.push"):
-                    A = push(A, ids, outer.reshape(-1, k * k),
-                             num_shards=self.num_shards, data_axis=data_axis)
-                    b = push(b, ids, vec,
-                             num_shards=self.num_shards, data_axis=data_axis)
                 with jax.named_scope("fps.metrics"):
                     out = jax.tree.map(
                         lambda v: lax.psum(lax.psum(v, SHARD_AXIS),
                                            DATA_AXIS), out)
-                return (A, b), out
+                return None, out
 
-            (A, b), metrics = lax.scan(body, (A, b), chunk)
+            _, metrics = lax.scan(body, None, chunk)
+
+            with jax.named_scope("fps.push"):
+                def column(x, dtype, fill):
+                    return jnp.concatenate([
+                        x.astype(dtype).reshape(T * B),
+                        jnp.full((N - T * B,), fill, dtype)])
+
+                r = column(chunk["rating"], cfg.dtype, 0)
+                w = column(chunk["weight"], cfg.dtype, 0)
+                # Group: the live ratings sorted by the solved side's id
+                # (weight-0 slots last, under the key ``num_ids``), an
+                # id's own in the plan's order (the sort is stable), each
+                # carrying the fixed side's id, its confidence minus 1 and
+                # its confidence, both masked.
+                key, fixed, cr, cw = lax.sort(
+                    (jnp.where(w > 0, column(chunk["solve_ids"], jnp.int32,
+                                             num_ids), num_ids),
+                     column(chunk["fixed_ids"], jnp.int32, 0),
+                     cfg.alpha * r * w, (1.0 + cfg.alpha * r) * w),
+                    num_keys=1, is_stable=True)
+                # A run's first and last rating (the kernel's bits) and
+                # how many runs end inside each block.
+                edge = jnp.full((1,), -1, jnp.int32)
+                first = key != jnp.concatenate([edge, key[:-1]])
+                last = (key != jnp.concatenate([key[1:], edge])) & (
+                    key < num_ids)
+                code = first.astype(jnp.int32) + 2 * last.astype(jnp.int32)
+                ended = jnp.sum(last.reshape(blocks, blk), axis=1,
+                                dtype=jnp.int32)
+                # Every worker pushes as often as the one with most to
+                # push (the pulls and pushes are collectives).
+                pushes = -(-lax.pmax(lax.pmax(ended, SHARD_AXIS),
+                                     DATA_AXIS) // RUN_PUSH)
+
+                def block(i, carry):
+                    A, b, open_sum = carry
+                    at = i * blk
+                    y = pull(fixed_table,
+                             lax.dynamic_slice(fixed, (at,), (blk,)),
+                             num_shards=self.num_shards)
+                    # A rating's addend: ``(alpha r w y) y^T`` and
+                    # ``(1 + alpha r) w y``.
+                    add = _addends(
+                        lax.dynamic_slice(cr, (at,), (blk,))[:, None] * y,
+                        y, lax.dynamic_slice(cw, (at,), (blk,))[:, None] * y)
+                    rows, run_ids, open_sum = _run_sums(
+                        lax.dynamic_slice(code, (at,), (blk,)),
+                        lax.dynamic_slice(key, (at,), (blk,)), add,
+                        open_sum, tile)
+
+                    def push_rows(j, Ab):
+                        A, b = Ab
+                        mine = (j * RUN_PUSH + jnp.arange(RUN_PUSH)
+                                < ended[i])
+                        # Rows past the block's runs were never written.
+                        ids = jnp.where(mine, lax.dynamic_slice(
+                            run_ids, (j * RUN_PUSH,), (RUN_PUSH,)), -1)
+                        flat = jnp.where(
+                            mine[:, None],
+                            lax.dynamic_slice(
+                                rows, (j * RUN_PUSH, 0, 0),
+                                (RUN_PUSH, R, 128)).reshape(RUN_PUSH, -1),
+                            0.0)
+                        A = push(A, ids,
+                                 flat[:, :kp * kp].reshape(-1, kp, kp)
+                                 [:, :k, :k].reshape(-1, k * k),
+                                 num_shards=self.num_shards,
+                                 data_axis=data_axis)
+                        b = push(b, ids, flat[:, g * 128:g * 128 + k],
+                                 num_shards=self.num_shards,
+                                 data_axis=data_axis)
+                        return A, b
+
+                    A, b = lax.fori_loop(0, pushes[i], push_rows, (A, b))
+                    return A, b, open_sum
+
+                A, b, _ = lax.fori_loop(
+                    0, blocks, block,
+                    (A, b, jnp.zeros((R, 128), cfg.dtype)))
             return A, b, metrics
 
         def run(fixed_table, solve_table, A, b, chunk):

@@ -128,8 +128,10 @@ def test_committed_benchmark_lists_the_four_metrics_in_the_cells_named():
         "ials-ml20m.sweeps"]
     for n in ("solver.user_sweep_device_s", "solver.item_sweep_device_s"):
         assert listed[n]["workloads"] == ["ials-ml20m.sweeps"]
-    # Appended: the entries the benchmark had stand where they stood.
-    assert [m["name"] for m in bench["per_layer"]][-4:] == [
+    # Appended together, in this order (later PRs append after them).
+    names = [m["name"] for m in bench["per_layer"]]
+    at = names.index("driver.call_device_ms")
+    assert names[at:at + 4] == [
         "driver.call_device_ms", "driver.starved_share",
         "solver.user_sweep_device_s", "solver.item_sweep_device_s"]
     for cell in [w["name"] for w in bench["workloads"]]:

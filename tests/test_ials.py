@@ -236,3 +236,163 @@ def test_cholesky_solve_matches_numpy_in_every_block(mods, devices8,
     want = np.linalg.solve(gram[None] + A + 0.3 * np.eye(k)[None],
                            b[:, :, None])[:, :, 0]
     np.testing.assert_allclose(np.asarray(got), want, rtol=2e-4, atol=2e-5)
+
+
+def _grouped_draw(L, nu, ni, seed):
+    """A chunk's worth of ratings skewed so that user 0 has ``2L + 1``
+    (its run of the sorted order crosses two blocks of ``L`` at least),
+    user 1 exactly ``L``, user 2 none and user 3 one, the rest drawn;
+    padding slots of weight 0 (carrying ids of their own, which must not
+    count) scattered through."""
+    rng = np.random.default_rng(seed)
+    users = np.concatenate([
+        np.zeros(2 * L + 1, np.int32), np.ones(L, np.int32),
+        np.full(1, 3, np.int32), rng.integers(4, nu, 11).astype(np.int32)])
+    n = len(users)
+    B = 8
+    T = -(-(n + 5) // B)
+    live = np.zeros(T * B, bool)
+    live[rng.permutation(T * B)[:n]] = True
+    cols = {"user": np.full(T * B, 2, np.int32),   # padding names user 2
+            "item": rng.integers(0, ni, T * B).astype(np.int32),
+            "rating": rng.uniform(0.5, 5.0, T * B).astype(np.float32),
+            "weight": live.astype(np.float32)}
+    cols["user"][live] = rng.permutation(users)
+    return {k: v.reshape(T, B) for k, v in cols.items()}
+
+
+@pytest.mark.parametrize("L,push,shards",
+                         [(8, 2, 1), (16, 1, 1), (16, 128, 1), (8, 2, 4)])
+def test_grouped_sums_are_an_ids_ratings_added_in_the_plans_order(
+        mods, devices8, monkeypatch, L, push, shards):
+    """The accumulate program's sums, an id at a time: on one worker the
+    float32 additions of the id's addends ``(alpha r y) y^T`` and
+    ``(1 + alpha r) y`` ONE AFTER ANOTHER in the order the chunk holds
+    them, bit for bit (the order the benchmark's reference adds them in);
+    on four the same sums to float32's rounding. An id whose run crosses
+    blocks of ``L`` ratings (tiles of 8), one of exactly a block, one
+    absent, padding slots, and more runs in a block than one push takes."""
+    jax, ials = mods["jax"], mods["ials"]
+    from fps_tpu.core.store import phys_to_id, rows_per_shard
+
+    monkeypatch.setattr(ials, "RUN_BLOCK", L)
+    monkeypatch.setattr(ials, "RUN_TILE_BYTES", 1)
+    monkeypatch.setattr(ials, "RUN_PUSH", push)
+    nu, ni, k, alpha = 11, 7, 5, 7.0
+    solver = _solver(mods, shards, nu, ni, k, alpha=alpha)
+    chunk = _grouped_draw(L, nu, ni, seed=L)
+    _, V = solver.factors()
+    f32 = np.float32
+    want_A = np.zeros((nu, k, k), f32)
+    want_b = np.zeros((nu, k), f32)
+    for u, i, r, w in zip(*(chunk[c].ravel() for c in
+                            ("user", "item", "rating", "weight"))):
+        if w > 0:
+            want_A[u] += ((f32(alpha) * r * w) * V[i])[:, None] * V[i][None]
+            want_b[u] += ((f32(1.0) + f32(alpha) * r) * w) * V[i]
+    assert want_A[2].any() == 0 and np.count_nonzero(
+        chunk["weight"] == 0) >= 5
+
+    rps = rows_per_shard(nu, shards)
+    tables = solver.store.tables
+    A, b, m = solver._accumulate_fn("user")(
+        tables[ials.ITEM_TABLE], tables[ials.USER_TABLE],
+        solver._zeros_acc(rps * shards, k * k),
+        solver._zeros_acc(rps * shards, k),
+        {"solve_ids": chunk["user"], "fixed_ids": chunk["item"],
+         "rating": chunk["rating"], "weight": chunk["weight"]})
+    ids = np.asarray(phys_to_id(np.arange(rps * shards), shards, rps))
+    real = ids < nu
+    got_A = np.zeros((nu, k, k), f32)
+    got_b = np.zeros((nu, k), f32)
+    got_A[ids[real]] = np.asarray(A)[real].reshape(-1, k, k)
+    got_b[ids[real]] = np.asarray(b)[real]
+    if shards == 1:
+        np.testing.assert_array_equal(got_A, want_A)
+        np.testing.assert_array_equal(got_b, want_b)
+    else:
+        np.testing.assert_allclose(got_A, want_A, rtol=1e-5, atol=1e-9)
+        np.testing.assert_allclose(got_b, want_b, rtol=1e-5, atol=1e-9)
+    assert not np.asarray(A)[~real].any()
+    np.testing.assert_array_equal(np.asarray(m["n"]),
+                                  chunk["weight"].sum(axis=1))
+
+
+@pytest.mark.parametrize("k", [1, 3, 8, 10, 16, 64, 128, 130])
+def test_addends_lie_as_the_sum_layout_says(mods, k):
+    """A rating's addend in rows of 128 lanes: the ``kp x kp`` left side
+    row-major from the first lane, the right side from row ``g``, zeros
+    elsewhere, at ranks that divide a lane row, that do not, and that
+    are wider than one."""
+    ials = mods["ials"]
+    rng = np.random.default_rng(k)
+    ya, y, by = (rng.normal(size=(5, k)).astype(np.float32)
+                 for _ in range(3))
+    kp, g, rows = ials._sum_layout(k)
+    assert kp >= k and rows % 8 == 0 and g * 128 >= kp * kp
+    out = np.asarray(ials._addends(*map(
+        mods["jax"].numpy.asarray, (ya, y, by))))
+    assert out.shape == (5, rows, 128)
+    flat = out.reshape(5, -1)
+    left = flat[:, :kp * kp].reshape(5, kp, kp)
+    np.testing.assert_array_equal(left[:, :k, :k],
+                                  ya[:, :, None] * y[:, None, :])
+    np.testing.assert_array_equal(flat[:, g * 128:g * 128 + k], by)
+    assert np.count_nonzero(flat) == np.count_nonzero(
+        left[:, :k, :k]) + np.count_nonzero(by)
+
+
+def test_one_chunk_and_several_chunks_solve_the_same_table(mods, devices8,
+                                                           monkeypatch):
+    """A sweep fed as ONE chunk and as several (a busy id's ratings then
+    recur from chunk to chunk, each chunk pushing its own sum of them)
+    forms the same equations: the accumulator is a table being pushed
+    to, whatever the chunking."""
+    ials = mods["ials"]
+    monkeypatch.setattr(ials, "RUN_BLOCK", 16)
+    monkeypatch.setattr(ials, "RUN_TILE_BYTES", 1)
+    monkeypatch.setattr(ials, "RUN_PUSH", 4)
+    nu, ni = 40, 12
+    data = mods["synthetic_implicit"](nu, ni, 9, rank=2, seed=4)
+    assert np.bincount(data["item"]).max() > 3 * 4
+
+    def run(steps_per_chunk):
+        solver = _solver(mods, 4, nu, ni, 6, alpha=8.0, reg=0.4)
+        for side in ("item", "user"):
+            m = solver.half_epoch(side, ials.interaction_chunks(
+                data, num_workers=4, local_batch=8,
+                steps_per_chunk=steps_per_chunk, seed=5))
+        return solver.factors(), m
+
+    (U_one, V_one), m_one = run(64)
+    (U_many, V_many), m_many = run(2)
+    assert m_one["n"].shape[0] == 64 and m_many["n"].shape[0] < 64
+    # The same equations summed in another order: float32 reassociation.
+    np.testing.assert_allclose(V_many, V_one, rtol=5e-4, atol=5e-5)
+    np.testing.assert_allclose(U_many, U_one, rtol=5e-4, atol=5e-5)
+
+
+def test_per_step_count_and_loss_follow_the_plans_order(mods, devices8):
+    """``n`` and ``loss`` step by step, in the order the chunks were fed,
+    under the tables the sweep found: the grouped sums move neither."""
+    ials = mods["ials"]
+    nu, ni, alpha = 30, 20, 6.0
+    solver = _solver(mods, 4, nu, ni, 4, alpha=alpha, reg=0.2)
+    data = mods["synthetic_implicit"](nu, ni, 10, rank=2, seed=8)
+
+    def chunks():
+        return ials.interaction_chunks(data, num_workers=4, local_batch=8,
+                                       steps_per_chunk=3, seed=2)
+
+    for side in ("user", "item"):
+        U, V = (t.astype(np.float64) for t in solver.factors())
+        n, loss = [], []
+        for c in chunks():
+            xy = np.sum(U[c["user"]] * V[c["item"]], axis=-1)
+            conf = 1.0 + alpha * c["rating"].astype(np.float64)
+            n.extend(c["weight"].sum(axis=1))
+            loss.extend((c["weight"] * conf * (1.0 - xy) ** 2).sum(axis=1))
+        m = solver.half_epoch(side, chunks())
+        np.testing.assert_array_equal(np.asarray(m["n"]), n)
+        np.testing.assert_allclose(np.asarray(m["loss"]), loss, rtol=2e-5)
+        assert min(n) == 0 < max(n)  # padding steps among them

@@ -83,7 +83,8 @@ def test_spec_validates_the_committed_benchmark_files():
     assert {m["name"] for m in cell["end_to_end"]} == {
         "setup_s", "examples_per_s"}
     assert {"solver.gram_ms_per_step", "solver.solve_ms_per_step",
-            "solver.accumulate_routes_in_program", "solver.half_epoch_ms",
+            "solver.accumulate_routes_in_program",
+            "solver.grouped_routes_in_program", "solver.half_epoch_ms",
             "kernel.xla_scatter_ms_per_step", "kernel.xla_gather_ms_per_step",
             "kernel.rowop_roofline", "device.peak_hbm_gb"} <= set(
         cell["readers"])
@@ -145,8 +146,8 @@ def test_cell_rehearsal_runs_the_runners_whole_path(n, seed):
     # One accumulate and one solve program a side, traced once each.
     routes = [(r.route, r.reason) for r in ops.routes_traced()
               if r.op == "als"]
-    assert routes == [("als.accumulate", "user"), ("als.solve", "cholesky"),
-                      ("als.accumulate", "item"), ("als.solve", "cholesky")]
+    assert routes == [("als.grouped", "user"), ("als.solve", "cholesky"),
+                      ("als.grouped", "item"), ("als.solve", "cholesky")]
     readings = next(e for e in events if e["event"] == "readings")
     # Every call reads every resident rating twice.
     assert readings["window_examples"] == 2 * 9001 * readings["n"]

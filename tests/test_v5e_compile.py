@@ -565,32 +565,51 @@ def test_ials_gramian_and_solve_are_float32_and_the_solve_fits(
 
 def test_ials_accumulate_scatters_in_place_under_the_step_scopes(
         ials_user_sweep):
-    """The accumulate program: the one op that makes an
-    ``f32[138493,4096]`` inside the loop is the wide push's scatter-add,
-    in place on the carry (the accumulator is aliased from argument to
-    result and nothing copies it); both pulls and both pushes take the
-    plain XLA routes; every step scope a reader selects by is in the
-    compiled text (``fps.metrics`` holds the cross-worker sums alone,
-    which one chip has none of)."""
+    """The accumulate program since PR 41 (``als.grouped``): no row a
+    RATING is pushed (no ``f32[16384,4096]``, no ``f32[16384,64,64]``);
+    the chunk is sorted STABLY under ``fps.push`` (an id's ratings stay
+    in the plan's order); a block of 2,048 ratings' addends is one fusion
+    of 40 lane rows a rating under ``fps.push/while/body`` and the Mosaic
+    kernel ``als_run_sums`` chains them in float32 (no contraction: the
+    program holds no ``convolution`` and nothing bfloat16); the one op
+    that makes an ``f32[138493,4096]`` is the finished sums' scatter-add,
+    32 rows a push, in place on the loops' carry (the accumulator is
+    aliased from argument to result and nothing copies it); the pulls
+    and the pushes take the plain XLA routes; every scope a reader
+    selects by is in the compiled text (``fps.metrics`` holds the
+    cross-worker sums alone, which one chip has none of)."""
     _, acc, _, routes = ials_user_sweep
+    # 64 steps of 16,384 ratings: no more runs of one id than users.
     assert routes == [
-        ("als.accumulate", 138_493, 4096, 16_384, "user"),
+        ("als.grouped", 138_493, 4096, 138_493, "user"),
         ("gather.xla", 26_744, 64, 16_384, "shape"),
         ("gather.xla", 138_493, 64, 16_384, "shape"),
-        ("scatter_add.xla", 138_493, 4096, 16_384, "shape"),
-        ("scatter_add.xla", 138_493, 64, 16_384, "shape")]
+        ("gather.xla", 26_744, 64, 2_048, "shape"),
+        ("scatter_add.xla", 138_493, 4096, 32, "shape"),
+        ("scatter_add.xla", 138_493, 64, 32, "shape")]
     mem = acc.memory_analysis()
     assert mem.alias_size_in_bytes >= 138_493 * 4096 * 4
     assert mem.temp_size_in_bytes < 1 << 30
     text = acc.as_text()
-    assert "bf16" not in text
+    assert "bf16" not in text and " convolution(" not in text
+    assert "f32[16384,4096]" not in text and "f32[16384,64,64]" not in text
+    sorts = [ln for ln in text.splitlines() if " sort(" in ln]
+    assert len(sorts) == 1 and "is_stable=true" in sorts[0] and (
+        "/fps.push/sort" in sorts[0]), sorts
+    kernels = [ln for ln in _top_level(text)
+               if 'custom_call_target="tpu_custom_call"' in ln]
+    assert len(kernels) == 1 and "f32[2048,40,128]" in kernels[0] and (
+        "/fps.push/while/body/" in kernels[0]
+        and "als_run_sums" in kernels[0]), kernels
     sized = [ln for ln in _top_level(text)
              if (m := re.search(r"= f32\[138493,4096\]\S* ([\w\-]+)\(", ln))
              and m.group(1) not in ("get-tuple-element", "parameter")]
-    assert len(sized) == 1 and " fusion(" in sized[0] and (
-        "/fps.push/fps.ops/scatter_add.xla/scatter-add" in sized[0]), sized
+    assert len(sized) == 1 and " fusion(" in sized[0] and re.search(
+        r"/fps\.push/while/body/(closed_call/)?while/body/fps\.ops/"
+        r"scatter_add\.xla/scatter-add", sized[0]), sized
     assert not [ln for ln in text.splitlines()
                 if re.search(r"= f32\[138493,4096\]\S* copy\(", ln)]
-    for scope in ("/fps.pull/fps.ops/gather.xla/", "/fps.compute/",
-                  "/fps.push/fps.ops/scatter_add.xla/"):
-        assert scope in text, scope
+    for scope in (r"/fps\.pull/fps\.ops/gather\.xla/", r"/fps\.compute/",
+                  r"/fps\.push/sort",
+                  r"/fps\.push/while/body/(closed_call/)?fps\.ops/gather\.xla/"):
+        assert re.search(scope, text), scope

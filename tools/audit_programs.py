@@ -226,14 +226,16 @@ BUDGETS: dict[str, dict] = {
     # Passive-aggressive shares logreg's route structure.
     "pa": dict(max_collectives=2, max_collective_bytes=3200,
                per_kind_max={"all_gather": 1, "all_to_all": 1}),
-    # iALS accumulate: the pulls of the fixed side's rows and, since
-    # PR 35, of the solved side's (the sweep's own loss), each an
-    # all_gather of ids and a reduce_scatter of rows, and the two
-    # gathered pushes' all_gathers of ids and rows feed the
-    # normal-equation fold (re-pinned in PR 36 with the builder repaired:
-    # 6 / 84,992 B until PR 35).
-    "ials": dict(max_collectives=8, max_collective_bytes=94208,
-                 per_kind_max={"all_gather": 6, "reduce_scatter": 2}),
+    # iALS accumulate (``als.grouped`` since PR 41): the scan's pulls of
+    # the fixed side's rows and of the solved side's (the sweep's own
+    # loss), each an all_gather of ids and a reduce_scatter of rows; the
+    # block loop's pull of the fixed side's rows in sorted order (one
+    # more of each) and its two gathered pushes' all_gathers of the
+    # finished sums' ids and rows. The bytes are a block's at the
+    # audit's scale (``ials.RUN_PUSH`` = 128 rows a push whatever the
+    # chunk), not a step's: 8 / 94,208 B while a row a rating was pushed.
+    "ials": dict(max_collectives=10, max_collective_bytes=690176,
+                 per_kind_max={"all_gather": 7, "reduce_scatter": 3}),
 }
 
 
