@@ -184,10 +184,18 @@ class TrainerConfig:
     # stream — e.g. online top-K recommendations interleaved with training
     # (the reference's ``...AndTopK`` jobs emit exactly such records on
     # WOut; see fps_tpu.models.recommendation.make_online_topk_tap).
-    # ``batch`` is the raw (pre-``prepare``) batch; ``tables`` are the
-    # values after this step's push DELIVERY — with ``push_delay > 0``
-    # that is the push from ``push_delay`` steps ago, not this step's
-    # (in-flight pushes are invisible, exactly like the async reference).
+    # ``batch`` is the raw (pre-``prepare``) batch. ``tables`` and
+    # ``local_state`` are the step's own PRE-update view: the live tables
+    # and worker state as step ``t - 1`` left them (with ``push_delay > 0``
+    # less the pushes still in flight), never a model that has trained on
+    # step ``t``'s batch — what the reference's ``...AndTopK`` jobs rank
+    # with ("rank, then learn"; prequential evaluation stands on it). Under
+    # SSP that is the live table, fresher than the round's snapshot the
+    # step's pulls read. The tap's device ops sit under a scope of their
+    # own, ``fps.tap`` (docs/observability.md). A tap may carry a
+    # ``journal`` attribute, ``journal(host tap output) -> {counter: n}``:
+    # wherever an epoch's or chunk's metrics are folded on the host its
+    # counts land on that journal event and under ``tap.<counter>``.
     step_tap: Callable[..., Any] | None = None
     # On-device push-delta health guard (fps_tpu.core.resilience): None
     # (default) traces the exact guard-free program of old — zero cost
@@ -1205,9 +1213,21 @@ class Trainer:
         x = lax.all_gather(x, DATA_AXIS)  # (D, S, ...)
         return x.reshape((self.num_workers,) + x.shape[2:])
 
-    def _run_tap(self, out, tables, batch, local_state, t):
+    def _tap_step(self, tables, batch, local_state, t):
+        """The step tap's output on the step's PRE-update view, gathered
+        over the workers, or None without a tap (nothing is traced then).
+        Every step builder calls it BEFORE ``_compute_step``, with the
+        tables and worker state step ``t - 1`` left, and mounts the result
+        on the step's metrics at its end (:meth:`_mount_tap`)."""
         tap = self.config.step_tap
         if tap is None:
+            return None
+        with jax.named_scope("fps.tap"):
+            return jax.tree.map(self._gather_workers,
+                                tap(tables, batch, local_state, t))
+
+    def _mount_tap(self, out, tapped):
+        if tapped is None:
             return out
         if not isinstance(out, dict):
             raise TypeError(
@@ -1219,8 +1239,7 @@ class Trainer:
                 "the worker's out channel already has a 'tap' key — it "
                 "would be silently clobbered by the step_tap output"
             )
-        tapped = tap(tables, batch, local_state, t)
-        return dict(out, tap=jax.tree.map(self._gather_workers, tapped))
+        return dict(out, tap=tapped)
 
     def _apply_or_buffer(self, tables, bufs, t, pushes, head_prefix=None):
         """Apply ``pushes`` now (push_delay 0) or deliver the pushes from
@@ -1530,6 +1549,7 @@ class Trainer:
                 (tables, hot, delta, fstates, sk, bufs, local_state,
                  key, t) = carry
                 key, sub = jax.random.split(key)
+                tapped = self._tap_step(tables, batch_t, local_state, t)
                 (pushes, local_state, out, hp, hcounts,
                  sk) = self._compute_step(
                     tables, snapshot, local_state, batch_t, sub,
@@ -1551,7 +1571,7 @@ class Trainer:
                         lambda x: lax.psum(lax.psum(x, SHARD_AXIS),
                                            DATA_AXIS), out
                     )
-                    out = self._run_tap(out, tables, batch_t, local_state, t)
+                out = self._mount_tap(out, tapped)
                 return (tables, hot, delta, fstates, sk, bufs,
                         local_state, key, t + 1), out
 
@@ -1821,6 +1841,7 @@ class Trainer:
                 # resident dataset inside the compiled loop.
                 with jax.named_scope("fps.ingest"):
                     batch = plan.local_batch_at(iargs, widx, t)
+                tapped = self._tap_step(tables, batch, local_state, t)
                 (pushes, local_state, out, hp, hcounts,
                  sk) = self._compute_step(
                     tables, snapshot, local_state, batch, sub,
@@ -1841,7 +1862,7 @@ class Trainer:
                         lambda x: lax.psum(lax.psum(x, SHARD_AXIS),
                                            DATA_AXIS), out
                     )
-                    out = self._run_tap(out, tables, batch, local_state, t)
+                out = self._mount_tap(out, tapped)
                 return (tables, hot, delta, fstates, sk, bufs,
                         local_state, key), out
 
@@ -2031,6 +2052,15 @@ class Trainer:
                 rec.inc("driver.steps", int(np.shape(metrics["n"])[0]))
                 if ev is not None:
                     ev["examples"] = n
+            journal = getattr(self.config.step_tap, "journal", None)
+            if (journal is not None and isinstance(metrics, Mapping)
+                    and "tap" in metrics):
+                # The tap's own counts (a top-K tap: lists answered,
+                # padding queries) beside the examples they rode with.
+                for name, count in journal(metrics["tap"]).items():
+                    rec.inc(f"tap.{name}", count)
+                    if ev is not None:
+                        ev[name] = count
         return poison
 
     def _apply_health_decision(self, health, rec, index, poison, what):

@@ -186,6 +186,7 @@ def build_megastep_fn(trainer, plan, mode: str, K: int, tick=None):
                 kk, sub = jax.random.split(kk)
                 with jax.named_scope("fps.ingest"):
                     batch = plan.local_batch_at(iargs, widx, t)
+                tapped = trainer._tap_step(tables, batch, local_state, t)
                 (pushes, local_state, out, hp, hcounts,
                  sk) = trainer._compute_step(
                     tables, snapshot, local_state, batch, sub,
@@ -203,8 +204,7 @@ def build_megastep_fn(trainer, plan, mode: str, K: int, tick=None):
                                                  tier, dropped)
                 with jax.named_scope("fps.metrics"):
                     out = jax.tree.map(_psum_workers, out)
-                    out = trainer._run_tap(out, tables, batch, local_state,
-                                           t)
+                out = trainer._mount_tap(out, tapped)
                 return (tables, hot, delta, fstates, sk, local_state,
                         kk), out
 

@@ -45,6 +45,11 @@ def main(argv=None) -> int:
                          "every N steps FROM INSIDE the compiled loop — the "
                          "reference's streaming ...AndTopK shape; requires "
                          "--topk")
+    ap.add_argument("--topk-queries", type=int, default=2,
+                    help="with --topk-every: how many rating events of a "
+                         "worker's step are answered with a list (the first "
+                         "N rows of its batch; the reference answers every "
+                         "event)")
     ap.add_argument("--negative-samples", type=int, default=0,
                     help="sample this many unrated items per rating as "
                          "weighted pseudo-negatives (implicit feedback)")
@@ -52,6 +57,9 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
     if args.topk_every and not args.topk:
         raise SystemExit("--topk-every requires --topk")
+    if args.topk_every and not 1 <= args.topk_queries <= args.local_batch:
+        raise SystemExit("--topk-queries must lie between 1 and "
+                         "--local-batch")
 
     from fps_tpu.core.driver import num_workers_of
     from fps_tpu.models.matrix_factorization import (
@@ -87,7 +95,7 @@ def main(argv=None) -> int:
             trainer.config,
             step_tap=make_online_topk_tap(
                 store, "item_factors", args.topk, every=args.topk_every,
-                query_fn=mf_topk_query_fn(W, num_queries=2),
+                query_fn=mf_topk_query_fn(W, num_queries=args.topk_queries),
             ),
         )
     apply_hot_tier(args, trainer)

@@ -33,12 +33,18 @@ import numpy as np
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from fps_tpu import ops
 from fps_tpu.core.store import ParamStore, phys_to_id
 from fps_tpu.parallel.mesh import SHARD_AXIS
 
 Array = jax.Array
 
 NEG_INF = jnp.float32(-3.0e38)
+
+# The most score-block bytes one ranking pass holds: queries are ranked
+# ``_SCORE_BLOCK_BYTES // (4 * rows)`` at a time (472 over 17,770 rows), so
+# the ``(queries, rows)`` block does not grow with the number of queries.
+_SCORE_BLOCK_BYTES = 32 << 20
 
 
 def build_topk_fn(store: ParamStore, table: str, k: int,
@@ -73,16 +79,17 @@ def build_topk_fn(store: ParamStore, table: str, k: int,
         )  # (B, n_local)
 
         # Merge: gather every shard's candidates (concat along axis 1).
-        all_s = lax.all_gather(top_s, SHARD_AXIS, axis=1, tiled=True)
-        all_i = lax.all_gather(top_ids, SHARD_AXIS, axis=1, tiled=True)
+        with jax.named_scope("topk.merge"):
+            all_s = lax.all_gather(top_s, SHARD_AXIS, axis=1, tiled=True)
+            all_i = lax.all_gather(top_ids, SHARD_AXIS, axis=1, tiled=True)
 
-        if exclude_capacity:
-            hit = jnp.any(
-                all_i[:, :, None] == exclude[:, None, :], axis=-1
-            )  # (B, S*n_local)
-            all_s = jnp.where(hit, NEG_INF, all_s)
+            if exclude_capacity:
+                hit = jnp.any(
+                    all_i[:, :, None] == exclude[:, None, :], axis=-1
+                )  # (B, S*n_local)
+                all_s = jnp.where(hit, NEG_INF, all_s)
 
-        return _merge_topk(all_s, all_i, k)
+            return _merge_topk(all_s, all_i, k)
 
     shmapped = jax.shard_map(
         device_fn,
@@ -133,21 +140,53 @@ def recommend_topk(
 
 def _score_and_local_topk(local, queries, *, num_shards, num_ids, n):
     """Shared per-shard scoring block: score ``queries`` against this
-    shard's rows (MXU matmul), mask padding rows, and take the local
-    top-``n`` with logical ids. Used by both the replicated-query ranking
+    shard's rows (one matrix product, float32 at ``precision=HIGHEST``:
+    the TPU's default would round both operands to bfloat16 and rank by
+    scores good to three digits), mask padding rows, and take the EXACT
+    local top-``n`` (``lax.top_k``; never ``approx_max_k``) with logical
+    ids, best first. Queries go through in blocks of bounded size
+    (:data:`_SCORE_BLOCK_BYTES`), so the score block's memory does not grow
+    with their number. Used by both the replicated-query ranking
     (:func:`build_topk_fn`) and the per-worker tap path, so masking /
-    id-translation fixes cannot drift between them."""
+    id-translation fixes cannot drift between them. Device scopes:
+    ``topk.score`` (the product and the mask), ``topk.select``."""
     rps = local.shape[0]
     me = lax.axis_index(SHARD_AXIS)
     phys = me * rps + jnp.arange(rps, dtype=jnp.int32)
     ids = phys_to_id(phys, num_shards, rps)
-
-    scores = queries.astype(jnp.float32) @ local.astype(jnp.float32).T
-    scores = jnp.where((ids < num_ids)[None, :], scores, NEG_INF)
-
+    rows = local.astype(jnp.float32)
     n_local = min(n, rps)
-    top_s, top_i = lax.top_k(scores, n_local)
-    return top_s, jnp.take(ids, top_i)
+
+    def rank(block):
+        with jax.named_scope("topk.score"):
+            scores = jnp.matmul(block, rows.T,
+                                precision=lax.Precision.HIGHEST)
+            scores = jnp.where((ids < num_ids)[None, :], scores, NEG_INF)
+        with jax.named_scope("topk.select"):
+            top_s, top_i = lax.top_k(scores, n_local)
+            return top_s, jnp.take(ids, top_i)
+
+    queries = queries.astype(jnp.float32)
+    q = queries.shape[0]
+    blocks = -(-q // max(8, _SCORE_BLOCK_BYTES // (4 * rps)))
+    if blocks <= 1:
+        return rank(queries)
+    per = -(-q // blocks)
+    padded = jnp.pad(queries, ((0, blocks * per - q), (0, 0)))
+    top_s, top_ids = lax.map(rank, padded.reshape(blocks, per, -1))
+    return (top_s.reshape(blocks * per, n_local)[:q],
+            top_ids.reshape(blocks * per, n_local)[:q])
+
+
+def _pad_to_k(ids, scores, k):
+    """Out to the ``(B, k)`` contract with the "no candidate" sentinels
+    (-1 ids, NEG_INF scores) where the pool held fewer than ``k``."""
+    short = k - scores.shape[1]
+    if short > 0:
+        pad = ((0, 0), (0, short))
+        scores = jnp.pad(scores, pad, constant_values=NEG_INF)
+        ids = jnp.pad(ids, pad, constant_values=-1)
+    return ids.astype(jnp.int32), scores
 
 
 def _merge_topk(scores, ids, k):
@@ -155,17 +194,10 @@ def _merge_topk(scores, ids, k):
     pool. On a small table (rows_per_shard < k/S) the pool can undershoot
     ``k``, and ``lax.top_k(x, k)`` with ``k > x.shape[-1]`` fails at trace
     time with an opaque XLA error — clamp, then pad back out to the (B, k)
-    contract with -1 ids / NEG_INF scores (the same "no candidate" sentinels
-    the off-cadence tap path emits). Shared by :func:`build_topk_fn` and
+    contract (:func:`_pad_to_k`). Shared by :func:`build_topk_fn` and
     :func:`_topk_local_queries` so the clamp cannot drift between them."""
-    k_eff = min(k, scores.shape[1])
-    out_s, out_j = lax.top_k(scores, k_eff)
-    out_i = jnp.take_along_axis(ids, out_j, axis=1)
-    if k_eff < k:
-        pad = ((0, 0), (0, k - k_eff))
-        out_s = jnp.pad(out_s, pad, constant_values=NEG_INF)
-        out_i = jnp.pad(out_i, pad, constant_values=-1)
-    return out_i.astype(jnp.int32), out_s
+    out_s, out_j = lax.top_k(scores, min(k, scores.shape[1]))
+    return _pad_to_k(jnp.take_along_axis(ids, out_j, axis=1), out_s, k)
 
 
 def _topk_local_queries(local, queries, *, num_shards, num_ids, k):
@@ -175,25 +207,30 @@ def _topk_local_queries(local, queries, *, num_shards, num_ids, k):
     ranks its OWN ``(q, dim)`` queries: queries are all-gathered across the
     shard axis so each shard scores its rows against everyone's queries,
     local candidates are exchanged, and each worker merges the slice
-    belonging to its queries. Candidate traffic only — the table never
-    moves.
+    belonging to its queries (scope ``topk.merge``). Candidate traffic
+    only — the table never moves. On ONE shard the local selection is the
+    answer, best first already: nothing is exchanged or merged.
     """
+    if num_shards == 1:
+        top_s, top_ids = _score_and_local_topk(
+            local, queries, num_shards=1, num_ids=num_ids, n=k)
+        return _pad_to_k(top_ids, top_s, k)
     me = lax.axis_index(SHARD_AXIS)
     q = queries.shape[0]
-    q_all = lax.all_gather(queries, SHARD_AXIS, tiled=True)  # (S*q, dim)
+    with jax.named_scope("topk.merge"):
+        q_all = lax.all_gather(queries, SHARD_AXIS, tiled=True)  # (S*q, dim)
     top_s, top_ids = _score_and_local_topk(
         local, q_all, num_shards=num_shards, num_ids=num_ids, n=k
     )  # (S*q, n_local)
-    n_local = top_s.shape[1]
 
-    all_s = lax.all_gather(top_s, SHARD_AXIS)  # (S, S*q, n_local)
-    all_i = lax.all_gather(top_ids, SHARD_AXIS)
-    mine_s = lax.dynamic_slice_in_dim(all_s, me * q, q, axis=1)  # (S, q, n)
-    mine_i = lax.dynamic_slice_in_dim(all_i, me * q, q, axis=1)
-    mine_s = mine_s.transpose(1, 0, 2).reshape(q, -1)  # (q, S*n_local)
-    mine_i = mine_i.transpose(1, 0, 2).reshape(q, -1)
-
-    return _merge_topk(mine_s, mine_i, k)
+    with jax.named_scope("topk.merge"):
+        all_s = lax.all_gather(top_s, SHARD_AXIS)  # (S, S*q, n_local)
+        all_i = lax.all_gather(top_ids, SHARD_AXIS)
+        mine_s = lax.dynamic_slice_in_dim(all_s, me * q, q, axis=1)
+        mine_i = lax.dynamic_slice_in_dim(all_i, me * q, q, axis=1)
+        mine_s = mine_s.transpose(1, 0, 2).reshape(q, -1)  # (q, S*n_local)
+        mine_i = mine_i.transpose(1, 0, 2).reshape(q, -1)
+        return _merge_topk(mine_s, mine_i, k)
 
 
 def make_online_topk_tap(store: ParamStore, table: str, k: int, *,
@@ -202,56 +239,95 @@ def make_online_topk_tap(store: ParamStore, table: str, k: int, *,
 
     The reference's ``...AndTopK`` jobs emit the current top-K items for
     the users being trained, interleaved with training on the output
-    stream. This tap reproduces that shape: every ``every`` steps each
-    worker ranks ``query_fn``'s queries against the live sharded table and
-    the results ride the metrics stream (leaves ``(T, W, q, k)`` after the
-    driver's per-worker gather); off-cadence steps emit ``-1`` ids and
-    ``NEG_INF`` scores and skip the ranking work entirely (``lax.cond``).
+    stream: for a rating event FIRST the user's list, THEN the SGD step on
+    it. This tap reproduces that shape: every ``every`` steps each worker
+    ranks ``query_fn``'s queries against the sharded table as the step
+    BEFORE left it (the driver hands a tap the step's pre-update view:
+    ``TrainerConfig.step_tap``) and the results ride the metrics stream
+    (leaves ``(T, W, q, k)`` after the driver's per-worker gather), exact
+    over every row of the table, best first; off-cadence steps emit ``-1``
+    ids and ``NEG_INF`` scores and skip the ranking work entirely
+    (``lax.cond``; ``every=1`` ranks unconditionally and lowers no
+    ``cond``). A padding query (``query_fn`` answers id ``-1`` for it)
+    emits the same sentinels and is counted, per worker and step, in the
+    leaf ``topk_padding``.
 
     ``query_fn(batch, local_state) -> (query_ids (q,) int32,
     queries (q, dim))`` — e.g. the first q users of the worker's current
     batch with their local factor rows (:func:`mf_topk_query_fn`).
+
+    Once a traced program the tap logs a route, ``tap.topk`` (rows ranked,
+    K, queries a worker and step, ``shards=S``), in ``fps_tpu.ops``' route
+    log; on the host its counts (``tap.journal``: ``topk_answered``,
+    ``topk_padding``) land on the epoch's or chunk's journal event.
     """
     num_shards = store.num_shards
     num_ids = store.specs[table].num_ids
+    if every < 1:
+        raise ValueError(f"every must be at least 1, got {every}")
 
     def tap(tables, batch, local_state, t):
         qids, queries = query_fn(batch, local_state)
+        qids = qids.astype(jnp.int32)
         q = queries.shape[0]
-
-        def emit(_):
-            return _topk_local_queries(
-                tables[table], queries,
-                num_shards=num_shards, num_ids=num_ids, k=k,
-            )
+        ops.log_route("tap", "topk", num_ids, k, q, f"shards={num_shards}")
 
         def skip(_):
             return (jnp.full((q, k), -1, jnp.int32),
-                    jnp.full((q, k), NEG_INF))
+                    jnp.full((q, k), NEG_INF), jnp.int32(0))
 
-        on = (t % every) == 0
-        ids, scores = lax.cond(on, emit, skip, None)
+        def emit(_):
+            ids, scores = _topk_local_queries(
+                tables[table], queries,
+                num_shards=num_shards, num_ids=num_ids, k=k,
+            )
+            live = (qids >= 0)[:, None]
+            return (jnp.where(live, ids, -1),
+                    jnp.where(live, scores, NEG_INF),
+                    jnp.sum(qids < 0, dtype=jnp.int32))
+
+        if every == 1:
+            ids, scores, padding = emit(None)
+        else:
+            on = (t % every) == 0
+            ids, scores, padding = lax.cond(on, emit, skip, None)
+            qids = jnp.where(on, qids, -1)
         return {
-            "topk_query": jnp.where(on, qids.astype(jnp.int32), -1),
+            "topk_query": qids,
             "topk_ids": ids,
             "topk_scores": scores,
+            "topk_padding": padding,
         }
 
+    tap.journal = topk_journal
     return tap
 
 
+def topk_journal(tapped) -> dict:
+    """A call's counts from the top-K tap's host output: lists answered
+    (live queries on the cadence) and padding queries that asked."""
+    return {
+        "topk_answered": int(np.sum(np.asarray(tapped["topk_query"]) >= 0)),
+        "topk_padding": int(np.sum(np.asarray(tapped["topk_padding"]))),
+    }
+
+
 def mf_topk_query_fn(num_workers: int, num_queries: int):
-    """Query fn for MF: the first ``num_queries`` users of the worker's
-    batch, with their worker-local factor rows (no communication).
+    """Query fn for MF: the first ``num_queries`` rows of the worker's
+    batch, each user with its worker-local factor row (no communication;
+    the read is the store's ``pull_local``, under ``fps.ops``).
 
     Padding rows (``weight == 0``) emit query id ``-1``: a padded slot's
     user id belongs to ANOTHER worker's routing domain, so its local
     factor-row lookup would silently rank with a different user's vector
-    — consumers must skip ``-1`` queries (their ranking rows are
-    meaningless)."""
+    — the tap answers such a query with sentinels and counts it."""
     from fps_tpu.core.store import pull_local
 
     def query_fn(batch, local_state):
+        if num_queries > batch["user"].shape[0]:
+            raise ValueError(
+                f"num_queries={num_queries} exceeds the worker's batch of "
+                f"{batch['user'].shape[0]} rows")
         users = batch["user"][:num_queries].astype(jnp.int32)
         valid = batch["weight"][:num_queries] > 0
         qids = jnp.where(valid, users, -1)

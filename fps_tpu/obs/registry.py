@@ -106,6 +106,15 @@ def default_registry() -> MetricsRegistry:
                    help="scan steps completed"),
         MetricSpec("driver.examples", "counter", unit="examples",
                    help="examples consumed (sum of the 'n' metrics leaf)"),
+        # A step tap's own counts (TrainerConfig.step_tap's ``journal``).
+        MetricSpec("tap.topk_answered", "counter", unit="lists",
+                   help="top-K lists the step tap answered (live queries "
+                        "on the tap's cadence: recommendation."
+                        "make_online_topk_tap)"),
+        MetricSpec("tap.topk_padding", "counter", unit="queries",
+                   help="padding queries of the top-K tap (rows of weight "
+                        "0 among a step's first rows): answered with the "
+                        "sentinel, never with a list"),
         # Phase timers (fps_tpu.obs.timing.PhaseTimer).
         MetricSpec("driver.phase_seconds", "histogram", unit="s",
                    labels=("phase",),

@@ -96,10 +96,15 @@ COMPILE_PHASES = ("compile.trace", "compile.lower", "compile.backend")
 # once a ROUND of steps (ROUND_SCOPES: ``ssp.snapshot``, the SSP round's
 # snapshot gather and its hot reconcile, ``Trainer._ssp_round``): a reader
 # counts steps by the ops under ``fps.*``, and an op that runs once in
-# ``sync_every`` steps would sit in that count at a fraction of a step. A
-# test walks the tree against the three lists.
-STEP_SCOPES = ("fps.ingest", "fps.prepare", "fps.sketch", "fps.pull",
-               "fps.compute", "fps.push", "fps.combine", "fps.ops",
+# ``sync_every`` steps would sit in that count at a fraction of a step.
+# ``fps.tap`` is ``TrainerConfig.step_tap`` on the step's pre-update view
+# (``Trainer._tap_step``, before the pull); what a tap names INSIDE it has
+# no prefix either (INNER_SCOPES: the top-K ranking's parts, which the
+# serving program ``recommendation.build_topk_fn`` shares). A test walks
+# the tree against the four lists.
+STEP_SCOPES = ("fps.ingest", "fps.tap", "fps.prepare", "fps.sketch",
+               "fps.pull", "fps.compute", "fps.push", "fps.combine",
+               "fps.ops",
                "fps.hot_accumulate", "fps.reconcile", "fps.sketch_merge",
                "fps.megastep_vote", "fps.megastep_tick", "fps.metrics")
 ONCE_SCOPES = ("ingest.pack", "ingest.tbuf", "ingest.perm", "ingest.chunk",
@@ -108,6 +113,7 @@ ONCE_SCOPES = ("ingest.pack", "ingest.tbuf", "ingest.perm", "ingest.chunk",
                # table, the accumulators' zero fill, the batched solve
                "als.gram", "als.zeros", "als.solve")
 ROUND_SCOPES = ("ssp.snapshot",)
+INNER_SCOPES = ("topk.score", "topk.select", "topk.merge")
 # Set-up spans (no timer: they report through the process-default
 # recorder). Those that queue device work close on its completion when a
 # recorder is installed, and only then (settle()).

@@ -123,9 +123,12 @@ def test_committed_benchmark_lists_the_four_metrics_in_the_cells_named():
     assert NEW <= set(listed)
     assert all(listed[n]["source"] == "program_span"
                and listed[n]["moves"] == "examples_per_s" for n in NEW)
-    assert listed["driver.call_device_ms"]["workloads"] == TRAINER_CELLS
+    # Cells of later PRs are appended (PR 42: the top-K tap's, a Trainer's).
+    later = ["mf-netflix-topk.epochs"]
+    assert listed["driver.call_device_ms"]["workloads"] == (
+        TRAINER_CELLS + later)
     assert listed["driver.starved_share"]["workloads"] == TRAINER_CELLS + [
-        "ials-ml20m.sweeps"]
+        "ials-ml20m.sweeps"] + later
     for n in ("solver.user_sweep_device_s", "solver.item_sweep_device_s"):
         assert listed[n]["workloads"] == ["ials-ml20m.sweeps"]
     # Appended together, in this order (later PRs append after them).

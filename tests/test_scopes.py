@@ -203,7 +203,7 @@ def test_scope_lists_name_every_scope_in_the_tree(w2v_programs):
     from fps_tpu.obs import timing
 
     declared = (set(timing.STEP_SCOPES) | set(timing.ONCE_SCOPES)
-                | set(timing.ROUND_SCOPES))
+                | set(timing.ROUND_SCOPES) | set(timing.INNER_SCOPES))
     root = os.path.join(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))), "fps_tpu")
     named = {COMBINE_SCOPE}
@@ -218,8 +218,8 @@ def test_scope_lists_name_every_scope_in_the_tree(w2v_programs):
                         and isinstance(node.args[0], ast.Constant)):
                     named.add(node.args[0].value)
     assert named == declared, named ^ declared
-    assert not [n for n in timing.ONCE_SCOPES + timing.ROUND_SCOPES
-                if n.startswith("fps.")]
+    assert not [n for n in (timing.ONCE_SCOPES + timing.ROUND_SCOPES
+                            + timing.INNER_SCOPES) if n.startswith("fps.")]
     used = {part for p in w2v_programs["indexed"] for part in p.split("/")
             if part.startswith("fps.")}
     assert {"fps.prepare", "fps.combine"} <= used <= set(timing.STEP_SCOPES)

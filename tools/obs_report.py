@@ -138,7 +138,7 @@ REQUIRED_FIELDS = (
     "schema", "obs_dir", "run_ids", "processes", "chunks", "epochs",
     "steps", "examples", "phase_seconds", "health", "incidents",
     "checkpoint", "checkpoint_saves", "quarantined", "wall_span_s",
-    "prefetch", "device",
+    "prefetch", "device", "tap",
     "hot_tier", "megastep", "tiering", "source_stalls", "analysis",
     "serve", "pod", "net", "recovery",
 )
@@ -400,6 +400,12 @@ def render_digest(obs_dir: str, *, recovery_slo_s: float | None = None) -> dict:
         "steps": int(counters.get("driver.steps", 0)),
         "examples": counters.get("driver.examples", 0.0),
         "phase_seconds": dict(sorted(phases.items())),
+        # What a step tap counted on the host (TrainerConfig.step_tap's
+        # ``journal``): the top-K tap's lists answered and the padding
+        # queries that asked; empty without such a tap.
+        "tap": {name[len("tap."):]: int(v)
+                for name, v in sorted(counters.items())
+                if name.startswith("tap.")},
         # The device's time, from inside (obs.timing.watch_device): the
         # phases above time the host QUEUEING; these say how long the
         # device ran what was queued, and whether the host kept it fed.
