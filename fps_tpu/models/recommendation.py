@@ -14,7 +14,12 @@ merge, all on-device:
   ``(B, dim) @ (rps, dim)^T`` — one MXU matmul per shard, no table movement;
 * each shard takes a **local top-(k+E)** of its partial scores
   (``E`` = exclusion capacity, so exclusions can never eat into the true
-  top-k);
+  top-k), exactly, and on a table large enough **prunes before it
+  selects**: a query's best ``n`` lie in the ``n`` chunks of largest
+  maximum, so the exact selection reads the chunk maxima and those
+  chunks' scores, a sixth of the row at 17,770 rows and ``n`` 100
+  (:func:`_score_and_local_topk` has the proof, :func:`_prune_plan` the
+  rule);
 * the ``S*(k+E)`` candidates per query are ``all_gather``-ed over ICI
   (tiny: candidates only, never the table) and merged with a final top-k.
 
@@ -31,6 +36,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from fps_tpu import ops
@@ -45,6 +52,13 @@ NEG_INF = jnp.float32(-3.0e38)
 # ``_SCORE_BLOCK_BYTES // (4 * rows)`` at a time (472 over 17,770 rows), so
 # the ``(queries, rows)`` block does not grow with the number of queries.
 _SCORE_BLOCK_BYTES = 32 << 20
+
+# The lane width: chunk counts and fetched candidates come in whole tiles.
+_LANES = 128
+
+# The most bytes of scores, and of candidates, one step of the fetch kernel
+# holds in VMEM (each twice: the pipeline's two buffers).
+_FETCH_BLOCK_BYTES = 2 << 20
 
 
 def build_topk_fn(store: ParamStore, table: str, k: int,
@@ -138,6 +152,108 @@ def recommend_topk(
     return np.asarray(ids), np.asarray(scores)
 
 
+def _prune_plan(rows: int, n: int):
+    """``(c, C)``, the chunking under which pruning by chunk maxima pays
+    for a top-``n`` over ``rows`` scores, or ``None`` where the block goes
+    straight to ``lax.top_k``.
+
+    A query's scores are laid out ``[c, C]``: ``C`` chunks on the lanes
+    (whole tiles of :data:`_LANES`), a chunk's ``c`` scores down the
+    sublanes (a power of two from 8, the sublane tile). The two exact
+    selections then read ``C`` maxima and ``c`` scores of each of ``n``
+    chunks (``n`` out to whole tiles): ``c`` is the one that makes the sum
+    least, near ``sqrt(rows / n)``. Pruning pays where that sum is at most
+    HALF the ``rows`` it replaces (``lax.top_k``'s time on the v5e goes
+    by the width out to its next power of two, for 256 queries at k 100
+    0.05 ms to 1,024, 0.12 to 2,048, 0.27 to 4,096, 1.48 at 17,770:
+    ``tools/bench_topk_select.py``) and there are ``n`` chunks to select;
+    a small table, or ``n`` near ``rows``, stays direct. 17,770 rows at
+    ``n`` 100: ``c`` 16, ``C`` 1,152, 3,200 scores selected over."""
+    n_pad = -(-n // _LANES) * _LANES
+
+    def chunks_of(c):
+        return -(-rows // (c * _LANES)) * _LANES
+
+    lengths = [8 << i for i in range(rows.bit_length())
+               if (8 << i) * _LANES <= rows]
+    if not lengths:
+        return None
+    c = min(lengths, key=lambda c: chunks_of(c) + n_pad * c)
+    C = chunks_of(c)
+    if (n > C or 2 * (C + n_pad * c) > rows
+            or 4 * n_pad * c > _FETCH_BLOCK_BYTES):  # a query's candidates
+        return None
+    return c, C
+
+
+def _fetch_chunks_kernel(chunks_ref, scores_ref, out_ref, *, per):
+    """One step: ``per`` queries, a run of whole lane tiles of their
+    chunks. A candidate's chunk lies in ONE tile of 128 lanes: each tile
+    is shuffled by the chunk numbers' low bits (the lane gather,
+    ``tpu.dynamic_gather``) and kept where the high bits name it. The
+    output block stays across the tile runs of a query (the second grid
+    axis), opened at ``-inf``, which a lane past ``n`` keeps."""
+    c, lanes, n_pad = scores_ref.shape[1], scores_ref.shape[2], out_ref.shape[2]
+    first_tile = pl.program_id(1) * (lanes // _LANES)
+
+    @pl.when(pl.program_id(1) == 0)
+    def _():
+        out_ref[...] = jnp.full(out_ref.shape, -jnp.inf, out_ref.dtype)
+
+    def one_query(i, _):
+        for u in range(0, n_pad, _LANES):
+            chunk = jnp.broadcast_to(chunks_ref[i, :, u:u + _LANES],
+                                     (c, _LANES))
+            lane, tile = chunk % _LANES, chunk // _LANES - first_tile
+            got = out_ref[i, :, u:u + _LANES]
+            for v in range(lanes // _LANES):
+                here = jnp.take_along_axis(
+                    scores_ref[i, :, v * _LANES:(v + 1) * _LANES], lane,
+                    axis=1, mode="promise_in_bounds")
+                got = jnp.where(tile == v, here, got)
+            out_ref[i, :, u:u + _LANES] = got
+        return 0
+
+    lax.fori_loop(0, per, one_query, 0)
+
+
+def _fetch_chunks(scores, chunks):
+    """``scores[q, :, chunks[q, t]]`` as ``[q, c, n_pad]``: the ``c``
+    scores of each selected chunk, ``n`` out to whole lane tiles (a lane
+    past ``n`` carries ``-inf``, under every score and under ``NEG_INF``,
+    so that it is never answered: ``n * c`` fetched scores stand above
+    it). A Mosaic kernel that only MOVES scores (compiled where
+    ``fps_tpu.ops`` compiles its own, interpreted elsewhere): at 256
+    queries, 17,770 rows and ``n`` 100 XLA's gather of the 409,600
+    scores costs 5 ms, its gather of 25,600 columns 0.46, a one-hot
+    product at HIGHEST 0.05 more than the kernel, and chunks of
+    consecutive columns fetched as slices 22
+    (``tools/bench_topk_select.py``)."""
+    q, c, C = scores.shape
+    n = chunks.shape[1]
+    n_pad = -(-n // _LANES) * _LANES
+    per = next(p for p in (8, 4, 2, 1) if q % p == 0
+               and (p == 1 or 4 * p * c * n_pad <= _FETCH_BLOCK_BYTES))
+    tiles = C // _LANES
+    most = max(1, _FETCH_BLOCK_BYTES // (4 * per * c * _LANES))
+    runs = next(d for d in range(1, tiles + 1)
+                if tiles % d == 0 and tiles // d <= most)
+    lanes = C // runs
+    return pl.pallas_call(
+        partial(_fetch_chunks_kernel, per=per),
+        grid=(q // per, runs),
+        in_specs=[pl.BlockSpec((per, 1, n_pad), lambda i, r: (i, 0, 0)),
+                  pl.BlockSpec((per, c, lanes), lambda i, r: (i, 0, r))],
+        out_specs=pl.BlockSpec((per, c, n_pad), lambda i, r: (i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((q, c, n_pad), scores.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        name="topk_fetch_chunks",
+        interpret=ops._use_pallas() != (True, False),
+    )(jnp.pad(chunks, ((0, 0), (0, n_pad - n)),
+              constant_values=-1)[:, None, :], scores)
+
+
 def _score_and_local_topk(local, queries, *, num_shards, num_ids, n):
     """Shared per-shard scoring block: score ``queries`` against this
     shard's rows (one matrix product, float32 at ``precision=HIGHEST``:
@@ -149,25 +265,86 @@ def _score_and_local_topk(local, queries, *, num_shards, num_ids, n):
     with their number. Used by both the replicated-query ranking
     (:func:`build_topk_fn`) and the per-worker tap path, so masking /
     id-translation fixes cannot drift between them. Device scopes:
-    ``topk.score`` (the product and the mask), ``topk.select``."""
+    ``topk.score`` (the product and the mask), ``topk.select``.
+
+    **Pruning before selecting** (where :func:`_prune_plan` says it pays;
+    logged once a traced program as the route ``tap.topk_pruned``).
+    ``lax.top_k`` costs 0.32 ns a score it is handed, so it is handed
+    fewer: a query's scores are produced as ``[c, C]`` (chunk ``j`` = rows
+    ``j, j + C, ...`` of the shard, the product over a ``[c, C, dim]``
+    view of the table), each chunk's maximum taken (``[q, C]``), the exact
+    top-``n`` of the maxima selects ``n`` chunks, their ``n * c`` scores
+    are fetched (:func:`_fetch_chunks`) and the exact top-``n`` of those
+    is the answer. The scores answered are the product's own numbers,
+    selected and never recomputed.
+
+    *No true top-``n`` item is lost.* Let ``x`` be among the ``n`` best
+    and suppose its chunk is NOT among the ``n`` chunks of largest maximum.
+    Then ``n`` other chunks each have a maximum ``>= max(chunk of x) >=
+    x``, so ``n`` scores outside ``x``'s chunk are ``>= x``: ``x`` is at
+    best TIED for place ``n``, and a list that answers those ``n`` in its
+    stead holds the same score at every rank. Ties may fall either way
+    (as between two equal scores under ``lax.top_k`` itself); no id is
+    answered twice (a position is fetched once) and a masked row
+    (``NEG_INF``: a padding row, a column that fills the last chunk) is
+    answered only where fewer than ``n`` live rows exist, as on the
+    direct path.
+
+    Either way a selected position becomes its logical id by arithmetic
+    (:func:`~fps_tpu.core.store.phys_to_id` on the ``n`` positions a
+    query), not by a gather from a vector of all the shard's ids."""
     rps = local.shape[0]
     me = lax.axis_index(SHARD_AXIS)
-    phys = me * rps + jnp.arange(rps, dtype=jnp.int32)
-    ids = phys_to_id(phys, num_shards, rps)
     rows = local.astype(jnp.float32)
     n_local = min(n, rps)
+    plan = _prune_plan(rps, n_local)
 
-    def rank(block):
+    def ids_of(pos):
+        return phys_to_id(me * rps + pos, num_shards, rps)
+
+    def masked(scores, pos):
+        return jnp.where((pos < rps) & (ids_of(pos) < num_ids), scores,
+                         NEG_INF)
+
+    def rank_direct(block):
         with jax.named_scope("topk.score"):
-            scores = jnp.matmul(block, rows.T,
-                                precision=lax.Precision.HIGHEST)
-            scores = jnp.where((ids < num_ids)[None, :], scores, NEG_INF)
+            scores = masked(
+                jnp.matmul(block, rows.T, precision=lax.Precision.HIGHEST),
+                jnp.arange(rps, dtype=jnp.int32))
         with jax.named_scope("topk.select"):
             top_s, top_i = lax.top_k(scores, n_local)
-            return top_s, jnp.take(ids, top_i)
+            return top_s, ids_of(top_i)
+
+    def rank_pruned(block):
+        c, C = plan
+        with jax.named_scope("topk.score"):
+            view = jnp.pad(rows, ((0, c * C - rps), (0, 0))).reshape(c, C, -1)
+            pos = (jnp.arange(c, dtype=jnp.int32)[:, None] * C
+                   + jnp.arange(C, dtype=jnp.int32)[None, :])
+            scores = masked(
+                jnp.einsum("qd,icd->qic", block, view,
+                           precision=lax.Precision.HIGHEST), pos)
+        with jax.named_scope("topk.select"):
+            _, chunks = lax.top_k(jnp.max(scores, axis=1), n_local)
+            cand = _fetch_chunks(scores, chunks)  # [q, c, n_pad]
+            n_pad = cand.shape[2]
+            top_s, flat = lax.top_k(cand.reshape(-1, c * n_pad), n_local)
+            # The chosen candidate's chunk, chunks[q, flat % n_pad], as a
+            # one-hot sum over the n selected: no gather.
+            chunk = jnp.sum(jnp.where(
+                (flat % n_pad)[:, :, None]
+                == jnp.arange(n_local, dtype=jnp.int32),
+                chunks[:, None, :], 0), axis=-1)
+            return top_s, ids_of((flat // n_pad) * C + chunk)
 
     queries = queries.astype(jnp.float32)
     q = queries.shape[0]
+    if plan is None:
+        rank = rank_direct
+    else:
+        rank = rank_pruned
+        ops.log_route("tap", "topk_pruned", rps, n_local, q,
+                      f"chunks={plan[1]}x{plan[0]}")
     blocks = -(-q // max(8, _SCORE_BLOCK_BYTES // (4 * rps)))
     if blocks <= 1:
         return rank(queries)
@@ -258,8 +435,12 @@ def make_online_topk_tap(store: ParamStore, table: str, k: int, *,
 
     Once a traced program the tap logs a route, ``tap.topk`` (rows ranked,
     K, queries a worker and step, ``shards=S``), in ``fps_tpu.ops``' route
-    log; on the host its counts (``tap.journal``: ``topk_answered``,
-    ``topk_padding``) land on the epoch's or chunk's journal event.
+    log, and where its selection prunes by chunk maxima
+    (:func:`_score_and_local_topk`: 17,770 rows at K 100 do, a table of a
+    few thousand rows does not) ``tap.topk_pruned`` beside it (a shard's
+    rows, K, queries a block, ``chunks=CxC's length``); on the host its
+    counts (``tap.journal``: ``topk_answered``, ``topk_padding``) land on
+    the epoch's or chunk's journal event.
     """
     num_shards = store.num_shards
     num_ids = store.specs[table].num_ids

@@ -102,10 +102,11 @@ def test_spec_validates_the_committed_benchmark_files():
     assert {m["name"] for m in cell["end_to_end"]} == {
         "setup_s", "examples_per_s"}
     # Every metric mf-netflix.epochs lists but the examples to the target,
-    # and the tap's four, which list this cell alone.
+    # and the tap's five, which list this cell alone.
     mf = set(spec.load_cell(bench, "mf-netflix.epochs")["readers"])
     tap = {"tap.topk_ms_per_step", "tap.score_ms_per_step",
-           "tap.select_ms_per_step", "tap.topk_routes_in_program"}
+           "tap.select_ms_per_step", "tap.topk_routes_in_program",
+           "tap.pruned_routes_in_program"}
     assert set(cell["readers"]) == (mf - {"worker.examples_to_target"}) | tap
     for m in bench["per_layer"]:
         if m["name"] in tap:

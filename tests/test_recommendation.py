@@ -279,3 +279,133 @@ def test_online_topk_tap_k_exceeds_candidates(devices8):
             assert (scores_tw[:, NI:] <= float(NEG_INF)).all()
             checked += int(valid.sum())
     assert checked > 0
+
+
+# ---------------------------------------------------------------------------
+# The selection that prunes by chunk maxima, against a full sort.
+# ---------------------------------------------------------------------------
+
+# Rows a shard at which pruning engages at every n up to 128: c 8, C 384.
+_PRUNED_RPS, _PRUNED_C = 3001, 384
+
+
+def _grid(rng, shape):
+    """Multiples of 1/64 in [-8, 8]: with small-integer queries every
+    product and every sum is exact in float32 in any order, so the
+    program's scores equal numpy's to the bit and ties are plentiful."""
+    return (rng.integers(-512, 513, shape) / 64.0).astype(np.float32)
+
+
+def _planted(num_ids, best_ids, tied_ids=()):
+    """A one-column table scored by the query [1]: ``best_ids`` get the
+    distinct largest scores, ``tied_ids`` all the next one, the rest a
+    pattern far below."""
+    col = -1.0 - (np.arange(num_ids) % 7)
+    col[np.asarray(tied_ids, int)] = 50.0
+    col[np.asarray(best_ids, int)] = 100.0 + np.arange(len(best_ids))
+    return col.astype(np.float32)[:, None], np.ones((3, 1), np.float32)
+
+
+def _selection_case(name):
+    """-> (shards, num_ids, k, exclude capacity, table, queries, the C of
+    the chunking ``C x 8`` it prunes by or None where it stays direct)"""
+    rng = np.random.default_rng(sum(map(ord, name)))
+    R, C = _PRUNED_RPS, _PRUNED_C
+
+    def random(num_ids, q=5, dim=4):
+        return (_grid(rng, (num_ids, dim)),
+                rng.integers(-2, 3, (q, dim)).astype(np.float32))
+
+    if name == "rows_not_a_multiple_of_c":
+        return 1, R, 100, 0, *random(R), C
+    if name == "best_all_in_one_chunk":  # chunk 7: rows 7, 7 + C, ...
+        return 1, R, 5, 0, *_planted(R, 7 + C * np.arange(5)), C
+    if name == "best_in_distinct_chunks":
+        return 1, R, 100, 0, *_planted(R, 3 * np.arange(100)), C
+    if name == "every_score_equal":
+        return 1, R, 100, 0, np.ones((R, 2), np.float32), \
+            np.ones((4, 2), np.float32), C
+    if name == "ties_across_a_chunk_boundary_at_rank_n":
+        # Four clear winners; rank 5 tied between neighbouring chunks, a
+        # winner's own chunk (row 7 + C) and the last, half-filled chunk.
+        return 1, R, 5, 0, *_planted(
+            R, [7, 8, 900, 2999],
+            [7 + C, 8 + C, 9, 10, 11, C - 1, 2 * C - 1, 3000]), C
+    if name == "n_exceeds_rows":
+        return 1, 6, 10, 0, *random(6), None
+    if name == "too_small_to_prune":
+        return 1, 2000, 100, 0, *random(2000), None
+    if name == "n_too_near_rows_to_prune":
+        return 1, R, 1500, 0, *random(R), None
+    if name == "one_shard_masked_padding":  # columns filling the last chunk
+        return 1, R + 5, 128, 0, *random(R + 5), C
+    if name == "two_shards_masked_padding":
+        return 2, 2 * R - 1, 100, 0, *random(2 * R - 1), C
+    if name == "eight_shards_masked_padding":
+        return 8, 8 * R - 5, 100, 0, *random(8 * R - 5), C
+    if name == "exclusions_through_build_topk_fn":
+        return 2, 2 * R, 100, 3, *random(2 * R), C
+    if name == "fetch_in_runs_of_lane_tiles":  # the kernel's second axis
+        return 1, 2 * R, 130, 0, *random(2 * R, q=16), 2 * C
+    raise KeyError(name)
+
+
+@pytest.mark.parametrize("name", [
+    "rows_not_a_multiple_of_c", "best_all_in_one_chunk",
+    "best_in_distinct_chunks", "every_score_equal",
+    "ties_across_a_chunk_boundary_at_rank_n", "n_exceeds_rows",
+    "too_small_to_prune", "n_too_near_rows_to_prune",
+    "one_shard_masked_padding", "two_shards_masked_padding",
+    "eight_shards_masked_padding", "exclusions_through_build_topk_fn",
+    "fetch_in_runs_of_lane_tiles",
+])
+def test_selection_equals_a_full_sort(devices8, monkeypatch, name):
+    """The selection, pruned or direct, against a full sort of the same
+    scores: the score at every rank is the reference's exactly, no id is
+    answered twice or out of range, every id's score is the product's own
+    (so a tie may fall either way and nothing else may differ); slots
+    past the table's rows carry the ``_pad_to_k`` sentinels; the route
+    log says whether the shape pruned."""
+    import fps_tpu.ops as ops
+    from fps_tpu.models import recommendation
+    from fps_tpu.models.recommendation import NEG_INF
+
+    shards, num_ids, k, E, logical, q, chunks = _selection_case(name)
+    if name == "fetch_in_runs_of_lane_tiles":
+        # One tile of 128 chunks a step of the kernel: six runs a query,
+        # each over the two lane tiles of its 130 candidates.
+        monkeypatch.setattr(recommendation, "_FETCH_BLOCK_BYTES",
+                            4 * 8 * 8 * 128)
+    mesh = make_ps_mesh(num_shards=shards, num_data=1,
+                        devices=devices8[:shards])
+
+    def init(key, ids):
+        return jnp.take(jnp.asarray(logical),
+                        jnp.minimum(ids, num_ids - 1), axis=0)
+
+    store = ParamStore(mesh, [TableSpec("items", num_ids, logical.shape[1],
+                                        init)])
+    store.init(jax.random.key(0))
+    full = q @ logical.T  # exact: see _grid
+    order = np.argsort(-full, axis=1, kind="stable")
+    exclude = order[:, :E].astype(np.int32) if E else None
+    if E:
+        full = full.copy()
+        np.put_along_axis(full, exclude, -np.inf, axis=1)
+
+    ops.clear_routes()
+    ids, scores = recommend_topk(store, "items", q, k, exclude=exclude)
+    routes = [r for r in ops.routes_traced() if r.route == "tap.topk_pruned"]
+    assert [r.reason for r in routes] == (
+        [f"chunks={chunks}x8"] if chunks else [])
+
+    real = min(k, num_ids)
+    want = -np.sort(-full, axis=1)[:, :real]
+    np.testing.assert_array_equal(scores[:, :real], want)
+    for row_ids, row_scores, row_full in zip(ids, scores, full):
+        live = row_ids[:real]
+        assert len(set(live.tolist())) == real
+        assert live.min() >= 0 and live.max() < num_ids
+        np.testing.assert_array_equal(row_full[live], row_scores[:real])
+    assert (ids[:, real:] == -1).all()
+    assert (scores[:, real:] <= float(NEG_INF)).all()

@@ -345,3 +345,68 @@ def test_mf_example_takes_topk_queries(devices8, capsys):
     with pytest.raises(SystemExit, match="--topk-queries"):
         mf.main(["--topk", "3", "--topk-every", "1", "--local-batch", "8",
                  "--topk-queries", "9"])
+
+
+def _tap_eqns(devices, num_items, k, q):
+    """Every equation of the tap traced alone on one shard at a table of
+    ``num_items`` rows, ``k`` and ``q`` (inner jaxprs included), and the
+    tap's entries of the route log."""
+    from jax.sharding import PartitionSpec as P
+
+    from fps_tpu.parallel.mesh import SHARD_AXIS
+
+    mesh = make_ps_mesh(num_shards=1, num_data=1, devices=devices[:1])
+    _, store = online_mf(mesh, MFConfig(num_users=NU, num_items=num_items,
+                                        rank=10), donate=False)
+    tap = make_online_topk_tap(store, "item_factors", k, every=1,
+                               query_fn=mf_topk_query_fn(1, q))
+    f32 = np.float32
+    tables = {"item_factors": jax.ShapeDtypeStruct((num_items, 10), f32)}
+    batch = {"user": jax.ShapeDtypeStruct((q,), np.int32),
+             "weight": jax.ShapeDtypeStruct((q,), f32)}
+    ops.clear_routes()
+    jaxpr = jax.make_jaxpr(jax.shard_map(
+        lambda tb, b, ls: tap(tb, b, ls, 0), mesh=mesh,
+        in_specs=({"item_factors": P(SHARD_AXIS, None)}, P(), P()),
+        out_specs=P(), check_vma=False))(
+            tables, batch, jax.ShapeDtypeStruct((NU, 10), f32))
+
+    def walk(jp):
+        for eqn in jp.eqns:
+            yield eqn
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from walk(sub)
+
+    return list(walk(jaxpr.jaxpr)), [
+        (r.route, r.rows, r.dim, r.ids, r.reason)
+        for r in ops.routes_traced() if r.op == "tap"]
+
+
+def test_the_tap_prunes_at_the_cells_shape_and_not_at_a_small_table(devices8):
+    """At ``mf-netflix-topk.epochs``' shape (17,770 rows, K 100, 256
+    queries, one shard) the tap logs ``tap.topk`` once and
+    ``tap.topk_pruned`` once with the chunking the rule gives; no
+    ``top_k`` of its program reads more than the maxima or the fetched
+    candidates (K out to whole lane tiles), and no gather reads a vector
+    of the table's ids. At a small table it logs no pruning and hands all
+    the rows to one ``top_k``."""
+    from fps_tpu.models.recommendation import _LANES, _prune_plan
+
+    rows, k, q = 17_770, 100, 256
+    c, C = _prune_plan(rows, k)
+    assert (c, C) == (16, 1152)
+    eqns, routes = _tap_eqns(devices8, rows, k, q)
+    assert routes == [("tap.topk", rows, k, q, "shards=1"),
+                      ("tap.topk_pruned", rows, k, q, f"chunks={C}x{c}")]
+    widths = sorted(e.invars[0].aval.shape[-1] for e in eqns
+                    if e.primitive.name == "top_k")
+    assert widths == [C, c * _LANES]
+    assert max(widths) <= max(C, c * -(-k // _LANES) * _LANES) < rows // 2
+    assert not [e for e in eqns if e.primitive.name == "gather"
+                and e.invars[0].aval.shape == (rows,)]
+
+    eqns, routes = _tap_eqns(devices8, NI, K, Q)
+    assert _prune_plan(NI, K) is None
+    assert routes == [("tap.topk", NI, K, Q, "shards=1")]
+    assert [e.invars[0].aval.shape for e in eqns
+            if e.primitive.name == "top_k"] == [(Q, NI)]

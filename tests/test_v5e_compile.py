@@ -613,3 +613,58 @@ def test_ials_accumulate_scatters_in_place_under_the_step_scopes(
                   r"/fps\.push/sort",
                   r"/fps\.push/while/body/(closed_call/)?fps\.ops/gather\.xla/"):
         assert re.search(scope, text), scope
+
+
+@pytest.mark.parametrize("shards,chunking,tiles_a_step", [
+    (1, (16, 1152), None), (4, (8, 640), None), (1, (16, 1152), 3)])
+def test_topk_selection_prunes_with_its_kernel(topo, monkeypatch, shards,
+                                               chunking, tiles_a_step):
+    """``mf-netflix-topk``'s selection (17,770 rows, K 100, 256 queries a
+    worker) compiled for one shard and for four: ``lax.top_k`` is handed
+    the chunk maxima and the fetched candidates, never a shard's whole
+    row of scores; the candidates are fetched by the Mosaic kernel
+    ``topk_fetch_chunks`` (the lane gather compiles for the v5e), once a
+    program, under ``topk.select``; so it does with its VMEM budget cut to
+    three lane tiles a step (the runs of tiles a table of millions of rows
+    is fetched in)."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from fps_tpu.models import recommendation as rec
+    from fps_tpu.parallel.mesh import SHARD_AXIS, make_ps_mesh
+
+    monkeypatch.setattr(ops, "_use_pallas", lambda: (True, False))
+    if tiles_a_step:
+        monkeypatch.setattr(rec, "_FETCH_BLOCK_BYTES",
+                            4 * 8 * chunking[0] * 128 * tiles_a_step)
+    rows, k, q, rank = 17_770, 100, 256, NETFLIX[1]
+    rps = -(-rows // shards)
+    c, C = chunking
+    assert rec._prune_plan(rps, k) == (c, C)
+    mesh = make_ps_mesh(num_shards=shards, devices=list(topo.devices)[:shards])
+
+    def shape(s, spec):
+        return jax.ShapeDtypeStruct(s, jnp.float32,
+                                    sharding=NamedSharding(mesh, spec))
+
+    text = jax.jit(jax.shard_map(
+        lambda t, qs: rec._topk_local_queries(
+            t, qs, num_shards=shards, num_ids=rows, k=k),
+        mesh=mesh, in_specs=(P(SHARD_AXIS, None), P(SHARD_AXIS, None)),
+        out_specs=(P(SHARD_AXIS, None), P(SHARD_AXIS, None)),
+        check_vma=False)).lower(
+            shape((rps * shards, rank), P(SHARD_AXIS, None)),
+            shape((q * shards, rank), P(SHARD_AXIS, None))
+    ).compile().as_text()
+    # XLA lowers a narrow lax.top_k to a sort and a wide one to its TopK
+    # custom call: either way, the widths it is handed.
+    handed = sorted(
+        int(m.group(1)) for ln in text.splitlines()
+        if '/top_k"' in ln and (" sort(" in ln or '"TopK"' in ln)
+        and (m := re.search(r"= \(?f32\[\d+,(\d+)\]", ln)))
+    merged = [shards * k] if shards > 1 else []
+    assert handed == sorted([C, c * 128] + merged), handed
+    kernels = [ln for ln in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in ln]
+    assert len(kernels) == 1 and "topk_fetch_chunks" in kernels[0] and (
+        "/topk.select/" in kernels[0]
+        and f"f32[{q * shards},{c},128]" in kernels[0]), kernels
