@@ -1057,6 +1057,10 @@ class Trainer:
                     # snapshot gathers as collective-free tier hits.
                     if H:
                         live = jnp.sum(tids >= 0, dtype=jnp.int32)
+                        # Logged before the gather.* entry of the replica
+                        # read it ends in (as pull.snapshot is).
+                        ops.log_route("pull", "hot", *hot[name].shape,
+                                      tids.shape[0], f"table={name}")
                     if H >= spec.num_ids:
                         # Fully-replicated table: the collective route is
                         # statically gone — a plain local gather.
@@ -1324,13 +1328,19 @@ class Trainer:
         cold_pushes = {}
         dropped = {}
         new_delta = dict(delta)
-        with jax.named_scope("fps.hot_accumulate"):
+        # Push work, once a step: INSIDE fps.push (path
+        # fps.push/fps.hot_accumulate), so what divides by the push's
+        # time keeps all of it when the tier takes rows off the cold route.
+        with jax.named_scope("fps.push"), \
+                jax.named_scope("fps.hot_accumulate"):
             for name, (pids, pdeltas) in pushes.items():
                 H = tier.get(name, 0)
                 if not H:
                     cold_pushes[name] = (pids, pdeltas)
                     continue
                 spec = self.store.specs[name]
+                ops.log_route("push", "hot", H, delta[name].shape[1],
+                              pids.shape[0], f"table={name}")
                 if H >= spec.num_ids:
                     hots = (pids, pdeltas)  # no cold residue to push
                 elif name in maps:
@@ -1376,10 +1386,20 @@ class Trainer:
         tables, hot, delta = dict(tables), dict(hot), dict(delta)
         folds = dict(folds)
         data_axis = DATA_AXIS if self.mesh.shape[DATA_AXIS] > 1 else None
-        with jax.named_scope("fps.reconcile"):
+        # Once a WINDOW of hot_sync_every steps: named without the fps.
+        # prefix (obs.timing.ROUND_SCOPES), in sync mode and inside
+        # ssp.snapshot alike.
+        with jax.named_scope("hot.reconcile"):
             for name, H in sorted(tier.items()):
                 fold = self._hot_fold(name)
                 fstate = folds.get(name)
+                # (a tiered table's combine is one of the four strings)
+                ops.log_route(
+                    "reconcile", "hot", H, delta[name].shape[1], 0,
+                    f"table={name} every={self.config.hot_sync_every} "
+                    f"combine={self._hot_combine(name)} "
+                    f"shards={self.num_shards} "
+                    f"bytes={delta[name].size * delta[name].dtype.itemsize}")
                 if name in gids:
                     (tables[name], hot[name], delta[name],
                      fstate) = reconcile_hot_mapped(
@@ -1410,7 +1430,8 @@ class Trainer:
         (pulls read the snapshot, pushes land in the live tables), the hot
         reconcile at its foot (identity when untiered), so the next
         round's gather sees reconciled head rows. Gather and reconcile run
-        once a round under ``ssp.snapshot``: no ``fps.`` prefix, since a
+        once a round under ``ssp.snapshot`` (the reconcile as
+        ``ssp.snapshot/hot.reconcile``): no ``fps.`` prefix, since a
         reader counts steps by the ops under ``fps.*``
         (``obs.timing.ROUND_SCOPES``). On one shard XLA drops the gather
         and copies the live table itself, under no name: the scope then
@@ -2003,6 +2024,58 @@ class Trainer:
             poison += nf + nm
         return poison
 
+    @staticmethod
+    def _record_hot_tier(rec, ht) -> dict:
+        """Fold one unit's HOST hot-tier channel (``{table: per-step
+        counters}``) into the recorder and return the unit's own sums a
+        table, the journal's fields: ``hot_rows``, ``pulled_rows``,
+        ``cold_dropped`` (0 where the cold route is not compacted: nothing
+        can be dropped there) and ``pending_delta``, the peak over the
+        unit's steps."""
+        sums = {}
+        for table, counters in ht.items():
+            # .get: a tiered table the worker pushes to but never
+            # pulls (or an SSP run, where reads come from the round
+            # snapshot, not the replica) carries no pull counters.
+            hot = float(np.sum(np.asarray(counters.get("hot_rows", 0)),
+                               dtype=np.float64))
+            pulled = float(np.sum(np.asarray(
+                counters.get("pulled_rows", 0)), dtype=np.float64))
+            rec.inc("hot_tier.hot_rows", hot, table=table)
+            rec.inc("hot_tier.pulled_rows", pulled, table=table)
+            dropped = 0.0
+            if "cold_dropped" in counters:
+                # Compacted-route overflow drops — ALWAYS zero for
+                # host-certified chunks; nonzero means a certifier
+                # bug, surfaced rather than silently losing updates.
+                dropped = float(np.sum(np.asarray(
+                    counters["cold_dropped"]), dtype=np.float64))
+                rec.inc("hot_tier.cold_dropped", dropped, table=table)
+            # Peak pending-delta magnitude across the call's steps —
+            # the parameter-plane staleness gauge (always 0 at the
+            # boundary itself: the flush reconcile drained it).
+            ds = np.asarray(counters.get("delta_sq", 0.0))
+            peak = float(np.sqrt(np.max(ds))) if ds.size else 0.0
+            rec.set("hot_tier.pending_delta", peak, table=table)
+            sums[table] = {"hot_rows": hot, "pulled_rows": pulled,
+                           "cold_dropped": dropped, "pending_delta": peak}
+        return sums
+
+    def _hot_tier_later(self, metrics):
+        """What ``watch_device`` runs once a unit whose metrics stay on
+        the device has completed (``run_indexed(as_numpy=False)``): the
+        hot tier's counters counted then, from a copy that waits for
+        nothing, and the unit's sums handed back for its ``device.*``
+        span, the journal's record of the epoch's completion (its
+        ``epoch`` event is written at dispatch, before the numbers
+        exist). ``None`` when the tier is off."""
+        ht = (metrics.get(resilience.HOT_TIER_KEY)
+              if isinstance(metrics, Mapping) else None)
+        if not ht:
+            return None
+        return lambda rec: {"hot_tier": self._record_hot_tier(
+            rec, jax.tree.map(np.asarray, ht))}
+
     def _fold_metrics_accounting(self, rec, metrics, ev=None) -> int:
         """The one per-chunk/epoch telemetry fold for a HOST metrics tree:
         per-table health counters (+ health.poisoned_chunks), example/step
@@ -2014,33 +2087,9 @@ class Trainer:
         ht = (metrics.get(resilience.HOT_TIER_KEY)
               if isinstance(metrics, Mapping) else None)
         if ht and rec is not None:
-            for table, counters in ht.items():
-                # .get: a tiered table the worker pushes to but never
-                # pulls (or an SSP run, where reads come from the round
-                # snapshot, not the replica) carries no pull counters.
-                rec.inc("hot_tier.hot_rows",
-                        float(np.sum(np.asarray(
-                            counters.get("hot_rows", 0)))),
-                        table=table)
-                rec.inc("hot_tier.pulled_rows",
-                        float(np.sum(np.asarray(
-                            counters.get("pulled_rows", 0)))),
-                        table=table)
-                if "cold_dropped" in counters:
-                    # Compacted-route overflow drops — ALWAYS zero for
-                    # host-certified chunks; nonzero means a certifier
-                    # bug, surfaced rather than silently losing updates.
-                    rec.inc("hot_tier.cold_dropped",
-                            float(np.sum(np.asarray(
-                                counters["cold_dropped"]))),
-                            table=table)
-                # Peak pending-delta magnitude across the call's steps —
-                # the parameter-plane staleness gauge (always 0 at the
-                # boundary itself: the flush reconcile drained it).
-                ds = np.asarray(counters.get("delta_sq", 0.0))
-                rec.set("hot_tier.pending_delta",
-                        float(np.sqrt(np.max(ds))) if ds.size else 0.0,
-                        table=table)
+            sums = self._record_hot_tier(rec, ht)
+            if ev is not None:
+                ev["hot_tier"] = sums
         if rec is not None:
             if poison:
                 rec.inc("health.poisoned_chunks")
@@ -2349,9 +2398,14 @@ class Trainer:
                     if n_calls * T_call > T:
                         metrics = jax.tree.map(lambda x: x[:T], metrics)
                     # The epoch is queued: its completion is the watcher's
-                    # to stamp (a None test with no recorder).
-                    watch_device("device.run_indexed", metrics, timer,
-                                 epoch=e, steps=T)
+                    # to stamp (a None test with no recorder), and with it
+                    # the hot tier's counts of an epoch nobody fetches.
+                    watch_device(
+                        "device.run_indexed", metrics, timer,
+                        on_done=(self._hot_tier_later(metrics)
+                                 if on_epoch is None and not as_numpy
+                                 and not sync_each else None),
+                        epoch=e, steps=T)
                     if quarantine is not None:
                         with _phase(timer, "host_sync"):
                             metrics, restored = self._maybe_quarantine(

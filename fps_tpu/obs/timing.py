@@ -90,13 +90,21 @@ COMPILE_PHASES = ("compile.trace", "compile.lower", "compile.backend")
 # is the worker's own sampling before the pull; ``fps.combine`` the
 # table-sized work of a non-"sum" push (``core/store.COMBINE_SCOPE``),
 # inside ``fps.push`` beside the routed scatter's ``fps.ops`` (under which
-# ``fps_tpu.ops`` names the route); the rest belong to the tiered and
+# ``fps_tpu.ops`` names the route), and so does ``fps.hot_accumulate``,
+# the two-tier storage's fold of a step's hot pushes into the pending
+# buffer (``Trainer._apply_hot_split``: push work, so what divides by the
+# push's time keeps all of it); the rest belong to the tiered and
 # megastep paths. Programs that run once a call or once a chunk are named
 # WITHOUT the prefix (ONCE_SCOPES), and so is what a compiled loop runs
 # once a ROUND of steps (ROUND_SCOPES: ``ssp.snapshot``, the SSP round's
-# snapshot gather and its hot reconcile, ``Trainer._ssp_round``): a reader
-# counts steps by the ops under ``fps.*``, and an op that runs once in
-# ``sync_every`` steps would sit in that count at a fraction of a step.
+# snapshot gather, ``Trainer._ssp_round``; ``hot.reconcile``, the hot
+# tier's window-end reduce-scatter, apply and all-gather, once in
+# ``hot_sync_every`` steps, ``Trainer._reconcile_carry``, in sync mode
+# and at an SSP round's foot, there as ``ssp.snapshot/hot.reconcile``):
+# a reader counts steps by the ops under ``fps.*``, and an op that runs
+# once in ``sync_every`` steps would sit in that count at a fraction of a
+# step. (The routed gather inside the reconcile keeps its
+# ``fps.ops/<route>`` name, one op a table a window.)
 # ``fps.tap`` is ``TrainerConfig.step_tap`` on the step's pre-update view
 # (``Trainer._tap_step``, before the pull); what a tap names INSIDE it has
 # no prefix either (INNER_SCOPES: the top-K ranking's parts, which the
@@ -105,14 +113,14 @@ COMPILE_PHASES = ("compile.trace", "compile.lower", "compile.backend")
 STEP_SCOPES = ("fps.ingest", "fps.tap", "fps.prepare", "fps.sketch",
                "fps.pull", "fps.compute", "fps.push", "fps.combine",
                "fps.ops",
-               "fps.hot_accumulate", "fps.reconcile", "fps.sketch_merge",
+               "fps.hot_accumulate", "fps.sketch_merge",
                "fps.megastep_vote", "fps.megastep_tick", "fps.metrics")
 ONCE_SCOPES = ("ingest.pack", "ingest.tbuf", "ingest.perm", "ingest.chunk",
                "ingest.compact",
                # models/ials.py, once a sweep: the Gramian of the fixed
                # table, the accumulators' zero fill, the batched solve
                "als.gram", "als.zeros", "als.solve")
-ROUND_SCOPES = ("ssp.snapshot",)
+ROUND_SCOPES = ("ssp.snapshot", "hot.reconcile")
 INNER_SCOPES = ("topk.score", "topk.select", "topk.merge")
 # Set-up spans (no timer: they report through the process-default
 # recorder). Those that queue device work close on its completion when a
@@ -250,11 +258,11 @@ class _DeviceUnit:
     """One unit of queued device work in the watcher's FIFO."""
 
     __slots__ = ("name", "leaves", "rec", "parent", "attrs", "t_enqueued",
-                 "in_flight")
+                 "in_flight", "on_done")
 
-    def __init__(self, name, leaves, rec, parent, attrs):
+    def __init__(self, name, leaves, rec, parent, attrs, on_done=None):
         self.name, self.leaves, self.rec = name, leaves, rec
-        self.parent, self.attrs = parent, attrs
+        self.parent, self.attrs, self.on_done = parent, attrs, on_done
         self.t_enqueued = _now()
         self.in_flight = 0
 
@@ -333,6 +341,11 @@ class _DeviceWatcher:
                 if t1 is not None:
                     prev, self._last_t1 = self._last_t1, t1
                     try:
+                        # After the stamp: what the caller left to be
+                        # counted once the unit's outputs exist (they
+                        # do: a copy to the host, no wait) rides the span.
+                        if unit.on_done is not None and unit.rec is not None:
+                            unit.attrs.update(unit.on_done(unit.rec) or {})
                         self._emit(unit, prev, t1)
                     except Exception:  # noqa: BLE001 - telemetry must
                         # not end the watcher
@@ -373,7 +386,7 @@ _watcher = _DeviceWatcher()
 
 
 def watch_device(name: str, outputs, timer: "PhaseTimer | None" = None,
-                 **attrs) -> None:
+                 on_done=None, **attrs) -> None:
     """Hand one unit of queued device work to the completion watcher.
 
     Called by a driver entry point right after the LAST program of the
@@ -404,7 +417,13 @@ def watch_device(name: str, outputs, timer: "PhaseTimer | None" = None,
       queued; ``attrs`` (``steps``, ``epoch``, ``chunk``, ``solve``):
       what the call site knows without reading the device (``steps``
       defaults to the leading dimension of the first output, the shape of
-      per-step metrics).
+      per-step metrics);
+    * ``on_done(recorder) -> dict | None``: run by the watcher right after
+      the stamp, on its own thread, for accounting the caller deferred
+      because the outputs it reads did not exist yet (the hot tier's hit
+      counts of a ``run_indexed(as_numpy=False)`` epoch): it may count on
+      the recorder, and what it returns joins the span's fields. The
+      caller is never made to wait for it.
 
     The span is the whole record: ``tools/obs_report.py``'s ``device``
     section and the benchmark's per-layer metrics read it, and nothing
@@ -425,7 +444,8 @@ def watch_device(name: str, outputs, timer: "PhaseTimer | None" = None,
         attrs["steps"] = int(leaves[0].shape[0])  # per-step metrics
     stack = getattr(_open, "stack", None)
     _watcher.put(_DeviceUnit(name, leaves, rec,
-                             stack[-1] if stack else (None, None), attrs))
+                             stack[-1] if stack else (None, None), attrs,
+                             on_done))
 
 
 def drain_device_spans(recorder) -> None:

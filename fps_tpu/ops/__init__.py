@@ -76,10 +76,15 @@ class Route(NamedTuple):
     TRACE time, never in the compiled program."""
     op: str          # "gather" | "scatter_add" | "push" (the store's choice
                      # of a combine's branch: fps_tpu.core.store.push) |
-                     # "pull" (the driver's read of the SSP snapshot)
+                     # "pull" (the driver's read of the SSP snapshot, or
+                     # of the two-tier storage's replica) | "reconcile"
+                     # (the hot tier's window-end exchange)
     route: str       # "gather.dim1_head", "scatter_add.xla", ...;
                      # "push.mean_rows" / "push.mean_dense" / "push.fold"
-                     # / "push.acc_runs"; "pull.snapshot"
+                     # / "push.acc_runs"; "pull.snapshot"; "pull.hot" /
+                     # "push.hot" (rows: the head H; dim: the replica's,
+                     # the pending buffer's with its count column; ids: a
+                     # step's, hot and cold) / "reconcile.hot" (ids 0)
     rows: int        # rows of the table (slice) the call sees
     dim: int
     ids: int         # ids the call moves
@@ -91,7 +96,10 @@ class Route(NamedTuple):
                      # of "push.mean_dense": "fold", "dtype", "small_table";
                      # of "push.fold": "apply_fn"; of "push.acc_runs"
                      # what brought the push to the accumulator: "fold",
-                     # "mean_dense", "callable"
+                     # "mean_dense", "callable"; of the three ".hot"
+                     # entries "table=<name>", the reconcile's also
+                     # "every=<E> combine=<c> shards=<S> bytes=<a device's
+                     # pending buffer, reduced once a window>"
 
 
 _ROUTES_TRACED: list[Route] = []

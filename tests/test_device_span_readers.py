@@ -124,7 +124,8 @@ def test_committed_benchmark_lists_the_four_metrics_in_the_cells_named():
     assert all(listed[n]["source"] == "program_span"
                and listed[n]["moves"] == "examples_per_s" for n in NEW)
     # Cells of later PRs are appended (PR 42: the top-K tap's, a Trainer's).
-    later = ["mf-netflix-topk.epochs"]
+    # PR 44: word2vec under the two-tier storage, a Trainer's too.
+    later = ["mf-netflix-topk.epochs", "w2v-1bw-hot.x4"]
     assert listed["driver.call_device_ms"]["workloads"] == (
         TRAINER_CELLS + later)
     assert listed["driver.starved_share"]["workloads"] == TRAINER_CELLS + [

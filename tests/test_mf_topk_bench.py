@@ -111,8 +111,12 @@ def test_spec_validates_the_committed_benchmark_files():
     for m in bench["per_layer"]:
         if m["name"] in tap:
             assert m["workloads"] == [CELL] and m["layer"] == "step tap"
-    assert len(bench["workloads"]) == 7
-    assert sum(w["chips"] == 4 for w in bench["workloads"]) == 1
+    # Seven cells when this one came; PR 44 appended the eighth, which is
+    # the second on four chips (tests/test_w2v_hot_bench.py counts them).
+    names = [w["name"] for w in bench["workloads"]]
+    assert names.index(CELL) == 6 and len(names) >= 7
+    assert [w["name"] for w in bench["workloads"][:7]
+            if w["chips"] == 4] == ["mf-netflix.x4"]
 
 
 def test_the_configuration_is_mf_netflix_with_the_tap():

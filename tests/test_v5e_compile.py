@@ -339,6 +339,144 @@ def test_w2v_epoch_program_fits_and_names_its_table_sized_work(
     assert not [ln for ln in combine if f"[{V}" in ln.split(" fusion(")[0]]
 
 
+def _loop_bodies(text):
+    """``{computation name: its instructions}`` for the compiled module's
+    computations that are neither fused bodies nor reducers: the entry and
+    the bodies and conditions of its loops."""
+    out = {}
+    for comp in text.split("\n\n"):
+        head, _, body = comp.strip().partition("\n")
+        if head.startswith(("%fused_computation", "HloModule")):
+            continue
+        out[head.split(" ")[0]] = [ln for ln in body.splitlines()
+                                   if " = " in ln]
+    return out
+
+
+def test_w2v_hot_epoch_program_reconciles_once_a_window_on_four_chips(
+        topo, monkeypatch):
+    """``w2v-1bw-hot.x4``'s epoch program at the cell's own size for a
+    described four-chip v5e (two ``[1115012, 300]`` tables over four
+    shards, a head of 32,768 rows of each replicated, windows of 8 steps,
+    168 steps a call). Read from the COMPILED text. The call is a loop of
+    21 windows round a loop of 8 steps; the step's body holds the replica
+    reads (``pull.hot``, a plain gather on ``[32768, 300]``), the cold
+    exchange at payload size (the ids of four workers, 32,788 and 196,728
+    rows of 300: no collective of a table's length) and the pending
+    scatter (``push.hot``, ``[32768, 301]``) INSIDE ``fps.push``; the
+    window's body, under ``hot.reconcile`` and outside every ``fps.``
+    scope but the routed gather's, reduces the two pending buffers
+    ``[32768, 301]`` once and all-gathers the combined step ``[32768,
+    300]`` once a table. What ``store._reconcile_combine`` asks for is a
+    reduce-scatter and an all-gather (the CPU lowering holds that:
+    ``tests/test_hot_tier.py``); the TPU's compiler makes the
+    reduce-scatter an all-reduce of the whole buffer (as it does with the
+    pull's ``psum_scatter``: ``[199424, 300]`` a step), and hoists the
+    step's metric sums out of the step loop into the same all-reduce: the
+    test takes either reduction. Of 352 ops named under ``fps.*`` 343 are
+    in the step's body and 9 in the window's, so a reader that counts
+    steps by a median over those names reads steps (a ragged tail, 172
+    steps, compiles a SECOND step body of as many: PERF.md, section 7).
+    Fits in 2.7 GB a chip before the runner's copies."""
+    import argparse
+
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from fps_tpu.examples.common import apply_hot_tier
+    from fps_tpu.models.word2vec import (
+        W2VConfig,
+        Word2VecDevicePlan,
+        word2vec_block,
+    )
+    from fps_tpu.parallel.mesh import make_ps_mesh
+
+    monkeypatch.setattr(ops, "_use_pallas", lambda: (True, False))
+    V, D, L, T, W, H, E = 1_115_011, 300, 8_192, 168, 4, 32_768, 8
+    mesh = make_ps_mesh(num_shards=W, devices=list(topo.devices)[:W])
+    cfg = W2VConfig(vocab_size=V, dim=D)
+    trainer, store = word2vec_block(mesh, cfg, 1.0 / (jnp.arange(V) + 1.5),
+                                    L)
+    apply_hot_tier(argparse.Namespace(hot_tier=H, hot_sync_every=E),
+                   trainer, store)
+    plan = object.__new__(Word2VecDevicePlan)
+    plan.cfg, plan.mode, plan.num_workers, plan.block_len = cfg, "block", W, L
+    plan.steps_per_epoch, plan.sync_every = T, None
+
+    def shape(s, dtype, spec=P()):
+        return jax.ShapeDtypeStruct(s, dtype,
+                                    sharding=NamedSharding(mesh, spec))
+
+    key = shape((), jax.random.key(0).dtype)
+    rows = -(-V // W) * W
+    tables = {}
+    for n in ("in_embeddings", "out_embeddings"):
+        tables[n] = shape((rows, D), jnp.float32, P("shard", None))
+        tables[n + "::hot"] = shape((H, D), jnp.float32)
+    iargs = {"compacted": shape((T * L * W + cfg.window,), jnp.int32),
+             "kept": shape((), jnp.int32), "wkey": key}
+    ops.clear_routes()
+    compiled = trainer._build_indexed_fn(plan, "sync").lower(
+        tables, (), iargs, jnp.int32(0), key).compile()
+    rps, ids_in, ids_out = rows // W, W * (L + 5), W * 6 * (L + 5)
+    assert [(r.route, r.rows, r.dim, r.ids) for r in ops.routes_traced()
+            if not r.route.startswith(("gather.", "scatter_add."))] == [
+        ("pull.hot", H, D, L + 5), ("pull.hot", H, D, 6 * (L + 5)),
+        ("push.hot", H, D + 1, L + 5), ("push.hot", H, D + 1, 6 * (L + 5)),
+        ("push.mean_rows", rps, D, ids_in),
+        ("push.mean_dense", rps, D, ids_out),
+        ("reconcile.hot", H, D + 1, 0), ("reconcile.hot", H, D + 1, 0)]
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 3 << 30
+    text = compiled.as_text()
+    def collectives(lines, kinds=("all-gather", "all-reduce",
+                                  "reduce-scatter", "all-to-all")):
+        """``(kind, result type)`` of each collective among ``lines`` (a
+        combined one's type is a tuple)."""
+        pat = re.compile(r" = (.*?) (%s)(?:-start)?\(" % "|".join(kinds))
+        return [(m.group(2), m.group(1)) for ln in lines
+                if (m := pat.search(ln))]
+
+    trivial = re.compile(
+        r"= \S+ (get-tuple-element|parameter|tuple|constant|bitcast)\(")
+    bodies = sorted(
+        ((sum("/fps." in ln and not trivial.search(ln) for ln in lines),
+          lines) for lines in _loop_bodies(text).values()),
+        key=lambda b: -b[0])
+    in_step_named, inner = bodies[0]
+    # One step body and no tail's: nothing else holds a thirtieth as many
+    # ops named under fps.* (the window's body, the loops of a route).
+    assert all(30 * n < in_step_named for n, _ in bodies[1:]), [
+        n for n, _ in bodies]
+    (outer,) = [lines for _, lines in bodies
+                if any("/hot.reconcile/all_gather" in ln for ln in lines)]
+    assert outer is not inner
+    # No collective anywhere moves a table's length.
+    for _, shape in collectives(text.splitlines()):
+        assert f"[{rps}," not in shape and f"[{rows}," not in shape, shape
+    # The step: no reduction or gather of the head's shapes; the pending
+    # scatter inside the push.
+    in_step = collectives(inner)
+    assert in_step and not [t for _, t in in_step if f"[{H},{D}" in t]
+    assert not [ln for ln in text.splitlines() if "fps.hot_accumulate" in ln
+                and "fps.push/fps.hot_accumulate/" not in ln]
+    assert any("fps.push/fps.hot_accumulate/fps.ops/scatter_add.xla/" in ln
+               and f"f32[{H},{D + 1}]" in ln for ln in inner)
+    # The window: each pending buffer reduced once, each combined step
+    # gathered once, under hot.reconcile (a combined all-reduce is named
+    # after one of its parts).
+    reduced = collectives(outer, ("all-reduce", "reduce-scatter"))
+    assert sum(t.count(f"f32[{H},{D + 1}]") + t.count(f"f32[{H // W},{D + 1}]")
+               for _, t in reduced) == 2, reduced
+    gathered = [ln for ln in outer if collectives([ln], ("all-gather",))]
+    assert len(gathered) == 2 and all(
+        f" = f32[{H},{D}]" in ln and "/hot.reconcile/all_gather" in ln
+        for ln in gathered), [ln[:200] for ln in gathered]
+    assert not [ln for ln in text.splitlines() if "fps.reconcile" in ln]
+    scoped = [ln for ln in outer if "/hot.reconcile/" in ln and "/fps." in ln]
+    assert scoped and all("/hot.reconcile/fps.ops/gather.xla/" in ln
+                          for ln in scoped), scoped
+
+
 def test_mf_epoch_step_keeps_the_accumulator_for_its_small_table(topo):
     """The MF twin: ``mf-netflix.epochs``'s step pushes 32,768 ratings'
     deltas into ``[17770, 10]`` (9.1 MB of accumulator under 16.8 MB of

@@ -1,0 +1,437 @@
+"""word2vec under the two-tier storage against its plain reference, and the
+benchmark cell ``w2v-1bw-hot.x4`` rehearsed on the CPU.
+
+Tiny sizes (vocabulary 2,003, dim 16, blocks of 64 tokens, a head of 64
+words reconciled every 4 steps) on 4 virtual devices: the tier engages on
+a mesh of more than one device only. What is checked is correctness and
+counts: the program under ``Trainer.run_indexed`` against
+``perfbench/lib/reference/sgns_block_hot.py`` over a call whose last
+window is ragged; that the comparison tells the configured window from
+another, a program that never reconciles and one that reads its own
+pending rows from the sound one; that replica and head agree bit for bit
+at the call's end; that the reference with no head is ``sgns_block``; the
+names the tier leaves in the program (scopes, route log) and in the
+journal (counters for a call nobody fetches); and the files the cell is
+made of. No rate is read: a CPU run has none.
+"""
+
+import contextlib
+import copy
+import importlib
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from perfbench import hot_window
+from perfbench.lib import check, resolve, runner, spec, window
+from perfbench.lib.reference import sgns_block, sgns_block_hot
+
+driver = importlib.import_module("fps_tpu.core.driver")
+
+CELL = "w2v-1bw-hot.x4"
+H, E = 64, 4
+TINY = {"model": {"vocab_size": 2003, "dim": 16, "block_len": 64,
+                  "hot_tier": H, "hot_sync_every": E},
+        "data": {"vocab_size": 2003, "tokens_resident": 41_000,
+                 "corpus_tokens": 2_000_000}}
+# float32 on both sides; what differs is the ORDER of sums (as in
+# tests/test_w2v_bench.py) and, for a hot row, a multiply by 1/count
+# where the reference divides.
+F32_GAP = 5e-6
+GAPS = ("loss_gap",) + tuple(
+    f"{kind}.{t}" for kind in ("table_gap", "update_gap")
+    for t in ("in_embeddings", "out_embeddings", "hot_in", "hot_out"))
+EXACT = ("examples", "feed") + tuple(
+    f"{kind}.{t}" for kind in ("table_gap", "update_gap")
+    for t in ("pending_in", "pending_out"))
+
+
+def tiny_cell():
+    loaded = spec.load_cell(spec.load_benchmark(), CELL)
+    cfg = copy.deepcopy(loaded["config"])
+    for part, over in TINY.items():
+        cfg[part].update(over)
+    loaded["config"] = cfg
+    return loaded
+
+
+@contextlib.contextmanager
+def mesh_devices(n):
+    real = jax.devices
+    jax.devices = lambda *a: real(*a)[:n]
+    try:
+        yield
+    finally:
+        jax.devices = real
+
+
+def build(seed=7, n=4):
+    loaded = tiny_cell()
+    cfg, traffic = loaded["config"], loaded["traffic"]
+    with mesh_devices(n):
+        data, data_sum = resolve.generator(cfg)(seed, cfg["data"])
+        system = resolve.system_class(cfg, traffic)(cfg, traffic, data, seed)
+    init = resolve.reference(cfg).init_tables(seed, cfg)
+    return loaded, system, init, data_sum
+
+
+def first_call_of(seed=7):
+    loaded, system, init, data_sum = build(seed)
+    state, warm = window.queue_call(system, system.place(init))
+    warm.wait()
+    return loaded, system, init, data_sum, state, warm
+
+
+@pytest.fixture(scope="module")
+def first_call():
+    """One ``run_indexed`` epoch of the timed entry from the benchmark's
+    seeded tables, and the reference's replay of it."""
+    loaded, system, init, data_sum, state, warm = first_call_of()
+    program = system.export(*state)
+    numbers, (ref_tables, ref_loss, ref_n) = check.compare_call(
+        system, loaded["config"], init, program, warm.host, data_sum)
+    return dict(loaded=loaded, system=system, init=init, program=program,
+                numbers=numbers, host=warm.host, data_sum=data_sum,
+                state=state, ref_tables=ref_tables, ref_loss=ref_loss,
+                ref_n=ref_n)
+
+
+def test_spec_validates_the_committed_benchmark_files():
+    bench = spec.load_benchmark()
+    spec.validate(bench)
+    cells = bench["workloads"]
+    assert len(cells) == 8 and sorted(
+        w["name"] for w in cells if w["chips"] == 4) == [
+            "mf-netflix.x4", CELL]
+    cell = spec.load_cell(bench, CELL)
+    assert cell["cell"]["chips"] == 4 and cell["traffic"]["name"] == "x4"
+    old = spec.load_cell(bench, "w2v-1bw.epochs")
+    assert set(old["readers"]) | {
+        "store.collective_ms_per_step", "store.hot_accumulate_ms_per_step",
+        "store.reconcile_ms_per_step", "store.hot_routes_in_program",
+        "store.hot_hit_percent"} == set(cell["readers"])
+    cfg, base = cell["config"], old["config"]
+    assert cfg["reduced"] == ["tokens_resident"]
+    # w2v-1bw's model key for key, but the kind and the tier's three.
+    added = {"kind": "word2vec_sgns_hot", "hot_tier": 32768,
+             "hot_sync_every": 8, "cold_budget": 0}
+    assert cfg["model"] == dict(base["model"], **added)
+    assert cfg["data"] == dict(base["data"],
+                               tokens_resident=cfg["data"]["tokens_resident"])
+    assert cfg["departures"][:-1] == base["departures"]
+    for k in ("rows_per_worker_step", "row_bytes"):
+        assert cfg["rowops"][k] == base["rowops"][k]
+    assert set(cfg["limits"]) == set(GAPS) | set(EXACT)
+    assert all(cfg["limits"][k] == 0 for k in EXACT)
+    assert len(next(c for c in bench["configs"]
+                    if c["name"] == cfg["name"])["source"]) <= 200
+
+
+def test_the_cells_plan_is_whole_windows_by_the_laws_counts():
+    """``tokens_resident`` was chosen so that the plan's steps are a
+    multiple of the window: the plan's own sizing, on the law's expected
+    kept share (no data made)."""
+    from perfbench.datasets import token_stream
+
+    cfg = spec.load_cell(spec.load_benchmark(), CELL)["config"]
+    d, m = cfg["data"], cfg["model"]
+    f = token_stream.unigram_counts(d) / d["corpus_tokens"]
+    kept = d["tokens_resident"] * float(
+        (f * np.minimum(1.0, np.sqrt(m["subsample_t"] / f))).sum())
+    bound = kept + 8.0 * np.sqrt(kept + 1.0) + 1024
+    steps = bound / (m["block_len"] * 4)
+    assert int(np.ceil(steps)) == 168 and 168 % m["hot_sync_every"] == 0
+    # ... well inside: the seed moves the kept count by about 900 tokens.
+    assert 0.25 < steps - 167 < 0.75
+    # The hot share ISSUE 44 reckons: positions 62 %, negatives 39.5 %.
+    Hc = m["hot_tier"]
+    kf = f * np.minimum(1.0, np.sqrt(m["subsample_t"] / f))
+    neg = f ** m["neg_power"]
+    assert abs(kf[:Hc].sum() / kf.sum() - 0.62) < 0.005
+    assert abs(neg[:Hc].sum() / neg.sum() - 0.395) < 0.005
+
+
+def test_program_agrees_with_the_reference_over_a_ragged_call(first_call):
+    c = first_call
+    T = int(c["system"].plan.steps_per_epoch)
+    assert T % E != 0 and T > 2 * E     # whole windows and a ragged tail
+    loss = np.concatenate([m["loss"] for m in c["host"]])
+    n = np.concatenate([m["n"] for m in c["host"]])
+    assert loss.shape == c["ref_loss"].shape == (T,)
+    np.testing.assert_array_equal(n, c["ref_n"])
+    np.testing.assert_allclose(loss, c["ref_loss"], rtol=F32_GAP)
+    for name in (sgns_block_hot.IN, sgns_block_hot.OUT):
+        ref = np.asarray(c["ref_tables"][name])
+        np.testing.assert_allclose(c["program"][name], ref, rtol=0,
+                                   atol=F32_GAP * np.abs(ref).max())
+        # ... and the call moved head and tail of both tables.
+        moved = np.abs(ref - c["init"][name]).max(axis=1)
+        assert moved[:H].max() > 1e-4 and moved[H:].max() > 1e-4
+    # The tier served and took a share of the rows in every step.
+    for t in (sgns_block_hot.IN, sgns_block_hot.OUT):
+        hot = np.concatenate([m[f"hot_tier.{t}.hot_rows"] for m in c["host"]])
+        pulled = np.concatenate([m[f"hot_tier.{t}.pulled_rows"]
+                                 for m in c["host"]])
+        # (an empty step's padding positions hold word 0, a hot one)
+        assert (0 < hot[n > 0]).all() and (hot[n > 0] < pulled[n > 0]).all()
+        assert (hot <= pulled).all()
+
+
+def test_every_compared_number_is_inside_a_float32_gap(first_call):
+    numbers = first_call["numbers"]
+    assert set(numbers) == set(GAPS) | set(EXACT)
+    assert all(numbers[k] == 0 for k in EXACT), numbers
+    assert max(numbers[k] for k in GAPS) < F32_GAP, numbers
+    within, _ = check.judge(numbers, first_call["loaded"]["config"]["limits"])
+    assert within
+
+
+def test_replica_equals_head_bit_for_bit_at_the_calls_end(first_call):
+    """One canonical table: what the program's replicas hold is the
+    tables' heads, exactly; ``pending_*`` is that difference."""
+    from fps_tpu.core.store import hot_key
+
+    p = first_call["program"]
+    tables = first_call["state"][0]
+    for name, hot, pend in (("in_embeddings", "hot_in", "pending_in"),
+                            ("out_embeddings", "hot_out", "pending_out")):
+        assert np.array_equal(p[hot], p[name][:H])
+        assert np.array_equal(np.asarray(tables[hot_key(name)]), p[hot])
+        assert p[pend].shape == (H, 17) and not p[pend].any()
+
+
+@pytest.mark.parametrize("other", [1, 2 * E])
+def test_the_reference_at_another_window_is_not_the_program(first_call,
+                                                            other):
+    """What ``hot_window.py`` reads on the chip: the same call against the
+    reference reconciling every step (the synchronous program) and half
+    as often fails the comparison by orders of magnitude."""
+    c = first_call
+    replay = copy.deepcopy(c["loaded"]["config"])
+    replay["model"]["hot_sync_every"] = other
+    numbers, _ = check.compare_call(c["system"], replay, c["init"],
+                                    c["program"], c["host"], c["data_sum"])
+    assert all(numbers[k] == 0 for k in EXACT), numbers
+    worst = max(numbers[k] for k in GAPS)
+    assert worst > 200 * F32_GAP, numbers
+    readings = {E: c["numbers"], other: numbers}
+    limits = c["loaded"]["config"]["limits"]
+    assert hot_window.unheld(readings, limits, E) == []
+    loose = dict(limits, **{k: 1.0 for k in GAPS})
+    assert hot_window.unheld(readings, loose, E) == [other]
+
+
+def _never_reconciles(monkeypatch):
+    """The window's sums are thrown away: no hot row ever moves."""
+    def dropped(cold, replica, delta, **kw):
+        return cold, replica, jnp.zeros_like(delta), kw.get("fold_state")
+    monkeypatch.setattr(driver, "reconcile_hot", dropped)
+
+
+def _reads_its_own_pending_rows(monkeypatch):
+    """A worker's replica takes the worker's own pushes at once (what it
+    added to its pending buffer in the step), and the window's combined
+    step on top of that at the reconcile."""
+    real = driver.Trainer._windowed_scan
+
+    def leaky(self, step, carry0, tier, **kw):
+        def own(c, x):
+            c2, out = step(c, x)
+            hot = {n: c2[1][n] + (c2[2][n] - c[2][n])[:, :-1].astype(
+                c2[1][n].dtype) for n in c2[1]}
+            return (c2[0], hot) + tuple(c2[2:]), out
+        return real(self, own, carry0, tier, **kw)
+    monkeypatch.setattr(driver.Trainer, "_windowed_scan", leaky)
+
+
+@pytest.mark.parametrize("break_program", [_never_reconciles,
+                                           _reads_its_own_pending_rows])
+def test_broken_timed_path_is_not_correct(monkeypatch, break_program):
+    break_program(monkeypatch)
+    loaded, system, init, data_sum, state, warm = first_call_of(seed=9)
+    numbers, _ = check.compare_call(system, loaded["config"], init,
+                                    system.export(*state), warm.host,
+                                    data_sum)
+    within, rows = check.judge(numbers, loaded["config"]["limits"])
+    assert not within, rows
+    assert max(numbers[k] for k in GAPS) > 200 * F32_GAP, numbers
+    if break_program is _reads_its_own_pending_rows:
+        # ... and its replicas are no longer the tables' heads.
+        assert numbers["table_gap.pending_in"] > 0
+
+
+def test_with_no_head_the_reference_is_sgns_block():
+    loaded, system, init, _ = build(seed=5)
+    cfg = copy.deepcopy(loaded["config"])
+    cfg["model"]["hot_tier"] = 0
+    chunk, live = next(iter(system.fed_chunks(0, 8)))
+    assert live == 8
+
+    def run(ref, tables):
+        step = ref.make_step(cfg, workers=system.W)
+        return jax.lax.scan(step, tables, chunk)
+
+    t0 = sgns_block_hot.init_tables(5, cfg)
+    assert t0["hot_in"].shape == (0, 16)
+    new, out_new = run(sgns_block_hot, t0)
+    old, out_old = run(sgns_block, {k: t0[k] for k in ("in_embeddings",
+                                                       "out_embeddings")})
+    for k in old:
+        assert np.array_equal(np.asarray(new[k]), np.asarray(old[k]))
+        assert np.abs(np.asarray(old[k]) - t0[k]).max() > 0
+    for k in out_old:
+        assert np.array_equal(np.asarray(out_new[k]), np.asarray(out_old[k]))
+
+
+def test_bf16_control_fails_the_comparison():
+    loaded, system, init, data_sum = build(seed=5)
+    cfg = loaded["config"]
+    ref, ref_loss, ref_n, feed = check.run_reference(system, cfg, init)
+    lt, low_loss, low_n, low_feed = check.run_reference(
+        system, cfg, init, dtype=jnp.bfloat16)
+    low = check.compare(
+        {k: np.asarray(v, np.float32) for k, v in lt.items()}, ref, init,
+        low_loss, low_n, ref_loss, ref_n, low_feed,
+        check.call_checksum(system, data_sum), system.examples_per_call)
+    assert all(low[k] == 0 for k in EXACT), low
+    assert max(low[k] for k in GAPS) > 1000 * F32_GAP, low
+    assert not check.judge(low, cfg["limits"])[0]
+
+
+def test_the_tier_needs_more_than_one_device():
+    with pytest.raises(RuntimeError, match="did not engage"):
+        build(n=1)
+
+
+# -- what the tier leaves in the program and in the journal ----------------
+
+@pytest.fixture(scope="module")
+def traced_program():
+    """The epoch program's named-scope paths (from its lowered text) and
+    the route log of that one trace."""
+    import re
+
+    from fps_tpu import ops
+
+    _, system, init, _ = build(seed=3)
+    trainer, plan = system.trainer, system.plan
+    tables, ls = system.place(init)
+    tables = trainer._attach_hot(tables)
+    ops.clear_routes()
+    fn = trainer._build_indexed_fn(plan, "sync")
+    from fps_tpu.parallel.mesh import key_to_replicated
+
+    text = fn.lower(tables, ls, plan.epoch_args(0), np.int32(0),
+                    key_to_replicated(jax.random.key(0), trainer.mesh)
+                    ).as_text(debug_info=True)
+    routes = ops.routes_traced()
+    paths = set(re.findall(r'loc\("([^"]*)"', text))
+    return paths, routes, int(plan.steps_per_epoch)
+
+
+def test_hot_accumulate_lies_inside_the_push_and_the_reconcile_has_no_prefix(
+        traced_program):
+    paths, _, _ = traced_program
+    assert any("fps.push/fps.hot_accumulate/" in p for p in paths)
+    assert not [p for p in paths if "fps.hot_accumulate" in p
+                and "fps.push/fps.hot_accumulate" not in p]
+    assert any(p.startswith("hot.reconcile/") for p in paths)
+    assert not [p for p in paths if "fps.reconcile" in p]
+    # Inside the reconcile only the routed gather carries the prefix.
+    inside = {p.split("hot.reconcile/", 1)[1] for p in paths
+              if "hot.reconcile/" in p}
+    assert {p.split("/")[0] for p in inside if "fps." in p} == {"fps.ops"}
+
+
+def test_route_log_names_the_hot_reads_writes_and_reconciles(traced_program):
+    _, routes, T = traced_program
+    # The step is traced once (both loops scan the same function); the
+    # window's end twice: in the loop of whole windows and after the tail.
+    assert T % E
+    hot = [r for r in routes if r.route.endswith(".hot")]
+    for route, dim, times in (("pull.hot", 16, 1), ("push.hot", 17, 1),
+                              ("reconcile.hot", 17, 2)):
+        got = [r for r in hot if r.route == route]
+        assert sorted(r.reason.split()[0] for r in got) == sorted(
+            times * ["table=in_embeddings", "table=out_embeddings"]), got
+        assert {(r.rows, r.dim) for r in got} == {(H, dim)}
+    rec = next(r for r in hot if r.route == "reconcile.hot")
+    assert rec.reason.split()[1:] == [
+        f"every={E}", "combine=mean", "shards=4", f"bytes={H * 17 * 4}"]
+    ids = {r.reason: r.ids for r in hot if r.route == "pull.hot"}
+    assert ids == {"table=in_embeddings": 69, "table=out_embeddings": 6 * 69}
+
+
+def test_a_call_nobody_fetches_is_counted_when_its_metrics_arrive():
+    """``run_indexed(as_numpy=False)`` under a recorder: the hot tier's
+    counters equal those of the fetching way of driving, and the call's
+    sums ride its ``device.run_indexed`` span."""
+    from fps_tpu import obs
+    from fps_tpu.obs import events
+
+    def counters(sink):
+        return {(m["name"], m["labels"]["table"]): m["value"]
+                for m in sink.snapshot_metrics()
+                if m["name"].startswith("hot_tier.")}
+
+    got = {}
+    for as_numpy in (False, True, "on_epoch"):
+        _, system, init, _ = build(seed=4)
+        sink = obs.MemorySink()
+        rec = obs.Recorder(sinks=[sink])
+        events.set_default_recorder(rec)
+        try:
+            tables, ls = system.place(init)
+            _, _, metrics = system.trainer.run_indexed(
+                tables, ls, system.plan, system.key, epochs=1,
+                as_numpy=as_numpy is not False, recorder=rec,
+                on_epoch=(lambda e, m: None) if as_numpy == "on_epoch"
+                else None)
+            if not as_numpy:
+                assert not isinstance(metrics[0]["n"], np.ndarray)
+        finally:
+            events.set_default_recorder(None)   # waits for the span
+        rec.flush()
+        spans = [e for e in sink.events("span")
+                 if e["span"] == "device.run_indexed"]
+        assert len(spans) == 1
+        got[as_numpy] = (rec, spans[0], metrics)
+        if as_numpy == "on_epoch":     # a run that syncs every epoch
+            (epoch,) = sink.events("epoch")
+    (rec_d, span_d, m_d), (rec_h, span_h, m_h) = got[False], got[True]
+    # ... writes the same sums on its epoch event, and nothing on the span.
+    assert epoch["hot_tier"] == span_d["hot_tier"]
+    assert "hot_tier" not in got["on_epoch"][1]
+    assert "hot_tier" not in span_h and set(span_d["hot_tier"]) == {
+        "in_embeddings", "out_embeddings"}
+    for table, sums in span_d["hot_tier"].items():
+        ch = m_h[0]["hot_tier"][table]
+        assert sums["hot_rows"] == float(np.sum(ch["hot_rows"])) > 0
+        assert sums["pulled_rows"] == float(np.sum(ch["pulled_rows"]))
+        assert sums["cold_dropped"] == 0
+        assert sums["pending_delta"] == pytest.approx(
+            float(np.sqrt(np.max(ch["delta_sq"]))))
+        for name in ("hot_tier.hot_rows", "hot_tier.pulled_rows"):
+            assert (rec_d.counter_value(name, table=table)
+                    == rec_h.counter_value(name, table=table) > 0)
+
+
+# -- the cell through the runner --------------------------------------------
+
+def test_cell_rehearsal_runs_the_runners_whole_path():
+    events = []
+    with mesh_devices(4):
+        result = runner.run_cell(
+            tiny_cell(), seed=2_147_484_123, seconds=0.3, trace=False,
+            t_start=time.perf_counter(),
+            emit=lambda event, **f: events.append(dict(event=event, **f)),
+            out_dir="unused")
+    compared = [e for e in events if e["event"] == "compared"]
+    assert result["correct"], compared
+    assert result["failed"] == 0 and result["attempted"] >= 2
+    assert set(result["metrics"]) == {"setup_s", "examples_per_s"}
+    assert {e["number"] for e in compared} == set(
+        tiny_cell()["config"]["limits"]) | {"programs_lowered_in_window"}
