@@ -73,6 +73,7 @@ from fps_tpu.core.store import (
     split_hot_push,
     split_hot_push_slots,
     split_tiering,
+    watch_routed,
 )
 from fps_tpu.obs.health import (
     HEALTH_ABORT,
@@ -556,6 +557,12 @@ class Trainer:
 
     # -- device-side bodies ----------------------------------------------
 
+    @property
+    def _data_axis(self) -> str | None:
+        """The data axis where it replicates (what ``store.pull`` / ``push``
+        / ``reconcile_hot`` take as ``data_axis``), else ``None``."""
+        return DATA_AXIS if self.mesh.shape[DATA_AXIS] > 1 else None
+
     def _resolve_hot_rows(self, spec) -> int:
         """LOCAL rows of a table's certified head: global head ids
         ``[0, H)`` (``spec.hot_ids``) sit in local rows ``[0, ceil(H/S))``
@@ -988,12 +995,13 @@ class Trainer:
                 pdeltas,
                 num_shards=self.num_shards,
                 shard_axis=SHARD_AXIS,
-                data_axis=DATA_AXIS if self.mesh.shape[DATA_AXIS] > 1 else None,
+                data_axis=self._data_axis,
                 apply_fn=self.server_logic[name].apply_fn,
                 combine=self.server_logic[name].combine,
                 hot_rows=hot_local,
                 dense=self._resolve_dense(spec),
                 head_prefix=head_prefix.get(name, 0),
+                table=name,
             )
         return new_tables
 
@@ -1041,6 +1049,7 @@ class Trainer:
                         sk[name] = _sketch.cm_update(
                             track[name], sk[name], ids[name])
         hot_counts = {}
+        data_axis = self._data_axis
         # fps.pull / fps.compute named scopes: device-timeline attribution
         # for the phases the host PhaseTimer cannot split (pull, worker
         # compute, and push fuse into one dispatch) — pure op metadata,
@@ -1102,6 +1111,7 @@ class Trainer:
                             num_shards=self.num_shards,
                             dense=False,
                             hot_rows=self._resolve_hot_rows(spec),
+                            data_axis=data_axis, table=name,
                         )
                         vals = ops.gather_rows(lane_vals, pos)
                         hot_counts[name]["cold_dropped"] = over
@@ -1111,6 +1121,7 @@ class Trainer:
                             dense=self._resolve_dense(spec),
                             hot_rows=self._resolve_hot_rows(spec),
                             head_prefix=hp.get(name, 0),
+                            data_axis=data_axis, table=name,
                         )
                     if H:
                         vals = jnp.where(hmask[:, None], hot_vals, vals)
@@ -1385,7 +1396,7 @@ class Trainer:
                                      carry[3])
         tables, hot, delta = dict(tables), dict(hot), dict(delta)
         folds = dict(folds)
-        data_axis = DATA_AXIS if self.mesh.shape[DATA_AXIS] > 1 else None
+        data_axis = self._data_axis
         # Once a WINDOW of hot_sync_every steps: named without the fps.
         # prefix (obs.timing.ROUND_SCOPES), in sync mode and inside
         # ssp.snapshot alike.
@@ -1472,12 +1483,16 @@ class Trainer:
         return carry, outs
 
     def _mount_hot_channel(self, out, hot_counts, delta, tier,
-                           dropped=None):
+                           dropped=None, routed=None):
         """Attach the hot-tier telemetry to the worker out channel (the
         health channel's transport): per-table hit counts plus the
         pending-buffer magnitude — the parameter-plane staleness gauge —
         and, on the compacted cold routes, the budget-overflow drop
-        count (zero for every host-certified chunk). Traced only when
+        count (zero for every host-certified chunk); and, where the
+        table's cold rows cross shards by the non-dense exchange, the
+        step's ``routed`` flag (``store.watch_routed``: 1 where its pull
+        and push ran owner-routed, 0 where one ran gathered; worker 0
+        alone carries it into the channel's sum). Traced only when
         the tier is on; same dict/collision contract as the guard's
         health entry."""
         if not tier:
@@ -1494,12 +1509,16 @@ class Trainer:
                 "it would collide with the tier's counters"
             )
         dropped = dropped or {}
+        routed = routed or {}
         chan = {}
         for name, H in sorted(tier.items()):
             counts = dict(hot_counts.get(name, {}))
             if name in dropped:
                 counts["cold_dropped"] = (
                     counts.get("cold_dropped", 0) + dropped[name])
+            if name in routed:
+                counts["routed"] = jnp.where(worker_index() == 0,
+                                             routed[name], 0)
             buf = delta[name]
             combine = self._hot_combine(name)
             dim = buf.shape[1] - (
@@ -1571,22 +1590,23 @@ class Trainer:
                  key, t) = carry
                 key, sub = jax.random.split(key)
                 tapped = self._tap_step(tables, batch_t, local_state, t)
-                (pushes, local_state, out, hp, hcounts,
-                 sk) = self._compute_step(
-                    tables, snapshot, local_state, batch_t, sub,
-                    hot=hot, tier=tier, maps=maps, track=track, sk=sk,
-                    compact=compact,
-                )
-                hp_seen.update(hp)  # static, identical every traced step
-                dropped = {}
-                if tier:
-                    tables, delta, dropped = self._apply_hot_split(
-                        tables, delta, pushes, tier, hp, maps, compact)
-                else:
-                    tables, bufs = self._apply_or_buffer(
-                        tables, bufs, t, pushes, hp)
+                with watch_routed() as routed:
+                    (pushes, local_state, out, hp, hcounts,
+                     sk) = self._compute_step(
+                        tables, snapshot, local_state, batch_t, sub,
+                        hot=hot, tier=tier, maps=maps, track=track, sk=sk,
+                        compact=compact,
+                    )
+                    hp_seen.update(hp)  # static, the same every traced step
+                    dropped = {}
+                    if tier:
+                        tables, delta, dropped = self._apply_hot_split(
+                            tables, delta, pushes, tier, hp, maps, compact)
+                    else:
+                        tables, bufs = self._apply_or_buffer(
+                            tables, bufs, t, pushes, hp)
                 out = self._mount_hot_channel(out, hcounts, delta, tier,
-                                              dropped)
+                                              dropped, routed)
                 with jax.named_scope("fps.metrics"):
                     out = jax.tree.map(
                         lambda x: lax.psum(lax.psum(x, SHARD_AXIS),
@@ -1863,21 +1883,22 @@ class Trainer:
                 with jax.named_scope("fps.ingest"):
                     batch = plan.local_batch_at(iargs, widx, t)
                 tapped = self._tap_step(tables, batch, local_state, t)
-                (pushes, local_state, out, hp, hcounts,
-                 sk) = self._compute_step(
-                    tables, snapshot, local_state, batch, sub,
-                    hot=hot, tier=tier, maps=maps, track=track, sk=sk,
-                )
-                hp_seen.update(hp)  # static, identical every traced step
-                dropped = {}
-                if tier:
-                    tables, delta, dropped = self._apply_hot_split(
-                        tables, delta, pushes, tier, hp, maps)
-                else:
-                    tables, bufs = self._apply_or_buffer(
-                        tables, bufs, t, pushes, hp)
+                with watch_routed() as routed:
+                    (pushes, local_state, out, hp, hcounts,
+                     sk) = self._compute_step(
+                        tables, snapshot, local_state, batch, sub,
+                        hot=hot, tier=tier, maps=maps, track=track, sk=sk,
+                    )
+                    hp_seen.update(hp)  # static, the same every traced step
+                    dropped = {}
+                    if tier:
+                        tables, delta, dropped = self._apply_hot_split(
+                            tables, delta, pushes, tier, hp, maps)
+                    else:
+                        tables, bufs = self._apply_or_buffer(
+                            tables, bufs, t, pushes, hp)
                 out = self._mount_hot_channel(out, hcounts, delta, tier,
-                                              dropped)
+                                              dropped, routed)
                 with jax.named_scope("fps.metrics"):
                     out = jax.tree.map(
                         lambda x: lax.psum(lax.psum(x, SHARD_AXIS),
@@ -2061,20 +2082,52 @@ class Trainer:
                            "cold_dropped": dropped, "pending_delta": peak}
         return sums
 
+    @staticmethod
+    def _record_exchange(rec, ht) -> dict:
+        """Fold the ``routed`` flags of one unit's HOST hot-tier channel
+        into the recorder (``exchange.routed_steps`` / ``exchange.steps``)
+        and return the unit's own sums a table, the journal's ``exchange``
+        field: of the ``steps`` in which the table's cold rows crossed
+        shards by the non-dense exchange, ``routed_steps`` ran it
+        owner-routed (the others gathered: a step whose ids did not fit
+        their lanes, or shapes that keep the exchange gathered). ``{}``
+        where no table carries the flag."""
+        sums = {}
+        for table, counters in ht.items():
+            if "routed" not in counters:
+                continue
+            flags = np.asarray(counters["routed"])
+            sums[table] = {"routed_steps": float(np.count_nonzero(flags)),
+                           "steps": float(flags.size)}
+            for k, v in sums[table].items():
+                rec.inc(f"exchange.{k}", v, table=table)
+        return sums
+
+    def _record_tier_channel(self, rec, ht) -> dict:
+        """Both folds of a unit's hot-tier channel, as the fields they
+        set on the journal's event (``exchange`` only where it holds
+        something)."""
+        fields = {"hot_tier": self._record_hot_tier(rec, ht)}
+        exchange = self._record_exchange(rec, ht)
+        if exchange:
+            fields["exchange"] = exchange
+        return fields
+
     def _hot_tier_later(self, metrics):
         """What ``watch_device`` runs once a unit whose metrics stay on
         the device has completed (``run_indexed(as_numpy=False)``): the
-        hot tier's counters counted then, from a copy that waits for
-        nothing, and the unit's sums handed back for its ``device.*``
-        span, the journal's record of the epoch's completion (its
-        ``epoch`` event is written at dispatch, before the numbers
-        exist). ``None`` when the tier is off."""
+        hot tier's counters (and its tables' ``routed`` flags) counted
+        then, from a copy that waits for nothing, and the unit's sums
+        handed back for its ``device.*`` span, the journal's record of
+        the epoch's completion (its ``epoch`` event is written at
+        dispatch, before the numbers exist). ``None`` when the tier is
+        off."""
         ht = (metrics.get(resilience.HOT_TIER_KEY)
               if isinstance(metrics, Mapping) else None)
         if not ht:
             return None
-        return lambda rec: {"hot_tier": self._record_hot_tier(
-            rec, jax.tree.map(np.asarray, ht))}
+        return lambda rec: self._record_tier_channel(
+            rec, jax.tree.map(np.asarray, ht))
 
     def _fold_metrics_accounting(self, rec, metrics, ev=None) -> int:
         """The one per-chunk/epoch telemetry fold for a HOST metrics tree:
@@ -2087,9 +2140,9 @@ class Trainer:
         ht = (metrics.get(resilience.HOT_TIER_KEY)
               if isinstance(metrics, Mapping) else None)
         if ht and rec is not None:
-            sums = self._record_hot_tier(rec, ht)
+            fields = self._record_tier_channel(rec, ht)
             if ev is not None:
-                ev["hot_tier"] = sums
+                ev.update(fields)
         if rec is not None:
             if poison:
                 rec.inc("health.poisoned_chunks")

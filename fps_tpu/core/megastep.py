@@ -67,6 +67,7 @@ from fps_tpu.core.store import (
     replica_from_shard,
     sketch_key,
     split_tiering,
+    watch_routed,
 )
 from fps_tpu.obs.timing import PhaseTimer, host_span, watch_device
 from fps_tpu.parallel.mesh import (
@@ -187,21 +188,22 @@ def build_megastep_fn(trainer, plan, mode: str, K: int, tick=None):
                 with jax.named_scope("fps.ingest"):
                     batch = plan.local_batch_at(iargs, widx, t)
                 tapped = trainer._tap_step(tables, batch, local_state, t)
-                (pushes, local_state, out, hp, hcounts,
-                 sk) = trainer._compute_step(
-                    tables, snapshot, local_state, batch, sub,
-                    hot=hot, tier=tier, maps=maps, track=track, sk=sk,
-                    compact=compact_map,
-                )
-                dropped = {}
-                if tier:
-                    tables, delta, dropped = trainer._apply_hot_split(
-                        tables, delta, pushes, tier, hp, maps,
-                        compact_map)
-                else:
-                    tables = trainer._apply_pushes(tables, pushes, hp)
+                with watch_routed() as routed:
+                    (pushes, local_state, out, hp, hcounts,
+                     sk) = trainer._compute_step(
+                        tables, snapshot, local_state, batch, sub,
+                        hot=hot, tier=tier, maps=maps, track=track, sk=sk,
+                        compact=compact_map,
+                    )
+                    dropped = {}
+                    if tier:
+                        tables, delta, dropped = trainer._apply_hot_split(
+                            tables, delta, pushes, tier, hp, maps,
+                            compact_map)
+                    else:
+                        tables = trainer._apply_pushes(tables, pushes, hp)
                 out = trainer._mount_hot_channel(out, hcounts, delta,
-                                                 tier, dropped)
+                                                 tier, dropped, routed)
                 with jax.named_scope("fps.metrics"):
                     out = jax.tree.map(_psum_workers, out)
                 out = trainer._mount_tap(out, tapped)

@@ -34,6 +34,19 @@ Inside ``shard_map``:
 Everything is static-shape and jit-compatible; XLA lowers the collectives
 onto ICI when the mesh spans a pod slice.
 
+That is the GATHERED exchange: every shard is handed every worker's ids
+(and a push's rows), ``O(W * B)`` a shard. Across more than one shard, with
+no data axis and a batch of at least :data:`ROUTED_MIN_IDS_PER_SHARD` ids
+a shard, :func:`pull` and :func:`push` take the OWNER-ROUTED exchange
+instead: a worker's ids are placed into one lane an owner shard
+(:func:`_owner_lanes`, ``LANE_MARGIN * B / S`` wide), the lanes are traded
+by ``all_to_all``, and a shard is handed ``LANE_MARGIN * B`` ids, all of
+them its own: the reference's own routing, a push goes to the one
+partition that owns the key. Whether a step's ids fit their lanes is
+certified in the graph each call (one ``pmax`` over the shard axis), and a
+step that does not fit runs the gathered exchange: nothing is ever
+dropped. Small tables keep the DENSE exchange (``dense=True``).
+
 Two-tier hot storage (``TableSpec.hot_tier``)
 ---------------------------------------------
 Real id streams are Zipf-skewed (ML20M users, text8 vocab, Criteo
@@ -69,9 +82,11 @@ zero-cost claim provable by lowered-HLO comparison).
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
+import math
 from functools import partial
-from typing import Any, Callable, Mapping
+from typing import Any, Callable, Mapping, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -638,6 +653,153 @@ def reconcile_hot(
 # Collective pull / push (call inside shard_map).
 # ---------------------------------------------------------------------------
 
+# The owner-routed exchange's lane: a worker's ``B`` ids fall ``B / S`` to
+# an owner shard on average, and a lane is LANE_MARGIN times that, rounded
+# up to 8 (a sublane tile). The owner is ``id % S``, which no skew of
+# FREQUENCY unbalances but by the few most frequent ids themselves (the
+# cyclic layout's reason for being): under word2vec's Zipf law over 1.1 M
+# words, untiered, shard 0 owns 1.09 x its share of a batch (it has rank 0);
+# with the head replicated the cold ids are near uniform, and at
+# ``w2v-1bw-hot.x4``'s smaller batch (8,197 ids, 4 shards: 2,049 +- 39 an
+# owner if every id were live) the lane of 2,568 stands 13 standard
+# deviations over the mean. A batch that does not fit runs the gathered
+# exchange that step. Under ROUTED_MIN_IDS_PER_SHARD ids a shard a lane
+# is mostly its rounding up to 8 (at 2 ids a shard the lanes hold as many
+# slots as the gathered exchange hands ids), and the exchange stays
+# gathered.
+LANE_MARGIN = 1.25
+ROUTED_MIN_IDS_PER_SHARD = 8
+
+
+def _lane_width(num_ids: int, num_shards: int) -> int:
+    return -(-math.ceil(LANE_MARGIN * num_ids / num_shards) // 8) * 8
+
+
+def _routes_to_owner(num_ids: int, num_shards: int,
+                     data_axis: str | None) -> bool:
+    """Whether a non-dense exchange of ``num_ids`` ids a worker is the
+    owner-routed one, from what :func:`pull` and :func:`push` see."""
+    return (num_shards > 1 and data_axis is None
+            and num_ids >= ROUTED_MIN_IDS_PER_SHARD * num_shards)
+
+
+class _Lanes(NamedTuple):
+    """A worker's ids routed by owner (:func:`_owner_lanes`)."""
+    fits: Array  # scalar bool, the same on every shard: no lane overflows
+    ids: Array   # [S, L] the ids of lane ``d`` are shard ``d``'s; -1 pads
+    src: Array   # [S * L] the batch position a lane slot holds, -1 pads
+    slot: Array  # [B] the flat lane slot of each id, S * L for one in none
+
+
+def _owner_lanes(ids: Array, *, num_shards: int, shard_axis: str) -> _Lanes:
+    """Place a worker's ``(B,)`` ids into one lane an owner shard, in the
+    batch's order (:func:`compact_cold`'s method, once an owner: a stable
+    position ``cumsum(live & owner == d) - 1``); negative ids (padding, the
+    tier's hot ids, the worker's dropped rows) take no lane. The lanes are
+    filled from ONE sort of the ids by their slot and a slice a lane, not
+    by a scatter. ``fits``: the fullest lane of any worker holds its ids,
+    by a ``pmax`` over the shard axis, so every shard takes the same
+    branch; an id past its lane's end is left out of the lane, which only
+    a caller that ignores ``fits`` would see."""
+    S, (B,) = num_shards, ids.shape
+    L = _lane_width(B, S)
+    live, owner = ids >= 0, ids % S
+    slot = jnp.full((B,), S * L, jnp.int32)
+    counts = []
+    for d in range(S):
+        mine = live & (owner == d)
+        pos = jnp.cumsum(mine.astype(jnp.int32)) - 1
+        slot = jnp.where(mine & (pos < L), d * L + pos, slot)
+        counts.append(pos[-1] + 1)
+    counts = jnp.stack(counts)
+    fits = lax.pmax(jnp.max(counts), shard_axis) <= L
+    # Sorted by slot an owner's ids are contiguous, in the batch's order,
+    # the ids of no lane last: lane d is L of them from where owner d's
+    # begin, cut at its count (the keys of the laned are distinct).
+    _, by_slot, at = lax.sort(
+        (slot, ids, jnp.arange(B, dtype=jnp.int32)), num_keys=1,
+        is_stable=False)
+    held = jnp.minimum(counts, L)
+    begin = jnp.cumsum(held) - held
+    lane = jnp.arange(L, dtype=jnp.int32)
+
+    def lanes_of(x):
+        x = jnp.concatenate([x, jnp.full((L,), -1, x.dtype)])
+        return jnp.stack([
+            jnp.where(lane < held[d],
+                      lax.dynamic_slice(x, (begin[d],), (L,)), -1)
+            for d in range(S)])
+
+    return _Lanes(fits, lanes_of(by_slot), lanes_of(at).reshape(S * L), slot)
+
+
+def _rows_at(x: Array, idx: Array) -> Array:
+    """``x[idx]`` with ZERO rows where ``idx`` is negative: rows moved
+    between a batch and its lanes (a buffer's rows, not a table's: no
+    route of :mod:`fps_tpu.ops` is asked)."""
+    live = idx >= 0
+    rows = jnp.take(x, jnp.where(live, idx, 0), axis=0)
+    return jnp.where(live[:, None], rows, jnp.zeros_like(rows))
+
+
+def _lanes_of_call(op: str, local_shard: Array, ids: Array, *,
+                   num_shards: int, shard_axis: str,
+                   data_axis: str | None, table: str) -> _Lanes | None:
+    """What a non-dense :func:`pull` / :func:`push` (``op``) opens with:
+    the lanes of its ids where the exchange is the owner-routed one
+    (logged as ``<op>.routed`` with the shard's shape, the worker's ids
+    and ``lanes=SxL``), ``None`` where it stays gathered; either way the
+    step's ``routed`` flag is noted over more than one shard."""
+    B = ids.shape[0]
+    if not _routes_to_owner(B, num_shards, data_axis):
+        if num_shards > 1:
+            _note_routed(table, 0)
+        return None
+    lanes = _owner_lanes(ids, num_shards=num_shards, shard_axis=shard_axis)
+    S, L = lanes.ids.shape
+    ops.log_route(op, "routed", *local_shard.shape, B,
+                  f"table={table} lanes={S}x{L}" if table
+                  else f"lanes={S}x{L}")
+    _note_routed(table, lanes.fits)
+    return lanes
+
+
+def _trade(lanes: Array, shard_axis: str) -> Array:
+    """Lane ``d`` of every worker to shard ``d``: ``[S, L, ...]`` by
+    destination in, ``[S, L, ...]`` by source out (position-indexed data:
+    no reduction order is delegated to the backend)."""
+    return lax.all_to_all(lanes, shard_axis, split_axis=0, concat_axis=0,
+                          tiled=False)
+
+
+# The certificates of the exchanges traced while a watch is open, by the
+# table the caller named: how a step's ``routed`` flag leaves the step
+# (:meth:`fps_tpu.core.driver.Trainer._mount_hot_channel`).
+_ROUTED_WATCHES: list[dict] = []
+
+
+@contextlib.contextmanager
+def watch_routed():
+    """Collect ``{table: flag}`` while a step is traced: for each table
+    whose :func:`pull` / :func:`push` (called with ``table=``) went over
+    more than one shard by the non-dense exchange, an int32 scalar, the
+    same on every shard: 1 where every such exchange of the step ran
+    owner-routed, 0 where one ran gathered (its ids did not fit their
+    lanes, or the shapes keep the exchange gathered)."""
+    seen: dict = {}
+    _ROUTED_WATCHES.append(seen)
+    try:
+        yield seen
+    finally:
+        _ROUTED_WATCHES.pop()
+
+
+def _note_routed(table: str, fits) -> None:
+    if _ROUTED_WATCHES and table:
+        seen = _ROUTED_WATCHES[-1]
+        seen[table] = seen.get(table, 1) * jnp.asarray(fits, jnp.int32)
+
+
 def pull(
     local_shard: Array,
     ids: Array,
@@ -648,6 +810,8 @@ def pull(
     hot_rows: int = 0,
     head_prefix: int = 0,
     exact: bool = False,
+    data_axis: str | None = None,
+    table: str = "",
 ) -> Array:
     """Gather parameter rows for ``ids`` from the sharded table.
 
@@ -664,6 +828,20 @@ def pull(
       exact: bit-exact reads — forward to :func:`fps_tpu.ops.gather_rows`
         so read-only pulls (eval, export) skip the lossy dim-1 route
         instead of inheriting training's precision contract.
+      data_axis: the mesh's replicated data axis where it is larger than
+        one (:func:`push`'s argument): the exchange then stays gathered.
+      table: the table's name, for the route log and the step's
+        ``routed`` flag (:func:`watch_routed`); nothing else reads it.
+
+    Not ``dense``, over more than one shard with no data axis, the
+    exchange is the OWNER-ROUTED one (:func:`_routes_to_owner`;
+    ``pull.routed`` in the route log): the ids go to their owners in lanes
+    (:func:`_owner_lanes`), an owner gathers the ``S x L`` rows it is
+    asked for, sends them back by a second ``all_to_all``, and the worker
+    reads its ``B`` rows from its lanes' slots. Each row comes from one
+    shard and nothing is summed, so the rows are the gathered exchange's
+    bit for bit; a step whose ids do not fit their lanes runs the gathered
+    exchange (``lax.cond`` on the lanes' certificate).
 
     Returns:
       ``(B, dim)`` values, one row per requested id.
@@ -681,22 +859,44 @@ def pull(
         # wrap them into range via the Python-semantics modulo).
         phys = jnp.where(ids >= 0, id_to_phys(ids, num_shards, rps), -1)
         return ops.gather_rows(full, phys, exact=exact)
-    me = lax.axis_index(shard_axis)
-    # Every shard sees every worker's request ids: (S*B,).
-    all_ids = lax.all_gather(ids, shard_axis, tiled=True)
-    owned = (all_ids % num_shards) == me
-    local_idx = jnp.where(owned, all_ids // num_shards, 0)
-    # The head-prefix guarantee only survives when the gathered stream IS
-    # the caller's stream (single shard; local_idx == ids there).
-    vals = ops.gather_rows(
-        local_shard, local_idx, hot_rows=hot_rows,
-        head_prefix=head_prefix if num_shards == 1 else 0,
-        exact=exact,
-    )
-    vals = jnp.where(owned[:, None], vals, jnp.zeros_like(vals))
-    # Each worker ends up with its own (B, dim) slice, summed over shards
-    # (exactly one shard contributed each row).
-    return lax.psum_scatter(vals, shard_axis, scatter_dimension=0, tiled=True)
+
+    def gathered():
+        me = lax.axis_index(shard_axis)
+        # Every shard sees every worker's request ids: (S*B,).
+        all_ids = lax.all_gather(ids, shard_axis, tiled=True)
+        owned = (all_ids % num_shards) == me
+        local_idx = jnp.where(owned, all_ids // num_shards, 0)
+        # The head-prefix guarantee only survives when the gathered stream
+        # IS the caller's stream (single shard; local_idx == ids there).
+        vals = ops.gather_rows(
+            local_shard, local_idx, hot_rows=hot_rows,
+            head_prefix=head_prefix if num_shards == 1 else 0,
+            exact=exact,
+        )
+        vals = jnp.where(owned[:, None], vals, jnp.zeros_like(vals))
+        # Each worker ends up with its own (B, dim) slice, summed over
+        # shards (exactly one shard contributed each row).
+        return lax.psum_scatter(vals, shard_axis, scatter_dimension=0,
+                                tiled=True)
+
+    lanes = _lanes_of_call("pull", local_shard, ids, num_shards=num_shards,
+                           shard_axis=shard_axis, data_axis=data_axis,
+                           table=table)
+    if lanes is None:
+        return gathered()
+    dim = local_shard.shape[1]
+
+    def routed():
+        # The ids this shard owns, by the worker that asks (-1 pads: floor
+        # division keeps it negative, and it reads a zero row).
+        asked = _trade(lanes.ids, shard_axis).reshape(-1)
+        rows = ops.gather_rows(local_shard, asked // num_shards, exact=exact)
+        back = _trade(rows.reshape(num_shards, -1, dim), shard_axis)
+        return _rows_at(back.reshape(-1, dim),
+                        jnp.where(lanes.slot < asked.shape[0], lanes.slot,
+                                  -1))
+
+    return lax.cond(lanes.fits, routed, gathered)
 
 
 # Device scope of a non-"sum" combine's own work in :func:`push`, whatever
@@ -868,6 +1068,24 @@ def _gathered_exchange(ids: Array, deltas: Array, *, rps: int,
     return jnp.where(owned, ids // num_shards, rps), deltas, owned
 
 
+def _routed_exchange(lanes: _Lanes, deltas: Array, *, rps: int,
+                     num_shards: int,
+                     shard_axis: str) -> tuple[Array, Array, Array]:
+    """The owner-routed exchange of a push: a worker's rows follow its ids
+    into the lanes (one gather by the lanes' batch positions, padding
+    slots zero rows), lane ``d`` goes to shard ``d``, and a shard is handed
+    ``S x L`` pushes, all of them its own, by worker and then by the
+    batch's order: :func:`_gathered_exchange`'s ``(local_idx, deltas,
+    owned)`` without the rows of other shards (and with padding where a
+    lane was not full: ``rps``, dropped by the scatter)."""
+    dim = deltas.shape[1]
+    ids = _trade(lanes.ids, shard_axis).reshape(-1)
+    rows = _trade(_rows_at(deltas, lanes.src).reshape(num_shards, -1, dim),
+                  shard_axis).reshape(-1, dim)
+    owned = ids >= 0
+    return jnp.where(owned, ids // num_shards, rps), rows, owned
+
+
 def _dense_exchange(buf: Array, *, num_shards: int, shard_axis: str,
                     data_axis: str | None) -> Array:
     """The dense exchange of a push: from ``buf [num_shards * rps, W]``,
@@ -938,6 +1156,7 @@ def push(
     hot_rows: int = 0,
     dense: bool = False,
     head_prefix: int = 0,
+    table: str = "",
 ) -> Array:
     """Scatter-add ``deltas`` for ``ids`` into the sharded table.
 
@@ -1020,6 +1239,19 @@ def push(
         (``push.mean_rows``, asked about the ``B`` ids and
         ``num_shards * rps`` rows the dense exchange would scatter). On
         one device nothing is exchanged and ``dense`` changes nothing.
+      table: the table's name, for the route log and the step's
+        ``routed`` flag (:func:`watch_routed`); nothing else reads it.
+
+    Every push that is not ``dense``, over more than one shard with no
+    data axis, takes the OWNER-ROUTED exchange (:func:`_routes_to_owner`;
+    ``push.routed`` in the route log): ids and rows go to their owners in
+    lanes (:func:`_routed_exchange`), and everything after the exchange
+    (the additive scatter, a mean's branch asked about the rows it is
+    handed, ``push.acc_runs``, a fold, max / min) runs on ``S x L`` handed
+    pushes where the gathered exchange hands ``S x B``: a row's pushes in
+    the same order, worker by worker and then as the batch has them. A
+    step whose ids do not fit their lanes runs the gathered exchange
+    (``lax.cond`` on the lanes' certificate), so no push is ever dropped.
 
     Returns:
       Updated ``(rps, dim)`` local block.
@@ -1046,96 +1278,75 @@ def push(
              and (combine != "mean" or _mean_push_route(
                  rps * num_shards, dim, local_shard.dtype, B, apply_fn)[0]
                  == "mean_dense"))
-    if dense:
-        exchange = partial(_dense_exchange, num_shards=num_shards,
-                           shard_axis=shard_axis, data_axis=data_axis)
-        # Physical (owner-major) rows; a dropped id past the last of them
-        # (the end a sort puts it at: ``push.acc_runs``).
-        acc_rows = rps * num_shards
-        local_idx = jnp.where(
-            ids >= 0, id_to_phys(ids, num_shards, rps), acc_rows)
-        if additive:
-            return local_shard + exchange(ops.scatter_add(
-                jnp.zeros((acc_rows, dim), local_shard.dtype), local_idx,
-                deltas))
-        rows = deltas.astype(acc_dt)
-        live = jnp.ones((B,), acc_dt)
-    else:
-        acc_rows = rps
-        local_idx, gathered_deltas, owned = _gathered_exchange(
-            ids, deltas, rps=rps, num_shards=num_shards,
-            shard_axis=shard_axis, data_axis=data_axis)
-        B = local_idx.shape[0]
-        masked = jnp.where(owned[:, None], gathered_deltas,
-                           jnp.zeros_like(gathered_deltas))
-        if additive:
-            # Head-prefix guarantee survives only when the gathered stream
-            # is the caller's own (single shard, no data axis — the driver
-            # also gates it to single-device meshes).
-            keep_prefix = (num_shards == 1 and data_axis is None)
-            return ops.scatter_add(
-                local_shard, local_idx, masked, hot_rows=hot_rows,
-                head_prefix=head_prefix if keep_prefix else 0)
-        rows = masked.astype(acc_dt)
-        live = owned.astype(acc_dt)
 
-    if combine == "mean":
-        route, reason = _mean_push_route(acc_rows, dim, local_shard.dtype, B,
-                                         apply_fn)
-        ops.log_route("push", route, acc_rows, dim, B, reason)
-        if route == "mean_rows":
-            # The cost follows the payload: every pushed row is scaled by
-            # 1 / (pushes of its id) and the rows of one id are summed
-            # FROM ZERO in a (B, dim) buffer, sum_i(d_i * (1/n)); then ONE
-            # scatter-add of the buffer's rows into the table itself, one
-            # add a touched row, as "sum" does. Scattering the scaled rows
-            # straight into the table rounds each of a hot id's hundreds
-            # of small addends at the TABLE value's magnitude: 12-20x the
-            # accumulator's gap to a float64 mean (chip run, PR 28).
-            with jax.named_scope(COMBINE_SCOPE):
-                n, slot, slot_idx = _id_runs(local_idx, rps)
-                scaled = rows * (1.0 / n.astype(acc_dt))[:, None]
-                combined = jnp.zeros((B, dim), acc_dt).at[slot].add(scaled)
-            # ``slot_idx`` is the distinct ids in their sorted order, the
-            # rows of ``combined`` beside them, and past the last of them
-            # nothing but the drop sentinel (the unowned run's slot, which
-            # is the last, included) over rows of exact zeros: the order
-            # the sort made is handed on, and a scatter-add that is told
-            # so stops where the dropped begin.
-            return ops.scatter_add(local_shard, slot_idx, combined,
-                                   ids_sorted=True)
-    if combine in ("max", "min"):
-        # Extremum fold: ONE scatter-max/min of the raw deltas (duplicates
-        # combine natively, no serialized pairwise fold) with the touched
-        # indicator riding as an appended column (owned rows contribute
-        # 1.0 vs the fill sentinel — same one-scatter trick as the sum
-        # path's count column; the scatter is per-row-transaction bound).
-        # Sentinel beyond any representable delta IN THE ACCUMULATOR dtype —
-        # a hard-coded f32-range constant would silently clamp f64 deltas of
-        # magnitude > 3e38 to the sentinel.
-        with jax.named_scope(COMBINE_SCOPE):  # its raw scatter too
-            lim = jnp.finfo(acc_dt).max
-            fill = jnp.asarray(-lim if combine == "max" else lim, acc_dt)
-            ind = jnp.where(owned, 1.0, fill)[:, None]
-            filled = jnp.where(
-                owned[:, None],
-                jnp.concatenate(
-                    [gathered_deltas.astype(acc_dt), ind], axis=1
-                ),
-                fill,
-            )
-            target = jnp.full((rps, dim + 1), fill, acc_dt)
-            if combine == "max":
-                ext = target.at[local_idx].max(filled, mode="drop")
-            else:
-                ext = target.at[local_idx].min(filled, mode="drop")
-            counts = (jnp.abs(ext[:, dim]) <= 1.0).astype(acc_dt)
-            combined = jnp.where((counts > 0)[:, None], ext[:, :dim], 0.0)
-    else:
-        # Combine duplicate ids first, then apply once per touched row. The
-        # per-id sums and counts ride ONE scatter (counts as an appended
-        # ones column) — the scatter is per-row-transaction bound on TPU,
-        # so a second scatter for counts would double its cost.
+    def summed(local_idx, rows, live, acc_rows, asked, *, raw=None,
+               owned=None, pad_to=0):
+        """The first half of a non-additive push, from what its exchange
+        handed this shard: row indices ``local_idx`` into ``acc_rows``
+        rows (that many for a push to drop), the pushes ``rows`` in the
+        accumulate dtype with the dropped ones zeroed, ``live`` (1.0 a
+        push that counts), ``raw`` and ``owned`` (the pushes as handed and
+        the mask of the kept) for max / min. Returns what :func:`folded`
+        applies to the shard, which does not depend on how many pushes
+        were handed: the ``(rows, dim + 1)`` accumulator (sums and counts,
+        or extrema and the touched indicator), or on the mean's row branch
+        the distinct ids sorted and their summed rows, with ``pad_to``
+        lengthened by dropped ids over zero rows to that many. ``asked``:
+        the handed pushes the mean's branch is chosen for."""
+        B = local_idx.shape[0]
+        if combine == "mean":
+            route, reason = _mean_push_route(acc_rows, dim,
+                                             local_shard.dtype, asked,
+                                             apply_fn)
+            ops.log_route("push", route, acc_rows, dim, B, reason)
+            if route == "mean_rows":
+                # The cost follows the payload: every pushed row is scaled
+                # by 1 / (pushes of its id) and the rows of one id are
+                # summed FROM ZERO in a (B, dim) buffer,
+                # sum_i(d_i * (1/n)); then ONE scatter-add of the buffer's
+                # rows into the table itself, one add a touched row, as
+                # "sum" does. Scattering the scaled rows straight into the
+                # table rounds each of a hot id's hundreds of small
+                # addends at the TABLE value's magnitude: 12-20x the
+                # accumulator's gap to a float64 mean (chip run, PR 28).
+                with jax.named_scope(COMBINE_SCOPE):
+                    n, slot, slot_idx = _id_runs(local_idx, rps)
+                    scaled = rows * (1.0 / n.astype(acc_dt))[:, None]
+                    combined = jnp.zeros((B, dim), acc_dt).at[slot].add(
+                        scaled)
+                    if pad_to > B:
+                        slot_idx = jnp.concatenate([slot_idx, jnp.full(
+                            (pad_to - B,), rps, slot_idx.dtype)])
+                        combined = jnp.concatenate([combined, jnp.zeros(
+                            (pad_to - B, dim), acc_dt)])
+                return slot_idx, combined
+        if combine in ("max", "min"):
+            # Extremum fold: ONE scatter-max/min of the raw deltas
+            # (duplicates combine natively, no serialized pairwise fold)
+            # with the touched indicator riding as an appended column
+            # (owned rows contribute 1.0 vs the fill sentinel — same
+            # one-scatter trick as the sum path's count column; the
+            # scatter is per-row-transaction bound). Sentinel beyond any
+            # representable delta IN THE ACCUMULATOR dtype — a hard-coded
+            # f32-range constant would silently clamp f64 deltas of
+            # magnitude > 3e38 to the sentinel.
+            with jax.named_scope(COMBINE_SCOPE):  # its raw scatter too
+                lim = jnp.finfo(acc_dt).max
+                fill = jnp.asarray(-lim if combine == "max" else lim, acc_dt)
+                ind = jnp.where(owned, 1.0, fill)[:, None]
+                filled = jnp.where(
+                    owned[:, None],
+                    jnp.concatenate([raw.astype(acc_dt), ind], axis=1),
+                    fill,
+                )
+                target = jnp.full((rps, dim + 1), fill, acc_dt)
+                if combine == "max":
+                    return (target.at[local_idx].max(filled, mode="drop"),)
+                return (target.at[local_idx].min(filled, mode="drop"),)
+        # Combine duplicate ids first, then apply once per touched row.
+        # The per-id sums and counts ride ONE scatter (counts as an
+        # appended ones column) — the scatter is per-row-transaction bound
+        # on TPU, so a second scatter for counts would double its cost.
         if apply_fn is not None and combine != "mean":
             # A stateful fold under "sum" (or a callable combine): the
             # (rows, dim + 1) accumulator, apply_fn over the whole shard
@@ -1169,26 +1380,106 @@ def push(
             zeros = jnp.broadcast_to(
                 lax.optimization_barrier(jnp.zeros((), acc_dt)),
                 (acc_rows, dim + 1))
-        acc = ops.scatter_add(zeros, local_idx, withcnt, ids_sorted=runs)
+        return (ops.scatter_add(zeros, local_idx, withcnt, ids_sorted=runs),)
+
+    def folded(summed, exchange=None):
+        """The second half: :func:`summed`'s result applied to the shard
+        (``exchange`` where the dense one still has the accumulator to
+        trade)."""
+        if len(summed) == 2:
+            # ``slot_idx`` is the distinct ids in their sorted order, the
+            # rows of ``combined`` beside them, and past the last of them
+            # nothing but the drop sentinel (the unowned run's slot, which
+            # is the last, included) over rows of exact zeros: the order
+            # the sort made is handed on, and a scatter-add that is told
+            # so stops where the dropped begin.
+            slot_idx, combined = summed
+            return ops.scatter_add(local_shard, slot_idx, combined,
+                                   ids_sorted=True)
+        (acc,) = summed
         with jax.named_scope(COMBINE_SCOPE):
-            if dense:
-                acc = exchange(acc)
-            combined, counts = acc[:, :dim], acc[:, dim]
-            if combine == "mean":
-                combined = combined * (
-                    1.0 / jnp.maximum(counts, 1.0))[:, None]
-            elif callable(combine):
-                combined = jnp.where(
-                    (counts > 0)[:, None], combine(combined, counts), 0.0
-                )
-    with jax.named_scope(COMBINE_SCOPE):
-        if apply_fn is None:
-            # Additive fold: untouched rows receive exactly zero, so no
-            # mask is needed (a full-table where() is a measurable
-            # per-step cost).
-            return local_shard + combined.astype(local_shard.dtype)
-        new_rows = apply_fn(local_shard, combined.astype(local_shard.dtype))
-        return jnp.where((counts > 0)[:, None], new_rows, local_shard)
+            if combine in ("max", "min"):
+                counts = (jnp.abs(acc[:, dim]) <= 1.0).astype(acc_dt)
+                combined = jnp.where((counts > 0)[:, None], acc[:, :dim],
+                                     0.0)
+            else:
+                if exchange is not None:
+                    acc = exchange(acc)
+                combined, counts = acc[:, :dim], acc[:, dim]
+                if combine == "mean":
+                    combined = combined * (
+                        1.0 / jnp.maximum(counts, 1.0))[:, None]
+                elif callable(combine):
+                    combined = jnp.where(
+                        (counts > 0)[:, None], combine(combined, counts), 0.0
+                    )
+        with jax.named_scope(COMBINE_SCOPE):
+            if apply_fn is None:
+                # Additive fold: untouched rows receive exactly zero, so
+                # no mask is needed (a full-table where() is a measurable
+                # per-step cost).
+                return local_shard + combined.astype(local_shard.dtype)
+            new_rows = apply_fn(local_shard,
+                                combined.astype(local_shard.dtype))
+            return jnp.where((counts > 0)[:, None], new_rows, local_shard)
+
+    if dense:
+        exchange = partial(_dense_exchange, num_shards=num_shards,
+                           shard_axis=shard_axis, data_axis=data_axis)
+        # Physical (owner-major) rows; a dropped id past the last of them
+        # (the end a sort puts it at: ``push.acc_runs``).
+        acc_rows = rps * num_shards
+        local_idx = jnp.where(
+            ids >= 0, id_to_phys(ids, num_shards, rps), acc_rows)
+        if additive:
+            return local_shard + exchange(ops.scatter_add(
+                jnp.zeros((acc_rows, dim), local_shard.dtype), local_idx,
+                deltas))
+        return folded(summed(local_idx, deltas.astype(acc_dt),
+                             jnp.ones((B,), acc_dt), acc_rows, B), exchange)
+
+    def handed(local_idx, handed_deltas, owned, asked=0, pad_to=0):
+        """The push, or its first half, from what the gathered or the
+        owner-routed exchange hands this shard: the additive scatter into
+        the shard itself, or :func:`summed`'s result."""
+        masked = jnp.where(owned[:, None], handed_deltas,
+                           jnp.zeros_like(handed_deltas))
+        if additive:
+            # Head-prefix guarantee survives only when the gathered stream
+            # is the caller's own (single shard, no data axis — the driver
+            # also gates it to single-device meshes).
+            keep_prefix = (num_shards == 1 and data_axis is None)
+            return ops.scatter_add(
+                local_shard, local_idx, masked, hot_rows=hot_rows,
+                head_prefix=head_prefix if keep_prefix else 0)
+        return summed(local_idx, masked.astype(acc_dt), owned.astype(acc_dt),
+                      rps, asked or local_idx.shape[0], raw=handed_deltas,
+                      owned=owned, pad_to=pad_to)
+
+    gathered = partial(_gathered_exchange, ids, deltas, rps=rps,
+                       num_shards=num_shards, shard_axis=shard_axis,
+                       data_axis=data_axis)
+    lanes = _lanes_of_call("push", local_shard, ids, num_shards=num_shards,
+                           shard_axis=shard_axis, data_axis=data_axis,
+                           table=table)
+    if lanes is None:
+        out = handed(*gathered())
+        return out if additive else folded(out)
+    # Both branches hand the second half the same shapes, so the shard
+    # itself stays out of the conditional (but for the additive scatter):
+    # the accumulator is the shard's size whatever was handed; the row
+    # branch's sorted ids are lengthened to the gathered exchange's, which
+    # a scatter that stops at the first dropped id does not pay for; and a
+    # step that falls back takes the mean's branch the lanes' S x L pushes
+    # chose.
+    span = lanes.src.shape[0]
+    out = lax.cond(
+        lanes.fits,
+        lambda: handed(*_routed_exchange(
+            lanes, deltas, rps=rps, num_shards=num_shards,
+            shard_axis=shard_axis), span, num_shards * B),
+        lambda: handed(*gathered(), span))
+    return out if additive else folded(out)
 
 
 # ---------------------------------------------------------------------------

@@ -401,9 +401,11 @@ class IALSSolver:
 
                 with jax.named_scope("fps.pull"):
                     y = pull(fixed_table, fixed_ids,
-                             num_shards=self.num_shards)
+                             num_shards=self.num_shards,
+                             data_axis=data_axis)
                     x = pull(solve_table, solve_ids,
-                             num_shards=self.num_shards)
+                             num_shards=self.num_shards,
+                             data_axis=data_axis)
                 with jax.named_scope("fps.compute"):
                     c = 1.0 + cfg.alpha * r  # confidence
                     miss = 1.0 - jnp.sum(x * y, axis=-1)
@@ -456,7 +458,8 @@ class IALSSolver:
                     at = i * blk
                     y = pull(fixed_table,
                              lax.dynamic_slice(fixed, (at,), (blk,)),
-                             num_shards=self.num_shards)
+                             num_shards=self.num_shards,
+                             data_axis=data_axis)
                     # A rating's addend: ``(alpha r w y) y^T`` and
                     # ``(1 + alpha r) w y``.
                     add = _addends(

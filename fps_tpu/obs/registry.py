@@ -217,6 +217,18 @@ def default_registry() -> MetricsRegistry:
                    help="cold rows dropped by the device-side compaction "
                         "lane (the observability net: zero for every "
                         "host-certified chunk by construction)"),
+        # The sharded exchange of a tiered table's cold rows (store.pull /
+        # store.push; docs/performance.md "The exchange's three forms"):
+        # the step's ``routed`` flag rides the hot tier's channel.
+        MetricSpec("exchange.steps", "counter", unit="steps",
+                   labels=("table",),
+                   help="steps in which the table's cold rows crossed "
+                        "shards by the non-dense exchange"),
+        MetricSpec("exchange.routed_steps", "counter", unit="steps",
+                   labels=("table",),
+                   help="of exchange.steps, those whose pull and push ran "
+                        "owner-routed (the ids fit their lanes); the rest "
+                        "ran the gathered exchange"),
         # Adaptive tiering (fps_tpu.tiering; docs/performance.md
         # "Adaptive tiering"): online hot-set re-ranking + auto-planner.
         MetricSpec("tiering.re_ranks", "counter", unit="re_ranks",

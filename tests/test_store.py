@@ -11,6 +11,7 @@ import numpy as np
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+import fps_tpu.core.store as store_mod
 import fps_tpu.ops as ops
 from fps_tpu.core.store import (
     ParamStore,
@@ -516,8 +517,12 @@ def _mean_push_case(D, S, num_ids, dim, seed=11):
 
 
 def _push_on_mesh(devices, D, S, table, ids, deltas, **kw):
-    """``push`` under ``shard_map`` on a ``D x S`` mesh; the table and the
-    route log of its one trace."""
+    """``push`` under ``shard_map`` on a ``D x S`` mesh by the GATHERED
+    exchange (the owner-routed one, which 96 ids a worker would take
+    without a data axis, is ruled out for the trace: what follows the
+    exchange is what these tests pin, on every pushed row handed to every
+    shard; ``test_routed_push_*`` run the same branches on lanes); the
+    table and the route log of its one trace."""
     mesh = make_ps_mesh(num_shards=S, num_data=D, devices=devices[:D * S])
     f = jax.jit(jax.shard_map(
         lambda t, i, d: push(t, i, d, num_shards=S,
@@ -527,9 +532,14 @@ def _push_on_mesh(devices, D, S, table, ids, deltas, **kw):
                   P((DATA_AXIS, SHARD_AXIS), None)),
         out_specs=P(SHARD_AXIS, None), check_vma=False))
     ops.clear_routes()
-    out = f(jax.device_put(jnp.asarray(table),
-                           NamedSharding(mesh, P(SHARD_AXIS, None))),
-            jnp.asarray(ids), jnp.asarray(deltas))
+    rule = store_mod._routes_to_owner
+    store_mod._routes_to_owner = lambda *a: False
+    try:
+        out = f(jax.device_put(jnp.asarray(table),
+                               NamedSharding(mesh, P(SHARD_AXIS, None))),
+                jnp.asarray(ids), jnp.asarray(deltas))
+    finally:
+        store_mod._routes_to_owner = rule
     return np.asarray(out), ops.routes_traced()
 
 
@@ -1117,3 +1127,281 @@ def test_dense_route_trains_pa_equivalently(devices8):
     w_gathered, m_gathered = run(False)
     assert np.abs(w_dense).max() > 0  # it actually trained
     np.testing.assert_allclose(w_dense, w_gathered, rtol=2e-4, atol=1e-6)
+
+
+# ---------------------------------------------------------------------------
+# The owner-routed exchange (PR 45): a worker's ids into one lane an owner
+# shard, lanes traded by all_to_all, certified in the graph, the gathered
+# exchange as the fallback.
+# ---------------------------------------------------------------------------
+
+def _routed_case(S, num_ids, dim, B=96, seed=31):
+    """``B`` ids a worker on ``S`` shards, each worker's spread evenly over
+    the owners in a shuffled order (so every lane fits): half of them
+    repeats of 12 hot rows, an eighth negative, onto a non-zero table
+    (physical layout); and a batch whose ids all belong to shard 1."""
+    rng = np.random.default_rng(seed)
+    rows = num_ids // S - 1
+    hot = rng.integers(0, rows, 12)
+    row = np.where(rng.random(S * B) < 0.5, hot[rng.integers(0, 12, S * B)],
+                   rng.integers(0, rows, S * B))
+    owner = np.concatenate([rng.permutation(np.arange(B) % S)
+                            for _ in range(S)])
+    ids = (row * S + owner).astype(np.int32)
+    ids[rng.random(S * B) < 0.125] = -1
+    one_owner = (row * S + 1).astype(np.int32)
+    deltas = rng.normal(0, 1, (S * B, dim)).astype(np.float32)
+    rps = rows_per_shard(num_ids, S)
+    table = rng.normal(0, 1, (rps * S, dim)).astype(np.float32)
+    return table, ids, one_owner, deltas
+
+
+def _exchange_on(devices, S, fn, table, ids, deltas, *, routed=True, D=1):
+    """``fn(local_shard, ids, deltas, data_axis)`` under ``shard_map`` on a
+    ``D x S`` mesh with the step's ``routed`` flags watched: ``(output,
+    flag of table "t", route log, lowered text)``. ``routed=False`` traces
+    with the owner-routed exchange ruled out: the gathered exchange as the
+    parent lowered it."""
+    mesh = make_ps_mesh(num_shards=S, num_data=D, devices=devices[:D * S])
+    workers = P((DATA_AXIS, SHARD_AXIS))
+
+    def body(t, i, d):
+        with store_mod.watch_routed() as seen:
+            out = fn(t, i, d, DATA_AXIS if D > 1 else None)
+        return out, jnp.reshape(seen.get("t", jnp.int32(-1)), (1,))
+
+    rows = P(SHARD_AXIS, None)
+    f = jax.jit(jax.shard_map(
+        body, mesh=mesh, in_specs=(rows, workers, P(workers[0], None)),
+        out_specs=(rows if fn.pushes else P(workers[0], None), workers),
+        check_vma=False))
+    args = (jax.device_put(jnp.asarray(table), NamedSharding(mesh, rows)),
+            jnp.asarray(ids), jnp.asarray(deltas))
+    rule = store_mod._routes_to_owner
+    if not routed:
+        store_mod._routes_to_owner = lambda *a: False
+    try:
+        ops.clear_routes()
+        text = f.lower(*args).as_text()
+        out, flag = f(*args)
+    finally:
+        store_mod._routes_to_owner = rule
+    return np.asarray(out), np.asarray(flag), ops.routes_traced(), text
+
+
+def _pulls(S, **kw):
+    def pulls(t, i, d, data_axis):
+        return pull(t, i, num_shards=S, data_axis=data_axis, table="t", **kw)
+    pulls.pushes = False  # it returns the workers' rows, not the table
+    return pulls
+
+
+def _pushes(S, **kw):
+    def pushes(t, i, d, data_axis):
+        return push(t, i, d, num_shards=S, data_axis=data_axis, table="t",
+                    **kw)
+    pushes.pushes = True
+    return pushes
+
+
+def test_lane_width_is_a_margin_over_the_mean_in_whole_tiles():
+    assert store_mod.LANE_MARGIN == 1.25
+    # w2v-1bw-hot.x4's two batches, and the smallest that routes.
+    assert [store_mod._lane_width(B, 4) for B in (49_182, 8_197, 32)] == [
+        15_376, 2_568, 16]
+    assert store_mod._routes_to_owner(32, 4, None)
+    assert not store_mod._routes_to_owner(31, 4, None)
+    assert not store_mod._routes_to_owner(96, 1, None)
+    assert not store_mod._routes_to_owner(96, 4, DATA_AXIS)
+
+
+@pytest.mark.parametrize("S", [4, 8])
+def test_owner_lanes_keep_the_batch_order_and_certify_the_fit(devices8, S):
+    """A worker's lanes: lane ``d`` holds its live ids owned by shard
+    ``d`` in the batch's order, ``-1`` after them; ``src`` the batch
+    positions they came from; ``slot`` where each id went (``S * L`` for a
+    negative id); ``fits`` the same on every shard, false where ANY
+    worker's lane overflows, and then the lane is cut, not spilled into
+    the next."""
+    B = 96
+    L = store_mod._lane_width(B, S)
+    _, ids, one_owner, _ = _routed_case(S, 1_000, 1, B)
+    mesh = make_ps_mesh(num_shards=S, devices=devices8[:S])
+
+    def lanes(i):
+        got = store_mod._owner_lanes(i, num_shards=S, shard_axis=SHARD_AXIS)
+        return (jnp.reshape(got.fits, (1,)), got.ids[None], got.src[None],
+                got.slot[None])
+
+    f = jax.jit(jax.shard_map(
+        lanes, mesh=mesh, in_specs=P(SHARD_AXIS),
+        out_specs=(P(SHARD_AXIS),) + (P(SHARD_AXIS, None),) * 3
+        , check_vma=False))
+    # One worker alone sends every id to shard 1: nobody's lanes fit.
+    spoiled = ids.copy()
+    spoiled[:B] = one_owner[:B]
+    for batch, fit in ((ids, True), (spoiled, False)):
+        fits, lane_ids, src, slot = map(np.asarray, f(jnp.asarray(batch)))
+        assert fits.tolist() == [fit] * S
+        for w in range(S):
+            mine = batch[w * B:(w + 1) * B]
+            for d in range(S):
+                at = np.flatnonzero((mine >= 0) & (mine % S == d))[:L]
+                want = np.full(L, -1)
+                want[:len(at)] = mine[at]
+                np.testing.assert_array_equal(lane_ids[w, d], want)
+                want[:len(at)] = at
+                np.testing.assert_array_equal(
+                    src[w].reshape(S, L)[d], want)
+                np.testing.assert_array_equal(
+                    slot[w][at], d * L + np.arange(len(at)))
+            assert (slot[w][mine < 0] == S * L).all()
+
+
+@pytest.mark.parametrize("S", [4, 8])
+def test_routed_pull_is_the_gathered_pull_bit_for_bit(devices8, S):
+    """Each row comes from one shard and nothing is summed: repeats, rows
+    of every shard and ``-1`` ids (zero rows) read the same bits by either
+    exchange; the route log names the routed one once, with its lanes."""
+    num_ids, dim, B = 1_000, 5, 96
+    table, ids, _, deltas = _routed_case(S, num_ids, dim, B)
+    got, flag, log, text = _exchange_on(devices8, S, _pulls(S), table, ids,
+                                        deltas)
+    want, off, log_g, text_g = _exchange_on(devices8, S, _pulls(S), table,
+                                            ids, deltas, routed=False)
+    np.testing.assert_array_equal(got, want)
+    rps = rows_per_shard(num_ids, S)
+    live = ids >= 0
+    np.testing.assert_array_equal(
+        got[live], table[np.asarray(id_to_phys(ids[live], S, rps))])
+    assert not got[~live].any() and (~live).sum() > S
+    assert flag.tolist() == [1] * S and off.tolist() == [0] * S
+    L = store_mod._lane_width(B, S)
+    assert [(r.route, r.rows, r.dim, r.ids, r.reason) for r in log
+            if r.op == "pull"] == [
+        ("pull.routed", rps, dim, B, f"table=t lanes={S}x{L}")]
+    assert not [r for r in log_g if r.op == "pull"]
+    assert "all_to_all" in text and "all_to_all" not in text_g
+
+
+_ROUTED_PUSHES = {
+    # name -> (push kwargs, the table's ids and width (a mean takes the
+    # branch named at them), the branch the route log names, float64
+    # oracle of a touched row from its value and its pushes)
+    "sum": (dict(), (1_000, 5), None,
+            lambda cur, rows: cur + rows.sum(axis=0)),
+    "mean_rows": (dict(combine="mean"), (65_536, 64), "push.mean_rows",
+                  lambda cur, rows: cur + rows.mean(axis=0)),
+    "mean_dense": (dict(combine="mean"), (1_000, 5), "push.mean_dense",
+                   lambda cur, rows: cur + rows.mean(axis=0)),
+    "fold": (dict(apply_fn=lambda cur, d: 0.5 * cur + d), (1_000, 5),
+             "push.fold", lambda cur, rows: 0.5 * cur + rows.sum(axis=0)),
+    "max": (dict(combine="max"), (1_000, 5), None,
+            lambda cur, rows: cur + rows.max(axis=0)),
+    # The accumulator summed by id run first (its predicate's constant
+    # patched to engage it), on a shard XLA would keep transposed.
+    "acc_runs": (dict(apply_fn=lambda cur, d: 0.5 * cur + d),
+                 (2_400_000, 2), "push.fold",
+                 lambda cur, rows: 0.5 * cur + rows.sum(axis=0)),
+}
+
+
+@pytest.mark.parametrize("kind", list(_ROUTED_PUSHES))
+@pytest.mark.parametrize("S", [4, 8])
+def test_routed_push_equals_the_gathered_push_and_the_oracle(devices8,
+                                                             monkeypatch, S,
+                                                             kind):
+    """Everything after the exchange runs on the ``S x L`` pushes a shard
+    is handed as it runs on the gathered ``S x B``: the additive scatter,
+    both branches of the mean (asked about the rows HANDED), a stateful
+    fold (its accumulator plain and summed by id run) and max give the
+    gathered branch's table and a float64 oracle's; rows nobody pushed
+    keep their bits."""
+    kw, (num_ids, dim), route, oracle = _ROUTED_PUSHES[kind]
+    B = 96
+    if kind == "acc_runs":
+        monkeypatch.setattr(ops, "ACC_RUNS_MIN_IDS_PER_ROW", 0.0)
+    table, ids, _, deltas = _routed_case(S, num_ids, dim, B)
+    got, flag, log, _ = _exchange_on(devices8, S, _pushes(S, **kw), table,
+                                     ids, deltas)
+    gathered, off, log_g, _ = _exchange_on(devices8, S, _pushes(S, **kw),
+                                           table, ids, deltas, routed=False)
+    rps = rows_per_shard(num_ids, S)
+    L = store_mod._lane_width(B, S)
+    assert flag.tolist() == [1] * S and off.tolist() == [0] * S
+    pushes = [(r.route, r.ids) for r in log if r.op == "push"]
+    assert pushes[0] == ("push.routed", B)
+    if route:  # once a branch of the certificate, on what it is handed
+        runs = [("push.acc_runs", n) for n in (S * L, S * B)
+                if kind == "acc_runs"]
+        assert pushes[1:] == [(route, S * L), *runs[:1], (route, S * B),
+                              *runs[1:]], pushes
+        assert [(r.route, r.ids) for r in log_g if r.op == "push"] == [
+            (route, S * B), *runs[1:]]
+    want = table.astype(np.float64)
+    keep = ids >= 0
+    phys = np.asarray(id_to_phys(ids[keep], S, rps))
+    touched = np.unique(phys)
+    for row in touched:
+        want[row] = oracle(want[row],
+                           deltas[keep][phys == row].astype(np.float64))
+    scale = np.abs(want).max()
+    assert np.abs(got - gathered).max() <= 1e-6 * scale
+    assert np.abs(got - want).max() <= 1e-6 * scale
+    untouched = np.setdiff1d(np.arange(rps * S), touched)
+    assert len(untouched) >= 20
+    np.testing.assert_array_equal(got[untouched], table[untouched])
+
+
+@pytest.mark.parametrize("op", ["pull", "push_sum", "push_mean"])
+@pytest.mark.parametrize("S", [4, 8])
+def test_ids_of_one_owner_fall_back_to_the_gathered_exchange(devices8, S,
+                                                             op):
+    """A batch whose ids all belong to one shard overflows that shard's
+    lane (``B`` ids into ``1.25 B / S`` slots): the certificate reads 0 on
+    every shard, the step runs the gathered branch, and the result is the
+    gathered exchange's own, bit for bit: nothing is dropped."""
+    num_ids, dim, B = 1_000, 5, 96
+    table, _, one_owner, deltas = _routed_case(S, num_ids, dim, B)
+    assert B > store_mod._lane_width(B, S)
+    fn = _pulls(S) if op == "pull" else _pushes(
+        S, **({"combine": "mean"} if op == "push_mean" else {}))
+    got, flag, log, _ = _exchange_on(devices8, S, fn, table, one_owner,
+                                     deltas)
+    want, _, _, _ = _exchange_on(devices8, S, fn, table, one_owner, deltas,
+                                 routed=False)
+    assert flag.tolist() == [0] * S
+    assert [r.route for r in log if r.route.endswith(".routed")] == [
+        op[:4] + ".routed"]  # the lanes are in the program, not in the step
+    np.testing.assert_array_equal(got, want)
+    if op == "pull":
+        rps = rows_per_shard(num_ids, S)
+        np.testing.assert_array_equal(
+            got, table[np.asarray(id_to_phys(one_owner, S, rps))])
+
+
+@pytest.mark.parametrize("op", ["pull", "push"])
+def test_a_data_axis_or_a_small_batch_keeps_the_exchange_gathered(devices8,
+                                                                  op):
+    """With a data axis (a push must reach every replica) and under 8 ids
+    a shard, what is lowered is the gathered exchange itself, text for
+    text, and the step's flag reads 0; a dense exchange notes no flag."""
+    dim = 5
+    for D, S, B in ((2, 4, 96), (1, 4, 24)):
+        table, ids, _, deltas = _routed_case(D * S, 1_000, dim, B)
+        rps = rows_per_shard(1_000, S)
+        table = table[:rps * S]
+        fn = _pulls(S) if op == "pull" else _pushes(S)
+        got, flag, log, text = _exchange_on(devices8, S, fn, table, ids,
+                                            deltas, D=D)
+        want, _, _, text_g = _exchange_on(devices8, S, fn, table, ids,
+                                          deltas, D=D, routed=False)
+        assert text == text_g and "all_to_all" not in text
+        assert not [r for r in log if r.route.endswith(".routed")]
+        assert flag.tolist() == [0] * (D * S)
+        np.testing.assert_array_equal(got, want)
+    fn = _pulls(4, dense=True) if op == "pull" else _pushes(4, dense=True)
+    table, ids, _, deltas = _routed_case(4, 1_000, dim)
+    _, flag, log, _ = _exchange_on(devices8, 4, fn, table, ids, deltas)
+    assert flag.tolist() == [-1] * 4
+    assert not [r for r in log if r.route.endswith(".routed")]

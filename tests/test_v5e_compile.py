@@ -373,15 +373,24 @@ def test_w2v_hot_epoch_program_reconciles_once_a_window_on_four_chips(
     reduce-scatter an all-reduce of the whole buffer (as it does with the
     pull's ``psum_scatter``: ``[199424, 300]`` a step), and hoists the
     step's metric sums out of the step loop into the same all-reduce: the
-    test takes either reduction. Of 352 ops named under ``fps.*`` 343 are
-    in the step's body and 9 in the window's, so a reader that counts
-    steps by a median over those names reads steps (a ragged tail, 172
-    steps, compiles a SECOND step body of as many: PERF.md, section 7).
-    Fits in 2.7 GB a chip before the runner's copies."""
+    test takes either reduction. Since PR 45 each of the step's four
+    exchanges is a conditional on its lanes' certificate: the ROUTED
+    branch holds ``all-to-all`` s alone (ids ``[4, 1, L]``, rows ``[4, L,
+    300]``, ``L`` 2,568 and 15,376) and nothing of the gathered
+    exchange's ``S x B`` length, the gathered branch the all-gathers and
+    the pull's all-reduce as before; the shard itself enters no
+    conditional of a push (the accumulator ``[278753, 301]`` or the row
+    branch's sorted ids leave it). The ops named under ``fps.*`` outside
+    those branches are in the step's body but for the window's handful,
+    so a reader that counts steps by a median over those names reads
+    steps (a ragged tail, 172 steps, compiles a SECOND step body of as
+    many: PERF.md, section 7). Fits in 3 GB a chip before the runner's
+    copies."""
     import argparse
 
     from jax.sharding import NamedSharding, PartitionSpec as P
 
+    from fps_tpu.core.store import _lane_width
     from fps_tpu.examples.common import apply_hot_tier
     from fps_tpu.models.word2vec import (
         W2VConfig,
@@ -418,11 +427,25 @@ def test_w2v_hot_epoch_program_reconciles_once_a_window_on_four_chips(
     compiled = trainer._build_indexed_fn(plan, "sync").lower(
         tables, (), iargs, jnp.int32(0), key).compile()
     rps, ids_in, ids_out = rows // W, W * (L + 5), W * 6 * (L + 5)
+    # Since PR 45 the cold rows go to their owners in lanes (15,376 and
+    # 2,568 wide, four a worker), and the gathered exchange is the other
+    # branch of each exchange's certificate: a mean push is logged once on
+    # the lanes' 10,272 / 61,504 handed rows and once on the gathered
+    # 32,788 / 196,728, by the branch the lanes' rows choose.
+    lanes_in, lanes_out = (W * _lane_width(n, W)
+                           for n in (L + 5, 6 * (L + 5)))
+    assert (lanes_in, lanes_out) == (10_272, 61_504)
     assert [(r.route, r.rows, r.dim, r.ids) for r in ops.routes_traced()
             if not r.route.startswith(("gather.", "scatter_add."))] == [
-        ("pull.hot", H, D, L + 5), ("pull.hot", H, D, 6 * (L + 5)),
+        ("pull.hot", H, D, L + 5), ("pull.routed", rps, D, L + 5),
+        ("pull.hot", H, D, 6 * (L + 5)),
+        ("pull.routed", rps, D, 6 * (L + 5)),
         ("push.hot", H, D + 1, L + 5), ("push.hot", H, D + 1, 6 * (L + 5)),
+        ("push.routed", rps, D, L + 5),
+        ("push.mean_rows", rps, D, lanes_in),
         ("push.mean_rows", rps, D, ids_in),
+        ("push.routed", rps, D, 6 * (L + 5)),
+        ("push.mean_dense", rps, D, lanes_out),
         ("push.mean_dense", rps, D, ids_out),
         ("reconcile.hot", H, D + 1, 0), ("reconcile.hot", H, D + 1, 0)]
     mem = compiled.memory_analysis()
@@ -438,9 +461,30 @@ def test_w2v_hot_epoch_program_reconciles_once_a_window_on_four_chips(
 
     trivial = re.compile(
         r"= \S+ (get-tuple-element|parameter|tuple|constant|bitcast)\(")
+    comps = _loop_bodies(text)
+    # The four exchanges' certificates: a conditional each, (gathered,
+    # routed). Their branches run inside the step, not beside it.
+    branches = [m.group(1).split(", ") for m in re.finditer(
+        r" conditional\(.*?branch_computations=\{([^}]*)\}", text)]
+    assert len(branches) == 4 and all(len(b) == 2 for b in branches)
+    for gathered, routed in branches:
+        kinds = {k for k, _ in collectives(comps[routed])}
+        assert kinds == {"all-to-all"}, (routed, kinds)
+        assert "all-to-all" not in {
+            k for k, _ in collectives(comps[gathered])}
+        # A shard is handed its own rows only: lanes, never S x B of them
+        # (the row branch's sorted ids and sums are LENGTHENED to that by
+        # a pad of dropped ids as the branch ends: no op works on it).
+        assert not [ln for ln in comps[routed]
+                    if (f"[{ids_in}," in ln or f"[{ids_out}," in ln
+                        or "[199424," in ln)
+                    and not re.search(r" (pad|tuple)\(", ln)]
+        assert [ln for ln in comps[routed]
+                if f"[{lanes_in}," in ln or f"[{lanes_out}," in ln]
     bodies = sorted(
         ((sum("/fps." in ln and not trivial.search(ln) for ln in lines),
-          lines) for lines in _loop_bodies(text).values()),
+          lines) for name, lines in comps.items()
+         if name not in sum(branches, [])),
         key=lambda b: -b[0])
     in_step_named, inner = bodies[0]
     # One step body and no tail's: nothing else holds a thirtieth as many
@@ -475,6 +519,97 @@ def test_w2v_hot_epoch_program_reconciles_once_a_window_on_four_chips(
     scoped = [ln for ln in outer if "/hot.reconcile/" in ln and "/fps." in ln]
     assert scoped and all("/hot.reconcile/fps.ops/gather.xla/" in ln
                           for ln in scoped), scoped
+
+
+def _reachable(comps, name):
+    """The instructions of computation ``name`` and of every computation
+    it calls (loop bodies, fused bodies and reducers left out of
+    ``comps`` are not followed)."""
+    seen, todo, lines = set(), [name], []
+    while todo:
+        cur = todo.pop()
+        if cur in seen or cur not in comps:
+            continue
+        seen.add(cur)
+        lines += comps[cur]
+        for ln in comps[cur]:
+            todo += re.findall(r"(?:body|condition|to_apply|calls)=(%[\w.]+)",
+                               ln)
+    return lines
+
+
+@pytest.mark.parametrize("side", ["pull", "push"])
+def test_routed_exchange_moves_no_row_that_is_nobodys(topo, monkeypatch,
+                                                      side):
+    """``store.pull`` / ``store.push`` (the per-id mean, on the
+    accumulator branch as the cell's out table takes it) at
+    ``w2v-1bw-hot.x4``'s larger shape, a ``[278753, 300]`` shard on each
+    of four described chips and 49,182 ids a worker, read from the
+    COMPILED text: one conditional on the lanes' certificate whose routed
+    branch trades lanes by ``all-to-all`` (the ids ``[4, 1, 15376]`` out,
+    the rows ``[4, 15376, 300]`` out for a push, back for a pull) and holds no
+    all-gather, no all-reduce and no reduce-scatter (no ``psum``: each row
+    comes from one shard), nor anything of the gathered exchange's 196,728
+    handed rows; the gathered branch keeps the all-gather (and the pull's
+    reduce, which the TPU's compiler makes an all-reduce of ``[199424,
+    300]``); a push's conditional returns the accumulator, so the shard
+    itself is not copied through it."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from fps_tpu.core import store
+    from fps_tpu.parallel.mesh import SHARD_AXIS, make_ps_mesh
+
+    monkeypatch.setattr(ops, "_use_pallas", lambda: (True, False))
+    W, rps, D, B = 4, 278_753, 300, 49_182
+    L = store._lane_width(B, W)
+    assert L == 15_376
+    mesh = make_ps_mesh(num_shards=W, devices=list(topo.devices)[:W])
+
+    def shape(s, dtype, spec):
+        return jax.ShapeDtypeStruct(s, dtype,
+                                    sharding=NamedSharding(mesh, spec))
+
+    def fn(t, i, d):
+        if side == "pull":
+            return store.pull(t, i, num_shards=W)
+        return store.push(t, i, d, num_shards=W, data_axis=None,
+                          combine="mean")
+
+    rows = P(SHARD_AXIS, None)
+    ops.clear_routes()
+    text = jax.jit(jax.shard_map(
+        fn, mesh=mesh, in_specs=(rows, P(SHARD_AXIS), rows),
+        out_specs=rows, check_vma=False)).lower(
+            shape((rps * W, D), jnp.float32, rows),
+            shape((W * B,), jnp.int32, P(SHARD_AXIS)),
+            shape((W * B, D), jnp.float32, rows)).compile().as_text()
+    assert [(r.route, r.rows, r.dim, r.ids, r.reason)
+            for r in ops.routes_traced() if r.route.endswith(".routed")] == [
+        (f"{side}.routed", rps, D, B, f"lanes={W}x{L}")]
+    comps = _loop_bodies(text)
+    ((gathered, routed),) = [m.group(1).split(", ") for m in re.finditer(
+        r" conditional\(.*?branch_computations=\{([^}]*)\}", text)]
+    pat = re.compile(r" = (.*?) (all-gather|all-reduce|reduce-scatter|"
+                     r"all-to-all|collective-permute)(?:-start)?\(")
+    moved = {side_: [(m.group(2), m.group(1)) for ln in _reachable(comps, c)
+                     if (m := pat.search(ln))]
+             for side_, c in (("routed", routed), ("gathered", gathered))}
+    assert {k for k, _ in moved["routed"]} == {"all-to-all"}, moved
+    assert sorted(t.split("{")[0] for _, t in moved["routed"]) == sorted(
+        [f"s32[{W},1,{L}]", f"f32[{W},{L},{D}]"]), moved["routed"]
+    assert "all-to-all" not in {k for k, _ in moved["gathered"]}
+    assert {k for k, _ in moved["gathered"]} >= (
+        {"all-reduce"} if side == "pull" else {"all-gather"})
+    handed = [ln for ln in _reachable(comps, routed)
+              if f"[{W * B}," in ln or f"[{W},{B}," in ln
+              or "[199424," in ln]
+    assert not handed, handed[:3]
+    (cond,) = [ln for ln in text.splitlines() if " conditional(" in ln]
+    assert re.search(r"= \(?f32\[%d,%d\]" % (
+        (B, D) if side == "pull" else (rps, D + 1)), cond), cond[:200]
+    if side == "push":
+        assert not [ln for c in (gathered, routed) for ln in comps[c]
+                    if " parameter(" in ln and f"f32[{rps},{D}]" in ln]
 
 
 def test_mf_epoch_step_keeps_the_accumulator_for_its_small_table(topo):
@@ -536,6 +671,7 @@ def test_lr_criteo_epoch_program_names_its_round_and_fits(topo, monkeypatch,
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from fps_tpu import DeviceEpochPlan
+    from fps_tpu.core.store import _lane_width
     from fps_tpu.models.logistic_regression import (
         LogRegConfig, logistic_regression,
     )
@@ -572,6 +708,7 @@ def test_lr_criteo_epoch_program_names_its_round_and_fits(topo, monkeypatch,
              "off_w": shape((shards,), jnp.int32),
              "perm": shape((1, 1), jnp.int32)}
     rows = B * (slots - 13) + 13
+    lane = _lane_width(rows, shards)
     ops.clear_routes()
     compiled = trainer._build_indexed_fn(plan, "ssp").lower(
         tables, (), iargs, jnp.int32(0), key).compile()
@@ -579,12 +716,20 @@ def test_lr_criteo_epoch_program_names_its_round_and_fits(topo, monkeypatch,
             for r in ops.routes_traced()] == [
         ("pull.snapshot", F, 2, rows, ""),
         ("gather.xla", F, 2, rows, "shape"),
-        ("push.fold", F // shards, 2, rows * shards, "apply_fn"),
-        *([("push.acc_runs", F, 2, rows, "fold"),
+        *([("push.fold", F, 2, rows, "apply_fn"),
+           ("push.acc_runs", F, 2, rows, "fold"),
            ("scatter_add.xla_sorted", F, 3, rows, "")] if shards == 1 else
           # 250,000 rows a shard: 128 MB of row-major tiles, which XLA
-          # keeps row-major in HBM; the plain accumulator stays.
-          [("scatter_add.xla", F // shards, 3, rows * shards, "shape")])]
+          # keeps row-major in HBM; the plain accumulator stays. Since
+          # PR 45 the pushes go to their owners in lanes (a shard is
+          # handed 532,512 where the gathered branch hands 1,703,988),
+          # and the fold is logged once a branch of the certificate.
+          [("push.routed", F // shards, 2, rows,
+            f"table=weights lanes={shards}x{lane}")]
+          + [entry for handed in (shards * lane, shards * rows)
+             for entry in (
+                 ("push.fold", F // shards, 2, handed, "apply_fn"),
+                 ("scatter_add.xla", F // shards, 3, handed, "shape"))])]
     text = compiled.as_text()
     snap = [ln for ln in text.splitlines() if "/ssp.snapshot/" in ln]
     assert not [ln for ln in snap if "/fps." in ln]

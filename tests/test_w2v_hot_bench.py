@@ -49,6 +49,20 @@ EXACT = ("examples", "feed") + tuple(
     for t in ("pending_in", "pending_out"))
 
 
+@pytest.fixture(scope="module", autouse=True)
+def tables_past_the_dense_edge():
+    """The cell's tables (1.3 GB each) are far past
+    ``ops.DENSE_TABLE_BYTES`` and take the sharded exchange; the tiny ones
+    would take the dense one. The edge is moved under them for this
+    module, so that what is rehearsed is the cell's own exchange: lanes,
+    certificate and the gathered fallback."""
+    from fps_tpu import ops
+
+    edge, ops.DENSE_TABLE_BYTES = ops.DENSE_TABLE_BYTES, 0
+    yield
+    ops.DENSE_TABLE_BYTES = edge
+
+
 def tiny_cell():
     loaded = spec.load_cell(spec.load_benchmark(), CELL)
     cfg = copy.deepcopy(loaded["config"])
@@ -112,7 +126,8 @@ def test_spec_validates_the_committed_benchmark_files():
     assert set(old["readers"]) | {
         "store.collective_ms_per_step", "store.hot_accumulate_ms_per_step",
         "store.reconcile_ms_per_step", "store.hot_routes_in_program",
-        "store.hot_hit_percent"} == set(cell["readers"])
+        "store.hot_hit_percent", "store.routed_routes_in_program",
+        "store.routed_fit_percent"} == set(cell["readers"])
     cfg, base = cell["config"], old["config"]
     assert cfg["reduced"] == ["tokens_resident"]
     # w2v-1bw's model key for key, but the kind and the tier's three.
@@ -363,6 +378,124 @@ def test_route_log_names_the_hot_reads_writes_and_reconciles(traced_program):
         f"every={E}", "combine=mean", "shards=4", f"bytes={H * 17 * 4}"]
     ids = {r.reason: r.ids for r in hot if r.route == "pull.hot"}
     assert ids == {"table=in_embeddings": 69, "table=out_embeddings": 6 * 69}
+
+
+def test_route_log_names_the_routed_exchange_of_both_tables(traced_program):
+    """The cold rows of both tables cross the four shards by the
+    owner-routed exchange: ``pull.routed`` and ``push.routed`` once a table
+    a traced step, each with the shard's shape, the worker's ids and its
+    lanes, beside the hot entries; the gathered exchange stays in the
+    program as the certificate's other branch (its mean pushes are logged
+    on ``S x B`` handed rows after the routed branch's on ``S x L``)."""
+    from fps_tpu.core import store
+
+    _, routes, _ = traced_program
+    rps = store.rows_per_shard(2003, 4)
+    routed = [(r.route, r.reason.split()[0], r.rows, r.dim, r.ids,
+               r.reason.split()[1]) for r in routes
+              if r.route.endswith(".routed")]
+    assert sorted(routed) == sorted(
+        (f"{op}.routed", f"table={t}", rps, 16, B,
+         f"lanes=4x{store._lane_width(B, 4)}")
+        for op in ("pull", "push")
+        for t, B in (("in_embeddings", 69), ("out_embeddings", 6 * 69)))
+    means = [(r.route.split("_")[0], r.ids) for r in routes
+             if r.route.startswith("push.mean_")]
+    assert means == [("push.mean", 4 * store._lane_width(B, 4) if lanes
+                      else 4 * B) for B in (69, 6 * 69)
+                     for lanes in (True, False)]
+    assert len([r for r in routes if r.route.endswith(".hot")]) == 8
+
+
+def test_the_routed_steps_arrive_with_a_call_nobody_fetches():
+    """The step's ``routed`` flags (on the hot tier's channel: 1 on worker
+    0 where the table's pull and push both ran on lanes) are summed when a
+    call's deferred metrics arrive: ``routed_steps`` of ``steps`` a table
+    on the ``device.run_indexed`` span's ``exchange`` field and in the
+    recorder's ``exchange.*`` counters, the same numbers the fetching way
+    of driving counts and writes on its epoch event; every step of the
+    tiny cell fits its lanes, and nothing is dropped."""
+    from fps_tpu import obs
+    from fps_tpu.obs import events
+
+    got = {}
+    for as_numpy in (False, "on_epoch"):
+        _, system, init, _ = build(seed=4)
+        sink = obs.MemorySink()
+        rec = obs.Recorder(sinks=[sink])
+        events.set_default_recorder(rec)
+        try:
+            tables, ls = system.place(init)
+            _, _, metrics = system.trainer.run_indexed(
+                tables, ls, system.plan, system.key, epochs=1,
+                as_numpy=bool(as_numpy), recorder=rec,
+                on_epoch=(lambda e, m: None) if as_numpy else None)
+        finally:
+            events.set_default_recorder(None)   # waits for the span
+        rec.flush()
+        (span,) = [e for e in sink.events("span")
+                   if e["span"] == "device.run_indexed"]
+        got[as_numpy] = (rec, span, sink.events("epoch"), metrics)
+    (rec_d, span_d, _, m_d), (rec_h, span_h, (epoch,), m_h) = (
+        got[False], got["on_epoch"])
+    T = int(system.plan.steps_per_epoch)
+    assert span_d["exchange"] == epoch["exchange"]
+    assert "exchange" not in span_h
+    assert set(span_d["exchange"]) == {"in_embeddings", "out_embeddings"}
+    for table, sums in span_d["exchange"].items():
+        flags = np.asarray(m_h[0]["hot_tier"][table]["routed"])
+        assert flags.shape == (T,) and set(flags.tolist()) <= {0, 1}
+        # (69 ids in lanes of 24: a step of the tiny cell may overflow
+        # one and run gathered; the cell's lanes stand 13 deviations off)
+        assert sums == {"routed_steps": float(flags.sum()),
+                        "steps": float(T)} and flags.sum() >= T - 3
+        assert not np.asarray(m_h[0]["hot_tier"][table].get(
+            "cold_dropped", 0)).any()
+        assert span_d["hot_tier"][table]["cold_dropped"] == 0
+        for rec in (rec_d, rec_h):
+            assert rec.counter_value("exchange.steps", table=table) == T
+            assert rec.counter_value("exchange.routed_steps",
+                                     table=table) == flags.sum()
+    assert span_d["exchange"]["out_embeddings"]["routed_steps"] == T
+
+
+def test_a_step_that_does_not_fit_its_lanes_is_counted_gathered(monkeypatch):
+    """With the lanes cut to a sliver of the mean (the margin patched for
+    the trace) no step fits: every step runs the gathered exchange, the
+    flags read 0, the counters say 0 of ``steps``, and the call is still
+    the reference's: the fallback drops nothing."""
+    from fps_tpu.core import store
+
+    monkeypatch.setattr(store, "LANE_MARGIN", 0.1)
+    loaded, system, init, _ = build(seed=7)
+    tables, ls = system.place(init)
+    tables, ls, metrics = system.trainer.run_indexed(
+        tables, ls, system.plan, system.key, epochs=1, as_numpy=True)
+    T = int(system.plan.steps_per_epoch)
+    live = np.asarray(metrics[0]["n"]) > 0  # (past them the blocks are empty)
+    assert 2 * E < live.sum() < T
+    sums = driver.Trainer._record_exchange(
+        type("Rec", (), {"inc": lambda *a, **k: None})(),
+        metrics[0]["hot_tier"])
+    for table in ("in_embeddings", "out_embeddings"):
+        flags = np.asarray(metrics[0]["hot_tier"][table]["routed"])
+        assert flags.shape == (T,) and not flags[live].any()
+        # (an empty step's positions hold word 0, a hot one: no cold id
+        # of the in table is left to overflow a lane)
+        assert sums[table] == {"routed_steps": float(flags.sum()),
+                               "steps": float(T)}
+    assert sums["in_embeddings"]["routed_steps"] == (~live).sum()
+    monkeypatch.undo()
+    _, sound, init2, _ = build(seed=7)
+    t2, l2 = sound.place(init2)
+    t2, l2, m2 = sound.trainer.run_indexed(
+        t2, l2, sound.plan, sound.key, epochs=1, as_numpy=True)
+    assert np.asarray(m2[0]["hot_tier"]["out_embeddings"]["routed"]).all()
+    for name in ("in_embeddings", "out_embeddings"):
+        a, b = np.asarray(tables[name]), np.asarray(t2[name])
+        assert np.abs(a - b).max() <= F32_GAP * np.abs(b).max()
+    np.testing.assert_allclose(np.asarray(metrics[0]["loss"]),
+                               np.asarray(m2[0]["loss"]), rtol=1e-5)
 
 
 def test_a_call_nobody_fetches_is_counted_when_its_metrics_arrive():

@@ -1,15 +1,17 @@
 """Are the accepted cells' step programs the same, instruction for
 instruction, in two trees? Compile-only: no chip is touched.
 
-    python tools/step_programs.py write <tree> <out_dir>
-    python tools/step_programs.py diff <out_dir_a> <out_dir_b>
+    python tools/step_programs.py write <tree> <out_dir> [cell ...]
+    python tools/step_programs.py diff <out_dir_a> <out_dir_b> [cell ...]
 
 ``write`` imports ``fps_tpu`` FROM ``tree`` (a checkout: this one, or a
 ``git archive`` of the parent), builds the step program of each cell of
 the benchmark at the cell's own shapes (``mf-netflix.epochs``,
 ``pa-rcv1.epochs``, ``mf-netflix.x4``, ``w2v-1bw.epochs``,
 ``lr-criteo.epochs`` and, since PR 36, both accumulate programs of
-``ials-ml20m.sweeps``, the ones that push), compiles it for a described
+``ials-ml20m.sweeps``, the ones that push; since PR 45
+``mf-netflix-topk.epochs`` and ``w2v-1bw-hot.x4``; all of them, or the
+cells named), compiles it for a described
 ``v5e:2x2`` with the ops layer routing as on the chip, and writes the
 compiled text with metadata,
 stack frames and location tables dropped, and the route log, under
@@ -17,7 +19,8 @@ stack frames and location tables dropped, and the route log, under
 ``diff`` counts the instructions of each program and the lines that
 differ; what is left are Pallas kernels' debug strings, which hold the
 checkout's path: it says so when the two differ in nothing else. Exit 1
-if a program or a route log differs otherwise.
+if a program or a route log differs otherwise. A PR that changes one
+cell's program on purpose names the OTHER cells to ``diff``.
 
 The method is PR 29's (PERF.md section 6); a PR that must leave the other
 cells' programs alone shows it this way before it spends chip time.
@@ -32,7 +35,8 @@ import sys
 
 CELLS = ("mf-netflix.epochs", "pa-rcv1.epochs", "mf-netflix.x4",
          "w2v-1bw.epochs", "lr-criteo.epochs", "ials-ml20m.sweeps.user",
-         "ials-ml20m.sweeps.item")
+         "ials-ml20m.sweeps.item", "mf-netflix-topk.epochs",
+         "w2v-1bw-hot.x4")
 
 
 def _normalised(text: str) -> str:
@@ -44,7 +48,7 @@ def _normalised(text: str) -> str:
                      and not re.match(r"^\d+ ", ln))
 
 
-def write(tree: str, out: str) -> None:
+def write(tree: str, out: str, cells=CELLS) -> None:
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     tree = os.path.abspath(tree)
@@ -91,16 +95,28 @@ def write(tree: str, out: str) -> None:
                                config + ".json")) as f:
             return json.load(f)[part]
 
-    def mf(n):
+    def mf(n, topk=False):
         from fps_tpu.models.matrix_factorization import MFConfig, online_mf
 
-        m = model("mf-netflix")
+        m = model("mf-netflix-topk" if topk else "mf-netflix")
         U, I, rank = m["num_users"], m["num_items"], m["rank"]
         mesh, shape = mesh_of(n)
         trainer, _ = online_mf(
             mesh, MFConfig(num_users=U, num_items=I, rank=rank,
                            learning_rate=m["learning_rate"], reg=m["reg"]),
             combine=m["combine"])
+        if topk:
+            import dataclasses
+
+            from fps_tpu.models.recommendation import (
+                make_online_topk_tap, mf_topk_query_fn,
+            )
+
+            trainer.config = dataclasses.replace(
+                trainer.config, step_tap=make_online_topk_tap(
+                    trainer.store, "item_factors", m["topk"],
+                    every=m["topk_every"], query_fn=mf_topk_query_fn(
+                        n, num_queries=m["queries_per_step"])))
         users, items = -(-U // n) * n, -(-I // n) * n
         tables = {"item_factors": shape((items, rank), jnp.float32,
                                         P("shard", None))}
@@ -110,7 +126,8 @@ def write(tree: str, out: str) -> None:
                                 ("rating", jnp.float32),
                                 ("weight", jnp.float32))}
         key = shape((), jax.random.key(0).dtype)
-        emit("mf-netflix." + ("epochs" if n == 1 else "x4"),
+        emit("mf-netflix-topk.epochs" if topk
+             else "mf-netflix." + ("epochs" if n == 1 else "x4"),
              lambda: trainer._build_chunk_fn("sync").lower(
                  tables, local, batches, key))
 
@@ -138,28 +155,43 @@ def write(tree: str, out: str) -> None:
              lambda: trainer._build_chunk_fn("sync").lower(
                  tables, (), batches, key))
 
-    def w2v():
+    def w2v(hot=False):
         from fps_tpu.models.word2vec import (
             W2VConfig, Word2VecDevicePlan, word2vec_block,
         )
 
-        m = model("w2v-1bw")
-        # T: the steps of an epoch over the cell's 2^21 resident tokens.
-        V, D, L, T = m["vocab_size"], m["dim"], m["block_len"], 172
-        mesh, shape = mesh_of(1)
+        m = model("w2v-1bw-hot" if hot else "w2v-1bw")
+        # T: the steps of an epoch over the cell's 2^21 resident tokens
+        # (the tiered cell's 8,212,000 over four workers: 21 windows).
+        V, D, L = m["vocab_size"], m["dim"], m["block_len"]
+        W, T = (4, 168) if hot else (1, 172)
+        mesh, shape = mesh_of(W)
         cfg = W2VConfig(vocab_size=V, dim=D)
-        trainer, _ = word2vec_block(mesh, cfg, 1.0 / (jnp.arange(V) + 1.5), L)
+        trainer, store = word2vec_block(mesh, cfg,
+                                        1.0 / (jnp.arange(V) + 1.5), L)
         # The plan's geometry without its uploads.
         plan = object.__new__(Word2VecDevicePlan)
         plan.cfg, plan.mode, plan.num_workers, plan.block_len = (
-            cfg, "block", 1, L)
+            cfg, "block", W, L)
         plan.steps_per_epoch, plan.sync_every = T, None
         key = shape((), jax.random.key(0).dtype)
-        tables = {n: shape((V, D), jnp.float32, P("shard", None))
+        tables = {n: shape((-(-V // W) * W, D), jnp.float32,
+                           P("shard", None))
                   for n in ("in_embeddings", "out_embeddings")}
-        iargs = {"compacted": shape((T * L + cfg.window,), jnp.int32),
+        if hot:
+            import argparse
+
+            from fps_tpu.examples.common import apply_hot_tier
+
+            apply_hot_tier(argparse.Namespace(
+                hot_tier=m["hot_tier"], hot_sync_every=m["hot_sync_every"],
+                cold_budget=m["cold_budget"]), trainer, store)
+            tables.update({n + "::hot": shape((m["hot_tier"], D),
+                                              jnp.float32)
+                           for n in list(tables)})
+        iargs = {"compacted": shape((T * L * W + cfg.window,), jnp.int32),
                  "kept": shape((), jnp.int32), "wkey": key}
-        emit("w2v-1bw.epochs",
+        emit("w2v-1bw-hot.x4" if hot else "w2v-1bw.epochs",
              lambda: trainer._build_indexed_fn(plan, "sync").lower(
                  tables, (), iargs, jnp.int32(0), key))
 
@@ -225,17 +257,18 @@ def write(tree: str, out: str) -> None:
                      table(other, K), table(n, K), table(n, K * K),
                      table(n, K), chunk))
 
-    mf(1)
-    pa()
-    mf(4)
-    w2v()
-    lr()
-    ials()
+    builders = dict(zip(CELLS, (
+        lambda: mf(1), pa, lambda: mf(4), w2v, lr, ials, ials,
+        lambda: mf(1, topk=True), lambda: w2v(hot=True))))
+    # (ials builds both its programs: once, whichever is asked for)
+    for build in dict.fromkeys(builders[name] for name in CELLS
+                               if name in cells):
+        build()
 
 
-def diff(a: str, b: str) -> int:
+def diff(a: str, b: str, cells=CELLS) -> int:
     worse = 0
-    for name in CELLS:
+    for name in cells:
         with open(os.path.join(a, name + ".txt")) as f:
             ta = f.read().splitlines()
         with open(os.path.join(b, name + ".txt")) as f:
@@ -259,11 +292,13 @@ def diff(a: str, b: str) -> int:
 
 
 def main(argv) -> int:
-    if len(argv) == 3 and argv[0] == "write":
-        write(argv[1], argv[2])
-        return 0
-    if len(argv) == 3 and argv[0] == "diff":
-        return diff(argv[1], argv[2])
+    cells = tuple(argv[3:]) or CELLS
+    if len(argv) >= 3 and set(cells) <= set(CELLS):
+        if argv[0] == "write":
+            write(argv[1], argv[2], cells)
+            return 0
+        if argv[0] == "diff":
+            return diff(argv[1], argv[2], cells)
     print(__doc__, file=sys.stderr)
     return 2
 
