@@ -13,7 +13,13 @@ on-device gathers:
   stays local (e.g. MF keyed by user; SURVEY.md §3.3). The per-worker
   queues (example indices with ``route_key % num_workers == w``) are
   computed on host *once* at construction and uploaded as a padded
-  ``(num_workers, max_queue)`` matrix;
+  ``(num_workers, max_queue)`` matrix. Without a route key the matrix
+  is a closed form (worker ``w`` owns rows ``w, w + W, ...``:
+  :func:`unkeyed_queue_rows`) and the step computes its rows: the
+  compiled call is handed no queue. The unpacked branch of
+  :meth:`DeviceEpochPlan.local_batch_at` says which in the route log
+  (``fps_tpu.ops.routes_traced``): ``ingest.rows_computed`` or, for a
+  keyed plan, ``ingest.rows_queued``;
 * **shuffle** — per epoch, each worker's queue is traversed under a
   permutation of ``[0, count)``: ``shuffle="sort"`` draws a true uniform
   permutation (on-device argsort of random keys), ``shuffle="interleave"``
@@ -57,6 +63,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from fps_tpu import ops
 from fps_tpu.obs.timing import host_span, settle
 from fps_tpu.parallel.mesh import DATA_AXIS, SHARD_AXIS, host_to_replicated
 
@@ -67,6 +74,15 @@ WORKER_AXES = (DATA_AXIS, SHARD_AXIS)
 # Cap on interleave grid rows: consecutive emitted examples sit ~count/r
 # apart in stream order, and r*c must stay int32-safe.
 _GRID_ROWS_MAX = 1 << 12
+
+
+def unkeyed_queue_rows(w, qpos, count, num_workers: int):
+    """Entries ``[w, qpos]`` of the queue matrix an unkeyed data set has
+    (:meth:`DeviceDataset.queues` with ``route_key=None``), padding
+    included: worker ``w`` owns rows ``w, w + W, ...`` in stream order,
+    and zeros stand behind its ``count``. ``qpos`` lies in ``[0, maxq)``.
+    """
+    return jnp.where(qpos < count, w + num_workers * qpos, 0)
 
 
 class DeviceDataset:
@@ -97,6 +113,11 @@ class DeviceDataset:
         The queue matrix is ``(num_workers, max_queue)`` int32 — worker
         ``w``'s first ``counts[w]`` entries are the example indices it owns,
         in stream order; the rest is padding (clamped reads, weight 0).
+        With ``route_key=None`` its content is :func:`unkeyed_queue_rows`,
+        which a step computes in place of reading it
+        (``ingest.rows_computed``); set-up still builds it, for
+        :meth:`packed` and the counts. A keyed plan's steps read the
+        matrix (``ingest.rows_queued``).
         """
         ck = (route_key, num_workers)
         if ck not in self._queues:
@@ -371,10 +392,13 @@ class DeviceEpochPlan:
                   if self.pack else None)
         args = {
             "columns": self.dataset.columns,
-            "queues": self._queues,
             "off_w": host_to_replicated(off_w, mesh),
             "perm": perm,
         }
+        if self.route_key is not None:
+            # An unkeyed plan's steps compute their rows (local_batch_at):
+            # no compiled call has a parameter of the queue's shape.
+            args["queues"] = self._queues
         if self._tbuf_jit is not None:
             args["tbuf"] = self._tbuf_jit(packed[0], off_w)
         elif packed is not None:
@@ -423,7 +447,8 @@ class DeviceEpochPlan:
             }
             batch["weight"] = valid.astype(jnp.float32)
             return batch
-        slot = w * self.maxq + jnp.clip(qpos, 0, self.maxq - 1)
+        qc = jnp.clip(qpos, 0, self.maxq - 1)
+        slot = w * self.maxq + qc
         if "packed" in args:
             # One gather of queue-ordered packed rows, then per-channel
             # bitcasts — replaces the queue indirection + one gather per
@@ -437,9 +462,18 @@ class DeviceEpochPlan:
                 for i, (k, dt) in enumerate(zip(names, dtypes))
             }
         else:
-            row = jnp.take(args["queues"].reshape(-1), slot)
+            cols = args["columns"]
+            # An unkeyed plan's queue matrix is a closed form: nothing is
+            # read to learn it, and epoch_args hands the call no queue.
+            computed = self.route_key is None
+            ops.log_route(
+                "ingest", "rows_computed" if computed else "rows_queued",
+                len(next(iter(cols.values()))), len(cols), self.local_batch)
+            row = (unkeyed_queue_rows(w, qc, cnt, self.num_workers)
+                   if computed
+                   else jnp.take(args["queues"].reshape(-1), slot))
             batch = {k: jnp.take(col, row, axis=0)
-                     for k, col in args["columns"].items()}
+                     for k, col in cols.items()}
         batch["weight"] = valid.astype(jnp.float32)
         return batch
 

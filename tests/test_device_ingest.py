@@ -12,15 +12,21 @@ import pytest
 
 import jax
 
+from fps_tpu import ops
 from fps_tpu.core.device_ingest import (
     DeviceDataset,
     DeviceEpochPlan,
     device_epoch_chunks,
+    unkeyed_queue_rows,
 )
 from fps_tpu.core.driver import num_workers_of
 from fps_tpu.models.matrix_factorization import MFConfig, online_mf
-from fps_tpu.parallel.mesh import make_ps_mesh
-from fps_tpu.utils.datasets import synthetic_ratings
+from fps_tpu.models.passive_aggressive import PAConfig, passive_aggressive
+from fps_tpu.parallel.mesh import key_to_replicated, make_ps_mesh
+from fps_tpu.utils.datasets import (
+    synthetic_ratings,
+    synthetic_sparse_classification,
+)
 
 
 @pytest.fixture(scope="module")
@@ -39,6 +45,36 @@ def data():
 @pytest.fixture(scope="module")
 def dataset(mesh, data):
     return DeviceDataset(mesh, data)
+
+
+SPARSE_NF, SPARSE_N = 211, 1003     # 1003 % 8 == 3: ragged queues
+SPARSE_CFG = PAConfig(num_features=SPARSE_NF, variant="PA-I", C=1.0)
+
+
+@pytest.fixture(scope="module")
+def sparse_data():
+    """2-D columns, and ``stream``: a keyed plan on it has the unkeyed
+    plan's queue matrix (row ``i`` goes to worker ``i % W``, in order)."""
+    d = synthetic_sparse_classification(SPARSE_N, SPARSE_NF, 6, seed=5)
+    # Rows told apart by their values (PA's labels must stay +-1).
+    d["feat_vals"] = (d["feat_vals"]
+                      + np.arange(SPARSE_N, dtype=np.float32)[:, None] * 1e-3)
+    return d
+
+
+@pytest.fixture(scope="module")
+def sparse_dataset(mesh, sparse_data):
+    return DeviceDataset(mesh, sparse_data)
+
+
+def _keyed_twin(mesh, sparse_data, **plan_kwargs):
+    """A KEYED plan over the same rows whose queue matrix is the unkeyed
+    one (key ``i`` of row ``i``; the stable host argsort keeps stream
+    order): it reads its rows from the uploaded host matrix, as every
+    plan did before PR 46."""
+    ds = DeviceDataset(mesh, dict(
+        sparse_data, stream=np.arange(SPARSE_N, dtype=np.int32)))
+    return DeviceEpochPlan(ds, route_key="stream", **plan_kwargs)
 
 
 LOCAL_BATCH = 16
@@ -125,6 +161,39 @@ def test_indexed_epoch_matches_chunked(mesh, dataset, data, sync_every):
         np.asarray(t1["item_factors"]), np.asarray(t2["item_factors"]),
         atol=1e-5,
     )
+
+
+@pytest.mark.parametrize("shuffle", [None, "interleave", "sort"])
+def test_indexed_epoch_matches_chunked_2d_columns(mesh, sparse_data,
+                                                  sparse_dataset, shuffle):
+    """The same parity for an UNKEYED data set with 2-D columns, whose
+    rows both drivers compute (``ingest.rows_computed``). The two drivers'
+    programs round differently on this backend (6e-8 on the tree before
+    PR 46 too), so bits are held where the programs differ in nothing but
+    the rows' origin: ``run_indexed`` through the closed form against
+    ``run_indexed`` reading the same queue matrix (``_keyed_twin``)."""
+    W = num_workers_of(mesh)
+    kw = dict(num_workers=W, local_batch=16, shuffle=shuffle, seed=7)
+    plan = DeviceEpochPlan(sparse_dataset, **kw)
+
+    def run(drive):
+        trainer, _ = passive_aggressive(mesh, SPARSE_CFG)
+        tables, ls = trainer.init_state(jax.random.key(0))
+        tables, ls, m = drive(trainer, tables, ls)
+        assert sum(float(x["n"].sum()) for x in m) == SPARSE_N
+        return np.asarray(tables["weights"])
+
+    chunked = run(lambda tr, t, l: tr.fit_stream(
+        t, l, device_epoch_chunks(sparse_dataset, num_workers=W,
+                                  local_batch=16, steps_per_chunk=4,
+                                  plan=plan), jax.random.key(1)))
+    indexed = run(lambda tr, t, l: tr.run_indexed(
+        t, l, plan, jax.random.key(1)))
+    queued = run(lambda tr, t, l: tr.run_indexed(
+        t, l, _keyed_twin(mesh, sparse_data, **kw), jax.random.key(1)))
+    np.testing.assert_allclose(chunked, indexed, atol=1e-6)
+    np.testing.assert_array_equal(indexed, queued)
+    assert np.abs(indexed).max() > 0.01
 
 
 def test_indexed_multi_epoch_converges(mesh, dataset):
@@ -384,3 +453,119 @@ def test_run_indexed_as_numpy_false_matches(mesh, dataset):
     for mh, md in zip(host, dev):
         for kh, kd in zip(jax.tree.leaves(mh), jax.tree.leaves(md)):
             np.testing.assert_array_equal(kh, np.asarray(kd))
+
+
+# -- the rows of an unkeyed plan are computed, not read (PR 46) -------------
+
+
+@pytest.mark.parametrize("W", [1, 2, 4, 8])
+@pytest.mark.parametrize("n", [1, 3, 7, 8, 9, 64, 1003])
+def test_closed_form_is_the_unkeyed_queue_matrix(mesh, n, W):
+    """``unkeyed_queue_rows`` against ``DeviceDataset.queues(None, W)``,
+    entry for entry: ragged ``n % W``, ``n < W`` (empty queues), the
+    padding zeros behind every count."""
+    ds = DeviceDataset(mesh, {"x": np.arange(n, dtype=np.int32)})
+    q, counts = ds.queues(None, W)
+    q = np.asarray(q)
+    assert q.shape == (W, max(-(-n // W), 1)) and counts.sum() == n
+    got = np.stack([
+        np.asarray(unkeyed_queue_rows(
+            np.int32(w), np.arange(q.shape[1], dtype=np.int32),
+            np.int32(counts[w]), W))
+        for w in range(W)])
+    assert got.dtype == q.dtype
+    np.testing.assert_array_equal(got, q)
+
+
+@pytest.mark.parametrize("shuffle", [None, "interleave", "sort"])
+def test_computed_rows_give_the_queued_batches(mesh, sparse_data,
+                                               sparse_dataset, shuffle):
+    """Every step of an epoch, every worker, padding rows included: the
+    batch through the closed form is the batch read through the host
+    queue matrix."""
+    W = 8
+    kw = dict(num_workers=W, local_batch=LOCAL_BATCH, shuffle=shuffle,
+              seed=3)
+    plan = DeviceEpochPlan(sparse_dataset, **kw)
+    twin = _keyed_twin(mesh, sparse_data, **kw)
+    q = np.asarray(twin._queues)
+    np.testing.assert_array_equal(
+        q, np.asarray(sparse_dataset.queues(None, W)[0]))
+    assert plan.steps_per_epoch == twin.steps_per_epoch
+    at, twin_at = jax.jit(plan.local_batch_at), jax.jit(twin.local_batch_at)
+    padded = 0
+    for epoch in (0, 1):
+        a, ta = plan.epoch_args(epoch), twin.epoch_args(epoch)
+        assert "queues" not in a and "queues" in ta
+        for t in range(plan.steps_per_epoch):
+            for w in range(W):
+                got = at(a, np.int32(w), np.int32(t))
+                want = twin_at(ta, np.int32(w), np.int32(t))
+                want.pop("stream")
+                assert set(got) == set(want)
+                for k in got:
+                    np.testing.assert_array_equal(
+                        np.asarray(got[k]), np.asarray(want[k]))
+                if shuffle is None:
+                    # ...and, in stream order, straight from the matrix.
+                    pos = t * LOCAL_BATCH + np.arange(LOCAL_BATCH)
+                    rows = q[w, np.minimum(pos, q.shape[1] - 1)]
+                    np.testing.assert_array_equal(
+                        np.asarray(got["feat_ids"]),
+                        sparse_data["feat_ids"][rows])
+                padded += int((np.asarray(got["weight"]) == 0).sum())
+    assert padded      # the weight-0 rows were compared too
+
+
+def _pa_step_text(mesh, plan):
+    trainer, _ = passive_aggressive(mesh, SPARSE_CFG)
+    tables, ls = trainer.init_state(jax.random.key(0))
+    args = plan.epoch_args(0)
+    return args, trainer._get_indexed_fn(plan, "sync").lower(
+        tables, ls, args, np.int32(0),
+        key_to_replicated(jax.random.key(1), mesh)).as_text()
+
+
+def test_unkeyed_step_has_no_queue_parameter(mesh, sparse_data,
+                                             sparse_dataset):
+    """That no path reads the queue is a property of the program's text:
+    the unkeyed plan's operands have no ``queues`` and its step no
+    parameter of the matrix's shape; a keyed plan's have both."""
+    W = num_workers_of(mesh)
+    kw = dict(num_workers=W, local_batch=LOCAL_BATCH, seed=3)
+    plan = DeviceEpochPlan(sparse_dataset, **kw)
+    twin = _keyed_twin(mesh, sparse_data, **kw)
+    assert twin.maxq == plan.maxq == 126
+    queue = f"tensor<{W}x{plan.maxq}xi32>"
+    args, text = _pa_step_text(mesh, plan)
+    assert "queues" not in args and queue not in text
+    args, text = _pa_step_text(mesh, twin)
+    assert args["queues"].shape == (W, plan.maxq) and queue in text
+
+
+@pytest.mark.parametrize("case, want", [
+    ("unkeyed-2d", "ingest.rows_computed"),
+    ("keyed-2d", "ingest.rows_queued"),
+    ("tbuf", None),
+    ("packed", None),
+])
+def test_route_log_names_the_ingest_branch(mesh, dataset, sparse_data,
+                                           sparse_dataset, case, want):
+    """The unpacked branch logs where a step's rows come from; the
+    transposed buffer and the packed matrix log nothing."""
+    kw = dict(num_workers=8, local_batch=LOCAL_BATCH, seed=3)
+    plan = {
+        "unkeyed-2d": lambda: DeviceEpochPlan(sparse_dataset, **kw),
+        "keyed-2d": lambda: _keyed_twin(mesh, sparse_data, **kw),
+        "tbuf": lambda: DeviceEpochPlan(dataset, **kw),
+        "packed": lambda: DeviceEpochPlan(dataset, shuffle="sort", **kw),
+    }[case]()
+    args = plan.epoch_args(0)
+    # The last two cases are named after the operand their branch reads.
+    assert (case in args) == (want is None)
+    ops.clear_routes()
+    jax.jit(plan.local_batch_at).lower(args, np.int32(0), np.int32(0))
+    got = [(r.route, r.rows, r.dim, r.ids) for r in ops.routes_traced()]
+    cols = len(plan.dataset.columns)
+    assert got == ([(want, SPARSE_N, cols, LOCAL_BATCH)] if want else []), got
+    assert not {r[0] for r in got} & ops.PALLAS_ROUTES

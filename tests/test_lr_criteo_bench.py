@@ -502,18 +502,23 @@ def test_route_log_names_the_snapshot_read_and_the_stateful_fold(
         lr_programs):
     """Each before the entry of the call it ends in; the fold's scatter is
     one column wider (its count); sync mode pulls through ``store.pull``
-    and logs no ``pull.*``."""
+    and logs no ``pull.*``. First of all the ingest's entry (PR 46): the
+    plan is unkeyed and its columns 2-D, so a step's rows are computed
+    (32 batches resident, three columns)."""
     rows = B * (NNZ - D) + D
     got = [(r.route, r.rows, r.dim, r.ids, r.reason)
            for r in lr_programs["routes", "ssp"]]
-    xla = got[1][4]     # the plain route's reason is the backend's here
-    assert got == [("pull.snapshot", F, 2, rows, ""),
+    xla = got[2][4]     # the plain route's reason is the backend's here
+    assert got == [("ingest.rows_computed", 32 * B, 3, B, ""),
+                   ("pull.snapshot", F, 2, rows, ""),
                    ("gather.xla", F, 2, rows, xla),
                    ("push.fold", F, 2, rows, "apply_fn"),
                    ("scatter_add.xla", F, 3, rows, xla)], got
     sync = [r.route for r in lr_programs["routes", "sync"]]
-    assert sync == ["gather.xla", "push.fold", "scatter_add.xla"], sync
-    assert not {"pull.snapshot", "push.fold"} & ops.PALLAS_ROUTES
+    assert sync == ["ingest.rows_computed", "gather.xla", "push.fold",
+                    "scatter_add.xla"], sync
+    assert not {"ingest.rows_computed", "pull.snapshot",
+                "push.fold"} & ops.PALLAS_ROUTES
 
 
 def test_route_log_names_the_summed_runs_past_the_vmem_regime(
@@ -534,9 +539,9 @@ def test_route_log_names_the_summed_runs_past_the_vmem_regime(
     rows = B * (NNZ - D) + D
     got = [(r.route, r.rows, r.dim, r.ids, r.reason)
            for r in ops.routes_traced()]
-    assert got[2:] == [("push.fold", F, 2, rows, "apply_fn"),
+    assert got[3:] == [("push.fold", F, 2, rows, "apply_fn"),
                        ("push.acc_runs", F, 2, rows, "fold"),
-                       ("scatter_add.xla", F, 3, rows, got[1][4])], got
+                       ("scatter_add.xla", F, 3, rows, got[2][4])], got
     assert "push.acc_runs" not in ops.PALLAS_ROUTES
     sorts = re.findall(r'loc\("([^"]+)/sort"\(',
                        lowered.as_text(debug_info=True))

@@ -689,7 +689,7 @@ def test_lr_criteo_epoch_program_names_its_round_and_fits(topo, monkeypatch,
     # The plan's geometry without its uploads (no device holds an array).
     plan = object.__new__(DeviceEpochPlan)
     plan.local_batch, plan.shuffle, plan.num_workers = B, "interleave", shards
-    plan.sync_every, plan.maxq, plan.grid_r = s, q, 4096
+    plan.sync_every, plan.maxq, plan.grid_r, plan.route_key = s, q, 4096, None
     plan.counts = np.full(shards, q, np.int32)
     plan.grid_c = np.full(shards, q // 4096, np.int32)
     plan.grid_m = np.full(shards, q, np.int32)
@@ -704,7 +704,6 @@ def test_lr_criteo_epoch_program_names_its_round_and_fits(topo, monkeypatch,
     iargs = {"columns": {"feat_ids": shape((N, slots), jnp.int32),
                          "feat_vals": shape((N, slots), jnp.float32),
                          "label": shape((N,), jnp.float32)},
-             "queues": shape((shards, q), jnp.int32),
              "off_w": shape((shards,), jnp.int32),
              "perm": shape((1, 1), jnp.int32)}
     rows = B * (slots - 13) + 13
@@ -714,6 +713,9 @@ def test_lr_criteo_epoch_program_names_its_round_and_fits(topo, monkeypatch,
         tables, (), iargs, jnp.int32(0), key).compile()
     assert [(r.route, r.rows, r.dim, r.ids, r.reason)
             for r in ops.routes_traced()] == [
+        # The plan is unkeyed: the rows of a step are computed (PR 46),
+        # and the program has no parameter of the queue's shape.
+        ("ingest.rows_computed", N, 3, B, ""),
         ("pull.snapshot", F, 2, rows, ""),
         ("gather.xla", F, 2, rows, "shape"),
         *([("push.fold", F, 2, rows, "apply_fn"),
@@ -731,6 +733,7 @@ def test_lr_criteo_epoch_program_names_its_round_and_fits(topo, monkeypatch,
                  ("push.fold", F // shards, 2, handed, "apply_fn"),
                  ("scatter_add.xla", F // shards, 3, handed, "shape"))])]
     text = compiled.as_text()
+    assert f"s32[{shards},{q}]" not in text
     snap = [ln for ln in text.splitlines() if "/ssp.snapshot/" in ln]
     assert not [ln for ln in snap if "/fps." in ln]
     if shards > 1:

@@ -217,6 +217,7 @@ def write(tree: str, out: str, cells=CELLS) -> None:
         plan = object.__new__(DeviceEpochPlan)
         plan.local_batch, plan.shuffle, plan.num_workers = B, "interleave", 1
         plan.sync_every, plan.maxq, plan.grid_r = s, N, 4096
+        plan.route_key = None
         plan.counts = np.full(1, N, np.int32)
         plan.grid_c = np.full(1, N // 4096, np.int32)
         plan.grid_m = np.full(1, N, np.int32)
@@ -226,9 +227,12 @@ def write(tree: str, out: str, cells=CELLS) -> None:
         iargs = {"columns": {"feat_ids": shape((N, slots), jnp.int32),
                              "feat_vals": shape((N, slots), jnp.float32),
                              "label": shape((N,), jnp.float32)},
-                 "queues": shape((1, N), jnp.int32),
                  "off_w": shape((1,), jnp.int32),
                  "perm": shape((1, 1), jnp.int32)}
+        if not hasattr(fps_tpu.core.device_ingest, "unkeyed_queue_rows"):
+            # A tree from before PR 46 reads an unkeyed plan's rows from
+            # the queue matrix.
+            iargs["queues"] = shape((1, N), jnp.int32)
         emit("lr-criteo.epochs",
              lambda: trainer._build_indexed_fn(plan, "ssp").lower(
                  tables, (), iargs, jnp.int32(0), key))
