@@ -371,8 +371,8 @@ def stage_pa(mesh, sizes: Sizes) -> dict:
         noise=0.05)
     q = 0
     if mesh.devices.size == 1:
-        # Head-prefix routing is specified on one device only (bench.py
-        # run_pa); wider meshes take the dense collective route.
+        # Head-prefix routing is specified on one device only; wider
+        # meshes take the dense collective route.
         data, q = head_sort_slots(data, sizes.pa_head)
         require(q > 0, "pa: head_sort_slots found no guaranteed head column")
     log(f"pa: {sizes.pa_examples} x {sizes.pa_nnz} nnz over "

@@ -3,18 +3,15 @@
 Every correctness claim this framework makes about its data plane is a
 claim about the *lowered program*: "the two-tier route has 2 cross-shard
 collectives per chunk", "prefetch on/off lowers the identical HLO",
-"tables are donated, not copied". Until now those claims were checked by
-one-off regexes buried in ``bench.py`` and ad-hoc test asserts. This
-module gives them a shared substrate: :class:`HloProgram` parses the
+"tables are donated, not copied". This
+module gives those claims a shared substrate: :class:`HloProgram` parses the
 ``jax.jit(...).lower(...).as_text()`` StableHLO module into a flat op
 list (with payload bytes, replica groups, custom-call targets) plus the
 ``@main`` argument/result metadata (donation markers, ``jax.result_info``
 names) that the analysis passes (:mod:`fps_tpu.analysis.passes`) audit.
 
 Parsing is line-based, matching the textual form jax emits (0.4.x through
-the installed 0.9.0; tests/test_analysis.py holds one of each) — the
-same approach (and the exact same payload/threshold semantics) as the
-``count_collectives`` helper this module absorbs from ``bench.py``. It
+the installed 0.9.0; tests/test_analysis.py holds one of each). It
 is deliberately tolerant: unknown ops are still modeled (kind + types),
 so a jax upgrade degrades to weaker analysis, never a crash.
 
@@ -31,7 +28,7 @@ import dataclasses
 import json
 import re
 
-# Cross-shard data-plane collectives (the set bench.py's tiered A/B counts).
+# Cross-shard data-plane collectives.
 COLLECTIVE_KINDS = (
     "all_gather",
     "all_reduce",
@@ -315,5 +312,5 @@ def collective_profile(text: str, min_bytes: int = 1024) -> list[Collective]:
 def count_collectives(text: str, min_bytes: int = 1024) -> int:
     """Cross-shard collectives in a lowered (StableHLO) program whose
     payload is at least ``min_bytes`` (see :func:`collective_profile` for
-    the structured form; this is the historical ``bench.py`` API)."""
+    the structured form)."""
     return len(collective_profile(text, min_bytes))

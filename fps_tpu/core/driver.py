@@ -1749,7 +1749,7 @@ class Trainer:
         compiled program — and hands the StableHLO text to the auditor;
         the actual dispatch path is the unmodified jitted callable, so
         donation/caching behavior is untouched. ``.lower`` passes
-        through for callers (bench.py) that inspect programs directly.
+        through for callers that inspect programs directly.
         """
         if self.audit is None:
             return fn
@@ -1796,7 +1796,7 @@ class Trainer:
 
         The one entry point for the static-analysis tools
         (``tools/audit_programs.py``, ``tools/chaos_sweep.py``'s digest
-        certificate, ``bench.py``'s tiered A/B) — keeping the
+        certificate) — keeping the
         init/attach/place/lower choreography in one place so a tiered
         trainer can't be lowered without its hot replicas. Read-only on
         the trainer: ``store.init`` writes fresh tables into

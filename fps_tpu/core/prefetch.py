@@ -41,8 +41,7 @@ Telemetry (all optional): a :class:`~fps_tpu.obs.timing.PhaseTimer` gets
 the worker's assemble+place seconds folded in as the ``prefetch`` phase,
 and a :class:`~fps_tpu.obs.registry.Recorder` gets a
 ``prefetch.queue_depth`` gauge plus a ``prefetch.chunks`` counter — the
-evidence ``tools/obs_report.py`` and ``bench.py`` render as the overlap
-breakdown.
+evidence ``tools/obs_report.py`` renders as the overlap breakdown.
 """
 
 from __future__ import annotations

@@ -71,8 +71,7 @@ class PAConfig:
     # in [0, H). The worker then flattens ids nnz-major so those q*B
     # leading entries ride head-only kernels with ceil(H/128) row tiles
     # instead of ceil(num_features/128) (``fps_tpu.ops.gather_rows``
-    # ``head_prefix``) — measured at ~15% of the END-TO-END PA headline
-    # (BASELINE.md round-5 section; widening q further is refuted there).
+    # ``head_prefix``): the kernels' cost follows the row tiles they sweep.
     # Purely a routing hint: results are identical (to the dim-1 kernels'
     # documented hi+lo precision) with it on or off.
     head_prefix_cols: int = 0

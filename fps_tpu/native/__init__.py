@@ -151,56 +151,6 @@ def _load():
             ctypes.c_long,
             ctypes.POINTER(ctypes.c_long),
         ]
-        lib.fps_baseline_mf.restype = ctypes.c_double
-        lib.fps_baseline_mf.argtypes = [
-            ctypes.POINTER(ctypes.c_int32),
-            ctypes.POINTER(ctypes.c_int32),
-            ctypes.POINTER(ctypes.c_float),
-            ctypes.c_long, ctypes.c_long, ctypes.c_long,
-            ctypes.c_int, ctypes.c_float, ctypes.c_float,
-            ctypes.c_uint64, ctypes.c_int, ctypes.c_int,
-            ctypes.POINTER(ctypes.c_double),
-            ctypes.POINTER(ctypes.c_double),
-        ]
-        lib.fps_baseline_w2v.restype = ctypes.c_double
-        lib.fps_baseline_w2v.argtypes = [
-            ctypes.POINTER(ctypes.c_int32),
-            ctypes.POINTER(ctypes.c_int32),
-            ctypes.c_long,
-            ctypes.POINTER(ctypes.c_double),
-            ctypes.c_long, ctypes.c_int, ctypes.c_int,
-            ctypes.c_float, ctypes.c_uint64, ctypes.c_int,
-            ctypes.POINTER(ctypes.c_double),
-        ]
-        lib.fps_baseline_pa.restype = ctypes.c_double
-        lib.fps_baseline_pa.argtypes = [
-            ctypes.POINTER(ctypes.c_int32),
-            ctypes.POINTER(ctypes.c_float),
-            ctypes.POINTER(ctypes.c_float),
-            ctypes.c_long, ctypes.c_long, ctypes.c_long,
-            ctypes.c_float, ctypes.c_int, ctypes.c_int,
-            ctypes.POINTER(ctypes.c_double),
-            ctypes.POINTER(ctypes.c_double),
-        ]
-        lib.fps_baseline_pa_mc.restype = ctypes.c_double
-        lib.fps_baseline_pa_mc.argtypes = [
-            ctypes.POINTER(ctypes.c_int32),
-            ctypes.POINTER(ctypes.c_float),
-            ctypes.POINTER(ctypes.c_int32),
-            ctypes.c_long, ctypes.c_long, ctypes.c_long, ctypes.c_long,
-            ctypes.c_float, ctypes.c_int, ctypes.c_int,
-            ctypes.POINTER(ctypes.c_double),
-            ctypes.POINTER(ctypes.c_double),
-        ]
-        lib.fps_baseline_logreg.restype = ctypes.c_double
-        lib.fps_baseline_logreg.argtypes = [
-            ctypes.POINTER(ctypes.c_int32),
-            ctypes.POINTER(ctypes.c_float),
-            ctypes.POINTER(ctypes.c_float),
-            ctypes.c_long, ctypes.c_long, ctypes.c_long,
-            ctypes.c_float, ctypes.c_int,
-            ctypes.POINTER(ctypes.c_double),
-        ]
         _lib = lib
         return _lib
 
@@ -391,155 +341,3 @@ def skipgram_pairs(
     if m < 0:
         return None
     return centers[:m], contexts[:m]
-
-
-def baseline_mf(users, items, ratings, num_users, num_items, *, rank,
-                lr=0.05, reg=0.01, seed=0, epochs=1, ps_mode=True):
-    """MEASURED sequential per-record MF baseline (bench.py's reference
-    stand-in — see the C++ docstring for the generosity argument).
-
-    Runs ``epochs`` passes of per-record SGD over the ratings and returns
-    ``(per_epoch_seconds, per_epoch_mse)`` (lists of length ``epochs``), or
-    ``None`` if the native library is unavailable. ``ps_mode=True`` forces
-    every pull/push through the message ring (the reference's operator-hop
-    structure); ``False`` measures the idealized fused loop."""
-    lib = _load()
-    if lib is None:
-        return None
-    users = np.ascontiguousarray(users, np.int32)
-    items = np.ascontiguousarray(items, np.int32)
-    ratings = np.ascontiguousarray(ratings, np.float32)
-    n = len(users)
-    secs = np.zeros(epochs, np.float64)
-    mses = np.zeros(epochs, np.float64)
-    total = lib.fps_baseline_mf(
-        _ptr(users, ctypes.c_int32), _ptr(items, ctypes.c_int32),
-        _ptr(ratings, ctypes.c_float), n, int(num_users), int(num_items),
-        int(rank), float(lr), float(reg), seed & 0xFFFFFFFFFFFFFFFF,
-        int(epochs), 1 if ps_mode else 0,
-        _ptr(secs, ctypes.c_double), _ptr(mses, ctypes.c_double),
-    )
-    if total < 0:
-        return None
-    return secs.tolist(), mses.tolist()
-
-
-def baseline_w2v(centers, contexts, uni, *, dim, negatives=5, lr=0.025,
-                 seed=0, ps_mode=True):
-    """MEASURED sequential per-pair SGNS baseline. One pass over the given
-    pairs; negatives drawn from the unigram^0.75 cdf. Returns
-    ``(seconds, mean_loss)`` or ``None`` if unavailable."""
-    lib = _load()
-    if lib is None:
-        return None
-    centers = np.ascontiguousarray(centers, np.int32)
-    contexts = np.ascontiguousarray(contexts, np.int32)
-    p = np.asarray(uni, np.float64) ** 0.75
-    cdf = np.cumsum(p / p.sum())
-    loss = ctypes.c_double(0.0)
-    secs = lib.fps_baseline_w2v(
-        _ptr(centers, ctypes.c_int32), _ptr(contexts, ctypes.c_int32),
-        len(centers), _ptr(cdf, ctypes.c_double), len(cdf), int(dim),
-        int(negatives), float(lr), seed & 0xFFFFFFFFFFFFFFFF,
-        1 if ps_mode else 0, ctypes.byref(loss),
-    )
-    if secs < 0:
-        return None
-    return float(secs), float(loss.value)
-
-
-def baseline_logreg(feat_ids, feat_vals, labels, num_features, *, lr=0.1,
-                    ps_mode=True):
-    """MEASURED sequential per-example sparse-logreg baseline (per-feature
-    pull/push fan-out, the reference's shape). One pass; returns
-    ``(seconds, mean_logloss)`` or ``None`` if unavailable."""
-    lib = _load()
-    if lib is None:
-        return None
-    feat_ids = np.ascontiguousarray(feat_ids, np.int32)
-    feat_vals = np.ascontiguousarray(feat_vals, np.float32)
-    labels = np.ascontiguousarray(labels, np.float32)
-    n, nnz = feat_ids.shape
-    loss = ctypes.c_double(0.0)
-    secs = lib.fps_baseline_logreg(
-        _ptr(feat_ids, ctypes.c_int32), _ptr(feat_vals, ctypes.c_float),
-        _ptr(labels, ctypes.c_float), n, nnz, int(num_features), float(lr),
-        1 if ps_mode else 0, ctypes.byref(loss),
-    )
-    if secs < 0:
-        return None
-    return float(secs), float(loss.value)
-
-
-def baseline_pa(feat_ids, feat_vals, labels, num_features, *, C=1.0,
-                variant="PA-I", ps_mode=True):
-    """MEASURED sequential per-example passive-aggressive baseline
-    (per-feature pull/push fan-out, the reference's shape; labels in
-    {-1,+1}). One pass; returns ``(seconds, mean_hinge, mistake_frac)``
-    or ``None`` if unavailable."""
-    lib = _load()
-    if lib is None:
-        return None
-    var = {"PA": 0, "PA-I": 1, "PA-II": 2}[variant]
-    feat_ids = np.ascontiguousarray(feat_ids, np.int32)
-    feat_vals = np.ascontiguousarray(feat_vals, np.float32)
-    labels = np.ascontiguousarray(labels, np.float32)
-    n, nnz = feat_ids.shape
-    hinge = ctypes.c_double(0.0)
-    mist = ctypes.c_double(0.0)
-    secs = lib.fps_baseline_pa(
-        _ptr(feat_ids, ctypes.c_int32), _ptr(feat_vals, ctypes.c_float),
-        _ptr(labels, ctypes.c_float), n, nnz, int(num_features), float(C),
-        var, 1 if ps_mode else 0, ctypes.byref(hinge), ctypes.byref(mist),
-    )
-    if secs < 0:
-        return None
-    return float(secs), float(hinge.value), float(mist.value)
-
-
-# The native multiclass PA kernel's class-row message rides a fixed slot
-# (id + kMaxClasses floats) — mirror of fps_native.cc's kMaxClasses.
-PA_MC_MAX_CLASSES = 120
-
-
-def baseline_pa_mc(feat_ids, feat_vals, labels, num_features, num_classes,
-                   *, C=1.0, variant="PA-I", ps_mode=True):
-    """MEASURED sequential per-example MULTICLASS passive-aggressive
-    baseline (per-feature pull/push fan-out of ``num_classes``-float class
-    rows; labels are class indices). One pass; returns
-    ``(seconds, mean_hinge, mistake_frac)`` or ``None`` **only** for
-    environment failures (library unavailable / allocation failure).
-
-    Data bugs raise ``ValueError`` here on the Python side —
-    ``num_classes`` outside ``[3, PA_MC_MAX_CLASSES]`` or labels outside
-    ``[0, num_classes)`` must surface to the bench caller, not silently
-    drop the baseline the way an environment failure does."""
-    var = {"PA": 0, "PA-I": 1, "PA-II": 2}[variant]
-    feat_ids = np.ascontiguousarray(feat_ids, np.int32)
-    feat_vals = np.ascontiguousarray(feat_vals, np.float32)
-    labels = np.ascontiguousarray(labels, np.int32)
-    if not 3 <= int(num_classes) <= PA_MC_MAX_CLASSES:
-        raise ValueError(
-            f"num_classes={num_classes} outside the multiclass kernel's "
-            f"[3, {PA_MC_MAX_CLASSES}] range (binary PA is baseline_pa)"
-        )
-    if labels.size and (labels.min() < 0 or labels.max() >= num_classes):
-        raise ValueError(
-            f"labels outside [0, {num_classes}): min={labels.min()}, "
-            f"max={labels.max()} — a data bug, not a baseline failure"
-        )
-    lib = _load()
-    if lib is None:
-        return None
-    n, nnz = feat_ids.shape
-    hinge = ctypes.c_double(0.0)
-    mist = ctypes.c_double(0.0)
-    secs = lib.fps_baseline_pa_mc(
-        _ptr(feat_ids, ctypes.c_int32), _ptr(feat_vals, ctypes.c_float),
-        _ptr(labels, ctypes.c_int32), n, nnz, int(num_features),
-        int(num_classes), float(C), var, 1 if ps_mode else 0,
-        ctypes.byref(hinge), ctypes.byref(mist),
-    )
-    if secs < 0:
-        return None
-    return float(secs), float(hinge.value), float(mist.value)

@@ -16,7 +16,6 @@
 #include <cstdio>
 #include <cstdlib>
 #include <cstring>
-#include <ctime>
 
 namespace {
 
@@ -529,556 +528,6 @@ long fps_skipgram_pairs(const int32_t* tokens, long n, int window,
   }
   free(kept);
   return out;
-}
-
-}  // extern "C"
-
-// ---------------------------------------------------------------------------
-// Measured sequential-baseline hot loops (bench.py's reference stand-in).
-//
-// The reference's hot path is a per-record parameter-server loop riding
-// Flink operators: worker receives a record, sends a pull message through a
-// keyed shuffle to the server operator, gets the row back, computes, sends a
-// push message. Its JVM stack cannot run in this image, so bench.py needs a
-// measured stand-in rather than a guessed constant. Two modes, both strictly
-// GENEROUS to the reference:
-//
-//   mode 0 ("ideal"): the fused sequential loop — pull/update/push collapse
-//     into direct array access. A floor no real deployment reaches (no
-//     framework, no serialization, no network, tables cache-resident).
-//   mode 1 ("ps"):    the same loop with every pull request, pull response
-//     and push delta forced through a bounded ring of message slots with
-//     real (noinline) memcpy on both ends — the cheapest possible model of
-//     the reference's operator hops: serialize -> channel -> deserialize
-//     becomes memcpy -> ring -> memcpy, with zero JVM, network or
-//     coordination cost on top.
-//
-// Timing uses CLOCK_MONOTONIC and excludes allocation/init. Each loop also
-// reports its own training-quality metric (online MSE / SGNS loss /
-// logloss) so the caller can verify the baseline LEARNS — the equal-epochs
-// credit in bench.py depends on it.
-
-namespace {
-
-inline double now_s() {
-  timespec ts;
-  clock_gettime(CLOCK_MONOTONIC, &ts);
-  return ts.tv_sec + 1e-9 * ts.tv_nsec;
-}
-
-// Bounded message ring: NSLOT fixed-size slots, reused round-robin like a
-// channel buffer. send/recv are noinline so -O3 cannot collapse the message
-// path back into the ideal loop — each message pays two real calls and two
-// real memcpys, the irreducible cost of an operator hop.
-struct Ring {
-  // >= largest message: id + kMaxClasses floats (multiclass PA row) and
-  // id + 100 floats (rank-100 w2v/MF rows) both fit; static_asserts at
-  // the consumers tie the caps to this size.
-  static const long SLOT = 512;
-  static const long NSLOT = 256;
-  char* data;
-  long w;
-  Ring() : data(static_cast<char*>(malloc(SLOT * NSLOT))), w(0) {}
-  ~Ring() { free(data); }
-  bool ok() const { return data != nullptr; }
-};
-
-// Move an int32 id in and out of a float-typed message slot without
-// violating strict aliasing; memcpy compiles to the same single store/load.
-inline void put_id(float* slot, int32_t id) { memcpy(slot, &id, sizeof(id)); }
-inline int32_t get_id(const float* slot) {
-  int32_t id;
-  memcpy(&id, slot, sizeof(id));
-  return id;
-}
-
-__attribute__((noinline)) char* ring_send(Ring& r, const void* src,
-                                          long nbytes) {
-  char* slot = r.data + (r.w++ % Ring::NSLOT) * Ring::SLOT;
-  memcpy(slot, src, nbytes);
-  return slot;
-}
-
-__attribute__((noinline)) void ring_recv(void* dst, const char* slot,
-                                         long nbytes) {
-  memcpy(dst, slot, nbytes);
-}
-
-inline float fast_sigmoid_arg(float z) {
-  // Guard exp against overflow; the loops' lr keep z small in practice.
-  if (z > 30.0f) z = 30.0f;
-  if (z < -30.0f) z = -30.0f;
-  return z;
-}
-
-}  // namespace
-
-extern "C" {
-
-// Sequential per-record MF SGD (the reference's worker-local user factors /
-// server-resident item factors split): per rating, pull the item row,
-// compute the error, update the local user row, push the item delta.
-// Runs `epochs` passes over the n ratings in the given order, writing
-// per-epoch wall seconds and per-epoch ONLINE train MSE (pre-update error,
-// the same semantic as the TPU path's metrics stream). Returns total train
-// seconds, or -1 on allocation failure.
-double fps_baseline_mf(const int32_t* users, const int32_t* items,
-                       const float* ratings, long n, long num_users,
-                       long num_items, int rank, float lr, float reg,
-                       uint64_t seed, int epochs, int ps_mode,
-                       double* per_epoch_s, double* per_epoch_mse) {
-  if (rank > 120) return -1.0;  // qbuf/dbuf + ring slot budget (cf. w2v)
-  float* P = static_cast<float*>(malloc(sizeof(float) * num_users * rank));
-  float* Q = static_cast<float*>(malloc(sizeof(float) * num_items * rank));
-  if (!P || !Q) {
-    free(P);
-    free(Q);
-    return -1.0;
-  }
-  Rng rng(seed);
-  for (long k = 0; k < num_users * rank; ++k)
-    P[k] = static_cast<float>((rng.uniform() - 0.5) * 0.2);
-  for (long k = 0; k < num_items * rank; ++k)
-    Q[k] = static_cast<float>((rng.uniform() - 0.5) * 0.2);
-
-  Ring ring;
-  if (ps_mode && !ring.ok()) {
-    free(P);
-    free(Q);
-    return -1.0;
-  }
-  float qbuf[128];
-  float dbuf[129];
-  double total = 0.0;
-  for (int e = 0; e < epochs; ++e) {
-    double se = 0.0;
-    double t0 = now_s();
-    for (long k = 0; k < n; ++k) {
-      long u = users[k], i = items[k];
-      float r = ratings[k];
-      float* p = P + u * rank;
-      const float* q;
-      if (ps_mode) {
-        // pull request (item id) -> server; response (rank floats) back.
-        int32_t req = static_cast<int32_t>(i);
-        char* s1 = ring_send(ring, &req, sizeof(req));
-        int32_t got_i;
-        ring_recv(&got_i, s1, sizeof(got_i));
-        char* s2 = ring_send(ring, Q + got_i * rank, sizeof(float) * rank);
-        ring_recv(qbuf, s2, sizeof(float) * rank);
-        q = qbuf;
-      } else {
-        q = Q + i * rank;
-      }
-      float dot = 0.0f;
-      for (int d = 0; d < rank; ++d) dot += p[d] * q[d];
-      float err = r - dot;
-      se += static_cast<double>(err) * err;
-      if (ps_mode) {
-        // local user update + push message (id + rank floats) -> server.
-        put_id(&dbuf[0], static_cast<int32_t>(i));
-        for (int d = 0; d < rank; ++d) {
-          float pd = p[d];
-          dbuf[1 + d] = lr * (err * pd - reg * q[d]);
-          p[d] = pd + lr * (err * q[d] - reg * pd);
-        }
-        char* s3 = ring_send(ring, dbuf, sizeof(float) * (rank + 1));
-        ring_recv(dbuf, s3, sizeof(float) * (rank + 1));
-        float* qrow = Q + get_id(&dbuf[0]) * rank;
-        for (int d = 0; d < rank; ++d) qrow[d] += dbuf[1 + d];
-      } else {
-        float* qrow = Q + i * rank;
-        for (int d = 0; d < rank; ++d) {
-          float pd = p[d], qd = qrow[d];
-          p[d] = pd + lr * (err * qd - reg * pd);
-          qrow[d] = qd + lr * (err * pd - reg * qd);
-        }
-      }
-    }
-    double dt = now_s() - t0;
-    total += dt;
-    if (per_epoch_s) per_epoch_s[e] = dt;
-    if (per_epoch_mse) per_epoch_mse[e] = se / (n > 0 ? n : 1);
-  }
-  free(P);
-  free(Q);
-  return total;
-}
-
-// Sequential per-pair word2vec SGNS: per (center, context) pair, pull the
-// center row and the 1+negatives output rows, update all of them, push them
-// back. Negatives are drawn from the unigram^0.75 cdf by binary search
-// (the reference's unigram-table draw). One pass over the given pairs.
-// Writes the mean SGNS loss over the pass. Returns seconds, or -1.
-double fps_baseline_w2v(const int32_t* centers, const int32_t* contexts,
-                        long n_pairs, const double* uni_cdf, long vocab,
-                        int dim, int negatives, float lr, uint64_t seed,
-                        int ps_mode, double* mean_loss) {
-  if (dim > 120) return -1.0;  // ring slot budget
-  float* IN = static_cast<float*>(malloc(sizeof(float) * vocab * dim));
-  float* OUT = static_cast<float*>(malloc(sizeof(float) * vocab * dim));
-  if (!IN || !OUT) {
-    free(IN);
-    free(OUT);
-    return -1.0;
-  }
-  Rng rng(seed);
-  for (long k = 0; k < vocab * dim; ++k)
-    IN[k] = static_cast<float>((rng.uniform() - 0.5) / dim);
-  memset(OUT, 0, sizeof(float) * vocab * dim);
-
-  Ring ring;
-  if (ps_mode && !ring.ok()) {
-    free(IN);
-    free(OUT);
-    return -1.0;
-  }
-  float vbuf[128], ubuf[128], dbuf[129];
-  double loss = 0.0;
-  double t0 = now_s();
-  for (long k = 0; k < n_pairs; ++k) {
-    long c = centers[k];
-    float* v;
-    if (ps_mode) {
-      int32_t req = static_cast<int32_t>(c);
-      char* s1 = ring_send(ring, &req, sizeof(req));
-      int32_t gi;
-      ring_recv(&gi, s1, sizeof(gi));
-      char* s2 = ring_send(ring, IN + gi * dim, sizeof(float) * dim);
-      ring_recv(vbuf, s2, sizeof(float) * dim);
-      v = vbuf;
-    } else {
-      v = IN + c * dim;
-    }
-    float dv[128];
-    for (int d = 0; d < dim; ++d) dv[d] = 0.0f;
-    for (int j = 0; j <= negatives; ++j) {
-      long o;
-      if (j == 0) {
-        o = contexts[k];
-      } else {
-        // binary search the cdf for a unigram^0.75 draw
-        double x = rng.uniform();
-        long lo = 0, hi = vocab - 1;
-        while (lo < hi) {
-          long mid = (lo + hi) >> 1;
-          if (uni_cdf[mid] < x) lo = mid + 1; else hi = mid;
-        }
-        o = lo;
-      }
-      float* u;
-      if (ps_mode) {
-        int32_t req = static_cast<int32_t>(o);
-        char* s1 = ring_send(ring, &req, sizeof(req));
-        int32_t gi;
-        ring_recv(&gi, s1, sizeof(gi));
-        char* s2 = ring_send(ring, OUT + gi * dim, sizeof(float) * dim);
-        ring_recv(ubuf, s2, sizeof(float) * dim);
-        u = ubuf;
-      } else {
-        u = OUT + o * dim;
-      }
-      float z = 0.0f;
-      for (int d = 0; d < dim; ++d) z += v[d] * u[d];
-      z = fast_sigmoid_arg(z);
-      float sig = 1.0f / (1.0f + __builtin_expf(-z));
-      float label = (j == 0) ? 1.0f : 0.0f;
-      float g = sig - label;
-      loss += (label > 0.5f)
-                  ? -__builtin_log(sig > 1e-7f ? sig : 1e-7f)
-                  : -__builtin_log(1.0f - sig > 1e-7f ? 1.0f - sig : 1e-7f);
-      for (int d = 0; d < dim; ++d) dv[d] -= lr * g * u[d];
-      if (ps_mode) {
-        put_id(&dbuf[0], static_cast<int32_t>(o));
-        for (int d = 0; d < dim; ++d) dbuf[1 + d] = -lr * g * v[d];
-        char* s3 = ring_send(ring, dbuf, sizeof(float) * (dim + 1));
-        ring_recv(dbuf, s3, sizeof(float) * (dim + 1));
-        float* orow = OUT + get_id(&dbuf[0]) * dim;
-        for (int d = 0; d < dim; ++d) orow[d] += dbuf[1 + d];
-      } else {
-        for (int d = 0; d < dim; ++d) u[d] -= lr * g * v[d];
-      }
-    }
-    if (ps_mode) {
-      put_id(&dbuf[0], static_cast<int32_t>(c));
-      for (int d = 0; d < dim; ++d) dbuf[1 + d] = dv[d];
-      char* s3 = ring_send(ring, dbuf, sizeof(float) * (dim + 1));
-      ring_recv(dbuf, s3, sizeof(float) * (dim + 1));
-      float* crow = IN + get_id(&dbuf[0]) * dim;
-      for (int d = 0; d < dim; ++d) crow[d] += dbuf[1 + d];
-    } else {
-      for (int d = 0; d < dim; ++d) v[d] += dv[d];
-    }
-  }
-  double dt = now_s() - t0;
-  if (mean_loss)
-    *mean_loss = loss / ((n_pairs > 0 ? n_pairs : 1) * (1 + negatives));
-  free(IN);
-  free(OUT);
-  return dt;
-}
-
-// Sequential per-example sparse logistic regression: the reference's
-// worker pulls each active feature id INDIVIDUALLY and pushes one delta per
-// feature (SURVEY §3.4's fan-out). Pad slots (value exactly 0) are skipped.
-// One pass; writes mean logloss. Returns seconds, or -1.
-double fps_baseline_logreg(const int32_t* ids, const float* vals,
-                           const float* labels, long n, long nnz,
-                           long num_features, float lr, int ps_mode,
-                           double* mean_logloss) {
-  float* w = static_cast<float*>(calloc(num_features, sizeof(float)));
-  if (!w) return -1.0;
-  Ring ring;
-  if (ps_mode && !ring.ok()) {
-    free(w);
-    return -1.0;
-  }
-  double loss = 0.0;
-  double t0 = now_s();
-  for (long k = 0; k < n; ++k) {
-    const int32_t* fid = ids + k * nnz;
-    const float* fval = vals + k * nnz;
-    float z = 0.0f;
-    for (long j = 0; j < nnz; ++j) {
-      if (fval[j] == 0.0f) continue;
-      float wj;
-      if (ps_mode) {
-        char* s1 = ring_send(ring, &fid[j], sizeof(int32_t));
-        int32_t gi;
-        ring_recv(&gi, s1, sizeof(gi));
-        char* s2 = ring_send(ring, &w[gi], sizeof(float));
-        ring_recv(&wj, s2, sizeof(float));
-      } else {
-        wj = w[fid[j]];
-      }
-      z += wj * fval[j];
-    }
-    z = fast_sigmoid_arg(z);
-    float sig = 1.0f / (1.0f + __builtin_expf(-z));
-    float y = labels[k];
-    float g = (sig - y) * lr;
-    loss += (y > 0.5f)
-                ? -__builtin_log(sig > 1e-7f ? sig : 1e-7f)
-                : -__builtin_log(1.0f - sig > 1e-7f ? 1.0f - sig : 1e-7f);
-    for (long j = 0; j < nnz; ++j) {
-      if (fval[j] == 0.0f) continue;
-      if (ps_mode) {
-        float msg[2];
-        put_id(&msg[0], fid[j]);
-        msg[1] = -g * fval[j];
-        char* s3 = ring_send(ring, msg, sizeof(msg));
-        ring_recv(msg, s3, sizeof(msg));
-        w[get_id(&msg[0])] += msg[1];
-      } else {
-        w[fid[j]] -= g * fval[j];
-      }
-    }
-  }
-  double dt = now_s() - t0;
-  if (mean_logloss) *mean_logloss = loss / (n > 0 ? n : 1);
-  free(w);
-  return dt;
-}
-
-
-// Sequential per-example passive-aggressive (binary, Crammer et al. 2006):
-// the reference's shape — pull each active feature individually, compute
-// the margin and the closed-form step (variant 0=PA, 1=PA-I, 2=PA-II),
-// push one delta per feature. Labels in {-1,+1}; pad slots (value 0)
-// skipped. One pass; writes the mean hinge loss and the online mistake
-// fraction. Returns seconds, or -1.
-double fps_baseline_pa(const int32_t* ids, const float* vals,
-                       const float* labels, long n, long nnz,
-                       long num_features, float C, int variant, int ps_mode,
-                       double* mean_hinge, double* mistake_frac) {
-  float* w = static_cast<float*>(calloc(num_features, sizeof(float)));
-  if (!w) return -1.0;
-  Ring ring;
-  if (ps_mode && !ring.ok()) {
-    free(w);
-    return -1.0;
-  }
-  double hinge = 0.0;
-  long mistakes = 0;
-  double t0 = now_s();
-  for (long k = 0; k < n; ++k) {
-    const int32_t* fid = ids + k * nnz;
-    const float* fval = vals + k * nnz;
-    float y = labels[k];
-    float m = 0.0f, x2 = 0.0f;
-    for (long j = 0; j < nnz; ++j) {
-      if (fval[j] == 0.0f) continue;
-      float wj;
-      if (ps_mode) {
-        char* s1 = ring_send(ring, &fid[j], sizeof(int32_t));
-        int32_t gi;
-        ring_recv(&gi, s1, sizeof(gi));
-        char* s2 = ring_send(ring, &w[gi], sizeof(float));
-        ring_recv(&wj, s2, sizeof(float));
-      } else {
-        wj = w[fid[j]];
-      }
-      m += wj * fval[j];
-      x2 += fval[j] * fval[j];
-    }
-    float l = 1.0f - y * m;
-    if (l < 0.0f) l = 0.0f;
-    hinge += l;
-    if (y * m <= 0.0f) ++mistakes;
-    if (l > 0.0f && x2 > 0.0f) {
-      float tau;
-      if (variant == 0) {
-        tau = l / x2;
-      } else if (variant == 1) {
-        tau = l / x2;
-        if (tau > C) tau = C;
-      } else {
-        tau = l / (x2 + 0.5f / C);
-      }
-      float step = tau * y;
-      for (long j = 0; j < nnz; ++j) {
-        if (fval[j] == 0.0f) continue;
-        if (ps_mode) {
-          float msg[2];
-          put_id(&msg[0], fid[j]);
-          msg[1] = step * fval[j];
-          char* s3 = ring_send(ring, msg, sizeof(msg));
-          ring_recv(msg, s3, sizeof(msg));
-          w[get_id(&msg[0])] += msg[1];
-        } else {
-          w[fid[j]] += step * fval[j];
-        }
-      }
-    }
-  }
-  double dt = now_s() - t0;
-  if (mean_hinge) *mean_hinge = hinge / (n > 0 ? n : 1);
-  if (mistake_frac)
-    *mistake_frac = static_cast<double>(mistakes) / (n > 0 ? n : 1);
-  free(w);
-  return dt;
-}
-
-// Sequential per-example MULTICLASS passive-aggressive (Crammer et al.
-// 2006 max-margin-violation update — the closed form the TPU path's
-// MulticlassPassiveAggressiveWorker computes in batch): per example, pull
-// each active feature's num_classes-float class row, score all classes,
-// take the true class r vs the highest-scoring wrong class s,
-// l = max(0, 1 - (score_r - score_s)), tau per variant with ||x||^2
-// DOUBLED (the update touches two class columns), then push one
-// num_classes-float delta row per active feature (+tau*x_j in column r,
-// -tau*x_j in column s). Labels are class indices in [0, num_classes).
-// ps_mode forces every pull request/response and push delta through the
-// message ring exactly like the binary loop, with row-sized messages.
-// One pass; writes mean hinge loss and the online mistake fraction.
-// Returns seconds, or -1.
-double fps_baseline_pa_mc(const int32_t* ids, const float* vals,
-                          const int32_t* labels, long n, long nnz,
-                          long num_features, long num_classes, float C,
-                          int variant, int ps_mode, double* mean_hinge,
-                          double* mistake_frac) {
-  // The class cap is tied to the fixed buffers below and the ring slot:
-  // msg carries id + num_classes floats, rowbuf/scores hold num_classes.
-  const long kMaxClasses = 120;
-  static_assert(sizeof(float) * (kMaxClasses + 1) <= Ring::SLOT,
-                "multiclass PA message must fit one ring slot");
-  static_assert(kMaxClasses + 1 <= 128,
-                "multiclass PA buffers are 128 floats");
-  if (num_classes < 3 || num_classes > kMaxClasses) return -1.0;
-  // Labels index the scores/msg stack arrays and the weight rows: an
-  // out-of-range class (1-based labels, -1 missing sentinel) must surface
-  // as the -1 error return, not as silent memory corruption.
-  for (long k = 0; k < n; ++k) {
-    if (labels[k] < 0 || labels[k] >= num_classes) return -1.0;
-  }
-  float* w =
-      static_cast<float*>(calloc(num_features * num_classes, sizeof(float)));
-  if (!w) return -1.0;
-  Ring ring;
-  if (ps_mode && !ring.ok()) {
-    free(w);
-    return -1.0;
-  }
-  float rowbuf[128];
-  float msg[128];  // id + num_classes floats
-  float scores[128];
-  double hinge = 0.0;
-  long mistakes = 0;
-  double t0 = now_s();
-  for (long k = 0; k < n; ++k) {
-    const int32_t* fid = ids + k * nnz;
-    const float* fval = vals + k * nnz;
-    long r = labels[k];
-    for (long c = 0; c < num_classes; ++c) scores[c] = 0.0f;
-    float x2 = 0.0f;
-    for (long j = 0; j < nnz; ++j) {
-      if (fval[j] == 0.0f) continue;
-      const float* row;
-      if (ps_mode) {
-        char* s1 = ring_send(ring, &fid[j], sizeof(int32_t));
-        int32_t gi;
-        ring_recv(&gi, s1, sizeof(gi));
-        char* s2 = ring_send(ring, w + static_cast<long>(gi) * num_classes,
-                             sizeof(float) * num_classes);
-        ring_recv(rowbuf, s2, sizeof(float) * num_classes);
-        row = rowbuf;
-      } else {
-        row = w + static_cast<long>(fid[j]) * num_classes;
-      }
-      float xv = fval[j];
-      for (long c = 0; c < num_classes; ++c) scores[c] += row[c] * xv;
-      x2 += xv * xv;
-    }
-    // Highest-scoring WRONG class s; prediction = overall argmax (first
-    // max wins, matching jnp.argmax).
-    long s = (r == 0) ? 1 : 0;
-    long pred = 0;
-    for (long c = 1; c < num_classes; ++c) {
-      if (scores[c] > scores[pred]) pred = c;
-      if (c != r && scores[c] > scores[s]) s = c;
-    }
-    if (pred != r) ++mistakes;
-    float l = 1.0f - (scores[r] - scores[s]);
-    if (l < 0.0f) l = 0.0f;
-    hinge += l;
-    if (l > 0.0f && x2 > 0.0f) {
-      float x2m = 2.0f * x2;
-      float tau;
-      if (variant == 0) {
-        tau = l / x2m;
-      } else if (variant == 1) {
-        tau = l / x2m;
-        if (tau > C) tau = C;
-      } else {
-        tau = l / (x2m + 0.5f / C);
-      }
-      for (long j = 0; j < nnz; ++j) {
-        if (fval[j] == 0.0f) continue;
-        float step = tau * fval[j];
-        if (ps_mode) {
-          put_id(&msg[0], fid[j]);
-          for (long c = 0; c < num_classes; ++c) msg[1 + c] = 0.0f;
-          msg[1 + r] = step;
-          msg[1 + s] = -step;
-          char* s3 = ring_send(ring, msg, sizeof(float) * (num_classes + 1));
-          ring_recv(msg, s3, sizeof(float) * (num_classes + 1));
-          float* wrow =
-              w + static_cast<long>(get_id(&msg[0])) * num_classes;
-          for (long c = 0; c < num_classes; ++c) wrow[c] += msg[1 + c];
-        } else {
-          float* wrow = w + static_cast<long>(fid[j]) * num_classes;
-          wrow[r] += step;
-          wrow[s] -= step;
-        }
-      }
-    }
-  }
-  double dt = now_s() - t0;
-  if (mean_hinge) *mean_hinge = hinge / (n > 0 ? n : 1);
-  if (mistake_frac)
-    *mistake_frac = static_cast<double>(mistakes) / (n > 0 ? n : 1);
-  free(w);
-  return dt;
 }
 
 }  // extern "C"

@@ -364,7 +364,7 @@ DEFAULT_SLOS = (
                     "plane bounded)"),
     # A beacon older than the liveness timeout in any window means a
     # reader sat wedged (SIGSTOP, deadlock, partition) — the incident
-    # the supervisor must act on, never a silent 0 q/s (BENCH_r14).
+    # the supervisor must act on, never a silent 0 q/s.
     SLO("reader_heartbeat_fresh", "reader_heartbeat_age_s_max", "<=",
         5.0, objective=0.75,
         description="worst fleet-reader liveness-beacon age per window "

@@ -401,7 +401,7 @@ def default_registry() -> MetricsRegistry:
                         "(fps_tpu.serve.fleet.liveness_check); beyond "
                         "the liveness timeout the reader is classified "
                         "reader_wedged — an incident, never a silent "
-                        "0 q/s (BENCH_r14)"),
+                        "0 q/s"),
         MetricSpec("serve.batches", "counter", unit="batches",
                    help="coalesced/multi batches executed by the "
                         "ReadServer (one merged fancy-index gather per "
@@ -628,7 +628,7 @@ class Recorder:
 
     def phase_totals(self) -> dict[str, dict]:
         """Per-phase ``{"s": total_seconds, "n": count}`` from the
-        ``driver.phase_seconds`` histogram — the bench.py breakdown."""
+        ``driver.phase_seconds`` histogram."""
         out = {}
         with self._lock:
             for key, h in self._hists.items():

@@ -69,7 +69,7 @@ FENCE_NAME = "serve_fence.json"
 # Liveness defaults: beacons ride the fleet dir (atomic-rename JSON like
 # everything here) at HEARTBEAT_INTERVAL_S; a reader whose newest beacon
 # is older than DEFAULT_LIVENESS_TIMEOUT_S is classified reader_wedged —
-# an INCIDENT the supervisor restarts, never a silent 0 q/s (BENCH_r14).
+# an INCIDENT the supervisor restarts, never a silent 0 q/s.
 HEARTBEAT_INTERVAL_S = 1.0
 DEFAULT_LIVENESS_TIMEOUT_S = 5.0
 
@@ -954,7 +954,7 @@ def liveness_check(ckpt_dir: str, *,
     SLO input) and journals one ``reader_wedged`` incident per wedged
     reader; returns ``{"ages": {reader: age_s}, "wedged": [ids]}``.
     A wedged reader is an INCIDENT the supervisor acts on, never a
-    silent zero in a bench average (BENCH_r14)."""
+    silent zero in an average."""
     beats = scan_heartbeats(ckpt_dir, now=now)
     ages = {r: b["age_s"] for r, b in beats.items()}
     wedged = sorted(r for r, age in ages.items() if age > timeout_s)

@@ -26,11 +26,11 @@ No locks on the read path.
 Latency: every request is timed into a bounded reservoir (plus a
 ``serve.request_seconds`` histogram and ``serve.requests`` /
 ``serve.rows`` counters through ``fps_tpu.obs``); :meth:`latency_s`
-reports p50/p99 — the numbers ``bench.py serve`` publishes. With a
+reports p50/p99. With a
 recorder attached, that is three metric records PER REQUEST (a JSONL
 sink writes three lines each) — the price of exact sample-level
 quantiles in the obs digest. High-qps paths that only need the local
-digest pass ``recorder=None`` (as ``bench.py serve`` does) and read
+digest pass ``recorder=None`` and read
 the reservoir through :meth:`stats`.
 
 thread-safety: the swap is a single reference assignment (atomic under

@@ -527,8 +527,7 @@ def head_sort_slots(data: dict, head_features: int):
     head ids in EVERY example — a static guarantee the sparse workers turn
     into ``ops.gather_rows``/``scatter_add`` ``head_prefix`` routing
     (head-only kernels whose cost scales with the head's row tiles, not
-    the table's). Measured at ~15% of the end-to-end PA headline
-    (BASELINE.md round-5 section). Slot order within an example is
+    the table's). Slot order within an example is
     semantically irrelevant (the models sum over slots), so this is a
     pure relayout.
 

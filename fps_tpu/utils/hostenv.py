@@ -68,7 +68,7 @@ def enable_compilation_cache() -> str | None:
     ``<checkout>/.jax_cache`` — or None on the CPU backend, where it
     stays off.
 
-    Every entry point (``chip_smoke.py``, ``bench.py``,
+    Every entry point (``chip_smoke.py``, ``perfbench/run.py``,
     ``__graft_entry__``, the example CLIs) calls this before its first
     compile. When the environment sets the directory JAX reads it itself
     and no directory is set in code. Otherwise the default is one fixed

@@ -195,13 +195,6 @@ def test_region_payload_from_closing_line():
     assert len(big) == 1 and big[0].payload_bytes == 2048
 
 
-def test_count_collectives_reexported_from_bench():
-    import bench
-
-    assert bench.count_collectives is count_collectives
-    assert bench.collective_profile is collective_profile
-
-
 # ---------------------------------------------------------------------------
 # Seeded mutations: each break is caught by exactly the pass that owns it.
 # ---------------------------------------------------------------------------

@@ -173,126 +173,19 @@ def test_parse_ratings_crlf_and_blank_lines(lib, tmp_path):
     np.testing.assert_allclose(r, [3.5, 2.0])
 
 
-def test_baseline_mf_learns_and_modes_agree(lib):
-    """The measured-baseline MF loop must actually train (bench.py's
-    equal-target credit depends on it), be deterministic per seed, and the
-    message-structured mode must be semantically identical to the fused
-    loop (the ring only adds cost, never changes updates)."""
-    rng = np.random.default_rng(0)
-    nu, ni, rank, n = 300, 200, 4, 20000
-    P = rng.normal(0, 0.5, (nu, rank))
-    Q = rng.normal(0, 0.5, (ni, rank))
-    u = rng.integers(0, nu, n).astype(np.int32)
-    i = rng.integers(0, ni, n).astype(np.int32)
-    r = (np.sum(P[u] * Q[i], 1) + 0.05 * rng.normal(size=n)).astype(
-        np.float32)
-    secs_ps, mse_ps = lib.baseline_mf(u, i, r, nu, ni, rank=rank, lr=0.1,
-                                      epochs=10, ps_mode=True)
-    secs_id, mse_id = lib.baseline_mf(u, i, r, nu, ni, rank=rank, lr=0.1,
-                                      epochs=10, ps_mode=False)
-    assert mse_ps[-1] < 0.5 * mse_ps[0]          # it learns
-    np.testing.assert_allclose(mse_ps, mse_id, rtol=1e-6)  # same semantics
-    assert all(s > 0 for s in secs_ps + secs_id)
-    # deterministic per seed
-    _, mse2 = lib.baseline_mf(u, i, r, nu, ni, rank=rank, lr=0.1, epochs=10,
-                              ps_mode=True)
-    np.testing.assert_array_equal(mse_ps, mse2)
+def test_bound_symbols_are_exported_and_nothing_else_is(lib):
+    """Every ``fps_*`` symbol the loader binds is exported by the library
+    built from the source in the tree, and the library exports nothing the
+    package does not bind: a function in ``fps_native.cc`` with no caller
+    in ``fps_tpu`` has no reader."""
+    import re
+    import subprocess
 
-
-def test_baseline_w2v_learns_and_modes_agree(lib):
-    rng = np.random.default_rng(1)
-    V, dim, n = 500, 16, 30000
-    # planted co-occurrence: context = center + small offset mod V
-    c = rng.integers(0, V, n).astype(np.int32)
-    x = ((c + rng.integers(1, 4, n)) % V).astype(np.int32)
-    uni = np.bincount(c, minlength=V).astype(np.float64) + 1
-    s_ps, loss_ps = lib.baseline_w2v(c, x, uni, dim=dim, negatives=3,
-                                     ps_mode=True)
-    s_id, loss_id = lib.baseline_w2v(c, x, uni, dim=dim, negatives=3,
-                                     ps_mode=False)
-    assert loss_ps < 0.6931  # below chance (sigmoid at 0)
-    assert abs(loss_ps - loss_id) < 1e-6
-    assert s_ps > 0 and s_id > 0
-
-
-def test_baseline_logreg_learns_and_modes_agree(lib):
-    rng = np.random.default_rng(2)
-    nf, nnz, n = 5000, 8, 40000
-    ids = rng.integers(0, nf, (n, nnz)).astype(np.int32)
-    vals = rng.normal(0, 1, (n, nnz)).astype(np.float32)
-    w_true = rng.normal(0, 1, nf)
-    y = ((vals * w_true[ids]).sum(1) > 0).astype(np.float32)
-    s_ps, ll_ps = lib.baseline_logreg(ids, vals, y, nf, ps_mode=True)
-    s_id, ll_id = lib.baseline_logreg(ids, vals, y, nf, ps_mode=False)
-    assert ll_ps < 0.6        # well below chance logloss 0.693
-    assert abs(ll_ps - ll_id) < 1e-6
-    assert s_ps > 0 and s_id > 0
-
-
-def test_baseline_pa_learns_and_modes_agree(lib):
-    rng = np.random.default_rng(4)
-    nf, nnz, n = 3000, 8, 30000
-    ids = rng.integers(0, nf, (n, nnz)).astype(np.int32)
-    vals = rng.normal(0, 1, (n, nnz)).astype(np.float32)
-    w_true = rng.normal(0, 1, nf)
-    y = np.where((vals * w_true[ids]).sum(1) > 0, 1.0, -1.0).astype(
-        np.float32)
-    s_ps, h_ps, m_ps = lib.baseline_pa(ids, vals, y, nf, ps_mode=True)
-    s_id, h_id, m_id = lib.baseline_pa(ids, vals, y, nf, ps_mode=False)
-    assert m_ps < 0.35          # online mistakes well below chance 0.5
-    assert abs(h_ps - h_id) < 1e-6 and abs(m_ps - m_id) < 1e-9
-    assert s_ps > 0 and s_id > 0
-
-
-def test_baseline_pa_mc_learns_and_modes_agree(lib):
-    rng = np.random.default_rng(5)
-    nf, nnz, n, nc = 3000, 8, 30000, 6
-    ids = rng.integers(0, nf, (n, nnz)).astype(np.int32)
-    vals = rng.normal(0, 1, (n, nnz)).astype(np.float32)
-    # Planted per-class weights: label = argmax of true class scores.
-    w_true = rng.normal(0, 1, (nf, nc))
-    scores = np.einsum("bn,bnc->bc", vals, w_true[ids])
-    y = np.argmax(scores, axis=-1).astype(np.int32)
-    s_ps, h_ps, m_ps = lib.baseline_pa_mc(ids, vals, y, nf, nc, ps_mode=True)
-    s_id, h_id, m_id = lib.baseline_pa_mc(ids, vals, y, nf, nc, ps_mode=False)
-    chance = 1.0 - 1.0 / nc
-    assert m_ps < chance - 0.2    # online mistakes well below chance
-    assert abs(h_ps - h_id) < 1e-6 and abs(m_ps - m_id) < 1e-9
-    assert s_ps > 0 and s_id > 0
-
-
-def test_baseline_pa_mc_data_bugs_raise(lib):
-    """Data bugs must raise ValueError on the Python side — only
-    environment failures (library unavailable / allocation) may map to the
-    silent-None baseline drop (ADVICE round 5 low #3)."""
-    ids = np.zeros((4, 2), np.int32)
-    vals = np.ones((4, 2), np.float32)
-    y = np.array([0, 1, 2, 3], np.int32)
-
-    with pytest.raises(ValueError, match="num_classes"):
-        lib.baseline_pa_mc(ids, vals, y, 10, 2)  # binary belongs to baseline_pa
-    with pytest.raises(ValueError, match="num_classes"):
-        lib.baseline_pa_mc(ids, vals, y, 10, lib.PA_MC_MAX_CLASSES + 1)
-    with pytest.raises(ValueError, match="labels"):
-        lib.baseline_pa_mc(ids, vals, np.array([0, 1, 2, 4], np.int32), 10, 4)
-    with pytest.raises(ValueError, match="labels"):
-        lib.baseline_pa_mc(ids, vals, np.array([-1, 1, 2, 3], np.int32), 10, 4)
-
-    # Valid data with the library present: a real measurement, not None.
-    r = lib.baseline_pa_mc(ids, vals, y, 10, 4)
-    assert r is not None and len(r) == 3
-
-
-def test_baseline_pa_mc_none_reserved_for_env_failure(monkeypatch):
-    """With the library unavailable, VALID data returns None (the bench
-    drops the baseline) while bad data still raises — the two failure
-    classes stay distinguishable."""
-    from fps_tpu import native as mod
-
-    monkeypatch.setattr(mod, "_load", lambda: None)
-    ids = np.zeros((4, 2), np.int32)
-    vals = np.ones((4, 2), np.float32)
-    y = np.array([0, 1, 2, 3], np.int32)
-    assert mod.baseline_pa_mc(ids, vals, y, 10, 4) is None
-    with pytest.raises(ValueError, match="labels"):
-        mod.baseline_pa_mc(ids, vals, np.array([9, 9, 9, 9], np.int32), 10, 4)
+    with open(lib.__file__) as f:
+        bound = set(re.findall(r"\blib\.(fps_\w+)", f.read()))
+    assert bound, "the loader binds no symbol"
+    out = subprocess.run(["nm", "-D", "--defined-only", lib._LIB],
+                         check=True, capture_output=True, text=True).stdout
+    exported = set(re.findall(r"\b(fps_\w+)$", out, re.M))
+    assert exported == bound, sorted(exported ^ bound)
+    assert not [s for s in exported if s.startswith("fps_baseline_")]

@@ -319,16 +319,33 @@ def test_multi_replayed_exactly_once_after_reconnect():
 # ---------------------------------------------------------------------------
 
 def test_coalescer_merges_concurrent_pulls_answers_unchanged():
-    server = ReadServer(coalesce=CoalesceConfig(max_batch=64,
-                                                max_delay_s=0.002))
+    cfg = CoalesceConfig(max_batch=64, max_delay_s=0.002)
+    server = ReadServer(coalesce=cfg)
     snap = _snapshot()
     server.swap_to(snap)
     N_THREADS, N_REQ = 8, 30
     errors: list = []
 
+    # Whether free-running threads ever overlap is the scheduler's
+    # choice, so the test makes one merge certain: the first batch is
+    # held EXECUTING until every other thread has queued behind it.
+    executing, release = threading.Event(), threading.Event()
+    run_batch = server._run_batch
+
+    def held_run_batch(snap_, calls):
+        if not executing.is_set():
+            executing.set()
+            assert release.wait(timeout=60)
+        return run_batch(snap_, calls)
+
+    server._run_batch = held_run_batch
+    barrier = threading.Barrier(N_THREADS - 1)
+
     def client(idx):
         rng = np.random.default_rng(idx)
         try:
+            if idx:
+                barrier.wait(timeout=60)
             for _ in range(N_REQ):
                 ids = rng.integers(0, 64, 4)
                 step, values = server.pull("weights", ids)
@@ -340,14 +357,22 @@ def test_coalescer_merges_concurrent_pulls_answers_unchanged():
 
     threads = [threading.Thread(target=client, args=(i,))
                for i in range(N_THREADS)]
-    for t in threads:
+    threads[0].start()
+    assert executing.wait(timeout=60)
+    for t in threads[1:]:
         t.start()
+    deadline = time.perf_counter() + 60
+    while server._coalescer.depth() < N_THREADS - 1:
+        assert time.perf_counter() < deadline, errors
+        time.sleep(cfg.max_delay_s)
+    release.set()
     for t in threads:
         t.join(timeout=60)
     assert not errors
     total = N_THREADS * N_REQ
     assert server.requests == total
-    # Batching actually happened: fewer executions than requests.
+    # Batching actually happened: fewer executions than requests (the
+    # seven requests queued behind the held batch ran as one).
     assert 1 <= server.batches < total
     assert server.batched_requests == total
 

@@ -67,9 +67,8 @@ def xla_scatter(tab, ids, deltas):
 
 def dim1_shapes():
     """Scalar-table (D=1) kernels at the PA workload shape: XLA gather and
-    scatter vs the in-kernel-lane-packed dim-1 kernels (the round-4 PA
-    win; numbers quoted in fps_tpu/ops/pallas_kernels.py's dim-1 header
-    and BASELINE.md). B = 2^20 ids, Zipf(0.9), ~95% duplication."""
+    scatter vs the in-kernel-lane-packed dim-1 kernels (numbers quoted in
+    fps_tpu/ops/pallas_kernels.py's dim-1 header). B = 2^20 ids, Zipf(0.9), ~95% duplication."""
     from fps_tpu.ops.pallas_kernels import (
         gather_rows_dim1_pallas, scatter_add_dim1_pallas,
     )

@@ -1,5 +1,5 @@
 """Chaos sweep: run the fault-injector matrix end-to-end and print a
-one-line survival digest (bench.py-style compact JSON).
+one-line survival digest (compact JSON).
 
 Scenarios (all deterministic — fps_tpu.testing.chaos; the training
 harness is shared with tests/test_resilience.py via

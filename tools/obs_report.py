@@ -603,7 +603,7 @@ def render_digest(obs_dir: str, *, recovery_slo_s: float | None = None) -> dict:
         # traffic, frames the length/CRC gates rejected, requests shed
         # by admission control or abandoned on a dead deadline, and
         # per-reader liveness — a wedged reader is a reader_wedged
-        # incident here, never a silent zero (BENCH_r14).
+        # incident here, never a silent zero.
         "net": {
             "retries": int(counters.get("net.retries", 0)),
             "reconnects": int(counters.get("net.reconnects", 0)),
