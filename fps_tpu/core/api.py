@@ -56,15 +56,50 @@ class StepOutput:
       local_state: updated worker-local pytree.
       out: the reference's ``WOut`` channel (``ParameterServerClient.output``)
         — a metrics/prediction pytree, summed or collected by the driver.
+      dense_grads: for a logic that declares dense parameters
+        (:class:`DenseLogic`), this worker's gradient of its own batch's
+        loss by each of them, under the parameters' names and shapes; the
+        driver sums them over the workers and folds them. ``None`` from
+        every other logic.
     """
 
     pushes: Mapping[str, tuple[Array, Array]]
     local_state: Pytree
     out: Pytree
+    dense_grads: Mapping[str, Array] | None = None
+
+
+@dataclasses.dataclass(frozen=True)
+class DenseLogic:
+    """A worker logic's DENSE parameters: what every example touches (the
+    layers of an MLP beside embedding tables), so no row of it is worth
+    routing. Every worker holds all of them (replicated, ``P()`` over the
+    mesh), reads them whole and returns a gradient for each; the driver
+    sums the gradients over every worker axis (one all-reduce a step; on
+    one device none) and applies ``theta -= learning_rate * sum`` inside
+    the compiled call, in the step that made them, under the scope
+    ``fps.dense`` (route ``dense.psum_sgd``). They never pass through a
+    table's gather or scatter. Parallax's hybrid (arXiv:1808.02621): the
+    sparse parameters on the servers, the dense ones data-parallel.
+
+    ``init_fn(key) -> {name: array}`` makes them; names hold no ``::``.
+    They ride the tables dict under ``<name>::dense``
+    (:func:`fps_tpu.core.store.dense_key`) and a snapshot as ``dense::``
+    arrays. A :class:`WorkerLogic` declares them by its ``dense``
+    attribute; its ``step`` is then handed them as ``dense=`` and
+    returns ``StepOutput.dense_grads``.
+    """
+
+    init_fn: Callable[[Array], Mapping[str, Array]]
+    learning_rate: float
 
 
 class WorkerLogic:
     """Base class for worker-side algorithm logic (pure functions only)."""
+
+    # Dense (replicated) parameters beside the tables: a DenseLogic, or
+    # None (every shipped logic but DLRM's). See DenseLogic.
+    dense: "DenseLogic | None" = None
 
     def init_local_state(self, key: Array, num_workers: int) -> Pytree:
         """Per-device local state; called once under the driver's sharding."""

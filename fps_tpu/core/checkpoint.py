@@ -514,19 +514,23 @@ class Checkpointer:
         # ``fold::`` entries, never part of the canonical table bytes —
         # an untiered (or older) reader skips the kind, a resuming
         # tiered trainer restores it for bit-identical replay.
-        from fps_tpu.core.store import FOLD_KEY_SUFFIX
+        # Dense parameters (api.DenseLogic) ride the same way, as
+        # ``dense::`` entries: state of their own beside the tables.
+        from fps_tpu.core.store import DENSE_KEY_SUFFIX, FOLD_KEY_SUFFIX
 
         for key in sorted(store.tables):
-            if not key.endswith(FOLD_KEY_SUFFIX):
-                continue
-            name = key[: -len(FOLD_KEY_SUFFIX)]
-            arr = store.tables[key]
-            if (hasattr(arr, "sharding")
-                    and not arr.sharding.is_fully_addressable):
-                from fps_tpu.parallel.mesh import replicate_to_mesh
+            for suffix, prefix in (
+                    (FOLD_KEY_SUFFIX, snapshot_format.FOLD_PREFIX),
+                    (DENSE_KEY_SUFFIX, snapshot_format.DENSE_PREFIX)):
+                if not key.endswith(suffix):
+                    continue
+                arr = store.tables[key]
+                if (hasattr(arr, "sharding")
+                        and not arr.sharding.is_fully_addressable):
+                    from fps_tpu.parallel.mesh import replicate_to_mesh
 
-                arr = replicate_to_mesh(arr, store.mesh)
-            arrays[snapshot_format.FOLD_PREFIX + name] = np.asarray(arr)
+                    arr = replicate_to_mesh(arr, store.mesh)
+                arrays[prefix + key[: -len(suffix)]] = np.asarray(arr)
         leaves, treedef = jax.tree.flatten(local_state)
         for i, leaf in enumerate(leaves):
             # Multi-controller: a worker-sharded leaf spans processes, and
@@ -982,7 +986,8 @@ class Checkpointer:
         # detect (and assert) an elastic re-split restore.
         tables.update({
             k: v for k, v in entries.items()
-            if k.startswith(snapshot_format.FOLD_PREFIX)
+            if k.startswith((snapshot_format.FOLD_PREFIX,
+                             snapshot_format.DENSE_PREFIX))
         })
         if snapshot_format.MESH_SHAPE_KEY in entries:
             tables[snapshot_format.MESH_SHAPE_KEY] = entries[
@@ -1193,6 +1198,19 @@ class Checkpointer:
             arr = np.asarray(values_by_name[key], np.float32)
             store.tables[name + FOLD_KEY_SUFFIX] = jax.device_put(
                 arr, store.sharding)
+        # Dense parameters are state of their own too, replicated: a
+        # snapshot without them (taken by a logic that declared none)
+        # leaves whatever the store holds.
+        from jax.sharding import NamedSharding, PartitionSpec
+
+        from fps_tpu.core.store import dense_key
+
+        for key in sorted(values_by_name):
+            if key.startswith(snapshot_format.DENSE_PREFIX):
+                store.tables[dense_key(
+                    key[len(snapshot_format.DENSE_PREFIX):])] = jax.device_put(
+                        np.asarray(values_by_name[key]),
+                        NamedSharding(store.mesh, PartitionSpec()))
         if resplit:
             # The explicit re-split assertion: every table, re-laid-out
             # onto the new mesh, dumps back to EXACTLY the snapshot's

@@ -52,6 +52,7 @@ from fps_tpu.core.store import (
     accumulate_hot,
     compact_cold,
     delta_counted,
+    dense_key,
     fold_key,
     hot_base,
     hot_delta_init,
@@ -71,6 +72,7 @@ from fps_tpu.core.store import (
     reconcile_hot_mapped,
     sketch_key,
     split_hot_push,
+    split_dense,
     split_hot_push_slots,
     split_tiering,
     watch_routed,
@@ -363,12 +365,106 @@ class Trainer:
         self._worker_sharding = NamedSharding(mesh, P(WORKER_AXES))
         self._replicated = NamedSharding(mesh, P())
         self._compiled = {}
+        self._check_dense()
+
+    # -- dense parameters (api.DenseLogic) --------------------------------
+
+    def _check_dense(self) -> None:
+        """Note the logic's dense parameters (``_dense_like``: ``{name:
+        ShapeDtypeStruct}``, empty where it declares none) and refuse, at
+        construction, every mode the dense route does not run under: each
+        would have to say when a dense gradient lands against when a push
+        does, and none says it yet."""
+        self._dense_like = {}
+        if self.logic.dense is None:
+            return
+        shapes = jax.eval_shape(self.logic.dense.init_fn, jax.random.key(0))
+        bad = [k for k in shapes if "::" in k]
+        if bad:
+            raise ValueError(f"dense parameter names hold '::': {bad}")
+        self._dense_like = dict(shapes)
+        names = sorted(shapes)
+        cfg = self.config
+        unsupported = {
+            "sync_every (SSP rounds: a round's snapshot holds tables only,"
+            " so dense reads would be fresh beside stale pulls)":
+                cfg.sync_every,
+            "push_delay (pushes would land later than the dense gradients"
+            " of the same step)": cfg.push_delay,
+            "step_tap (a tap is handed tables and local state, not the"
+            " dense parameters)": cfg.step_tap,
+            "guard (it screens pushes only; a poisoned step's dense"
+            " gradients would still be folded)": cfg.guard,
+            "hot_sync_every > 1 / TableSpec.hot_tier (the tier's windows"
+            " reconcile tables only)": (
+                cfg.hot_sync_every > 1
+                or any(spec.hot_tier for spec in self.store.specs.values())),
+            "auto_tier": cfg.auto_tier,
+        }
+        bad = [what for what, on in unsupported.items() if on]
+        if bad:
+            raise ValueError(
+                f"the worker logic declares dense parameters {names}; the "
+                "dense route (fps.dense) is not supported with: "
+                + "; ".join(bad))
+
+    def _dense_specs(self) -> dict:
+        """Partition specs of the dense entries of the tables dict: every
+        worker holds all of every one."""
+        return {dense_key(name): P() for name in self._dense_like}
+
+    def _dense_fields(self) -> dict:
+        """What a queued unit's span says of the dense route: how many
+        parameters a step reduces and folds, and their bytes."""
+        if not self._dense_like:
+            return {}
+        shapes = self._dense_like.values()
+        return {"dense_params": sum(int(np.prod(s.shape)) for s in shapes),
+                "dense_bytes": sum(int(np.prod(s.shape)) * s.dtype.itemsize
+                                   for s in shapes)}
+
+    def _fold_dense(self, dense, grads):
+        """The dense route of one step: every worker's gradients summed
+        over the worker axes (ONE all-reduce, of all of them together; on
+        one device none) and ``theta -= lr * sum`` applied whole, no
+        gather and no scatter."""
+        if grads is None or set(grads) != set(dense):
+            raise ValueError(
+                "a logic with dense parameters returns StepOutput."
+                f"dense_grads for each of {sorted(dense)}; got "
+                f"{None if grads is None else sorted(grads)}")
+        lr = self.logic.dense.learning_rate
+        names = sorted(dense)
+        with jax.named_scope("fps.dense"):
+            ops.log_route("dense", "psum_sgd",
+                          sum(int(np.prod(dense[k].shape)) for k in names),
+                          1, 0, f"workers={self.num_workers}")
+            summed = [grads[k] for k in names]
+            if self.num_workers > 1:
+                # One vector, so ONE all-reduce in the graph as traced (a
+                # psum of the list lowers to an all-reduce an array).
+                flat = lax.psum(jnp.concatenate(
+                    [g.reshape(-1) for g in summed]), WORKER_AXES)
+                edges = np.cumsum([g.size for g in summed])[:-1]
+                summed = [f.reshape(g.shape).astype(g.dtype) for f, g in
+                          zip(jnp.split(flat, edges), summed)]
+            return {k: dense[k] - (lr * g).astype(dense[k].dtype)
+                    for k, g in zip(names, summed)}
 
     # -- state ------------------------------------------------------------
 
     def init_state(self, key: Array) -> tuple[dict[str, Array], Pytree]:
         with host_span("init_state"):
             tables = self.store.init(jax.random.fold_in(key, 0))
+            if self.logic.dense is not None:
+                # Into the store's own dict, as the call that ends a run
+                # leaves them there (``store.tables = dict(tables)``).
+                dense = jax.jit(
+                    self.logic.dense.init_fn,
+                    out_shardings=jax.tree.map(
+                        lambda _: self._replicated, self._dense_like),
+                )(jax.random.fold_in(key, 2))
+                tables.update({dense_key(k): v for k, v in dense.items()})
             ls_key = jax.random.fold_in(key, 1)
 
             def make_local_state():
@@ -1007,12 +1103,14 @@ class Trainer:
 
     def _compute_step(self, tables, snapshot, local_state, batch, key,
                       hot=None, tier=None, maps=None, track=None,
-                      sk=None, compact=None):
+                      sk=None, compact=None, dense=None):
         """Pull (from live tables, or the SSP ``snapshot`` when given), run
         the worker step, and return its pushes WITHOUT applying them,
         plus the (static) head-prefix guarantee for those pushes, the
         hot-tier pull accounting ({} when the tier is off — nothing extra
-        is traced then), and the updated sketch accumulators.
+        is traced then), the updated sketch accumulators, and ``dense``
+        after the step's fold (:meth:`_fold_dense`; handed back as it
+        came, ``None`` or empty, by a logic that declares none).
 
         ``hot``/``tier``: the replicated hot-head arrays and the resolved
         {table: H} map. Sync-mode pulls partition on hot membership: hot
@@ -1150,7 +1248,13 @@ class Trainer:
                         head_prefix=hp.get(name, 0),
                     )
         with jax.named_scope("fps.compute"):
-            out = self.logic.step(batch, pulled, local_state, key)
+            if dense:
+                out = self.logic.step(batch, pulled, local_state, key,
+                                      dense=dense)
+            else:
+                out = self.logic.step(batch, pulled, local_state, key)
+        if dense:
+            dense = self._fold_dense(dense, out.dense_grads)
         pushes, outch, new_local = out.pushes, out.out, out.local_state
         guard = resilience.as_guard(self.config.guard)
         if guard is not None:
@@ -1185,7 +1289,7 @@ class Trainer:
                         "key — it would collide with the guard's counters"
                     )
                 outch = dict(outch, **{resilience.HEALTH_KEY: health})
-        return pushes, new_local, outch, hp, hot_counts, sk
+        return pushes, new_local, outch, hp, hot_counts, sk, dense
 
     # -- delayed pushes (async in-flight emulation) ------------------------
 
@@ -1568,6 +1672,7 @@ class Trainer:
         def chunk_device(tables, local_state, batches, key):
             # Per-device key stream, decorrelated across workers.
             key = jax.random.fold_in(key, worker_index())
+            tables, dense = split_dense(tables)
             (tables, hot, maps, gids, sketches,
              fstates) = split_tiering(tables)
             delta = self._init_hot_deltas(tables, tier)
@@ -1587,15 +1692,15 @@ class Trainer:
 
             def step_fn(carry, batch_t, snapshot=None):
                 (tables, hot, delta, fstates, sk, bufs, local_state,
-                 key, t) = carry
+                 key, t, dense) = carry
                 key, sub = jax.random.split(key)
                 tapped = self._tap_step(tables, batch_t, local_state, t)
                 with watch_routed() as routed:
                     (pushes, local_state, out, hp, hcounts,
-                     sk) = self._compute_step(
+                     sk, dense) = self._compute_step(
                         tables, snapshot, local_state, batch_t, sub,
                         hot=hot, tier=tier, maps=maps, track=track, sk=sk,
-                        compact=compact,
+                        compact=compact, dense=dense,
                     )
                     hp_seen.update(hp)  # static, the same every traced step
                     dropped = {}
@@ -1614,10 +1719,10 @@ class Trainer:
                     )
                 out = self._mount_tap(out, tapped)
                 return (tables, hot, delta, fstates, sk, bufs,
-                        local_state, key, t + 1), out
+                        local_state, key, t + 1, dense), out
 
             carry0 = (tables, hot, delta, fstates, sk0, bufs,
-                      local_state, key, jnp.int32(0))
+                      local_state, key, jnp.int32(0), dense)
             if mode == "sync":
                 if not tier:
                     carry, outs = lax.scan(step_fn, carry0, batches)
@@ -1637,11 +1742,11 @@ class Trainer:
                         gids=gids,
                     )
                 (tables, hot, delta, fstates, sk, bufs, local_state, _,
-                 t) = carry
+                 t, dense) = carry
             else:
                 # SSP: batches leaves are (R, s, B_local, ...).
                 (tables, hot, delta, fstates, sk, bufs, local_state, _,
-                 t), outs = lax.scan(
+                 t, dense), outs = lax.scan(
                     lambda c, batches_r: self._ssp_round(
                         step_fn, c, batches_r, tier, gids),
                     carry0, batches)
@@ -1650,6 +1755,7 @@ class Trainer:
                 )
             tables = self._flush_push_bufs(tables, bufs, t, hp_seen)
             tables = {**tables,
+                      **{dense_key(n): v for n, v in sorted(dense.items())},
                       **{hot_key(n): v for n, v in sorted(hot.items())},
                       **{map_key(n): v for n, v in sorted(maps.items())},
                       **{ids_key(n): v for n, v in sorted(gids.items())},
@@ -1668,6 +1774,7 @@ class Trainer:
         # order), replicated over data — never a full copy per device.
         table_specs.update({fold_key(name): P(SHARD_AXIS, None)
                             for name in sorted(folds_on)})
+        table_specs.update(self._dense_specs())
         ls_spec = P(WORKER_AXES)
 
         def specs_for_batches(batches):
@@ -1860,6 +1967,7 @@ class Trainer:
         def epoch_device(tables, local_state, iargs, start, key):
             widx = worker_index()
             key = jax.random.fold_in(key, widx)
+            tables, dense = split_dense(tables)
             (tables, hot, maps, gids, sketches,
              fstates) = split_tiering(tables)
             delta = self._init_hot_deltas(tables, tier)
@@ -1876,7 +1984,7 @@ class Trainer:
 
             def step_t(carry, t, snapshot=None):
                 (tables, hot, delta, fstates, sk, bufs, local_state,
-                 key) = carry
+                 key, dense) = carry
                 key, sub = jax.random.split(key)
                 # Device ingest: the step's batch, gathered from the
                 # resident dataset inside the compiled loop.
@@ -1885,9 +1993,10 @@ class Trainer:
                 tapped = self._tap_step(tables, batch, local_state, t)
                 with watch_routed() as routed:
                     (pushes, local_state, out, hp, hcounts,
-                     sk) = self._compute_step(
+                     sk, dense) = self._compute_step(
                         tables, snapshot, local_state, batch, sub,
                         hot=hot, tier=tier, maps=maps, track=track, sk=sk,
+                        dense=dense,
                     )
                     hp_seen.update(hp)  # static, the same every traced step
                     dropped = {}
@@ -1906,14 +2015,16 @@ class Trainer:
                     )
                 out = self._mount_tap(out, tapped)
                 return (tables, hot, delta, fstates, sk, bufs,
-                        local_state, key), out
+                        local_state, key, dense), out
 
             def finish(carry, outs):
                 (tables, hot, delta, fstates, sk, bufs, local_state,
-                 _) = carry
+                 _, dense) = carry
                 tables = self._flush_push_bufs(tables, bufs, start + T,
                                                hp_seen)
                 tables = {**tables,
+                          **{dense_key(n): v
+                             for n, v in sorted(dense.items())},
                           **{hot_key(n): v for n, v in sorted(hot.items())},
                           **{map_key(n): v for n, v in sorted(maps.items())},
                           **{ids_key(n): v for n, v in sorted(gids.items())},
@@ -1923,7 +2034,7 @@ class Trainer:
                 return tables, local_state, outs
 
             carry0 = (tables, hot, delta, fstates, sk0, bufs,
-                      local_state, key)
+                      local_state, key, dense)
             if mode == "sync":
                 if not tier:
                     carry, outs = lax.scan(
@@ -1965,6 +2076,7 @@ class Trainer:
                             for name in sorted(track)})
         table_specs.update({fold_key(name): P(SHARD_AXIS, None)
                             for name in sorted(folds_on)})
+        table_specs.update(self._dense_specs())
         ls_spec = P(WORKER_AXES)
 
         def run(tables, local_state, iargs, start, key):
@@ -2458,7 +2570,7 @@ class Trainer:
                         on_done=(self._hot_tier_later(metrics)
                                  if on_epoch is None and not as_numpy
                                  and not sync_each else None),
-                        epoch=e, steps=T)
+                        epoch=e, steps=T, **self._dense_fields())
                     if quarantine is not None:
                         with _phase(timer, "host_sync"):
                             metrics, restored = self._maybe_quarantine(
@@ -2890,7 +3002,8 @@ class Trainer:
             watcher's to stamp (a None test with no recorder)."""
             out = self.run_chunk(tables, local_state, chunk, ckey,
                                  timer=timer, recorder=rec)
-            watch_device("device.fit_stream", out[2], timer, chunk=i)
+            watch_device("device.fit_stream", out[2], timer, chunk=i,
+                         **self._dense_fields())
             return out
 
         def sync_entry(entry):

@@ -137,6 +137,12 @@ def build_megastep_fn(trainer, plan, mode: str, K: int, tick=None):
     from fps_tpu.core.driver import worker_index
     from fps_tpu.core.store import fold_key, hot_key, ids_key, map_key
 
+    if trainer.logic.dense is not None:
+        raise ValueError(
+            "the worker logic declares dense parameters "
+            f"{sorted(trainer._dense_like)}; the megastep does not "
+            "carry them (its segments thread tables and tier state only): "
+            "drive run_indexed or fit_stream")
     T = trainer._indexed_call_steps(plan)
     s = trainer.config.sync_every
     tier = trainer._hot_tier_map()
@@ -190,7 +196,7 @@ def build_megastep_fn(trainer, plan, mode: str, K: int, tick=None):
                 tapped = trainer._tap_step(tables, batch, local_state, t)
                 with watch_routed() as routed:
                     (pushes, local_state, out, hp, hcounts,
-                     sk) = trainer._compute_step(
+                     sk, _) = trainer._compute_step(
                         tables, snapshot, local_state, batch, sub,
                         hot=hot, tier=tier, maps=maps, track=track, sk=sk,
                         compact=compact_map,

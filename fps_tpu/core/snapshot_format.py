@@ -44,7 +44,7 @@ from fps_tpu.core import retry as _retry
 
 __all__ = [
     "SNAPSHOT_RE", "SNAPSHOT_FMT", "SEP", "TABLE_PREFIX", "LS_PREFIX",
-    "FOLD_PREFIX", "MESH_SHAPE_KEY", "POD_EPOCH_KEY",
+    "FOLD_PREFIX", "DENSE_PREFIX", "MESH_SHAPE_KEY", "POD_EPOCH_KEY",
     "CRC_PREFIX", "IO_ERRORS", "array_crc32", "snapshot_path",
     "snapshot_steps", "verify_snapshot_file", "latest_valid_snapshot",
     "map_snapshot_arrays",
@@ -86,6 +86,11 @@ LS_PREFIX = f"ls{SEP}"
 # snapshot stays restorable by untiered/older readers (which simply skip
 # the kind, as the default ``map_snapshot_arrays`` filter does).
 FOLD_PREFIX = f"fold{SEP}"
+# ``dense::<name>`` entries hold a worker logic's dense parameters
+# (``api.DenseLogic``: replicated arrays folded beside the tables), whole,
+# one entry each; like ``fold::`` they are no table, so a reader that
+# serves tables skips the kind.
+DENSE_PREFIX = f"dense{SEP}"
 CRC_PREFIX = f"meta{SEP}crc{SEP}"
 # ``meta::mesh_shape`` records the (data, shard) mesh shape the snapshot
 # was taken on (a JSON object) — restore detects a mesh-shape change and

@@ -151,6 +151,30 @@ AUX_KEY_SUFFIXES = (HOT_KEY_SUFFIX, MAP_KEY_SUFFIX, IDS_KEY_SUFFIX,
                     SKETCH_KEY_SUFFIX, FOLD_KEY_SUFFIX)
 
 
+# Dense parameters of a worker logic (``api.DenseLogic``): replicated
+# arrays that ride the tables dict beside the tables, as STATE of their
+# own (made by ``Trainer.init_state``, carried and folded by the compiled
+# call, saved as ``dense::`` arrays), not a projection of a table: not an
+# aux entry, so nothing that re-derives the tiering drops them.
+DENSE_KEY_SUFFIX = "::dense"
+
+
+def dense_key(name: str) -> str:
+    """Tables-dict key of the dense parameter ``name``."""
+    return name + DENSE_KEY_SUFFIX
+
+
+def split_dense(tables: Mapping[str, Any]) -> tuple[dict, dict]:
+    """``(everything else, dense parameters by their own names)``."""
+    rest, dense = {}, {}
+    for k, v in tables.items():
+        if k.endswith(DENSE_KEY_SUFFIX):
+            dense[k[: -len(DENSE_KEY_SUFFIX)]] = v
+        else:
+            rest[k] = v
+    return rest, dense
+
+
 def hot_key(name: str) -> str:
     """Tables-dict key of ``name``'s replicated hot-head array."""
     return name + HOT_KEY_SUFFIX

@@ -108,11 +108,14 @@ COMPILE_PHASES = ("compile.trace", "compile.lower", "compile.backend")
 # ``fps.tap`` is ``TrainerConfig.step_tap`` on the step's pre-update view
 # (``Trainer._tap_step``, before the pull); what a tap names INSIDE it has
 # no prefix either (INNER_SCOPES: the top-K ranking's parts, which the
-# serving program ``recommendation.build_topk_fn`` shares). A test walks
-# the tree against the four lists.
+# serving program ``recommendation.build_topk_fn`` shares; so have the
+# parts a worker names inside ``fps.compute``). ``fps.dense`` is the
+# trainer's dense route (``Trainer._fold_dense``: the all-reduce and the
+# fold of a logic's dense parameters, right after ``fps.compute``). A
+# test walks the tree against the four lists.
 STEP_SCOPES = ("fps.ingest", "fps.tap", "fps.prepare", "fps.sketch",
-               "fps.pull", "fps.compute", "fps.push", "fps.combine",
-               "fps.ops",
+               "fps.pull", "fps.compute", "fps.dense", "fps.push",
+               "fps.combine", "fps.ops",
                "fps.hot_accumulate", "fps.sketch_merge",
                "fps.megastep_vote", "fps.megastep_tick", "fps.metrics")
 ONCE_SCOPES = ("ingest.pack", "ingest.tbuf", "ingest.perm", "ingest.chunk",
@@ -121,7 +124,10 @@ ONCE_SCOPES = ("ingest.pack", "ingest.tbuf", "ingest.perm", "ingest.chunk",
                # table, the accumulators' zero fill, the batched solve
                "als.gram", "als.zeros", "als.solve")
 ROUND_SCOPES = ("ssp.snapshot", "hot.reconcile")
-INNER_SCOPES = ("topk.score", "topk.select", "topk.merge")
+INNER_SCOPES = ("topk.score", "topk.select", "topk.merge",
+                # models/dlrm.py, inside fps.compute: the step's three
+                # parts, each part's backward ops under the part's name
+                "dlrm.bottom", "dlrm.interact", "dlrm.top")
 # Set-up spans (no timer: they report through the process-default
 # recorder). Those that queue device work close on its completion when a
 # recorder is installed, and only then (settle()).

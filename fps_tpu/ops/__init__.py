@@ -337,6 +337,32 @@ MEAN_ROWS_TABLE_RATIO = 6.0
 # Netflix's block, plain / packed: 1,024 ids 114 / 169, 4,096 312 / 324,
 # 8,192 572 / 244, 16,384 1102 / 337). Widths outside XLA_PACKED_DIMS
 # were not swept.
+#
+# Thirty times past the grid (``tools/bench_scatter.py rows dlrm``, one v5
+# lite chip, builder's chip run, PR 48): ``dlrm-criteo``'s table,
+# f32[33762577,16], under a step's 425,984 ids (26 fields, Zipf(1.05)
+# within a field: 78,500 distinct), the table a donated loop carry. XLA
+# keeps it TRANSPOSED, ``{0,1:T(8,128)}``: 16 sublanes by 33.8 M lanes,
+# 2.16 GB, no padding (its row-major tiles would be 17.3 GB), as entry
+# parameter, loop carry and scatter operand alike, and the plain ops read
+# and write it in place: nothing is relaid out and nothing table-sized is
+# made (``tests/test_v5e_compile.py`` guards it). Every route above stays
+# out (the packed form would be 2.16 GB, past XLA_PACKED_TABLE_BYTES) and
+# the calls log ``gather.xla`` / ``scatter_add.xla``, reason ``shape``.
+# us a call, the plain ops / a RESIDENT lane-packed form of the same rows
+# (``[4220323, 128]``, eight consecutive rows a packed row, never relaid
+# out: whole packed rows gathered and the id's lanes picked by a one-hot;
+# each update widened to its packed row):
+#
+#   gather 9587 / 7461    scatter-add 43254 / 33127    pair 52516 / 38331
+#
+# 22.5 ns a gathered id and 101.5 ns a scattered one, where [1048576, 16]
+# read 7.2 and 61: the transposed regime does NOT pay the same for every
+# id at every size (per id the scatter is 1.66x and the gather 3.1x dearer
+# at 32 times the rows; which of rows, skew and the 13x more ids is not
+# separated). The resident packed form wins all three (22-27 %) and is
+# what a store that owned the table's layout would carry (ROADMAP M3 (i));
+# nothing routes to it yet.
 XLA_VMEM_TABLE_BYTES = 96 << 20
 XLA_PACKED_TABLE_BYTES = 32 << 20
 XLA_PACKED_DIMS = (8, 32)

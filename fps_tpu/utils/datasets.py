@@ -517,6 +517,55 @@ def synthetic_sparse_classification(
     }
 
 
+# The 26 categorical columns' distinct-token counts of the Criteo Display
+# Advertising Challenge (Kaggle) training set as DLRM's loader reports
+# them (33,762,577 in all); written from memory of the published
+# statistics, as perfbench/configs/lr-criteo.json has them.
+CRITEO_KAGGLE_FIELD_ROWS = (
+    1460, 583, 10131227, 2202608, 305, 24, 12517, 633, 3, 93145, 5683,
+    8351593, 3194, 27, 14992, 5461306, 10, 5652, 2173, 4, 7046547, 18, 15,
+    286181, 105, 142572)
+
+
+def synthetic_click_fields(
+    num_examples: int,
+    field_rows,
+    *,
+    numeric: int = 13,
+    seed: int = 0,
+    noise: float = 0.05,
+):
+    """Click-log rows in the Kaggle layout DLRM trains on: one RAW token a
+    categorical field (Zipf-like within its field's ``field_rows[f]``
+    tokens), ``numeric`` counts already ``log1p``-ed, and a click planted
+    by a per-token effect and a linear effect of the counts, ``noise`` of
+    the labels flipped.
+
+    Returns dict with ``tokens (N, F)`` int32, ``counts (N, numeric)``
+    float32, ``label (N,)`` in {0, 1}.
+    """
+    rng = np.random.default_rng(seed)
+    field_rows = np.asarray(field_rows, np.int64)
+    u = rng.random((num_examples, len(field_rows)))
+    # Continuous inverse CDF of Zipf(1.05), rank = token.
+    e = 1.0 - 1.05
+    top = np.power(field_rows + 1.0, e) - 1.0
+    tokens = np.clip(np.floor(np.power(top * u + 1.0, 1.0 / e)) - 1,
+                     0, field_rows - 1).astype(np.int32)
+    counts = np.log1p(np.floor(np.exp(
+        rng.normal(1.0, 1.5, (num_examples, numeric))))).astype(np.float32)
+    effect = np.sin(tokens * 12.9898 + np.arange(len(field_rows)) * 78.233)
+    margin = (effect.sum(axis=1) / np.sqrt(len(field_rows))
+              + (counts - counts.mean(axis=0)) @ rng.normal(
+                  0, 0.3, numeric))
+    flip = rng.random(num_examples) < noise
+    return {
+        "tokens": tokens,
+        "counts": counts,
+        "label": ((margin > 0) ^ flip).astype(np.float32),
+    }
+
+
 def head_sort_slots(data: dict, head_features: int):
     """Reorder each example's nnz slots so frequency-head ids come first.
 

@@ -92,6 +92,40 @@ def test_logreg_entrypoint(devices8, capsys, tmp_path):
     assert snaps, "no checkpoints written despite --checkpoint-dir"
 
 
+def test_dlrm_entrypoint(devices8, capsys, tmp_path):
+    """The hybrid job on the 8-device mesh: embedding fields on the
+    servers, the MLPs on the trainer's dense route; its snapshots carry
+    the dense parameters."""
+    import numpy as np
+
+    from fps_tpu.examples import dlrm
+
+    ckdir = tmp_path / "ck"
+    ev = run_main(
+        dlrm,
+        TINY + ["--num-examples", "30000", "--epochs", "3",
+                "--arch-sparse-feature-size", "8",
+                "--arch-mlp-bot", "13-32-16-8", "--arch-mlp-top", "32-16-1",
+                "--checkpoint-dir", str(ckdir), "--checkpoint-every", "4"],
+        capsys,
+    )
+    assert ev["start"][0]["rows"] == 6560
+    assert ev["done"][0]["test_accuracy"] > 0.6
+    losses = [c["logloss"] for c in ev["chunk"]]
+    assert losses[-1] < losses[0]
+    snaps = sorted(ckdir.glob("ckpt_*.npz"))
+    assert snaps, "no checkpoints written despite --checkpoint-dir"
+    with np.load(snaps[-1]) as z:
+        assert "dense::top_w0" in z.files and "table::emb" in z.files
+
+
+def test_dlrm_entrypoint_refuses_ssp(devices8):
+    from fps_tpu.examples import dlrm
+
+    with pytest.raises(SystemExit, match="sync-every"):
+        dlrm.main(TINY + ["--sync-every", "4"])
+
+
 def test_ials_entrypoint(devices8, capsys):
     from fps_tpu.examples import ials
 

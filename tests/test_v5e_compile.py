@@ -954,3 +954,42 @@ def test_topk_selection_prunes_with_its_kernel(topo, monkeypatch, shards,
     assert len(kernels) == 1 and "topk_fetch_chunks" in kernels[0] and (
         "/topk.select/" in kernels[0]
         and f"f32[{q * shards},{c},128]" in kernels[0]), kernels
+
+
+def test_dlrm_table_is_read_and_written_in_place_transposed(one_chip,
+                                                             monkeypatch):
+    """``dlrm-criteo``'s table, f32[33762577,16] under a step's 425,984
+    ids as a donated loop carry: XLA keeps it TRANSPOSED
+    (``{0,1:T(8,128)}``: 2.16 GB; row-major tiles would be 17.3 GB and
+    could not be held) as parameter, carry and scatter operand, the plain
+    routes take both ops (reason ``shape``), the scatter writes the carry
+    in place, and no temporary is of the table's size: what ``ops``'
+    comment table says of the shape (PR 48; on the chip 9.6 and 43.3 ms a
+    call, builder's run)."""
+    monkeypatch.setattr(ops, "_use_pallas", lambda: (True, False))
+    R, D, B = 33_762_577, 16, 425_984
+
+    def steps(t, ids):
+        def body(t, i):
+            rows = ops.gather_rows(t, i)
+            return ops.scatter_add(t, i, 0.1 * rows), jnp.sum(rows)
+        return lax.scan(body, t, ids)
+
+    ops.clear_routes()
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in (
+        ((R, D), jnp.float32), ((2, B), jnp.int32))]
+    c = jax.jit(steps, donate_argnums=0).lower(*args).compile()
+    assert [(r.route, r.reason) for r in ops.routes_traced()] == [
+        ("gather.xla", "shape"), ("scatter_add.xla", "shape")]
+    text = c.as_text()
+    table = f"f32[{R},{D}]"
+    layouts = set(re.findall(re.escape(table) + r"(\{[^}]*\})", text))
+    assert layouts == {"{0,1:T(8,128)}"}, layouts
+    (scatter,) = [ln for ln in _top_level(text)
+                  if re.search(r"= " + re.escape(table) + r"\S* fusion\(", ln)]
+    assert "/fps.ops/scatter_add.xla/" in scatter and "kind=kCustom" in scatter
+    assert not re.search(r"= " + re.escape(table) + r"\S* (copy|transpose)\(",
+                         text)
+    memory = c.memory_analysis()
+    assert memory.temp_size_in_bytes < 256 << 20        # the table: 2.16 GB
+    assert memory.alias_size_in_bytes >= R * D * 4      # donated, in place

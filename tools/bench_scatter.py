@@ -11,6 +11,13 @@ route (``fps_tpu.ops``: ``gather.xla_packed`` / ``scatter_add.xla_packed``)
 over table rows x row width at uniform ids — the sweep that set
 ``ops.XLA_VMEM_TABLE_BYTES`` / ``XLA_PACKED_DIMS`` / ``XLA_PACKED_MIN_IDS``.
 
+``rows dlrm``: the plain ops at ``dlrm-criteo.epochs``' own table and ids
+(``[33762577, 16]``, a step's 425,984 ids of the 26 fields, Zipf(1.05)
+within a field), the table a loop carry in the one form XLA keeps it in
+(transposed, 2.16 GB), beside a RESIDENT lane-packed form of the same
+rows (``[4220323, 128]``, eight rows a packed row, never relaid out: what
+a store that owned the layout would carry).
+
 ``mean`` arm: the store's per-id mean push (``fps_tpu.core.store.push``,
 ``combine="mean"``) by its accumulator branch against its row branch over
 rows x width x ids at Zipf(1.0) ids — the sweep that set
@@ -175,6 +182,98 @@ def rows_point(R, D, B, ops_wanted=("scatter", "gather")):
     return out
 
 
+DLRM_R, DLRM_D, DLRM_B, DLRM_T = 33_762_577, 16, 425_984, 16
+
+
+def dlrm_rows():
+    """us a call of the plain XLA gather, scatter-add and their pair on
+    ``dlrm-criteo.epochs``' table under its own ids, and of the same three
+    on a resident lane-packed form (``pack`` = 8 consecutive rows a
+    128-lane row; a gather fetches whole packed rows and picks the id's
+    lanes by a one-hot, a scatter-add widens each update to its packed
+    row). The deltas are made from the ids inside the loop: ``[T, B, 16]``
+    of them would be 3.5 GB in 128-lane tiles."""
+    import json
+
+    from perfbench.datasets.criteo_rows import zipf_tokens
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "perfbench/configs/dlrm-criteo.json")) as fh:
+        d = json.load(fh)["data"]
+    cards = d["categorical_cardinalities"]
+    R, D, B, steps = DLRM_R, DLRM_D, DLRM_B, DLRM_T
+    assert sum(cards) == R and B == 16_384 * len(cards)
+    offsets = jnp.asarray(np.concatenate([[0], np.cumsum(cards)[:-1]]),
+                          jnp.int32)
+    u = jax.random.uniform(jax.random.key(48), (steps, B // len(cards),
+                                                len(cards)))
+    ids = (zipf_tokens(u, cards, d["token_zipf"])
+           + offsets).reshape(steps, B)
+    distinct = int(np.mean([len(np.unique(r)) for r in np.asarray(ids)]))
+    pack, Rp = 128 // D, -(-R // (128 // D))
+
+    def deltas(i):
+        return ((i % 7).astype(jnp.float32) * 1e-6)[:, None] * jnp.ones(
+            (1, D), jnp.float32)
+
+    def plain_gather(t, i):
+        return jnp.take(t, i, axis=0)
+
+    def packed_slots(i):
+        return i // pack, jnp.arange(pack)[None, :] == (i % pack)[:, None]
+
+    def packed_gather(t, i):
+        row, sel = packed_slots(i)
+        rows = jnp.take(t, row, axis=0).reshape(B, pack, D)
+        return jnp.sum(jnp.where(sel[:, :, None], rows, 0), axis=1)
+
+    def packed_scatter(t, i, dl):
+        row, sel = packed_slots(i)
+        upd = jnp.where(sel[:, :, None], dl[:, None, :], 0).reshape(B, 128)
+        return t.at[row].add(upd, mode="drop")
+
+    forms = {"plain": ((R, D), plain_gather, xla_scatter),
+             "packed_resident": ((Rp, 128), packed_gather, packed_scatter)}
+    out = {"rows": R, "dim": D, "ids": B, "distinct_ids": distinct}
+    for name, (shape, gather, scatter) in forms.items():
+        def fresh_table():
+            return jax.jit(lambda k: 0.01 * jax.random.normal(k, shape))(
+                jax.random.key(1))
+
+        tab = fresh_table()
+        for op in ("gather", "scatter", "pair"):
+            def body(carry, i):
+                t, acc = carry
+                if op == "gather":
+                    t = lax.dynamic_update_slice(t, acc[:1, :1], (0, 0))
+                    return (t, acc + gather(t, i)), None
+                if op == "scatter":
+                    return (scatter(t, i, deltas(i)), acc), None
+                return (scatter(t, i, deltas(i) + 1e-6 * gather(t, i)),
+                        acc), None
+
+            f = jax.jit(lambda t, ids: lax.scan(
+                body, (t, jnp.zeros((B, D), jnp.float32)), ids)[0],
+                donate_argnums=0)
+            try:
+                best = 1e9
+                for _ in range(3):  # the first call compiles
+                    t0 = time.perf_counter()
+                    tab, acc = f(tab, ids)
+                    np.asarray(acc[0, 0]), np.asarray(tab[0, 0])
+                    best = min(best, time.perf_counter() - t0)
+                out[f"{op}_{name}_us"] = round(best / steps * 1e6, 1)
+            except Exception as e:  # noqa: BLE001 (does not fit / compile)
+                out[f"{op}_{name}_us"] = f"{type(e).__name__}: {str(e)[:200]}"
+                tab = fresh_table()  # the failed call donated the old one
+            print(json.dumps(out), flush=True)
+        del tab
+    os.makedirs("chiprun_out", exist_ok=True)
+    with open("chiprun_out/bench_scatter_rows.jsonl", "a") as fh:
+        fh.write(json.dumps(dict(
+            out, device=jax.devices()[0].device_kind)) + "\n")
+
+
 def rows_sweep(args):
     """``rows``: the whole grid at B = 32768 (pair at D = 10 only), then
     the fewest ids at which the route pays at Netflix's user block.
@@ -182,6 +281,8 @@ def rows_sweep(args):
     stdout, all of them in ``chiprun_out/bench_scatter_rows.jsonl``."""
     import json
 
+    if args == ["dlrm"]:
+        return dlrm_rows()
     B = 32768
     if args == ["quick"]:
         points = [(480_189, 10, B, ("scatter", "gather", "pair"))]
@@ -790,7 +891,7 @@ if __name__ == "__main__":
     else:
         raise SystemExit(
             f"unknown args {sys.argv[1:]!r} — usage: bench_scatter.py "
-            "dim1|rows [quick]|mean [counts]|wide [quick]|"
+            "dim1|rows [quick|dlrm]|mean [counts]|wide [quick]|"
             "fold [quick|edge|probes]  ('dim1' = "
             "scalar-table PA shape; 'rows' = plain XLA against the "
             "lane-packed XLA route over table rows x row width; 'mean' = "

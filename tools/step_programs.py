@@ -10,12 +10,13 @@ the benchmark at the cell's own shapes (``mf-netflix.epochs``,
 ``pa-rcv1.epochs``, ``mf-netflix.x4``, ``w2v-1bw.epochs``,
 ``lr-criteo.epochs`` and, since PR 36, both accumulate programs of
 ``ials-ml20m.sweeps``, the ones that push; since PR 45
-``mf-netflix-topk.epochs`` and ``w2v-1bw-hot.x4``; all of them, or the
-cells named), compiles it for a described
+``mf-netflix-topk.epochs`` and ``w2v-1bw-hot.x4``; since PR 48
+``dlrm-criteo.epochs``, which a tree from before it skips; all of them, or
+the cells named), compiles it for a described
 ``v5e:2x2`` with the ops layer routing as on the chip, and writes the
 compiled text with metadata,
 stack frames and location tables dropped, and the route log, under
-``out_dir``. One process per tree (a process imports one ``fps_tpu``).
+``out_dir``, and prints the compile's temporary bytes beside the count. One process per tree (a process imports one ``fps_tpu``).
 ``diff`` counts the instructions of each program and the lines that
 differ; what is left are Pallas kernels' debug strings, which hold the
 checkout's path: it says so when the two differ in nothing else. Exit 1
@@ -36,7 +37,7 @@ import sys
 CELLS = ("mf-netflix.epochs", "pa-rcv1.epochs", "mf-netflix.x4",
          "w2v-1bw.epochs", "lr-criteo.epochs", "ials-ml20m.sweeps.user",
          "ials-ml20m.sweeps.item", "mf-netflix-topk.epochs",
-         "w2v-1bw-hot.x4")
+         "w2v-1bw-hot.x4", "dlrm-criteo.epochs")
 
 
 def _normalised(text: str) -> str:
@@ -78,14 +79,17 @@ def write(tree: str, out: str, cells=CELLS) -> None:
 
     def emit(name, lower):
         ops.clear_routes()
-        text = _normalised(lower().compile().as_text())
+        compiled = lower().compile()
+        text = _normalised(compiled.as_text())
         routes = [list(r) for r in ops.routes_traced()]
         with open(os.path.join(out, name + ".txt"), "w") as f:
             f.write(text)
         with open(os.path.join(out, name + ".routes.json"), "w") as f:
             json.dump(routes, f)
         print(name, sum(" = " in ln for ln in text.splitlines()),
-              "instructions", len(routes), "routes", flush=True)
+              "instructions", len(routes), "routes",
+              compiled.memory_analysis().temp_size_in_bytes, "temp bytes",
+              flush=True)
 
     workers = P(None, ("data", "shard"))
 
@@ -237,6 +241,50 @@ def write(tree: str, out: str, cells=CELLS) -> None:
              lambda: trainer._build_indexed_fn(plan, "ssp").lower(
                  tables, (), iargs, jnp.int32(0), key))
 
+    def dlrm_cell():
+        import numpy as np
+
+        from fps_tpu import DeviceEpochPlan
+
+        try:
+            from fps_tpu.models.dlrm import DLRMConfig, dlrm
+        except ImportError:  # a tree from before PR 48
+            print("dlrm-criteo.epochs: no fps_tpu.models.dlrm in this tree",
+                  flush=True)
+            return
+        m, d = model("dlrm-criteo"), model("dlrm-criteo", "data")
+        B, N = m["local_batch"], d["examples_resident"]
+        cfg = DLRMConfig(field_rows=d["categorical_cardinalities"],
+                         embed_dim=m["embed_dim"], numeric=m["numeric"],
+                         bottom_mlp=m["bottom_mlp"], top_mlp=m["top_mlp"],
+                         learning_rate=m["learning_rate"])
+        mesh, shape = mesh_of(1)
+        trainer, _ = dlrm(mesh, cfg)
+        # The plan's geometry without its uploads.
+        grid_r = 1 << min(12, N.bit_length() // 2)
+        plan = object.__new__(DeviceEpochPlan)
+        plan.local_batch, plan.shuffle, plan.num_workers = B, "interleave", 1
+        plan.sync_every, plan.maxq, plan.grid_r = None, N, grid_r
+        plan.route_key = None
+        plan.counts = np.full(1, N, np.int32)
+        plan.grid_c = np.full(1, -(-N // grid_r), np.int32)
+        plan.grid_m = plan.grid_c * grid_r
+        plan.steps_per_epoch = -(-(N + grid_r) // B)
+        key = shape((), jax.random.key(0).dtype)
+        tables = {"emb": shape((cfg.num_rows, cfg.embed_dim), jnp.float32,
+                               P("shard", None))}
+        tables.update({k + "::dense": shape(s, jnp.float32)
+                       for k, s in cfg.layer_shapes().items()})
+        iargs = {"columns": {
+            "tokens": shape((N, len(cfg.field_rows)), jnp.int32),
+            "counts": shape((N, cfg.numeric), jnp.float32),
+            "label": shape((N,), jnp.float32)},
+            "off_w": shape((1,), jnp.int32),
+            "perm": shape((1, 1), jnp.int32)}
+        emit("dlrm-criteo.epochs",
+             lambda: trainer._build_indexed_fn(plan, "sync").lower(
+                 tables, (), iargs, jnp.int32(0), key))
+
     def ials():
         from fps_tpu.models.ials import IALSConfig, IALSSolver
 
@@ -263,7 +311,7 @@ def write(tree: str, out: str, cells=CELLS) -> None:
 
     builders = dict(zip(CELLS, (
         lambda: mf(1), pa, lambda: mf(4), w2v, lr, ials, ials,
-        lambda: mf(1, topk=True), lambda: w2v(hot=True))))
+        lambda: mf(1, topk=True), lambda: w2v(hot=True), dlrm_cell)))
     # (ials builds both its programs: once, whichever is asked for)
     for build in dict.fromkeys(builders[name] for name in CELLS
                                if name in cells):
@@ -273,6 +321,10 @@ def write(tree: str, out: str, cells=CELLS) -> None:
 def diff(a: str, b: str, cells=CELLS) -> int:
     worse = 0
     for name in cells:
+        if not all(os.path.exists(os.path.join(d, name + ".txt"))
+                   for d in (a, b)):
+            print(name, "is not in both trees; skipped")
+            continue
         with open(os.path.join(a, name + ".txt")) as f:
             ta = f.read().splitlines()
         with open(os.path.join(b, name + ".txt")) as f:
