@@ -76,6 +76,7 @@ from fps_tpu.core.store import (
     split_hot_push_slots,
     split_tiering,
     watch_routed,
+    watch_sum_runs,
 )
 from fps_tpu.obs.health import (
     HEALTH_ABORT,
@@ -1641,6 +1642,45 @@ class Trainer:
             chan[name] = counts
         return dict(out, **{resilience.HOT_TIER_KEY: chan})
 
+    @staticmethod
+    def _mount_sum_runs(out, summed):
+        """Attach what the step's ``push.sum_runs`` pushes counted
+        (``store.watch_sum_runs``: a table's pushes handed and kept, and
+        the distinct ids among them, a shard) to the worker out channel,
+        whose sum over the workers makes them the step's: plain leaves
+        ``sum_runs.<table>.<count>`` beside the worker's own (a consumer
+        of per-step metrics sees arrays, no nested channel), the first
+        data replica alone carrying them (a replica's shards are handed
+        every replica's pushes). Nothing noted, nothing mounted: a program
+        without the route grows no leaf."""
+        if not summed:
+            return out
+        leaves = {
+            f"{resilience.SUM_RUNS_KEY}.{name}.{k}": v
+            for name, counts in sorted(summed.items())
+            for k, v in counts.items()}
+        if not isinstance(out, dict) or set(leaves) & set(out):
+            raise TypeError(
+                "push.sum_runs' counts ride the worker's out channel: it "
+                f"must be a dict without the keys {sorted(leaves)}")
+        first = lax.axis_index(DATA_AXIS) == 0
+        return dict(out, **{k: jnp.where(first, v, 0).astype(jnp.float32)
+                            for k, v in leaves.items()})
+
+    @staticmethod
+    def _sum_runs_channel(metrics) -> dict:
+        """``{table: {count: per-step values}}`` from the leaves
+        :meth:`_mount_sum_runs` put into a unit's metrics; ``{}`` where
+        there are none."""
+        chan: dict = {}
+        prefix = resilience.SUM_RUNS_KEY + "."
+        if isinstance(metrics, Mapping):
+            for key in metrics:
+                if isinstance(key, str) and key.startswith(prefix):
+                    table, _, count = key[len(prefix):].rpartition(".")
+                    chan.setdefault(table, {})[count] = metrics[key]
+        return chan
+
     def _merge_sketches(self, sketches, sk):
         """End-of-call sketch merge: psum each tracked table's LOCAL
         window accumulator over the worker axes and fold it into the
@@ -1695,7 +1735,8 @@ class Trainer:
                  key, t, dense) = carry
                 key, sub = jax.random.split(key)
                 tapped = self._tap_step(tables, batch_t, local_state, t)
-                with watch_routed() as routed:
+                with watch_routed() as routed, \
+                        watch_sum_runs() as summed:
                     (pushes, local_state, out, hp, hcounts,
                      sk, dense) = self._compute_step(
                         tables, snapshot, local_state, batch_t, sub,
@@ -1712,6 +1753,7 @@ class Trainer:
                             tables, bufs, t, pushes, hp)
                 out = self._mount_hot_channel(out, hcounts, delta, tier,
                                               dropped, routed)
+                out = self._mount_sum_runs(out, summed)
                 with jax.named_scope("fps.metrics"):
                     out = jax.tree.map(
                         lambda x: lax.psum(lax.psum(x, SHARD_AXIS),
@@ -1991,7 +2033,8 @@ class Trainer:
                 with jax.named_scope("fps.ingest"):
                     batch = plan.local_batch_at(iargs, widx, t)
                 tapped = self._tap_step(tables, batch, local_state, t)
-                with watch_routed() as routed:
+                with watch_routed() as routed, \
+                        watch_sum_runs() as summed:
                     (pushes, local_state, out, hp, hcounts,
                      sk, dense) = self._compute_step(
                         tables, snapshot, local_state, batch, sub,
@@ -2008,6 +2051,7 @@ class Trainer:
                             tables, bufs, t, pushes, hp)
                 out = self._mount_hot_channel(out, hcounts, delta, tier,
                                               dropped, routed)
+                out = self._mount_sum_runs(out, summed)
                 with jax.named_scope("fps.metrics"):
                     out = jax.tree.map(
                         lambda x: lax.psum(lax.psum(x, SHARD_AXIS),
@@ -2215,6 +2259,22 @@ class Trainer:
                 rec.inc(f"exchange.{k}", v, table=table)
         return sums
 
+    @staticmethod
+    def _record_sum_runs(rec, sr) -> dict:
+        """Fold one unit's HOST ``sum_runs`` channel (``{table: per-step
+        pushed_ids / live_ids}``) into the recorder
+        (``sum_runs.pushed_ids`` / ``sum_runs.live_ids``) and return the
+        unit's own sums a table, the journal's ``sum_runs`` field: of the
+        ``pushed_ids`` the additive pushes were handed and kept, their
+        scatters paid for ``live_ids``, the distinct ones a step."""
+        sums = {}
+        for table, counters in sr.items():
+            sums[table] = {k: float(np.sum(np.asarray(v), dtype=np.float64))
+                           for k, v in counters.items()}
+            for k, v in sums[table].items():
+                rec.inc(f"sum_runs.{k}", v, table=table)
+        return sums
+
     def _record_tier_channel(self, rec, ht) -> dict:
         """Both folds of a unit's hot-tier channel, as the fields they
         set on the journal's event (``exchange`` only where it holds
@@ -2232,14 +2292,26 @@ class Trainer:
         then, from a copy that waits for nothing, and the unit's sums
         handed back for its ``device.*`` span, the journal's record of
         the epoch's completion (its ``epoch`` event is written at
-        dispatch, before the numbers exist). ``None`` when the tier is
-        off."""
+        dispatch, before the numbers exist); likewise the counts of the
+        pushes on ``push.sum_runs`` (the span's ``sum_runs`` field).
+        ``None`` when the unit carries neither."""
         ht = (metrics.get(resilience.HOT_TIER_KEY)
               if isinstance(metrics, Mapping) else None)
-        if not ht:
+        sr = self._sum_runs_channel(metrics)
+        if not ht and not sr:
             return None
-        return lambda rec: self._record_tier_channel(
-            rec, jax.tree.map(np.asarray, ht))
+
+        def later(rec):
+            fields = {}
+            if ht:
+                fields.update(self._record_tier_channel(
+                    rec, jax.tree.map(np.asarray, ht)))
+            if sr:
+                fields[resilience.SUM_RUNS_KEY] = self._record_sum_runs(
+                    rec, jax.tree.map(np.asarray, sr))
+            return fields
+
+        return later
 
     def _fold_metrics_accounting(self, rec, metrics, ev=None) -> int:
         """The one per-chunk/epoch telemetry fold for a HOST metrics tree:
@@ -2255,6 +2327,11 @@ class Trainer:
             fields = self._record_tier_channel(rec, ht)
             if ev is not None:
                 ev.update(fields)
+        sr = self._sum_runs_channel(metrics)
+        if sr and rec is not None:
+            sums = self._record_sum_runs(rec, sr)
+            if ev is not None:
+                ev[resilience.SUM_RUNS_KEY] = sums
         if rec is not None:
             if poison:
                 rec.inc("health.poisoned_chunks")

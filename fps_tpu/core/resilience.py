@@ -57,6 +57,13 @@ LOCAL_STATE_KEY = "local_state"
 # as one consistent unit.
 HOT_TIER_KEY = "hot_tier"
 
+# Reserved prefix of the worker out channel's keys under which the additive
+# pushes that summed their rows by id first (``push.sum_runs``,
+# :func:`fps_tpu.core.store.push`) carry their per-step counts, as plain
+# leaves ``sum_runs.<table>.pushed_ids`` / ``.live_ids``, summed over the
+# workers. Mounted by the driver only where a push took the route.
+SUM_RUNS_KEY = "sum_runs"
+
 GUARD_MODES = ("observe", "mask")
 
 

@@ -803,6 +803,17 @@ _ROUTED_WATCHES: list[dict] = []
 
 
 @contextlib.contextmanager
+def _watch(watches: list[dict]):
+    """Open a dict on ``watches`` for what is noted while a step is
+    traced, and hand it over."""
+    seen: dict = {}
+    watches.append(seen)
+    try:
+        yield seen
+    finally:
+        watches.pop()
+
+
 def watch_routed():
     """Collect ``{table: flag}`` while a step is traced: for each table
     whose :func:`pull` / :func:`push` (called with ``table=``) went over
@@ -810,18 +821,37 @@ def watch_routed():
     same on every shard: 1 where every such exchange of the step ran
     owner-routed, 0 where one ran gathered (its ids did not fit their
     lanes, or the shapes keep the exchange gathered)."""
-    seen: dict = {}
-    _ROUTED_WATCHES.append(seen)
-    try:
-        yield seen
-    finally:
-        _ROUTED_WATCHES.pop()
+    return _watch(_ROUTED_WATCHES)
 
 
 def _note_routed(table: str, fits) -> None:
     if _ROUTED_WATCHES and table:
         seen = _ROUTED_WATCHES[-1]
         seen[table] = seen.get(table, 1) * jnp.asarray(fits, jnp.int32)
+
+
+# What the additive pushes that summed their rows by id first
+# (``push.sum_runs``) counted while a watch is open, by the table the
+# caller named: how a step's counts leave the step
+# (:meth:`fps_tpu.core.driver.Trainer._mount_sum_runs`).
+_SUM_RUNS_WATCHES: list[dict] = []
+
+
+def watch_sum_runs():
+    """Collect ``{table: {"pushed_ids", "live_ids"}}`` while a step is
+    traced: for each table whose :func:`push` (called with ``table=``)
+    took ``push.sum_runs``, two int32 scalars of THIS shard: the pushes it
+    was handed and kept, and the distinct ids among them, which are what
+    its scatter then pays for. Empty where no push took the route."""
+    return _watch(_SUM_RUNS_WATCHES)
+
+
+def _note_sum_runs(table: str, pushed, live) -> None:
+    if _SUM_RUNS_WATCHES and table:
+        counts = _SUM_RUNS_WATCHES[-1].setdefault(
+            table, {"pushed_ids": 0, "live_ids": 0})
+        counts["pushed_ids"] += pushed
+        counts["live_ids"] += live
 
 
 def pull(
@@ -972,20 +1002,23 @@ def _id_runs(idx: Array, drop: int) -> tuple[Array, Array, Array]:
 
 
 def _run_sums(first: Array, cols: tuple[Array, ...]) -> tuple[Array, ...]:
-    """Inclusive sums of each of ``cols`` (``[B]`` arrays) WITHIN the runs
-    whose first elements ``first [B]`` flags: a run's last element holds
-    its total. A segmented scan of log depth by doubling: at distance
-    ``s`` an element adds the one ``s`` before it unless a run began in
-    between, ``ceil(log2(B))`` elementwise passes over ``[B]`` arrays. A
-    run's addends meet in a balanced tree, so its total carries the
-    rounding of ``log2(run length)`` additions of its OWN addends; the
+    """Inclusive sums of each of ``cols`` (``[B]`` arrays, or ``[W, B]``:
+    the batch along the LAST axis, the lane-dense form of ``W`` columns)
+    WITHIN the runs whose first elements ``first [B]`` flags: a run's last
+    element holds its total. A segmented scan of log depth by doubling: at
+    distance ``s`` an element adds the one ``s`` before it unless a run
+    began in between, ``ceil(log2(B))`` elementwise passes over ``[B]``
+    arrays. A run's addends meet in a balanced tree, so its total carries
+    the rounding of ``log2(run length)`` additions of its OWN addends; the
     difference of two running sums over the batch would carry that of a
     prefix of the whole batch."""
     B = first.shape[0]
     began, s = first, 1
     while s < B:
         cols = tuple(
-            jnp.where(began, c, c + jnp.pad(c[:-s], (s, 0))) for c in cols)
+            jnp.where(began, c, c + jnp.pad(
+                c[..., :-s], [(0, 0)] * (c.ndim - 1) + [(s, 0)]))
+            for c in cols)
         began = began | jnp.pad(began[:-s], (s, 0), constant_values=True)
         s *= 2
     return cols
@@ -1037,6 +1070,163 @@ def _acc_runs_route(rps: int, dim: int, num_ids: int, acc_dt) -> bool:
     (:data:`fps_tpu.ops.ACC_RUNS_MIN_IDS_PER_ROW`)."""
     return (ops._xla_transposed(rps, dim + 1, acc_dt)
             and num_ids >= ops.ACC_RUNS_MIN_IDS_PER_ROW * rps)
+
+
+def _sum_runs_route(rps: int, dim: int, num_ids: int, dt) -> bool:
+    """Sum the pushed rows by id before the ADDITIVE push's scatter into
+    the shard itself (``push.sum_runs``)? From :func:`push`'s own shapes,
+    and only where the scatter that follows stops at the last distinct id
+    (:func:`fps_tpu.ops._route_xla_sorted`: on the TPU, backend not
+    ``"xla"``, more ids than one of its blocks; handed to any other
+    scatter the sums buy nothing): a float32 table of narrow rows so
+    large that XLA keeps it transposed in HBM
+    (:func:`fps_tpu.ops._xla_transposed_hbm`), where the plain scatter
+    pays some 100 ns for every id it is handed, a repeat or not. A table
+    narrower than float32 stays out (its scatter adds an id's pushes in
+    the table's own dtype one by one; sums formed first would round
+    elsewhere), and so does a wider one. Whether the batch HAS repeats is
+    not in the shapes: :func:`_sorted_runs` reads it from the ids."""
+    return (jnp.dtype(dt) == jnp.float32
+            and ops._xla_transposed_hbm(rps, dim, dt)
+            and ops._route_xla_sorted(rps, dim, num_ids, dt, True))
+
+
+def _run_ends(s: Array) -> tuple[Array, Array]:
+    """Of sorted ``s [B]``: where a run of equal values begins, and where
+    it ends."""
+    edge = s[1:] != s[:-1]
+    one = jnp.ones((1,), bool)
+    return jnp.concatenate([one, edge]), jnp.concatenate([edge, one])
+
+
+def _sorted_runs(idx: Array, rows: Array, drop: int, pad_to: int = 0):
+    """The first half of ``push.sum_runs``, from what the exchange hands a
+    shard and with nothing of the table in it: the batch sorted by index
+    and LOOKED AT. ``(s, in_order, begun, in_long, long_ids, pushed,
+    live)`` from ``idx [B]`` (``drop`` or more for a row to leave out) and
+    ``rows [B, W]``: the indices sorted (``drop`` last) and the rows
+    beside them, an index's rows in the BATCH'S order (the sort's second
+    key is the position); ``pushed`` the rows not left out and ``live``
+    the distinct indices among them. One sort of ``(index, position)``,
+    never of the rows' ``W`` columns (a TPU sort's time, and above all its
+    compile time, grows with its operands: :func:`_sum_id_runs` carries
+    3 - 4, this would carry 17: 426 s of compile), and ONE gather of the
+    rows into that order.
+
+    Where the batch repeats itself (:func:`_repeats`), also its LONG runs,
+    those of more than :data:`fps_tpu.ops.SUM_RUNS_TREE_MAX_RUN` rows:
+    ``long_ids [H]`` their indices in order (``drop`` after the last; ``H``
+    is the most a batch of ``max(B, pad_to)`` can hold), ``begun [B]`` how many of them have
+    begun at or before an element and ``in_long [B]`` whether it lies in
+    one: what :func:`_summed_runs` chains. From a comparison with the
+    element that many places on, one cumulative maximum, one cumulative
+    sum and a third sort, of the indices alone. With ``pad_to`` the
+    per-element arrays are lengthened to that many by elements to drop."""
+    B, W = rows.shape
+    T = ops.SUM_RUNS_TREE_MAX_RUN
+    H = max(B, pad_to) // (T + 1) + 1
+    pos = jnp.arange(B, dtype=jnp.int32)
+    s, order = lax.sort((jnp.minimum(idx, drop), pos), num_keys=2,
+                        is_stable=False)  # the keys are distinct
+    in_order = jnp.take(rows, order, axis=0)
+    first, _ = _run_ends(s)
+    kept = s < drop
+    pushed = jnp.sum(kept.astype(jnp.int32))
+    live = jnp.sum((first & kept).astype(jnp.int32))
+
+    def long_runs():
+        # A run is long where its first element finds its own index T
+        # places on; the flag rides the run's start through a cumulative
+        # maximum (starts increase) to every element of the run.
+        long_first = first & kept & (s == jnp.concatenate(
+            [s[T:], jnp.full((min(T, B),), -1, s.dtype)])[:B])
+        in_long = lax.cummax(jnp.where(
+            first, 2 * pos + long_first.astype(jnp.int32), 0)) % 2 == 1
+        (ids,) = lax.sort((jnp.where(long_first, s, drop),),
+                          is_stable=False)
+        return jnp.cumsum(long_first.astype(jnp.int32)), in_long, ids[:H]
+
+    begun, in_long, long_ids = lax.cond(
+        _repeats(pushed, live), long_runs,
+        lambda: (jnp.zeros((B,), jnp.int32), jnp.zeros((B,), bool),
+                 jnp.full((H,), drop, s.dtype)))
+    if pad_to > B:
+        n = pad_to - B
+        s = jnp.concatenate([s, jnp.full((n,), drop, s.dtype)])
+        in_order = jnp.concatenate([in_order, jnp.zeros((n, W), rows.dtype)])
+        begun = jnp.concatenate([begun, jnp.full((n,), H, jnp.int32)])
+        in_long = jnp.concatenate([in_long, jnp.zeros((n,), bool)])
+    return s, in_order, begun, in_long, long_ids, pushed, live
+
+
+def _repeats(pushed: Array, live: Array) -> Array:
+    """Does a batch of ``pushed`` kept rows on ``live`` distinct indices
+    repeat itself enough for its sums to pay
+    (:data:`fps_tpu.ops.SUM_RUNS_MAX_DISTINCT_SHARE`)?"""
+    return live <= ops.SUM_RUNS_MAX_DISTINCT_SHARE * pushed
+
+
+def _summed_runs(s: Array, in_order: Array, begun: Array, in_long: Array,
+                 long_rows: Array, pushed: Array, live: Array,
+                 drop: int) -> tuple[Array, Array]:
+    """The second half of ``push.sum_runs``: ``(ids [B], sums [B, W])``
+    for the scatter into the shard, ``ids`` non-decreasing with everything
+    to drop last (what :func:`fps_tpu.ops.scatter_add` calls
+    ``ids_sorted``), from :func:`_sorted_runs`' result and ``long_rows [H,
+    W]``, the shard's rows at ``long_ids`` as they stand. The TABLE is in
+    neither branch: both return arrays of the payload's size.
+
+    Where the batch repeats itself, each distinct index once beside ONE
+    row to add. A run of up to :data:`fps_tpu.ops.SUM_RUNS_TREE_MAX_RUN`
+    rows gives their sum (:func:`_run_sums` over the transposed rows, ``[W,
+    B]``: lane-dense passes, the addends in a tree among themselves). A
+    LONG run gives what adding its rows ONE BY ONE to the shard's row, in
+    the batch's order, changes that row by: the rows are scatter-added
+    into ``long_rows`` (a small buffer in XLA's VMEM regime, a few ns a
+    row handed; every other element rides along as a zero row on the next
+    long run's slot, so the slots are sorted) and ``long_rows`` is taken
+    off again. That is the plain scatter's own arithmetic, a rounding an
+    addend at the ROW's magnitude: a tree is nearer the exact sum, but
+    what a plain float32 reference computes is the chain, and on a row
+    that takes thousands of small addends a step the two differ by the
+    chain's own rounding (``dlrm-criteo``'s 3-row field: 1.2e-2 of its
+    change in 65 steps, chip run, PR 49). A second sort of ``(index on a
+    run's last element or drop, position)`` brings the distinct indices to
+    the front, and their rows are fetched from where that sort says, a
+    block of the sorted route's at a time and only the blocks that hold a
+    live index; the rows past them are zeros beside ``drop``. Where the
+    batch hardly repeats itself the sums would cost more than the scatter
+    saves, and the sorted batch is handed on as it is, repeats adjacent
+    and in the batch's order."""
+    B, W = in_order.shape
+    H = long_rows.shape[0]
+    C = min(ops.XLA_SORTED_BLOCK_IDS, B)
+    pos = jnp.arange(B, dtype=jnp.int32)
+
+    def summed():
+        first, last = _run_ends(s)
+        chained = long_rows.at[begun - in_long.astype(jnp.int32)].add(
+            jnp.where(in_long[:, None], in_order, 0), mode="drop") - long_rows
+        (totals,) = _run_sums(first, (in_order.T,))
+        totals = totals.T
+        slot = jnp.where(in_long, begun - 1, H)
+        ids, at = lax.sort((jnp.where(last & (s < drop), s, drop), pos),
+                           num_keys=1, is_stable=False)
+
+        def fetch(c, sums):
+            start = jnp.minimum(c * C, B - C)
+            p = lax.dynamic_slice(at, (start,), (C,))
+            h = jnp.take(slot, p)
+            block = jnp.where(
+                (h < H)[:, None],
+                jnp.take(chained, jnp.minimum(h, H - 1), axis=0),
+                jnp.take(totals, p, axis=0))
+            return lax.dynamic_update_slice(sums, block, (start, 0))
+
+        return ids, lax.fori_loop(0, (live + C - 1) // C, fetch,
+                                  jnp.zeros((B, W), in_order.dtype))
+
+    return lax.cond(_repeats(pushed, live), summed, lambda: (s, in_order))
 
 
 def _mean_push_ratio(rps: int, dim: int, num_ids: int, dtype) -> float:
@@ -1210,7 +1400,16 @@ def push(
       combine: how duplicate ids within one push combine — the analog of
         the reference's pluggable combining senders (user-supplied
         ``CombinationLogic``, expected upstream ``.../ps/client/sender/``):
-        * ``"sum"`` — every message folds in (reference semantics);
+        * ``"sum"`` — every message folds in (reference semantics). With
+          the additive fold the pushes are scatter-added into the shard
+          itself; where the shard is one XLA keeps transposed in HBM
+          (:func:`_sum_runs_route`: ``push.sum_runs`` in the route log)
+          and the batch is seen to repeat its ids, the rows of one id are
+          first summed (:func:`_summed_runs`) and the
+          scatter is handed each distinct id once, sorted, and nothing
+          after the last: every push still folds in, the same float32
+          addends in another order, and the cost follows the distinct
+          ids;
         * ``"mean"`` — per-id average: one averaged step per touched row
           per push, stable for Zipfian-hot ids under large batches. Two
           branches, chosen from the arguments' shapes and logged in the
@@ -1465,9 +1664,24 @@ def push(
     def handed(local_idx, handed_deltas, owned, asked=0, pad_to=0):
         """The push, or its first half, from what the gathered or the
         owner-routed exchange hands this shard: the additive scatter into
-        the shard itself, or :func:`summed`'s result."""
+        the shard itself (where the rows are summed by id first,
+        ``push.sum_runs``, what :func:`finished` scatters), or
+        :func:`summed`'s result."""
         masked = jnp.where(owned[:, None], handed_deltas,
                            jnp.zeros_like(handed_deltas))
+        if sum_runs:
+            # Into a table XLA keeps transposed in HBM the scatter pays
+            # for every id it is handed, and most of a skewed batch's ids
+            # are repeats: the rows of one id are summed first, the same
+            # float32 addends in a tree among themselves, and reach the
+            # table in ONE add, beside the distinct ids sorted and the
+            # drop sentinel after the last.
+            ops.log_route("push", "sum_runs", rps, dim,
+                          local_idx.shape[0], "xla_transposed_hbm")
+            with jax.named_scope(COMBINE_SCOPE):
+                return _sorted_runs(local_idx,
+                                    masked.astype(local_shard.dtype), rps,
+                                    pad_to)
         if additive:
             # Head-prefix guarantee survives only when the gathered stream
             # is the caller's own (single shard, no data axis — the driver
@@ -1480,21 +1694,46 @@ def push(
                       rps, asked or local_idx.shape[0], raw=handed_deltas,
                       owned=owned, pad_to=pad_to)
 
+    def finished(out):
+        """The push from :func:`handed`'s result: the additive scatter's
+        table as it is; the sorted runs summed and scattered into the
+        shard, outside every conditional, its counts noted for the step;
+        :func:`summed`'s result folded."""
+        if sum_runs:
+            *runs, long_ids, pushed, live = out
+            _note_sum_runs(table, pushed, live)
+            with jax.named_scope(COMBINE_SCOPE):
+                # The long runs' rows as they stand (a buffer's worth; ids
+                # past the last are clipped and their rows unused).
+                long_rows = jnp.take(
+                    local_shard, jnp.minimum(long_ids, rps - 1), axis=0)
+                slot_idx, sums = _summed_runs(*runs, long_rows, pushed,
+                                              live, rps)
+            return ops.scatter_add(local_shard, slot_idx, sums,
+                                   ids_sorted=True)
+        return out if additive else folded(out)
+
     gathered = partial(_gathered_exchange, ids, deltas, rps=rps,
                        num_shards=num_shards, shard_axis=shard_axis,
                        data_axis=data_axis)
     lanes = _lanes_of_call("push", local_shard, ids, num_shards=num_shards,
                            shard_axis=shard_axis, data_axis=data_axis,
                            table=table)
+    # The additive push's own regime, asked about the pushes the exchange
+    # hands this shard (the lanes' S x L where it is the owner-routed one,
+    # as the mean's branch is).
+    sum_runs = additive and _sum_runs_route(
+        rps, dim, workers * B if lanes is None else lanes.src.shape[0],
+        local_shard.dtype)
     if lanes is None:
-        out = handed(*gathered())
-        return out if additive else folded(out)
+        return finished(handed(*gathered()))
     # Both branches hand the second half the same shapes, so the shard
-    # itself stays out of the conditional (but for the additive scatter):
-    # the accumulator is the shard's size whatever was handed; the row
-    # branch's sorted ids are lengthened to the gathered exchange's, which
-    # a scatter that stops at the first dropped id does not pay for; and a
-    # step that falls back takes the mean's branch the lanes' S x L pushes
+    # itself stays out of the conditional (but for the additive scatter
+    # where the rows are not summed first): the accumulator is the shard's
+    # size whatever was handed; the row branch's sorted ids (the mean's,
+    # and ``push.sum_runs``') are lengthened to the gathered exchange's,
+    # which a scatter that stops at the first dropped id does not pay for;
+    # and a step that falls back takes the branch the lanes' S x L pushes
     # chose.
     span = lanes.src.shape[0]
     out = lax.cond(
@@ -1503,7 +1742,7 @@ def push(
             lanes, deltas, rps=rps, num_shards=num_shards,
             shard_axis=shard_axis), span, num_shards * B),
         lambda: handed(*gathered(), span))
-    return out if additive else folded(out)
+    return finished(out)
 
 
 # ---------------------------------------------------------------------------

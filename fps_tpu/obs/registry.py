@@ -229,6 +229,17 @@ def default_registry() -> MetricsRegistry:
                    help="of exchange.steps, those whose pull and push ran "
                         "owner-routed (the ids fit their lanes); the rest "
                         "ran the gathered exchange"),
+        # The additive push that sums a step's repeated ids before it
+        # writes them (store.push, ``push.sum_runs``): counted on the
+        # device, riding the worker out channel's ``sum_runs`` entry.
+        MetricSpec("sum_runs.pushed_ids", "counter", unit="ids",
+                   labels=("table",),
+                   help="pushes the additive pushes on push.sum_runs were "
+                        "handed and kept (every shard together)"),
+        MetricSpec("sum_runs.live_ids", "counter", unit="ids",
+                   labels=("table",),
+                   help="of sum_runs.pushed_ids, the distinct ids a step: "
+                        "what the scatter into the table then pays for"),
         # Adaptive tiering (fps_tpu.tiering; docs/performance.md
         # "Adaptive tiering"): online hot-set re-ranking + auto-planner.
         MetricSpec("tiering.re_ranks", "counter", unit="re_ranks",

@@ -514,6 +514,131 @@ XLA_TRANSPOSED_TABLE_BYTES = 144 << 20
 XLA_TRANSPOSED_DIMS = (3, 4)
 ACC_RUNS_MIN_IDS_PER_ROW = 0.4
 
+# A table of the lane-packed route's WIDTHS with far more rows than that
+# route reaches, and when the store's ADDITIVE push (fps_tpu.core.store.push,
+# combine="sum", no apply_fn) sums a step's rows by id before its scatter
+# into the table itself ("push.sum_runs": one sort of (id, position), a
+# gather of the B rows into that order, and, where the batch is seen to
+# repeat itself, a segmented scan over the transposed rows, a second
+# 2-operand sort and the distinct ids' totals fetched a block at a time; the
+# scatter handed each distinct id once, sorted, the sentinel after: the
+# sorted route above then stops at the last of them). Compile-only, v5e
+# (``tests/test_v5e_compile.py`` guards the cell's): f32[R,16] as a donated
+# loop carry is ``{0,1:T(8,128)}``, TRANSPOSED IN HBM (no ``S(1)``), at
+# 1,048,576, 2,097,152, 4,194,304, 8,440,645 and 33,762,577 rows alike, by
+# the plain scatter and by the block loop; the ``[425984,16]`` payload and
+# every array of the sums are kept transposed too, and in VMEM
+# (``{0,1:T(8,128)S(1)}``), so the scan's 19 doubling passes are lane-dense
+# as they stand. In time (``tools/bench_scatter.py rows dlrm sums`` and
+# ``rows dlrm edge``, one v5 lite chip, builder's chip run, PR 49, call 207;
+# f32, width 16, 425,984 ids a step, the table a donated loop carry; us a
+# call). At ``dlrm-criteo.epochs``' own shape, [33762577,16], under its own
+# ids (26 fields, Zipf(1.05) within a field: 78,500 distinct, 18.4 %),
+# under ids UNIFORM over the rows (423,306 distinct, 99.4 %) and under a
+# half-and-half batch (thirteen fields the cell's, thirteen uniform:
+# 244,767 distinct, 57.5 %):
+#
+#                                  the cell's   half-and-half   uniform
+#   scatter-add alone, plain       42451        42956           42663
+#   push, plain                    42510        42968           42701
+#   push, push.sum_runs, every run a tree (the first form, NOT shipped)
+#                                  11798        29084           44428
+#   ... the sums ALWAYS formed                                  47791
+#   ... the sums NEVER formed      47134
+#   push, push.sum_runs as SHIPPED (long runs chained, below; call 224,
+#   where the plain push read 42975 / 43374 / 43038)
+#                                  17699        36460           44610
+#   ... the sums ALWAYS formed                                  56810
+#   ... the sums NEVER formed      49281
+#   the block loop alone on the distinct ids sorted, sentinel after
+#                                  8120                         41766
+#   the block loop alone on ALL the ids sorted, repeats adjacent
+#                                  44633                        41960
+#
+# The block loop on an HBM-resident transposed table costs 103 ns a LIVE id
+# (8120 / 78,500; 98.7 under uniform ids) and as much for an adjacent repeat
+# (104.8): what the plain op pays for every id handed (99.7), so the gain
+# is the share of the ids that the sums take away, 81.6 % on the cell. The
+# sums, alone, on the cell's ids (``sums_*``): every run a tree 3672 (one
+# sort of (id, position), the gather of 425,984 rows, the scan over [16, B],
+# the second sort, the totals fetched 77 blocks of 1,024 at a time), as
+# shipped 9468 (the long runs' chain, its cumulative maximum and sum and the
+# third sort: 5.8 ms, of it the VMEM scatter of 425,984 handed rows most); with
+# ALL B totals gathered by the second sort 5050; the rows transposed before
+# the first gather and gathered along the lanes both times 5048 (XLA makes
+# the same program of it); ``_sum_id_runs`` as the accumulator has it (two
+# sorts carrying all 16 columns and a count) compiles in 426 s for a
+# described v5e where the shipped form takes 17, and was not run. A batch
+# WITHOUT repeats cannot gain: with the sums always formed the push loses
+# 11.9 % to the plain one under uniform ids (32 % as shipped), so the batch
+# is LOOKED AT (the first sort gives the distinct count) and the sums are
+# formed only where at most SUM_RUNS_MAX_DISTINCT_SHARE of the pushed ids
+# are distinct; past it the sorted batch is handed on as it is and the push
+# loses 4.0 % (3.7 % as shipped: the sort and the gather, which the look
+# cannot avoid). The constant sits at the measured win nearest the loss
+# (57.5 % distinct: x1.48, x1.19 as shipped); (0.575, 0.994) is unmeasured.
+# Over the ROWS (the FIRST form, every run a tree; both predicates answering
+# yes whatever the rows; us a push, plain / push.sum_runs; Zipf(1.05) over
+# the rows, then uniform; under each pair the distinct ids a step):
+#
+#   R            Zipf(1.05)             uniform
+#   1,048,576    41898 / 9756  (x4.3)   42502 / 22119 (x1.9)
+#                94,935                 350,008 (82 %: not summed)
+#   4,194,304    43260 / 16526 (x2.6)   43325 / 48661 (-12 %)
+#                115,959                405,036
+#   8,440,645    43942 / 17339 (x2.5)   43277 / 46620 (-7.7 %)
+#                125,137                415,410
+#   33,762,577   (the cell, above: x3.6; shipped x2.4) (-4.0 %; -3.7 %)
+#
+# (8,440,645 rows: a shard of ``dlrm-criteo``'s table on four.) AS SHIPPED
+# (call 225, the same sweep, plain / push.sum_runs):
+#
+#   1,048,576    41919 / 19129 (x2.19)  42518 / 46461 (-9.3 %)
+#   4,194,304    43302 / 22774 (x1.90)  43327 / 48752 (-12.5 %)
+#   8,440,645    43969 / 23957 (x1.84)  43296 / 46713 (-7.9 %)
+#   33,762,577   (the cell: x2.43)                    (-3.7 %)
+#
+# Under skew the route wins every measured point, x1.84 - x2.43 as shipped
+# (x2.5 - x4.3 with every run a tree); without repeats it loses 3.7 -
+# 12.5 %, the sort, the gather and a block loop that is up to 4 % dearer
+# on a sorted batch than the plain op on an unsorted one (1.7 % cheaper at
+# 33.8 M rows; at 1,048,576 rows the first form read a WIN without
+# repeats, 22119, which the shipped form does not repeat: not explained).
+# XLA_TRANSPOSED_HBM_ROWS is the fewest rows measured; between
+# the lane-packed route's reach (about 0.5 M rows at width 16) and it
+# nothing is measured and the plain routes stay. The width swept is 16;
+# 8 and 32 (``_xla_packable``'s ends) compile to the same layout at
+# 4,194,304 rows and past 16 M, one to four groups of eight sublanes a row,
+# and the widths between are taken with it unswept, as width 4 was with 3
+# above. float32 alone: the sums are formed in the table's dtype.
+#
+# HOW a run's rows meet is part of the answer. The plain scatter adds an
+# id's rows to the table's row ONE BY ONE, each addend rounded at the ROW's
+# magnitude, and so does any plain float32 reference; a tree over the
+# addends alone and one add to the row is nearer the exact sum, and differs
+# from the chain by the chain's own rounding, which on a row that takes
+# thousands of small addends a step is large against the row's net change:
+# with every run summed in a tree (the readings above are that form's)
+# ``dlrm-criteo.epochs`` read 548,300 examples/s and its comparison refused
+# 2 runs of 8 (``update_gap`` of the 3-row field 1.17e-2 and 3.3e-3, of the
+# 4-row field 3.1e-3, where 2.5e-3 is the limit and the plain push reads
+# 5e-5; by field the gap fell with the rows: 1.8e-3 at 18 rows, 4.5e-4 at
+# 105, level with the large fields from 305; builder's chip run, PR 49,
+# call 208). So a LONG run, of more than SUM_RUNS_TREE_MAX_RUN rows, is
+# CHAINED as the plain scatter chains it: its rows are scatter-added, in
+# the batch's order (the first sort's second key is the position), into a
+# copy of the table's rows in a ``[B / 33, dim]`` buffer that XLA keeps in
+# VMEM (its small-table scatter, a few ns a row handed: the emitter a
+# reference's own small tables take), and what the buffer's row changed by
+# is the run's one row to add; on the CPU the chained rows equal the plain
+# scatter's bit for bit (``tests/test_store.py``). On the cell 1,173 rows a
+# step are long and take 274,249 of its 425,984 pushes; a run of up to 32
+# rows stays a tree (about one ulp of the row a step apart from its chain).
+# The constant is the one value run; others were not.
+XLA_TRANSPOSED_HBM_ROWS = 1_048_576
+SUM_RUNS_MAX_DISTINCT_SHARE = 0.6
+SUM_RUNS_TREE_MAX_RUN = 32
+
 
 def _bf16_pair_ok(dtype) -> bool:
     """The dim-1 kernels carry values as bf16 hi+lo: f64 would silently
@@ -575,23 +700,36 @@ def _xla_transposed(R: int, D: int, dtype) -> bool:
             and _tiled_table_bytes(R, D, dtype) > XLA_TRANSPOSED_TABLE_BYTES)
 
 
+def _xla_transposed_hbm(R: int, D: int, dtype) -> bool:
+    """A table of the lane-packed route's widths (:func:`_xla_packable`)
+    with so many rows that XLA keeps it TRANSPOSED IN HBM, far past that
+    route's reach and past XLA's VMEM regime
+    (:data:`XLA_TRANSPOSED_HBM_ROWS`, the fewest rows measured): there the
+    plain scatter-add pays about 100 ns for every id it is handed, a
+    repeat or dropped by the sentinel alike."""
+    return _xla_packable(D, dtype) and R >= XLA_TRANSPOSED_HBM_ROWS
+
+
 def _route_xla_sorted(R: int, D: int, B: int, dtype,
                       ids_sorted: bool) -> bool:
     """Scatter by blocks and stop where the dropped ids begin? Only on the
     caller's guarantee, on the TPU (backend not ``"xla"``), for more ids
     than one block, where the plain op pays for every id it is handed,
-    dropped or not. Two such regimes are measured: rows WIDER than the
+    dropped or not. Three such regimes are measured: rows WIDER than the
     lane-packed route's (:data:`XLA_PACKED_DIMS`) in a table whose tiled
     form is past XLA's VMEM regime (:data:`XLA_VMEM_TABLE_BYTES`), under
-    few enough ids against the rows (:data:`XLA_SORTED_ROWS_PER_ID`); and
+    few enough ids against the rows (:data:`XLA_SORTED_ROWS_PER_ID`);
     rows NARROWER than them in a table XLA keeps transposed
-    (:func:`_xla_transposed`), whatever the ids."""
+    (:func:`_xla_transposed`), whatever the ids; and rows of that route's
+    own widths in a table it cannot reach, which XLA keeps transposed in
+    HBM (:func:`_xla_transposed_hbm`), whatever the ids."""
     use, interpret = _use_pallas()
     if not (ids_sorted and use and not interpret
             and jnp.dtype(dtype).itemsize <= 4
             and B > XLA_SORTED_BLOCK_IDS):
         return False
-    return _xla_transposed(R, D, dtype) or (
+    return _xla_transposed(R, D, dtype) or _xla_transposed_hbm(
+        R, D, dtype) or (
         D > XLA_PACKED_DIMS[1]
         and _tiled_table_bytes(R, D, dtype) > XLA_VMEM_TABLE_BYTES
         and B <= R // XLA_SORTED_ROWS_PER_ID)

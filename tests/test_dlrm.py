@@ -367,3 +367,60 @@ def test_worker_step_output_keeps_its_old_shape(devices8):
     ``StepOutput(pushes, local_state, out)`` stands."""
     out = StepOutput(pushes={}, local_state=(), out={})
     assert out.dense_grads is None
+
+
+@pytest.mark.parametrize("kind", sorted(MESHES))
+def test_sum_runs_counts_arrive_with_a_call_nobody_fetches(devices8,
+                                                           monkeypatch, kind):
+    """With ``push.sum_runs`` engaged (its regime's constants patched so
+    that the tiny key space takes it, the ops layer routing as on the
+    chip), the runner's own call, which
+    fetches nothing, still matches the reference, and what the pushes
+    counted reaches the ``device.run_indexed`` span's ``sum_runs`` field
+    and the recorder's ``sum_runs.*`` counters when the call's deferred
+    metrics arrive: every step's pushes (6 fields a row, every worker
+    together, counted once whatever the mesh) and the distinct ids among
+    them, plain per-step leaves of the metrics beside the worker's own."""
+    from fps_tpu import obs
+    from fps_tpu.obs import events
+
+    monkeypatch.setattr(ops, "_use_pallas", lambda: (True, False))
+    monkeypatch.setattr(ops, "XLA_TRANSPOSED_HBM_ROWS", 1_000)
+    monkeypatch.setattr(ops, "XLA_SORTED_BLOCK_IDS", 16)
+    monkeypatch.setattr(ops, "DENSE_TABLE_BYTES", 0)   # a LARGE table's way
+    cfg, system, init, data_sum = build(kind, monkeypatch)
+    sink = obs.MemorySink()
+    rec = obs.Recorder(sinks=[sink])
+    events.set_default_recorder(rec)
+    ops.clear_routes()
+    try:
+        state, warm = window.queue_call(system, system.place(init))
+        warm.wait()
+    finally:
+        events.set_default_recorder(None)   # waits for the span
+    rec.flush()
+    shards, replicas = MESHES[kind]
+    pushes = [(r.route, r.dim) for r in ops.routes_traced()
+              if r.route == "push.sum_runs"]
+    # once a traced step program; on lanes once a branch of the certificate
+    assert pushes and set(pushes) == {("push.sum_runs", 8)}
+    numbers, _ = check.compare_call(system, cfg, init,
+                                    system.export(*state), warm.host,
+                                    data_sum)
+    assert numbers["examples"] == 0 and numbers["feed"] == 0
+    assert max(v for k, v in numbers.items()
+               if k.startswith(("table_gap", "update_gap", "loss_gap"))
+               ) < F32_GAP
+    (span,) = [e for e in sink.events("span")
+               if e["span"] == "device.run_indexed"]
+    (m,) = warm.host
+    pushed = np.asarray(m[f"sum_runs.{EMB_TABLE}.pushed_ids"], np.float64)
+    live = np.asarray(m[f"sum_runs.{EMB_TABLE}.live_ids"], np.float64)
+    n = np.asarray(m["n"], np.float64)
+    np.testing.assert_array_equal(pushed, len(CARDS) * n)
+    assert (live <= pushed).all() and (live[n > 0] > 0).all()
+    assert live.sum() < 0.9 * pushed.sum()   # the fields of 3 and 17 rows
+    assert span["sum_runs"] == {EMB_TABLE: {
+        "pushed_ids": pushed.sum(), "live_ids": live.sum()}}
+    for k, v in span["sum_runs"][EMB_TABLE].items():
+        assert rec.counter_value(f"sum_runs.{k}", table=EMB_TABLE) == v

@@ -472,6 +472,208 @@ def test_push_combine_mean_float64_precision(devices8, R, route):
         assert got2[phys, 0] == pytest.approx(-1.0e39, rel=1e-12)
 
 
+_SUM_RUNS_CASES = {
+    # name -> (ids of the 96 a worker drawn how, the sorted route's block
+    # while the push is traced, the table's dtype, does the route engage)
+    "repeats": ("skewed", 32, np.float32, True),
+    "no_repeats": ("distinct", 32, np.float32, True),
+    "few_repeats": ("mostly_distinct", 32, np.float32, True),
+    "one_id": ("one", 32, np.float32, True),
+    "long_runs": ("two_hot", 32, np.float32, True),
+    "padding_mixed_in": ("skewed_padded", 32, np.float32, True),
+    "all_dropped": ("dropped", 32, np.float32, True),
+    "ragged_last_block": ("skewed_padded", 80, np.float32, True),
+    "under_one_block": ("skewed", 1_024, np.float32, False),
+    "bf16_table": ("skewed", 32, jnp.bfloat16, False),
+    "f64_table": ("skewed", 32, np.float64, False),
+}
+
+
+def _sum_runs_ids(how, n, num_ids, rng):
+    if how == "distinct":
+        return rng.choice(num_ids, n, replace=False)
+    if how == "mostly_distinct":  # 3 in 4 distinct: past the share
+        ids = rng.choice(num_ids, n, replace=False)
+        ids[::4] = ids[1::4]
+        return ids
+    if how == "one":
+        return np.full(n, 77)
+    if how == "two_hot":  # two ids take 40 pushes a worker each
+        ids = rng.integers(0, num_ids, n)
+        ids[rng.permutation(n)[:n * 5 // 6]] = np.tile([5, 1_234], n)[
+            :n * 5 // 6]
+        return ids
+    if how == "dropped":
+        return np.full(n, -1)
+    hot = rng.integers(0, num_ids, 12)
+    ids = np.where(rng.random(n) < 0.7, hot[rng.integers(0, 12, n)],
+                   rng.integers(0, num_ids, n))
+    if how == "skewed_padded":
+        ids[rng.random(n) < 0.125] = -1
+    return ids
+
+
+@pytest.mark.parametrize("values", ["integers", "floats"])
+@pytest.mark.parametrize("case", list(_SUM_RUNS_CASES))
+@pytest.mark.parametrize("S", [1, 4])
+def test_push_sum_runs_equals_the_plain_scatter(devices8, monkeypatch, S,
+                                                case, values):
+    """The additive push through ``push.sum_runs`` (a step's rows summed
+    by id, the scatter into the shard handed each distinct id once,
+    sorted, the dropped last; the route's constants patched so that a
+    tiny shard engages it, the ops layer routing as on the chip: the
+    scatter is the sorted route's block loop) is
+    ``table.at[ids].add(deltas)``: exactly on
+    integer-valued rows, to float32 rounding otherwise; on one shard and
+    on what the gathered exchange hands each of four; whatever the
+    batch's repeats, padding and length against the block; a long run
+    chained as the plain scatter chains it, bit for bit; and what it
+    counts (``watch_sum_runs``) is the pushes kept and the distinct ids.
+    A batch under one block, a bf16 and an f64 table stay on the plain
+    scatter."""
+    how, block, dtype, engages = _SUM_RUNS_CASES[case]
+    R, dim, n = 3_000 * S, 16, 96 * S
+    rng = np.random.default_rng(49)
+    ids = _sum_runs_ids(how, n, R, rng).astype(np.int32)
+    if values == "integers":
+        deltas = rng.integers(-3, 4, (n, dim)).astype(np.float32)
+        table = rng.integers(-50, 50, (R, dim)).astype(np.float32)
+    else:
+        deltas = rng.normal(0, 1, (n, dim)).astype(np.float32)
+        table = rng.normal(0, 1, (R, dim)).astype(np.float32)
+        if how in ("one", "two_hot"):
+            # Small steps on rows away from zero, as training's are: what a
+            # long run changes its row by is then an exact difference.
+            deltas, table = deltas * 1e-3, 1 + np.abs(table)
+    rps, seen, plain_scatter = rows_per_shard(R, S), [], ops.scatter_add
+
+    def spy(t, i, d, **kw):
+        jax.debug.callback(lambda i: seen.append(np.asarray(i)), i)
+        assert kw == {"ids_sorted": True}
+        return plain_scatter(t, i, d, **kw)
+
+    def counted_push(t, i, d):
+        with store_mod.watch_sum_runs() as noted:
+            out = push(t, i, d, num_shards=S, data_axis=None, table="emb")
+        counts = noted.get("emb", {"pushed_ids": 0, "live_ids": 0})
+        assert set(noted) <= {"emb"}
+        return out, jnp.stack([jnp.asarray(counts[k], jnp.int32) for k in (
+            "pushed_ids", "live_ids")])[None]
+
+    monkeypatch.setattr(ops, "_use_pallas", lambda: (True, False))
+    monkeypatch.setattr(ops, "XLA_TRANSPOSED_HBM_ROWS", 1_000)
+    monkeypatch.setattr(ops, "XLA_SORTED_BLOCK_IDS", block)
+    monkeypatch.setattr(store_mod, "_routes_to_owner", lambda *a: False)
+    if engages:
+        monkeypatch.setattr(ops, "scatter_add", spy)
+    mesh = make_ps_mesh(num_shards=S, devices=devices8[:S])
+    f = jax.jit(jax.shard_map(
+        counted_push, mesh=mesh,
+        in_specs=(P(SHARD_AXIS, None), P(SHARD_AXIS), P(SHARD_AXIS, None)),
+        out_specs=(P(SHARD_AXIS, None), P(SHARD_AXIS, None)),
+        check_vma=False))
+    ops.clear_routes()
+    x64 = jax.config.jax_enable_x64
+    jax.config.update("jax_enable_x64", dtype is np.float64)
+    try:
+        got, counts = f(
+            jax.device_put(jnp.asarray(table, dtype),
+                           NamedSharding(mesh, P(SHARD_AXIS, None))),
+            jnp.asarray(ids), jnp.asarray(deltas))
+        jax.effects_barrier()
+        assert got.dtype == dtype
+    finally:
+        jax.config.update("jax_enable_x64", x64)
+    log = [(r.route, r.rows, r.dim, r.ids) for r in ops.routes_traced()]
+    live = np.unique(ids[ids >= 0])
+    if not engages:
+        assert log == [("scatter_add.xla", rps, dim, n)]
+        assert not np.asarray(counts).any()
+    else:
+        assert log == [("push.sum_runs", rps, dim, n),
+                       ("scatter_add.xla_sorted", rps, dim, n)]
+        assert ops.routes_traced()[0].reason == "xla_transposed_hbm"
+        # What the scatter was handed on each shard: non-decreasing, none
+        # negative, everything to drop last; where the batch repeats
+        # itself, each of the shard's distinct ids ONCE.
+        assert len(seen) == S
+        for i in seen:
+            assert i.shape == (n,) and i.min() >= 0
+            assert (np.diff(i) >= 0).all()
+            if how != "mostly_distinct":
+                assert (np.diff(i[i < rps]) > 0).all()
+        handed = sum((i < rps).sum() for i in seen)
+        assert handed == ((ids >= 0).sum() if how == "mostly_distinct"
+                          else len(live))
+        assert np.asarray(counts).sum(axis=0).tolist() == [
+            (ids >= 0).sum(), len(live)]
+    want = table.astype(np.float64)
+    phys = np.asarray(id_to_phys(ids[ids >= 0], S, rps))
+    np.add.at(want, phys, deltas[ids >= 0].astype(np.float64))
+    got = np.asarray(got.astype(jnp.float32) if dtype is jnp.bfloat16
+                     else got)
+    touched = np.zeros(R, bool)
+    touched[phys] = True
+    stored = (np.asarray(jnp.asarray(table, dtype).astype(jnp.float32))
+              if dtype is jnp.bfloat16 else table)
+    np.testing.assert_array_equal(got[~touched], stored[~touched])
+    if engages and values == "floats":
+        # A LONG run (over ops.SUM_RUNS_TREE_MAX_RUN rows) is chained at
+        # its row's value in the batch's order, the plain scatter's own
+        # float32 arithmetic, bit for bit; a short one is a tree's sum.
+        chain = table.copy()
+        for row, delta in zip(phys, deltas[ids >= 0]):
+            chain[row] += delta
+        rows, counts = np.unique(phys, return_counts=True)
+        long = rows[counts > ops.SUM_RUNS_TREE_MAX_RUN]
+        assert len(long) == {"one": 1, "two_hot": 2}.get(how, 0)
+        np.testing.assert_array_equal(got[long], chain[long])
+    if values == "integers" and dtype is not jnp.bfloat16:
+        np.testing.assert_array_equal(got, want)
+    elif dtype is jnp.bfloat16:
+        np.testing.assert_allclose(got, want, rtol=0.05,
+                                   atol=0.05 * np.abs(want).max())
+    else:
+        np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-6)
+
+
+@pytest.mark.parametrize("cell,rps,dim,num_ids,dtype,engages", [
+    ("mf-netflix.epochs", 17_770, 10, 32_768, jnp.float32, False),
+    ("pa-rcv1.epochs", 47_236, 1, 1_048_576, jnp.float32, False),
+    ("mf-netflix.x4", 4_443, 10, 131_072, jnp.float32, False),
+    ("w2v-1bw.epochs", 1_115_011, 300, 49_182, jnp.float32, False),
+    ("lr-criteo.epochs", 1_000_000, 2, 425_997, jnp.float32, False),
+    ("ials-ml20m.sweeps", 138_493, 4_096, 138_493, jnp.float32, False),
+    ("mf-netflix-topk.epochs", 17_770, 10, 32_768, jnp.float32, False),
+    ("w2v-1bw-hot.x4", 278_753, 300, 61_504, jnp.float32, False),
+    ("dlrm-criteo.epochs", 33_762_577, 16, 425_984, jnp.float32, True),
+    # The same table narrower or wider than float32 (the scatter adds in
+    # the table's dtype), under one block of ids, as a shard of four (what
+    # the queued ``dlrm-criteo.x4``'s lanes hand it), and with half a
+    # million rows (between the lane-packed route's reach and the fewest
+    # rows measured: it stays out).
+    ("dlrm bf16", 33_762_577, 16, 425_984, jnp.bfloat16, False),
+    ("dlrm f64", 33_762_577, 16, 425_984, jnp.float64, False),
+    ("dlrm one block", 33_762_577, 16, 1_024, jnp.float32, False),
+    ("dlrm-criteo.x4", 8_440_645, 16, 4 * 133_120, jnp.float32, True),
+    ("unmeasured band", 786_432, 16, 425_984, jnp.float32, False),
+])
+def test_sum_runs_route_from_shapes_alone(monkeypatch, cell, rps, dim,
+                                          num_ids, dtype, engages):
+    """``push.sum_runs`` engages by ``push``'s own shapes, with the sorted
+    scatter's third regime and nowhere without it: of the nine cells'
+    pushed tables only ``dlrm-criteo.epochs``' (narrow float32 rows, so
+    many that XLA keeps the table transposed in HBM, under more ids than
+    a block); off the TPU, or under the ``"xla"`` backend, nothing."""
+    from fps_tpu.core.store import _sum_runs_route
+
+    assert not _sum_runs_route(rps, dim, num_ids, dtype)  # the CPU's routing
+    monkeypatch.setattr(ops, "_use_pallas", lambda: (True, False))
+    assert _sum_runs_route(rps, dim, num_ids, dtype) is engages
+    assert ops._route_xla_sorted(rps, dim, num_ids, dtype, True) is (
+        engages or cell in ("w2v-1bw.epochs", "dlrm bf16"))
+
+
 @pytest.mark.parametrize("idx", [
     [7], [3, 3, 3, 3], [5, 1, 4, 2, 3], [9, 0, 9, 9, 0, 4, 9, 100, 100],
     list(np.random.default_rng(3).integers(0, 40, 1000)),
@@ -1303,6 +1505,10 @@ _ROUTED_PUSHES = {
     "acc_runs": (dict(apply_fn=lambda cur, d: 0.5 * cur + d),
                  (2_400_000, 2), "push.fold",
                  lambda cur, rows: 0.5 * cur + rows.sum(axis=0)),
+    # The additive push's rows summed by id first (its regime's constants
+    # patched to engage it): the shard stays out of the conditional.
+    "sum_runs": (dict(), (12_000, 16), "push.sum_runs",
+                 lambda cur, rows: cur + rows.sum(axis=0)),
 }
 
 
@@ -1321,6 +1527,10 @@ def test_routed_push_equals_the_gathered_push_and_the_oracle(devices8,
     B = 96
     if kind == "acc_runs":
         monkeypatch.setattr(ops, "ACC_RUNS_MIN_IDS_PER_ROW", 0.0)
+    if kind == "sum_runs":
+        monkeypatch.setattr(ops, "_use_pallas", lambda: (True, False))
+        monkeypatch.setattr(ops, "XLA_TRANSPOSED_HBM_ROWS", 1_000)
+        monkeypatch.setattr(ops, "XLA_SORTED_BLOCK_IDS", 64)
     table, ids, _, deltas = _routed_case(S, num_ids, dim, B)
     got, flag, log, _ = _exchange_on(devices8, S, _pushes(S, **kw), table,
                                      ids, deltas)

@@ -993,3 +993,70 @@ def test_dlrm_table_is_read_and_written_in_place_transposed(one_chip,
     memory = c.memory_analysis()
     assert memory.temp_size_in_bytes < 256 << 20        # the table: 2.16 GB
     assert memory.alias_size_in_bytes >= R * D * 4      # donated, in place
+
+
+def test_dlrm_push_sums_its_runs_and_scatters_by_blocks_in_place(
+        topo, monkeypatch):
+    """The same table through ``store.push`` (the additive sum; PR 49):
+    the routes are ``push.sum_runs`` and ``scatter_add.xla_sorted``; the
+    table is ``{0,1:T(8,128)}`` everywhere, written in place by ONE
+    scatter fusion inside the block loop, an operand of no conditional
+    (the look at the batch chooses between payload-sized arrays), never
+    copied or transposed; the sums' temporaries are the payload's (three
+    sorts of ids and positions alone, none that carries the rows' 16
+    columns: 73 s of compile where ``_sum_id_runs``' form takes 426; the
+    long runs chained in a ``[12909,16]`` buffer in VMEM), and the alias
+    is the table's size."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from fps_tpu.core import store
+    from fps_tpu.parallel.mesh import DATA_AXIS, SHARD_AXIS
+
+    monkeypatch.setattr(ops, "_use_pallas", lambda: (True, False))
+    R, D, B = 33_762_577, 16, 425_984
+    mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1),
+                (DATA_AXIS, SHARD_AXIS))
+
+    def steps(t, ids, deltas):
+        def body(t, x):
+            return jax.shard_map(
+                lambda t, i, d: store.push(t, i, d, num_shards=1,
+                                           data_axis=None, table="emb"),
+                mesh=mesh, in_specs=(P(SHARD_AXIS, None), P(), P()),
+                out_specs=P(SHARD_AXIS, None), check_vma=False)(t, *x), None
+        return lax.scan(body, t, (ids, deltas))[0]
+
+    ops.clear_routes()
+    args = [jax.ShapeDtypeStruct(s, d, sharding=NamedSharding(mesh, p))
+            for s, d, p in (((R, D), jnp.float32, P(SHARD_AXIS, None)),
+                            ((2, B), jnp.int32, P()),
+                            ((2, B, D), jnp.float32, P()))]
+    c = jax.jit(steps, donate_argnums=0).lower(*args).compile()
+    assert [(r.route, r.rows, r.dim, r.ids, r.reason)
+            for r in ops.routes_traced()] == [
+        ("push.sum_runs", R, D, B, "xla_transposed_hbm"),
+        ("scatter_add.xla_sorted", R, D, B, "")]
+    text = c.as_text()
+    table = f"f32[{R},{D}]"
+    layouts = set(re.findall(re.escape(table) + r"(\{[^}]*\})", text))
+    assert layouts == {"{0,1:T(8,128)}"}, layouts
+    (scatter,) = [ln for ln in _top_level(text)
+                  if re.search(r"= " + re.escape(table) + r"\S* fusion\(", ln)]
+    assert ("/fps.ops/scatter_add.xla_sorted/while/body/" in scatter
+            and "kind=kCustom" in scatter)
+    assert not re.search(
+        r"= " + re.escape(table) + r"\S* (copy|transpose)\(", text)
+    conditionals = [ln for ln in text.splitlines() if " conditional(" in ln]
+    assert conditionals and not [ln for ln in conditionals if table in ln]
+    # The three sorts carry ids and positions, nothing of the rows.
+    sorts = [ln for ln in text.splitlines() if " sort(" in ln]
+    assert len(sorts) == 3 and all(
+        re.search(r"= \(?s32\[\d+\]\S*(, s32\[\d+\]\S*\))? sort\(", ln)
+        for ln in sorts), sorts
+    # The long runs are chained by XLA's own scatter in its VMEM regime.
+    assert re.search(r"f32\[12909,16\]\{1,0:T\(8,128\)S\(1\)\} scatter\(",
+                     text)
+    memory = c.memory_analysis()
+    payload = B * D * 4
+    assert memory.temp_size_in_bytes < (256 << 20) + 4 * payload
+    assert memory.alias_size_in_bytes >= R * D * 4      # donated, in place

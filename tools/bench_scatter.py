@@ -16,7 +16,13 @@ over table rows x row width at uniform ids — the sweep that set
 within a field), the table a loop carry in the one form XLA keeps it in
 (transposed, 2.16 GB), beside a RESIDENT lane-packed form of the same
 rows (``[4220323, 128]``, eight rows a packed row, never relaid out: what
-a store that owned the layout would carry).
+a store that owned the layout would carry). ``rows dlrm sums`` (PR 49): the
+additive push that sums a step's repeated ids first (``push.sum_runs``) at
+that shape: the ways to form the sums at width 16, the block loop alone
+on sorted ids, and the whole push plain against summed under the cell's
+ids, uniform ids and a half-and-half batch; ``rows dlrm edge``: the push
+plain against summed over the table's rows, the sweep
+``ops.XLA_TRANSPOSED_HBM_ROWS`` stands on.
 
 ``mean`` arm: the store's per-id mean push (``fps_tpu.core.store.push``,
 ``combine="mean"``) by its accumulator branch against its row branch over
@@ -195,20 +201,8 @@ def dlrm_rows():
     of them would be 3.5 GB in 128-lane tiles."""
     import json
 
-    from perfbench.datasets.criteo_rows import zipf_tokens
-
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(root, "perfbench/configs/dlrm-criteo.json")) as fh:
-        d = json.load(fh)["data"]
-    cards = d["categorical_cardinalities"]
     R, D, B, steps = DLRM_R, DLRM_D, DLRM_B, DLRM_T
-    assert sum(cards) == R and B == 16_384 * len(cards)
-    offsets = jnp.asarray(np.concatenate([[0], np.cumsum(cards)[:-1]]),
-                          jnp.int32)
-    u = jax.random.uniform(jax.random.key(48), (steps, B // len(cards),
-                                                len(cards)))
-    ids = (zipf_tokens(u, cards, d["token_zipf"])
-           + offsets).reshape(steps, B)
+    ids = jnp.asarray(_dlrm_ids("cell"))
     distinct = int(np.mean([len(np.unique(r)) for r in np.asarray(ids)]))
     pack, Rp = 128 // D, -(-R // (128 // D))
 
@@ -274,15 +268,270 @@ def dlrm_rows():
             out, device=jax.devices()[0].device_kind)) + "\n")
 
 
+def _dlrm_ids(kind, steps=None, R=DLRM_R, B=DLRM_B):
+    """``(steps, B)`` ids a step: ``cell`` (``dlrm-criteo.epochs``' own:
+    26 fields, Zipf(1.05) within a field), ``uniform`` over the ``R`` rows,
+    ``half`` (the cell's in thirteen fields, uniform in the others) or
+    ``zipf`` (Zipf(1.05) over ``R`` rows, for a table of other rows)."""
+    import json
+
+    from perfbench.datasets.criteo_rows import zipf_tokens
+
+    steps = steps or DLRM_T
+    rng = np.random.default_rng(R * 131 + B)
+    if kind == "zipf":
+        return _zipf_ids(rng, R, (steps, B), alpha=1.05)
+    uniform = rng.integers(0, R, (steps, B)).astype(np.int32)
+    if kind == "uniform":
+        return uniform
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "perfbench/configs/dlrm-criteo.json")) as fh:
+        d = json.load(fh)["data"]
+    cards = d["categorical_cardinalities"]
+    assert sum(cards) == R and B == 16_384 * len(cards)
+    offsets = np.concatenate([[0], np.cumsum(cards)[:-1]]).astype(np.int32)
+    u = jax.random.uniform(jax.random.key(48), (steps, B // len(cards),
+                                                len(cards)))
+    cell = np.asarray(zipf_tokens(u, cards, d["token_zipf"])) + offsets
+    if kind == "half":
+        cell[:, :, 1::2] = uniform.reshape(cell.shape)[:, :, 1::2]
+    return cell.reshape(steps, B).astype(np.int32)
+
+
+def _dlrm_deltas(i, D=DLRM_D):
+    """A step's pushed rows made from its ids on the device (no ``[T, B,
+    16]`` operand), the columns different multiples of a small number."""
+    g = ((i % 97).astype(jnp.float32) - 48.0) * 1e-6
+    return g[:, None] * (1.0 + jnp.arange(D, dtype=jnp.float32))[None, :]
+
+
+def _dlrm_timer(R, ids, D=DLRM_D):
+    """``us_a_step(op)`` of ``op(table [R, D], ids [B]) -> table`` scanned
+    over the steps of ``ids``, the table a DONATED loop carry (one
+    resident copy: 2.16 GB at the cell's rows), the best of two timed
+    calls after the one that compiles; and that compile's seconds."""
+    steps = ids.shape[0]
+    ids = jnp.asarray(ids)
+
+    def us_a_step(op):
+        tab = jax.jit(lambda k: 0.01 * jax.random.normal(k, (R, D)))(
+            jax.random.key(1))
+        f = jax.jit(lambda t, ids: lax.scan(
+            lambda t, i: (op(t, i), None), t, ids)[0], donate_argnums=0)
+        took = []
+        for _ in range(3):  # the first call compiles
+            t0 = time.perf_counter()
+            tab = f(tab, ids)
+            np.asarray(tab[0, 0])
+            took.append(time.perf_counter() - t0)
+        del tab
+        best = min(took[1:])
+        return round(best / steps * 1e6, 1), round(took[0] - best, 1)
+    return us_a_step
+
+
+def _sum_forms():
+    """Ways to form ``push.sum_runs``' sums at width 16, ``(table, idx,
+    rows, drop) -> (ids, sums)``: ``a`` :func:`store._sum_id_runs` as it
+    is (two sorts carrying all 16 columns and a count: 426 s of compile
+    for a described v5e, so it runs only when named); ``b_full`` one sort
+    of ``(id, position)``, a gather of the rows into that order, the run
+    sums over the transposed rows, a second sort of ``(id or sentinel,
+    position)`` and ALL ``B`` totals gathered by it; ``b_blocks`` the
+    same with the totals gathered a block of 1,024 at a time, live blocks
+    only; ``c`` the rows transposed BEFORE the first gather and gathered
+    along the lanes both times; ``shipped`` what ``store.push`` runs
+    (``b_blocks`` with the long runs chained at their rows' values, the
+    batch taken as repeating)."""
+    import fps_tpu.ops as ops
+    from fps_tpu.core import store
+
+    def two_sorts(idx, drop):
+        pos = jnp.arange(idx.shape[0], dtype=jnp.int32)
+        s, order = lax.sort((idx, pos), num_keys=1, is_stable=False)
+        first, last = store._run_ends(s)
+        ids, at = lax.sort((jnp.where(last & (s < drop), s, drop), pos),
+                           num_keys=1, is_stable=False)
+        return first, order, ids, at
+
+    def a(t, idx, rows, drop):
+        ids, sums = store._sum_id_runs(idx, rows, drop)
+        return ids, sums[:, :-1]
+
+    def b_full(t, idx, rows, drop):
+        first, order, ids, at = two_sorts(idx, drop)
+        (tot,) = store._run_sums(first, (jnp.take(rows, order, axis=0).T,))
+        return ids, jnp.take(tot.T, at, axis=0)
+
+    def b_blocks(t, idx, rows, drop):
+        B, C = idx.shape[0], ops.XLA_SORTED_BLOCK_IDS
+        first, order, ids, at = two_sorts(idx, drop)
+        (tot,) = store._run_sums(first, (jnp.take(rows, order, axis=0).T,))
+        tot = tot.T
+
+        def fetch(c, sums):
+            start = jnp.minimum(c * C, B - C)
+            block = jnp.take(tot, lax.dynamic_slice(at, (start,), (C,)),
+                             axis=0)
+            return lax.dynamic_update_slice(sums, block, (start, 0))
+
+        live = jnp.sum((ids < drop).astype(jnp.int32))
+        return ids, lax.fori_loop(0, (live + C - 1) // C, fetch,
+                                  jnp.zeros_like(rows))
+
+    def c(t, idx, rows, drop):
+        first, order, ids, at = two_sorts(idx, drop)
+        (tot,) = store._run_sums(first, (jnp.take(rows.T, order, axis=1),))
+        return ids, jnp.take(tot, at, axis=1).T
+
+    def shipped(t, idx, rows, drop):
+        keep = ops.SUM_RUNS_MAX_DISTINCT_SHARE
+        ops.SUM_RUNS_MAX_DISTINCT_SHARE = 2.0  # summed whatever the batch
+        try:
+            *runs, long_ids, pushed, live = store._sorted_runs(idx, rows,
+                                                               drop)
+            return store._summed_runs(
+                *runs, jnp.take(t, jnp.minimum(long_ids, drop - 1), axis=0),
+                pushed, live, drop)
+        finally:
+            ops.SUM_RUNS_MAX_DISTINCT_SHARE = keep
+
+    return {"a": a, "b_full": b_full, "b_blocks": b_blocks, "c": c,
+            "shipped": shipped}
+
+
+def _store_sum_push(route, share=None, R=DLRM_R, D=DLRM_D):
+    """``store.push`` of the additive sum on a one-shard mesh with
+    ``store._sum_runs_route`` answering ``route`` whatever the shape while
+    it is traced (and ``ops._route_xla_sorted``'s third regime with it),
+    and ``ops.SUM_RUNS_MAX_DISTINCT_SHARE`` at ``share`` where given (2:
+    the sums always formed; -1: never, the sorted batch handed on)."""
+    from jax.sharding import PartitionSpec as P
+
+    import fps_tpu.ops as ops
+    from fps_tpu.parallel.mesh import SHARD_AXIS, make_ps_mesh
+    from fps_tpu.core import store
+
+    mesh = make_ps_mesh(num_shards=1, devices=jax.devices()[:1])
+
+    def op(t, i):
+        keep = ops.XLA_TRANSPOSED_HBM_ROWS, ops.SUM_RUNS_MAX_DISTINCT_SHARE
+        ops.XLA_TRANSPOSED_HBM_ROWS = 0 if route else 1 << 62
+        if share is not None:
+            ops.SUM_RUNS_MAX_DISTINCT_SHARE = share
+        try:
+            return jax.shard_map(
+                lambda t, i: store.push(t, i, _dlrm_deltas(i, D),
+                                        num_shards=1, data_axis=None),
+                mesh=mesh, in_specs=(P(SHARD_AXIS, None), P()),
+                out_specs=P(SHARD_AXIS, None), check_vma=False)(t, i)
+        finally:
+            (ops.XLA_TRANSPOSED_HBM_ROWS,
+             ops.SUM_RUNS_MAX_DISTINCT_SHARE) = keep
+    return op
+
+
+def dlrm_sums(forms=("b_full", "b_blocks", "c", "shipped")):
+    """``push.sum_runs`` at ``dlrm-criteo.epochs``' shape, us a call: the
+    plain scatter-add and the whole additive push, plain against shipped,
+    under the cell's ids, uniform ids and the half-and-half batch (under
+    each the distinct ids a step); the push with the sums always formed
+    under uniform ids and never under the cell's (what the look at the
+    batch is worth both ways); the block loop of ``scatter_add.xla_sorted``
+    ALONE on a step's distinct ids sorted, the sentinel after (the cell's:
+    78,500 live; uniform: 423,300), and on all of a step's ids sorted,
+    repeats and all; and each way to form the sums, no scatter
+    (:func:`_sum_forms`; ``rows dlrm sums a`` adds the 17-operand sorts)."""
+    import json
+
+    import fps_tpu.ops as ops
+
+    R, D, B = DLRM_R, DLRM_D, DLRM_B
+    out = {"rows": R, "dim": D, "ids": B, "compile_s": {}}
+
+    def read(name, us_a_step, op):
+        try:
+            out[f"{name}_us"], out["compile_s"][name] = us_a_step(op)
+        except Exception as e:  # noqa: BLE001 (does not fit / compile)
+            out[f"{name}_us"] = f"{type(e).__name__}: {str(e)[:200]}"
+        print(json.dumps({name: out[f"{name}_us"],
+                          "compile_s": out["compile_s"].get(name)}),
+              flush=True)
+
+    def plain(t, i):
+        return xla_scatter(t, i, _dlrm_deltas(i))
+
+    def blocks(t, i):
+        return ops._xla_sorted_scatter_add(t, i, _dlrm_deltas(i))
+
+    def sums_alone(form):
+        def op(t, i):
+            ids, sums = form(t, i, _dlrm_deltas(i), R)
+            return lax.dynamic_update_slice(
+                t, (jnp.sum(sums) + jnp.sum(ids) * 1e-9)[None, None] * 1e-9,
+                (0, 0))
+        return op
+
+    for kind in ("cell", "uniform", "half"):
+        ids = _dlrm_ids(kind)
+        out[f"distinct_{kind}"] = int(np.mean(
+            [len(np.unique(r)) for r in ids]))
+        us_a_step = _dlrm_timer(R, ids)
+        read(f"scatter_plain_{kind}", us_a_step, plain)
+        read(f"push_plain_{kind}", us_a_step, _store_sum_push(False))
+        read(f"push_shipped_{kind}", us_a_step, _store_sum_push(True))
+        if kind == "uniform":
+            read("push_always_summed_uniform", us_a_step,
+                 _store_sum_push(True, 2.0))
+        if kind == "cell":
+            read("push_never_summed_cell", us_a_step,
+                 _store_sum_push(True, -1.0))
+            for name in forms:
+                read(f"sums_{name}_cell", us_a_step,
+                     sums_alone(_sum_forms()[name]))
+        if kind != "half":
+            read(f"blocks_distinct_{kind}",
+                 _dlrm_timer(R, _compacted(ids, R)), blocks)
+            read(f"blocks_all_sorted_{kind}",
+                 _dlrm_timer(R, np.sort(ids, axis=1)), blocks)
+    return out
+
+
+def dlrm_edge_point(R, kind):
+    """us a call of the additive push into ``[R, 16]`` under 425,984 ids,
+    plain against ``push.sum_runs`` (both predicates answering yes
+    whatever the rows), and the distinct ids a step."""
+    ids = _dlrm_ids(kind, R=R)
+    us_a_step = _dlrm_timer(R, ids)
+    out = {"rows": R, "dim": DLRM_D, "ids": DLRM_B, "dist": kind,
+           "distinct": int(np.mean([len(np.unique(r)) for r in ids]))}
+    for name, route in (("plain", False), ("sum_runs", True)):
+        out[f"push_{name}_us"], _ = us_a_step(_store_sum_push(route, R=R))
+    return out
+
+
+DLRM_EDGE_R = (1_048_576, 4_194_304, 8_440_645)
+
+
 def rows_sweep(args):
     """``rows``: the whole grid at B = 32768 (pair at D = 10 only), then
     the fewest ids at which the route pays at Netflix's user block.
-    ``rows quick``: Netflix's user block alone. One JSON line a point on
-    stdout, all of them in ``chiprun_out/bench_scatter_rows.jsonl``."""
+    ``rows quick``: Netflix's user block alone. ``rows dlrm``: the plain
+    ops and the resident packed form at ``dlrm-criteo.epochs``' shape;
+    ``rows dlrm sums [a]`` and ``rows dlrm edge``: ``push.sum_runs`` there
+    and over the rows. One JSON line a point on stdout, all of them in
+    ``chiprun_out/bench_scatter_rows.jsonl``."""
     import json
 
     if args == ["dlrm"]:
         return dlrm_rows()
+    if args[:2] == ["dlrm", "sums"]:
+        forms = ("b_full", "b_blocks", "c", "shipped") + tuple(args[2:])
+        return _write_points("rows", [(dlrm_sums, (forms,))])
+    if args == ["dlrm", "edge"]:
+        return _write_points("rows", [
+            (dlrm_edge_point, (R, kind)) for R in DLRM_EDGE_R
+            for kind in ("zipf", "uniform")])
     B = 32768
     if args == ["quick"]:
         points = [(480_189, 10, B, ("scatter", "gather", "pair"))]
@@ -712,11 +961,6 @@ def _runs_variants():
     parts of it alone (what they return is not the sums)."""
     from fps_tpu.core import store
 
-    def ends(s):
-        edge = s[1:] != s[:-1]
-        one = jnp.ones((1,), bool)
-        return jnp.concatenate([one, edge]), jnp.concatenate([edge, one])
-
     def unstable(*operands):
         return lax.sort(operands, num_keys=1, is_stable=False)
 
@@ -725,7 +969,7 @@ def _runs_variants():
 
     def assoc_scan(idx, rows, drop):
         s, *cols = unstable(idx, *rows.T)
-        first, last = ends(s)
+        first, last = store._run_ends(s)
 
         def seg(a, b):
             return a[0] | b[0], jnp.where(b[0][:, None], b[1], a[1] + b[1])
@@ -738,7 +982,7 @@ def _runs_variants():
     def positions(idx, rows, drop):
         pos = jnp.arange(idx.shape[0], dtype=jnp.int32)
         s, order = unstable(idx, pos)
-        first, last = ends(s)
+        first, last = store._run_ends(s)
         cols = store._run_sums(first, counted(
             s, tuple(jnp.take(rows, order, axis=0).T), drop))
         ids, order = unstable(jnp.where(last, s, drop), pos)
@@ -746,7 +990,7 @@ def _runs_variants():
 
     def ones_through_both_sorts(idx, rows, drop):
         s, *cols = unstable(idx, *counted(idx, tuple(rows.T), drop))
-        first, last = ends(s)
+        first, last = store._run_ends(s)
         ids, *cols = unstable(jnp.where(last, s, drop),
                               *store._run_sums(first, tuple(cols)))
         return ids, jnp.stack(cols, axis=1)
@@ -757,7 +1001,7 @@ def _runs_variants():
 
     def sorts_no_scan(idx, rows, drop):
         s, *cols = unstable(idx, *rows.T)
-        ids, *cols = unstable(jnp.where(ends(s)[1], s, drop),
+        ids, *cols = unstable(jnp.where(store._run_ends(s)[1], s, drop),
                               *counted(s, cols, drop))
         return ids, jnp.stack(cols, axis=1)
 
@@ -891,7 +1135,7 @@ if __name__ == "__main__":
     else:
         raise SystemExit(
             f"unknown args {sys.argv[1:]!r} — usage: bench_scatter.py "
-            "dim1|rows [quick|dlrm]|mean [counts]|wide [quick]|"
+            "dim1|rows [quick|dlrm [sums [a]|edge]]|mean [counts]|wide [quick]|"
             "fold [quick|edge|probes]  ('dim1' = "
             "scalar-table PA shape; 'rows' = plain XLA against the "
             "lane-packed XLA route over table rows x row width; 'mean' = "
