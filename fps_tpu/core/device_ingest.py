@@ -16,10 +16,11 @@ on-device gathers:
   ``(num_workers, max_queue)`` matrix. Without a route key the matrix
   is a closed form (worker ``w`` owns rows ``w, w + W, ...``:
   :func:`unkeyed_queue_rows`) and the step computes its rows: the
-  compiled call is handed no queue. The unpacked branch of
-  :meth:`DeviceEpochPlan.local_batch_at` says which in the route log
-  (``fps_tpu.ops.routes_traced``): ``ingest.rows_computed`` or, for a
-  keyed plan, ``ingest.rows_queued``;
+  compiled call is handed no queue. Where the columns do not pack,
+  :meth:`DeviceEpochPlan.local_batch_at` says in the route log
+  (``fps_tpu.ops.routes_traced``) where a step's rows come from:
+  ``ingest.rows_sliced``, ``ingest.rows_computed`` or, for a keyed plan,
+  ``ingest.rows_queued``;
 * **shuffle** — per epoch, each worker's queue is traversed under a
   permutation of ``[0, count)``: ``shuffle="sort"`` draws a true uniform
   permutation (on-device argsort of random keys), ``shuffle="interleave"``
@@ -32,7 +33,19 @@ on-device gathers:
   order), so any epoch permutation is already an upgrade; ``shuffle=None``
   preserves stream order exactly like the reference;
 * **padding** — workers with short queues (skewed routing) read zero-weight
-  padding rows, identical semantics to the host path.
+  padding rows, identical semantics to the host path;
+* **the transposed epoch** — under ``interleave`` and in stream order the
+  bijection is "roll, view as a grid, transpose", so a once-a-call
+  regular relayout (``ingest.tbuf``) puts a step's rows side by side and
+  the step reads ONE contiguous slice where it would gather
+  ``local_batch`` rows. Two forms: the packed rows of a data set whose
+  columns are all 1-D (bit-packed into one int32 matrix, keyed or not),
+  and, for an unkeyed plan over columns that do not pack (one of them
+  2-D), a buffer a column in its own dtype and tail
+  (``DeviceEpochPlan.sliced``), where :func:`columns_take_slices` says
+  from the columns' shapes that the copies pay and fit. Everything else
+  (``shuffle="sort"``, a keyed plan over 2-D columns, columns 64 slots
+  wide) gathers row by row.
 
 Two consumption styles, one geometry (:class:`DeviceEpochPlan`):
 
@@ -74,6 +87,93 @@ WORKER_AXES = (DATA_AXIS, SHARD_AXIS)
 # Cap on interleave grid rows: consecutive emitted examples sit ~count/r
 # apart in stream order, and r*c must stay int32-safe.
 _GRID_ROWS_MAX = 1 << 12
+
+
+# Which unkeyed data sets read a step as SLICES of a once-a-call transposed
+# copy of each column (``DeviceEpochPlan.sliced``, PR 50) and which keep
+# the row gather. Either way an epoch touches every row once, so the
+# question is a row's cost by each, and whether the copies fit. All
+# readings: ``tools/bench_ingest.py`` on one v5e chip, a step of 16,384
+# rows, 4-byte columns (builder's chip runs: PR 46, call 138; PR 50,
+# call 1).
+#
+# Slots a row from which the copy stops paying. The chip keeps a narrower
+# column column-major (rows minor: ``[N, 39]`` is ``{0,1:T(8,128)}``) and
+# the row gather reads a row one strided word at a time: 12.0 ns a row of
+# 1 slot (a 1-D column), 20.4 of 13, 34.4 of 26, 43.7 of 39, where the
+# copy moves it for 0.7 / 1.2 / 2.4 / 3.0 ns and the slice reads it for
+# 0.2 - 0.5. At 64 slots a 128-lane tile row no more than doubles the
+# column and the step program re-tiles it row-major once a call (12 ms
+# for ``[9652968, 64]``): the gather then reads a row for 8.1 ns, the
+# copy moves it for 10.5 (+1.4 % at best on ``pa-rcv1.epochs``). Nothing
+# between 39 and 64 was measured.
+_SLICED_SLOTS_MAX = 64          # exclusive
+# Copies alive at once: a caller that keeps a call queued behind the one
+# running (``run_indexed(..., as_numpy=False)`` in a loop) holds the
+# running call's and the queued call's (``lr-criteo.epochs``' peak reads
+# 8.33 GB = the parent's 2.81 + 2 x 2.76: builder's chip runs, PR 50);
+# the third is the one the next ``epoch_args`` may make before the first
+# is released.
+_SLICED_CALLS_ALIVE = 3
+# Share of the device's memory limit that the resident columns and those
+# copies may take. The rest is the model's tables and what no
+# ``memory_stats`` shows: the builder's own temporaries (one more copy of
+# the widest column: compiled for a described v5e, PR 50) and the step
+# program's (a ``[9652968, 64]`` column re-tiled: 4.94 GB each, PR 46).
+# ``lr-criteo``'s columns: 2.72 GB resident + 3 x 2.76 = 11.0 of 16.9 GB,
+# 65 %, in; ``pa-rcv1``'s: 4.98 + 3 x 4.99 = 19.9 GB, 118 %, out (and out
+# at two copies alive, 88 %).
+_SLICED_HBM_SHARE = 0.75
+
+
+def _tiled_bytes(rows: int, tail, itemsize: int) -> int:
+    """Bytes of a ``(rows, *tail)`` array as the chip tiles a column it
+    keeps column-major: the rows in lanes of 128, the slots in sublanes
+    of 8 words (a 1-D array: tiles of 1,024)."""
+    if not tail:
+        return -(-rows // 1024) * 1024 * itemsize
+    sublanes = 8 * max(1, 4 // itemsize)
+    slots = -(-int(np.prod(tail)) // sublanes) * sublanes
+    return slots * -(-rows // 128) * 128 * itemsize
+
+
+def _slices_pay(columns) -> bool:
+    """Every column narrow enough that the copy beats the row gather."""
+    return all(int(np.prod(c.shape[1:])) < _SLICED_SLOTS_MAX
+               for c in columns.values())
+
+
+def _slices_fit(columns, num_workers: int, buffer_rows: int,
+                hbm_bytes: int | None) -> bool:
+    """The resident columns and the copies alive at once within the share
+    of ``hbm_bytes`` (``None``: the backend reports no limit; they fit)."""
+    if hbm_bytes is None:
+        return True
+
+    def tiled(rows=None):
+        return sum(_tiled_bytes(rows or c.shape[0], c.shape[1:],
+                                np.dtype(c.dtype).itemsize)
+                   for c in columns.values())
+
+    return (tiled() + _SLICED_CALLS_ALIVE * tiled(num_workers * buffer_rows)
+            <= _SLICED_HBM_SHARE * hbm_bytes)
+
+
+def columns_take_slices(columns, num_workers: int, buffer_rows: int,
+                        hbm_bytes: int | None) -> bool:
+    """Should an unkeyed plan over ``columns`` (name -> anything with a
+    ``shape`` and a ``dtype``) read its steps as slices of transposed
+    copies of ``buffer_rows`` rows a worker? From the shapes alone: the
+    copies pay and, under ``hbm_bytes`` (the device's memory limit), fit."""
+    return _slices_pay(columns) and _slices_fit(
+        columns, num_workers, buffer_rows, hbm_bytes)
+
+
+def _hbm_bytes(mesh) -> int | None:
+    """The memory limit of one of the mesh's devices, where the backend
+    reports one (the CPU's does not)."""
+    stats = mesh.local_devices[0].memory_stats()
+    return (stats or {}).get("bytes_limit")
 
 
 def unkeyed_queue_rows(w, qpos, count, num_workers: int):
@@ -264,11 +364,23 @@ class DeviceEpochPlan:
         # (~11ns/row measured on a 20M-row matrix = ~360us/step at B=32k);
         # the transpose is bandwidth bound (~1ms/epoch for 240MB) and the
         # contiguous dynamic_slice is ~free.
+        #
+        # Two forms of the buffer: the PACKED rows of a data set whose
+        # columns are all 1-D (one int32 matrix, any plan), and, for an
+        # unkeyed plan over columns that do not pack, one buffer a COLUMN
+        # in its own dtype and tail, where :func:`columns_take_slices`
+        # says the copies pay and fit (``sliced``).
         self._tbuf_jit = None
+        self.sliced = False
         if pack and shuffle in (None, "interleave"):
             packed = dataset.packed(route_key, num_workers)
             if packed is not None:
-                self._tbuf_jit = self._make_tbuf_jit(packed[0].shape[1])
+                self._tbuf_jit = self._make_tbuf_jit()
+            elif route_key is None and columns_take_slices(
+                    dataset.columns, num_workers, steps * local_batch,
+                    _hbm_bytes(dataset.mesh)):
+                self.sliced = True
+                self._tbuf_jit = self._make_column_tbuf_jit()
 
         if shuffle == "sort":
             maxq, counts, W = self.maxq, jnp.asarray(self.counts), num_workers
@@ -296,47 +408,77 @@ class DeviceEpochPlan:
                 out_shardings=NamedSharding(dataset.mesh, P()),
             )
 
-    def _make_tbuf_jit(self, num_channels: int):
+    def _transposed_rows(self, rows, off_w, w: int):
+        """Worker ``w``'s queue-ordered ``rows`` (``(any, *tail)``, zeros
+        behind its count) in STEP order, ``(steps * B, *tail)``: padded or
+        cut to the grid's ``m_w``, rolled by the epoch's offset, viewed as
+        ``(r, c_w)`` and transposed (stream order: as they are), then
+        padded or cut to the epoch's steps. Regular ops only (slice, roll,
+        reshape, transpose, pad): no gathers."""
+        r, c_w, m_w = self.grid_r, int(self.grid_c[w]), int(self.grid_m[w])
+        out_rows = self.steps_per_epoch * self.local_batch
+        tail = rows.shape[1:]
+
+        def padded(x, n):
+            if x.shape[0] >= n:
+                return x[:n]
+            return jnp.concatenate(
+                [x, jnp.zeros((n - x.shape[0],) + tail, x.dtype)])
+
+        tb = padded(rows, m_w)
+        if self.shuffle == "interleave":
+            rolled = jnp.roll(tb, -off_w[w], axis=0)
+            tb = jnp.swapaxes(
+                rolled.reshape((r, c_w) + tail), 0, 1).reshape((m_w,) + tail)
+        return padded(tb, out_rows)
+
+    def _make_tbuf_jit(self):
         """Jitted per-epoch builder of the transposed row buffer.
 
         ``(packed rows, per-worker offsets) -> (W, steps*B, C)`` where entry
         ``[w, pos]`` holds worker ``w``'s step-order example at position
         ``pos`` — i.e. ``packed[w*maxq + (bij(pos) + off_w) mod m_w]`` — so
-        :meth:`local_batch_at` reads plain contiguous slices. Built from
-        regular ops only (slice, roll, transpose, pad): no gathers.
+        :meth:`local_batch_at` reads plain contiguous slices
+        (:meth:`_transposed_rows`).
         """
-        W, r, maxq = self.num_workers, self.grid_r, self.maxq
-        out_rows = self.steps_per_epoch * self.local_batch
-        C = num_channels
+        W, maxq = self.num_workers, self.maxq
 
         @jax.named_scope("ingest.tbuf")
         def build(packed_mat, off_w):
-            outs = []
-            for w in range(W):
-                c_w = int(self.grid_c[w])
-                m_w = int(self.grid_m[w])
-                seg = packed_mat[w * maxq : (w + 1) * maxq]
-                if m_w <= maxq:
-                    rows = seg[:m_w]
-                else:
-                    rows = jnp.concatenate(
-                        [seg, jnp.zeros((m_w - maxq, C), seg.dtype)]
-                    )
-                if self.shuffle == "interleave":
-                    rolled = jnp.roll(rows, -off_w[w], axis=0)
-                    tb = (
-                        rolled.reshape(r, c_w, C)
-                        .transpose(1, 0, 2)
-                        .reshape(m_w, C)
-                    )
-                else:  # stream order: contiguous already, just pad
-                    tb = rows
-                if m_w < out_rows:
-                    tb = jnp.concatenate(
-                        [tb, jnp.zeros((out_rows - m_w, C), tb.dtype)]
-                    )
-                outs.append(tb[:out_rows])
-            return jnp.stack(outs)
+            return jnp.stack([
+                self._transposed_rows(
+                    packed_mat[w * maxq : (w + 1) * maxq], off_w, w)
+                for w in range(W)])
+
+        return jax.jit(
+            build, out_shardings=NamedSharding(self._mesh, P())
+        )
+
+    def _make_column_tbuf_jit(self):
+        """Jitted per-epoch builder of the transposed buffers of an unkeyed
+        plan's own columns: ``(columns, per-worker offsets) -> {name:
+        (W * steps*B, *tail)}``, each in its column's dtype, nothing
+        bit-packed. Entry ``[w * steps*B + pos]`` is the row worker ``w``
+        reads at position ``pos``, or zeros where the position holds
+        none. A worker's queue is the column's rows ``w, w + W, ...``
+        (:func:`unkeyed_queue_rows`): on one worker the column itself, so
+        there is no packed copy and no queue. The workers' segments lie
+        end to end along the ROWS, not along a leading axis: the buffer
+        is then an array of the column's own rank, which the TPU lays
+        out as it does the column (a leading ``[1, ...]`` axis gives it
+        another tiling, and the builder one more pass of temporaries the
+        size of the column: compiled for a described v5e, PR 50).
+        """
+        W = self.num_workers
+
+        @jax.named_scope("ingest.tbuf")
+        def build(columns, off_w):
+            return {
+                k: jnp.concatenate([
+                    self._transposed_rows(col[w::W], off_w, w)
+                    for w in range(W)])
+                for k, col in columns.items()
+            }
 
         return jax.jit(
             build, out_shardings=NamedSharding(self._mesh, P())
@@ -399,7 +541,9 @@ class DeviceEpochPlan:
             # An unkeyed plan's steps compute their rows (local_batch_at):
             # no compiled call has a parameter of the queue's shape.
             args["queues"] = self._queues
-        if self._tbuf_jit is not None:
+        if self.sliced:
+            args["tbuf"] = self._tbuf_jit(self.dataset.columns, off_w)
+        elif self._tbuf_jit is not None:
             args["tbuf"] = self._tbuf_jit(packed[0], off_w)
         elif packed is not None:
             args["packed"] = packed[0]
@@ -428,6 +572,25 @@ class DeviceEpochPlan:
         else:
             qpos = pos
             valid = pos < cnt
+        if self.sliced:
+            # The columns' own transposed buffers: ONE contiguous slice a
+            # column, where the unpacked branch below gathers row by row.
+            # The buffers encode the bijection and the offset; ``valid``
+            # comes from the same (qpos, cnt) math above.
+            ops.log_route("ingest", "rows_sliced", int(self.counts.sum()),
+                          len(args["tbuf"]), self.local_batch)
+            start = (w * self.steps_per_epoch + t) * self.local_batch
+            # Materialised here, as the gathered batch is: left alone XLA
+            # fuses each slice into its consumers and the read leaves
+            # ``fps.ingest`` for their scopes. Free end to end
+            # (``lr-criteo.epochs`` 2,249,597 examples/s with, 2,248,931
+            # without, one seed: builder's chip run, PR 50).
+            batch = jax.lax.optimization_barrier({
+                k: jax.lax.dynamic_slice_in_dim(buf, start, self.local_batch)
+                for k, buf in args["tbuf"].items()
+            })
+            batch["weight"] = valid.astype(jnp.float32)
+            return batch
         if "tbuf" in args:
             # Transposed fast path: batch = one contiguous slice. The buffer
             # already encodes the shuffle bijection + offset; ``valid`` was

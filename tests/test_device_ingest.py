@@ -13,9 +13,11 @@ import pytest
 import jax
 
 from fps_tpu import ops
+from fps_tpu.core import device_ingest
 from fps_tpu.core.device_ingest import (
     DeviceDataset,
     DeviceEpochPlan,
+    columns_take_slices,
     device_epoch_chunks,
     unkeyed_queue_rows,
 )
@@ -167,14 +169,19 @@ def test_indexed_epoch_matches_chunked(mesh, dataset, data, sync_every):
 def test_indexed_epoch_matches_chunked_2d_columns(mesh, sparse_data,
                                                   sparse_dataset, shuffle):
     """The same parity for an UNKEYED data set with 2-D columns, whose
-    rows both drivers compute (``ingest.rows_computed``). The two drivers'
-    programs round differently on this backend (6e-8 on the tree before
-    PR 46 too), so bits are held where the programs differ in nothing but
-    the rows' origin: ``run_indexed`` through the closed form against
-    ``run_indexed`` reading the same queue matrix (``_keyed_twin``)."""
+    steps both drivers slice from the columns' transposed buffers
+    (``ingest.rows_sliced``; under ``sort`` they compute and gather,
+    ``ingest.rows_computed``). Two programs round differently on this
+    backend (the two drivers' 6e-8 on the tree before PR 46 too; the
+    sliced step against the gathered one 3e-8 on batches that are the
+    same to the bit), so bits are held where the programs differ in
+    nothing but the rows' origin: ``run_indexed`` through the closed form
+    (``pack=False``) against ``run_indexed`` reading the same queue
+    matrix (``_keyed_twin``)."""
     W = num_workers_of(mesh)
     kw = dict(num_workers=W, local_batch=16, shuffle=shuffle, seed=7)
     plan = DeviceEpochPlan(sparse_dataset, **kw)
+    assert plan.sliced == (shuffle != "sort")
 
     def run(drive):
         trainer, _ = passive_aggressive(mesh, SPARSE_CFG)
@@ -189,10 +196,14 @@ def test_indexed_epoch_matches_chunked_2d_columns(mesh, sparse_data,
                                   plan=plan), jax.random.key(1)))
     indexed = run(lambda tr, t, l: tr.run_indexed(
         t, l, plan, jax.random.key(1)))
+    computed = run(lambda tr, t, l: tr.run_indexed(
+        t, l, DeviceEpochPlan(sparse_dataset, pack=False, **kw),
+        jax.random.key(1)))
     queued = run(lambda tr, t, l: tr.run_indexed(
         t, l, _keyed_twin(mesh, sparse_data, **kw), jax.random.key(1)))
     np.testing.assert_allclose(chunked, indexed, atol=1e-6)
-    np.testing.assert_array_equal(indexed, queued)
+    np.testing.assert_allclose(indexed, computed, atol=1e-6)
+    np.testing.assert_array_equal(computed, queued)
     assert np.abs(indexed).max() > 0.01
 
 
@@ -486,7 +497,8 @@ def test_computed_rows_give_the_queued_batches(mesh, sparse_data,
     W = 8
     kw = dict(num_workers=W, local_batch=LOCAL_BATCH, shuffle=shuffle,
               seed=3)
-    plan = DeviceEpochPlan(sparse_dataset, **kw)
+    # pack=False: the row gather, not the slices these shapes take (PR 50).
+    plan = DeviceEpochPlan(sparse_dataset, pack=False, **kw)
     twin = _keyed_twin(mesh, sparse_data, **kw)
     q = np.asarray(twin._queues)
     np.testing.assert_array_equal(
@@ -544,28 +556,201 @@ def test_unkeyed_step_has_no_queue_parameter(mesh, sparse_data,
 
 
 @pytest.mark.parametrize("case, want", [
-    ("unkeyed-2d", "ingest.rows_computed"),
+    ("unkeyed-2d", "ingest.rows_sliced"),
+    ("unkeyed-2d-sort", "ingest.rows_computed"),
+    ("unkeyed-2d-unpacked", "ingest.rows_computed"),
     ("keyed-2d", "ingest.rows_queued"),
     ("tbuf", None),
     ("packed", None),
 ])
 def test_route_log_names_the_ingest_branch(mesh, dataset, sparse_data,
                                            sparse_dataset, case, want):
-    """The unpacked branch logs where a step's rows come from; the
-    transposed buffer and the packed matrix log nothing."""
+    """Where the columns do not pack, the step logs where its rows come
+    from, once: sliced from the columns' transposed buffers (PR 50),
+    computed and gathered, or read from the queue and gathered; the packed
+    rows' transposed buffer and the packed matrix log nothing."""
     kw = dict(num_workers=8, local_batch=LOCAL_BATCH, seed=3)
     plan = {
         "unkeyed-2d": lambda: DeviceEpochPlan(sparse_dataset, **kw),
+        "unkeyed-2d-sort": lambda: DeviceEpochPlan(
+            sparse_dataset, shuffle="sort", **kw),
+        "unkeyed-2d-unpacked": lambda: DeviceEpochPlan(
+            sparse_dataset, pack=False, **kw),
         "keyed-2d": lambda: _keyed_twin(mesh, sparse_data, **kw),
         "tbuf": lambda: DeviceEpochPlan(dataset, **kw),
         "packed": lambda: DeviceEpochPlan(dataset, shuffle="sort", **kw),
     }[case]()
     args = plan.epoch_args(0)
-    # The last two cases are named after the operand their branch reads.
+    # The last two cases are named after the operand their branch reads;
+    # the sliced plan's operand is a buffer a column under the same name.
     assert (case in args) == (want is None)
+    assert plan.sliced == (want == "ingest.rows_sliced") == isinstance(
+        args.get("tbuf"), dict)
     ops.clear_routes()
     jax.jit(plan.local_batch_at).lower(args, np.int32(0), np.int32(0))
     got = [(r.route, r.rows, r.dim, r.ids) for r in ops.routes_traced()]
     cols = len(plan.dataset.columns)
     assert got == ([(want, SPARSE_N, cols, LOCAL_BATCH)] if want else []), got
     assert not {r[0] for r in got} & ops.PALLAS_ROUTES
+
+
+# -- an unkeyed plan's 2-D columns read as slices (PR 50) -------------------
+
+SLICED_N = 1003     # 1003 % 4 == 3; neither 1003 nor 251 a multiple of the grid
+
+
+@pytest.fixture(scope="module")
+def wide_data():
+    rng = np.random.default_rng(7)
+    return {"ids": rng.integers(0, 1 << 20, (SLICED_N, 5)).astype(np.int32),
+            "vals": rng.normal(size=(SLICED_N, 3)).astype(np.float32),
+            # Rows told apart, none of them zero: a padding row reads 0.
+            "row": np.arange(1, SLICED_N + 1, dtype=np.float32)}
+
+
+@pytest.fixture(scope="module")
+def wide_dataset(mesh, wide_data):
+    return DeviceDataset(mesh, wide_data)
+
+
+@pytest.mark.parametrize("sync_every", [None, 8])
+@pytest.mark.parametrize("shuffle", [None, "interleave"])
+@pytest.mark.parametrize("W", [1, 4])
+def test_sliced_batches_are_the_gathered_and_the_bijections(
+        wide_data, wide_dataset, W, shuffle, sync_every):
+    """Two epochs, every step and worker: the batch sliced from the
+    columns' transposed buffers is (i) the ``rows_computed`` branch's
+    (the same plan with ``pack=False``) and (ii) the rows a numpy
+    computation of the bijection names, bit for bit; every row once an
+    epoch; a position that holds no row reads zeros at weight 0."""
+    kw = dict(num_workers=W, local_batch=LOCAL_BATCH, shuffle=shuffle,
+              seed=3, sync_every=sync_every)
+    plan = DeviceEpochPlan(wide_dataset, **kw)
+    gathered = DeviceEpochPlan(wide_dataset, pack=False, **kw)
+    assert plan.sliced and not gathered.sliced
+    assert (W == 1 or SLICED_N % W) and (plan.counts % plan.grid_r).all()
+    steps = plan.steps_per_epoch
+    assert steps == gathered.steps_per_epoch
+    assert not sync_every or steps % sync_every == 0
+    at, gathered_at = (jax.jit(plan.local_batch_at),
+                       jax.jit(gathered.local_batch_at))
+    offsets = set()
+    for epoch in (0, 1):
+        a, ga = plan.epoch_args(epoch), gathered.epoch_args(epoch)
+        assert set(a["tbuf"]) == set(wide_data) and "tbuf" not in ga
+        for k, buf in a["tbuf"].items():
+            assert buf.dtype == wide_data[k].dtype
+            assert buf.shape == ((W * steps * LOCAL_BATCH,)
+                                 + wide_data[k].shape[1:])
+        off_w = np.asarray(a["off_w"])
+        offsets.add(int(off_w[0]))
+        seen = []
+        for w in range(W):
+            r, c, m, cnt = (plan.grid_r, int(plan.grid_c[w]),
+                            int(plan.grid_m[w]), int(plan.counts[w]))
+            for t in range(steps):
+                got = at(a, np.int32(w), np.int32(t))
+                want = gathered_at(ga, np.int32(w), np.int32(t))
+                got, want = ({k: np.asarray(v) for k, v in b.items()}
+                             for b in (got, want))
+                # (ii) the bijection, on the host.
+                pos = t * LOCAL_BATCH + np.arange(LOCAL_BATCH)
+                if shuffle == "interleave":
+                    qpos = ((pos % r) * c + pos // r + off_w[w]) % m
+                    live = (pos < m) & (qpos < cnt)
+                else:
+                    qpos, live = pos, pos < cnt
+                rows = (w + W * qpos)[live]
+                assert set(got) == set(want) == set(wide_data) | {"weight"}
+                np.testing.assert_array_equal(got["weight"], live)
+                np.testing.assert_array_equal(want["weight"], live)
+                for k, col in wide_data.items():
+                    assert got[k].dtype == col.dtype
+                    np.testing.assert_array_equal(got[k][live], col[rows])
+                    np.testing.assert_array_equal(got[k][live],
+                                                  want[k][live])      # (i)
+                    assert not got[k][~live].any()
+                seen.append(rows)
+        np.testing.assert_array_equal(np.sort(np.concatenate(seen)),
+                                      np.arange(SLICED_N))
+    assert shuffle is None or len(offsets) == 2     # a fresh roll an epoch
+
+
+def _columns(n, *slots):
+    """A cell's resident columns by shape: two wide ones and the label."""
+    wide = {f"c{i}": jax.ShapeDtypeStruct((n, s), d) for i, (s, d) in
+            enumerate(zip(slots, (np.int32, np.float32)))}
+    return {**wide, "label": jax.ShapeDtypeStruct((n,), np.float32)}
+
+
+V5E_HBM = 16_909_336_064        # one v5e chip's ``bytes_limit``
+B_CELL = 16_384
+
+
+CELLS = {   # the cell's resident columns, the steps of its call
+    "lr-criteo": (_columns(1 << 23, 39, 39), 520),
+    "dlrm-criteo": (_columns(1 << 20, 26, 13), 65),
+    "pa-rcv1": (_columns(9_652_968, 64, 64), 590),
+}
+
+
+@pytest.mark.parametrize("cell, pays, fits", [
+    ("lr-criteo", True, True),
+    ("dlrm-criteo", True, True),
+    # Out on both grounds: the copy of a 64-slot row costs more than its
+    # gather, and three calls' copies beside the columns are 118 % of
+    # the chip.
+    ("pa-rcv1", False, False),
+])
+def test_the_predicate_at_the_three_cells_shapes(cell, pays, fits):
+    columns, steps = CELLS[cell]
+    rows = steps * B_CELL
+    assert device_ingest._slices_pay(columns) == pays
+    assert device_ingest._slices_fit(columns, 1, rows, V5E_HBM) == fits
+    assert columns_take_slices(columns, 1, rows, V5E_HBM) == (pays and fits)
+    # A backend that reports no limit (the CPU's): the bytes decide
+    # nothing, the widths still do.
+    assert columns_take_slices(columns, 1, rows, None) == pays
+
+
+def test_the_fit_keeps_a_margin(monkeypatch):
+    """What no ``memory_stats`` shows has room: PA's columns stay out
+    with two copies alive (its step program re-tiles 9.9 GB besides),
+    lr's go out on a chip of three quarters the memory, dlrm's 0.2 GB
+    fit either way."""
+    def fit(cell, hbm):
+        columns, steps = CELLS[cell]
+        return device_ingest._slices_fit(columns, 1, steps * B_CELL, hbm)
+
+    assert not fit("lr-criteo", V5E_HBM * 3 // 4)
+    assert fit("dlrm-criteo", V5E_HBM * 3 // 4)
+    monkeypatch.setattr(device_ingest, "_SLICED_CALLS_ALIVE", 2)
+    assert not fit("pa-rcv1", V5E_HBM)
+    assert fit("lr-criteo", V5E_HBM)
+
+
+@pytest.mark.parametrize("case", ["keyed", "packable", "sort", "unpacked"])
+def test_the_other_plans_keep_their_branches(mesh, dataset, sparse_data,
+                                             sparse_dataset, case):
+    """A keyed plan, a data set that packs, ``shuffle="sort"`` and
+    ``pack=False`` are never sliced: their operands are what they were."""
+    kw = dict(num_workers=8, local_batch=LOCAL_BATCH, seed=3)
+    plan = {
+        "keyed": lambda: _keyed_twin(mesh, sparse_data, **kw),
+        "packable": lambda: DeviceEpochPlan(dataset, **kw),
+        "sort": lambda: DeviceEpochPlan(sparse_dataset, shuffle="sort",
+                                        **kw),
+        "unpacked": lambda: DeviceEpochPlan(sparse_dataset, pack=False,
+                                            **kw),
+    }[case]()
+    args = plan.epoch_args(0)
+    assert not plan.sliced
+    assert set(args) == {
+        "keyed": {"columns", "off_w", "perm", "queues"},
+        "packable": {"columns", "off_w", "perm", "tbuf"},
+        "sort": {"columns", "off_w", "perm"},
+        "unpacked": {"columns", "off_w", "perm"},
+    }[case]
+    if case == "packable":
+        assert args["tbuf"].shape == (
+            8, plan.steps_per_epoch * LOCAL_BATCH, len(plan.dataset.columns))

@@ -181,7 +181,8 @@ def test_cell_rehearsal_runs_the_runners_whole_path():
     assert {e["number"] for e in compared} == want
     routes = [r.route for r in ops.routes_traced()]
     assert routes.count("dense.psum_sgd") >= 1
-    assert "ingest.rows_computed" in routes
+    assert "ingest.rows_sliced" in routes
+    assert "ingest.rows_computed" not in routes
     readings = next(e for e in events if e["event"] == "readings")
     assert readings["window_examples"] == 3001 * readings["n"]
 

@@ -502,22 +502,23 @@ def test_route_log_names_the_snapshot_read_and_the_stateful_fold(
         lr_programs):
     """Each before the entry of the call it ends in; the fold's scatter is
     one column wider (its count); sync mode pulls through ``store.pull``
-    and logs no ``pull.*``. First of all the ingest's entry (PR 46): the
-    plan is unkeyed and its columns 2-D, so a step's rows are computed
-    (32 batches resident, three columns)."""
+    and logs no ``pull.*``. First of all the ingest's entry: the plan is
+    unkeyed and its columns 2-D and 39 slots wide, so a step is sliced
+    from the columns' transposed buffers (PR 50; 32 batches resident,
+    three columns)."""
     rows = B * (NNZ - D) + D
     got = [(r.route, r.rows, r.dim, r.ids, r.reason)
            for r in lr_programs["routes", "ssp"]]
     xla = got[2][4]     # the plain route's reason is the backend's here
-    assert got == [("ingest.rows_computed", 32 * B, 3, B, ""),
+    assert got == [("ingest.rows_sliced", 32 * B, 3, B, ""),
                    ("pull.snapshot", F, 2, rows, ""),
                    ("gather.xla", F, 2, rows, xla),
                    ("push.fold", F, 2, rows, "apply_fn"),
                    ("scatter_add.xla", F, 3, rows, xla)], got
     sync = [r.route for r in lr_programs["routes", "sync"]]
-    assert sync == ["ingest.rows_computed", "gather.xla", "push.fold",
+    assert sync == ["ingest.rows_sliced", "gather.xla", "push.fold",
                     "scatter_add.xla"], sync
-    assert not {"ingest.rows_computed", "pull.snapshot",
+    assert not {"ingest.rows_sliced", "pull.snapshot",
                 "push.fold"} & ops.PALLAS_ROUTES
 
 

@@ -107,15 +107,24 @@ def mf_plans(devices8):
 
 
 @pytest.mark.parametrize("program,name", [
-    ("tbuf", "ingest.tbuf"), ("perm", "ingest.perm"),
-    ("chunk", "ingest.chunk"),
+    ("tbuf", "ingest.tbuf"), ("tbuf.columns", "ingest.tbuf"),
+    ("perm", "ingest.perm"), ("chunk", "ingest.chunk"),
 ])
 def test_once_a_call_programs_carry_no_fps_scope(mf_plans, program, name):
-    _, plan, sort_plan = mf_plans
+    mesh, plan, sort_plan = mf_plans
     if program == "tbuf":
         packed = plan.dataset.packed(plan.route_key, plan.num_workers)[0]
         lowered = plan._tbuf_jit.lower(
             packed, np.zeros(plan.num_workers, np.int32))
+    elif program == "tbuf.columns":
+        # An unkeyed plan over a 2-D column: a buffer a column (PR 50).
+        sliced = DeviceEpochPlan(
+            DeviceDataset(mesh, {"x": np.zeros((512, 3), np.float32),
+                                 "y": np.zeros(512, np.float32)}),
+            num_workers=2, local_batch=32, seed=3)
+        assert sliced.sliced
+        lowered = sliced._tbuf_jit.lower(
+            sliced.dataset.columns, np.zeros(2, np.int32))
     elif program == "perm":
         lowered = sort_plan._perm_jit.lower(
             np.zeros(sort_plan._key_data_shape, np.uint32))

@@ -694,6 +694,7 @@ def test_lr_criteo_epoch_program_names_its_round_and_fits(topo, monkeypatch,
     plan.grid_c = np.full(shards, q // 4096, np.int32)
     plan.grid_m = np.full(shards, q, np.int32)
     plan.steps_per_epoch = 520 // shards // s * s
+    plan.sliced = True      # these columns take the slices (PR 50)
 
     def shape(sh, dtype, spec=P()):
         return jax.ShapeDtypeStruct(sh, dtype,
@@ -701,9 +702,10 @@ def test_lr_criteo_epoch_program_names_its_round_and_fits(topo, monkeypatch,
 
     key = shape((), jax.random.key(0).dtype)
     tables = {"weights": shape((F, 2), jnp.float32, P("shard", None))}
-    iargs = {"columns": {"feat_ids": shape((N, slots), jnp.int32),
-                         "feat_vals": shape((N, slots), jnp.float32),
-                         "label": shape((N,), jnp.float32)},
+    buf = shards * plan.steps_per_epoch * B
+    iargs = {"tbuf": {"feat_ids": shape((buf, slots), jnp.int32),
+                      "feat_vals": shape((buf, slots), jnp.float32),
+                      "label": shape((buf,), jnp.float32)},
              "off_w": shape((shards,), jnp.int32),
              "perm": shape((1, 1), jnp.int32)}
     rows = B * (slots - 13) + 13
@@ -713,9 +715,10 @@ def test_lr_criteo_epoch_program_names_its_round_and_fits(topo, monkeypatch,
         tables, (), iargs, jnp.int32(0), key).compile()
     assert [(r.route, r.rows, r.dim, r.ids, r.reason)
             for r in ops.routes_traced()] == [
-        # The plan is unkeyed: the rows of a step are computed (PR 46),
-        # and the program has no parameter of the queue's shape.
-        ("ingest.rows_computed", N, 3, B, ""),
+        # The plan is unkeyed and its columns 39 slots wide: a step is
+        # three slices of the columns' transposed buffers (PR 50), and
+        # the program has no parameter of the queue's shape.
+        ("ingest.rows_sliced", N, 3, B, ""),
         ("pull.snapshot", F, 2, rows, ""),
         ("gather.xla", F, 2, rows, "shape"),
         *([("push.fold", F, 2, rows, "apply_fn"),
@@ -747,7 +750,16 @@ def test_lr_criteo_epoch_program_names_its_round_and_fits(topo, monkeypatch,
               if re.search(rf"= f32\[{F},2\]\S* copy\(", ln)]
     assert copies and not [ln for ln in copies if "op_name" in ln], copies
     mem = compiled.memory_analysis()
-    assert 2.6e9 < mem.argument_size_in_bytes < 2.9e9     # the columns
+    assert 2.6e9 < mem.argument_size_in_bytes < 2.9e9     # the buffers
+    # The ingest: the buffers stay as they are handed over, column-major
+    # like the columns (no copy of one, as a ``[N, 64]`` column gets), a
+    # step's rows are dynamic slices of them, and no gather makes a batch.
+    for dtype in ("s32", "f32"):
+        assert f"{dtype}[{buf},{slots}]{{0,1:T(8,128)}} parameter(" in text
+        assert not re.search(
+            rf"= {dtype}\[{buf},{slots}\]\S* (copy|fusion)\(", text)
+        assert re.search(rf"{dtype}\[{B},\d+\]\S* dynamic-slice\(", text)
+    assert not re.search(rf"\[{B}(,{slots})?\]\S* gather\(", text)
     assert mem.temp_size_in_bytes < 256 << 20
     # The fold's accumulator: ONE scatter fusion, of a block of the summed
     # runs' ids inside the sorted route's own loop, transposed and in VMEM

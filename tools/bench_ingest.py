@@ -1,11 +1,14 @@
 """On-chip microbench of the device ingest of a 2-D column: three ways to
-read a step's 16,384 rows out of a resident column, at the two cells that
-take the unpacked branch of ``DeviceEpochPlan.local_batch_at``
-(``pa-rcv1.epochs``: ``[9652968, 64]`` and its 1-D label;
-``lr-criteo.epochs``: ``[8388608, 39]`` and its label), one worker, the
-``interleave`` shuffle on a grid of 4,096 rows.
+read a step's 16,384 rows out of a resident column, at the three cells
+whose columns do not pack (``pa-rcv1.epochs``: ``[9652968, 64]`` and its
+1-D label; ``lr-criteo.epochs``: ``[8388608, 39]`` and its label;
+``dlrm-criteo.epochs``: ``[1048576, 26]``, ``[1048576, 13]`` and its
+label), one worker, the ``interleave`` shuffle on the plan's own grid
+(4,096 rows; 1,024 over dlrm's 2^20). The readings are the ones beside
+``fps_tpu.core.device_ingest._SLICED_SLOTS_MAX``.
 
-    chiprun --chips 1 -- python tools/bench_ingest.py [pa] [lr] [wide] [label]
+    chiprun --chips 1 -- python tools/bench_ingest.py \
+        [pa] [lr] [dlrm] [dlrm.dense] [wide] [label]
 
 * ``rows``: today's program. A step's rows computed from its positions
   (``pos -> (pos % r) * c + pos // r + off  mod m``) and one gather of
@@ -17,8 +20,9 @@ take the unpacked branch of ``DeviceEpochPlan.local_batch_at``
   puts the batch back in position order.
 * ``tbuf``: a contiguous slice of a once-an-epoch transposed copy (roll by
   the epoch's offset, view as ``(r, c)``, transpose: what
-  ``_make_tbuf_jit`` does for packed 1-D columns), with the copy's own
-  cost (``build_ms``) and bytes (``copy_bytes``) beside it.
+  ``DeviceEpochPlan._transposed_rows`` does; since PR 50 the plan's own
+  path for lr's and dlrm's columns), with the copy's own cost
+  (``build_ms``) and bytes (``copy_bytes``) beside it.
 
 Every arm is a scan of steps whose carry is a checksum of the batch (the
 same number in all three arms: printed, and compared), fenced by a host
@@ -44,20 +48,38 @@ import jax  # noqa: E402
 import jax.numpy as jnp
 from jax import lax
 
-STEPS, B, R = (64, 512), 16_384, 4_096
-# rows, slots, the epoch's offset: one under which no run of 4 positions
-# crosses the wrap (``m``) or the data's end inside the steps timed, so
-# that the ``runs`` arm reads the ``rows`` arm's rows.
-SHAPES = {"pa": (9_652_968, 64, 123_457), "lr": (8_388_608, 39, 123_456)}
+STEPS, B = (64, 512), 16_384
+# rows, slots, the epoch's offset: one under which no run of consecutive
+# positions crosses the wrap (``m``) or the data's end inside the steps
+# timed, so that the ``runs`` arm reads the ``rows`` arm's rows. The two
+# ``dlrm`` shapes (PR 50) are ``dlrm-criteo.epochs``' sparse and dense
+# columns: 2^20 rows are 64 steps, which the scans walk eight times.
+SHAPES = {"pa": (9_652_968, 64, 123_457), "lr": (8_388_608, 39, 123_456),
+          "dlrm": (1_048_576, 26, 12_352),
+          "dlrm.dense": (1_048_576, 13, 12_352)}
+
+
+def _grid(n):
+    """``(r, c, m)`` of ``DeviceEpochPlan``'s interleave grid over ``n``
+    rows on one worker: about ``sqrt(n)`` rows, a power of two, at most
+    4,096."""
+    r = 1 << min(12, n.bit_length() // 2)
+    c = -(-n // r)
+    return r, c, r * c
+
+
+def _steps(steps, n):
+    """The scan's steps: an epoch's, walked again where the scan is
+    longer than the epoch."""
+    return jnp.arange(steps, dtype=jnp.int32) % (_grid(n)[2] // B)
 
 
 def _positions(t, n, off):
     """Queue positions of step ``t`` under ``interleave`` (one worker):
     ``(qpos clamped, valid)`` as ``local_batch_at`` forms them."""
-    c = -(-n // R)
-    m = R * c
+    r, c, m = _grid(n)
     pos = t * B + jnp.arange(B, dtype=jnp.int32)
-    qpos = (pos % R) * c + pos // R + off
+    qpos = (pos % r) * c + pos // r + off
     qpos = jnp.where(qpos >= m, qpos - m, qpos)
     return jnp.clip(qpos, 0, n - 1), (pos < m) & (qpos < n)
 
@@ -81,44 +103,44 @@ def arm_rows(col, off, *, steps):
         qc, valid = _positions(t, n, off)
         return _fold(acc, jnp.take(col, qc, axis=0), valid), None
 
-    return lax.scan(step, jnp.int32(0), jnp.arange(steps, dtype=jnp.int32))[0]
+    return lax.scan(step, jnp.int32(0), _steps(steps, n))[0]
 
 
 def arm_runs(col, off, *, steps):
     n = col.shape[0]
-    k = B // R          # consecutive queue positions a run
+    r = _grid(n)[0]
+    k = B // r          # consecutive queue positions a run
     tail = col.shape[1:]
 
     def step(acc, t):
         qc, valid = _positions(t, n, off)
-        # Position j + R * i (i < k) is the i-th row of grid row j's run.
+        # Position j + r * i (i < k) is the i-th row of grid row j's run.
         # A run that crossed m (the wrap) or n inside its k rows would
         # read other rows than the ``rows`` arm: the offsets in SHAPES
         # leave none in the steps timed, and the checksums say so.
-        starts = qc[:R]
+        starts = qc[:r]
         runs = lax.gather(
             col, starts[:, None],
             lax.GatherDimensionNumbers(
                 offset_dims=tuple(range(1, 2 + len(tail))),
                 collapsed_slice_dims=(), start_index_map=(0,)),
-            slice_sizes=(k,) + tail, mode="clip")        # (R, k, ...)
+            slice_sizes=(k,) + tail, mode="clip")        # (r, k, ...)
         batch = jnp.swapaxes(runs, 0, 1).reshape((B,) + tail)
         return _fold(acc, batch, valid), None
 
-    return lax.scan(step, jnp.int32(0), jnp.arange(steps, dtype=jnp.int32))[0]
+    return lax.scan(step, jnp.int32(0), _steps(steps, n))[0]
 
 
 def build_tbuf(col, off):
     """The epoch's transposed copy: entry ``pos`` holds the row the
     ``rows`` arm reads at position ``pos``."""
     n = col.shape[0]
-    c = -(-n // R)
-    m = R * c
+    r, c, m = _grid(n)
     tail = col.shape[1:]
     if m > n:
         col = jnp.concatenate([col, jnp.zeros((m - n,) + tail, col.dtype)])
     rolled = jnp.roll(col, -off, axis=0)
-    return jnp.swapaxes(rolled.reshape((R, c) + tail), 0, 1).reshape(
+    return jnp.swapaxes(rolled.reshape((r, c) + tail), 0, 1).reshape(
         (m,) + tail)
 
 
@@ -131,7 +153,7 @@ def arm_tbuf(tbuf, off, *, steps, n):
             tbuf, (t * B,) + (0,) * len(tail), (B,) + tail)
         return _fold(acc, batch, valid), None
 
-    return lax.scan(step, jnp.int32(0), jnp.arange(steps, dtype=jnp.int32))[0]
+    return lax.scan(step, jnp.int32(0), _steps(steps, n))[0]
 
 
 def _best(fn, *args):
@@ -183,7 +205,8 @@ def main(argv):
             base = {"shape": name, "column": list(col.shape),
                     "layout": str(col.format.layout),
                     "rows_a_step": B, "row_bytes": col.nbytes // n,
-                    "steps": list(STEPS), "device": dev.device_kind}
+                    "steps": list(STEPS), "device": dev.device_kind,
+                    "hbm_bytes_limit": dev.memory_stats()["bytes_limit"]}
             sums = {}
             for arm, fn in (("rows", arm_rows), ("runs", arm_runs)):
                 ms, fixed, sums[arm] = _timed_arm(fn, col, off)

@@ -34,6 +34,9 @@ import os
 import re
 import sys
 
+# What one v5e chip's ``memory_stats()["bytes_limit"]`` reads.
+V5E_HBM_BYTES = 16_909_336_064
+
 CELLS = ("mf-netflix.epochs", "pa-rcv1.epochs", "mf-netflix.x4",
          "w2v-1bw.epochs", "lr-criteo.epochs", "ials-ml20m.sweeps.user",
          "ials-ml20m.sweeps.item", "mf-netflix-topk.epochs",
@@ -92,6 +95,24 @@ def write(tree: str, out: str, cells=CELLS) -> None:
               flush=True)
 
     workers = P(None, ("data", "shard"))
+
+    def ingest_operands(plan, columns, shape):
+        """The ingest's operands as ``tree``'s own unkeyed plan over
+        ``columns`` hands them to the step on one v5e chip: the resident
+        columns, or, where the tree has the sliced branch (PR 50) and its
+        predicate takes these shapes, the transposed buffers (``plan`` is
+        told which: ``plan.sliced``)."""
+        from fps_tpu.core import device_ingest
+
+        take = getattr(device_ingest, "columns_take_slices", None)
+        if take is None:
+            return {"columns": columns}
+        rows = plan.steps_per_epoch * plan.local_batch
+        plan.sliced = take(columns, 1, rows, V5E_HBM_BYTES)
+        if not plan.sliced:
+            return {"columns": columns}
+        return {"tbuf": {k: shape((rows,) + c.shape[1:], c.dtype)
+                         for k, c in columns.items()}}
 
     def model(config, part="model"):
         """A group of ``tree``'s own configuration file."""
@@ -228,9 +249,10 @@ def write(tree: str, out: str, cells=CELLS) -> None:
         plan.steps_per_epoch = -(-N // B // s) * s + s
         key = shape((), jax.random.key(0).dtype)
         tables = {"weights": shape((F, 2), jnp.float32, P("shard", None))}
-        iargs = {"columns": {"feat_ids": shape((N, slots), jnp.int32),
-                             "feat_vals": shape((N, slots), jnp.float32),
-                             "label": shape((N,), jnp.float32)},
+        iargs = {**ingest_operands(plan, {
+            "feat_ids": shape((N, slots), jnp.int32),
+            "feat_vals": shape((N, slots), jnp.float32),
+            "label": shape((N,), jnp.float32)}, shape),
                  "off_w": shape((1,), jnp.int32),
                  "perm": shape((1, 1), jnp.int32)}
         if not hasattr(fps_tpu.core.device_ingest, "unkeyed_queue_rows"):
@@ -275,10 +297,10 @@ def write(tree: str, out: str, cells=CELLS) -> None:
                                P("shard", None))}
         tables.update({k + "::dense": shape(s, jnp.float32)
                        for k, s in cfg.layer_shapes().items()})
-        iargs = {"columns": {
+        iargs = {**ingest_operands(plan, {
             "tokens": shape((N, len(cfg.field_rows)), jnp.int32),
             "counts": shape((N, cfg.numeric), jnp.float32),
-            "label": shape((N,), jnp.float32)},
+            "label": shape((N,), jnp.float32)}, shape),
             "off_w": shape((1,), jnp.int32),
             "perm": shape((1, 1), jnp.int32)}
         emit("dlrm-criteo.epochs",
