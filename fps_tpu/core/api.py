@@ -224,7 +224,12 @@ class HotFold:
     combined delta ``g`` (after the ``combine`` normalization) into an
     adaptively-scaled step on the slice:
 
-    * ``"adagrad"`` — ``G += g²; step = lr · g / (sqrt(G) + eps)``;
+    * ``"adagrad"`` — ``G += g²; step = lr · g / (sqrt(G) + eps)``,
+      ``G`` starting at ``initial_accumulator`` (0: a coordinate's first
+      step is ``lr`` in size whatever ``|g|`` is, so two float32 sums of
+      one gradient that differ in the last bit near zero step ``+lr`` and
+      ``-lr``; a positive start, TensorFlow's 0.1, makes the first step
+      continuous in ``g``);
     * ``"adam"`` — lazy per-row Adam: rows untouched in a window keep
       their moments and step count unchanged (sparse-table convention —
       decaying untouched rows would make zero-traffic rows drift), rows
@@ -242,6 +247,11 @@ class HotFold:
     ``hot_sync_every > 1``, full replication — partial heads would give
     head rows an adaptive step and tail rows a raw one, a silent
     semantic fork, so they are rejected at resolution).
+
+    The same declaration as ``ServerLogic.fold`` is the TABLE's own
+    optimizer, hot tier or none: the window is then one step, the state
+    one row an id, laid out and sharded like the table
+    (:func:`fps_tpu.core.store.push`, ``fold=``).
     """
 
     kind: str  # "adagrad" | "adam"
@@ -249,12 +259,19 @@ class HotFold:
     eps: float = 1e-8
     beta1: float = 0.9
     beta2: float = 0.999
+    initial_accumulator: float = 0.0  # Adagrad's G before the first push
 
     def __post_init__(self):
         if self.kind not in ("adagrad", "adam"):
             raise ValueError(
                 f"HotFold.kind {self.kind!r} — expected 'adagrad' or 'adam'"
             )
+        if self.initial_accumulator < 0 or (
+                self.initial_accumulator and self.kind != "adagrad"):
+            raise ValueError(
+                "HotFold.initial_accumulator is Adagrad's starting G, a "
+                f"value >= 0 — got {self.initial_accumulator!r} with kind "
+                f"{self.kind!r}")
 
     def state_cols(self, dim: int) -> int:
         """Columns of per-row optimizer state: Adagrad keeps ``G``;
@@ -298,11 +315,26 @@ class ServerLogic:
     replica axis by the sharded reconcile — see :class:`HotFold` for the
     exact semantics and the resolution requirements. Ignored (with the
     tier's usual loud resolution errors) when the tier is off.
+
+    ``fold`` (a :class:`HotFold`, or its kind string) is the TABLE's own
+    optimizer, untiered: once a step the pushes of every worker are
+    summed by id (``combine="sum"``, no ``apply_fn``) and each id pushed
+    takes one :class:`HotFold` step on that sum, against optimizer state
+    the trainer makes (zeros), carries, donates, shards by owner like the
+    table and snapshots beside it (``fold::`` arrays, logical id order).
+    A row nobody pushed keeps its value and its state bit for bit. On a
+    table large against a step's pushes only the pushed rows and their
+    state are read and written (``push.fold_rows`` in the route log), on
+    a small one the ``(rows, dim + 1)`` accumulator is kept
+    (``push.fold``): :func:`fps_tpu.core.store.push` chooses from the
+    shapes. SSP rounds, ``push_delay``, the hot tier on the same table,
+    ``auto_tier`` and the megastep are refused at construction.
     """
 
     apply_fn: Callable[[Array, Array], Array] | None = None
     combine: str | Callable[[Array, Array], Array] = "sum"
     hot_fold: "HotFold | str | None" = None
+    fold: "HotFold | str | None" = None
 
 
 ADDITIVE = ServerLogic(apply_fn=None)

@@ -56,7 +56,9 @@ import numpy as np
 from fps_tpu.core import retry as _retry
 from fps_tpu.core import snapshot_format
 from fps_tpu.core.resilience import SnapshotCorruptionError, array_crc32
-from fps_tpu.core.store import ParamStore, id_to_phys, rows_per_shard
+from fps_tpu.core.store import (
+    ParamStore, id_to_phys, padded_rows, rows_per_shard,
+)
 
 Pytree = Any
 
@@ -137,6 +139,15 @@ def _table_arrays(store: ParamStore) -> dict[str, np.ndarray]:
     return {
         f"table{_SEP}{name}": store.dump_model(name)[1] for name in store.specs
     }
+
+
+def _phys_rows(store: ParamStore, name: str) -> np.ndarray:
+    """Physical row of every logical id of table ``name`` on ``store``'s
+    mesh (what lays a table-shaped array out, or reads it back)."""
+    n = store.specs[name].num_ids
+    return np.asarray(id_to_phys(
+        np.arange(n, dtype=np.int64), store.num_shards,
+        rows_per_shard(n, store.num_shards)))
 
 
 def export_model(store: ParamStore, path: str) -> None:
@@ -530,7 +541,13 @@ class Checkpointer:
                     from fps_tpu.parallel.mesh import replicate_to_mesh
 
                     arr = replicate_to_mesh(arr, store.mesh)
-                arrays[prefix + key[: -len(suffix)]] = np.asarray(arr)
+                name, arr = key[: -len(suffix)], np.asarray(arr)
+                if suffix == FOLD_KEY_SUFFIX and name in store.row_folds:
+                    # A table's own optimizer state is laid out like the
+                    # table: saved like it, logical id order, padding
+                    # stripped, so any shard count restores it.
+                    arr = arr[_phys_rows(store, name)]
+                arrays[prefix + name] = arr
         leaves, treedef = jax.tree.flatten(local_state)
         for i, leaf in enumerate(leaves):
             # Multi-controller: a worker-sharded leaf spans processes, and
@@ -1196,6 +1213,11 @@ class Checkpointer:
             if name not in store.specs:
                 continue
             arr = np.asarray(values_by_name[key], np.float32)
+            if name in store.row_folds:  # logical rows -> this mesh's layout
+                laid = np.zeros((padded_rows(len(arr), store.num_shards),)
+                                + arr.shape[1:], np.float32)
+                laid[_phys_rows(store, name)] = arr
+                arr = laid
             store.tables[name + FOLD_KEY_SUFFIX] = jax.device_put(
                 arr, store.sharding)
         # Dense parameters are state of their own too, replicated: a

@@ -65,6 +65,7 @@ from fps_tpu.core.store import (
     is_hot_key,
     lookup_hot_slots,
     map_key,
+    padded_rows,
     pull,
     pull_hot,
     push,
@@ -75,6 +76,7 @@ from fps_tpu.core.store import (
     split_dense,
     split_hot_push_slots,
     split_tiering,
+    watch_fold_rows,
     watch_routed,
     watch_sum_runs,
 )
@@ -367,6 +369,7 @@ class Trainer:
         self._replicated = NamedSharding(mesh, P())
         self._compiled = {}
         self._check_dense()
+        self._check_fold()
 
     # -- dense parameters (api.DenseLogic) --------------------------------
 
@@ -451,6 +454,62 @@ class Trainer:
                           zip(jnp.split(flat, edges), summed)]
             return {k: dense[k] - (lr * g).astype(dense[k].dtype)
                     for k, g in zip(names, summed)}
+
+    # -- a table's own stateful fold (ServerLogic.fold) -------------------
+
+    def _check_fold(self) -> None:
+        """Refuse, at construction, every mode that has no rule yet for
+        when a step folded by a table's own optimizer
+        (``ServerLogic.fold``) lands, and tell the store which tables'
+        ``::fold`` entries are laid out like the table (a snapshot saves
+        those in logical id order)."""
+        folds = self._row_fold_map()
+        self.store.row_folds = {
+            name: f.state_cols(self.store.specs[name].dim)
+            for name, f in folds.items()}
+        cfg = self.config
+        for name in sorted(folds):
+            sl, spec = self.server_logic[name], self.store.specs[name]
+            unsupported = {
+                "apply_fn (the fold is the table's apply)":
+                    sl.apply_fn is not None,
+                f"combine={sl.combine!r} (the fold takes the SUM of an "
+                "id's pushes)": sl.combine != "sum",
+                "hot_fold / TableSpec.hot_tier on the same table (the "
+                "tier's window would fold once where the table folds "
+                "every step)": sl.hot_fold is not None or spec.hot_tier,
+                "auto_tier (it may tier the table)": cfg.auto_tier,
+                "sync_every (SSP rounds: a round's snapshot holds no "
+                "state, and says nothing of a fold against stale rows)":
+                    cfg.sync_every,
+                "push_delay (a delayed push would fold against state "
+                "later pushes have already moved)": cfg.push_delay,
+            }
+            bad = [what for what, on in unsupported.items() if on]
+            if bad:
+                raise ValueError(
+                    f"table {name!r}: ServerLogic.fold="
+                    f"{folds[name].kind!r} is not supported with: "
+                    + "; ".join(bad))
+
+    def _row_fold_map(self) -> dict:
+        """{table: HotFold} for the tables that declare an optimizer of
+        their own (``ServerLogic.fold``). Part of the compile-cache key
+        via :meth:`_server_logic_key`."""
+        return {name: as_hot_fold(sl.fold)
+                for name, sl in sorted(self.server_logic.items())
+                if sl.fold is not None and name in self.store.specs}
+
+    def _fold_state_shape(self, name: str) -> tuple[int, int]:
+        """GLOBAL shape of a table's ``::fold`` entry: the hot fold's in
+        slice order, the table's own fold's the table's rows."""
+        spec = self.store.specs[name]
+        if name in self.store.row_folds:
+            return (padded_rows(spec.num_ids, self.num_shards),
+                    self.store.row_folds[name])
+        return tuple(hot_fold_state_shape(
+            self._hot_fold_map()[name], self._hot_tier_map()[name],
+            spec.dim, self.num_shards))
 
     # -- state ------------------------------------------------------------
 
@@ -946,8 +1005,10 @@ class Trainer:
         tier = self._hot_tier_map()
         mapped = self._mapped_tables()
         track = self._track_specs()
-        folds = self._hot_fold_map()
-        if not (tier or track) and not any(is_aux_key(k) for k in tables):
+        # Both kinds of ``::fold`` entry: the hot tier's and the table's own.
+        folds = {**self._hot_fold_map(), **self._row_fold_map()}
+        if not (tier or track or folds) and not any(
+                is_aux_key(k) for k in tables):
             return tables
         out = {}
         for k, v in tables.items():
@@ -973,12 +1034,8 @@ class Trainer:
                     out[k] = v
             elif k.endswith(FOLD_KEY_SUFFIX):
                 name = k[: -len(FOLD_KEY_SUFFIX)]
-                fold = folds.get(name)
-                if fold is not None and tuple(v.shape) == tuple(
-                        hot_fold_state_shape(
-                            fold, tier[name],
-                            self.store.specs[name].dim,
-                            self.num_shards)):
+                if name in folds and tuple(
+                        v.shape) == self._fold_state_shape(name):
                     out[k] = v  # live/restored state: keep (not derivable)
         missing_hot = [n for n in sorted(tier) if hot_key(n) not in out]
         missing_map = [n for n in sorted(mapped)
@@ -1016,15 +1073,18 @@ class Trainer:
                 out[sketch_key(name)] = jax.device_put(
                     np.asarray(win, np.float32), self._replicated)
             for name in missing_fold:
-                # Fresh (zero) optimizer state, SHARDED over the shard
+                # Fresh optimizer state (zero, or Adagrad's declared
+                # ``initial_accumulator``), SHARDED over the shard
                 # axis in reduce-scatter slice order; restored states
                 # arrive already in ``tables`` (checkpoint ``fold::``
-                # arrays) and were kept above.
-                shape = hot_fold_state_shape(
-                    folds[name], tier[name],
-                    self.store.specs[name].dim, self.num_shards)
-                out[fold_key(name)] = jax.device_put(
-                    np.zeros(shape, np.float32), self._table_sharding)
+                # arrays) and were kept above. Made on the device: a
+                # table's own state is the table's size.
+                shape = self._fold_state_shape(name)
+                start = folds[name].initial_accumulator
+                out[fold_key(name)] = jax.jit(
+                    lambda shape=shape, start=start: jnp.full(
+                        shape, start, jnp.float32),
+                    out_shardings=self._table_sharding)()
         return out
 
     def _enter_tiering(self) -> None:
@@ -1083,10 +1143,11 @@ class Trainer:
 
     def _apply_pushes_inner(self, tables, pushes, head_prefix):
         new_tables = {}
+        folds = self._row_fold_map()
         for name, (pids, pdeltas) in pushes.items():
             spec = self.store.specs[name]
             hot_local = self._resolve_hot_rows(spec)
-            new_tables[name] = push(
+            pushed = push(
                 tables[name],
                 pids,
                 pdeltas,
@@ -1099,8 +1160,26 @@ class Trainer:
                 dense=self._resolve_dense(spec),
                 head_prefix=head_prefix.get(name, 0),
                 table=name,
+                fold=folds.get(name),
+                fold_state=tables.get(fold_key(name)),
             )
+            if name in folds:
+                # The table's own optimizer state rides the step's tables
+                # under its ``::fold`` key (:meth:`_with_row_folds`).
+                new_tables[name], new_tables[fold_key(name)] = pushed
+            else:
+                new_tables[name] = pushed
         return new_tables
+
+    def _with_row_folds(self, tables, fstates):
+        """The state of the tables' own folds moved from ``fstates`` (what
+        ``split_tiering`` set apart) back beside their tables under the
+        ``::fold`` key, where a step's push reads and writes it; the hot
+        tier's states stay in ``fstates``."""
+        mine = self._row_fold_map()
+        return ({**tables, **{fold_key(n): v for n, v in fstates.items()
+                              if n in mine}},
+                {n: v for n, v in fstates.items() if n not in mine})
 
     def _compute_step(self, tables, snapshot, local_state, batch, key,
                       hot=None, tier=None, maps=None, track=None,
@@ -1643,43 +1722,48 @@ class Trainer:
         return dict(out, **{resilience.HOT_TIER_KEY: chan})
 
     @staticmethod
-    def _mount_sum_runs(out, summed):
-        """Attach what the step's ``push.sum_runs`` pushes counted
-        (``store.watch_sum_runs``: a table's pushes handed and kept, and
-        the distinct ids among them, a shard) to the worker out channel,
-        whose sum over the workers makes them the step's: plain leaves
-        ``sum_runs.<table>.<count>`` beside the worker's own (a consumer
+    def _mount_counts(out, noted):
+        """Attach what the step's pushes counted on the device
+        (``noted``: ``{channel: {table: {count: scalar}}}``, the channels
+        of ``resilience.COUNT_KEYS``: ``store.watch_sum_runs``, a table's
+        pushes handed and kept and the distinct ids among them, a shard,
+        on ``push.sum_runs``; ``store.watch_fold_rows``, the same on
+        ``push.fold_rows``) to the worker out channel, whose sum over the
+        workers makes them the step's: plain leaves
+        ``<channel>.<table>.<count>`` beside the worker's own (a consumer
         of per-step metrics sees arrays, no nested channel), the first
         data replica alone carrying them (a replica's shards are handed
         every replica's pushes). Nothing noted, nothing mounted: a program
-        without the route grows no leaf."""
-        if not summed:
-            return out
+        without the routes grows no leaf."""
         leaves = {
-            f"{resilience.SUM_RUNS_KEY}.{name}.{k}": v
-            for name, counts in sorted(summed.items())
+            f"{channel}.{name}.{k}": v
+            for channel, tables in noted.items()
+            for name, counts in sorted(tables.items())
             for k, v in counts.items()}
+        if not leaves:
+            return out
         if not isinstance(out, dict) or set(leaves) & set(out):
             raise TypeError(
-                "push.sum_runs' counts ride the worker's out channel: it "
+                "the pushes' counts ride the worker's out channel: it "
                 f"must be a dict without the keys {sorted(leaves)}")
         first = lax.axis_index(DATA_AXIS) == 0
         return dict(out, **{k: jnp.where(first, v, 0).astype(jnp.float32)
                             for k, v in leaves.items()})
 
     @staticmethod
-    def _sum_runs_channel(metrics) -> dict:
-        """``{table: {count: per-step values}}`` from the leaves
-        :meth:`_mount_sum_runs` put into a unit's metrics; ``{}`` where
-        there are none."""
-        chan: dict = {}
-        prefix = resilience.SUM_RUNS_KEY + "."
-        if isinstance(metrics, Mapping):
-            for key in metrics:
-                if isinstance(key, str) and key.startswith(prefix):
-                    table, _, count = key[len(prefix):].rpartition(".")
-                    chan.setdefault(table, {})[count] = metrics[key]
-        return chan
+    def _counts_channels(metrics) -> dict:
+        """``{channel: {table: {count: per-step values}}}`` from the
+        leaves :meth:`_mount_counts` put into a unit's metrics; ``{}``
+        where there are none."""
+        chans: dict = {}
+        for key in metrics if isinstance(metrics, Mapping) else ():
+            channel, _, rest = (key if isinstance(key, str)
+                                else "").partition(".")
+            if channel in resilience.COUNT_KEYS:
+                table, _, count = rest.rpartition(".")
+                chans.setdefault(channel, {}).setdefault(
+                    table, {})[count] = metrics[key]
+        return chans
 
     def _merge_sketches(self, sketches, sk):
         """End-of-call sketch merge: psum each tracked table's LOCAL
@@ -1705,7 +1789,7 @@ class Trainer:
         tier = self._hot_tier_map()
         mapped = self._mapped_tables()
         track = self._track_specs()
-        folds_on = self._hot_fold_map()
+        folds_on = {**self._hot_fold_map(), **self._row_fold_map()}
         compact = dict(compact or {})
         E = self.config.hot_sync_every
 
@@ -1715,6 +1799,7 @@ class Trainer:
             tables, dense = split_dense(tables)
             (tables, hot, maps, gids, sketches,
              fstates) = split_tiering(tables)
+            tables, fstates = self._with_row_folds(tables, fstates)
             delta = self._init_hot_deltas(tables, tier)
             # Sketch accumulators start at ZERO: each device folds only
             # its own ids, and the end-of-call psum merges exactly the
@@ -1736,7 +1821,8 @@ class Trainer:
                 key, sub = jax.random.split(key)
                 tapped = self._tap_step(tables, batch_t, local_state, t)
                 with watch_routed() as routed, \
-                        watch_sum_runs() as summed:
+                        watch_sum_runs() as summed, \
+                        watch_fold_rows() as folded:
                     (pushes, local_state, out, hp, hcounts,
                      sk, dense) = self._compute_step(
                         tables, snapshot, local_state, batch_t, sub,
@@ -1753,7 +1839,9 @@ class Trainer:
                             tables, bufs, t, pushes, hp)
                 out = self._mount_hot_channel(out, hcounts, delta, tier,
                                               dropped, routed)
-                out = self._mount_sum_runs(out, summed)
+                out = self._mount_counts(
+                    out, {resilience.SUM_RUNS_KEY: summed,
+                          resilience.FOLD_ROWS_KEY: folded})
                 with jax.named_scope("fps.metrics"):
                     out = jax.tree.map(
                         lambda x: lax.psum(lax.psum(x, SHARD_AXIS),
@@ -1855,7 +1943,8 @@ class Trainer:
         ``id()`` could be reused by a later callable after the original is
         garbage-collected, silently hitting a stale compiled program."""
         return tuple(
-            (name, sl.combine, sl.apply_fn, as_hot_fold(sl.hot_fold))
+            (name, sl.combine, sl.apply_fn, as_hot_fold(sl.hot_fold),
+             as_hot_fold(sl.fold))
             for name, sl in sorted(self.server_logic.items())
         )
 
@@ -2003,7 +2092,7 @@ class Trainer:
         tier = self._hot_tier_map()
         mapped = self._mapped_tables()
         track = self._track_specs()
-        folds_on = self._hot_fold_map()
+        folds_on = {**self._hot_fold_map(), **self._row_fold_map()}
         E = self.config.hot_sync_every
 
         def epoch_device(tables, local_state, iargs, start, key):
@@ -2012,6 +2101,7 @@ class Trainer:
             tables, dense = split_dense(tables)
             (tables, hot, maps, gids, sketches,
              fstates) = split_tiering(tables)
+            tables, fstates = self._with_row_folds(tables, fstates)
             delta = self._init_hot_deltas(tables, tier)
             sk0 = {name: jnp.zeros_like(sketches[name])
                    for name in sorted(track)}
@@ -2034,7 +2124,8 @@ class Trainer:
                     batch = plan.local_batch_at(iargs, widx, t)
                 tapped = self._tap_step(tables, batch, local_state, t)
                 with watch_routed() as routed, \
-                        watch_sum_runs() as summed:
+                        watch_sum_runs() as summed, \
+                        watch_fold_rows() as folded:
                     (pushes, local_state, out, hp, hcounts,
                      sk, dense) = self._compute_step(
                         tables, snapshot, local_state, batch, sub,
@@ -2051,7 +2142,9 @@ class Trainer:
                             tables, bufs, t, pushes, hp)
                 out = self._mount_hot_channel(out, hcounts, delta, tier,
                                               dropped, routed)
-                out = self._mount_sum_runs(out, summed)
+                out = self._mount_counts(
+                    out, {resilience.SUM_RUNS_KEY: summed,
+                          resilience.FOLD_ROWS_KEY: folded})
                 with jax.named_scope("fps.metrics"):
                     out = jax.tree.map(
                         lambda x: lax.psum(lax.psum(x, SHARD_AXIS),
@@ -2260,20 +2353,23 @@ class Trainer:
         return sums
 
     @staticmethod
-    def _record_sum_runs(rec, sr) -> dict:
-        """Fold one unit's HOST ``sum_runs`` channel (``{table: per-step
-        pushed_ids / live_ids}``) into the recorder
-        (``sum_runs.pushed_ids`` / ``sum_runs.live_ids``) and return the
-        unit's own sums a table, the journal's ``sum_runs`` field: of the
-        ``pushed_ids`` the additive pushes were handed and kept, their
-        scatters paid for ``live_ids``, the distinct ones a step."""
-        sums = {}
-        for table, counters in sr.items():
-            sums[table] = {k: float(np.sum(np.asarray(v), dtype=np.float64))
-                           for k, v in counters.items()}
-            for k, v in sums[table].items():
-                rec.inc(f"sum_runs.{k}", v, table=table)
-        return sums
+    def _record_counts(rec, chans) -> dict:
+        """Fold one unit's HOST count channels (:meth:`_counts_channels`)
+        into the recorder (``sum_runs.pushed_ids`` / ``sum_runs.live_ids``,
+        ``fold_rows.handed_ids`` / ``fold_rows.folded_ids``) and return
+        the unit's own sums a table, the journal's ``sum_runs`` /
+        ``fold_rows`` fields: of the ids the pushes were handed and kept,
+        the distinct ones a step, which are what they then paid for."""
+        fields = {}
+        for channel, tables in chans.items():
+            sums = fields[channel] = {}
+            for table, counters in tables.items():
+                sums[table] = {
+                    k: float(np.sum(np.asarray(v), dtype=np.float64))
+                    for k, v in counters.items()}
+                for k, v in sums[table].items():
+                    rec.inc(f"{channel}.{k}", v, table=table)
+        return fields
 
     def _record_tier_channel(self, rec, ht) -> dict:
         """Both folds of a unit's hot-tier channel, as the fields they
@@ -2293,12 +2389,13 @@ class Trainer:
         handed back for its ``device.*`` span, the journal's record of
         the epoch's completion (its ``epoch`` event is written at
         dispatch, before the numbers exist); likewise the counts of the
-        pushes on ``push.sum_runs`` (the span's ``sum_runs`` field).
-        ``None`` when the unit carries neither."""
+        pushes on ``push.sum_runs`` and ``push.fold_rows`` (the span's
+        ``sum_runs`` and ``fold_rows`` fields). ``None`` when the unit
+        carries none of them."""
         ht = (metrics.get(resilience.HOT_TIER_KEY)
               if isinstance(metrics, Mapping) else None)
-        sr = self._sum_runs_channel(metrics)
-        if not ht and not sr:
+        chans = self._counts_channels(metrics)
+        if not ht and not chans:
             return None
 
         def later(rec):
@@ -2306,9 +2403,8 @@ class Trainer:
             if ht:
                 fields.update(self._record_tier_channel(
                     rec, jax.tree.map(np.asarray, ht)))
-            if sr:
-                fields[resilience.SUM_RUNS_KEY] = self._record_sum_runs(
-                    rec, jax.tree.map(np.asarray, sr))
+            fields.update(self._record_counts(
+                rec, jax.tree.map(np.asarray, chans)))
             return fields
 
         return later
@@ -2327,11 +2423,11 @@ class Trainer:
             fields = self._record_tier_channel(rec, ht)
             if ev is not None:
                 ev.update(fields)
-        sr = self._sum_runs_channel(metrics)
-        if sr and rec is not None:
-            sums = self._record_sum_runs(rec, sr)
+        chans = self._counts_channels(metrics)
+        if chans and rec is not None:
+            fields = self._record_counts(rec, chans)
             if ev is not None:
-                ev[resilience.SUM_RUNS_KEY] = sums
+                ev.update(fields)
         if rec is not None:
             if poison:
                 rec.inc("health.poisoned_chunks")

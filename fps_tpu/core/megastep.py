@@ -144,6 +144,11 @@ def build_megastep_fn(trainer, plan, mode: str, K: int, tick=None):
             f"{sorted(trainer._dense_like)}; the megastep does not "
             "carry them (its segments thread tables and tier state only): "
             "drive run_indexed or fit_stream")
+    if trainer._row_fold_map():
+        raise ValueError(
+            f"tables {sorted(trainer._row_fold_map())} declare an "
+            "optimizer of their own (ServerLogic.fold); the megastep does "
+            "not carry its state: drive run_indexed or fit_stream")
     T = trainer._indexed_call_steps(plan)
     s = trainer.config.sync_every
     tier = trainer._hot_tier_map()
@@ -212,7 +217,8 @@ def build_megastep_fn(trainer, plan, mode: str, K: int, tick=None):
                         tables = trainer._apply_pushes(tables, pushes, hp)
                 out = trainer._mount_hot_channel(out, hcounts, delta,
                                                  tier, dropped, routed)
-                out = trainer._mount_sum_runs(out, summed)
+                out = trainer._mount_counts(
+                    out, {resilience.SUM_RUNS_KEY: summed})
                 with jax.named_scope("fps.metrics"):
                     out = jax.tree.map(_psum_workers, out)
                 out = trainer._mount_tap(out, tapped)

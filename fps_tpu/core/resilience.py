@@ -63,6 +63,12 @@ HOT_TIER_KEY = "hot_tier"
 # leaves ``sum_runs.<table>.pushed_ids`` / ``.live_ids``, summed over the
 # workers. Mounted by the driver only where a push took the route.
 SUM_RUNS_KEY = "sum_runs"
+# Likewise the pushes that folded the touched rows alone
+# (``push.fold_rows``, a table's own stateful fold): leaves
+# ``fold_rows.<table>.handed_ids`` / ``.folded_ids``.
+FOLD_ROWS_KEY = "fold_rows"
+# The step-count channels, in the order the driver mounts and records them.
+COUNT_KEYS = (SUM_RUNS_KEY, FOLD_ROWS_KEY)
 
 GUARD_MODES = ("observe", "mask")
 

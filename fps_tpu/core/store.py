@@ -833,7 +833,7 @@ def _note_routed(table: str, fits) -> None:
 # What the additive pushes that summed their rows by id first
 # (``push.sum_runs``) counted while a watch is open, by the table the
 # caller named: how a step's counts leave the step
-# (:meth:`fps_tpu.core.driver.Trainer._mount_sum_runs`).
+# (:meth:`fps_tpu.core.driver.Trainer._mount_counts`).
 _SUM_RUNS_WATCHES: list[dict] = []
 
 
@@ -846,12 +846,27 @@ def watch_sum_runs():
     return _watch(_SUM_RUNS_WATCHES)
 
 
-def _note_sum_runs(table: str, pushed, live) -> None:
-    if _SUM_RUNS_WATCHES and table:
-        counts = _SUM_RUNS_WATCHES[-1].setdefault(
-            table, {"pushed_ids": 0, "live_ids": 0})
-        counts["pushed_ids"] += pushed
-        counts["live_ids"] += live
+def _note_counts(watches: list[dict], table: str, **counts) -> None:
+    if watches and table:
+        seen = watches[-1].setdefault(table, dict.fromkeys(counts, 0))
+        for k, v in counts.items():
+            seen[k] += v
+
+
+# What the pushes that folded the touched rows alone (``push.fold_rows``)
+# counted while a watch is open, as ``push.sum_runs``' counts are kept:
+# how a step's counts leave the step
+# (:meth:`fps_tpu.core.driver.Trainer._mount_counts`).
+_FOLD_ROWS_WATCHES: list[dict] = []
+
+
+def watch_fold_rows():
+    """Collect ``{table: {"handed_ids", "folded_ids"}}`` while a step is
+    traced: for each table whose :func:`push` (called with ``table=``)
+    took ``push.fold_rows``, two int32 scalars of THIS shard: the pushes
+    it was handed and kept, and the distinct ids among them, each folded
+    once. Empty where no push took the route."""
+    return _watch(_FOLD_ROWS_WATCHES)
 
 
 def pull(
@@ -1263,6 +1278,33 @@ def _mean_push_route(rps: int, dim: int, dt, num_ids: int,
     return "mean_rows", ""
 
 
+def _fold_push_route(rps: int, dim: int, dt, num_ids: int) -> tuple[str, str]:
+    """Which body a push with the table's own stateful fold
+    (``ServerLogic.fold``) takes, from the shapes its exchange scatters, as
+    :func:`_mean_push_route` chooses for a mean: ``("fold_rows", "")``,
+    the SPARSE body (the pushed rows summed by id in a payload-sized
+    buffer, the distinct ids' state gathered, the fold applied on those
+    rows, the table's rows added to and the state's rows written, nothing
+    table-sized anywhere), or ``("fold", "small_table")``, the ``(rows,
+    dim + 1)`` accumulator and the fold over the whole shard, whose
+    passes cost less than the sparse body's sorts and row operations
+    while the table is small against the payload
+    (:data:`fps_tpu.ops.MEAN_ROWS_TABLE_RATIO`, the mean's edge: the
+    fold's own is not measured)."""
+    if _mean_push_ratio(rps, dim, num_ids, dt) < ops.MEAN_ROWS_TABLE_RATIO:
+        return "fold", "small_table"
+    return "fold_rows", ""
+
+
+# Device scope of what the sparse body of a table's own stateful fold
+# (``push.fold_rows``) does AFTER the pushed rows are summed by id (that
+# is ``fps.combine``'s, as on ``push.mean_rows``): the gather of the
+# distinct ids' state, the fold on those rows, the add to the table's rows
+# and the write of the state's, all payload-sized; the routed row
+# operations keep their ``fps.ops/<route>`` names INSIDE it.
+FOLD_ROWS_SCOPE = "fps.fold_rows"
+
+
 def _gathered_exchange(ids: Array, deltas: Array, *, rps: int,
                        num_shards: int, shard_axis: str,
                        data_axis: str | None) -> tuple[Array, Array, Array]:
@@ -1371,7 +1413,9 @@ def push(
     dense: bool = False,
     head_prefix: int = 0,
     table: str = "",
-) -> Array:
+    fold=None,
+    fold_state: Array | None = None,
+) -> Array | tuple[Array, Array]:
     """Scatter-add ``deltas`` for ``ids`` into the sharded table.
 
     Args:
@@ -1464,6 +1508,24 @@ def push(
         one device nothing is exchanged and ``dense`` changes nothing.
       table: the table's name, for the route log and the step's
         ``routed`` flag (:func:`watch_routed`); nothing else reads it.
+      fold, fold_state: the table's own stateful fold
+        (:class:`fps_tpu.core.api.HotFold`, ``ServerLogic.fold``) and this
+        shard's ``(rps, fold.state_cols(dim))`` block of its state; the
+        push then returns ``(new block, new state)``. Under ``"sum"``
+        with no ``apply_fn``: every id pushed takes ONE
+        :func:`apply_hot_fold` step on the sum of its pushes over every
+        worker, a row nobody pushed keeps its value and its state bit for
+        bit. Two bodies, chosen from the shapes (:func:`_fold_push_route`)
+        and logged: ``push.fold_rows``, the sparse one: the handed rows
+        summed by id from zero in a ``(B, dim)`` buffer (:func:`_id_runs`,
+        under ``fps.combine`` as on ``push.mean_rows``), then under
+        ``fps.fold_rows`` the distinct ids' state rows gathered, the fold
+        on those ``B`` rows, the steps scatter-added into the table and
+        the new state rows written (:func:`fps_tpu.ops.scatter_set`),
+        both told the ids are sorted and the dropped last; or
+        ``push.fold`` (reason ``small_table``), the accumulator body
+        with :func:`apply_hot_fold` over the whole shard, which the dense
+        exchange may fill. No conditional takes the table or the state.
 
     Every push that is not ``dense``, over more than one shard with no
     data axis, takes the OWNER-ROUTED exchange (:func:`_routes_to_owner`;
@@ -1481,9 +1543,12 @@ def push(
     """
     if not callable(combine) and combine not in ("sum", "mean", "max", "min"):
         raise ValueError(f"unknown combine mode {combine!r}")
+    if fold is not None and (apply_fn is not None or combine != "sum"):
+        raise ValueError("a table's own fold sums an id's pushes "
+                         "(combine='sum') and takes no apply_fn")
     rps, dim = local_shard.shape
     B = ids.shape[0]
-    additive = apply_fn is None and combine == "sum"
+    additive = apply_fn is None and combine == "sum" and fold is None
     # Accumulate in at least f32, but never BELOW the table's own precision:
     # a float64 table must fold its duplicates in float64 (hard-coding f32
     # here would silently shave 29 mantissa bits off every non-"sum" push).
@@ -1500,7 +1565,9 @@ def push(
     dense = (dense and workers > 1 and combine not in ("max", "min")
              and (combine != "mean" or _mean_push_route(
                  rps * num_shards, dim, local_shard.dtype, B, apply_fn)[0]
-                 == "mean_dense"))
+                 == "mean_dense")
+             and (fold is None or _fold_push_route(
+                 rps * num_shards, dim, local_shard.dtype, B)[0] == "fold"))
 
     def summed(local_idx, rows, live, acc_rows, asked, *, raw=None,
                owned=None, pad_to=0):
@@ -1517,12 +1584,13 @@ def push(
         lengthened by dropped ids over zero rows to that many. ``asked``:
         the handed pushes the mean's branch is chosen for."""
         B = local_idx.shape[0]
-        if combine == "mean":
-            route, reason = _mean_push_route(acc_rows, dim,
-                                             local_shard.dtype, asked,
-                                             apply_fn)
+        if combine == "mean" or fold is not None:
+            route, reason = (
+                _fold_push_route(acc_rows, dim, local_shard.dtype, asked)
+                if fold is not None else _mean_push_route(
+                    acc_rows, dim, local_shard.dtype, asked, apply_fn))
             ops.log_route("push", route, acc_rows, dim, B, reason)
-            if route == "mean_rows":
+            if route in ("mean_rows", "fold_rows"):
                 # The cost follows the payload: every pushed row is scaled
                 # by 1 / (pushes of its id) and the rows of one id are
                 # summed FROM ZERO in a (B, dim) buffer,
@@ -1532,9 +1600,12 @@ def push(
                 # table rounds each of a hot id's hundreds of small
                 # addends at the TABLE value's magnitude: 12-20x the
                 # accumulator's gap to a float64 mean (chip run, PR 28).
+                # The fold's sparse body sums the same way, unscaled, and
+                # hands on how many pushes it kept (its counter's base).
                 with jax.named_scope(COMBINE_SCOPE):
                     n, slot, slot_idx = _id_runs(local_idx, rps)
-                    scaled = rows * (1.0 / n.astype(acc_dt))[:, None]
+                    scaled = rows if fold is not None else rows * (
+                        1.0 / n.astype(acc_dt))[:, None]
                     combined = jnp.zeros((B, dim), acc_dt).at[slot].add(
                         scaled)
                     if pad_to > B:
@@ -1542,6 +1613,9 @@ def push(
                             (pad_to - B,), rps, slot_idx.dtype)])
                         combined = jnp.concatenate([combined, jnp.zeros(
                             (pad_to - B, dim), acc_dt)])
+                if fold is not None:
+                    return slot_idx, combined, jnp.sum(live > 0,
+                                                       dtype=jnp.int32)
                 return slot_idx, combined
         if combine in ("max", "min"):
             # Extremum fold: ONE scatter-max/min of the raw deltas
@@ -1573,10 +1647,11 @@ def push(
         if apply_fn is not None and combine != "mean":
             # A stateful fold under "sum" (or a callable combine): the
             # (rows, dim + 1) accumulator, apply_fn over the whole shard
-            # and a table-sized where (a mean push logged its own branch).
+            # and a table-sized where (a mean push logged its own branch,
+            # and so did a table's own fold).
             ops.log_route("push", "fold", acc_rows, dim, B, "apply_fn")
-        why = ("mean_dense" if combine == "mean"
-               else "fold" if apply_fn is not None else "callable")
+        why = ("mean_dense" if combine == "mean" else "fold"
+               if apply_fn is not None or fold is not None else "callable")
         if dense:
             # The accumulator is filled by the dense exchange: this
             # worker's own B rows into all the table's rows, then the
@@ -1609,6 +1684,22 @@ def push(
         """The second half: :func:`summed`'s result applied to the shard
         (``exchange`` where the dense one still has the accumulator to
         trade)."""
+        if len(summed) == 3:
+            # ``push.fold_rows``: the distinct ids sorted, their pushes'
+            # sums beside them, the drop sentinel over zero rows after the
+            # last; state and table are read and written at those ids.
+            slot_idx, combined, kept = summed
+            with jax.named_scope(FOLD_ROWS_SCOPE):
+                touched = slot_idx < rps
+                _note_counts(_FOLD_ROWS_WATCHES, table, handed_ids=kept,
+                             folded_ids=jnp.sum(touched, dtype=jnp.int32))
+                step, rows = apply_hot_fold(
+                    fold, ops.gather_rows(fold_state, slot_idx), combined,
+                    touched.astype(acc_dt))
+                return (ops.scatter_add(local_shard, slot_idx, step,
+                                        ids_sorted=True),
+                        ops.scatter_set(fold_state, slot_idx, rows,
+                                        ids_sorted=True))
         if len(summed) == 2:
             # ``slot_idx`` is the distinct ids in their sorted order, the
             # rows of ``combined`` beside them, and past the last of them
@@ -1637,6 +1728,12 @@ def push(
                         (counts > 0)[:, None], combine(combined, counts), 0.0
                     )
         with jax.named_scope(COMBINE_SCOPE):
+            if fold is not None:
+                # The table's own fold over the whole shard: an untouched
+                # row takes a zero step and keeps its state.
+                step, state = apply_hot_fold(fold, fold_state, combined,
+                                             counts)
+                return local_shard + step.astype(local_shard.dtype), state
             if apply_fn is None:
                 # Additive fold: untouched rows receive exactly zero, so
                 # no mask is needed (a full-table where() is a measurable
@@ -1701,7 +1798,8 @@ def push(
         :func:`summed`'s result folded."""
         if sum_runs:
             *runs, long_ids, pushed, live = out
-            _note_sum_runs(table, pushed, live)
+            _note_counts(_SUM_RUNS_WATCHES, table, pushed_ids=pushed,
+                         live_ids=live)
             with jax.named_scope(COMBINE_SCOPE):
                 # The long runs' rows as they stand (a buffer's worth; ids
                 # past the last are clipped and their rows unused).
@@ -1886,6 +1984,11 @@ class ParamStore:
         self.num_shards = mesh.shape[SHARD_AXIS]
         self.sharding = NamedSharding(mesh, P(SHARD_AXIS, None))
         self.tables: dict[str, Array] = {}
+        # {table: state columns} of the tables whose ``::fold`` entry is
+        # their OWN optimizer's state, laid out like the table (set by the
+        # Trainer from ``ServerLogic.fold``; a snapshot saves those in
+        # logical id order).
+        self.row_folds: dict[str, int] = {}
         self._head_replica_fns: dict = {}  # (name, hot_rows) -> jitted gather
         self._rows_replica_fns: dict = {}  # (name, nrows) -> jitted gather
 

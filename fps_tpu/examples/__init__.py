@@ -10,6 +10,7 @@ algorithm, launched with ``flink run``). Here each module is runnable as
     python -m fps_tpu.examples.word2vec --dim 100 --negatives 5 ...
     python -m fps_tpu.examples.logreg_ssp --sync-every 8 ...
     python -m fps_tpu.examples.ials --rank 16 --alpha 40 ...
+    python -m fps_tpu.examples.kge --rank 32 --negatives 10 ...
 
 Every entrypoint falls back to a synthetic dataset with matched statistics
 when no input path is given (this environment has no network egress), prints

@@ -240,6 +240,16 @@ def default_registry() -> MetricsRegistry:
                    labels=("table",),
                    help="of sum_runs.pushed_ids, the distinct ids a step: "
                         "what the scatter into the table then pays for"),
+        # A table's own stateful fold on the touched rows alone (store.push,
+        # ``push.fold_rows``): counted on the device like ``sum_runs``.
+        MetricSpec("fold_rows.handed_ids", "counter", unit="ids",
+                   labels=("table",),
+                   help="pushes the folds on push.fold_rows were handed "
+                        "and kept (every shard together)"),
+        MetricSpec("fold_rows.folded_ids", "counter", unit="ids",
+                   labels=("table",),
+                   help="of fold_rows.handed_ids, the distinct ids a step: "
+                        "each folded once, its state read and written"),
         # Adaptive tiering (fps_tpu.tiering; docs/performance.md
         # "Adaptive tiering"): online hot-set re-ranking + auto-planner.
         MetricSpec("tiering.re_ranks", "counter", unit="re_ranks",

@@ -127,7 +127,10 @@ ROUND_SCOPES = ("ssp.snapshot", "hot.reconcile")
 INNER_SCOPES = ("topk.score", "topk.select", "topk.merge",
                 # models/dlrm.py, inside fps.compute: the step's three
                 # parts, each part's backward ops under the part's name
-                "dlrm.bottom", "dlrm.interact", "dlrm.top")
+                "dlrm.bottom", "dlrm.interact", "dlrm.top",
+                # models/kge.py, inside fps.compute: the scoring of a
+                # step's triples and its backward
+                "kge.score")
 # Set-up spans (no timer: they report through the process-default
 # recorder). Those that queue device work close on its completion when a
 # recorder is installed, and only then (settle()).

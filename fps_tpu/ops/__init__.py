@@ -65,6 +65,9 @@ _BACKEND = os.environ.get("FPS_TPU_OPS", "auto").lower()
 ROUTES = {
     "gather": ("dim1_head", "dim1", "xla_packed", "xla"),
     "scatter_add": ("dim1_head", "dim1", "xla_packed", "xla_sorted", "xla"),
+    # :func:`scatter_set`, a row's NEW value written to distinct ids (the
+    # state of a table's own stateful fold, ``push.fold_rows``).
+    "scatter_set": ("xla_sorted", "xla"),
 }
 PALLAS_ROUTES = frozenset(
     f"{op}.{r}" for op, rs in ROUTES.items() for r in rs
@@ -816,18 +819,20 @@ def _xla_packed_scatter_add(table: Array, ids: Array,
         return _xla_unpack(packed, R, D)
 
 
-def _xla_sorted_scatter_add(table: Array, ids: Array,
-                            deltas: Array) -> Array:
+def _xla_sorted_scatter_add(table: Array, ids: Array, deltas: Array,
+                            op: str = "scatter_add") -> Array:
     """``scatter_add.xla_sorted``: the plain XLA scatter-add, a block of
     :data:`XLA_SORTED_BLOCK_IDS` ids at a time, in place on the table, for
     as many blocks as hold a live id. The guarantee puts the ids to drop
     last, so the blocks past ``ceil(live / block)`` hold nothing but them.
     The last block starts early enough to end with the batch; the ids it
     shares with the block before are dropped from it. Duplicates add in
-    the batch's order, as on the plain route."""
+    the batch's order, as on the plain route. With ``op="scatter_set"``
+    the same loop round the plain XLA scatter that WRITES the rows
+    (``scatter_set.xla_sorted``)."""
     R, D = table.shape
     B, C = ids.shape[0], XLA_SORTED_BLOCK_IDS
-    with _routed("scatter_add", "xla_sorted", R, D, B):
+    with _routed(op, "xla_sorted", R, D, B):
         safe = jnp.where((ids >= 0) & (ids < R), ids, R)
         deltas = deltas.astype(table.dtype)
         live = jnp.sum((safe < R).astype(jnp.int32))
@@ -837,6 +842,8 @@ def _xla_sorted_scatter_add(table: Array, ids: Array,
             i = jax.lax.dynamic_slice(safe, (start,), (C,))
             i = jnp.where(start + jnp.arange(C) >= c * C, i, R)
             d = jax.lax.dynamic_slice(deltas, (start, 0), (C, D))
+            if op == "scatter_set":
+                return t.at[i].set(d, mode="drop")
             return t.at[i].add(d, mode="drop")
 
         return jax.lax.fori_loop(0, (live + C - 1) // C, block, table)
@@ -981,3 +988,24 @@ def scatter_add(
         keep = (ids >= 0) & (ids < R)
         safe = jnp.where(keep, ids, R)
         return table.at[safe].add(deltas.astype(table.dtype), mode="drop")
+
+
+def scatter_set(table: Array, ids: Array, rows: Array, *,
+                ids_sorted: bool = False) -> Array:
+    """``table.at[ids].set(rows)`` for DISTINCT ids; ids outside ``[0,
+    rows)`` are dropped (any number of them: they write nothing), every
+    row not named keeps its bits. What a stateful fold writes back: a
+    row's new value, not a sum into it. ``ids_sorted`` is
+    :func:`scatter_add`'s guarantee and opens the same block loop
+    (``scatter_set.xla_sorted``: :func:`_route_xla_sorted`), which never
+    looks past the last live id; everywhere else the plain XLA scatter
+    (``scatter_set.xla``). Two in-range ids that are equal leave either
+    of their rows."""
+    R, D = table.shape
+    B = ids.shape[0]
+    if _route_xla_sorted(R, D, B, table.dtype, ids_sorted):
+        return _xla_sorted_scatter_add(table, ids, rows, op="scatter_set")
+    with _routed("scatter_set", "xla", R, D, B,
+                 _xla_reason(R, D, table.dtype)):
+        safe = jnp.where((ids >= 0) & (ids < R), ids, R)
+        return table.at[safe].set(rows.astype(table.dtype), mode="drop")

@@ -566,6 +566,37 @@ def synthetic_click_fields(
     }
 
 
+def synthetic_triples(
+    num_triples: int,
+    num_entities: int,
+    num_relations: int,
+    *,
+    clusters: int = 16,
+    seed: int = 0,
+):
+    """Knowledge-graph triples ``(s, r, o)`` with planted structure: entity
+    ``e`` lies in cluster ``e % clusters``, and relation ``r`` links a
+    subject of cluster ``c`` to an object of cluster ``(c + r + 1) %
+    clusters``; subjects Zipf-like (rank = entity id), objects uniform
+    within their cluster. A held-out triple then shares its clusters'
+    pattern with the training triples, which is what a link predictor has
+    to learn.
+
+    Returns dict with ``s``, ``r``, ``o`` int32 ``(N,)``.
+    """
+    rng = np.random.default_rng(seed)
+    e = 1.0 - 1.05
+    top = np.power(num_entities + 1.0, e) - 1.0
+    s = np.clip(np.floor(np.power(top * rng.random(num_triples) + 1.0,
+                                  1.0 / e)) - 1, 0, num_entities - 1)
+    s = s.astype(np.int64)
+    r = rng.integers(0, num_relations, num_triples)
+    per = num_entities // clusters
+    o = (s + r + 1) % clusters + clusters * rng.integers(0, per, num_triples)
+    return {"s": s.astype(np.int32), "r": r.astype(np.int32),
+            "o": o.astype(np.int32)}
+
+
 def head_sort_slots(data: dict, head_features: int):
     """Reorder each example's nnz slots so frequency-head ids come first.
 

@@ -125,9 +125,10 @@ def test_committed_benchmark_lists_the_four_metrics_in_the_cells_named():
                and listed[n]["moves"] == "examples_per_s" for n in NEW)
     # Cells of later PRs are appended (PR 42: the top-K tap's, a Trainer's).
     # PR 44: word2vec under the two-tier storage, a Trainer's too.
-    # PR 48: DLRM, a Trainer's with the dense route.
+    # PR 48: DLRM, a Trainer's with the dense route. PR 51: ComplEx, a
+    # Trainer's with the table's own fold.
     later = ["mf-netflix-topk.epochs", "w2v-1bw-hot.x4",
-             "dlrm-criteo.epochs"]
+             "dlrm-criteo.epochs", "kge-wikidata5m.epochs"]
     assert listed["driver.call_device_ms"]["workloads"] == (
         TRAINER_CELLS + later)
     assert listed["driver.starved_share"]["workloads"] == TRAINER_CELLS + [

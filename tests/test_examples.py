@@ -222,3 +222,38 @@ def test_logreg_real_input_criteo(devices8, capsys, tmp_path):
         capsys,
     )
     assert ev["done"][0]["test_accuracy"] > 0.8
+
+
+def test_kge_entrypoint(devices8, capsys, tmp_path):
+    """ComplEx on the 8-device mesh, both tables under the table's own
+    AdaGrad fold: held-out triples outrank a corruption, and the snapshots
+    carry the optimizer state beside the tables."""
+    import numpy as np
+
+    from fps_tpu.examples import kge
+
+    ckdir = tmp_path / "ck"
+    ev = run_main(
+        kge,
+        ["--num-triples", "40000", "--num-entities", "2048", "--rank", "8",
+         "--epochs", "3", "--local-batch", "64",
+         "--checkpoint-dir", str(ckdir), "--checkpoint-every", "1"],
+        capsys,
+    )
+    assert ev["start"][0]["row_floats"] == 16
+    assert ev["done"][0]["pairwise_accuracy"] > 0.8
+    losses = [c["loss"] for c in ev["chunk"]]
+    assert len(losses) == 3 and losses[-1] < losses[0]
+    snaps = sorted(ckdir.glob("ckpt_*.npz"))
+    assert snaps, "no checkpoints written despite --checkpoint-dir"
+    with np.load(snaps[-1]) as z:
+        assert z["fold::entity"].shape == z["table::entity"].shape == (2048,
+                                                                       16)
+        assert np.any(z["fold::entity"] > 0)
+
+
+def test_kge_entrypoint_refuses_ssp(devices8):
+    from fps_tpu.examples import kge
+
+    with pytest.raises(SystemExit, match="sync-every"):
+        kge.main(["--sync-every", "4"])

@@ -860,7 +860,38 @@ _CELL_ROW_OPS = [
      "scatter_add.xla_sorted", ""),
     ("scatter_add_sorted", 1_115_011, 300, 49_182,
      "scatter_add.xla_sorted", ""),
+    # kge-wikidata5m.epochs (PR 51): the pull of 4 KB rows, and the table's
+    # own fold on its sparse body: the state's gather, the table's
+    # scatter-add and the state's scatter, ids sorted; the 822-row relation
+    # table keeps the accumulator.
+    ("gather", 393_216, 1000, 49_152, "gather.xla", "shape"),
+    ("gather", 822, 1000, 4_096, "gather.xla", "shape"),
+    ("scatter_add_sorted", 393_216, 1000, 49_152,
+     "scatter_add.xla_sorted", ""),
+    ("scatter_set_sorted", 393_216, 1000, 49_152,
+     "scatter_set.xla_sorted", ""),
+    ("scatter_add", 822, 1001, 4_096, "scatter_add.xla", "shape"),
 ]
+
+# ``scatter_set`` shares the sorted route's predicate; everywhere else the
+# plain XLA scatter: (rows, width, ids, ids_sorted) -> route.
+_SCATTER_SET_CASES = [
+    (393_216, 1000, 49_152, True, "xla_sorted"),
+    (393_216, 1000, 49_152, False, "xla"),       # no guarantee given
+    (393_216, 1000, 65_536, True, "xla"),        # under 8 rows an id
+    (16_384, 1000, 2_048, True, "xla"),          # inside XLA's VMEM regime
+]
+
+
+@pytest.mark.parametrize("R,D,B,ids_sorted,route", _SCATTER_SET_CASES)
+def test_scatter_set_takes_the_sorted_route_by_its_predicate(
+        as_on_tpu, R, D, B, ids_sorted, route):
+    f32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32)  # noqa: E731
+    ops.clear_routes()
+    jax.eval_shape(lambda t, i, d: ops.scatter_set(
+        t, i, d, ids_sorted=ids_sorted), f32(R, D),
+        jax.ShapeDtypeStruct((B,), jnp.int32), f32(B, D))
+    assert [r.route for r in ops.routes_traced()] == ["scatter_set." + route]
 
 
 @pytest.mark.parametrize("op,R,D,B,route,reason", _CELL_ROW_OPS)
@@ -872,7 +903,9 @@ def test_route_of_every_row_op_of_the_benchmarks_cells(as_on_tpu, op, R, D,
     if op == "gather":
         jax.eval_shape(lambda t, i: ops.gather_rows(t, i), f32(R, D), ids)
     else:
-        jax.eval_shape(lambda t, i, d: ops.scatter_add(
+        scatter = (ops.scatter_set if op.startswith("scatter_set")
+                   else ops.scatter_add)
+        jax.eval_shape(lambda t, i, d: scatter(
             t, i, d, ids_sorted=op.endswith("_sorted")), f32(R, D), ids,
             f32(B, D))
     assert ops.routes_traced() == [
@@ -888,7 +921,8 @@ def test_every_declared_route_is_a_cells_or_a_swept_predicates():
              "dim1_head": {taken for *_, taken in _HEAD_PREFIX_CASES},
              "xla_packed": {taken for *_, taken, _ in _XLA_PACKED_CASES},
              "xla_sorted": {route == "xla_sorted"
-                            for *_, route, _ in _XLA_SORTED_CASES}}
+                            for *_, route, _ in _XLA_SORTED_CASES},
+             "xla": {route == "xla" for *_, route in _SCATTER_SET_CASES}}
     assert all(sides == {True, False} for sides in swept.values()), swept
     for op, declared in ops.ROUTES.items():
         of_cells = {route.split(".", 1)[1] for o, *_, route, _

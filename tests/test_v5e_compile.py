@@ -1072,3 +1072,88 @@ def test_dlrm_push_sums_its_runs_and_scatters_by_blocks_in_place(
     payload = B * D * 4
     assert memory.temp_size_in_bytes < (256 << 20) + 4 * payload
     assert memory.alias_size_in_bytes >= R * D * 4      # donated, in place
+
+
+def test_kge_step_folds_the_pushed_rows_alone_in_place(topo, monkeypatch):
+    """``kge-wikidata5m.epochs``'s step at the cell's own size (``entity``
+    ``[393216, 1000]`` and its AdaGrad state of the same shape, 4,096
+    positives and 10 corruptions each a step: 49,152 entity ids) for one
+    described chip, through the trainer's chunk program. The entity table
+    takes the table's own fold on its SPARSE body (``push.fold_rows``):
+    the step's one gather of the state and its two writes (the table's
+    scatter-add and the state's scatter, each a block of ids at a time in
+    the sorted route's loop, in place on the donated carry) are the only
+    ops whose result has the table's or the state's shape; no ``[rows,
+    1001]`` accumulator, no copy, fill or select of either, and no
+    conditional takes either in. The 822-row relation table keeps the
+    accumulator body (``push.fold``, ``small_table``). ONCE A CALL, in the
+    entry computation, XLA relays both out: it takes the parameters
+    column-major (``{0,1}``: 1,000 is no multiple of 128 lanes, 393,216
+    is) and carries them row-major through the loop, a copy each way of
+    each, which is the 3.15 GB of the temporaries that is no step's."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from fps_tpu.core.store import fold_key
+    from fps_tpu.models.kge import KGEConfig, kge
+    from fps_tpu.parallel.mesh import make_ps_mesh
+
+    monkeypatch.setattr(ops, "_use_pallas", lambda: (True, False))
+    E, R, K, B, N = 393_216, 822, 500, 4_096, 10
+    D, ids = 2 * K, B * (2 + N)
+    mesh = make_ps_mesh(num_shards=1, devices=list(topo.devices)[:1])
+    trainer, _ = kge(mesh, KGEConfig(num_entities=E, num_relations=R,
+                                     rank=K, negatives=N))
+
+    def shape(s, dtype, spec=P()):
+        return jax.ShapeDtypeStruct(s, dtype,
+                                    sharding=NamedSharding(mesh, spec))
+
+    rows = P("shard", None)
+    tables = {"entity": shape((E, D), jnp.float32, rows),
+              fold_key("entity"): shape((E, D), jnp.float32, rows),
+              "relation": shape((R, D), jnp.float32, rows),
+              fold_key("relation"): shape((R, D), jnp.float32, rows)}
+    workers = P(None, ("data", "shard"))
+    batches = {k: shape((2, B), d, workers) for k, d in (
+        ("s", jnp.int32), ("r", jnp.int32), ("o", jnp.int32),
+        ("weight", jnp.float32))}
+    ops.clear_routes()
+    compiled = trainer._build_chunk_fn("sync").lower(
+        tables, (), batches, shape((), jax.random.key(0).dtype)).compile()
+    assert [(r.route, r.rows, r.dim, r.ids, r.reason)
+            for r in ops.routes_traced()] == [
+        ("gather.xla", E, D, ids, "shape"),
+        ("gather.xla", R, D, B, "shape"),
+        ("push.fold_rows", E, D, ids, ""),
+        ("gather.xla", E, D, ids, "shape"),
+        ("scatter_add.xla_sorted", E, D, ids, ""),
+        ("scatter_set.xla_sorted", E, D, ids, ""),
+        ("push.fold", R, D, B, "small_table"),
+        ("scatter_add.xla", R, D + 1, B, "shape")]
+    text = compiled.as_text()
+    table = f"f32[{E},{D}]"
+    assert f"f32[{E},{D + 1}]" not in text
+    # Every computation but the entry: the step's loop body and the
+    # bodies of the sorted route's own loops inside it.
+    sized = [ln for ln in _top_level(text)
+             if (m := re.search(r"= " + re.escape(table) + r"\S* ([\w\-]+)\(",
+                                ln))
+             and m.group(1) not in ("get-tuple-element", "parameter")]
+    assert len(sized) == 2 and all(
+        "/fps.push/fps.fold_rows/fps.ops/" in ln and "/while/body/" in ln
+        and " fusion(" in ln for ln in sized), sized
+    assert [("scatter_add.xla_sorted" in ln, "scatter_set.xla_sorted" in ln)
+            for ln in sorted(sized, key=lambda ln: "scatter_set" in ln)] == [
+        (True, False), (False, True)]
+    assert not [ln for ln in _top_level(text) if re.search(
+        r"= " + re.escape(table) + r"\S* (copy|transpose|select|broadcast)\(",
+        ln)]
+    relayouts = re.findall(
+        r"= " + re.escape(table) + r"(\{[01],[01])\S* copy\(", text)
+    assert sorted(relayouts) == ["{0,1", "{0,1", "{1,0", "{1,0"], relayouts
+    assert not [ln for ln in text.splitlines()
+                if " conditional(" in ln and table in ln]
+    memory = compiled.memory_analysis()
+    payload, both = ids * D * 4, 2 * E * D * 4  # a step's rows: 197 MB
+    assert memory.temp_size_in_bytes < both + 12 * payload
+    assert memory.alias_size_in_bytes >= both   # donated, in place

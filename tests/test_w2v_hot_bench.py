@@ -117,7 +117,7 @@ def test_spec_validates_the_committed_benchmark_files():
     bench = spec.load_benchmark()
     spec.validate(bench)
     cells = bench["workloads"]
-    assert len(cells) == 9 and sorted(
+    assert len(cells) == 10 and sorted(
         w["name"] for w in cells if w["chips"] == 4) == [
             "mf-netflix.x4", CELL]
     cell = spec.load_cell(bench, CELL)

@@ -11,8 +11,8 @@ the benchmark at the cell's own shapes (``mf-netflix.epochs``,
 ``lr-criteo.epochs`` and, since PR 36, both accumulate programs of
 ``ials-ml20m.sweeps``, the ones that push; since PR 45
 ``mf-netflix-topk.epochs`` and ``w2v-1bw-hot.x4``; since PR 48
-``dlrm-criteo.epochs``, which a tree from before it skips; all of them, or
-the cells named), compiles it for a described
+``dlrm-criteo.epochs`` and since PR 51 ``kge-wikidata5m.epochs``, each of
+which a tree from before it skips; all of them, or the cells named), compiles it for a described
 ``v5e:2x2`` with the ops layer routing as on the chip, and writes the
 compiled text with metadata,
 stack frames and location tables dropped, and the route log, under
@@ -40,7 +40,7 @@ V5E_HBM_BYTES = 16_909_336_064
 CELLS = ("mf-netflix.epochs", "pa-rcv1.epochs", "mf-netflix.x4",
          "w2v-1bw.epochs", "lr-criteo.epochs", "ials-ml20m.sweeps.user",
          "ials-ml20m.sweeps.item", "mf-netflix-topk.epochs",
-         "w2v-1bw-hot.x4", "dlrm-criteo.epochs")
+         "w2v-1bw-hot.x4", "dlrm-criteo.epochs", "kge-wikidata5m.epochs")
 
 
 def _normalised(text: str) -> str:
@@ -331,9 +331,36 @@ def write(tree: str, out: str, cells=CELLS) -> None:
                      table(other, K), table(n, K), table(n, K * K),
                      table(n, K), chunk))
 
+    def kge_cell():
+        try:
+            from fps_tpu.models.kge import KGEConfig, kge
+        except ImportError:  # a tree from before PR 51
+            print("kge-wikidata5m.epochs: no fps_tpu.models.kge in this "
+                  "tree", flush=True)
+            return
+        m = model("kge-wikidata5m")
+        E, R, D, B = m["entities"], m["relations"], 2 * m["rank"], m[
+            "local_batch"]
+        mesh, shape = mesh_of(1)
+        trainer, _ = kge(mesh, KGEConfig(
+            num_entities=E, num_relations=R, rank=m["rank"],
+            negatives=m["negatives"], l2=m["l2"],
+            learning_rate=m["learning_rate"], eps=m["eps"]))
+        # Both tables beside their own fold's state (``<table>::fold``).
+        tables = {name + key: shape((rows, D), jnp.float32, P("shard", None))
+                  for name, rows in (("entity", E), ("relation", R))
+                  for key in ("", "::fold")}
+        batches = {k: shape((2, B), d, workers) for k, d in (
+            ("s", jnp.int32), ("r", jnp.int32), ("o", jnp.int32),
+            ("weight", jnp.float32))}
+        emit("kge-wikidata5m.epochs",
+             lambda: trainer._build_chunk_fn("sync").lower(
+                 tables, (), batches, shape((), jax.random.key(0).dtype)))
+
     builders = dict(zip(CELLS, (
         lambda: mf(1), pa, lambda: mf(4), w2v, lr, ials, ials,
-        lambda: mf(1, topk=True), lambda: w2v(hot=True), dlrm_cell)))
+        lambda: mf(1, topk=True), lambda: w2v(hot=True), dlrm_cell,
+        kge_cell)))
     # (ials builds both its programs: once, whichever is asked for)
     for build in dict.fromkeys(builders[name] for name in CELLS
                                if name in cells):
