@@ -24,6 +24,11 @@ Per positive triple ``(s, r, o)`` with weight ``q`` (0 = padding):
   pushes over all workers and takes one AdaGrad step on the sum.
 
 Batch columns: ``s``, ``r``, ``o`` int32, ``weight``. float32.
+
+``complex_score`` and ``step_loss`` are the DEFINITION, written out;
+the worker computes the same loss and pushes through the score's linear
+form (``loss_and_pushes``), which ``tests/test_kge.py`` holds to
+``jax.value_and_grad`` of the definition.
 """
 
 from __future__ import annotations
@@ -99,6 +104,79 @@ def step_loss(cfg: KGEConfig, es, wr, eo, en, side, q) -> Array:
     return jnp.sum(q * (pos + jnp.sum(neg, axis=1)))
 
 
+def _subject_partner(wr, eo) -> Array:
+    """``u (B, 2K)`` with ``phi(x, r, o) = <x, u>`` over all ``2K``
+    components of the subject's row ``x``."""
+    a_r, b_r = _parts(wr)
+    a_o, b_o = _parts(eo)
+    return jnp.concatenate([a_r * a_o + b_r * b_o, a_r * b_o - b_r * a_o],
+                           axis=-1)
+
+
+def _object_partner(es, wr) -> Array:
+    """``v (B, 2K)`` with ``phi(s, r, x) = <x, v>`` for the object's row
+    ``x``."""
+    a_s, b_s = _parts(es)
+    a_r, b_r = _parts(wr)
+    return jnp.concatenate([a_s * a_r - b_s * b_r, a_s * b_r + b_s * a_r],
+                           axis=-1)
+
+
+def loss_and_pushes(cfg: KGEConfig, ent, wr, side, q):
+    """:func:`step_loss` and MINUS its gradient by every pulled row,
+    through the score's linear form: ``phi`` is linear in each of its three
+    rows, so a corruption scores ``<e_n, u>`` (subject replaced) or
+    ``<e_n, v>`` (object replaced) against a ``(B, 2K)`` partner of the
+    rows it keeps, and every pass over the replacements takes them as
+    whole rows.
+
+    The entity rows come and go in the pull's own 2-D layout,
+    ``ent (B (2 + N), 2K)``: subjects, objects, then the replacements
+    CORRUPTION-MAJOR, rows ``[(2 + j) B, (3 + j) B)`` the ``j``-th
+    corruption of every positive, with ``side (N, B)`` laid the same way.
+    Each such slab is scored against ``u`` and ``v`` as they are: a
+    ``(B, N, 2K)`` view pads N to the tile's 8 sublanes, and a partner
+    broadcast along a new axis is written out whole. Returns ``loss,
+    entity pushes (as ent), relation pushes (B, 2K)``."""
+    sq = lambda x: jnp.sum(x * x, axis=-1)  # noqa: E731
+    N, B = side.shape
+    es, eo, *slabs = (ent[j * B:(j + 1) * B] for j in range(2 + N))
+    u, pull_u = jax.vjp(_subject_partner, wr, eo)
+    v, pull_v = jax.vjp(_object_partner, es, wr)
+    phi_pos = jnp.sum(es * u, axis=-1)
+    phi_neg = jnp.where(
+        side, jnp.stack([jnp.sum(x * u, axis=-1) for x in slabs]),
+        jnp.stack([jnp.sum(x * v, axis=-1) for x in slabs]))
+    sq_s, sq_r, sq_o = sq(es), sq(wr), sq(eo)
+    softplus_pos, pull_pos = jax.vjp(jax.nn.softplus, -phi_pos)
+    softplus_neg, pull_neg = jax.vjp(jax.nn.softplus, phi_neg)
+    pos = softplus_pos + cfg.l2 * (sq_s + sq_r + sq_o)
+    neg = softplus_neg + cfg.l2 * (
+        jnp.stack([sq(x) for x in slabs]) + sq_r
+        + jnp.where(side, sq_o, sq_s))
+    loss = jnp.sum(q * (pos + jnp.sum(neg, axis=0)))
+    # Backward, in the pushes' sign: c = -dL/dphi, decay = -dL/d|row|^2.
+    # softplus' own derivative as autodiff has it, as the definition's
+    # gradient does: the chip's logistic is another float32 function.
+    (c_pos,) = pull_pos(q)
+    c_neg = -pull_neg(jnp.broadcast_to(q, phi_neg.shape))[0]
+    decay = (-cfg.l2 * 2 * q)[:, None]
+    p_n = [c[:, None] * jnp.where(a[:, None], u, v) + decay * x
+           for c, a, x in zip(c_neg, side, slabs)]
+    p_u = c_pos[:, None] * es + sum(
+        jnp.where(a, c, 0)[:, None] * x for c, a, x in zip(c_neg, side, slabs))
+    p_v = sum(
+        jnp.where(a, 0, c)[:, None] * x for c, a, x in zip(c_neg, side, slabs))
+    r_u, o_u = pull_u(p_u)
+    s_v, r_v = pull_v(p_v)
+    # The L2 of a kept row, once a scored triple that scores it.
+    replaced = jnp.sum(side, axis=0).astype(q.dtype)[:, None]  # subjects
+    p_s = c_pos[:, None] * u + s_v + decay * (1 + N - replaced) * es
+    p_o = o_u + decay * (1 + replaced) * eo
+    p_r = r_u + r_v + decay * (1 + N) * wr
+    return loss, jnp.concatenate([p_s, p_o, *p_n]), p_r
+
+
 class KGEWorker(WorkerLogic):
     def __init__(self, cfg: KGEConfig):
         self.cfg = cfg
@@ -113,9 +191,12 @@ class KGEWorker(WorkerLogic):
                 k_ent, (B, N), 0, self.cfg.num_entities, jnp.int32))
 
     def _entity_ids(self, batch) -> Array:
+        # Subjects, objects, then the replacements corruption-major: the
+        # j-th corruption of every positive is one contiguous (B, 2K) slab
+        # of the pulled rows.
         return jnp.concatenate([
             batch["s"].astype(jnp.int32), batch["o"].astype(jnp.int32),
-            batch["neg_entity"].reshape(-1)])
+            batch["neg_entity"].T.reshape(-1)])
 
     def pull_ids(self, batch) -> Mapping[str, Array]:
         return {ENTITY_TABLE: self._entity_ids(batch),
@@ -123,27 +204,19 @@ class KGEWorker(WorkerLogic):
 
     def step(self, batch, pulled, local_state, key) -> StepOutput:
         cfg = self.cfg
-        B, N = batch["neg_entity"].shape
+        N = cfg.negatives
         q = batch["weight"].astype(cfg.dtype)
-        ent = pulled[ENTITY_TABLE]
-        es, eo = ent[:B], ent[B:2 * B]
-        en = ent[2 * B:].reshape(B, N, cfg.dim)
-        wr = pulled[RELATION_TABLE]
-        # kge.score: the scoring and its backward, apart from what the
-        # step does to shape its pushes.
+        # kge.score: the scoring, its backward and the pushes as they
+        # leave, apart from what the step does to their ids.
         with jax.named_scope("kge.score"):
-            loss, (g_s, g_r, g_o, g_n) = jax.value_and_grad(
-                lambda *rows: step_loss(cfg, *rows, batch["neg_side"], q),
-                argnums=(0, 1, 2, 3))(es, wr, eo, en)
+            loss, p_ent, p_rel = loss_and_pushes(
+                cfg, pulled[ENTITY_TABLE], pulled[RELATION_TABLE],
+                batch["neg_side"].T, q)
         live = q > 0
-        ent_ids = jnp.where(jnp.concatenate([live, live, jnp.repeat(live, N)]),
-                            self._entity_ids(batch), -1)
+        ent_ids = jnp.where(jnp.tile(live, 2 + N), self._entity_ids(batch), -1)
         rel_ids = jnp.where(live, batch["r"].astype(jnp.int32), -1)
-        pushes = {
-            ENTITY_TABLE: (ent_ids, -jnp.concatenate(
-                [g_s, g_o, g_n.reshape(B * N, cfg.dim)])),
-            RELATION_TABLE: (rel_ids, -g_r),
-        }
+        pushes = {ENTITY_TABLE: (ent_ids, p_ent),
+                  RELATION_TABLE: (rel_ids, p_rel)}
         out = {"loss": loss.astype(jnp.float32),
                "n": jnp.sum(q).astype(jnp.float32)}
         return StepOutput(pushes=pushes, local_state=local_state, out=out)

@@ -31,6 +31,7 @@ from fps_tpu.core.driver import Trainer, TrainerConfig
 from fps_tpu.core.store import fold_key
 from fps_tpu.models.kge import (
     ENTITY_TABLE, RELATION_TABLE, KGEConfig, KGEWorker, kge, make_store,
+    step_loss,
 )
 from fps_tpu.parallel.mesh import SHARD_AXIS, make_ps_mesh
 from perfbench.lib import check, resolve, spec, window
@@ -136,6 +137,98 @@ def test_the_fold_body_follows_the_shapes_and_is_logged(devices8, compared,
     assert ("push.routed" in by_route) == bool(exchange)
     assert ("push.dense_acc" in by_route) == (shards > 1)
     assert "push.mean_rows" not in by_route and "push.sum_runs" not in by_route
+
+
+# -- the worker's linear form against the written-out definition ------------
+
+def _sum_by_id(ids, rows, num_ids):
+    out = np.zeros((num_ids, rows.shape[1]), np.float64)
+    np.add.at(out, ids[ids >= 0], rows[ids >= 0])
+    return out
+
+
+@pytest.mark.parametrize("l2", [0.0, 1e-5])
+@pytest.mark.parametrize("coins", ["subject", "object", "mixed"])
+@pytest.mark.parametrize("negatives", [1, 10])
+@pytest.mark.parametrize("rank", [6, 500])
+def test_step_is_the_definitions_loss_and_gradient(rank, negatives, coins, l2):
+    """``KGEWorker.step`` (the score's linear form on the pulled rows'
+    2-D layout) against ``jax.value_and_grad`` of ``step_loss`` on
+    ``complex_score`` in FLOAT64: the loss, the relation pushes, and the
+    entity pushes matched id by id whatever order the worker lays its ids
+    in (the same multiset of ids; every id but two occurs once in the
+    step, so its push is one row; the two that repeat are compared as the
+    server sees them, summed). Every case has three padding positives
+    (``weight`` 0: ``-1`` ids, rows exactly zero), a replacement equal to
+    its positive's own subject and one equal to its own object.
+
+    The tolerance is float32's rounding of the definition itself: a
+    component of a push is a sum of at most ``2 (1 + N)`` triple products
+    of numbers near 0.3, each rounded to ``2^-24`` of its size. Evaluated
+    in float32, the DEFINITION reads from the oracle, as a share of the
+    largest component (0.29 to 1.9), up to 1.7e-7 on the entity pushes,
+    2.8e-7 on the relation pushes and 1.5e-7 of the loss (18 to 130) over
+    these 24 cases; the linear form 1.6e-7, 1.7e-7 and 1.1e-7. Held to
+    1e-6 of the largest component and of the loss: six times the floor,
+    and under what the smallest term left out would read (a row's L2,
+    ``2 l2 |e|``: 2e-5 where a component is near 1)."""
+    B, N, R = 16, negatives, 5
+    E = B * (2 + N) + 8
+    rng = np.random.default_rng([rank, N, len(coins)])
+    cfg = KGEConfig(num_entities=E, num_relations=R, rank=rank, negatives=N,
+                    l2=l2)
+    entity = rng.normal(0, 0.3, (E, cfg.dim)).astype(np.float32)
+    relation = rng.normal(0, 0.3, (R, cfg.dim)).astype(np.float32)
+    distinct = rng.permutation(E).astype(np.int32)
+    s, o = distinct[:B], distinct[B:2 * B]
+    neg = distinct[2 * B:B * (2 + N)].reshape(B, N).copy()
+    neg[0, 0], neg[1, -1] = s[0], o[1]
+    side = {"subject": np.ones, "object": np.zeros,
+            "mixed": lambda shape, _: rng.random(shape) < 0.5}[coins](
+                (B, N), bool)
+    weight = np.ones(B, np.float32)
+    weight[-3:] = 0
+    batch = {k: jnp.asarray(v) for k, v in dict(
+        s=s, r=rng.integers(0, R, B).astype(np.int32), o=o, weight=weight,
+        neg_side=side, neg_entity=neg).items()}
+
+    worker = KGEWorker(cfg)
+    ids = worker.pull_ids(batch)
+    assert ids[ENTITY_TABLE].shape == (B * (2 + N),)
+    assert ids[RELATION_TABLE].shape == (B,)
+    pulled = {ENTITY_TABLE: jnp.asarray(entity)[ids[ENTITY_TABLE]],
+              RELATION_TABLE: jnp.asarray(relation)[ids[RELATION_TABLE]]}
+    out = worker.step(batch, pulled, (), jax.random.key(0))
+    ent_ids, ent_rows = map(np.asarray, out.pushes[ENTITY_TABLE])
+    rel_ids, rel_rows = map(np.asarray, out.pushes[RELATION_TABLE])
+    assert ent_rows.dtype == rel_rows.dtype == np.float32
+
+    with jax.enable_x64(True):
+        f64 = lambda x: jnp.asarray(x, jnp.float64)  # noqa: E731
+        loss, (g_s, g_r, g_o, g_n) = jax.value_and_grad(
+            lambda *rows: step_loss(cfg, *rows, jnp.asarray(side),
+                                    f64(weight)),
+            argnums=(0, 1, 2, 3))(f64(entity[s]), f64(relation[batch["r"]]),
+                                  f64(entity[o]), f64(entity[neg]))
+        loss, g_s, g_r, g_o, g_n = map(np.asarray, (loss, g_s, g_r, g_o, g_n))
+    live = weight > 0
+    want_ids = np.where(np.concatenate([live, live, np.repeat(live, N)]),
+                        np.concatenate([s, o, neg.reshape(-1)]), -1)
+    want_rows = -np.concatenate([g_s, g_o, g_n.reshape(B * N, -1)])
+
+    np.testing.assert_array_equal(np.sort(ent_ids), np.sort(want_ids))
+    np.testing.assert_array_equal(rel_ids, np.where(live, batch["r"], -1))
+    assert (ent_ids < 0).sum() == 3 * (2 + N)
+    assert not ent_rows[ent_ids < 0].any() and not rel_rows[~live].any()
+    np.testing.assert_allclose(float(out.out["loss"]), loss, rtol=1e-6)
+    scale = np.abs(want_rows).max()
+    assert 0.2 < scale < 2
+    np.testing.assert_allclose(
+        _sum_by_id(ent_ids, ent_rows, E), _sum_by_id(want_ids, want_rows, E),
+        rtol=0, atol=1e-6 * scale)
+    np.testing.assert_allclose(rel_rows, -g_r, rtol=0,
+                               atol=1e-6 * np.abs(g_r).max())
+    assert float(out.out["n"]) == B - 3
 
 
 # -- store.push with a fold, by itself --------------------------------------

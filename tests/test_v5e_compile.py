@@ -1074,32 +1074,22 @@ def test_dlrm_push_sums_its_runs_and_scatters_by_blocks_in_place(
     assert memory.alias_size_in_bytes >= R * D * 4      # donated, in place
 
 
-def test_kge_step_folds_the_pushed_rows_alone_in_place(topo, monkeypatch):
-    """``kge-wikidata5m.epochs``'s step at the cell's own size (``entity``
-    ``[393216, 1000]`` and its AdaGrad state of the same shape, 4,096
-    positives and 10 corruptions each a step: 49,152 entity ids) for one
-    described chip, through the trainer's chunk program. The entity table
-    takes the table's own fold on its SPARSE body (``push.fold_rows``):
-    the step's one gather of the state and its two writes (the table's
-    scatter-add and the state's scatter, each a block of ids at a time in
-    the sorted route's loop, in place on the donated carry) are the only
-    ops whose result has the table's or the state's shape; no ``[rows,
-    1001]`` accumulator, no copy, fill or select of either, and no
-    conditional takes either in. The 822-row relation table keeps the
-    accumulator body (``push.fold``, ``small_table``). ONCE A CALL, in the
-    entry computation, XLA relays both out: it takes the parameters
-    column-major (``{0,1}``: 1,000 is no multiple of 128 lanes, 393,216
-    is) and carries them row-major through the loop, a copy each way of
-    each, which is the 3.15 GB of the temporaries that is no step's."""
+KGE = (393_216, 822, 500, 4_096, 10)  # entities, relations, rank, B, N
+
+
+@pytest.fixture(scope="module")
+def kge_step(topo):
+    """``kge-wikidata5m.epochs``'s step at the cell's own size through the
+    trainer's chunk program, compiled ONCE for one described chip: the
+    compiled program and the routes its trace logged."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from fps_tpu.core.store import fold_key
     from fps_tpu.models.kge import KGEConfig, kge
     from fps_tpu.parallel.mesh import make_ps_mesh
 
-    monkeypatch.setattr(ops, "_use_pallas", lambda: (True, False))
-    E, R, K, B, N = 393_216, 822, 500, 4_096, 10
-    D, ids = 2 * K, B * (2 + N)
+    E, R, K, B, N = KGE
+    D = 2 * K
     mesh = make_ps_mesh(num_shards=1, devices=list(topo.devices)[:1])
     trainer, _ = kge(mesh, KGEConfig(num_entities=E, num_relations=R,
                                      rank=K, negatives=N))
@@ -1117,11 +1107,35 @@ def test_kge_step_folds_the_pushed_rows_alone_in_place(topo, monkeypatch):
     batches = {k: shape((2, B), d, workers) for k, d in (
         ("s", jnp.int32), ("r", jnp.int32), ("o", jnp.int32),
         ("weight", jnp.float32))}
-    ops.clear_routes()
-    compiled = trainer._build_chunk_fn("sync").lower(
-        tables, (), batches, shape((), jax.random.key(0).dtype)).compile()
-    assert [(r.route, r.rows, r.dim, r.ids, r.reason)
-            for r in ops.routes_traced()] == [
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(ops, "_use_pallas", lambda: (True, False))
+        ops.clear_routes()
+        compiled = trainer._build_chunk_fn("sync").lower(
+            tables, (), batches, shape((), jax.random.key(0).dtype)).compile()
+        return compiled, ops.routes_traced()
+
+
+def test_kge_step_folds_the_pushed_rows_alone_in_place(kge_step):
+    """``kge-wikidata5m.epochs``'s step at the cell's own size (``entity``
+    ``[393216, 1000]`` and its AdaGrad state of the same shape, 4,096
+    positives and 10 corruptions each a step: 49,152 entity ids) for one
+    described chip, through the trainer's chunk program. The entity table
+    takes the table's own fold on its SPARSE body (``push.fold_rows``):
+    the step's one gather of the state and its two writes (the table's
+    scatter-add and the state's scatter, each a block of ids at a time in
+    the sorted route's loop, in place on the donated carry) are the only
+    ops whose result has the table's or the state's shape; no ``[rows,
+    1001]`` accumulator, no copy, fill or select of either, and no
+    conditional takes either in. The 822-row relation table keeps the
+    accumulator body (``push.fold``, ``small_table``). ONCE A CALL, in the
+    entry computation, XLA relays both out: it takes the parameters
+    column-major (``{0,1}``: 1,000 is no multiple of 128 lanes, 393,216
+    is) and carries them row-major through the loop, a copy each way of
+    each, which is the 3.15 GB of the temporaries that is no step's."""
+    E, R, K, B, N = KGE
+    D, ids = 2 * K, B * (2 + N)
+    compiled, routes = kge_step
+    assert [(r.route, r.rows, r.dim, r.ids, r.reason) for r in routes] == [
         ("gather.xla", E, D, ids, "shape"),
         ("gather.xla", R, D, B, "shape"),
         ("push.fold_rows", E, D, ids, ""),
@@ -1157,3 +1171,64 @@ def test_kge_step_folds_the_pushed_rows_alone_in_place(topo, monkeypatch):
     payload, both = ids * D * 4, 2 * E * D * 4  # a step's rows: 197 MB
     assert memory.temp_size_in_bytes < both + 12 * payload
     assert memory.alias_size_in_bytes >= both   # donated, in place
+
+
+def test_kge_scoring_writes_the_pushes_and_no_other_replacement_sized_array(
+        kge_step):
+    """The same compiled step, under ``fps.compute``: the ComplEx worker
+    scores through the score's linear form on the pulled rows' own 2-D
+    layout (PR 52), the ten corruptions as ten ``[4096, 1000]`` slabs
+    against ``[4096, 1000]`` partners. What is held:
+
+    * under ``kge.score`` no instruction's result keeps N as an axis of
+      its own beside whole or half rows (``[.., 10, 1000]``,
+      ``[.., 10, 500]``, either order: a ``[4096, 10, 1000]`` float32 array
+      pads 10 sublanes to 16, 268 MB for 164) and none is a half-row slice
+      of the replacements (``[40960, 500]``);
+    * in all of ``fps.compute`` the results of ``B N 2K`` elements or more
+      (tuple elements counted one by one, bitcasts not) number ONE, the
+      pushes' own ``[49152, 1000]`` under ``kge.score``. The parent's step
+      (``jax.value_and_grad`` through two ``where``s on ``[4096, 10,
+      1000]``) had TEN: two broadcasts, four reshapes that are physical
+      copies, four fusion results; held to a third of that. XLA writes
+      that one buffer a ``[4096, 1000]`` slab at a time, in place: the
+      concatenation becomes twelve dynamic-update-slice fusions, each
+      computing its slab's pushes where they land, and only the LAST
+      keeps the concatenation's name. The other eleven carry no
+      ``op_name`` at all, so a trace reads their time under no scope
+      (``PERF.md`` section 7): pinned here so that a compiler that names
+      them, or stops writing in place, is noticed;
+    * ``memory_analysis()``'s temporaries: both entity arrays' once-a-call
+      relayout and under four payloads of a step's rows (3.73 GB read;
+      the parent's step read 5,341,910,528, eleven payloads)."""
+    E, _, K, B, N = KGE
+    D, ids = 2 * K, B * (2 + N)
+    compiled, _ = kge_step
+    top = list(_top_level(compiled.as_text()))
+    results = []        # (scope path, opcode, [dims of each array result])
+    for ln in top:
+        m = re.search(r"= (.*?) ([\w\-]+)\(", ln)
+        name = re.search(r'op_name="([^"]*)"', ln)
+        if (m and name and "/fps.compute/" in name.group(1)
+                and m.group(2) not in ("get-tuple-element", "parameter",
+                                       "bitcast")):
+            results.append((name.group(1), m.group(2), [
+                [int(d) for d in dims.split(",") if d]
+                for dims in re.findall(r"\w+\[([\d,]*)\]", m.group(1))]))
+    scoring = [r for r in results if "/kge.score/" in r[0]]
+    assert len(scoring) > 5 and len(results) > len(scoring)
+    padded = [r for r in scoring for dims in r[2]
+              if (N in dims[:-1] and dims[-1] in (D, K) and len(dims) > 2)
+              or dims == [B * N, K]]
+    assert not padded, padded
+    big = [(r[0], r[1], dims) for r in results for dims in r[2]
+           if int(np.prod(dims)) >= B * N * D]
+    assert len(big) <= 10 // 3, big
+    assert [(b[1], b[2]) for b in big] == [("fusion", [ids, D])] and big[0][
+        0].endswith("/kge.score/concatenate"), big
+    nameless = [ln for ln in top if "op_name=" not in ln
+                and re.search(rf"= f32\[{ids},{D}\]\S* fusion\(", ln)]
+    assert len(nameless) == 1 + N and all(
+        "dynamic-update-slice" in ln for ln in nameless), nameless
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < 2 * E * D * 4 + 4 * ids * D * 4
