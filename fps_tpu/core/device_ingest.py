@@ -77,7 +77,8 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from fps_tpu import ops
-from fps_tpu.obs.timing import host_span, settle
+from fps_tpu.obs.timing import (device_bytes, host_span, settle,
+                                watch_program)
 from fps_tpu.parallel.mesh import DATA_AXIS, SHARD_AXIS, host_to_replicated
 
 Array = jax.Array
@@ -109,20 +110,20 @@ _GRID_ROWS_MAX = 1 << 12
 # between 39 and 64 was measured.
 _SLICED_SLOTS_MAX = 64          # exclusive
 # Copies alive at once: a caller that keeps a call queued behind the one
-# running (``run_indexed(..., as_numpy=False)`` in a loop) holds the
-# running call's and the queued call's (``lr-criteo.epochs``' peak reads
-# 8.33 GB = the parent's 2.81 + 2 x 2.76: builder's chip runs, PR 50);
-# the third is the one the next ``epoch_args`` may make before the first
-# is released.
+# running (``run_indexed(..., as_numpy=False)`` in a loop) holds both
+# calls' copies: ``lr-criteo.epochs`` peaks at 8.33 GB = 2.78 resident +
+# 2 x 2.76 held a queued call + 0.03 (``device.*_gb``, builder's traced
+# chip run, PR 53): two, never three, two deep. The third is headroom
+# for the copy a deeper queue's ``epoch_args`` makes before one is freed.
 _SLICED_CALLS_ALIVE = 3
 # Share of the device's memory limit that the resident columns and those
-# copies may take. The rest is the model's tables and what no
-# ``memory_stats`` shows: the builder's own temporaries (one more copy of
-# the widest column: compiled for a described v5e, PR 50) and the step
-# program's (a ``[9652968, 64]`` column re-tiled: 4.94 GB each, PR 46).
-# ``lr-criteo``'s columns: 2.72 GB resident + 3 x 2.76 = 11.0 of 16.9 GB,
-# 65 %, in; ``pa-rcv1``'s: 4.98 + 3 x 4.99 = 19.9 GB, 118 %, out (and out
-# at two copies alive, 88 %).
+# copies may take. The rest is the tables and the programs' temporaries,
+# which ``memory_stats`` never shows and ``program.memory`` states (PR
+# 53): the builder's (one more copy of the widest column: 1.34 GB on
+# ``lr-criteo``), the step program's (PA's two ``[9652968, 64]`` columns
+# re-tiled, 4.94 GB each and BOTH AT ONCE: 9.89 GB beside 5.02 resident).
+# ``lr-criteo``'s columns: 2.72 + 3 x 2.76 = 11.0 of 16.9 GB, 65 %, in;
+# ``pa-rcv1``'s: 4.98 + 3 x 4.99 = 19.9 GB, 118 %, out (two alive: 88 %).
 _SLICED_HBM_SHARE = 0.75
 
 
@@ -170,10 +171,10 @@ def columns_take_slices(columns, num_workers: int, buffer_rows: int,
 
 
 def _hbm_bytes(mesh) -> int | None:
-    """The memory limit of one of the mesh's devices, where the backend
-    reports one (the CPU's does not)."""
-    stats = mesh.local_devices[0].memory_stats()
-    return (stats or {}).get("bytes_limit")
+    """The smallest memory limit of the mesh's local devices, where the
+    backend reports one (the CPU's does not)."""
+    stats = device_bytes(mesh)
+    return None if stats is None else stats.limit
 
 
 def unkeyed_queue_rows(w, qpos, count, num_workers: int):
@@ -200,7 +201,7 @@ class DeviceDataset:
             raise ValueError(f"column lengths differ: {lengths}")
         self.n = next(iter(lengths.values()))
         self._host_data = {k: np.asarray(v) for k, v in data.items()}
-        with host_span("dataset.place"):
+        with host_span("dataset.place", memory=True):
             self.columns = settle({
                 k: host_to_replicated(v, mesh)
                 for k, v in self._host_data.items()
@@ -224,7 +225,7 @@ class DeviceDataset:
             self._queues[ck] = self._build_queues(route_key, num_workers)
         return self._queues[ck]
 
-    @host_span("dataset.queues")
+    @host_span("dataset.queues", memory=True)
     def _build_queues(self, route_key: str | None, num_workers: int):
         """The host sort into per-worker queues, and its upload."""
         if route_key is None:
@@ -285,7 +286,7 @@ class DeviceDataset:
                     ]
                     return jnp.stack(chans, axis=-1)
 
-                with host_span("dataset.pack"):
+                with host_span("dataset.pack", memory=True):
                     arr = settle(jax.jit(
                         build,
                         out_shardings=NamedSharding(self.mesh, P()),
@@ -317,7 +318,7 @@ class DeviceEpochPlan:
     epoch; positions past a worker's queue produce weight-0 padding rows.
     """
 
-    @host_span("plan.build")
+    @host_span("plan.build", memory=True)
     def __init__(self, dataset: DeviceDataset, *, num_workers: int,
                  local_batch: int, route_key: str | None = None,
                  shuffle: str | None = "interleave", seed: int = 0,
@@ -403,10 +404,10 @@ class DeviceEpochPlan:
             # the (W, maxq) argsort program every epoch. Takes raw key data
             # (a plain numpy array, implicitly replicated) so the path works
             # under multi-controller JAX too.
-            self._perm_jit = jax.jit(
+            self._perm_jit = watch_program(jax.jit(
                 mk_perm,
                 out_shardings=NamedSharding(dataset.mesh, P()),
-            )
+            ), "ingest.perm")
 
     def _transposed_rows(self, rows, off_w, w: int):
         """Worker ``w``'s queue-ordered ``rows`` (``(any, *tail)``, zeros
@@ -450,9 +451,9 @@ class DeviceEpochPlan:
                     packed_mat[w * maxq : (w + 1) * maxq], off_w, w)
                 for w in range(W)])
 
-        return jax.jit(
+        return watch_program(jax.jit(
             build, out_shardings=NamedSharding(self._mesh, P())
-        )
+        ), "ingest.tbuf")
 
     def _make_column_tbuf_jit(self):
         """Jitted per-epoch builder of the transposed buffers of an unkeyed
@@ -480,9 +481,9 @@ class DeviceEpochPlan:
                 for k, col in columns.items()
             }
 
-        return jax.jit(
+        return watch_program(jax.jit(
             build, out_shardings=NamedSharding(self._mesh, P())
-        )
+        ), "ingest.tbuf")
 
     def calls_per_epoch(self, steps_per_call: int) -> int:
         """Compiled calls covering one epoch at ``steps_per_call`` steps
@@ -503,7 +504,7 @@ class DeviceEpochPlan:
             (tag, self.seed & ((1 << 64) - 1), epoch)
         )
 
-    @host_span("epoch_args")
+    @host_span("epoch_args", memory=True)
     def epoch_args(self, epoch: int):
         """Device operands for one epoch (replicated pytree)."""
         mesh = self.dataset.mesh

@@ -86,7 +86,8 @@ from fps_tpu.obs.health import (
     HealthMonitor,
     StepWatchdog,
 )
-from fps_tpu.obs.timing import PhaseTimer, host_span, settle, watch_device
+from fps_tpu.obs.timing import (PhaseTimer, host_span, settle, watch_device,
+                                watch_program)
 from fps_tpu.parallel.mesh import (
     DATA_AXIS,
     SHARD_AXIS,
@@ -514,7 +515,7 @@ class Trainer:
     # -- state ------------------------------------------------------------
 
     def init_state(self, key: Array) -> tuple[dict[str, Array], Pytree]:
-        with host_span("init_state"):
+        with host_span("init_state", memory=True):
             tables = self.store.init(jax.random.fold_in(key, 0))
             if self.logic.dense is not None:
                 # Into the store's own dict, as the call that ends a run
@@ -1980,29 +1981,25 @@ class Trainer:
     # -- compile-time program certification (fps_tpu.analysis) ------------
 
     def _wrap_audit(self, fn, label: str):
-        """Certify ``fn``'s lowered program on its first call (no-op
-        passthrough when ``self.audit`` is unset at build time).
+        """``fn`` behind its first-call seam
+        (:func:`fps_tpu.obs.timing.watch_program`): ``self.audit``, where
+        set at build time, certifies the lowered program before the first
+        call, and under a process-default recorder a ``program.memory``
+        span states what the compiled program needs; with neither ``fn``
+        itself comes back.
 
-        The wrapper lowers once more — trace cost only, paid once per
+        The audit lowers once more — trace cost only, paid once per
         compiled program — and hands the StableHLO text to the auditor;
         the actual dispatch path is the unmodified jitted callable, so
         donation/caching behavior is untouched. ``.lower`` passes
         through for callers that inspect programs directly.
         """
         if self.audit is None:
-            return fn
-        state = {"done": False}
-
-        def audited(*args):
-            if not state["done"]:
-                state["done"] = True
-                self._audit_program(label, fn, args)
-            return fn(*args)
-
-        audited.lower = fn.lower
-        audited.__wrapped__ = fn
-        audited._fps_audited = True
-        return audited
+            return watch_program(fn, label)
+        watched = watch_program(
+            fn, label, lambda args: self._audit_program(label, fn, args))
+        watched._fps_audited = True
+        return watched
 
     def _audit_program(self, label: str, fn, args) -> None:
         from fps_tpu import analysis

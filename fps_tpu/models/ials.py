@@ -98,7 +98,7 @@ from fps_tpu.core.store import (
     ranged_uniform_init,
     rows_per_shard,
 )
-from fps_tpu.obs.timing import host_span, watch_device
+from fps_tpu.obs.timing import host_span, watch_device, watch_program
 from fps_tpu.parallel.mesh import DATA_AXIS, SHARD_AXIS
 
 Array = jax.Array
@@ -632,15 +632,17 @@ class IALSSolver:
                              self.store.tables[solve_name])
             with host_span("als.gram"):
                 if fixed_name not in self._compiled_gram:
-                    self._compiled_gram[fixed_name] = self._gram_fn(
-                        fixed_n, fixed_rps)
+                    self._compiled_gram[fixed_name] = watch_program(
+                        self._gram_fn(fixed_n, fixed_rps),
+                        f"als.gram/{solve}")
                 gram = self._compiled_gram[fixed_name](fixed)
 
             A = self._zeros_acc(solve_rps * self.num_shards, k * k)
             b = self._zeros_acc(solve_rps * self.num_shards, k)
             acc = self._compiled_acc.get(solve)
             if acc is None:
-                acc = self._compiled_acc[solve] = self._accumulate_fn(solve)
+                acc = self._compiled_acc[solve] = watch_program(
+                    self._accumulate_fn(solve), f"als.accumulate/{solve}")
 
             it, pf = chunks, None
             if self.prefetch:
@@ -662,8 +664,9 @@ class IALSSolver:
 
             with host_span("als.solve"):
                 if solve_name not in self._compiled_solve:
-                    self._compiled_solve[solve_name] = self._solve_fn(
-                        solve_n, solve_rps)
+                    self._compiled_solve[solve_name] = watch_program(
+                        self._solve_fn(solve_n, solve_rps),
+                        f"als.solve/{solve}")
                 self.store.tables[solve_name] = self._compiled_solve[
                     solve_name](gram, A, b)
             # The sweep is queued: its completion (the solved table, which

@@ -36,7 +36,7 @@ import numpy as np
 
 from fps_tpu.core.api import StepOutput, WorkerLogic
 from fps_tpu.core.store import ParamStore, TableSpec, ranged_uniform_init
-from fps_tpu.obs.timing import host_span
+from fps_tpu.obs.timing import host_span, watch_program
 from fps_tpu.parallel.mesh import host_to_replicated, key_to_replicated
 
 Array = jax.Array
@@ -739,7 +739,7 @@ class Word2VecDevicePlan:
 
     TOKEN = "token"  # the data set's one column
 
-    @host_span("plan.build")
+    @host_span("plan.build", memory=True)
     def __init__(self, dataset, unigram_counts: np.ndarray,
                  cfg: W2VConfig, mesh, *, num_workers: int,
                  block_len: int = 8192, seed: int = 0,
@@ -803,12 +803,12 @@ class Word2VecDevicePlan:
 
         # Takes a replicated key (key_to_replicated) and pins replicated
         # outputs, so the path works under multi-controller JAX.
-        self._compact_jit = jax.jit(
+        self._compact_jit = watch_program(jax.jit(
             compact, out_shardings=(replicated, replicated)
-        )
+        ), "ingest.compact")
         self._mesh = mesh
 
-    @host_span("epoch_args")
+    @host_span("epoch_args", memory=True)
     def epoch_args(self, epoch: int):
         ekey = jax.random.fold_in(jax.random.key(self.seed), epoch)
         ck, wk = jax.random.split(ekey)

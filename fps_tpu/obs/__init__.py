@@ -15,8 +15,12 @@ One subsystem, four altitudes (see ``docs/observability.md``):
   cache hits in; :func:`watch_device` hands every unit of work an entry
   point queues to a watcher thread that stamps its completion (the
   ``device.<entry>`` spans: how long the device ran the unit, how long
-  it starved before it, with no caller made to wait); :func:`trace`
-  completes the clock set.
+  it starved before it, with no caller made to wait);
+  :func:`device_bytes` reads the device's memory, which those spans carry
+  under a recorder (``hbm_open`` / ``hbm_close`` of a call, ``hbm_delta``
+  of a set-up span, ``hbm_done`` / ``hbm_peak`` of a device span), and
+  :func:`watch_program` states each compiled program's own
+  (``program.memory``); :func:`trace` completes the clock set.
 * **alerting** — :class:`HealthMonitor` thresholds the guard's health
   channel (observe→mask escalation, poison abort);
   :class:`StepWatchdog` deadline-flags stalled chunks/stragglers.
@@ -60,6 +64,10 @@ device span                ``device.run_indexed`` (an epoch)
 ``fps.device.<entry>``     ``device.run_megastep`` ``device.als.half_epoch``
 in a profiler trace)       (a sweep): ``t0`` ``t1`` ``t_enqueued``
                            ``wait_s`` ``starved_s`` ``in_flight`` ``steps``
+                           ``hbm_done`` ``hbm_peak``
+program span               ``program.memory`` (one a compiled program):
+(:func:`watch_program`)    ``label`` ``argument_bytes`` ``output_bytes``
+                           ``alias_bytes`` ``temp_bytes`` ``code_bytes``
 compile phase              ``compile.trace`` ``compile.lower``
                            ``compile.backend``; counters
                            ``compile.cache_hits`` / ``_misses``; event
@@ -104,10 +112,12 @@ from fps_tpu.obs.sinks import JsonlSink, MemorySink, PrometheusSink, Sink
 from fps_tpu.obs.timing import (
     DRIVER_PHASES,
     PhaseTimer,
+    device_bytes,
     host_span,
     trace,
     watch_compiles,
     watch_device,
+    watch_program,
 )
 from fps_tpu.obs.trace import (
     PARENT_SPAN_ENV,
@@ -122,7 +132,8 @@ __all__ = [
     "MetricSpec", "MetricsRegistry", "Recorder", "default_registry",
     "Sink", "JsonlSink", "MemorySink", "PrometheusSink",
     "PhaseTimer", "trace", "DRIVER_PHASES",
-    "host_span", "watch_compiles", "watch_device",
+    "host_span", "watch_compiles", "watch_device", "device_bytes",
+    "watch_program",
     "HealthMonitor", "StepWatchdog",
     "HEALTH_OK", "HEALTH_ESCALATE", "HEALTH_ABORT",
     "RunJournal", "new_run_id", "config_digest", "process_index",

@@ -127,7 +127,9 @@ def default_registry() -> MetricsRegistry:
                         "call spans run_indexed / fit_stream / "
                         "run_megastep; the set-up spans dataset.place / "
                         "dataset.queues / dataset.pack / plan.build / "
-                        "init_state; and JAX's own compile.trace / "
+                        "init_state; program.memory, the reading of a "
+                        "compiled program's memory after its first call "
+                        "(watch_program); and JAX's own compile.trace / "
                         "compile.lower / compile.backend (work done under "
                         "dispatch) — a sum over phases must leave the "
                         "nested, call and compile ones out"),

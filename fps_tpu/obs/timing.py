@@ -29,6 +29,12 @@ host-side clocks the chunked driver makes natural:
   so a ``device.<entry>`` span says how long the device ran the unit,
   how long the unit waited for it and how long the device starved before
   it — no profiler, and no caller made to wait.
+* :func:`device_bytes` — the DEVICE's memory, from inside: the one place
+  the package reads ``memory_stats()``. Under a recorder the spans above
+  carry it (a call's ``hbm_open`` / ``hbm_close``, a set-up span's
+  ``hbm_delta``, a device span's ``hbm_done`` / ``hbm_peak``) and
+  :func:`watch_program` adds what each compiled program needs beyond its
+  operands (``program.memory``); without one not a byte is read.
 * :func:`trace` — context manager writing a Perfetto/XProf-compatible
   trace of everything (XLA ops, collectives, host callbacks).
 
@@ -44,6 +50,7 @@ import itertools
 import logging
 import threading
 import time
+from typing import NamedTuple
 
 from fps_tpu.obs import events
 from fps_tpu.obs.trace import new_span_id
@@ -143,6 +150,9 @@ CALL_SPANS = ("run_indexed", "fit_stream", "run_megastep", "als.half_epoch")
 # ``als.accumulate`` a chunk queued, the solve queued: the host's cost of
 # queueing, like ``enqueue`` (a sweep reads nothing back).
 SWEEP_PHASES = ("als.gram", "als.accumulate", "als.solve")
+# One span a compiled program, right after the call that built it
+# (:func:`watch_program`): what the program needs in device memory.
+PROGRAM_SPANS = ("program.memory",)
 # Device spans (:func:`watch_device`), one per unit of work an entry point
 # queues: an epoch of ``run_indexed``, a chunk of ``fit_stream`` /
 # ``run_chunk``, a megastep, an ALS sweep. In a profiler trace the
@@ -168,6 +178,44 @@ def _now() -> float:
     return _EPOCH + time.perf_counter()
 
 
+class DeviceBytes(NamedTuple):
+    """What :func:`device_bytes` reads, in bytes."""
+
+    in_use: int        # allocated now, on the fullest device
+    peak: int          # the most ever allocated at once, likewise
+    limit: int | None  # what the allocator may hand out (the smallest)
+
+
+def device_bytes(where=None) -> DeviceBytes | None:
+    """The device's memory as its allocator counts it: the fullest local
+    device's ``bytes_in_use`` and ``peak_bytes_in_use`` (each the largest
+    over ``where``'s local devices: a mesh, an iterable of devices, or
+    ``None`` for every local device) and the smallest ``bytes_limit``.
+    ``None`` where the backend reports nothing (the CPU's
+    ``memory_stats()`` is ``None``).
+
+    THE place ``memory_stats()`` is read: the spans below call it under a
+    recorder and only then, ``DeviceEpochPlan`` reads the limit through it
+    at plan build. A buffer counts from the moment the program that
+    writes it is QUEUED, so a reading taken right after a dispatch holds
+    what the dispatch allocated. A compiled program's temporaries are in
+    NEITHER number on the TPU runtime read (v5e, PR 53: 9.9 GB of them ran
+    under a peak of 5.03): ``program.memory`` states them."""
+    if where is None:
+        import jax
+
+        where = jax.local_devices()
+    stats = [s for s in (d.memory_stats() for d in
+                         getattr(where, "local_devices", where)) if s]
+    if not stats:
+        return None
+    limits = [s["bytes_limit"] for s in stats if "bytes_limit" in s]
+    return DeviceBytes(
+        max(int(s.get("bytes_in_use", 0)) for s in stats),
+        max(int(s.get("peak_bytes_in_use", 0)) for s in stats),
+        int(min(limits)) if limits else None)
+
+
 def settle(tree):
     """Wait for ``tree``'s device work — WHEN a process-default recorder is
     installed, and not otherwise. The set-up spans that queue device work
@@ -183,7 +231,7 @@ def settle(tree):
 
 @contextlib.contextmanager
 def host_span(name: str, timer: "PhaseTimer | None" = None, *,
-              call: bool = False, **attrs):
+              call: bool = False, memory: bool = False, **attrs):
     """One host phase, on every clock the repo has.
 
     Always a ``TraceAnnotation("fps.host.<name>")`` (a flag test when no
@@ -201,6 +249,19 @@ def host_span(name: str, timer: "PhaseTimer | None" = None, *,
     point (a context manager made by ``contextlib`` is a decorator too,
     opening a fresh span per call). Yields a dict: keys set on it before
     the span closes ride the record (``built=True``).
+
+    Under a recorder, and where the backend counts its memory
+    (:func:`device_bytes`), a ``call=True`` span also carries ``hbm_open``
+    and ``hbm_close``, the bytes in use when the entry point is entered
+    and when it returns with its last program queued (their difference is
+    what queueing this call COST: outputs nothing donates, the plan's
+    per-call buffers, whatever the runtime allocates at dispatch),
+    ``hbm_peak``, the allocator's running peak at that return (the FIRST
+    call's: what set-up's transients reached before any program of a call
+    had run) and ``hbm_limit``; a ``memory=True`` span carries
+    ``hbm_delta``, the bytes in use at its close less at its open: what it
+    left resident, its children's included. Both readings lie OUTSIDE
+    ``t0`` .. ``t1``: the span is no longer for them.
 
     No recorder, no profiler: two flag tests. Spans are per call or per
     chunk, never per step.
@@ -222,6 +283,7 @@ def host_span(name: str, timer: "PhaseTimer | None" = None, *,
     sid = new_span_id() if rec is not None else None
     timed = rec is not None or timer is not None
     stack.append((sid, index))
+    opened = device_bytes() if rec is not None and (call or memory) else None
     t0 = _now() if timed else 0.0
     try:
         with jax.profiler.TraceAnnotation(HOST_SPAN_PREFIX + name):
@@ -238,6 +300,15 @@ def host_span(name: str, timer: "PhaseTimer | None" = None, *,
             if rec is not None:
                 if index is not None:
                     attrs.setdefault("call", index)
+                if opened is not None:
+                    closed = device_bytes()
+                    if call:
+                        attrs.update(hbm_open=opened.in_use,
+                                     hbm_close=closed.in_use,
+                                     hbm_peak=closed.peak,
+                                     hbm_limit=closed.limit)
+                    if memory:
+                        attrs["hbm_delta"] = closed.in_use - opened.in_use
                 _emit_span(rec, guarded, name, sid, parent, t0, t1, attrs)
 
 
@@ -355,6 +426,12 @@ class _DeviceWatcher:
                         # do: a copy to the host, no wait) rides the span.
                         if unit.on_done is not None and unit.rec is not None:
                             unit.attrs.update(unit.on_done(unit.rec) or {})
+                        # ... and the device's memory as the unit left it.
+                        held = (device_bytes() if unit.rec is not None
+                                else None)
+                        if held is not None:
+                            unit.attrs.update(hbm_done=held.in_use,
+                                              hbm_peak=held.peak)
                         self._emit(unit, prev, t1)
                     except Exception:  # noqa: BLE001 - telemetry must
                         # not end the watcher
@@ -427,6 +504,10 @@ def watch_device(name: str, outputs, timer: "PhaseTimer | None" = None,
       what the call site knows without reading the device (``steps``
       defaults to the leading dimension of the first output, the shape of
       per-step metrics);
+    * ``hbm_done`` / ``hbm_peak`` (where the backend counts its memory,
+      :func:`device_bytes`): the bytes in use and the allocator's running
+      peak, read by the watcher right after the stamp: the unit is done,
+      what was queued behind it holds its buffers already;
     * ``on_done(recorder) -> dict | None``: run by the watcher right after
       the stamp, on its own thread, for accounting the caller deferred
       because the outputs it reads did not exist yet (the hot tier's hit
@@ -481,10 +562,13 @@ _watching = False
 _watch_lock = threading.Lock()
 
 
+_probing = threading.local()  # .on: this thread is inside program.memory
+
+
 def _on_compile_duration(event, duration, **kw):
     phase = _COMPILE_EVENTS.get(event)
-    if phase is None:
-        return
+    if phase is None or getattr(_probing, "on", False):
+        return  # not JAX's compile timings, or a probe's look-up of them
     events.record_metric("observe", "driver.phase_seconds", duration,
                          phase=phase)
     if phase == "compile.backend":
@@ -520,6 +604,95 @@ def watch_compiles() -> None:
             _on_compile_duration)
         jax.monitoring.register_event_listener(_on_cache_event)
         _watching = True
+
+
+# -- a program's memory, from inside --------------------------------------
+
+_MEMORY_FIELDS = {
+    "argument_bytes": "argument_size_in_bytes",
+    "output_bytes": "output_size_in_bytes",
+    "alias_bytes": "alias_size_in_bytes",
+    "temp_bytes": "temp_size_in_bytes",
+    "code_bytes": "generated_code_size_in_bytes",
+}
+
+
+def _abstract(x):
+    """A device array as the shape, dtype and sharding a lowering keys on
+    (the call that follows may donate the array itself)."""
+    import jax
+
+    if isinstance(x, jax.Array):
+        return jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=x.sharding,
+            weak_type=getattr(x, "weak_type", False))  # a key array has none
+    return x
+
+
+def _program_memory(label: str, fn, operands) -> None:
+    """The ``program.memory`` span of ``fn`` as ``operands`` compiled it."""
+    with host_span("program.memory", label=label) as span:
+        _probing.on = True
+        try:
+            # The call before this built the executable, and JAX keeps it
+            # by the lowering's own key: this looks it up (one cached
+            # trace; no lowering, no compile, persistent cache untouched).
+            stats = fn.lower(*operands).compile().memory_analysis()
+        except Exception:  # noqa: BLE001 - telemetry must not end a run
+            _log.warning("program.memory: %s not read", label, exc_info=True)
+            stats = None
+        finally:
+            _probing.on = False
+        for field, attr in _MEMORY_FIELDS.items():
+            value = getattr(stats, attr, None)
+            if value is not None:
+                span[field] = int(value)
+
+
+def watch_program(fn, label: str, first=None):
+    """The first-call seam of a compiled program (a jitted callable an
+    entry point builds once and keeps): ``first(args)`` runs before the
+    first call (the auditor's certification, ``Trainer._wrap_audit``), and
+    with a process-default recorder a ``program.memory`` span follows it,
+    carrying ``label`` and, per device, from the executable's
+    ``memory_analysis()``: ``argument_bytes``, ``output_bytes``,
+    ``alias_bytes`` (arguments the outputs reuse: donation), ``temp_bytes``
+    (what the program needs BESIDE its operands and outputs while it runs,
+    which the allocator's counters leave out: :func:`device_bytes`) and
+    ``code_bytes``. The span's length is what
+    the reading cost.
+
+    With neither a hook nor a recorder at BUILD time ``fn`` itself comes
+    back: an untraced, unaudited run dispatches the bare jitted callable.
+    The reading pays no compile and no lowering: it runs after the call,
+    on the call's abstract operands (taken before it: the call may donate
+    them), and finds the executable that call built under JAX's own key.
+    The compile timings JAX raises on the way (a cached trace) are not
+    sampled: ``compile.*`` reads what the call itself cost. Later calls go
+    straight through; a second shape the same callable compiles is not
+    read."""
+    if first is None and events.get_default_recorder() is None:
+        return fn
+    pending = [True]
+
+    def watched(*args):
+        if not pending:
+            return fn(*args)
+        pending.clear()
+        if first is not None:
+            first(args)
+        if events.get_default_recorder() is None:
+            return fn(*args)
+        import jax
+
+        operands = jax.tree.map(_abstract, args)
+        out = fn(*args)
+        _program_memory(label, fn, operands)
+        return out
+
+    watched.lower = fn.lower
+    watched.__wrapped__ = fn
+    return watched
 
 
 class PhaseTimer:

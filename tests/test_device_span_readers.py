@@ -7,6 +7,8 @@ run installs it, three calls of a tiny cell queued through
 ``window.queue_call``, the sink collected, the metrics read through
 ``readers.read_all``. What is checked is counts and identities of the
 spans' own numbers: a CPU run has no rate and no device time to report.
+The second half does the same for the metrics that read the device's MEMORY
+off those spans (ISSUE 53).
 """
 
 import contextlib
@@ -16,7 +18,7 @@ import statistics
 import jax
 import pytest
 
-from fps_tpu.obs import events
+from fps_tpu.obs import events, timing
 from perfbench.lib import program_spans, readers, resolve, spec, window
 
 NEW = {"driver.call_device_ms", "driver.starved_share",
@@ -162,8 +164,11 @@ def mesh_devices(n):
         jax.devices = real
 
 
-@pytest.mark.parametrize("cell", ["ials-ml20m.sweeps", "mf-netflix.epochs"])
-def test_three_queued_calls_read_as_the_cells_device_span_metrics(cell):
+def three_queued_calls(cell, placed=lambda system: None):
+    """A tiny form of ``cell`` under a recorder installed the way a traced
+    run installs it, three calls queued through ``window.queue_call`` and
+    waited for; ``placed(system)`` runs once the state is on the device.
+    Returns ``(loaded cell, the recorder's sink)``."""
     loaded = spec.load_cell(spec.load_benchmark(), cell)
     cfg = copy.deepcopy(loaded["config"])
     for part, over in TINY[cfg["name"]].items():
@@ -176,6 +181,7 @@ def test_three_queued_calls_read_as_the_cells_device_span_metrics(cell):
             system = resolve.system_class(cfg, traffic)(cfg, traffic, data,
                                                         seed)
         state = system.place(resolve.reference(cfg).init_tables(seed, cfg))
+        placed(system)
         calls = []
         for _ in range(3):
             state, completion = window.queue_call(system, state)
@@ -184,6 +190,12 @@ def test_three_queued_calls_read_as_the_cells_device_span_metrics(cell):
             c.wait()
     finally:
         events.set_default_recorder(None)  # drains the watcher
+    return loaded, sink
+
+
+@pytest.mark.parametrize("cell", ["ials-ml20m.sweeps", "mf-netflix.epochs"])
+def test_three_queued_calls_read_as_the_cells_device_span_metrics(cell):
+    loaded, sink = three_queued_calls(cell)
     everything = (0.0, float("inf"))       # the whole run as the window
     ctx = {"program_spans": program_spans.collect(sink, *everything),
            "program_span_events": program_spans.collect_events(
@@ -221,3 +233,158 @@ def test_three_queued_calls_read_as_the_cells_device_span_metrics(cell):
             1000 * statistics.median(lengths))
         assert got["driver.call_device_ms"]["unit"] == "ms"
         assert [e["epoch"] for e in spans] == [0, 1, 2]
+
+
+# ---------------------------------------------------------------------------
+# The device's MEMORY off the same spans (ISSUE 53): the reader
+# ``perfbench/readers/span_field_stat.py`` on hand-made contexts, the
+# committed files with the four metrics in all ten cells, and from the
+# program to a metric under a fake ``device_bytes`` (the CPU's
+# ``memory_stats()`` is ``None``: a CPU run has no device memory to report).
+# ---------------------------------------------------------------------------
+
+MEMORY = ["device.resident_gb", "device.call_held_gb",
+          "device.program_temp_gb", "device.span_peak_hbm_gb"]
+CALLS = ["run_indexed", "fit_stream", "run_megastep", "als.half_epoch"]
+
+# -- the reader, on hand-made contexts ---------------------------------------
+
+ROOTS = [
+    _span("als.half_epoch", 0.0, 0.1, solve="user", hbm_open=10, hbm_close=40),
+    _span("als.half_epoch", 0.2, 0.3, solve="item", hbm_open=40, hbm_close=50),
+    _span("run_indexed", 0.4, 0.5, hbm_open=50, hbm_close=57),
+    _span("als.half_epoch", 0.6, 0.7, solve="user", hbm_open=60, hbm_close=92),
+    _span("run_indexed", 0.8, 0.9, hbm_open=70),           # never closed
+    _span("device.run_indexed", 0.5, 1.5, hbm_peak=80, hbm_done=5),
+    _span("device.als.half_epoch", 1.5, 2.5, hbm_peak=95, hbm_done=6),
+    _span("enqueue", 0.45, 0.46),
+]
+HELD = {"span": CALLS, "minus": ["hbm_close", "hbm_open"]}
+
+
+@pytest.mark.parametrize("params,expected", [
+    # Each stat over a field; the events of several names in t0 order.
+    (dict(span=CALLS, field="hbm_open", stat="first"), 10),
+    (dict(span=CALLS, field="hbm_open", stat="max"), 70),
+    (dict(span=CALLS, field="hbm_open", stat="median"), 50),
+    (dict(span="run_indexed", field="hbm_open", stat="first", scale=0.5), 25),
+    # ``minus``: a difference an event; one that lacks a side is passed over.
+    (dict(HELD, stat="median"), statistics.median([30, 10, 7, 32])),
+    (dict(HELD, stat="max"), 32),
+    (dict(HELD, span="run_indexed", stat="median"), 7),
+    # ``where`` picks among the events that CARRY the field: the root spans
+    # of the other entry, which have no ``solve``, stay in.
+    (dict(HELD, where={"solve": "user"}, stat="median"),
+     statistics.median([30, 7, 32])),
+    (dict(HELD, span="als.half_epoch", where={"solve": "item"}), 10),
+    (dict(HELD, span="als.half_epoch", where={"solve": "neither"}), None),
+    # A name ending in ``*`` is a prefix; the default stat is the median.
+    (dict(span="device.*", field="hbm_peak", stat="max", scale=1e-1), 9.5),
+    (dict(span="device.*", field="hbm_done"), 5.5),
+    # Nothing to read: another part, no such span, no such field.
+    (dict(span=CALLS, field="hbm_open", part="setup"), None),
+    (dict(span="program.memory", field="temp_bytes", stat="max"), None),
+    (dict(span="enqueue", field="hbm_open", stat="first"), None),
+])
+def test_span_field_stat_reads_one_statistic_of_one_number(params, expected):
+    got = readers.reader("span_field_stat")(_ctx(*ROOTS), params)
+    assert got == (None if expected is None else pytest.approx(expected))
+
+
+def test_span_field_stat_reads_none_where_the_spans_carry_no_bytes():
+    """A parent commit's spans (or the CPU's) carry no ``hbm_*`` field and
+    there is no ``program.memory`` span: every one of the four metrics is
+    left out of the line, nothing raises."""
+    bare = _ctx(_span("run_indexed", 0.0, 1.0, call=0),
+                _span("device.run_indexed", 0.0, 2.0, t_enqueued=0.0))
+    files = spec.load_cell(spec.load_benchmark(),
+                           "mf-netflix.epochs")["readers"]
+    for ctx in ({}, {"program_span_events": {}}, bare,
+                _ctx(*ROOTS, part="after")):
+        assert readers.read_all({n: files[n] for n in MEMORY}, ctx) == {}
+
+
+# -- the files ----------------------------------------------------------------
+
+def test_committed_benchmark_lists_the_four_metrics_in_all_ten_cells():
+    bench = spec.load_benchmark()
+    spec.validate(bench)
+    cells = [w["name"] for w in bench["workloads"]]
+    assert len(cells) >= 10
+    assert [m["name"] for m in bench["per_layer"]][-4:] == MEMORY
+    peak = next(m for m in bench["per_layer"]
+                if m["name"] == "device.peak_hbm_gb")
+    for m in bench["per_layer"][-4:]:
+        assert m == dict(peak, name=m["name"], source="program_span",
+                         workloads=cells)
+    for cell in cells:
+        files = spec.load_cell(bench, cell)["readers"]
+        assert all(files[n]["reader"] == "span_field_stat" for n in MEMORY)
+    held = files["device.call_held_gb"]["params"]
+    assert held["where"] == {"solve": "user"} and held["span"] == CALLS
+
+
+# -- from the program to the metric, as a traced run goes --------------------
+
+GB = 10 ** 9
+
+
+class _Allocator:
+    """Stands for ``timing.device_bytes``: ``in_use`` is what the test set,
+    the peak the most it ever read."""
+
+    def __init__(self):
+        self.in_use = self.peak = 0
+
+    def __call__(self, where=None):
+        self.peak = max(self.peak, self.in_use)
+        return timing.DeviceBytes(self.in_use, self.peak, 16 * GB)
+
+
+@pytest.mark.parametrize("cell", ["ials-ml20m.sweeps", "mf-netflix.epochs"])
+def test_three_queued_calls_read_as_the_cells_memory_metrics(
+        cell, monkeypatch):
+    alloc = _Allocator()
+    monkeypatch.setattr(timing, "device_bytes", alloc)
+
+    def placed(system):
+        alloc.in_use = 3 * GB            # what set-up left resident
+        real_call = system.call
+
+        def call(*args):                 # every call queued holds 2 GB more
+            out = real_call(*args)
+            alloc.in_use += 2 * GB
+            return out
+
+        monkeypatch.setattr(system, "call", call)
+
+    loaded, sink = three_queued_calls(cell, placed)
+    # The warm-up call is set-up, the two behind it the window.
+    whole = program_spans.collect_events(sink, 0.0, float("inf"))
+    entry = "als.half_epoch" if cell.startswith("ials") else "run_indexed"
+    roots = whole[entry]["window"]
+    opened_at = roots[2 if cell.startswith("ials") else 1]["t0"]
+    ctx = {"program_span_events": program_spans.collect_events(
+        sink, opened_at, float("inf"))}
+    got = readers.read_all({n: loaded["readers"][n] for n in MEMORY}, ctx)
+    assert set(got) == set(MEMORY) and all(
+        v["unit"] == "GB" for v in got.values())
+    assert got["device.resident_gb"]["value"] == 3.0
+    # The test's ``call`` adds its 2 GB after the entry point returned: the
+    # root spans of a call see what the calls before it left (an ALS call
+    # is two sweeps, the second opening where the first closed).
+    assert got["device.call_held_gb"]["value"] == 0.0
+    assert [e["hbm_open"] // GB for e in roots] == (
+        [3, 3, 5, 5, 7, 7] if cell.startswith("ials") else [3, 5, 7])
+    assert all(e["hbm_limit"] == 16 * GB for e in roots)
+    assert got["device.span_peak_hbm_gb"]["value"] == pytest.approx(
+        alloc.peak / GB)
+    programs = whole["program.memory"]["window"]
+    assert got["device.program_temp_gb"]["value"] == pytest.approx(
+        max(e["temp_bytes"] for e in programs) / GB)
+    assert got["device.program_temp_gb"]["value"] > 0
+    # Every program the cell's entry builds was read once, by the warm-up
+    # call, before the window.
+    assert all(e["t1"] <= opened_at for e in programs)
+    labels = sorted(e["label"] for e in programs)
+    assert len(labels) == len(set(labels)) >= 2

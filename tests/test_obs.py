@@ -641,7 +641,7 @@ def test_driver_phases_cover_what_the_drivers_emit():
 
     declared = (set(timing.DRIVER_PHASES) | set(timing.NESTED_PHASES)
                 | set(timing.SETUP_PHASES) | set(timing.CALL_SPANS)
-                | set(timing.SWEEP_PHASES))
+                | set(timing.SWEEP_PHASES) | set(timing.PROGRAM_SPANS))
     root = os.path.join(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))), "fps_tpu")
     emitted = set()
@@ -1017,6 +1017,239 @@ def test_each_entry_point_queues_one_device_span_a_unit(devices8, entry):
     assert all(a["t1"] <= b["t0"] for a, b in zip(spans, spans[1:]))
     if entry == "als.half_epoch":
         assert [e["solve"] for e in spans] == ["user", "item"]
+
+
+# ---------------------------------------------------------------------------
+# The device's memory on the spans (ISSUE 53): obs.timing.device_bytes is
+# the one place memory_stats() is read, the spans carry it under a
+# recorder, watch_program states each compiled program's own.
+# ---------------------------------------------------------------------------
+
+STEP = 1000
+
+
+class _FakeBytes:
+    """Stands for ``timing.device_bytes``: on the calling thread every
+    reading is ``STEP`` bytes over the one before, so a difference of two
+    readings counts the readings between them; the watcher's thread reads
+    a running peak of its own."""
+
+    def __init__(self):
+        self.main = threading.get_ident()
+        self.n = self.units = 0
+
+    def __call__(self, where=None):
+        from fps_tpu.obs import timing
+
+        if threading.get_ident() != self.main:
+            self.units += 1
+            return timing.DeviceBytes(7, 9000 + self.units, 10 ** 6)
+        self.n += 1
+        return timing.DeviceBytes(STEP * self.n, 9000, 10 ** 6)
+
+
+def _lowerings():
+    """Programs lowered, counted as ``perfbench/lib/runner.py`` counts
+    them; returns the list the listener appends to."""
+    seen = []
+
+    def lowered(event, duration, **kw):
+        if event == "/jax/core/compile/jaxpr_to_mlir_module_duration":
+            seen.append(event)
+
+    jax.monitoring.register_event_duration_secs_listener(lowered)
+    return seen
+
+
+_ENTRIES = ["run_indexed", "fit_stream", "run_megastep", "als.half_epoch"]
+_PROGRAMS = {
+    "run_indexed": {"ingest.tbuf", "indexed/sync"},
+    "fit_stream": {"ingest.tbuf", "chunk/sync"},
+    "run_megastep": {"ingest.tbuf", "megastep/sync"},
+    "als.half_epoch": {"ingest.tbuf"} | {
+        f"als.{p}/{s}" for p in ("gram", "accumulate", "solve")
+        for s in ("user", "item")},
+}
+
+
+def _entry_mesh_data(devices8):
+    from fps_tpu.utils.datasets import synthetic_ratings
+
+    mesh = make_ps_mesh(num_shards=2, num_data=1, devices=devices8[:2])
+    data = synthetic_ratings(57, 31, 600, seed=0)
+    data["weight"] = np.ones(len(data["rating"]), np.float32)
+    return mesh, data
+
+
+@pytest.mark.parametrize("entry", _ENTRIES)
+def test_spans_carry_the_devices_memory_under_a_recorder(
+        devices8, monkeypatch, entry):
+    """With a fake ``device_bytes``: the root call span carries
+    ``hbm_open`` / ``hbm_close`` / ``hbm_peak`` / ``hbm_limit`` and the
+    difference of the first two is
+    the fake's step a reading made inside the call; every set-up span and
+    ``epoch_args`` carries ``hbm_delta``; every device span ``hbm_done``
+    and ``hbm_peak`` as the watcher's own thread read them; one
+    ``program.memory`` span a program built."""
+    from fps_tpu.obs import timing
+
+    mesh, data = _entry_mesh_data(devices8)
+    fake = _FakeBytes()
+    monkeypatch.setattr(timing, "device_bytes", fake)
+    sink = obs.MemorySink()
+    with obs_events.default_recorder(obs.Recorder(sinks=[sink])):
+        _, units, _ = _entry_point_run(entry, mesh, data)
+    spans = sink.events("span")
+    roots = [e for e in spans if e["span"] == entry]
+    assert roots
+    for root in roots:
+        inside = [e for e in spans if e is not root and "hbm_delta" in e
+                  and root["t0"] <= e["t0"] and e["t1"] <= root["t1"]]
+        # A span inside the call read twice, the call's own close once.
+        assert root["hbm_close"] - root["hbm_open"] == STEP * (
+            2 * len(inside) + 1)
+        assert root["hbm_limit"] == 10 ** 6 and root["hbm_peak"] == 9000
+    carrying = {e["span"] for e in spans if "hbm_delta" in e}
+    assert carrying == {"dataset.place", "dataset.queues", "dataset.pack",
+                        "plan.build", "epoch_args"} | (
+        set() if entry == "als.half_epoch" else {"init_state"})
+    for e in spans:
+        if e["span"] in ("dataset.place", "init_state", "epoch_args"):
+            assert e["hbm_delta"] == STEP  # no memory span inside it
+    (plan,) = [e for e in spans if e["span"] == "plan.build"]
+    assert plan["hbm_delta"] == 5 * STEP   # the queues' and the pack's too
+    device = _device_spans(sink, units)
+    assert len(device) == units == fake.units
+    assert [e["hbm_peak"] for e in device] == [
+        9001 + i for i in range(units)]
+    assert all(e["hbm_done"] == 7 for e in device)
+    assert all(not k.startswith("hbm_") for e in spans
+               if e["span"] not in carrying | {entry}
+               and not e["span"].startswith("device.") for k in e)
+    programs = [e for e in spans if e["span"] == "program.memory"]
+    assert sorted(e["label"] for e in programs) == sorted(_PROGRAMS[entry])
+    for e in programs:
+        assert all(isinstance(e[k], int) and e[k] >= 0 for k in (
+            "argument_bytes", "output_bytes", "alias_bytes", "temp_bytes",
+            "code_bytes")), e
+    assert any(e["temp_bytes"] > 0 for e in programs)
+
+
+@pytest.mark.parametrize("entry", _ENTRIES)
+def test_no_recorder_reads_no_memory_and_a_recorder_lowers_nothing_more(
+        devices8, monkeypatch, entry):
+    """With NO recorder a ``device_bytes`` that raises is never reached and
+    the entry points dispatch the bare jitted callables; on the CPU, whose
+    ``memory_stats()`` is ``None``, a recorder's spans carry no ``hbm_*``
+    field and nothing fails; and the recorder's run lowers exactly the
+    programs the run without one lowers (``program.memory`` finds the
+    executable its call built: no lowering, no compile)."""
+    from fps_tpu.obs import timing
+
+    mesh, data = _entry_mesh_data(devices8)
+    assert timing.device_bytes() is None  # the CPU counts no memory
+    lowered = _lowerings()
+    _entry_point_run(entry, mesh, data)   # warms jax's own helper programs
+
+    def unreachable(where=None):
+        raise AssertionError("device_bytes reached without a recorder")
+
+    with monkeypatch.context() as m:
+        m.setattr(timing, "device_bytes", unreachable)
+        del lowered[:]
+        _entry_point_run(entry, mesh, data)
+        off = len(lowered)
+    sink = obs.MemorySink()
+    with obs_events.default_recorder(obs.Recorder(sinks=[sink])):
+        del lowered[:]
+        _entry_point_run(entry, mesh, data)
+        on = len(lowered)
+    assert on == off > 0
+    spans = sink.events("span")
+    assert {e["span"] for e in spans} >= {entry, "program.memory",
+                                          "plan.build", "epoch_args"}
+    assert not [k for e in spans for k in e if k.startswith("hbm_")]
+
+
+def test_untraced_unaudited_trainer_keeps_the_bare_jitted_callable(devices8):
+    from fps_tpu.obs import timing
+
+    calls = []
+    fn = jax.jit(lambda x: x + 1)
+    assert obs_events.get_default_recorder() is None
+    assert timing.watch_program(fn, "p") is fn
+    hooked = timing.watch_program(fn, "p", calls.append)
+    assert hooked is not fn and hooked.__wrapped__ is fn
+    assert hooked.lower == fn.lower
+    hooked(np.float32(1.0))
+    hooked(np.float32(2.0))
+    assert len(calls) == 1  # the hook ran before the first call only
+
+
+def test_one_program_memory_span_a_built_program_and_none_on_a_cache_hit(
+        devices8):
+    """A second call of the same entry on the same trainer and plan finds
+    its programs in the trainer's cache: no span more. The audit and the
+    span share ONE first-call wrapper."""
+    from fps_tpu.core.device_ingest import DeviceDataset, DeviceEpochPlan
+    from fps_tpu.models.matrix_factorization import MFConfig, online_mf
+
+    mesh, data = _entry_mesh_data(devices8)
+    sink = obs.MemorySink()
+    with obs_events.default_recorder(obs.Recorder(sinks=[sink])):
+        trainer, _ = online_mf(mesh, MFConfig(num_users=57, num_items=31,
+                                              rank=4))
+        trainer.audit = True  # read where a program is built
+        plan = DeviceEpochPlan(DeviceDataset(mesh, data), num_workers=2,
+                               local_batch=16, route_key="user", seed=3)
+        tables, ls = trainer.init_state(jax.random.key(0))
+        key = jax.random.key(1)
+        for start in (0, 1):
+            tables, ls, _ = trainer.run_indexed(
+                tables, ls, plan, key, epochs=1, start_epoch=start)
+    labels = [e["label"] for e in sink.events("span")
+              if e["span"] == "program.memory"]
+    assert sorted(labels) == ["indexed/sync", "ingest.tbuf"]
+    (fn,) = trainer._compiled.values()
+    # ... and under that one wrapper the jitted callable itself.
+    assert fn._fps_audited and isinstance(
+        fn.__wrapped__, type(jax.jit(lambda: 0)))
+    assert [c.ok for c in trainer.audit.certificates] == [True]
+    assert len([e for e in sink.events("span")
+                if e["span"] == "run_indexed"]) == 2
+
+
+def test_compile_events_under_program_memory_stay_out_of_compile_phases():
+    """The reading traces once more (a cached trace) to find the
+    executable: JAX's timing of that is not a ``compile.*`` sample, so a
+    watched program's compile phases count what a bare call's count."""
+    import jax.numpy as jnp
+
+    from fps_tpu.obs import timing
+
+    def phases(watch):
+        fn = jax.jit(lambda x, s: (x * s + 1).sum())
+        sink = obs.MemorySink()
+        with obs_events.default_recorder(obs.Recorder(sinks=[sink])):
+            if watch:
+                fn = timing.watch_program(fn, "toy")
+            with obs.host_span("enqueue"):
+                fn(jnp.arange(7.0), np.int32(3)).block_until_ready()
+        spans = [e for e in sink.events("span")
+                 if e["span"] == "program.memory"]
+        counts = {}
+        for m in sink.metrics("driver.phase_seconds"):
+            phase = m["labels"]["phase"]
+            counts[phase] = counts.get(phase, 0) + 1
+        return counts, spans
+
+    phases(False)  # warm jnp's own helper programs
+    bare, none = phases(False)
+    watched, spans = phases(True)
+    assert not none and len(spans) == 1 and spans[0]["label"] == "toy"
+    assert spans[0]["temp_bytes"] >= 0 and spans[0]["argument_bytes"] > 0
+    assert watched.pop("program.memory") == 1
+    assert watched == bare and bare["compile.backend"] >= 1
 
 
 # ---------------------------------------------------------------------------

@@ -210,6 +210,100 @@ def test_obs_report_device_section(tmp_path):
     assert report.render_digest(d)["device"] == {}
 
 
+def test_obs_report_memory_section(tmp_path):
+    """ISSUE 53: the device's memory from inside, from the journal alone:
+    what is resident before any call, what a queued call holds, the peak,
+    the limit and what is left, each program's own; a span in both files
+    counts once, and a journal whose spans carry no bytes (the CPU's, an
+    untraced run's) leaves the section empty."""
+    report = _load_report()
+    d = str(tmp_path)
+    GB = 10 ** 9
+
+    def span(name, t0, **kw):
+        return {"kind": "event", "t": t0 + 0.1, "event": "span",
+                "span": name, "t0": t0, "t1": t0 + 0.1, **kw}
+
+    recs = [
+        span("dataset.place", 1.0, hbm_delta=2 * GB),
+        span("dataset.queues", 2.1, hbm_delta=1 * GB),
+        span("plan.build", 2.0, hbm_delta=1 * GB),
+        span("epoch_args", 3.0, hbm_delta=3 * GB),
+        span("epoch_args", 5.0, hbm_delta=3 * GB),
+        span("program.memory", 3.2, label="ingest.tbuf", temp_bytes=7,
+             argument_bytes=3 * GB, output_bytes=3 * GB, alias_bytes=0,
+             code_bytes=11),
+        span("program.memory", 3.5, label="indexed/ssp", temp_bytes=5 * GB,
+             argument_bytes=6 * GB, output_bytes=GB, alias_bytes=GB,
+             code_bytes=13),
+        span("run_indexed", 2.9, hbm_open=3 * GB, hbm_close=6 * GB,
+             hbm_peak=8 * GB, hbm_limit=16 * GB),
+        span("run_indexed", 4.9, hbm_open=6 * GB, hbm_close=9 * GB,
+             hbm_limit=16 * GB),
+        span("run_indexed", 6.9, hbm_open=6 * GB, hbm_close=10 * GB,
+             hbm_limit=16 * GB),
+        span("als.half_epoch", 8.0, solve="user", hbm_open=7 * GB,
+             hbm_close=9 * GB, hbm_limit=16 * GB),
+        span("device.run_indexed", 3.0, t_enqueued=3.0, hbm_done=6 * GB,
+             hbm_peak=9 * GB),
+        span("device.run_indexed", 5.0, t_enqueued=5.0, hbm_done=6 * GB,
+             hbm_peak=11 * GB),
+        span("enqueue", 3.1),
+    ]
+    with open(os.path.join(d, "events-p0.jsonl"), "w") as f:
+        for rec in recs:
+            f.write(json.dumps(rec) + "\n")
+    with open(os.path.join(d, "journal-p0.jsonl"), "w") as f:
+        for rec in recs[:6]:  # the same records again: folded once
+            f.write(json.dumps(rec) + "\n")
+    mem = report.render_digest(d)["memory"]
+    assert mem == {
+        "resident_bytes": 3 * GB, "setup_peak_bytes": 8 * GB,
+        "held_per_call_bytes": {"als.half_epoch": 2 * GB,
+                                "run_indexed": 3 * GB},
+        "peak_bytes": 11 * GB,
+        "done_bytes": {"first": 6 * GB, "last": 6 * GB},
+        "limit_bytes": 16 * GB,
+        "left_bytes": 5 * GB,
+        "setup_bytes": {"dataset.place": 2 * GB, "dataset.queues": GB,
+                        "epoch_args": 6 * GB, "plan.build": GB},
+        "programs": {
+            "indexed/ssp": {"argument_bytes": 6 * GB, "output_bytes": GB,
+                            "alias_bytes": GB, "temp_bytes": 5 * GB,
+                            "code_bytes": 13},
+            "ingest.tbuf": {"argument_bytes": 3 * GB,
+                            "output_bytes": 3 * GB, "alias_bytes": 0,
+                            "temp_bytes": 7, "code_bytes": 11}},
+        "largest_program_temp_bytes": 5 * GB,
+    }
+    # The compiles beside them: the program_compiled events and the
+    # persistent cache's counters (obs.timing.watch_compiles).
+    with open(os.path.join(d, "events-p0.jsonl"), "a") as f:
+        for name, secs in (("run", 81.0), ("build", 0.5)):
+            f.write(json.dumps({"kind": "event", "t": 3.3, "seconds": secs,
+                                "event": "program_compiled",
+                                "fun_name": name}) + "\n")
+        for name, n in (("compile.cache_hits", 1), ("compile.cache_hits", 1),
+                        ("compile.cache_misses", 1)):
+            f.write(json.dumps({"kind": "metric", "t": 3.3, "name": name,
+                                "mtype": "counter", "value": n}) + "\n")
+    assert report.render_digest(d)["compile"] == {
+        "programs": 2, "backend_s": 81.5, "cache_hits": 2,
+        "cache_misses": 1,
+        "slowest": {"fun_name": "run", "seconds": 81.0}}
+    # Spans without bytes: the section is there, empty.
+    os.remove(os.path.join(d, "journal-p0.jsonl"))
+    with open(os.path.join(d, "events-p0.jsonl"), "w") as f:
+        f.write(json.dumps(span("run_indexed", 1.0)) + "\n")
+        f.write(json.dumps(span("device.run_indexed", 1.0,
+                                t_enqueued=1.0)) + "\n")
+    empty = report.render_digest(d)
+    assert empty["memory"] == {}
+    assert empty["compile"] == {"programs": 0, "backend_s": 0.0,
+                                "cache_hits": 0, "cache_misses": 0,
+                                "slowest": None}
+
+
 def test_obs_report_recovery_slo_breach(tmp_path):
     """--recovery-slo-s turns a late paired restart into a
     recovery_slo_breach incident and annotates the recovery section;

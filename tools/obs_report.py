@@ -16,6 +16,23 @@ join on ``run_id``) and prints a single JSON digest:
   device ran them, the share of their extent it had nothing of the
   program's queued (``starved_share``: the host was late), the median
   time a unit waited for the device, and the most units in flight;
+* **memory** — the device's memory from inside (the ``hbm_*`` fields the
+  spans carry under a recorder on a backend that counts its memory, and
+  the ``program.memory`` spans; ``fps_tpu.obs.timing.device_bytes`` /
+  ``watch_program``), in bytes of the fullest local device: what is
+  resident before any call is queued (``resident_bytes``: the first root
+  call span's ``hbm_open``; ``setup_peak_bytes``: the running peak as that
+  call returned, set-up's transients), what each set-up span left there, what one
+  call queued ahead holds from its dispatch (per entry point: the median
+  ``hbm_close - hbm_open``), the running peak as the last unit left it,
+  what was in use as the first and the last unit ended (a leak shows as
+  growth), the allocator's limit and what is left under it, and what each compiled
+  program needs beside its operands and outputs while it runs
+  (``programs``: ``temp_bytes`` and the rest of ``memory_analysis()``);
+* **compile** — compiles from inside (``obs.timing.watch_compiles``):
+  programs the backend compiled or loaded and their seconds, the
+  persistent cache's hits and misses, and the slowest program by name
+  ("which program recompiled, and was the cache warm");
 * **host pipeline** — chunks prefetched and the queue-depth gauge's
   last/max (the gauge samples after every put/get, so with any traffic
   the max is >= 1; a max STUCK at 1 means the driver drained each chunk
@@ -138,7 +155,7 @@ REQUIRED_FIELDS = (
     "schema", "obs_dir", "run_ids", "processes", "chunks", "epochs",
     "steps", "examples", "phase_seconds", "health", "incidents",
     "checkpoint", "checkpoint_saves", "quarantined", "wall_span_s",
-    "prefetch", "device", "tap",
+    "prefetch", "device", "memory", "compile", "tap",
     "hot_tier", "megastep", "tiering", "source_stalls", "analysis",
     "serve", "pod", "net", "recovery",
 )
@@ -194,6 +211,82 @@ def _device_section(spans: dict) -> dict:
     return out
 
 
+# The driver entry points' root spans (``obs.timing.CALL_SPANS``; this tool
+# imports nothing of the package).
+_CALL_SPANS = ("run_indexed", "fit_stream", "run_megastep", "als.half_epoch")
+_PROGRAM_FIELDS = ("argument_bytes", "output_bytes", "alias_bytes",
+                   "temp_bytes", "code_bytes")
+
+
+def _memory_section(spans: list) -> dict:
+    """The device's memory from the journal's span events alone: empty
+    where no span carries bytes (no recorder on the chip, or a backend
+    that counts none). Bytes of the fullest local device."""
+    spans = sorted(spans, key=lambda e: (e["t0"], e["t1"]))
+    calls = [e for e in spans if e["span"] in _CALL_SPANS
+             and "hbm_open" in e and "hbm_close" in e]
+    units = [e for e in spans if e["span"].startswith("device.")
+             and "hbm_peak" in e]
+    programs, setup = {}, collections.defaultdict(int)
+    for e in spans:
+        if e["span"] == "program.memory" and "temp_bytes" in e:
+            programs[str(e.get("label"))] = {
+                k: int(e[k]) for k in _PROGRAM_FIELDS if k in e}
+        if "hbm_delta" in e:
+            setup[e["span"]] += int(e["hbm_delta"])
+    out: dict = {}
+    if calls:
+        held = collections.defaultdict(list)
+        for e in calls:
+            held[e["span"]].append(int(e["hbm_close"]) - int(e["hbm_open"]))
+        out["resident_bytes"] = int(calls[0]["hbm_open"])
+        if "hbm_peak" in calls[0]:
+            # The peak as the FIRST call returned, none of its programs
+            # run: what set-up's transients (data made, tables placed)
+            # had reached. A run's peak at or under it is set-up's.
+            out["setup_peak_bytes"] = int(calls[0]["hbm_peak"])
+        out["held_per_call_bytes"] = {
+            of: _quantile(sorted(v), 0.5) for of, v in sorted(held.items())}
+    peaks = [int(e["hbm_peak"]) for e in units + calls if "hbm_peak" in e]
+    limits = [int(e["hbm_limit"]) for e in calls
+              if e.get("hbm_limit") is not None]
+    if peaks:
+        out["peak_bytes"] = max(peaks)
+    done = [int(e["hbm_done"]) for e in units if "hbm_done" in e]
+    if done:
+        # In use as the first and the last unit ended: a run that holds
+        # more at every completion is leaking buffers.
+        out["done_bytes"] = {"first": done[0], "last": done[-1]}
+    if limits:
+        out["limit_bytes"] = min(limits)
+        if peaks:
+            out["left_bytes"] = min(limits) - max(peaks)
+    if setup:
+        out["setup_bytes"] = dict(sorted(setup.items()))
+    if programs:
+        out["programs"] = dict(sorted(programs.items()))
+        out["largest_program_temp_bytes"] = max(
+            p["temp_bytes"] for p in programs.values())
+    return out
+
+
+def _compile_section(compiled: list, counters) -> dict:
+    """From the ``program_compiled`` events (one a backend compile or
+    cache load, JAX's own timing) and the persistent cache's counters."""
+    slowest = max(compiled, key=lambda e: e.get("seconds", 0.0),
+                  default=None)
+    return {
+        "programs": len(compiled),
+        "backend_s": round(sum(float(e.get("seconds", 0.0))
+                               for e in compiled), 6),
+        "cache_hits": int(counters.get("compile.cache_hits", 0)),
+        "cache_misses": int(counters.get("compile.cache_misses", 0)),
+        "slowest": (None if slowest is None else {
+            "fun_name": slowest.get("fun_name"),
+            "seconds": round(float(slowest.get("seconds", 0.0)), 6)}),
+    }
+
+
 def _read_jsonl(path: str):
     with open(path, encoding="utf-8") as f:
         for line in f:
@@ -239,6 +332,8 @@ def render_digest(obs_dir: str, *, recovery_slo_s: float | None = None) -> dict:
     swap_directions: dict[str, int] = collections.defaultdict(int)
     phases: dict[str, dict] = {}
     device_spans: dict[str, list] = collections.defaultdict(list)
+    memory_spans: list = []  # every span that carries bytes
+    compiled: list = []      # program_compiled events
     health: dict[str, dict] = {}
     incidents: dict[str, list] = {k: [] for k in _INCIDENT_EVENTS}
     run_ids: set[str] = set()
@@ -280,6 +375,14 @@ def render_digest(obs_dir: str, *, recovery_slo_s: float | None = None) -> dict:
                 and all(isinstance(rec.get(k), (int, float))
                         for k in ("t0", "t1", "t_enqueued"))):
             device_spans[rec["span"][len("device."):]].append(rec)
+        if et == "program_compiled":
+            compiled.append(rec)
+        if (et == "span" and isinstance(rec.get("span"), str)
+                and any(k.startswith("hbm_") or k == "temp_bytes"
+                        for k in rec)
+                and all(isinstance(rec.get(k), (int, float))
+                        for k in ("t0", "t1"))):
+            memory_spans.append(rec)
         if (et in ("attempt_first_signal", "attempt_end")
                 and rec.get("t") is not None
                 and rec.get("attempt") is not None):
@@ -410,6 +513,12 @@ def render_digest(obs_dir: str, *, recovery_slo_s: float | None = None) -> dict:
         # phases above time the host QUEUEING; these say how long the
         # device ran what was queued, and whether the host kept it fed.
         "device": _device_section(device_spans),
+        # The device's MEMORY, from inside (obs.timing.device_bytes on the
+        # spans, watch_program's program.memory): what is resident, what a
+        # queued call holds, the peak, the limit, each program's own.
+        "memory": _memory_section(memory_spans),
+        # Compiles, from inside (obs.timing.watch_compiles).
+        "compile": _compile_section(compiled, counters),
         # Host pipeline (fps_tpu.core.prefetch): the 'prefetch' entry in
         # phase_seconds is this worker's time, overlapped with the rest.
         "prefetch": {
