@@ -76,6 +76,7 @@ from fps_tpu.core.store import (
     split_dense,
     split_hot_push_slots,
     split_tiering,
+    watch_distinct_pulls,
     watch_fold_rows,
     watch_routed,
     watch_sum_runs,
@@ -1723,21 +1724,36 @@ class Trainer:
         return dict(out, **{resilience.HOT_TIER_KEY: chan})
 
     @staticmethod
+    @contextlib.contextmanager
+    def _watch_counts():
+        """Open the store's count watches round a traced step: what
+        :meth:`_mount_counts` is handed, ``{channel: {table: {count:
+        scalar}}}`` by the channels of ``resilience.COUNT_KEYS``."""
+        with watch_sum_runs() as summed, watch_fold_rows() as folded, \
+                watch_distinct_pulls() as pulled:
+            yield {resilience.SUM_RUNS_KEY: summed,
+                   resilience.FOLD_ROWS_KEY: folded,
+                   resilience.DISTINCT_PULLS_KEY: pulled}
+
+    @staticmethod
     def _mount_counts(out, noted):
         """Attach what the step's pushes counted on the device
         (``noted``: ``{channel: {table: {count: scalar}}}``, the channels
         of ``resilience.COUNT_KEYS``: ``store.watch_sum_runs``, a table's
         pushes handed and kept and the distinct ids among them, a shard,
         on ``push.sum_runs``; ``store.watch_fold_rows``, the same on
-        ``push.fold_rows``) to the worker out channel, whose sum over the
-        workers makes them the step's: plain leaves
+        ``push.fold_rows``; ``store.watch_distinct_pulls``, a table's
+        pulled ids kept and the distinct ones among them on
+        ``pull.distinct_rows``) to the worker out channel, whose sum over
+        the workers makes them the step's: plain leaves
         ``<channel>.<table>.<count>`` beside the worker's own (a consumer
-        of per-step metrics sees arrays, no nested channel), the first
-        data replica alone carrying them (a replica's shards are handed
-        every replica's pushes). Nothing noted, nothing mounted: a program
+        of per-step metrics sees arrays, no nested channel), a push's from
+        the first data replica alone (a replica's shards are handed every
+        replica's pushes; a pull's ids are the replica's own, and every
+        replica's count). Nothing noted, nothing mounted: a program
         without the routes grows no leaf."""
         leaves = {
-            f"{channel}.{name}.{k}": v
+            f"{channel}.{name}.{k}": (channel, v)
             for channel, tables in noted.items()
             for name, counts in sorted(tables.items())
             for k, v in counts.items()}
@@ -1745,11 +1761,13 @@ class Trainer:
             return out
         if not isinstance(out, dict) or set(leaves) & set(out):
             raise TypeError(
-                "the pushes' counts ride the worker's out channel: it "
+                "the step's counts ride the worker's out channel: it "
                 f"must be a dict without the keys {sorted(leaves)}")
         first = lax.axis_index(DATA_AXIS) == 0
-        return dict(out, **{k: jnp.where(first, v, 0).astype(jnp.float32)
-                            for k, v in leaves.items()})
+        return dict(out, **{
+            k: (v if channel == resilience.DISTINCT_PULLS_KEY
+                else jnp.where(first, v, 0)).astype(jnp.float32)
+            for k, (channel, v) in leaves.items()})
 
     @staticmethod
     def _counts_channels(metrics) -> dict:
@@ -1822,8 +1840,7 @@ class Trainer:
                 key, sub = jax.random.split(key)
                 tapped = self._tap_step(tables, batch_t, local_state, t)
                 with watch_routed() as routed, \
-                        watch_sum_runs() as summed, \
-                        watch_fold_rows() as folded:
+                        self._watch_counts() as counted:
                     (pushes, local_state, out, hp, hcounts,
                      sk, dense) = self._compute_step(
                         tables, snapshot, local_state, batch_t, sub,
@@ -1840,9 +1857,7 @@ class Trainer:
                             tables, bufs, t, pushes, hp)
                 out = self._mount_hot_channel(out, hcounts, delta, tier,
                                               dropped, routed)
-                out = self._mount_counts(
-                    out, {resilience.SUM_RUNS_KEY: summed,
-                          resilience.FOLD_ROWS_KEY: folded})
+                out = self._mount_counts(out, counted)
                 with jax.named_scope("fps.metrics"):
                     out = jax.tree.map(
                         lambda x: lax.psum(lax.psum(x, SHARD_AXIS),
@@ -2121,8 +2136,7 @@ class Trainer:
                     batch = plan.local_batch_at(iargs, widx, t)
                 tapped = self._tap_step(tables, batch, local_state, t)
                 with watch_routed() as routed, \
-                        watch_sum_runs() as summed, \
-                        watch_fold_rows() as folded:
+                        self._watch_counts() as counted:
                     (pushes, local_state, out, hp, hcounts,
                      sk, dense) = self._compute_step(
                         tables, snapshot, local_state, batch, sub,
@@ -2139,9 +2153,7 @@ class Trainer:
                             tables, bufs, t, pushes, hp)
                 out = self._mount_hot_channel(out, hcounts, delta, tier,
                                               dropped, routed)
-                out = self._mount_counts(
-                    out, {resilience.SUM_RUNS_KEY: summed,
-                          resilience.FOLD_ROWS_KEY: folded})
+                out = self._mount_counts(out, counted)
                 with jax.named_scope("fps.metrics"):
                     out = jax.tree.map(
                         lambda x: lax.psum(lax.psum(x, SHARD_AXIS),
@@ -2353,10 +2365,12 @@ class Trainer:
     def _record_counts(rec, chans) -> dict:
         """Fold one unit's HOST count channels (:meth:`_counts_channels`)
         into the recorder (``sum_runs.pushed_ids`` / ``sum_runs.live_ids``,
-        ``fold_rows.handed_ids`` / ``fold_rows.folded_ids``) and return
-        the unit's own sums a table, the journal's ``sum_runs`` /
-        ``fold_rows`` fields: of the ids the pushes were handed and kept,
-        the distinct ones a step, which are what they then paid for."""
+        ``fold_rows.handed_ids`` / ``fold_rows.folded_ids``,
+        ``distinct_pulls.pulled_ids`` / ``distinct_pulls.live_ids``) and
+        return the unit's own sums a table, the journal's ``sum_runs`` /
+        ``fold_rows`` / ``distinct_pulls`` fields: of the ids the pushes
+        (the pulls) were handed and kept, the distinct ones a step, which
+        are what they then paid for."""
         fields = {}
         for channel, tables in chans.items():
             sums = fields[channel] = {}
@@ -2386,8 +2400,9 @@ class Trainer:
         handed back for its ``device.*`` span, the journal's record of
         the epoch's completion (its ``epoch`` event is written at
         dispatch, before the numbers exist); likewise the counts of the
-        pushes on ``push.sum_runs`` and ``push.fold_rows`` (the span's
-        ``sum_runs`` and ``fold_rows`` fields). ``None`` when the unit
+        pushes on ``push.sum_runs`` and ``push.fold_rows`` and of the pulls
+        on ``pull.distinct_rows`` (the span's ``sum_runs``, ``fold_rows``
+        and ``distinct_pulls`` fields). ``None`` when the unit
         carries none of them."""
         ht = (metrics.get(resilience.HOT_TIER_KEY)
               if isinstance(metrics, Mapping) else None)

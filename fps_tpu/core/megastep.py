@@ -68,7 +68,6 @@ from fps_tpu.core.store import (
     sketch_key,
     split_tiering,
     watch_routed,
-    watch_sum_runs,
 )
 from fps_tpu.obs.timing import PhaseTimer, host_span, watch_device
 from fps_tpu.parallel.mesh import (
@@ -201,7 +200,7 @@ def build_megastep_fn(trainer, plan, mode: str, K: int, tick=None):
                     batch = plan.local_batch_at(iargs, widx, t)
                 tapped = trainer._tap_step(tables, batch, local_state, t)
                 with watch_routed() as routed, \
-                        watch_sum_runs() as summed:
+                        trainer._watch_counts() as counted:
                     (pushes, local_state, out, hp, hcounts,
                      sk, _) = trainer._compute_step(
                         tables, snapshot, local_state, batch, sub,
@@ -217,8 +216,7 @@ def build_megastep_fn(trainer, plan, mode: str, K: int, tick=None):
                         tables = trainer._apply_pushes(tables, pushes, hp)
                 out = trainer._mount_hot_channel(out, hcounts, delta,
                                                  tier, dropped, routed)
-                out = trainer._mount_counts(
-                    out, {resilience.SUM_RUNS_KEY: summed})
+                out = trainer._mount_counts(out, counted)
                 with jax.named_scope("fps.metrics"):
                     out = jax.tree.map(_psum_workers, out)
                 out = trainer._mount_tap(out, tapped)

@@ -67,8 +67,14 @@ SUM_RUNS_KEY = "sum_runs"
 # (``push.fold_rows``, a table's own stateful fold): leaves
 # ``fold_rows.<table>.handed_ids`` / ``.folded_ids``.
 FOLD_ROWS_KEY = "fold_rows"
+# Likewise the pulls that read each distinct row of a step once
+# (``pull.distinct_rows``, :func:`fps_tpu.core.store.pull`): leaves
+# ``distinct_pulls.<table>.pulled_ids`` / ``.live_ids``. A pull's ids are
+# a data replica's OWN (a push's are every replica's), so every replica's
+# counts are summed.
+DISTINCT_PULLS_KEY = "distinct_pulls"
 # The step-count channels, in the order the driver mounts and records them.
-COUNT_KEYS = (SUM_RUNS_KEY, FOLD_ROWS_KEY)
+COUNT_KEYS = (SUM_RUNS_KEY, FOLD_ROWS_KEY, DISTINCT_PULLS_KEY)
 
 GUARD_MODES = ("observe", "mask")
 

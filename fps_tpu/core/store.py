@@ -869,6 +869,101 @@ def watch_fold_rows():
     return _watch(_FOLD_ROWS_WATCHES)
 
 
+# What the pulls that read each distinct row of a step once
+# (``pull.distinct_rows``) counted while a watch is open, as
+# ``push.sum_runs``' counts are kept.
+_DISTINCT_PULL_WATCHES: list[dict] = []
+
+
+def watch_distinct_pulls():
+    """Collect ``{table: {"pulled_ids", "live_ids"}}`` while a step is
+    traced: for each table whose :func:`pull` (called with ``table=``)
+    took ``pull.distinct_rows``, two int32 scalars of THIS shard: the ids
+    it was handed and kept (its own, none negative), and the distinct ids
+    among them, which are the rows its gather from the table then pays
+    for. Empty where no pull took the route."""
+    return _watch(_DISTINCT_PULL_WATCHES)
+
+
+def _distinct_pull_route(rps: int, dim: int, num_ids: int, dt) -> bool:
+    """Read each DISTINCT row of a pull from the shard once
+    (``pull.distinct_rows``)? From :func:`pull`'s own shapes, by the
+    predicate ``push.sum_runs`` stands on for the same reason: a table of
+    narrow float rows so large that XLA keeps it transposed in HBM
+    (:func:`fps_tpu.ops._xla_transposed_hbm`), where the plain gather
+    pays some 23 ns for every id it is handed, a repeat or not; on the
+    TPU, backend not ``"xla"``, for more ids than one block of the loop
+    that reads the distinct rows. Any float dtype of that predicate: a
+    gather copies, nothing is rounded. Whether the batch HAS repeats is
+    not in the shapes: :func:`_distinct_rows` reads it from the ids."""
+    use, interpret = ops._use_pallas()
+    return (use and not interpret and num_ids > ops.XLA_SORTED_BLOCK_IDS
+            and ops._xla_transposed_hbm(rps, dim, dt))
+
+
+def _distinct_rows(local_shard: Array, idx: Array, keep: Array | None,
+                   plain: Callable[[], Array], *,
+                   exact: bool) -> tuple[Array, Array, Array]:
+    """``pull.distinct_rows``: ``(rows [N, dim], pulled, live)``, the
+    shard's rows at ``idx [N]`` with ZERO rows where an index is outside
+    the shard or ``keep`` (a mask, where given) says to leave it out;
+    ``pulled`` the entries kept and ``live`` the distinct indices among
+    them. In ``push.sum_runs``' vocabulary: one sort of ``(index or the
+    drop sentinel, position)``, never of rows; the batch LOOKED AT
+    (:func:`_repeats`); where it repeats itself, the distinct indices
+    compacted to the front by a sort of the indices alone, their rows read
+    from the shard ONCE each, a block of
+    :data:`fps_tpu.ops.XLA_SORTED_BLOCK_IDS` at a time
+    (:func:`fps_tpu.ops.gather_rows`, so under ``fps.ops/gather.xla``) and
+    only the blocks that hold a live index, into a buffer of the BATCH'S
+    shape (``[N, dim]``: XLA then keeps buffer and rows in one layout,
+    transposed and in VMEM, and the expand is the 2 ms gather the push
+    makes of its own rows; a buffer of
+    :data:`fps_tpu.ops.SUM_RUNS_MAX_DISTINCT_SHARE` of the ids, all a
+    batch that repeats itself can fill, is copied row-major into HBM
+    first and the expand reads 4.5 ms: chip runs, PR 54); each entry's
+    run number brought back to the batch's order by a third sort, of
+    ``(position, run)``, and its row taken out of the buffer. Where the
+    batch hardly repeats itself the sorts would cost more than the repeats,
+    and ``plain()`` reads every index as before. Either way the rows are
+    the plain gather's bit for bit: a gather copies."""
+    rps, dim = local_shard.shape
+    (N,) = idx.shape
+    C = min(ops.XLA_SORTED_BLOCK_IDS, N)
+    ops.log_route("pull", "distinct_rows", rps, dim, N, "xla_transposed_hbm")
+    kept = (idx >= 0) & (idx < rps)
+    if keep is not None:
+        kept &= keep
+    s, order = lax.sort(
+        (jnp.where(kept, idx, rps), jnp.arange(N, dtype=jnp.int32)),
+        num_keys=2, is_stable=False)  # the keys are distinct
+    first, _ = _run_ends(s)
+    pulled = jnp.sum(kept, dtype=jnp.int32)
+    live = jnp.sum(first & (s < rps), dtype=jnp.int32)
+
+    def distinct():
+        (ids,) = lax.sort((jnp.where(first, s, rps),), is_stable=False)
+
+        def fetch(c, buf):
+            start = jnp.minimum(c * C, N - C)
+            block = ops.gather_rows(
+                local_shard, lax.dynamic_slice(ids, (start,), (C,)),
+                exact=exact)
+            return lax.dynamic_update_slice(buf, block, (start, 0))
+
+        buf = lax.fori_loop(0, (live + C - 1) // C, fetch,
+                            jnp.zeros((N, dim), local_shard.dtype))
+        # The entries left out are the LAST run, number ``live``, and the
+        # buffer's row ``live`` is a zero row: with an entry left out
+        # ``live <= pulled < N``, and that row was either never fetched
+        # or fetched at the sentinel. No mask, no pass more.
+        _, run = lax.sort((order, jnp.cumsum(first.astype(jnp.int32)) - 1),
+                          num_keys=1, is_stable=False)
+        return jnp.take(buf, run, axis=0, mode="clip")
+
+    return lax.cond(_repeats(pulled, live), distinct, plain), pulled, live
+
+
 def pull(
     local_shard: Array,
     ids: Array,
@@ -899,8 +994,18 @@ def pull(
         instead of inheriting training's precision contract.
       data_axis: the mesh's replicated data axis where it is larger than
         one (:func:`push`'s argument): the exchange then stays gathered.
-      table: the table's name, for the route log and the step's
-        ``routed`` flag (:func:`watch_routed`); nothing else reads it.
+      table: the table's name, for the route log, the step's ``routed``
+        flag (:func:`watch_routed`) and its counts
+        (:func:`watch_distinct_pulls`); nothing else reads it.
+
+    Not ``dense``, where the shard is one XLA keeps transposed in HBM
+    (:func:`_distinct_pull_route`: ``pull.distinct_rows`` in the route
+    log) and the batch is seen to repeat its ids, each DISTINCT row is
+    read from the shard once and every requested position is handed its
+    row out of a payload-sized buffer (:func:`_distinct_rows`): the plain
+    gather's rows bit for bit (a gather copies), negative ids and the ids
+    of other shards still zero rows, at the cost of the distinct ids and
+    not of the requests. Both exchanges below read the shard that way.
 
     Not ``dense``, over more than one shard with no data axis, the
     exchange is the OWNER-ROUTED one (:func:`_routes_to_owner`;
@@ -929,6 +1034,30 @@ def pull(
         phys = jnp.where(ids >= 0, id_to_phys(ids, num_shards, rps), -1)
         return ops.gather_rows(full, phys, exact=exact)
 
+    rps, dim = local_shard.shape
+    lanes = _lanes_of_call("pull", local_shard, ids, num_shards=num_shards,
+                           shard_axis=shard_axis, data_axis=data_axis,
+                           table=table)
+    # The pull's own regime, asked about the ids the exchange hands this
+    # shard (the lanes' S x L where it is the owner-routed one, as the
+    # push's is).
+    distinct = _distinct_pull_route(
+        rps, dim, num_shards * ids.shape[0] if lanes is None
+        else lanes.src.shape[0], local_shard.dtype)
+
+    def rows_of(idx, keep=None, **head):
+        """The shard's rows at ``idx`` (zero rows outside it), the one
+        place this pull reads the table: each distinct row once where the
+        regime is the one whose gather pays for every id it is handed,
+        with the step's counts beside the rows; ``keep``: the entries
+        whose rows the caller uses."""
+        def plain():
+            return ops.gather_rows(local_shard, idx, exact=exact, **head)
+
+        if not distinct:
+            return (plain(),)
+        return _distinct_rows(local_shard, idx, keep, plain, exact=exact)
+
     def gathered():
         me = lax.axis_index(shard_axis)
         # Every shard sees every worker's request ids: (S*B,).
@@ -937,35 +1066,33 @@ def pull(
         local_idx = jnp.where(owned, all_ids // num_shards, 0)
         # The head-prefix guarantee only survives when the gathered stream
         # IS the caller's stream (single shard; local_idx == ids there).
-        vals = ops.gather_rows(
-            local_shard, local_idx, hot_rows=hot_rows,
-            head_prefix=head_prefix if num_shards == 1 else 0,
-            exact=exact,
-        )
+        vals, *counts = rows_of(
+            local_idx, owned, hot_rows=hot_rows,
+            head_prefix=head_prefix if num_shards == 1 else 0)
         vals = jnp.where(owned[:, None], vals, jnp.zeros_like(vals))
         # Each worker ends up with its own (B, dim) slice, summed over
         # shards (exactly one shard contributed each row).
-        return lax.psum_scatter(vals, shard_axis, scatter_dimension=0,
-                                tiled=True)
-
-    lanes = _lanes_of_call("pull", local_shard, ids, num_shards=num_shards,
-                           shard_axis=shard_axis, data_axis=data_axis,
-                           table=table)
-    if lanes is None:
-        return gathered()
-    dim = local_shard.shape[1]
+        return (lax.psum_scatter(vals, shard_axis, scatter_dimension=0,
+                                 tiled=True), *counts)
 
     def routed():
         # The ids this shard owns, by the worker that asks (-1 pads: floor
         # division keeps it negative, and it reads a zero row).
         asked = _trade(lanes.ids, shard_axis).reshape(-1)
-        rows = ops.gather_rows(local_shard, asked // num_shards, exact=exact)
+        rows, *counts = rows_of(asked // num_shards)
         back = _trade(rows.reshape(num_shards, -1, dim), shard_axis)
-        return _rows_at(back.reshape(-1, dim),
-                        jnp.where(lanes.slot < asked.shape[0], lanes.slot,
-                                  -1))
+        return (_rows_at(back.reshape(-1, dim),
+                         jnp.where(lanes.slot < asked.shape[0], lanes.slot,
+                                   -1)), *counts)
 
-    return lax.cond(lanes.fits, routed, gathered)
+    # The counts leave the exchange's conditional with the rows, and are
+    # noted for the step outside it.
+    vals, *counts = (gathered() if lanes is None
+                     else lax.cond(lanes.fits, routed, gathered))
+    if counts:
+        _note_counts(_DISTINCT_PULL_WATCHES, table, pulled_ids=counts[0],
+                     live_ids=counts[1])
+    return vals
 
 
 # Device scope of a non-"sum" combine's own work in :func:`push`, whatever

@@ -252,6 +252,16 @@ def default_registry() -> MetricsRegistry:
                    labels=("table",),
                    help="of fold_rows.handed_ids, the distinct ids a step: "
                         "each folded once, its state read and written"),
+        # The pull that reads each distinct row of a step once (store.pull,
+        # ``pull.distinct_rows``): counted on the device like ``sum_runs``.
+        MetricSpec("distinct_pulls.pulled_ids", "counter", unit="ids",
+                   labels=("table",),
+                   help="ids the pulls on pull.distinct_rows were handed "
+                        "and kept (every shard together)"),
+        MetricSpec("distinct_pulls.live_ids", "counter", unit="ids",
+                   labels=("table",),
+                   help="of distinct_pulls.pulled_ids, the distinct ids a "
+                        "step: the rows the gather from the table reads"),
         # Adaptive tiering (fps_tpu.tiering; docs/performance.md
         # "Adaptive tiering"): online hot-set re-ranking + auto-planner.
         MetricSpec("tiering.re_ranks", "counter", unit="re_ranks",

@@ -80,11 +80,13 @@ class Route(NamedTuple):
     op: str          # "gather" | "scatter_add" | "push" (the store's choice
                      # of a combine's branch: fps_tpu.core.store.push) |
                      # "pull" (the driver's read of the SSP snapshot, or
-                     # of the two-tier storage's replica) | "reconcile"
+                     # of the two-tier storage's replica; the store's
+                     # pull of each distinct row once) | "reconcile"
                      # (the hot tier's window-end exchange)
     route: str       # "gather.dim1_head", "scatter_add.xla", ...;
                      # "push.mean_rows" / "push.mean_dense" / "push.fold"
-                     # / "push.acc_runs"; "pull.snapshot"; "pull.hot" /
+                     # / "push.acc_runs"; "pull.snapshot";
+                     # "pull.distinct_rows"; "pull.hot" /
                      # "push.hot" (rows: the head H; dim: the replica's,
                      # the pending buffer's with its count column; ids: a
                      # step's, hot and cold) / "reconcile.hot" (ids 0)
@@ -638,6 +640,59 @@ ACC_RUNS_MIN_IDS_PER_ROW = 0.4
 # step are long and take 274,249 of its 425,984 pushes; a run of up to 32
 # rows stays a tree (about one ulp of the row a step apart from its chain).
 # The constant is the one value run; others were not.
+#
+# The PULL of the same regime (fps_tpu.core.store.pull, "pull.distinct_rows",
+# PR 54). The plain gather out of the HBM-resident transposed table pays for
+# every id it is handed as the scatter does, a repeat or not (21.5 - 22.9 ns
+# an id at 425,984 ids a step, skewed or uniform; 38 at 12,909: not flat in
+# the ids). So the pull sorts (id or sentinel, position), LOOKS at the batch
+# by the push's own test (SUM_RUNS_MAX_DISTINCT_SHARE) and, where it repeats
+# itself, sorts the distinct ids to the front, reads their rows a block of
+# XLA_SORTED_BLOCK_IDS at a time and only the live blocks (gather_rows:
+# "gather.xla" with that many ids) into a buffer of the BATCH'S shape,
+# brings each entry's run number back to the batch's order by a third sort
+# and takes each entry's row out of the buffer; a batch that hardly repeats
+# itself is gathered id by id as before. Any float dtype of the regime's
+# predicate: a gather copies. In time (``tools/bench_scatter.py rows dlrm
+# pull``, one v5 lite chip, builder's chip runs, PR 54, call 259; f32,
+# [33762577,16], 425,984 ids a step, the table a donated loop carry; us a
+# call; the cell's ids: 78,500 distinct, uniform ids: 423,306):
+#
+#                                          the cell's   uniform
+#   gather_rows, plain                     9322         9119
+#   store.pull, plain                      9282         9158
+#   store.pull, pull.distinct_rows         6136         9654  (+5.4 %)
+#   ... each distinct row once WHATEVER the batch       13075 (+43 %)
+#   the parts, each from host-made inputs, the cell's ids:
+#   the first sort of (id, position)       599
+#   the firsts, their count, the sort of the ids alone  508
+#   the block loop over the live ids       2353   (uniform: 9272)
+#   (position, run) back by a sort         565    (by a scalar scatter: 2207)
+#   the expand, 425,984 rows of the buffer 2260
+#
+# 30.0 ns a LIVE id at 78,500 sorted distinct ids (25.8 inside the cell's
+# step) and 21.9 at 423,306: a sorted distinct id is no cheaper than an
+# unsorted one, the gain is the repeats not read, 81.6 % on the cell, less
+# the three sorts and the expand: 9.28 -> 6.14 ms. A batch WITHOUT repeats
+# pays the first sort and the look, 5.4 %; with the look dropped it would
+# pay 43 %. THE BUFFER'S SHAPE IS PART OF THE ANSWER: with the batch's own
+# shape XLA keeps the buffer and the rows the expand hands back in one
+# layout, transposed and in VMEM, and the expand is the gather the push
+# makes of its own rows (2.26 ms; 1.93 in the step); a buffer of
+# SUM_RUNS_MAX_DISTINCT_SHARE of the ids ([256000,16], all a batch that
+# repeats itself can fill: the first form, call 258) is copied ROW-MAJOR
+# into HBM for the expand, whose rows come back row-major and are relaid
+# out twice: 6477 us in isolation, 4.48 ms in the step, the pull no faster
+# than plain in isolation (9558) and the cell at x1.063 where the batch's
+# shape gives x1.163. ``jnp.take`` in its default mode or along the lanes
+# of the transposed buffer compiles to the same copies. Compile-only, v5e
+# (``tests/test_v5e_compile.py`` guards it): the table is an operand of the
+# look's conditional, ``{0,1:T(8,128)}`` everywhere and never copied (the
+# cell's temporaries FALL, 0.904 -> 0.770 GB), and the three sorts carry one
+# or two s32 operands (the step compiles about as fast as its parent).
+# Unmeasured: every other row count, width and id count of the regime
+# (``dlrm-criteo.x4``'s shard of 8,440,645 rows under its lanes' ids among
+# them), distinct shares between 18.4 % and 99.4 %, bfloat16.
 XLA_TRANSPOSED_HBM_ROWS = 1_048_576
 SUM_RUNS_MAX_DISTINCT_SHARE = 0.6
 SUM_RUNS_TREE_MAX_RUN = 32

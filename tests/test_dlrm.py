@@ -424,3 +424,71 @@ def test_sum_runs_counts_arrive_with_a_call_nobody_fetches(devices8,
         "pushed_ids": pushed.sum(), "live_ids": live.sum()}}
     for k, v in span["sum_runs"][EMB_TABLE].items():
         assert rec.counter_value(f"sum_runs.{k}", table=EMB_TABLE) == v
+
+
+@pytest.mark.parametrize("kind", sorted(MESHES) + ["another_cells_shapes"])
+def test_distinct_pull_counts_arrive_with_a_call_nobody_fetches(
+        devices8, monkeypatch, kind):
+    """With ``pull.distinct_rows`` engaged as ``push.sum_runs`` is above
+    (the same regime, the same patched constants), the runner's own call
+    still matches the reference (the pulled rows are the plain pull's bit
+    for bit), its program logs ``pull.distinct_rows`` and what the pulls
+    counted reaches the ``device.run_indexed`` span's ``distinct_pulls``
+    field and the recorder's ``distinct_pulls.*`` counters: every step's
+    pulled ids (6 fields a row, every worker's own together, whatever the
+    mesh) and the distinct ids among them. With the constants as they are
+    (a table of every other cell's kind: too few rows) the program logs no
+    such route, grows no leaf and the span carries no such field."""
+    from fps_tpu import obs
+    from fps_tpu.obs import events
+
+    engaged = kind in MESHES
+    if engaged:
+        monkeypatch.setattr(ops, "_use_pallas", lambda: (True, False))
+        monkeypatch.setattr(ops, "XLA_TRANSPOSED_HBM_ROWS", 1_000)
+        monkeypatch.setattr(ops, "XLA_SORTED_BLOCK_IDS", 16)
+        monkeypatch.setattr(ops, "DENSE_TABLE_BYTES", 0)  # a LARGE table's way
+    cfg, system, init, data_sum = build(kind if engaged else "one",
+                                        monkeypatch)
+    sink = obs.MemorySink()
+    rec = obs.Recorder(sinks=[sink])
+    events.set_default_recorder(rec)
+    ops.clear_routes()
+    try:
+        state, warm = window.queue_call(system, system.place(init))
+        warm.wait()
+    finally:
+        events.set_default_recorder(None)   # waits for the span
+    rec.flush()
+    pulls = {(r.route, r.dim, r.reason) for r in ops.routes_traced()
+             if r.route == "pull.distinct_rows"}
+    (span,) = [e for e in sink.events("span")
+               if e["span"] == "device.run_indexed"]
+    (m,) = warm.host
+    if not engaged:
+        assert not pulls and "distinct_pulls" not in span
+        assert not [k for k in m if k.startswith("distinct_pulls.")]
+        return
+    # once a traced step program; on lanes once a branch of the certificate
+    assert pulls == {("pull.distinct_rows", 8, "xla_transposed_hbm")}
+    numbers, _ = check.compare_call(system, cfg, init,
+                                    system.export(*state), warm.host,
+                                    data_sum)
+    assert numbers["examples"] == 0 and numbers["feed"] == 0
+    assert max(v for k, v in numbers.items()
+               if k.startswith(("table_gap", "update_gap", "loss_gap"))
+               ) < F32_GAP
+    pulled = np.asarray(m[f"distinct_pulls.{EMB_TABLE}.pulled_ids"],
+                        np.float64)
+    live = np.asarray(m[f"distinct_pulls.{EMB_TABLE}.live_ids"], np.float64)
+    n = np.asarray(m["n"], np.float64)
+    # A padded example pulls its ids like any other (and pushes none).
+    workers = MESHES[kind][0] * MESHES[kind][1]
+    np.testing.assert_array_equal(pulled, len(CARDS) * B * workers)
+    assert (pulled >= len(CARDS) * n).all()
+    assert (live <= pulled).all() and (live > 0).all()
+    assert live.sum() < 0.9 * pulled.sum()   # the fields of 3 and 17 rows
+    assert span["distinct_pulls"] == {EMB_TABLE: {
+        "pulled_ids": pulled.sum(), "live_ids": live.sum()}}
+    for k, v in span["distinct_pulls"][EMB_TABLE].items():
+        assert rec.counter_value(f"distinct_pulls.{k}", table=EMB_TABLE) == v

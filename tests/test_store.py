@@ -1486,6 +1486,197 @@ def test_routed_pull_is_the_gathered_pull_bit_for_bit(devices8, S):
     assert "all_to_all" in text and "all_to_all" not in text_g
 
 
+_DISTINCT_PULL_CASES = {
+    # name -> (the local rows of a worker's ids drawn how, pull kwargs)
+    "repeats": ("skewed", {}),
+    "no_repeats": ("distinct", {}),
+    "negative_ids": ("skewed_negative", {}),
+    "one_id": ("one", {}),
+    "exact": ("skewed", {"exact": True}),
+}
+_DISTINCT_PULL_EXCHANGES = {"one_shard": (1, True), "gathered": (8, False),
+                            "routed": (8, True)}
+
+
+def _distinct_pull_case(how, S, num_ids, dim, B=96, seed=54):
+    """``B`` ids a worker on ``S`` shards, each worker's spread evenly over
+    the owners (so every lane fits) but for ``one`` (every id the same: no
+    lane holds them), onto a table in physical layout that holds a
+    ``-0.0`` and a NaN: what a copy keeps and arithmetic would not."""
+    rng = np.random.default_rng(seed)
+    rows = num_ids // S - 1
+    if how == "distinct":
+        row = rng.permutation(rows)[:S * B]
+    else:
+        hot = rng.integers(0, rows, 12)
+        row = np.where(rng.random(S * B) < 0.7,
+                       hot[rng.integers(0, 12, S * B)],
+                       rng.integers(0, rows, S * B))
+    owner = np.concatenate([rng.permutation(np.arange(B) % S)
+                            for _ in range(S)])
+    ids = (row * S + owner).astype(np.int32)
+    if how == "one":
+        ids[:] = 77 * S + S // 2
+    if how == "skewed_negative":
+        ids[rng.random(S * B) < 0.125] = -1
+        ids[5] = -7
+    rps = rows_per_shard(num_ids, S)
+    table = rng.normal(0, 1, (rps * S, dim)).astype(np.float32)
+    live = np.asarray(id_to_phys(ids[ids >= 0], S, rps))
+    table[live[0], 0], table[live[-1], 1] = -0.0, np.nan
+    return table, ids
+
+
+def _engage_distinct_pulls(monkeypatch, block=32):
+    """The regime's constants patched so that a tiny shard answers the
+    predicate, the ops layer routing as on the chip."""
+    monkeypatch.setattr(ops, "_use_pallas", lambda: (True, False))
+    monkeypatch.setattr(ops, "XLA_TRANSPOSED_HBM_ROWS", 1_000)
+    monkeypatch.setattr(ops, "XLA_SORTED_BLOCK_IDS", block)
+
+
+@pytest.mark.parametrize("case", list(_DISTINCT_PULL_CASES))
+@pytest.mark.parametrize("exchange", list(_DISTINCT_PULL_EXCHANGES))
+def test_pull_distinct_rows_is_the_plain_pull_bit_for_bit(
+        devices8, monkeypatch, exchange, case):
+    """The pull through ``pull.distinct_rows`` (a step's ids sorted, each
+    distinct row read from the shard once, a block at a time, every
+    requested position handed its row out of that buffer) returns the plain
+    pull's rows BIT FOR BIT, a ``-0.0`` and a NaN included: on one shard,
+    on what the gathered exchange hands each of eight (ids of the other
+    shards among them) and on the owner-routed exchange's lanes; with
+    repeats, without (the look at the batch then reads every id by the
+    plain gather, in the graph), with negative ids (zero rows), with every
+    id the same (no lane holds them: the gathered fallback, the route still
+    taken), and under ``exact=True``."""
+    how, kw = _DISTINCT_PULL_CASES[case]
+    S, routed = _DISTINCT_PULL_EXCHANGES[exchange]
+    num_ids, dim, B = 3_000 * S, 16, 96
+    table, ids = _distinct_pull_case(how, S, num_ids, dim, B)
+    rps, handed, plain_gather = rows_per_shard(num_ids, S), [], ops.gather_rows
+
+    def spy(t, i, **k):
+        jax.debug.callback(lambda i: handed.append(i.shape[0]), i)
+        return plain_gather(t, i, **k)
+
+    def pulled(engaged):
+        with monkeypatch.context() as m:
+            if engaged:
+                _engage_distinct_pulls(m)
+                m.setattr(ops, "gather_rows", spy)
+            out = _exchange_on(devices8, S, _pulls(S, **kw), table, ids,
+                               np.zeros((S * B, dim), np.float32),
+                               routed=routed)
+            jax.effects_barrier()
+            return out
+
+    want, _, log_plain, _ = pulled(False)
+    got, flag, log, _ = pulled(True)
+    np.testing.assert_array_equal(got.view(np.uint32), want.view(np.uint32))
+    live = ids >= 0
+    np.testing.assert_array_equal(   # the exchange's sum reads -0.0 as 0.0
+        got[live], table[np.asarray(id_to_phys(ids[live], S, rps))])
+    assert not got[~live].any()
+    assert not [r for r in log_plain if r.route == "pull.distinct_rows"]
+    L = store_mod._lane_width(B, S)
+    fits = routed and S > 1 and how != "one"
+    sizes = {"one_shard": [B], "gathered": [S * B],
+             "routed": [S * L, S * B]}[exchange]
+    assert [(r.route, r.rows, r.dim, r.ids, r.reason) for r in log
+            if r.route == "pull.distinct_rows"] == [
+        ("pull.distinct_rows", rps, dim, n, "xla_transposed_hbm")
+        for n in sizes]
+    if S > 1 and routed:
+        assert flag.tolist() == [int(fits)] * S
+    # What the table's gather was handed on each shard: blocks of 32 ids
+    # where the batch repeats itself, all of the exchange's ids at once
+    # where it does not.
+    if how == "distinct":
+        assert handed == [S * L if fits else S * B] * S
+    else:
+        # ceil(live / block) blocks a shard, and no more.
+        mine = [np.unique(ids[live & (ids % S == d)]) for d in range(S)]
+        assert handed == [32] * sum(-(-len(m) // 32) for m in mine)
+
+
+@pytest.mark.parametrize("S", [1, 8])
+def test_pull_distinct_rows_counts_the_ids_it_kept_and_the_distinct(
+        devices8, monkeypatch, S):
+    """What the route counts for the step (``watch_distinct_pulls``):
+    every shard's own ids kept (none negative, none another shard's) and
+    the distinct ids among them, against ``numpy.unique`` on a seeded
+    batch; and the route log holds ``pull.distinct_rows`` once."""
+    _engage_distinct_pulls(monkeypatch)
+    monkeypatch.setattr(store_mod, "_routes_to_owner", lambda *a: False)
+    num_ids, dim, B = 3_000 * S, 8, 96
+    table, ids = _distinct_pull_case("skewed_negative", S, num_ids, dim, B)
+    mesh = make_ps_mesh(num_shards=S, devices=devices8[:S])
+
+    def counted_pull(t, i):
+        with store_mod.watch_distinct_pulls() as noted:
+            out = pull(t, i, num_shards=S, data_axis=None, table="emb")
+        assert set(noted) == {"emb"}
+        return out, jnp.stack([noted["emb"][k] for k in (
+            "pulled_ids", "live_ids")])[None]
+
+    ops.clear_routes()
+    _, counts = jax.jit(jax.shard_map(
+        counted_pull, mesh=mesh, in_specs=(P(SHARD_AXIS, None), P(SHARD_AXIS)),
+        out_specs=(P(SHARD_AXIS, None), P(SHARD_AXIS, None)),
+        check_vma=False))(
+            jax.device_put(jnp.asarray(table),
+                           NamedSharding(mesh, P(SHARD_AXIS, None))),
+            jnp.asarray(ids))
+    counts = np.asarray(counts)
+    assert counts.dtype == np.int32 and counts.shape == (S, 2)
+    for d in range(S):
+        mine = ids[(ids >= 0) & (ids % S == d)]
+        assert counts[d].tolist() == [len(mine), len(np.unique(mine))]
+    assert counts.sum(axis=0).tolist() == [
+        (ids >= 0).sum(), len(np.unique(ids[ids >= 0]))]
+    assert [r.route for r in ops.routes_traced()
+            if r.op == "pull"] == ["pull.distinct_rows"]
+
+
+@pytest.mark.parametrize("cell,rps,dim,num_ids,dtype,engages", [
+    ("mf-netflix.epochs", 17_770, 10, 32_768, jnp.float32, False),
+    ("pa-rcv1.epochs", 47_236, 1, 1_048_576, jnp.float32, False),
+    ("mf-netflix.x4", 4_443, 10, 131_072, jnp.float32, False),
+    ("w2v-1bw.epochs", 1_115_011, 300, 49_182, jnp.float32, False),
+    ("lr-criteo.epochs", 1_000_000, 2, 425_997, jnp.float32, False),
+    ("ials-ml20m.sweeps", 138_493, 64, 138_493, jnp.float32, False),
+    ("mf-netflix-topk.epochs", 17_770, 10, 32_768, jnp.float32, False),
+    ("w2v-1bw-hot.x4", 278_753, 300, 61_504, jnp.float32, False),
+    ("dlrm-criteo.epochs", 33_762_577, 16, 425_984, jnp.float32, True),
+    ("kge-wikidata5m.epochs", 393_216, 1_000, 49_152, jnp.float32, False),
+    # The same table in bfloat16 (a copy rounds nothing: it engages, where
+    # the push's sums stay out), wider than float32, under one block of
+    # ids, as a shard of four (what the queued ``dlrm-criteo.x4``'s lanes
+    # hand it), and with fewer rows than any measured.
+    ("dlrm bf16", 33_762_577, 16, 425_984, jnp.bfloat16, True),
+    ("dlrm f64", 33_762_577, 16, 425_984, jnp.float64, False),
+    ("dlrm one block", 33_762_577, 16, 1_024, jnp.float32, False),
+    ("dlrm-criteo.x4", 8_440_645, 16, 4 * 133_120, jnp.float32, True),
+    ("unmeasured band", 786_432, 16, 425_984, jnp.float32, False),
+])
+def test_distinct_pull_route_from_shapes_alone(monkeypatch, cell, rps, dim,
+                                               num_ids, dtype, engages):
+    """``pull.distinct_rows`` engages by ``pull``'s own shapes, where
+    ``push.sum_runs`` does and for its reason: of the ten cells' pulled
+    tables only ``dlrm-criteo.epochs``' (narrow float rows, so many that
+    XLA keeps the table transposed in HBM, under more ids than a block);
+    off the TPU, or under the ``"xla"`` backend, nothing."""
+    from fps_tpu.core.store import _distinct_pull_route
+
+    assert not _distinct_pull_route(rps, dim, num_ids, dtype)  # the CPU's
+    monkeypatch.setattr(ops, "_use_pallas", lambda: (True, False))
+    assert _distinct_pull_route(rps, dim, num_ids, dtype) is engages
+    # Off the TPU under the forced "pallas" backend; under "xla" anywhere.
+    for elsewhere in ((True, True), (False, False)):
+        monkeypatch.setattr(ops, "_use_pallas", lambda: elsewhere)
+        assert not _distinct_pull_route(rps, dim, num_ids, dtype)
+
+
 _ROUTED_PUSHES = {
     # name -> (push kwargs, the table's ids and width (a mean takes the
     # branch named at them), the branch the route log names, float64

@@ -1077,6 +1077,90 @@ def test_dlrm_push_sums_its_runs_and_scatters_by_blocks_in_place(
 KGE = (393_216, 822, 500, 4_096, 10)  # entities, relations, rank, B, N
 
 
+def test_dlrm_pull_reads_each_distinct_row_once_and_copies_no_table(
+        topo, monkeypatch):
+    """The same table through ``store.pull`` (PR 54): the routes are
+    ``pull.distinct_rows`` and the two ``gather.xla`` reads it chooses
+    between (a block of 1,024 ids a trip of its loop; all the ids at
+    once); the table is ``{0,1:T(8,128)}`` everywhere, an operand of the
+    look's conditional that is never copied or transposed; the buffer of
+    the distinct rows has the batch's shape, and XLA keeps it and the rows
+    the expand hands back in ONE layout, transposed and in VMEM, the
+    expand reading the buffer as the block loop left it (a shorter buffer
+    it copies row-major into HBM first, and the expand then costs twice as
+    much: chip runs, PR 54); and the three sorts carry ids and positions
+    alone."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from fps_tpu.core import store
+    from fps_tpu.parallel.mesh import DATA_AXIS, SHARD_AXIS
+
+    monkeypatch.setattr(ops, "_use_pallas", lambda: (True, False))
+    R, D, B = 33_762_577, 16, 425_984
+    mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1),
+                (DATA_AXIS, SHARD_AXIS))
+
+    def steps(t, ids):
+        def pulled(t, i):
+            rows = store.pull(t, i, num_shards=1, data_axis=None,
+                              table="emb")
+            # The next step's table depends on this one's rows.
+            return lax.dynamic_update_slice(t, rows[:1, :1], (0, 0)), \
+                jnp.sum(rows)
+
+        def body(t, i):
+            return jax.shard_map(
+                pulled, mesh=mesh, in_specs=(P(SHARD_AXIS, None), P()),
+                out_specs=(P(SHARD_AXIS, None), P()), check_vma=False)(t, i)
+        return lax.scan(body, t, ids)
+
+    ops.clear_routes()
+    args = [jax.ShapeDtypeStruct(s, d, sharding=NamedSharding(mesh, p))
+            for s, d, p in (((R, D), jnp.float32, P(SHARD_AXIS, None)),
+                            ((2, B), jnp.int32, P()))]
+    c = jax.jit(steps, donate_argnums=0).lower(*args).compile()
+    assert [(r.route, r.rows, r.dim, r.ids, r.reason)
+            for r in ops.routes_traced()] == [
+        ("pull.distinct_rows", R, D, B, "xla_transposed_hbm"),
+        ("gather.xla", R, D, ops.XLA_SORTED_BLOCK_IDS, "shape"),
+        ("gather.xla", R, D, B, "shape")]
+    text = c.as_text()
+    table = f"f32[{R},{D}]"
+    layouts = set(re.findall(re.escape(table) + r"(\{[^}]*\})", text))
+    assert layouts == {"{0,1:T(8,128)}"}, layouts
+    assert not re.search(
+        r"= " + re.escape(table) + r"\S* (copy|transpose)\(", text)
+    # The look: one conditional, the table an operand of both branches.
+    (look,) = [ln for ln in text.splitlines() if " conditional(" in ln]
+    branches = re.search(r"branch_computations=\{%([\w.]+), %([\w.]+)\}",
+                         look).groups()
+    for name in branches:
+        (head,) = [ln for ln in text.splitlines()
+                   if ln.startswith(f"%{name} (")]
+        assert table in head, head
+    # The distinct rows are gathered inside the block loop, under the
+    # route's own scope.
+    assert re.search(r"branch_1_fun/while/body/fps\.ops/gather\.xla/", text)
+    # The expand reads the buffer as the loop left it, in the same layout.
+    buffer = f"f32[{B},{D}]" + "{0,1:T(8,128)S(1)}"
+    (expand,) = [ln for ln in text.splitlines()
+                 if "branch_1_fun/jit(_take)/gather" in ln
+                 and "kind=kCustom" in ln]
+    assert f" = {buffer} fusion(" in expand, expand
+    source = re.search(r" fusion\((%[\w.]+),", expand).group(1)
+    (left,) = [ln for ln in text.splitlines()
+               if ln.lstrip().startswith(f"{source} = ")]
+    assert f" = {buffer} get-tuple-element(" in left and (
+        "branch_1_fun/while" in left), left
+    sorts = [ln for ln in text.splitlines() if " sort(" in ln]
+    assert len(sorts) == 3 and all(
+        re.search(r"= \(?s32\[\d+\]\S*(, s32\[\d+\]\S*\))? sort\(", ln)
+        for ln in sorts), sorts
+    memory = c.memory_analysis()
+    assert memory.temp_size_in_bytes < 64 << 20         # the table: 2.16 GB
+    assert memory.alias_size_in_bytes >= R * D * 4      # donated, in place
+
+
 @pytest.fixture(scope="module")
 def kge_step(topo):
     """``kge-wikidata5m.epochs``'s step at the cell's own size through the

@@ -22,7 +22,13 @@ that shape: the ways to form the sums at width 16, the block loop alone
 on sorted ids, and the whole push plain against summed under the cell's
 ids, uniform ids and a half-and-half batch; ``rows dlrm edge``: the push
 plain against summed over the table's rows, the sweep
-``ops.XLA_TRANSPOSED_HBM_ROWS`` stands on.
+``ops.XLA_TRANSPOSED_HBM_ROWS`` stands on. ``rows dlrm pull`` (PR 54): the
+pull that reads each distinct row of a step once (``pull.distinct_rows``)
+at that shape: the plain gather against ``store.pull`` as shipped under
+the cell's ids and under uniform ids (what a batch without repeats pays),
+and the form's parts apart (first sort, compaction, the block loop at the
+live ids, the way back to the batch's order by a sort and by a scalar
+scatter, the expand).
 
 ``mean`` arm: the store's per-id mean push (``fps_tpu.core.store.push``,
 ``combine="mean"``) by its accumulator branch against its row branch over
@@ -44,6 +50,7 @@ and ``ops.ACC_RUNS_MIN_IDS_PER_ROW`` stand on; ``fold probes``: the ways to
 scatter the summed runs and to sum them, at the cell's shape.
 """
 
+import contextlib
 import os
 import sys
 import time
@@ -400,6 +407,42 @@ def _sum_forms():
             "shipped": shipped}
 
 
+def _reader(out):
+    """``read(name, us_a_step, op)``: one reading into ``out`` (``<name>_us``
+    and its compile's seconds; what does not fit or compile as its error),
+    printed as it is made."""
+    import json
+
+    def read(name, us_a_step, op):
+        try:
+            out[f"{name}_us"], out["compile_s"][name] = us_a_step(op)
+        except Exception as e:  # noqa: BLE001 (does not fit / compile)
+            out[f"{name}_us"] = f"{type(e).__name__}: {str(e)[:200]}"
+        print(json.dumps({name: out[f"{name}_us"],
+                          "compile_s": out["compile_s"].get(name)}),
+              flush=True)
+    return read
+
+
+@contextlib.contextmanager
+def _regime(route, share=None):
+    """While a store call is traced: ``ops._xla_transposed_hbm`` answering
+    ``route`` whatever the rows (so ``push.sum_runs`` and
+    ``pull.distinct_rows`` engage or stay out), and
+    ``ops.SUM_RUNS_MAX_DISTINCT_SHARE`` at ``share`` where given (2: the
+    batch taken as repeating whatever it holds; -1: never)."""
+    import fps_tpu.ops as ops
+
+    keep = ops.XLA_TRANSPOSED_HBM_ROWS, ops.SUM_RUNS_MAX_DISTINCT_SHARE
+    ops.XLA_TRANSPOSED_HBM_ROWS = 0 if route else 1 << 62
+    if share is not None:
+        ops.SUM_RUNS_MAX_DISTINCT_SHARE = share
+    try:
+        yield
+    finally:
+        ops.XLA_TRANSPOSED_HBM_ROWS, ops.SUM_RUNS_MAX_DISTINCT_SHARE = keep
+
+
 def _store_sum_push(route, share=None, R=DLRM_R, D=DLRM_D):
     """``store.push`` of the additive sum on a one-shard mesh with
     ``store._sum_runs_route`` answering ``route`` whatever the shape while
@@ -408,26 +451,18 @@ def _store_sum_push(route, share=None, R=DLRM_R, D=DLRM_D):
     the sums always formed; -1: never, the sorted batch handed on)."""
     from jax.sharding import PartitionSpec as P
 
-    import fps_tpu.ops as ops
     from fps_tpu.parallel.mesh import SHARD_AXIS, make_ps_mesh
     from fps_tpu.core import store
 
     mesh = make_ps_mesh(num_shards=1, devices=jax.devices()[:1])
 
     def op(t, i):
-        keep = ops.XLA_TRANSPOSED_HBM_ROWS, ops.SUM_RUNS_MAX_DISTINCT_SHARE
-        ops.XLA_TRANSPOSED_HBM_ROWS = 0 if route else 1 << 62
-        if share is not None:
-            ops.SUM_RUNS_MAX_DISTINCT_SHARE = share
-        try:
+        with _regime(route, share):
             return jax.shard_map(
                 lambda t, i: store.push(t, i, _dlrm_deltas(i, D),
                                         num_shards=1, data_axis=None),
                 mesh=mesh, in_specs=(P(SHARD_AXIS, None), P()),
                 out_specs=P(SHARD_AXIS, None), check_vma=False)(t, i)
-        finally:
-            (ops.XLA_TRANSPOSED_HBM_ROWS,
-             ops.SUM_RUNS_MAX_DISTINCT_SHARE) = keep
     return op
 
 
@@ -442,21 +477,12 @@ def dlrm_sums(forms=("b_full", "b_blocks", "c", "shipped")):
     78,500 live; uniform: 423,300), and on all of a step's ids sorted,
     repeats and all; and each way to form the sums, no scatter
     (:func:`_sum_forms`; ``rows dlrm sums a`` adds the 17-operand sorts)."""
-    import json
 
     import fps_tpu.ops as ops
 
     R, D, B = DLRM_R, DLRM_D, DLRM_B
     out = {"rows": R, "dim": D, "ids": B, "compile_s": {}}
-
-    def read(name, us_a_step, op):
-        try:
-            out[f"{name}_us"], out["compile_s"][name] = us_a_step(op)
-        except Exception as e:  # noqa: BLE001 (does not fit / compile)
-            out[f"{name}_us"] = f"{type(e).__name__}: {str(e)[:200]}"
-        print(json.dumps({name: out[f"{name}_us"],
-                          "compile_s": out["compile_s"].get(name)}),
-              flush=True)
+    read = _reader(out)
 
     def plain(t, i):
         return xla_scatter(t, i, _dlrm_deltas(i))
@@ -497,6 +523,147 @@ def dlrm_sums(forms=("b_full", "b_blocks", "c", "shipped")):
     return out
 
 
+def _pull_timer(R, xs, D=DLRM_D, B=DLRM_B):
+    """``us_a_step(op)`` of ``op(table [R, D], acc [B, D], x) -> arrays``
+    scanned over the steps of ``xs`` (a step's host-made inputs, stacked),
+    the table a DONATED loop carry written at one corner from ``acc`` each
+    step (no step's reads can be hoisted or shared), what ``op`` returns
+    folded into ``acc``: added where it is a ``[B, D]`` array, summed into
+    one corner otherwise. The best of two timed calls after the one that
+    compiles; and that compile's seconds."""
+    steps = jax.tree.leaves(xs)[0].shape[0]
+    xs = jax.tree.map(jnp.asarray, xs)
+
+    def us_a_step(op):
+        def body(carry, x):
+            t, acc = carry
+            t = lax.dynamic_update_slice(t, acc[:1, :1], (0, 0))
+            for out in jax.tree.leaves(op(t, acc, x)):
+                acc = (acc + out if out.shape == acc.shape else
+                       acc.at[0, 0].add(jnp.sum(out.astype(jnp.float32))))
+            return (t, acc), None
+
+        tab = jax.jit(lambda k: 0.01 * jax.random.normal(k, (R, D)))(
+            jax.random.key(1))
+        f = jax.jit(lambda t, xs: lax.scan(
+            body, (t, jnp.zeros((B, D), jnp.float32)), xs)[0],
+            donate_argnums=0)
+        took = []
+        for _ in range(3):  # the first call compiles
+            t0 = time.perf_counter()
+            tab, acc = f(tab, xs)
+            np.asarray(acc[0, 0]), np.asarray(tab[0, 0])
+            took.append(time.perf_counter() - t0)
+        del tab, acc
+        best = min(took[1:])
+        return round(best / steps * 1e6, 1), round(took[0] - best, 1)
+    return us_a_step
+
+
+def _store_pull(route, share=None, D=DLRM_D):
+    """``store.pull`` on a one-shard mesh with
+    ``store._distinct_pull_route`` answering ``route`` whatever the shape
+    while it is traced, and ``ops.SUM_RUNS_MAX_DISTINCT_SHARE`` at
+    ``share`` where given (2: each distinct row read once whatever the
+    batch)."""
+    from jax.sharding import PartitionSpec as P
+
+    from fps_tpu.parallel.mesh import SHARD_AXIS, make_ps_mesh
+    from fps_tpu.core import store
+
+    mesh = make_ps_mesh(num_shards=1, devices=jax.devices()[:1])
+
+    def op(t, acc, i):
+        with _regime(route, share):
+            return jax.shard_map(
+                lambda t, i: store.pull(t, i, num_shards=1, data_axis=None),
+                mesh=mesh, in_specs=(P(SHARD_AXIS, None), P()),
+                out_specs=P(), check_vma=False)(t, i)
+    return op
+
+
+def dlrm_pull():
+    """``pull.distinct_rows`` at ``dlrm-criteo.epochs``' shape, us a call,
+    under the cell's ids and under ids uniform over the rows (under each
+    the distinct ids a step): the plain gather; ``store.pull`` plain and
+    as shipped (the batch looked at: a batch without repeats pays the
+    first sort beside the plain gather) and with each distinct row read
+    once WHATEVER the batch (what dropping the look would cost it); and,
+    under the cell's ids, the form's parts apart, each from host-made
+    inputs: the first sort of ``(id, position)``; the compaction (the
+    runs' firsts, their running count and the sort of the ids alone); the
+    block loop over the live ids alone, into a buffer of the batch's
+    shape; the run numbers brought back to the batch's order by
+    a sort of ``(position, run)`` and by a scalar scatter; and the expand,
+    a gather of the ``B`` rows out of that buffer."""
+    import fps_tpu.ops as ops
+    from fps_tpu.core import store
+
+    R, D, B, C = DLRM_R, DLRM_D, DLRM_B, ops.XLA_SORTED_BLOCK_IDS
+    out = {"rows": R, "dim": D, "ids": B, "compile_s": {}}
+    read = _reader(out)
+    pos = jnp.arange(B, dtype=jnp.int32)
+
+    def blocks(M):
+        def op(t, acc, x):
+            ids, live = x["compact"][:M], x["live"]
+
+            def fetch(c, buf):
+                start = jnp.minimum(c * C, M - C)
+                block = ops.gather_rows(
+                    t, lax.dynamic_slice(ids, (start,), (C,)))
+                return lax.dynamic_update_slice(buf, block, (start, 0))
+            return lax.fori_loop(0, (live + C - 1) // C, fetch,
+                                 jnp.zeros((M, D), t.dtype))
+        return op
+
+    def compact(t, acc, x):
+        first = store._run_ends(x["s"])[0]
+        return (lax.sort((jnp.where(first, x["s"], R),), is_stable=False)[0],
+                jnp.cumsum(first.astype(jnp.int32)) - 1)
+
+    parts = {
+        "sort_first": lambda t, acc, x: lax.sort(
+            (x["ids"], pos), num_keys=2, is_stable=False),
+        "compact": compact,
+        "blocks_live": blocks(B),
+        "back_sort": lambda t, acc, x: lax.sort(
+            (x["order"], x["run"]), num_keys=1, is_stable=False)[1],
+        "back_scatter": lambda t, acc, x: jnp.zeros((B,), jnp.int32).at[
+            x["order"]].set(x["run"], unique_indices=True),
+        "expand": lambda t, acc, x: jnp.take(
+            acc, x["run_back"], axis=0, mode="clip"),
+    }
+    for kind in ("cell", "uniform"):
+        ids = _dlrm_ids(kind)
+        order = np.argsort(ids, axis=1, kind="stable").astype(np.int32)
+        s = np.take_along_axis(ids, order, axis=1)
+        first = np.concatenate(
+            [np.ones((len(ids), 1), bool), s[:, 1:] != s[:, :-1]], axis=1)
+        run = (np.cumsum(first, axis=1) - 1).astype(np.int32)
+        run_back = np.empty_like(run)
+        np.put_along_axis(run_back, order, run, axis=1)
+        live = first.sum(axis=1).astype(np.int32)
+        out[f"distinct_{kind}"] = int(live.mean())
+        us_a_step = _pull_timer(R, {
+            "ids": ids, "s": s, "order": order, "run": run,
+            "run_back": run_back, "compact": _compacted(ids, R),
+            "live": live})
+        read(f"gather_plain_{kind}", us_a_step,
+             lambda t, acc, x: ops.gather_rows(t, x["ids"]))
+        for name, op in (("plain", _store_pull(False)),
+                         ("shipped", _store_pull(True)),
+                         ("always_distinct", _store_pull(True, 2.0))):
+            read(f"pull_{name}_{kind}", us_a_step,
+                 lambda t, acc, x, op=op: op(t, acc, x["ids"]))
+        if kind == "cell":
+            for name, op in parts.items():
+                read(f"{name}_cell", us_a_step, op)
+        else:
+            read("blocks_live_uniform", us_a_step, parts["blocks_live"])
+    return out
+
+
 def dlrm_edge_point(R, kind):
     """us a call of the additive push into ``[R, 16]`` under 425,984 ids,
     plain against ``push.sum_runs`` (both predicates answering yes
@@ -519,7 +686,8 @@ def rows_sweep(args):
     ``rows quick``: Netflix's user block alone. ``rows dlrm``: the plain
     ops and the resident packed form at ``dlrm-criteo.epochs``' shape;
     ``rows dlrm sums [a]`` and ``rows dlrm edge``: ``push.sum_runs`` there
-    and over the rows. One JSON line a point on stdout, all of them in
+    and over the rows; ``rows dlrm pull``: ``pull.distinct_rows`` there.
+    One JSON line a point on stdout, all of them in
     ``chiprun_out/bench_scatter_rows.jsonl``."""
     import json
 
@@ -528,6 +696,8 @@ def rows_sweep(args):
     if args[:2] == ["dlrm", "sums"]:
         forms = ("b_full", "b_blocks", "c", "shipped") + tuple(args[2:])
         return _write_points("rows", [(dlrm_sums, (forms,))])
+    if args == ["dlrm", "pull"]:
+        return _write_points("rows", [(dlrm_pull, ())])
     if args == ["dlrm", "edge"]:
         return _write_points("rows", [
             (dlrm_edge_point, (R, kind)) for R in DLRM_EDGE_R
@@ -1135,7 +1305,7 @@ if __name__ == "__main__":
     else:
         raise SystemExit(
             f"unknown args {sys.argv[1:]!r} — usage: bench_scatter.py "
-            "dim1|rows [quick|dlrm [sums [a]|edge]]|mean [counts]|wide [quick]|"
+            "dim1|rows [quick|dlrm [sums [a]|edge|pull]]|mean [counts]|wide [quick]|"
             "fold [quick|edge|probes]  ('dim1' = "
             "scalar-table PA shape; 'rows' = plain XLA against the "
             "lane-packed XLA route over table rows x row width; 'mean' = "
